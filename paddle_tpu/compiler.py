@@ -268,21 +268,29 @@ class CompiledProgram:
     def with_data_parallel(self, loss_name=None, build_strategy=None,
                            exec_strategy=None, share_vars_from=None,
                            places=None):
-        """Shard the batch over every visible device (or ``places``)."""
+        """Shard the batch over every visible device, or over ``places``:
+        a device count, ``jax.Device`` objects, or ``TPUPlace(i)`` objects
+        whose ordinals are honoured (a missing chip raises)."""
         self._is_data_parallel = True
         self._loss_name = loss_name
         if build_strategy is not None:
             self._build_strategy = build_strategy
         self._share_vars_from = share_vars_from
         from .parallel.mesh import make_mesh
-        devices = None
-        if places:
-            if isinstance(places, int):
-                devices = jax.devices()[:places]
-            elif hasattr(places[0], "platform"):   # jax Device objects
-                devices = list(places)
-        if devices is None:
+        if not places:
             devices = jax.devices()
+        elif isinstance(places, int):
+            devices = jax.devices()[:places]
+        elif all(hasattr(p, "platform") for p in places):  # jax Devices
+            devices = list(places)
+        elif all(hasattr(p, "device_id") for p in places):  # TPUPlace(i)
+            from .device import tpu_device
+            devices = [tpu_device(p.device_id) for p in places]
+        else:
+            raise TypeError(
+                "with_data_parallel(places=...) takes a device count, "
+                "jax.Device objects or TPUPlace objects, got "
+                f"{[type(p).__name__ for p in places]}")
         self._mesh = make_mesh({"dp": len(devices)}, devices)
         # reconfiguration changes what the executor must lower (mesh,
         # shardings) without touching the program fingerprint — a new

@@ -6,10 +6,13 @@ per device with the PADDLE_TRAINER_ID / PADDLE_CURRENT_ENDPOINT /
 PADDLE_TRAINERS_NUM / PADDLE_TRAINER_ENDPOINTS contract, streams logs,
 and tears the job down if any rank dies.
 
-TPU note: on TPU pods the natural unit is one process per *host* (each
-process owns all local chips; jax.distributed federates hosts), so
-``--nproc_per_node`` defaults to 1.  The rank-0 endpoint doubles as the
-jax.distributed coordinator address.
+TPU note: a chip belongs to one process at a time, and one process owns
+ALL local chips (jax.distributed federates hosts), so on a chip host
+``--nproc_per_node`` is 1 — ``FLAGS_selected_tpus`` is exported for rank
+bookkeeping only and restricts nothing, so two ranks on one chip host
+would fight for the same chips.  Values above 1 are for CPU ranks (the
+localhost drills).  The launcher itself initialises no JAX backend.  The
+rank-0 endpoint doubles as the jax.distributed coordinator address.
 
 Gang coordination: by default (``--gang_backend socket``) the node-0
 launcher hosts a :class:`~paddle_tpu.distributed.coordinator.
@@ -57,7 +60,9 @@ def _parse_args(argv=None):
                    help="this node's ip")
     p.add_argument("--started_port", type=int, default=6170)
     p.add_argument("--nproc_per_node", type=int, default=1,
-                   help="processes per node (1 per TPU host)")
+                   help="processes per node; on a chip host this is 1 — "
+                        "one process owns all local chips (more is for "
+                        "CPU ranks only)")
     p.add_argument("--log_dir", default=None)
     p.add_argument("--gang_dir", default=None,
                    help="shared rendezvous dir for gang checkpoint "

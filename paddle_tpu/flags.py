@@ -55,7 +55,10 @@ _DEFAULTS: Dict[str, Any] = {
     "FLAGS_tracer_profile_fname": "",
     # persistent XLA compilation cache (no reference analog — its CUDA
     # kernels ship precompiled; here first-compile is the analogous cost,
-    # 20-40 s for a big train step, and the cache removes it on re-runs)
+    # 20-40 s for a big train step, and the cache removes it on re-runs).
+    # Placement rule (device.place_compile_cache): JAX_COMPILATION_CACHE_DIR
+    # wins when set, then this flag, then <checkout>/.cache/xla_compile —
+    # the cache is never off
     "FLAGS_xla_compile_cache_dir": "",
     # unified runtime telemetry (paddle_tpu.monitor): span recording for
     # the step tracer.  The metrics REGISTRY is always live (it backs the
@@ -517,15 +520,8 @@ def _apply_side_effects(name: str, value):
         # env so set_flags governs the transport retry loop
         os.environ[name] = str(int(value))
     elif name == "FLAGS_xla_compile_cache_dir":
-        import jax
-        jax.config.update("jax_compilation_cache_dir",
-                          str(value) if value else None)
-        if value:
-            try:
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 1.0)
-            except Exception:
-                pass  # knob varies across jax versions; dir alone works
+        from .device import place_compile_cache
+        place_compile_cache(str(value))
 
 
 def set_flags(flags: Dict[str, Any]):
@@ -684,6 +680,10 @@ def _bootstrap_from_env():
             set_flags({name: raw})
         except (ValueError, TypeError) as e:
             warnings.warn(f"ignoring malformed env var {name}={raw!r}: {e}")
+    # the compile cache is placed at import whether or not a flag named a
+    # directory, so every entry point (trainer, server, bench) has one
+    _apply_side_effects("FLAGS_xla_compile_cache_dir",
+                        _values["FLAGS_xla_compile_cache_dir"])
 
 
 _bootstrap_from_env()

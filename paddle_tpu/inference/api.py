@@ -23,8 +23,8 @@ from .. import io as _io
 #: are resolved ONCE per (model artifact, ir_optim) per process.  A
 #: second predictor on the same model shares the SAME jitted function, so
 #: it pays zero re-optimization, zero re-trace, and the XLA executable is
-#: the in-memory jit-cache hit (across processes,
-#: FLAGS_xla_compile_cache_dir makes the compile itself a disk hit).
+#: the in-memory jit-cache hit (across processes, the persistent compile
+#: cache — device.place_compile_cache — makes the compile a disk hit).
 _ENGINE_CACHE: Dict[tuple, "_InferenceEngine"] = {}  # guarded-by: _ENGINE_LOCK
 _ENGINE_LOCK = threading.Lock()
 _ENGINE_CTR = _monitor.REGISTRY.counter(

@@ -46,11 +46,8 @@ def _ring_attention(ctx, ins, attrs):
     mesh = current_mesh()
     if mesh is not None and axis in mesh.axis_names and \
             mesh.shape[axis] > 1:
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-        try:
-            from jax import shard_map
-        except ImportError:  # pragma: no cover
-            from jax.experimental.shard_map import shard_map
         from ..pallas import ring_attention as _ring
         spec = P(None, None, axis, None)
         fn = shard_map(

@@ -52,14 +52,15 @@ def _fused_conv1x1_bn(ctx, ins, attrs):
         saved_m, saved_v = mean, jax.lax.rsqrt(var + eps)
         mean_out, var_out = mean, var
     else:
-        from ..pallas.flash_attention import _on_tpu
-        if _on_tpu():
+        from ..device import on_tpu
+        if on_tpu():
+            # compiled by Mosaic, or the compiler's error raises
             from ..pallas.conv_bn import conv1x1_stats
             y_raw, s, s2 = conv1x1_stats(xf, w2)
         else:
-            # CPU/GPU fallback: the same (y, sum, sumsq) in plain jnp —
-            # the interpreted Pallas kernel would run the tile loop as
-            # traced ops (measured 1.66x the whole RN50 CPU step).
+            # CPU test path, chosen by platform: the same (y, sum, sumsq)
+            # in plain jnp — the interpreted Pallas kernel would run the
+            # tile loop as traced ops (1.66x the whole RN50 CPU step).
             # Mirrors the unfused chain's dtypes: the matmul in bf16
             # under AMP (conv2d is amp white-listed), stats accumulated
             # in f32 (batch_norm's one-pass rule)

@@ -25,8 +25,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .flash_attention import _on_tpu
-
 
 def _kernel(x_ref, w_ref, mu_ref, inv_ref, g_ref, b_ref, y_ref, s_ref,
             s2_ref, *, relu, normalize, out_dtype):
@@ -107,7 +105,8 @@ def matmul_bn_stats(x, w, producer_stats=None, relu=True, block_m=1024,
         out_shape=[jax.ShapeDtypeStruct((mp, n), x.dtype),
                    jax.ShapeDtypeStruct((1, n), jnp.float32),
                    jax.ShapeDtypeStruct((1, n), jnp.float32)],
-        interpret=interpret or not _on_tpu(),
+        interpret=interpret,
+        name="matmul_bn_stats",
     )(x, w, *stat_args)
     return y, s.reshape(n), s2.reshape(n)
 
@@ -169,27 +168,28 @@ def conv1x1_stats_nchw(x, w, block_hw=512, interpret=False):
         out_shape=[jax.ShapeDtypeStruct((nb, cout, p), x.dtype),
                    jax.ShapeDtypeStruct((cout, 1), jnp.float32),
                    jax.ShapeDtypeStruct((cout, 1), jnp.float32)],
-        interpret=interpret or not _on_tpu(),
+        interpret=interpret,
+        name="conv1x1_stats_nchw",
     )(x, w)
     return y, s.reshape(cout), s2.reshape(cout)
 
 
-@jax.custom_vjp
-def conv1x1_stats(x, w):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def conv1x1_stats(x, w, interpret=False):
     """Differentiable (y, sums, sumsqs) over NCHW-flattened x [N,Cin,P].
 
     Backward is XLA dot_generals in the SAME layout (no transposes):
     dy_eff = dy + ds + 2·y·ds2; dx[n,ci,p] = Σ_co w[co,ci]·dy_eff;
     dw[co,ci] = Σ_{n,p} dy_eff[n,co,p]·x[n,ci,p]."""
-    return conv1x1_stats_nchw(x, w)
+    return conv1x1_stats_nchw(x, w, interpret=interpret)
 
 
-def _conv1x1_stats_fwd(x, w):
-    y, s, s2 = conv1x1_stats_nchw(x, w)
+def _conv1x1_stats_fwd(x, w, interpret):
+    y, s, s2 = conv1x1_stats_nchw(x, w, interpret=interpret)
     return (y, s, s2), (x, w, y)
 
 
-def _conv1x1_stats_bwd(res, cts):
+def _conv1x1_stats_bwd(interpret, res, cts):
     x, w, y = res
     dy, ds, ds2 = cts
     dy_eff = (dy.astype(jnp.float32) + ds[None, :, None]
@@ -210,8 +210,8 @@ conv1x1_stats.defvjp(_conv1x1_stats_fwd, _conv1x1_stats_bwd)
 # is what the model pass uses)
 # ---------------------------------------------------------------------------
 
-@jax.custom_vjp
-def mm_stats(x, w):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def mm_stats(x, w, interpret=False):
     """(y, sums, sumsqs) with y = x @ w — the Pallas fused forward.
 
     Backward is plain XLA matmul math (dy_eff = dy + ds + 2·y·ds2,
@@ -219,16 +219,15 @@ def mm_stats(x, w):
     matmuls already run at the MXU rate and XLA fuses the stat-cotangent
     elementwise into them, so a Pallas backward has nothing left to save
     (RN50_ABLATION.md round-4 addendum)."""
-    y, s, s2 = matmul_bn_stats(x, w, None, relu=False)
-    return y, s, s2
+    return matmul_bn_stats(x, w, None, relu=False, interpret=interpret)
 
 
-def _mm_stats_fwd(x, w):
-    y, s, s2 = matmul_bn_stats(x, w, None, relu=False)
+def _mm_stats_fwd(x, w, interpret):
+    y, s, s2 = matmul_bn_stats(x, w, None, relu=False, interpret=interpret)
     return (y, s, s2), (x, w, y)
 
 
-def _mm_stats_bwd(res, cts):
+def _mm_stats_bwd(interpret, res, cts):
     x, w, y = res
     dy, ds, ds2 = cts
     dy_eff = (dy.astype(jnp.float32) + ds[None, :]

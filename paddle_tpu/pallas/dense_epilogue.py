@@ -24,17 +24,38 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .flash_attention import _on_tpu
-
 _LANE = 128
 
 
-def _act_fn(name, approximate=False):
+def _erf_gelu(v):
+    """Exact (erf) gelu for use INSIDE the kernel: the Pallas TPU lowering
+    of JAX 0.9 implements neither ``erf`` nor the ``erfc`` that
+    ``jax.nn.gelu(approximate=False)`` now emits, so erf is the
+    Abramowitz-Stegun 7.1.26 rational form (|error| <= 1.5e-7, below the
+    bf16 output's rounding) built from ``exp`` alone."""
+    z = jnp.abs(v) * 0.7071067811865476
+    t = 1.0 / (1.0 + 0.3275911 * z)
+    poly = t * (0.254829592 + t * (-0.284496736 + t * (
+        1.421413741 + t * (-1.453152027 + t * 1.061405429))))
+    erf = jnp.sign(v) * (1.0 - poly * jnp.exp(-z * z))
+    return 0.5 * v * (1.0 + erf)
+
+
+def _act_fn(name, approximate=False, in_kernel=False):
     if name == "relu":
         return lambda v: jnp.maximum(v, 0.0)
     if name == "gelu":
+        if in_kernel and not approximate:
+            return _erf_gelu
         return functools.partial(jax.nn.gelu, approximate=approximate)
     return lambda v: v
+
+
+def tileable(m: int, block_m: int = 512) -> bool:
+    """Whether ``M`` rows split into dividing row blocks of >= 8 (or fit
+    one block) — what ``matmul_bias_act`` needs; callers test it up front
+    instead of catching the kernel's ValueError."""
+    return m <= block_m or m % 8 == 0
 
 
 def _kernel(x_ref, w_ref, b_ref, y_ref, *, act, approximate, out_dtype):
@@ -45,7 +66,7 @@ def _kernel(x_ref, w_ref, b_ref, y_ref, *, act, approximate, out_dtype):
     y = lax.dot_general(x, w, (((1,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32)
     y = y + b_ref[...].astype(jnp.float32)
-    y = _act_fn(act, approximate)(y)
+    y = _act_fn(act, approximate, in_kernel=True)(y)
     y_ref[...] = y.astype(out_dtype)
 
 
@@ -54,8 +75,9 @@ def matmul_bias_act(x, w, b, act="", approximate=False, block_m=512,
     """``act(x @ w + b)`` with the epilogue fused into the GEMM tile.
 
     ``x``: [M, K]; ``w``: [K, N]; ``b``: [N].  Differentiable via
-    custom_vjp (XLA matmul backward).  Off-TPU runs in interpret mode —
-    numerics match the jnp composition to bf16 rounding.
+    custom_vjp (XLA matmul backward).  Compiled by Mosaic unless
+    ``interpret`` is passed (the CPU test path) — numerics match the jnp
+    composition to bf16 rounding.  ``M`` must satisfy :func:`tileable`.
     """
     return _mba(x, w, b, act, bool(approximate), int(block_m),
                 bool(interpret))
@@ -88,7 +110,8 @@ def _mba_fwd_impl(x, w, b, act, approximate, block_m, interpret):
                   pl.BlockSpec((1, n), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-        interpret=interpret or not _on_tpu(),
+        interpret=interpret,
+        name="matmul_bias_act",
     )(x, w, b.reshape(1, n))
     return y
 

@@ -26,7 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .flash_attention import _on_tpu
+from ..device import on_tpu
 
 _LANE = 128
 
@@ -105,6 +105,7 @@ def _fwd_pallas(x2, scale, bias, eps, interpret):
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x2.dtype),
         interpret=interpret,
+        name="layer_norm_fwd",
     )(x2, scale, bias)
 
 
@@ -137,12 +138,13 @@ def _bwd_pallas(x2, scale, dy2, eps, interpret):
             pltpu.VMEM((d,), jnp.float32),
         ],
         interpret=interpret,
+        name="layer_norm_bwd",
     )(x2, scale, dy2)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _fused_ln(x2, scale, bias, eps, interpret):
-    if _on_tpu() or interpret:
+    if on_tpu() or interpret:
         return _fwd_pallas(x2, scale, bias, eps, interpret)
     return _ln_ref(x2, scale, bias, eps)
 
@@ -153,7 +155,7 @@ def _fused_ln_fwd(x2, scale, bias, eps, interpret):
 
 def _fused_ln_bwd(eps, interpret, res, dy):
     x2, scale = res
-    if _on_tpu() or interpret:
+    if on_tpu() or interpret:
         dx, ds, db = _bwd_pallas(x2, scale, dy, eps, interpret)
     else:
         xf = x2.astype(jnp.float32)

@@ -28,13 +28,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..device import on_tpu
 from .flash_attention import (NEG_INF, _flash_bwd_jax, _flash_fwd_jax,
-                              _flash_fwd_pallas, _on_tpu)
+                              _flash_fwd_pallas)
 
 
 def _chunk_fwd(q, k, v, bias, sm_scale, interpret):
     """(o, lse) of one q-shard vs one kv-shard, Pallas on TPU."""
-    if _on_tpu() or interpret:
+    if on_tpu() or interpret:
         return _flash_fwd_pallas(q, k, v, bias, False, sm_scale,
                                  128, 128, 0, interpret)
     return _flash_fwd_jax(q, k, v, bias, False, sm_scale, 128, 0)

@@ -21,6 +21,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..device import is_tpu
+
 AXES = ("dp", "mp", "sp", "pp", "ep")
 
 _current_mesh: Optional[Mesh] = None
@@ -88,10 +90,11 @@ def make_topology_mesh(axes: Dict[str, int], devices=None) -> Mesh:
     devices so the innermost (mp/sp) axes land on physically adjacent
     chips — ICI rings for the model-parallel collectives, DCN only
     across the outermost (dp) axis.  Multi-host meshes go through the
-    hybrid constructor (one slow axis per granule, fast axes inside);
-    anything mesh_utils cannot map (CPU fan-outs, odd shapes) falls
-    back to :func:`make_mesh`, which is always valid, just not
-    bandwidth-optimal."""
+    hybrid constructor (one slow axis per granule, fast axes inside).
+    Virtual CPU devices have no topology, so the CPU test mesh is
+    :func:`make_mesh`'s row-major reshape, chosen by platform; on TPU a
+    shape mesh_utils cannot map raises instead of quietly losing the
+    physical adjacency."""
     devices = list(devices if devices is not None else jax.devices())
     names = [a for a in AXES if a in axes] + \
         [a for a in axes if a not in AXES]
@@ -100,27 +103,26 @@ def make_topology_mesh(axes: Dict[str, int], devices=None) -> Mesh:
         raise ValueError(
             f"mesh {axes} needs {int(np.prod(sizes))} devices, "
             f"have {len(devices)}")
-    try:
-        from jax.experimental import mesh_utils
-        n_hosts = len({getattr(d, "process_index", 0) for d in devices})
-        if n_hosts > 1 and len(sizes) > 1:
-            per_host = len(devices) // n_hosts
-            # split each axis between the DCN (host) and ICI (chip)
-            # levels, outermost axes absorbing the host factor first
-            dcn, ici, hosts_left = [], [], n_hosts
-            for s in sizes:
-                g = np.gcd(s, hosts_left)
-                dcn.append(int(g))
-                ici.append(s // int(g))
-                hosts_left //= int(g)
-            if hosts_left == 1 and int(np.prod(ici)) == per_host:
-                arr = mesh_utils.create_hybrid_device_mesh(
-                    ici, dcn, devices=devices)
-                return Mesh(arr, axis_names=tuple(names))
-        arr = mesh_utils.create_device_mesh(sizes, devices=devices)
-        return Mesh(arr, axis_names=tuple(names))
-    except Exception:
+    if not is_tpu(devices[0]):
         return make_mesh(axes, devices)
+    from jax.experimental import mesh_utils
+    n_hosts = len({getattr(d, "process_index", 0) for d in devices})
+    if n_hosts > 1 and len(sizes) > 1:
+        per_host = len(devices) // n_hosts
+        # split each axis between the DCN (host) and ICI (chip)
+        # levels, outermost axes absorbing the host factor first
+        dcn, ici, hosts_left = [], [], n_hosts
+        for s in sizes:
+            g = np.gcd(s, hosts_left)
+            dcn.append(int(g))
+            ici.append(s // int(g))
+            hosts_left //= int(g)
+        if hosts_left == 1 and int(np.prod(ici)) == per_host:
+            arr = mesh_utils.create_hybrid_device_mesh(
+                ici, dcn, devices=devices)
+            return Mesh(arr, axis_names=tuple(names))
+    arr = mesh_utils.create_device_mesh(sizes, devices=devices)
+    return Mesh(arr, axis_names=tuple(names))
 
 
 def mesh_axis_sizes(mesh: Mesh) -> Dict[str, int]:

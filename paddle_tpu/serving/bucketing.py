@@ -6,8 +6,8 @@ design — PAPERS.md arxiv 1802.04799).
 A bucket is a sequence length; every feed of a request is padded along its
 leading (per-example sequence) axis up to the bucket, and the batch is
 padded to a FIXED per-bucket width — so each bucket lowers to exactly one
-XLA executable, persisted across restarts via
-``FLAGS_xla_compile_cache_dir``.  Fluid programs bake the sequence length
+XLA executable, persisted across restarts by the persistent compile cache
+(``device.place_compile_cache``).  Fluid programs bake the sequence length
 into op attrs (position-table slices, causal-mask ranges), so the server
 materializes one program per bucket through a ``program_factory`` and runs
 each through ``compiler.optimize`` — the verifier / cost / memory stamps

@@ -359,8 +359,9 @@ class InferenceServer(_ServerBase):
     sequence length into op attrs, so each bucket is its own program —
     all sharing one scope of parameters).  Each bucket compiles ONCE
     (fixed width x bucket feed shapes through ``compiler.optimize`` with
-    the verifier/cost/memory stamps riding along) and persists via
-    ``FLAGS_xla_compile_cache_dir``, so a server restart is warm and the
+    the verifier/cost/memory stamps riding along) and persists in the
+    compile cache placed at import (``device.place_compile_cache``), so a
+    server restart is warm and the
     compile count equals the bucket count — never the number of distinct
     request shapes.
     """

@@ -68,10 +68,7 @@ def ring_attention_check():
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from paddle_tpu.pallas import mha_reference, ring_attention
 
     B, H, T, D = 1, 2, 16, 8

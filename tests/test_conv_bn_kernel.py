@@ -3,6 +3,8 @@ conv+BN machinery RN50_ABLATION.md's round-4 addendum documents): kernel
 parity, custom-vjp gradients, block sizing, and the flash backward's
 partial-budget fallback logic."""
 
+import functools
+
 import numpy as np
 
 import jax
@@ -48,7 +50,8 @@ def test_conv1x1_stats_custom_vjp_matches_reference():
         y = jnp.einsum("oc,ncp->nop", w, x)
         return y, y.sum((0, 2)), (y * y).sum((0, 2))
 
-    g = jax.grad(loss(conv1x1_stats), argnums=(0, 1))(x, w)
+    g = jax.grad(loss(functools.partial(conv1x1_stats, interpret=True)),
+                 argnums=(0, 1))(x, w)
     g_ref = jax.grad(loss(ref), argnums=(0, 1))(x, w)
     for a, b in zip(g, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -83,7 +86,7 @@ def test_mm_stats_grads():
     x, w = _rand((64, 16), 9), _rand((16, 8), 10)
 
     def loss(x, w):
-        y, s, s2 = mm_stats(x, w)
+        y, s, s2 = mm_stats(x, w, interpret=True)
         return (y.astype(jnp.float32) ** 2).sum() + s.sum() + s2.sum()
 
     def ref(x, w):

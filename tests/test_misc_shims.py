@@ -151,13 +151,21 @@ def test_flags_system(monkeypatch):
 
 
 def test_xla_compile_cache_flag(tmp_path):
-    """FLAGS_xla_compile_cache_dir wires jax's persistent compilation
+    """FLAGS_xla_compile_cache_dir places jax's persistent compilation
     cache (first-compile is the TPU analog of the reference's CUDA
-    kernel-build cost)."""
+    kernel-build cost) — unless JAX_COMPILATION_CACHE_DIR is set, which
+    always wins; emptying the flag returns to the checkout default, never
+    to "off" (tests/test_compile_cache_placement.py has the full matrix)."""
+    import os
+
     import jax
+    from paddle_tpu.device import DEFAULT_COMPILE_CACHE_DIR
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     d = str(tmp_path / "xla_cache")
     fluid.set_flags({"FLAGS_xla_compile_cache_dir": d})
     try:
-        assert jax.config.jax_compilation_cache_dir == d
+        assert jax.config.jax_compilation_cache_dir == (env_dir or d)
     finally:
         fluid.set_flags({"FLAGS_xla_compile_cache_dir": ""})
+    assert jax.config.jax_compilation_cache_dir == (
+        env_dir or DEFAULT_COMPILE_CACHE_DIR)

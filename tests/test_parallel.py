@@ -127,10 +127,7 @@ def test_hierarchical_mesh_and_allreduce():
     NCCLCommunicator hierarchical allreduce semantics)."""
     import jax
     import jax.numpy as jnp
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from paddle_tpu.parallel import (hierarchical_allreduce,
                                      make_hierarchical_mesh)

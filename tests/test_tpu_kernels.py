@@ -1,0 +1,306 @@
+"""Compiled-kernel sweep: every Pallas kernel in ``paddle_tpu/pallas``
+compiled by Mosaic (never interpreted) on the real chip and compared with
+its in-tree reference, at the shapes the models actually reach.
+
+Run (on a chip, one process):
+    PADDLE_TPU_TEST_HW=1 python -m pytest -m tpu_hw tests/test_tpu_kernels.py -q
+Skipped automatically on the CPU-mesh test config; the two table tests at
+the bottom run on CPU.
+
+Tolerances are on ``max|got - want| / max|want|`` (scale-free: gradient
+magnitudes grow with T): 3e-2 for bf16 inputs (8 mantissa bits, f32
+accumulation in-kernel), 1e-2 for f32 inputs (the MXU's default-precision
+passes inside the kernel against a highest-precision reference).
+"""
+
+import functools
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+tpu_hw = pytest.mark.tpu_hw
+
+TOL = {jnp.bfloat16: 3e-2, jnp.float32: 1e-2}
+
+
+def _record(kernel, **metrics):
+    path = os.environ.get("PADDLE_TPU_NUMERICS_OUT")
+    if path:
+        with open(path, "a") as f:
+            f.write(json.dumps({"kernel": kernel, **metrics}) + "\n")
+
+
+def _rel_err(got, want):
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    assert np.isfinite(got).all()
+    return float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-6))
+
+
+def _rand(shape, seed, dtype=jnp.float32, scale=1.0):
+    return jnp.asarray(np.random.RandomState(seed).randn(*shape)
+                       .astype(np.float32) * scale).astype(dtype)
+
+
+# ---------------------------------------------------------------------------
+# flash attention: forward + both backward implementations
+# ---------------------------------------------------------------------------
+
+def _flash_case(t, d, heads, dtype, causal, bwd_impl, bias=False):
+    from paddle_tpu.pallas import flash_attention, mha_reference
+
+    shape = (1, heads, t, d)
+    q, k, v, w = (_rand(shape, s, dtype, 0.5) for s in range(4))
+    b = _rand((1, 1, t, t), 9, jnp.float32, 0.5) if bias else None
+
+    def loss(fn, **kw):
+        def go(q, k, v):
+            o = fn(q, k, v, bias=b, causal=causal, **kw)
+            return jnp.sum(o.astype(jnp.float32) * w.astype(jnp.float32))
+        return go
+
+    got = (flash_attention(q, k, v, bias=b, causal=causal),) + jax.grad(
+        loss(flash_attention, bwd_impl=bwd_impl), argnums=(0, 1, 2))(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        f32 = [a.astype(jnp.float32) for a in (q, k, v)]
+        want = (mha_reference(*f32, bias=b, causal=causal),) + jax.grad(
+            loss(mha_reference), argnums=(0, 1, 2))(*f32)
+    errs = {n: _rel_err(g, r)
+            for n, g, r in zip(("o", "dq", "dk", "dv"), got, want)}
+    _record("flash_attention", t=t, d=d, heads=heads, causal=causal,
+            dtype=jnp.dtype(dtype).name, bwd_impl=bwd_impl, bias=bias,
+            **errs)
+    assert max(errs.values()) < TOL[dtype], errs
+
+
+@tpu_hw
+@pytest.mark.parametrize("bwd_impl", ["combined", "split"])
+@pytest.mark.parametrize("t,causal,heads", [
+    (2048, True, 4),      # _FWD_DEFAULTS (1024,1024) / _BWD (1024,512)
+    (4096, False, 2),     # (512,2048) / (1024,1024)
+    (8192, False, 1),     # (512,2048) / (1024,512)
+    (16384, False, 1),    # (512,2048) / (1024,1024)
+])
+def test_flash_table_entries_d64(t, causal, heads, bwd_impl):
+    """Every entry of the per-length block tables, bf16 as under AMP."""
+    _flash_case(t, 64, heads, jnp.bfloat16, causal, bwd_impl)
+
+
+@tpu_hw
+@pytest.mark.parametrize("bwd_impl", ["combined", "split"])
+def test_flash_d128_baseline_blocks(bwd_impl):
+    """d > 64 skips the tables: the (512, 1024) baseline blocks."""
+    _flash_case(2048, 128, 2, jnp.bfloat16, True, bwd_impl)
+
+
+@tpu_hw
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_padded_length(causal):
+    """T = 1000 divides no block: the padding masks run."""
+    _flash_case(1000, 64, 2, jnp.float32, causal, "combined")
+    _flash_case(1000, 64, 2, jnp.float32, causal, "split")
+
+
+@tpu_hw
+def test_flash_with_bias():
+    """A [1, 1, T, T] bias rides the forward kernel (the backward with a
+    bias is the blockwise-jax path by design)."""
+    _flash_case(1024, 64, 2, jnp.float32, False, "combined", bias=True)
+
+
+# ---------------------------------------------------------------------------
+# conv1x1 + BN statistics (RN50's default fused path)
+# ---------------------------------------------------------------------------
+
+@tpu_hw
+@pytest.mark.parametrize("cin,cout,hw", [
+    (64, 64, 56 * 56),     # smallest 1x1 site (stage 1, b0)
+    (256, 64, 56 * 56),
+    (2048, 512, 7 * 7),    # largest-Cin site (stage 4, b0)
+    (512, 2048, 7 * 7),    # largest-Cout site (stage 4, b2)
+])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_conv1x1_stats_rn50_sites(cin, cout, hw, dtype):
+    """NCHW-native kernel, the layout ``ops/conv_bn_ops.py`` feeds it
+    (bf16 under AMP, f32 without), forward and custom-vjp backward."""
+    from paddle_tpu.pallas.conv_bn import conv1x1_stats
+
+    x = _rand((8, cin, hw), 0, dtype)
+    w = _rand((cout, cin), 1, dtype, cin ** -0.5)
+
+    def ref(x, w):
+        y = jnp.einsum("oc,ncp->nop", w.astype(jnp.bfloat16),
+                       x.astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32)
+        return y, y.sum((0, 2)), (y * y).sum((0, 2))
+
+    def loss(fn):
+        def go(x, w):
+            y, s, s2 = fn(x, w)
+            return (jnp.sum(y.astype(jnp.float32) ** 2) * 0.5
+                    + jnp.sum(s) * 0.1 + jnp.sum(s2) * 0.01)
+        return go
+
+    got = conv1x1_stats(x, w)
+    want = ref(x, w)
+    errs = {n: _rel_err(g, r)
+            for n, g, r in zip(("y", "sum", "sumsq"), got, want)}
+    g_got = jax.grad(loss(conv1x1_stats), argnums=(0, 1))(x, w)
+    g_want = jax.grad(loss(ref), argnums=(0, 1))(x, w)
+    errs.update({n: _rel_err(g, r)
+                 for n, g, r in zip(("dx", "dw"), g_got, g_want)})
+    _record("conv1x1_stats", cin=cin, cout=cout, hw=hw,
+            dtype=jnp.dtype(dtype).name, **errs)
+    assert max(errs.values()) < 3e-2, errs
+
+
+@tpu_hw
+@pytest.mark.parametrize("m,k,n", [(8 * 56 * 56, 64, 64),
+                                   (8 * 7 * 7, 2048, 512)])
+def test_matmul_bn_stats_channel_minor(m, k, n):
+    """The channel-minor variant (``mm_stats``; kept for the microbench),
+    with (1, n) stat blocks at n = 64."""
+    from paddle_tpu.pallas.conv_bn import mm_stats
+
+    x, w = _rand((m, k), 0, jnp.bfloat16), _rand((k, n), 1, jnp.bfloat16,
+                                                 k ** -0.5)
+    y, s, s2 = mm_stats(x, w)
+    yr = jnp.dot(x, w, preferred_element_type=jnp.float32)
+    errs = {"y": _rel_err(y, yr), "sum": _rel_err(s, yr.sum(0)),
+            "sumsq": _rel_err(s2, (yr * yr).sum(0))}
+    _record("matmul_bn_stats", m=m, k=k, n=n, **errs)
+    assert max(errs.values()) < 3e-2, errs
+
+
+# ---------------------------------------------------------------------------
+# layer norm, dense epilogue
+# ---------------------------------------------------------------------------
+
+@tpu_hw
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_fused_layer_norm_d768(dtype):
+    from paddle_tpu.pallas.layer_norm import _ln_ref, fused_layer_norm
+
+    x = _rand((128, 128, 768), 0, dtype) + 3.0
+    s, b = _rand((768,), 1) + 1.0, _rand((768,), 2)
+    w = _rand((128, 128, 768), 3)
+
+    def loss(fn):
+        return lambda x, s, b: jnp.sum(fn(x, s, b).astype(jnp.float32) * w)
+
+    ln_ref = functools.partial(_ln_ref, eps=1e-5)
+    errs = {"y": _rel_err(fused_layer_norm(x, s, b), ln_ref(x, s, b))}
+    g_got = jax.grad(loss(fused_layer_norm), argnums=(0, 1, 2))(x, s, b)
+    g_want = jax.grad(loss(ln_ref), argnums=(0, 1, 2))(x, s, b)
+    errs.update({n: _rel_err(g, r) for n, g, r in
+                 zip(("dx", "dscale", "dbias"), g_got, g_want)})
+    _record("fused_layer_norm", d=768, dtype=jnp.dtype(dtype).name, **errs)
+    assert max(errs.values()) < TOL[dtype], errs
+
+
+@tpu_hw
+@pytest.mark.parametrize("k,n,act", [(768, 3072, "gelu"), (3072, 768, ""),
+                                     (768, 768, "relu")])
+def test_matmul_bias_act_bert_ffn(k, n, act):
+    """BERT-base FFN shapes at batch 128 x seq 128, bf16 as under AMP."""
+    from paddle_tpu.pallas import matmul_bias_act
+
+    m = 128 * 128
+    x = _rand((m, k), 0, jnp.bfloat16)
+    w = _rand((k, n), 1, jnp.bfloat16, k ** -0.5)
+    b = _rand((n,), 2)
+
+    act_fn = {"gelu": functools.partial(jax.nn.gelu, approximate=False),
+              "relu": jax.nn.relu, "": lambda v: v}[act]
+
+    def ref(x, w, b):
+        y = jnp.dot(x, w, preferred_element_type=jnp.float32) + b
+        return act_fn(y).astype(x.dtype)
+
+    def loss(fn):
+        return lambda x, w, b: jnp.sum(fn(x, w, b).astype(jnp.float32) ** 2)
+
+    fused = functools.partial(matmul_bias_act, act=act)
+    errs = {"y": _rel_err(fused(x, w, b), ref(x, w, b))}
+    g_got = jax.grad(loss(fused), argnums=(0, 1, 2))(x, w, b)
+    g_want = jax.grad(loss(ref), argnums=(0, 1, 2))(x, w, b)
+    errs.update({n_: _rel_err(g, r) for n_, g, r in
+                 zip(("dx", "dw", "db"), g_got, g_want)})
+    _record("matmul_bias_act", k=k, n=n, act=act, **errs)
+    assert max(errs.values()) < 3e-2, errs
+
+
+# ---------------------------------------------------------------------------
+# the real step conv1x1_stats runs in: RN50, batch 256, default flags
+# ---------------------------------------------------------------------------
+
+@tpu_hw
+def test_rn50_step_batch256_default_flags(tmp_path):
+    import paddle_tpu as pt
+    from paddle_tpu import optimizer as opt
+    from paddle_tpu.framework import (Program, Scope, program_guard,
+                                      scope_guard)
+    from paddle_tpu.models.resnet import build_resnet_train
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from chip_smoke import lowered_kernel_names
+
+    assert pt.get_flags("FLAGS_graph_fusion")["FLAGS_graph_fusion"]
+    scope = Scope()
+    with scope_guard(scope), program_guard(Program(), Program()):
+        _, _, loss, _ = build_resnet_train(class_dim=1000, depth=50)
+        pt.amp.decorate(opt.MomentumOptimizer(
+            learning_rate=0.1, momentum=0.9)).minimize(loss)
+        exe = pt.Executor(pt.TPUPlace(0))
+        exe.run(pt.default_startup_program(), scope=scope, seed=3)
+        rng = np.random.RandomState(0)
+        feed = {"image": rng.rand(256, 3, 224, 224).astype(np.float32),
+                "label": rng.randint(0, 1000, (256, 1)).astype(np.int32)}
+        jax.config.update("jax_dump_ir_to", str(tmp_path))
+        try:
+            l0, = exe.run(feed=feed, fetch_list=[loss.name], scope=scope)
+        finally:
+            jax.config.update("jax_dump_ir_to", None)
+        l1, = exe.run(feed=feed, fetch_list=[loss.name], scope=scope)
+    kernels = lowered_kernel_names(str(tmp_path))
+    _record("rn50_step_b256", losses=[float(l0), float(l1)],
+            conv1x1_stats_calls=kernels.count("conv1x1_stats_nchw"))
+    assert np.isfinite(l0) and np.isfinite(l1), (l0, l1)
+    # all 36 fused 1x1 conv+BN sites reach the compiled Mosaic kernel
+    # (72 calls: each site's grad op lowers the forward a second time)
+    assert kernels.count("conv1x1_stats_nchw") >= 36, kernels
+
+
+# ---------------------------------------------------------------------------
+# CPU: a TPU kind the peak tables do not know is an error, never a default
+# ---------------------------------------------------------------------------
+
+class _StubTPU:
+    platform = "tpu"
+
+    def __init__(self, kind):
+        self.device_kind = kind
+
+
+def test_peak_tables_raise_on_unknown_tpu_kind():
+    from paddle_tpu.analysis import device_link_bandwidth, device_peak_flops
+
+    with pytest.raises(ValueError, match="TPU v99"):
+        device_peak_flops(_StubTPU("TPU v99"))
+    with pytest.raises(ValueError, match="TPU v99"):
+        device_link_bandwidth(_StubTPU("TPU v99"))
+
+
+def test_peak_tables_know_the_v5e():
+    from paddle_tpu.analysis import device_link_bandwidth, device_peak_flops
+
+    assert device_peak_flops(_StubTPU("TPU v5 lite")) == 197e12
+    assert device_link_bandwidth(_StubTPU("TPU v5 lite")) == 200e9
+    assert device_peak_flops(_StubTPU("TPU v5")) == 459e12      # a v5p

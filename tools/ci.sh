@@ -5,11 +5,9 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-# persistent XLA compile cache (ROADMAP open item): workspace-local so
-# repeated CI rounds skip the first-compile cost; the compile-span
-# telemetry labels hits vs. writes so the effect is measurable
-export FLAGS_xla_compile_cache_dir="${FLAGS_xla_compile_cache_dir:-$PWD/.cache/xla_compile}"
-mkdir -p "$FLAGS_xla_compile_cache_dir"
+# The persistent XLA compile cache needs no setup here: the package places
+# it at import (JAX_COMPILATION_CACHE_DIR if set, else .cache/xla_compile
+# in this checkout), so repeated CI rounds skip the first-compile cost.
 
 echo "== native runtime build =="
 make -C native

@@ -13,8 +13,8 @@
 """
 
 import os
+import shutil
 import sys
-import tempfile
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
@@ -116,32 +116,38 @@ def main():
               f"{worst:.2e})")
 
         # -- gate 4: autotune verdicts cached + persisted -----------------
-        with tempfile.TemporaryDirectory() as tmp:
-            pt.set_flags({"FLAGS_xla_compile_cache_dir": tmp,
-                          "FLAGS_fusion_autotune": True})
-            try:
-                fusion.clear_cache()
-                miss0 = counter_total(
-                    "paddle_tpu_fusion_autotune_total", cache="miss")
-                hit0 = counter_total(
-                    "paddle_tpu_fusion_autotune_total", cache="hit")
-                fusion.fuse_program(prog, (loss.name,),
-                                    feed_shapes={"image": (4, 3, 8, 8)})
-                miss1 = counter_total(
-                    "paddle_tpu_fusion_autotune_total", cache="miss")
-                assert miss1 > miss0, "autotune never benchmarked"
-                assert os.path.exists(
-                    os.path.join(tmp, "fusion_autotune.json")), \
-                    "autotune verdicts not persisted next to the XLA cache"
-                fusion.clear_cache()     # drops memory, keeps the file
-                fusion.fuse_program(prog, (loss.name,),
-                                    feed_shapes={"image": (4, 3, 8, 8)})
-                hit1 = counter_total(
-                    "paddle_tpu_fusion_autotune_total", cache="hit")
-                assert hit1 > hit0, "persisted autotune cache not hit"
-            finally:
-                pt.set_flags({"FLAGS_xla_compile_cache_dir": "",
-                              "FLAGS_fusion_autotune": False})
+        # a FIXED scratch dir (the path is part of the XLA cache key, and
+        # the flag moves that cache too), emptied so the verdicts are new
+        from paddle_tpu.device import DEFAULT_COMPILE_CACHE_DIR
+        tmp = os.path.join(os.path.dirname(DEFAULT_COMPILE_CACHE_DIR),
+                           "fusion_smoke")
+        shutil.rmtree(tmp, ignore_errors=True)
+        os.makedirs(tmp)
+        pt.set_flags({"FLAGS_xla_compile_cache_dir": tmp,
+                      "FLAGS_fusion_autotune": True})
+        try:
+            fusion.clear_cache()
+            miss0 = counter_total(
+                "paddle_tpu_fusion_autotune_total", cache="miss")
+            hit0 = counter_total(
+                "paddle_tpu_fusion_autotune_total", cache="hit")
+            fusion.fuse_program(prog, (loss.name,),
+                                feed_shapes={"image": (4, 3, 8, 8)})
+            miss1 = counter_total(
+                "paddle_tpu_fusion_autotune_total", cache="miss")
+            assert miss1 > miss0, "autotune never benchmarked"
+            assert os.path.exists(
+                os.path.join(tmp, "fusion_autotune.json")), \
+                "autotune verdicts not persisted next to the XLA cache"
+            fusion.clear_cache()     # drops memory, keeps the file
+            fusion.fuse_program(prog, (loss.name,),
+                                feed_shapes={"image": (4, 3, 8, 8)})
+            hit1 = counter_total(
+                "paddle_tpu_fusion_autotune_total", cache="hit")
+            assert hit1 > hit0, "persisted autotune cache not hit"
+        finally:
+            pt.set_flags({"FLAGS_xla_compile_cache_dir": "",
+                          "FLAGS_fusion_autotune": False})
         print("gate 4 OK: autotune measured, persisted, and cache-hit")
     print("fusion smoke OK")
 
