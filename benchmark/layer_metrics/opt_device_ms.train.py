@@ -1,0 +1,9 @@
+"""Device milliseconds per traced step under the ``pt.opt/*`` scopes: the
+optimizer's update ops (``benchmark/op_scopes.py``; each instant counted
+once, mean over the chips)."""
+
+from .. import op_scopes
+
+
+def read(inputs):
+    return op_scopes.train_ms_of_role(inputs, "opt")

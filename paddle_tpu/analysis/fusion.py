@@ -820,6 +820,10 @@ def _finish_candidate(graph, program, cand, *, fused_type, fused_ins,
                     g_outs["IG$Addends"] = real
             g_attrs = dict(attrs)
             g_attrs["__fwd_type__"] = fused_type
+            # a grad op like the ones it replaces (backward.py tags those
+            # through _op_role_guard): clone(for_test) prunes by this role
+            # and the executor names the op's scope ``pt.bwd/...`` by it
+            g_attrs["op_role"] = "backward"
             gnode = g.create_op_node(fused_type + "_grad", inputs=g_ins,
                                      outputs=g_outs, attrs=g_attrs)
             if addend_grads and any(n is None for n in addend_nodes):

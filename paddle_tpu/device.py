@@ -57,7 +57,16 @@ def place_compile_cache(flag_dir: str = "") -> str:
     ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and nothing
     here (nor ``FLAGS_xla_compile_cache_dir``) overrides or clears it.
     Otherwise the cache is ``flag_dir`` when given, else
-    :data:`DEFAULT_COMPILE_CACHE_DIR`.  Touches no backend."""
+    :data:`DEFAULT_COMPILE_CACHE_DIR`.  In every case HLO metadata (source
+    lines, named scopes) is made part of the cache key.  Touches no
+    backend."""
+    # Wherever the cache is: the key covers the HLO metadata too.  JAX leaves
+    # it out by default, and an executable cached by a commit that named its
+    # operations otherwise (or not at all) is then loaded for this one: its
+    # profile carries the old names, and ``pt.<role>/<op>`` scopes never reach
+    # the device trace (measured on a v5e, PR 24: a cache the parent commit
+    # had filled gave 5 hits and no scoped operation).
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         return env_dir

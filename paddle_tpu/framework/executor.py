@@ -44,9 +44,6 @@ from .scope import Scope, global_scope
 #: dispatch counters below are per-executor label series of these same
 #: families, so `Executor.dispatch_stats()`, the profiler aggregate, and
 #: the JSON/Prometheus exporters read ONE store
-_THROTTLE_HIST = _monitor.REGISTRY.histogram(
-    "paddle_tpu_executor_throttle_wait_us",
-    "in-flight throttle: host wait per blocking probe pop (us)")
 _COMPILE_HIST = _monitor.REGISTRY.histogram(
     "paddle_tpu_compile_ms",
     "trace + lower + XLA compile wall time per fresh compiled block (ms)",
@@ -59,6 +56,18 @@ _COMPILE_CTR = _monitor.REGISTRY.counter(
     "(disk hit, or compile under the persist threshold), 'off' = "
     "jax_compilation_cache_dir cleared behind the package's back",
     ("persist",))
+_COMPILE_PHASE_HIST = _monitor.REGISTRY.histogram(
+    "paddle_tpu_compile_phase_seconds",
+    "first call of a compiled block, split by phase (seconds): 'prepare' "
+    "= run() entry to the jit call (fusion/verify passes, persistable "
+    "classification, feed staging), 'trace' = the block's ops lowered to "
+    "a jaxpr, 'lower' = jaxpr to MLIR, 'backend' = XLA compile or "
+    "persistent-cache load, 'first_run' = the rest of the call; 'retrace' "
+    "= trace+lower+backend that JAX ran again inside a LATER dispatch of "
+    "the same block.  block='train' when the block holds backward or "
+    "optimizer ops, else 'other'",
+    ("phase", "block"),
+    buckets=(0.01, 0.1, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0, 120.0, 300.0))
 _COLLECTIVE_CTR = _monitor.REGISTRY.counter(
     "paddle_tpu_collective_launches_total",
     "host-launched collectives by kind (in-graph c_* ops are compiled "
@@ -220,6 +229,64 @@ _COLL_STEP = _COLLECTIVE_CTR.labels(kind="shard_map_step")
 _COLL_ALLGATHER = _COLLECTIVE_CTR.labels(kind="process_allgather")
 _COLL_H2G = _COLLECTIVE_CTR.labels(kind="host_to_global")
 _COLL_BARRIER = _COLLECTIVE_CTR.labels(kind="step_barrier")
+
+
+#: jax.monitoring's own compile events -> the phase each one ENDS
+_JAX_PHASE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+#: per-thread sink of (phase, t_start, t_end) on the perf_counter clock,
+#: armed around the jitted call of every dispatch: JAX fires its events
+#: on the calling thread, a steady-state step fires none
+_phase_sink = threading.local()
+_phase_listener_lock = threading.Lock()
+_phase_listener_on = False
+
+
+def _on_jax_duration(event, secs, **_):
+    phase = _JAX_PHASE_EVENTS.get(event)
+    sink = getattr(_phase_sink, "events", None)
+    if phase is not None and sink is not None:
+        end = time.perf_counter()
+        sink.append((phase, end - secs, end))
+
+
+def _install_phase_listener() -> None:
+    """Hear JAX's compile events (once per process; jax.monitoring has no
+    public way to take a listener off again)."""
+    global _phase_listener_on
+    with _phase_listener_lock:
+        if not _phase_listener_on:
+            import jax.monitoring
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_jax_duration)
+            _phase_listener_on = True
+
+
+def _compile_phase_bounds(events, t_call, t_end):
+    """Partition of ``[t_call, t_end]`` (the jitted call that compiled)
+    into trace | lower | backend | first_run, cut where JAX's own events
+    of each kind end: ``trace`` runs to the end of the jaxpr trace (kernel
+    traces made later, inside lowering, are lowering), ``lower`` to the end
+    of the MLIR conversion, ``backend`` (cache-key hashing, persistent-
+    cache read, XLA compile) to the end of the backend event.  A phase JAX
+    reported no event for is empty."""
+    first_lower = min((a for p, a, _ in events if p == "lower"),
+                      default=float("inf"))
+    cuts = [max((b for p, _, b in events
+                 if p == "trace" and b <= first_lower), default=t_call),
+            max((b for p, _, b in events if p == "lower"), default=t_call),
+            max((b for p, _, b in events if p == "backend"),
+                default=t_call)]
+    out, t = [], t_call
+    for name, cut in zip(("trace", "lower", "backend"), cuts):
+        cut = min(max(cut, t), t_end)
+        out.append((name, t, cut))
+        t = cut
+    out.append(("first_run", t, t_end))
+    return out
 
 
 def _compile_cache_entries(cache_dir: str) -> int:
@@ -726,7 +793,8 @@ class LowerCtx:
                 # rbg: much cheaper per-block random bits on TPU than
                 # threefry — dropout RNG was ~40% of a BERT step with the
                 # default impl
-                self._key = jax.random.key(seed, impl="rbg")
+                with jax.named_scope("pt.exec/seed"):
+                    self._key = jax.random.key(seed, impl="rbg")
         return self._key
 
     def rng(self):
@@ -847,19 +915,42 @@ def run_op(ctx: LowerCtx, block: Block, op: Operator, state: _ExecState) -> None
         raise err from e
 
 
+#: ``op_role`` attr -> the role part of an op's scope (none: forward)
+_SCOPE_ROLES = {"backward": "bwd", "optimize": "opt", "lrsched": "lr"}
+
+
+def op_scope(op: Operator) -> str:
+    """``pt.<role>/<op type>``: the ``jax.named_scope`` every op of a block
+    is lowered under, so that each device operation of the compiled step
+    carries, in its HLO metadata, the program op it came from (read back by
+    ``benchmark/op_scopes.py``).  The type is the one written in the
+    optimised program; a grad op lowered by the generic vjp keeps the grad
+    op's scope, so forward work lowered again inside the backward reads as
+    ``bwd``.  Ops of a sub-block nest under their parent (``while``,
+    ``cond``), whose scope comes first in the name.  Metadata only: a
+    context manager per op while tracing, nothing per step."""
+    return "pt.%s/%s" % (_SCOPE_ROLES.get(op.attrs.get("op_role"), "fwd"),
+                         op.type)
+
+
 def _run_op_inner(ctx, block, op, state) -> None:
+    name = op_scope(op)
     if op.type.endswith("_grad") and not registry.has_op(op.type):
-        _run_generic_grad(ctx, block, op, state)
+        with jax.named_scope(name):
+            _run_generic_grad(ctx, block, op, state)
         return
     info = registry.get_op_info(op.type)
     if info.raw:
-        info.lower(ctx, block, op, state)
+        with jax.named_scope(name):
+            info.lower(ctx, block, op, state)
         return
     ins = {slot: [state.read(block, n) for n in names]
            for slot, names in op.inputs.items()}
     if ctx.amp:
         from .. import amp as _amp
-        ins = _amp.cast_ins(op.type, ins)
+        # outside the op's scope: a cast that survives fusion is AMP's
+        with jax.named_scope("pt.amp/cast"):
+            ins = _amp.cast_ins(op.type, ins)
     if info.stateful_rng:
         # remember where the counter stream stood so a generic-vjp grad op
         # can REPLAY the same draws when it retraces this forward (else the
@@ -871,15 +962,18 @@ def _run_op_inner(ctx, block, op, state) -> None:
             for n in names:
                 if n:
                     state.rng_marks[n] = mark
-    outs = info.lower(ctx, ins, op.attrs) or {}
+    with jax.named_scope(name):
+        outs = info.lower(ctx, ins, op.attrs) or {}
     from ..flags import get_flags
     if get_flags("FLAGS_check_nan_inf")["FLAGS_check_nan_inf"]:
-        _sanitize_outputs(op, outs)
-    for slot, names in op.outputs.items():
-        vals = outs.get(slot, [])
-        for i, n in enumerate(names):
-            if i < len(vals):
-                state.write(n, vals[i])
+        with jax.named_scope("pt.exec/check_nan_inf"):
+            _sanitize_outputs(op, outs)
+    with jax.named_scope(name):  # a sharding constraint belongs to its op
+        for slot, names in op.outputs.items():
+            vals = outs.get(slot, [])
+            for i, n in enumerate(names):
+                if i < len(vals):
+                    state.write(n, vals[i])
 
 
 def _run_generic_grad(ctx, block: Block, op: Operator, state: _ExecState):
@@ -986,8 +1080,9 @@ class _CompiledBlock:
                 # lazy FetchHandle still points at it.  An explicit copy
                 # forces the fetch into its own (never-donated) buffer.
                 rw_ids = {id(v) for v in new_rw}
-                fetches = [jnp.copy(f) if id(f) in rw_ids else f
-                           for f in fetches]
+                with jax.named_scope("pt.exec/fetch_copy"):
+                    fetches = [jnp.copy(f) if id(f) in rw_ids else f
+                               for f in fetches]
             # dedicated throttle probe: a tiny COMPUTED output (a bare
             # pass-through would alias the seed input buffer and read as
             # ready instantly).  Its buffer becomes ready only when the
@@ -996,14 +1091,17 @@ class _CompiledBlock:
             # has a waitable array even on fetch-less train_from_dataset
             # loops whose rw state the next step donates.  seed is always
             # a uint32 scalar here (_finish_run mints it).
-            probe = seed + jnp.uint32(1)
+            with jax.named_scope("pt.exec/probe"):
+                probe = seed + jnp.uint32(1)
             if num_on:
                 # force=True keeps the output arity FIXED (out_shardings
                 # / shard_map out_specs are declared before tracing): a
                 # block with nothing to observe emits an all-zero header
-                layout, packed = _numerics().build_step_stats(
-                    state.values, state.written, feed_names, persist_rw,
-                    rw, new_rw, numerics_mode, spec=num_spec, force=True)
+                with jax.named_scope("pt.exec/numerics"):
+                    layout, packed = _numerics().build_step_stats(
+                        state.values, state.written, feed_names,
+                        persist_rw, rw, new_rw, numerics_mode,
+                        spec=num_spec, force=True)
                 self._num_layout_box[:] = [layout]
                 return fetches, new_rw, probe, packed
             return fetches, new_rw, probe
@@ -1040,23 +1138,28 @@ class _CompiledBlock:
             def sharded_step(feeds, ro, rw, seed):
                 # per-rank RNG stream (reference multi-process trainers have
                 # independent seeds) — fold in the rank
-                rank_seed = seed + lax.axis_index("dp").astype(
-                    jnp.uint32) * jnp.uint32(1000003)
+                with jax.named_scope("pt.exec/seed"):
+                    rank_seed = seed + lax.axis_index("dp").astype(
+                        jnp.uint32) * jnp.uint32(1000003)
                 out = step(feeds, ro, rw, rank_seed)
                 fetches, new_rw = out[0], out[1]
                 synced_rw = []
-                for v, is_p in zip(new_rw, rw_is_param):
-                    if is_p:
-                        synced_rw.append(v)
-                    elif jnp.issubdtype(v.dtype, jnp.floating):
-                        synced_rw.append(lax.pmean(v, "dp"))
-                    else:
-                        synced_rw.append(lax.pmax(v, "dp"))
+                # the executor's own collectives (the program's c_* ops
+                # carry their op's scope)
+                with jax.named_scope("pt.dp/allreduce"):
+                    for v, is_p in zip(new_rw, rw_is_param):
+                        if is_p:
+                            synced_rw.append(v)
+                        elif jnp.issubdtype(v.dtype, jnp.floating):
+                            synced_rw.append(lax.pmean(v, "dp"))
+                        else:
+                            synced_rw.append(lax.pmax(v, "dp"))
                 # probe from the PRE-fold seed: replicated by construction
                 # (its per-rank counterpart diverges and would need a
                 # collective to satisfy the replicated out_spec)
-                res = ([f[None] for f in fetches], synced_rw,
-                       seed + jnp.uint32(1))
+                with jax.named_scope("pt.exec/probe"):
+                    res = ([f[None] for f in fetches], synced_rw,
+                           seed + jnp.uint32(1))
                 if len(out) == 4:
                     # per-rank stats stack like fetches; the engine's
                     # frame decoder combines them (counts sum, absmax
@@ -1205,6 +1308,7 @@ class Executor:
         self._comm_gate_fails = 0
         self._comm_gate_off = False
         self._stats = _DispatchStats()
+        _install_phase_listener()
         # async dispatch throttle: representative output arrays of the last
         # N dispatched steps; run() blocks on the oldest once more than
         # FLAGS_executor_max_inflight_steps are in flight, so lazy-fetch
@@ -1574,7 +1678,12 @@ class Executor:
         # executable exists; only the execution is async).  Record the
         # wall time and whether the persistent disk cache absorbed it —
         # heuristically, by whether the cache dir gained an entry ('hit'
-        # also covers compiles under jax's persist threshold).
+        # also covers compiles under jax's persist threshold).  JAX's own
+        # compile events of this dispatch land in ``jax_events`` (a first
+        # call fires them, the cost cross-check's AOT compile below
+        # included; a steady-state step fires none): they cut the first
+        # call into phases and give a later re-trace away.
+        _phase_sink.events = jax_events = []
         pending_compile = getattr(cb, "pending_compile", False)
         if pending_compile:
             # read-and-clear under the lock: a second thread cache-hitting
@@ -1629,7 +1738,10 @@ class Executor:
                 # forensics path a real RESOURCE_EXHAUSTED from the
                 # compile/dispatch below does (tools/hbm_smoke.py)
                 _resil.maybe_inject("memory.oom")
-                out = cb(feeds, ro_vals, rw_vals, seed_arr)
+                try:
+                    out = cb(feeds, ro_vals, rw_vals, seed_arr)
+                finally:
+                    _phase_sink.events = None
                 if len(out) == 4:
                     fetches, new_rw, probe, num_stats = out
                 else:
@@ -1703,6 +1815,21 @@ class Executor:
                     "xla.compile", "compile", tc0, tdisp,
                     {"persist_cache": outcome,
                      "fetches": list(cb.fetch_names)})
+            # the same first call by phase: spans for a reader of the ring,
+            # the histogram for one who comes after the ring was cleared
+            self._note_compile_phases(
+                program, [("prepare", t0, tc0)]
+                + _compile_phase_bounds(jax_events, tc0, tdisp), outcome)
+        elif jax_events:
+            # JAX traced and compiled again inside a block this executor
+            # had compiled already (an argument's layout or sharding
+            # changed under the same shapes): a re-trace nobody asked for.
+            # Not a ``traces`` bump: that counts the executor's own
+            # lowerings; the histogram's count of 'retrace' counts these
+            self._note_compile_phases(
+                program,
+                [("retrace", min(a for _, a, _ in jax_events), tdisp)],
+                None)
         if cb.collective_nranks or getattr(cb, "partitioned", False):
             if cb.collective_nranks:
                 _COLL_STEP.inc()
@@ -1917,6 +2044,23 @@ class Executor:
         stats.incr("lazy_fetch_steps")
         return [FetchHandle(f, stats) for f in fetches]
 
+    @staticmethod
+    def _note_compile_phases(program, phases, outcome) -> None:
+        """Record ``(phase, t0, t1)`` intervals of a compiling dispatch as
+        ``compile.<phase>`` spans and in ``paddle_tpu_compile_phase_
+        seconds``; ``compile.backend`` carries the persistent cache's
+        ``outcome`` like ``xla.compile`` does."""
+        kind = "train" if any(
+            op.attrs.get("op_role") in ("backward", "optimize")
+            for op in program.global_block().ops) else "other"
+        for phase, a, b in phases:
+            _COMPILE_PHASE_HIST.observe(b - a, phase=phase, block=kind)
+            if _monitor.TRACER.enabled:
+                _monitor.TRACER.add_complete(
+                    "compile." + phase, "compile", a, b,
+                    {"persist_cache": outcome} if phase == "backend"
+                    else None)
+
     def _maybe_step_barrier(self, cb, program):
         """Automatic per-step gang barrier for collective shard_map
         dispatches, behind ``FLAGS_gang_step_barrier``: every step first
@@ -2107,7 +2251,6 @@ class Executor:
                     tb1 = time.perf_counter()
                     stats.incr("throttle_waits")
                     stats.block("throttle_block_us", (tb1 - tb) * 1e6)
-                    _THROTTLE_HIST.observe((tb1 - tb) * 1e6)
                     if _monitor.TRACER.enabled:
                         _monitor.TRACER.add_complete(
                             "executor.throttle_wait", "dispatch", tb, tb1)

@@ -23,6 +23,7 @@ program in tests/test_serving.py.
 from __future__ import annotations
 
 import threading
+import time
 from typing import Dict, List, Optional
 
 import jax
@@ -166,6 +167,14 @@ def _layer_norm(x, w, b, eps=1e-5):
     return (y * w.astype(y.dtype) + b.astype(y.dtype)).astype(x.dtype)
 
 
+def _part(name: str):
+    """``pt.decode/<part>``: the scope a part of the decode step is traced
+    under, so its device operations carry the part's name in their HLO
+    metadata (the layer index is not in the name: the device trace is read
+    as per-part sums by ``benchmark/op_scopes.py``)."""
+    return jax.named_scope("pt.decode/" + name)
+
+
 class GPTDecodeModel:
     """One-token-per-slot decode step over the paged cache, jitted once.
 
@@ -214,52 +223,64 @@ class GPTDecodeModel:
         T = MP * PL                      # max attended context per slot
         scale = float(Dh) ** -0.5
 
-        x = params["word_embedding"][ids] + params["pos_embedding"][pos]
-        x = _layer_norm(x, params["pre_encoder.ln.w"],
-                        params["pre_encoder.ln.b"])
+        with _part("embed"):
+            x = params["word_embedding"][ids] + params["pos_embedding"][pos]
+            x = _layer_norm(x, params["pre_encoder.ln.w"],
+                            params["pre_encoder.ln.b"])
 
-        # this token's write target: (page, offset) per slot; inactive
-        # slots are routed to scratch page 0 so the scatter stays dense
-        page_idx = pos // PL
-        offset = pos % PL
-        cur_page = jnp.take_along_axis(
-            page_table, page_idx[:, None], axis=1)[:, 0]
-        cur_page = jnp.where(active, cur_page, 0)
+        with _part("kv_write"):
+            # this token's write target: (page, offset) per slot; inactive
+            # slots are routed to scratch page 0 so the scatter stays dense
+            page_idx = pos // PL
+            offset = pos % PL
+            cur_page = jnp.take_along_axis(
+                page_table, page_idx[:, None], axis=1)[:, 0]
+            cur_page = jnp.where(active, cur_page, 0)
 
-        # context mask: position t of the gathered pages is attendable
-        # iff t <= pos (page-table order IS position order)
-        t_idx = jnp.arange(T)
-        attend = t_idx[None, :] <= pos[:, None]          # [S, T]
-        neg = jnp.asarray(-1e9, x.dtype)
+        with _part("attention"):
+            # context mask: position t of the gathered pages is attendable
+            # iff t <= pos (page-table order IS position order)
+            t_idx = jnp.arange(T)
+            attend = t_idx[None, :] <= pos[:, None]          # [S, T]
+            neg = jnp.asarray(-1e9, x.dtype)
 
         for i in range(cfg.n_layer):
             p = f"enc_{i}"
-            qkv = x @ params[f"{p}.attn.qkv.w"] + params[f"{p}.attn.qkv.b"]
-            q, k_new, v_new = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(S, H, Dh)
-            k_new = k_new.reshape(S, H, Dh)
-            v_new = v_new.reshape(S, H, Dh)
-            k = k.at[i, cur_page, offset].set(k_new)
-            v = v.at[i, cur_page, offset].set(v_new)
-            # gather this slot's prefix: [S, MP, PL, H, Dh] -> [S, T, H, Dh]
-            kp = k[i][page_table].reshape(S, T, H, Dh)
-            vp = v[i][page_table].reshape(S, T, H, Dh)
-            scores = jnp.einsum("shd,sthd->sht", q, kp) * scale
-            scores = jnp.where(attend[:, None, :], scores, neg)
-            w = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-            w = w.astype(x.dtype)
-            ctx = jnp.einsum("sht,sthd->shd", w, vp).reshape(S, D)
-            attn = ctx @ params[f"{p}.attn.out.w"] + \
-                params[f"{p}.attn.out.b"]
-            x = _layer_norm(x + attn, params[f"{p}.ln1.w"],
-                            params[f"{p}.ln1.b"])
-            h = x @ params[f"{p}.ffn.fc1.w"] + params[f"{p}.ffn.fc1.b"]
-            h = jax.nn.gelu(h, approximate=False)
-            ffn = h @ params[f"{p}.ffn.fc2.w"] + params[f"{p}.ffn.fc2.b"]
-            x = _layer_norm(x + ffn, params[f"{p}.ln2.w"],
-                            params[f"{p}.ln2.b"])
+            with _part("qkv"):
+                qkv = x @ params[f"{p}.attn.qkv.w"] + \
+                    params[f"{p}.attn.qkv.b"]
+                q, k_new, v_new = jnp.split(qkv, 3, axis=-1)
+                q = q.reshape(S, H, Dh)
+                k_new = k_new.reshape(S, H, Dh)
+                v_new = v_new.reshape(S, H, Dh)
+            with _part("kv_write"):
+                k = k.at[i, cur_page, offset].set(k_new)
+                v = v.at[i, cur_page, offset].set(v_new)
+            with _part("kv_gather"):
+                # this slot's prefix: [S, MP, PL, H, Dh] -> [S, T, H, Dh]
+                kp = k[i][page_table].reshape(S, T, H, Dh)
+                vp = v[i][page_table].reshape(S, T, H, Dh)
+            with _part("attention"):
+                scores = jnp.einsum("shd,sthd->sht", q, kp) * scale
+                scores = jnp.where(attend[:, None, :], scores, neg)
+                w = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+                w = w.astype(x.dtype)
+                ctx = jnp.einsum("sht,sthd->shd", w, vp).reshape(S, D)
+            with _part("attn_out"):
+                attn = ctx @ params[f"{p}.attn.out.w"] + \
+                    params[f"{p}.attn.out.b"]
+                x = _layer_norm(x + attn, params[f"{p}.ln1.w"],
+                                params[f"{p}.ln1.b"])
+            with _part("ffn"):
+                h = x @ params[f"{p}.ffn.fc1.w"] + params[f"{p}.ffn.fc1.b"]
+                h = jax.nn.gelu(h, approximate=False)
+                ffn = h @ params[f"{p}.ffn.fc2.w"] + \
+                    params[f"{p}.ffn.fc2.b"]
+                x = _layer_norm(x + ffn, params[f"{p}.ln2.w"],
+                                params[f"{p}.ln2.b"])
 
-        logits = x @ params["lm_out.w"] + params["lm_out.b"]
+        with _part("lm_head"):
+            logits = x @ params["lm_out.w"] + params["lm_out.b"]
         return logits, k, v
 
 
@@ -352,10 +373,22 @@ class DecodeEngine:
         self.cache.free_slot(slot)
         self.page_table[slot, :] = 0
 
+    #: ``perf_counter`` marks of the last :meth:`run_iteration`: entered,
+    #: the jitted step returned (dispatched), the logits ready on the
+    #: device, the logits on the host.  The scheduler turns them into the
+    #: ``serving.decode_step.*`` child spans of its iteration span.
+    phase_marks: Optional[tuple] = None
+
     def run_iteration(self, ids, pos, active):
         """One decode step over all slots; returns logits [S, vocab]
         (host numpy) after updating the donated pools."""
+        t0 = time.perf_counter()
         logits, self.cache.k, self.cache.v = self.model.step(
             self.params, self.cache.k, self.cache.v, ids, pos,
             self.page_table, active)
-        return np.asarray(logits)
+        t1 = time.perf_counter()
+        logits.block_until_ready()   # np.asarray would wait here anyway
+        t2 = time.perf_counter()
+        out = np.asarray(logits)
+        self.phase_marks = (t0, t1, t2, time.perf_counter())
+        return out

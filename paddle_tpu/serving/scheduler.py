@@ -49,10 +49,6 @@ OCCUPANCY_HIST = _monitor.REGISTRY.histogram(
     "process running both must not blend them in per-server views",
     labelnames=("mode",),
     buckets=(1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0, 24.0, 32.0, 64.0))
-BATCHES_CTR = _monitor.REGISTRY.counter(
-    "paddle_tpu_serving_batches_total",
-    "dispatched serving batches / decode iterations, by bucket "
-    "(bucket='decode' for the KV-cache loop)", ("bucket",))
 FAULTS_ABSORBED_CTR = _monitor.REGISTRY.counter(
     "paddle_tpu_serving_faults_absorbed_total",
     "transient dispatch faults absorbed by a batch re-dispatch "
@@ -324,7 +320,6 @@ class ContinuousBatcher:
             t_d0 = time.perf_counter()
             handles = self._dispatch(compiled, feed, fetch_names, batch)
             t_d1 = time.perf_counter()
-            BATCHES_CTR.inc(1, bucket=str(bucket))
             OCCUPANCY_HIST.observe(float(len(batch)), mode="batch")
             _monitor.SERVING_LAST_OCC_GAUGE.set(float(len(batch)))
             if handles is None:
@@ -450,7 +445,7 @@ class ContinuousBatcher:
 
 
 class _SlotState:
-    __slots__ = ("req", "tokens", "pos", "generated", "iters")
+    __slots__ = ("req", "tokens", "pos", "generated", "iters", "token_t")
 
     def __init__(self, req: Request):
         self.req = req
@@ -458,6 +453,7 @@ class _SlotState:
             req.prompt).ravel()]
         self.pos = 0
         self.generated: List[int] = []
+        self.token_t: List[float] = []   # perf_counter of each of them
         self.iters = 0          # decode iterations this request rode
 
 
@@ -592,10 +588,10 @@ class DecodeScheduler:
                 _monitor.TRACER.add_complete(
                     "serving.decode_iter", "serving", t_i0, t_i1,
                     {"iter": self._iter, "occupancy": len(stepped)})
+                self._emit_step_phases(t_i0, t_i1)
             if logits is None:
                 continue
             self._logits_sentinel(logits, stepped)
-            BATCHES_CTR.inc(1, bucket="decode")
             OCCUPANCY_HIST.observe(float(len(stepped)), mode="decode")
             _monitor.SERVING_LAST_OCC_GAUGE.set(float(len(stepped)))
             now = time.perf_counter()
@@ -609,6 +605,7 @@ class DecodeScheduler:
                 nxt = int(np.argmax(logits[s]))
                 st.tokens.append(nxt)
                 st.generated.append(nxt)
+                st.token_t.append(time.perf_counter())
                 n_gen += 1
                 done = (len(st.generated) >= st.req.max_new_tokens
                         or (st.req.eos_id is not None
@@ -617,6 +614,26 @@ class DecodeScheduler:
                 if done:
                     self._retire(s, st, now)
             self._update_token_rate(now, n_gen)
+            if _monitor.TRACER.enabled:
+                # argmax, retirements and their callbacks: the host work
+                # that follows the step before the next one can start
+                _monitor.TRACER.add_complete(
+                    "serving.decode_iter.sample", "serving", t_i1,
+                    time.perf_counter(), {"iter": self._iter})
+
+    def _emit_step_phases(self, t_i0: float, t_i1: float) -> None:
+        """The engine's marks of the step just run (``DecodeEngine.
+        phase_marks``) as child spans of the iteration span: ``dispatch``
+        (the jitted call until it returns), ``device_wait`` (until the
+        logits are ready), ``logits_to_host`` (the copy)."""
+        marks = getattr(self._engine, "phase_marks", None)
+        if not marks or not t_i0 <= marks[0] <= marks[3] <= t_i1:
+            return                  # no marks, or an earlier iteration's
+        args = {"iter": self._iter}
+        for name, a, b in zip(("dispatch", "device_wait", "logits_to_host"),
+                              marks, marks[1:]):
+            _monitor.TRACER.add_complete(
+                "serving.decode_step." + name, "serving", a, b, args)
 
     def _run_step(self, ids, pos, active, stepped):
         from .. import resilience as _resil
@@ -746,6 +763,19 @@ class DecodeScheduler:
         _monitor.SERVING_TPS_GAUGE.set(
             round(sum(n for _, n in win) / span, 3))
 
+    @staticmethod
+    def _decode_span_args(st) -> dict:
+        """What the request's ``serving.decode`` span carries, written once
+        at completion: every generated token's time as milliseconds from
+        submission (``token_ms``; the first is ``ttft_ms``)."""
+        t_sub = st.req.t_submit
+        token_ms = [round((t - t_sub) * 1e3, 3) for t in st.token_t]
+        args = {"iters": st.iters, "generated": len(st.generated),
+                "token_ms": token_ms}
+        if token_ms:
+            args["ttft_ms"] = token_ms[0]
+        return args
+
     def _retire(self, s, st, now) -> None:
         self._kv_account(st.req.tenant,
                          -len(self._engine.cache.pages_of(s)))
@@ -761,8 +791,8 @@ class DecodeScheduler:
             ("decode", tm.get("slot"), now),
             ("materialize", now, done_t),
         ), e2e_ms, bucket="decode",
-            extra={"decode": {"iters": st.iters,
-                              "generated": len(st.generated)}})
+            extra={"decode": self._decode_span_args(st)}
+            if _monitor.TRACER.enabled else None)
         _monitor.SERVING_FREE_SLOTS_GAUGE.set(float(sum(
             1 for x in self._slots if x is None)))
         self._on_complete(st.req, out, e2e_ms)
