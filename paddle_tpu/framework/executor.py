@@ -782,6 +782,9 @@ class LowerCtx:
         # set when the block runs under collective shard_map mode: the mesh
         # axis (or ring_id->axis map) the c_* collective ops reduce over
         self.collective_axis = collective_axis
+        # type of the program op being lowered (run_op sets it): the label
+        # under which per_dp_shard counts who asked
+        self.op_type = None
 
     def _base_key(self):
         if self._key is None:
@@ -809,6 +812,68 @@ class LowerCtx:
         keeps the tag stream disjoint from the counter stream above."""
         return jax.random.fold_in(
             jax.random.fold_in(self._base_key(), 0x5EED), tag)
+
+
+DP_LOCAL_CTR = _monitor.REGISTRY.counter(
+    "paddle_tpu_dp_local_lowerings_total",
+    "op lowerings that asked per_dp_shard to run their internals per "
+    "data-parallel batch shard, by program op type and by whether the "
+    "per-shard path engaged (1) or the lowering fell back to the whole "
+    "operands (0: the mesh has another axis than dp, the block is already "
+    "one collective shard_map, or the batch does not divide) — counted "
+    "while tracing, once per compile, nothing per step; a lowering traced "
+    "with no mesh does not ask", ("op", "engaged"))
+
+#: what a per-shard function is told about its extent: the shard's index
+#: along ``dp`` (None on the whole operands) and the number of shards
+DpShard = collections.namedtuple("DpShard", "index count")
+_WHOLE = DpShard(None, 1)
+
+
+def per_dp_shard(ctx, fn, sharded=(), replicated=(), batch=None):
+    """Run ``fn(shard, *sharded, *replicated)`` once per data-parallel
+    batch shard — for the internals of a lowering that XLA's partitioner
+    can only replicate (a scan over the batch axis, ``rng-bit-generator``):
+    left to it, every chip computes them for the global batch.
+
+    ``sharded`` operands and every output (an array or a tuple of arrays)
+    carry the batch in their leading dimension and cross the boundary
+    ``P("dp")``; ``replicated`` operands enter whole, and the transpose of
+    one is a single ``psum`` of its cotangent.  ``batch`` is the leading
+    dimension where no sharded operand gives it (a mask made from a shape).
+
+    The per-shard path is a ``jax.shard_map`` over ``ctx.mesh`` and engages
+    only where the trace shows all of: a mesh whose only axis of size > 1
+    is ``dp`` (with ``mp``/``sp`` the replicated operands may themselves be
+    sharded), no ``ctx.collective_axis`` (that block is one shard_map
+    already), and a batch that divides by the ``dp`` size.  Otherwise
+    ``fn`` runs once on the whole operands with ``DpShard(None, 1)``: one
+    implementation of the arithmetic, whose extent follows the mesh."""
+    mesh = ctx.mesh
+    if mesh is None:
+        return fn(_WHOLE, *sharded, *replicated)
+    from jax.sharding import PartitionSpec as P
+    sizes = mesh.shape
+    n = sizes.get("dp", 1)
+    leads = {int(a.shape[0]) if a.ndim else None for a in sharded}
+    if batch is not None:
+        leads.add(int(batch))
+    lead = leads.pop() if len(leads) == 1 else None
+    engaged = (n > 1 and all(s == 1 for a, s in sizes.items() if a != "dp")
+               and ctx.collective_axis is None
+               and lead is not None and lead % n == 0)
+    DP_LOCAL_CTR.inc(op=ctx.op_type or "?",
+                     engaged=str(int(engaged)))
+    if not engaged:
+        return fn(_WHOLE, *sharded, *replicated)
+
+    def local(*ops):
+        return fn(DpShard(jax.lax.axis_index("dp"), n), *ops)
+
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(P("dp"),) * len(sharded) + (P(),) * len(replicated),
+        out_specs=P("dp"), check_vma=False)(*sharded, *replicated)
 
 
 def _seed_to_key(seed):
@@ -935,6 +1000,7 @@ def op_scope(op: Operator) -> str:
 
 def _run_op_inner(ctx, block, op, state) -> None:
     name = op_scope(op)
+    ctx.op_type = op.type
     if op.type.endswith("_grad") and not registry.has_op(op.type):
         with jax.named_scope(name):
             _run_generic_grad(ctx, block, op, state)

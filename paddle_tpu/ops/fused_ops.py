@@ -30,6 +30,7 @@ import numpy as np
 from ..device import on_tpu
 from ..framework.registry import register_op
 from .common import X, XS, broadcast_to_x
+from .nn_ops import _dropout_keep
 
 
 def _amp_pair(ctx, *arrs):
@@ -90,8 +91,8 @@ def _fused_dense_act(ctx, ins, attrs):
             out = jax.nn.relu(out)
     out = out.reshape(out_shape)
 
-    # stage 3 — tagged dropout (exact _dropout_lower replica; the tag
-    # makes fwd/bwd/unfused draws identical)
+    # stage 3 — tagged dropout (_dropout_lower's arithmetic on
+    # _dropout_keep's mask; the tag makes fwd/bwd/unfused draws identical)
     tag = int(attrs.get("seed", 0))
     if tag:
         p = attrs.get("dropout_prob", 0.5)
@@ -99,11 +100,7 @@ def _fused_dense_act(ctx, ins, attrs):
         if attrs.get("is_test", False):
             out = out * (1.0 - p) if impl == "downgrade_in_infer" else out
         else:
-            key = ctx.rng_tagged(tag)
-            bits = jax.random.bits(key, out.shape, jnp.uint8)
-            threshold = max(1, int(round(float(p) * 256.0))) if p > 0 \
-                else 0
-            keep = bits.astype(jnp.int32) >= threshold
+            keep = _dropout_keep(ctx, attrs, out.shape)
             if impl == "upscale_in_train":
                 scale = 1.0 / (1.0 - p) if p < 1.0 else 0.0
                 out = jnp.where(keep, out * scale, 0.0)

@@ -23,6 +23,7 @@ import numpy as np
 
 from ..framework.core import grad_var_name
 from ..framework.registry import register_op
+from ..framework.executor import per_dp_shard
 from .common import X, XS, broadcast_to_x, static_int
 
 # ---------------------------------------------------------------------------
@@ -654,15 +655,29 @@ def _dropout_keep(ctx, attrs, shape):
     element; resolution 1/256 rounds the keep rate by <0.2% absolute.
     Compare in int32: the threshold for p→1.0 is 256, which would wrap to
     0 as uint8 and keep everything.
+
+    Under data parallel the bits are drawn per batch shard (XLA's
+    partitioner does not split ``rng-bit-generator``: every chip would draw
+    the global shape and keep its slice), each shard from the key folded
+    with its own index, or all shards would drop the same positions.
     """
     p = attrs.get("dropout_prob", 0.5)
     tag = attrs.get("seed", 0)
     key = ctx.rng_tagged(tag) if tag else ctx.rng()
-    bits = jax.random.bits(key, shape, jnp.uint8)
     # floor of 1 so tiny-but-nonzero probs still drop ~1/256 instead of
     # silently becoming a no-op
     threshold = max(1, int(round(float(p) * 256.0))) if p > 0 else 0
-    return bits.astype(jnp.int32) >= threshold
+
+    def keep(shard, key):
+        local = shape
+        if shard.index is not None:
+            key = jax.random.fold_in(key, shard.index)
+            local = (shape[0] // shard.count,) + tuple(shape[1:])
+        bits = jax.random.bits(key, local, jnp.uint8)
+        return bits.astype(jnp.int32) >= threshold
+
+    return per_dp_shard(ctx, keep, replicated=(key,),
+                        batch=shape[0] if shape else None)
 
 
 def _dropout_lower(ctx, ins, attrs):
@@ -937,33 +952,46 @@ def _fused_lm_head_ce(ctx, ins, attrs):
     label = X(ins, "Label")
     ignore = attrs.get("ignore_index", -100)
     chunk = int(attrs.get("chunk_size", 1024))
-    lead = x.shape[:-1]
-    d = x.shape[-1]
-    n = int(np.prod(lead))
-    x2 = x.reshape(n, d)
-    l1 = label.reshape(n)
-    pad = (-n) % chunk
-    if pad:
-        x2 = jnp.concatenate([x2, jnp.zeros((pad, d), x2.dtype)])
-        l1 = jnp.concatenate(
-            [l1, jnp.full((pad,), ignore, l1.dtype)])
-    n_chunks = (n + pad) // chunk
-    xc = x2.reshape(n_chunks, chunk, d)
-    lc = l1.reshape(n_chunks, chunk)
 
-    def body(carry, inp):
-        xi, li = inp
-        logits = (xi.astype(jnp.bfloat16) @ w.astype(jnp.bfloat16)
-                  ).astype(jnp.float32)
-        if b is not None:
-            logits = logits + b.astype(jnp.float32)
-        m = jax.lax.stop_gradient(jnp.max(logits, axis=-1, keepdims=True))
-        lse = jnp.log(jnp.sum(jnp.exp(logits - m), axis=-1)) + m[:, 0]
-        safe = jnp.where(li == ignore, 0, li)
-        picked = jnp.take_along_axis(logits, safe[:, None], axis=-1)[:, 0]
-        loss = jnp.where(li == ignore, 0.0, lse - picked)
-        return carry, loss
+    def head(shard, x, label, w, *bias):
+        b = bias[0] if bias else None
+        lead = x.shape[:-1]
+        d = x.shape[-1]
+        n = int(np.prod(lead))
+        x2 = x.reshape(n, d)
+        l1 = label.reshape(n)
+        pad = (-n) % chunk
+        if pad:
+            x2 = jnp.concatenate([x2, jnp.zeros((pad, d), x2.dtype)])
+            l1 = jnp.concatenate(
+                [l1, jnp.full((pad,), ignore, l1.dtype)])
+        n_chunks = (n + pad) // chunk
+        xc = x2.reshape(n_chunks, chunk, d)
+        lc = l1.reshape(n_chunks, chunk)
 
-    _, losses = jax.lax.scan(jax.checkpoint(body), 0.0, (xc, lc))
-    out = losses.reshape(-1)[:n].reshape(lead + (1,))
+        def body(carry, inp):
+            xi, li = inp
+            logits = (xi.astype(jnp.bfloat16) @ w.astype(jnp.bfloat16)
+                      ).astype(jnp.float32)
+            if b is not None:
+                logits = logits + b.astype(jnp.float32)
+            m = jax.lax.stop_gradient(
+                jnp.max(logits, axis=-1, keepdims=True))
+            lse = jnp.log(jnp.sum(jnp.exp(logits - m), axis=-1)) + m[:, 0]
+            safe = jnp.where(li == ignore, 0, li)
+            picked = jnp.take_along_axis(
+                logits, safe[:, None], axis=-1)[:, 0]
+            loss = jnp.where(li == ignore, 0.0, lse - picked)
+            return carry, loss
+
+        _, losses = jax.lax.scan(jax.checkpoint(body), 0.0, (xc, lc))
+        return losses.reshape(-1)[:n].reshape(lead + (1,))
+
+    # per batch shard under data parallel: the scan runs over the token
+    # chunks, which is the sharded batch axis, and a `while` cannot be
+    # partitioned along its own iteration axis — left to the partitioner,
+    # x is all-gathered and every chip runs the global batch's chunks.
+    # The transpose of the replicated W is one psum of dW after the scan.
+    out = per_dp_shard(ctx, head, sharded=(x, label),
+                       replicated=(w,) if b is None else (w, b))
     return {"Loss": [out]}
