@@ -45,6 +45,8 @@ BF16_IF_BIG = {
     "elementwise_add", "elementwise_sub", "elementwise_mul", "dropout",
     "gelu", "relu", "tanh", "sigmoid", "swish", "leaky_relu", "relu6",
     "softmax", "layer_norm", "batch_norm", "group_norm", "scale", "concat",
+    # float32 inside (statistics, angles), the stream in bf16
+    "rms_norm", "rope",
 }
 
 _COMPUTE = jnp.bfloat16
@@ -53,7 +55,7 @@ _FLOATS = (jnp.float32, jnp.bfloat16, jnp.float16)
 # norm ops carry f32 STATE inputs (running mean/var, scale/bias) that must
 # not be rounded to bf16 every step — only the activation slot is cast
 _SLOT_RESTRICT = {"batch_norm": {"X"}, "layer_norm": {"X"},
-                  "group_norm": {"X"}}
+                  "group_norm": {"X"}, "rms_norm": {"X"}}
 
 # NOTE: the analysis.fusion targets (fused_dense_act,
 # fused_embedding_layer_norm) appear in NO list above on purpose: one
@@ -61,6 +63,9 @@ _SLOT_RESTRICT = {"batch_norm": {"X"}, "layer_norm": {"X"},
 # chain it replaced (e.g. a 2-D bias add stays f32 unfused), so their
 # lowerings in ops/fused_ops.py replicate this module's per-stage policy
 # internally — keep the three policies in sync when editing the lists.
+# moe_ffn (ops/moe_ops.py) is in no list either: its router is float32 at
+# full precision and its rows and expert weights bf16, decided inside the
+# lowering from ``ctx.amp``.
 
 
 def _cast_all(ins, target, slots=None):

@@ -1010,7 +1010,10 @@ def _run_op_inner(ctx, block, op, state) -> None:
         with jax.named_scope(name):
             info.lower(ctx, block, op, state)
         return
-    ins = {slot: [state.read(block, n) for n in names]
+    # a hand-written grad op names its output-grad slots as the generic one
+    # does, and like it may find one absent (output unused downstream)
+    ins = {slot: [state.values.get(n) if slot.startswith("OG$") else
+                  state.read(block, n) for n in names]
            for slot, names in op.inputs.items()}
     if ctx.amp:
         from .. import amp as _amp

@@ -262,6 +262,37 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1, epsilon=1e-5,
     return helper.append_activation(out)
 
 
+def rms_norm(input, begin_norm_axis=1, epsilon=1e-5, param_attr=None,
+             name=None):
+    """``w * x / sqrt(mean(x^2) + epsilon)`` over the axes from
+    ``begin_norm_axis`` (no mean subtraction, no bias; float32 inside).
+    ``param_attr=False`` leaves the scale out."""
+    helper = LayerHelper("rms_norm", name=name)
+    inputs = {"X": [input]}
+    if param_attr is not False:
+        norm_dim = int(np.prod(input.shape[begin_norm_axis:]))
+        inputs["Scale"] = [helper.create_parameter(
+            param_attr, shape=[norm_dim], dtype=input.dtype,
+            default_initializer=ConstantInitializer(1.0))]
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op("rms_norm", inputs=inputs, outputs={"Y": [out]},
+                     attrs={"epsilon": epsilon,
+                            "begin_norm_axis": begin_norm_axis})
+    return out
+
+
+def rope(x, head_dim, theta=10000.0, name=None):
+    """Rotary position embedding over [batch, T, n * head_dim], before the
+    head split: position = index along axis 1, rotate-half pairing
+    (dimension ``i`` of a head with ``i + head_dim / 2``)."""
+    helper = LayerHelper("rope", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op("rope", inputs={"X": [x]}, outputs={"Out": [out]},
+                     attrs={"head_dim": int(head_dim),
+                            "theta": float(theta)})
+    return out
+
+
 def group_norm(input, groups, epsilon=1e-5, param_attr=None, bias_attr=None,
                act=None, data_layout="NCHW", name=None):
     helper = LayerHelper("group_norm", act=act, name=name)
@@ -1564,3 +1595,49 @@ def switch_moe_ffn(x, num_experts, d_inner, capacity_factor=1.25,
         outputs={"Out": [out], "AuxLoss": [aux]},
         attrs={"capacity_factor": float(capacity_factor), "act": act})
     return out, aux
+
+
+def moe_ffn(x, num_experts, top_k, d_expert, norm_topk_prob=False,
+            param_prefix="moe", initializer=None, name=None):
+    """Dropless top-k mixture of gated-SiLU experts over [b, t, d] input
+    (``moe_ffn`` op: sorted rows + grouped matmuls, no capacity, no dropped
+    token).  Returns ``(out, lb_loss, z_loss, expert_load)``: the
+    load-balancing loss ``E * sum_e f_e P_e``, the router z-loss (mean
+    squared logsumexp of the router logits) — add small multiples of both
+    to the training loss — and the [E] int32 rows each expert received.
+    The op's fifth output, ``TopExperts`` [b, t, k] (each token's experts),
+    is a variable of the block for whoever wants to fetch it
+    (``op.outputs["TopExperts"]``).  No bias anywhere.  Expert weights carry dist_spec ("ep", ...) like
+    ``switch_moe_ffn``'s."""
+    from ..param_attr import ParamAttr
+    helper = LayerHelper("moe_ffn", name=name)
+    d = int(x.shape[-1])
+    E, F = int(num_experts), int(d_expert)
+
+    def _p(suffix, shape, ep_spec):
+        v = helper.create_parameter(
+            ParamAttr(name=f"{param_prefix}.{suffix}",
+                      initializer=initializer), shape, x.dtype)
+        v.dist_spec = ep_spec
+        return v
+
+    ep = ("ep", None, None)
+    inputs = {"X": [x], "RouterW": [_p("router.w", [d, E], None)],
+              "GateW": [_p("gate.w", [E, d, F], ep)],
+              "UpW": [_p("up.w", [E, d, F], ep)],
+              "DownW": [_p("down.w", [E, F, d], ep)]}
+    out = helper.create_variable_for_type_inference(x.dtype)
+    lb = helper.create_variable_for_type_inference("float32")
+    z = helper.create_variable_for_type_inference("float32")
+    load = helper.create_variable_for_type_inference("int32", True)
+    top = helper.create_variable_for_type_inference("int32", True)
+    # what moe_ffn_grad reuses: sort order, sorted rows, gate and up
+    # projections, the experts' output
+    saved = [helper.create_variable_for_type_inference(t, True)
+             for t in ("int32", x.dtype, x.dtype, x.dtype, x.dtype)]
+    helper.append_op(
+        "moe_ffn", inputs=inputs,
+        outputs={"Out": [out], "LbLoss": [lb], "ZLoss": [z],
+                 "ExpertLoad": [load], "TopExperts": [top], "Saved": saved},
+        attrs={"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob)})
+    return out, lb, z, load

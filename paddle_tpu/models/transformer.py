@@ -23,7 +23,8 @@ from ..param_attr import ParamAttr
 def multi_head_attention(queries, keys, values, d_model, n_head,
                          dropout_rate=0.0, attn_bias=None, is_test=False,
                          param_prefix="attn", attn_impl="base",
-                         causal=False):
+                         causal=False, bias=True, n_kv_head=None,
+                         qk_hook=None):
     """ref dist_transformer.py:958 multi_head_attention.
 
     attn_impl: "base" (matmul→softmax→matmul chain, ref recipe),
@@ -32,8 +33,16 @@ def multi_head_attention(queries, keys, values, d_model, n_head,
     it's the measured winner (T ≥ 1024 on v5e, and exact semantics are
     preserved, i.e. no attention-weight dropout wanted), else base.
     Fused paths skip attention-weight dropout (standard for flash).
+
+    ``bias=False``: no bias on any projection.  ``n_kv_head`` (default
+    ``n_head``): K and V are projected to that many heads and each is
+    shared by ``n_head // n_kv_head`` query heads.  ``qk_hook(q, k) ->
+    (q, k)`` runs on the projected [b, t, heads * d_head] tensors before
+    the head split (QK-norm, rotary embedding).
     """
     d_head = d_model // n_head
+    n_kv_head = n_kv_head or n_head
+    d_kv = n_kv_head * d_head
     if attn_impl == "auto":
         seq = queries.shape[1] if queries.shape is not None else 0
         exact = (dropout_rate == 0.0) or is_test
@@ -42,24 +51,35 @@ def multi_head_attention(queries, keys, values, d_model, n_head,
     def _proj(x, size, name):
         return layers.fc(x, size=size, num_flatten_dims=2,
                          param_attr=ParamAttr(name=f"{param_prefix}.{name}.w"),
-                         bias_attr=ParamAttr(name=f"{param_prefix}.{name}.b"))
+                         bias_attr=ParamAttr(name=f"{param_prefix}.{name}.b")
+                         if bias else False)
 
     if queries is keys and keys is values:
         # self-attention: one fused QKV projection — bigger MXU tile, one
         # HBM read of the activations instead of three
-        qkv = _proj(queries, 3 * d_model, "qkv")
-        q, k, v = layers.split(qkv, 3, dim=2)
+        qkv = _proj(queries, d_model + 2 * d_kv, "qkv")
+        q, k, v = layers.split(
+            qkv, 3 if d_kv == d_model else [d_model, d_kv, d_kv], dim=2)
     else:
         q = _proj(queries, d_model, "q")
-        k = _proj(keys, d_model, "k")
-        v = _proj(values, d_model, "v")
+        k = _proj(keys, d_kv, "k")
+        v = _proj(values, d_kv, "v")
+    if qk_hook is not None:
+        q, k = qk_hook(q, k)
 
-    def _split_heads(x):
+    def _split_heads(x, heads=n_head):
         # [b, t, d] -> [b, h, t, dh]
-        y = layers.reshape(x, shape=[0, 0, n_head, d_head])
-        return layers.transpose(y, perm=[0, 2, 1, 3])
+        y = layers.reshape(x, shape=[0, 0, heads, d_head])
+        y = layers.transpose(y, perm=[0, 2, 1, 3])
+        if heads != n_head:
+            # each K/V head serves n_head // heads query heads
+            rep = n_head // heads
+            y = layers.expand(layers.unsqueeze(y, [2]), [1, 1, rep, 1, 1])
+            y = layers.reshape(y, shape=[0, n_head, -1, d_head])
+        return y
 
-    q, k, v = _split_heads(q), _split_heads(k), _split_heads(v)
+    q = _split_heads(q)
+    k, v = _split_heads(k, n_kv_head), _split_heads(v, n_kv_head)
     if attn_impl == "flash":
         ctx = layers.flash_attention(q, k, v, bias=attn_bias, causal=causal,
                                      sm_scale=float(d_head) ** -0.5)
@@ -93,7 +113,8 @@ def multi_head_attention(queries, keys, values, d_model, n_head,
     ctx = layers.reshape(ctx, shape=[0, 0, d_model])
     return layers.fc(ctx, size=d_model, num_flatten_dims=2,
                      param_attr=ParamAttr(name=f"{param_prefix}.out.w"),
-                     bias_attr=ParamAttr(name=f"{param_prefix}.out.b"))
+                     bias_attr=ParamAttr(name=f"{param_prefix}.out.b")
+                     if bias else False)
 
 
 def positionwise_ffn(x, d_inner, d_model, dropout_rate=0.0, is_test=False,
@@ -197,19 +218,20 @@ class BertConfig:
         return V * D + P * D + 2 * D + L * per_layer
 
 
-def _lm_head_loss(enc, cfg, lm_label, fused_head, param_name):
+def _lm_head_loss(enc, cfg, lm_label, fused_head, param_name, bias=True):
     """Shared LM head + masked-mean CE (label 0 = [PAD] excluded) used by
-    both the MLM and causal-LM builders."""
+    the MLM and the causal-LM builders; ``bias=False`` for a bias-free
+    head."""
+    w_attr = ParamAttr(name=f"{param_name}.w")
+    b_attr = ParamAttr(name=f"{param_name}.b") if bias else False
     if fused_head:
         loss = layers.fused_lm_head_ce(
-            enc, cfg.vocab_size, lm_label,
-            param_attr=ParamAttr(name=f"{param_name}.w"),
-            bias_attr=ParamAttr(name=f"{param_name}.b"), ignore_index=0)
+            enc, cfg.vocab_size, lm_label, param_attr=w_attr,
+            bias_attr=b_attr, ignore_index=0)
         logits = None
     else:
         logits = layers.fc(enc, size=cfg.vocab_size, num_flatten_dims=2,
-                           param_attr=ParamAttr(name=f"{param_name}.w"),
-                           bias_attr=ParamAttr(name=f"{param_name}.b"))
+                           param_attr=w_attr, bias_attr=b_attr)
         loss = layers.softmax_with_cross_entropy(
             logits, layers.unsqueeze(lm_label, [2]), ignore_index=0)
     mask = layers.cast(lm_label > 0, "float32")
@@ -307,6 +329,95 @@ def build_gpt_serving(cfg: BertConfig, seq_len, attn_impl="auto"):
                        param_attr=ParamAttr(name="lm_out.w"),
                        bias_attr=ParamAttr(name="lm_out.b"))
     return (src_ids,), logits
+
+
+# -- OLMoE: pre-norm decoder with QK-norm, rotary and a sparse-expert FFN ----
+
+class OlmoeConfig:
+    """OLMoE-1B-7B defaults (``allenai/OLMoE-1B-7B-0125-Instruct``
+    config.json); the loss coefficients are the training recipe's
+    (arXiv:2409.02060)."""
+
+    def __init__(self, vocab_size=50304, d_model=2048, n_layer=16, n_head=16,
+                 n_kv_head=None, d_expert=1024, n_experts=64, top_k=8,
+                 norm_topk_prob=False, rms_eps=1e-5, rope_theta=10000.0,
+                 lb_coef=0.01, z_coef=0.001, init_std=0.02):
+        self.vocab_size = vocab_size
+        self.d_model = d_model
+        self.n_layer = n_layer
+        self.n_head = n_head
+        self.n_kv_head = n_kv_head or n_head
+        self.d_expert = d_expert
+        self.n_experts = n_experts
+        self.top_k = top_k
+        self.norm_topk_prob = norm_topk_prob
+        self.rms_eps = rms_eps
+        self.rope_theta = rope_theta
+        self.lb_coef = lb_coef
+        self.z_coef = z_coef
+        self.init_std = init_std
+
+
+def olmoe_decoder_layer(x, cfg: OlmoeConfig, idx=0, attn_impl="flash",
+                        is_test=False):
+    """Pre-norm block: ``h = x + Attn(RMSNorm(x))``, ``out = h +
+    MoE(RMSNorm(h))``; no bias anywhere.  Q and K are RMS-normed over the
+    whole projection before the head split, then rotated.  Returns
+    ``(out, lb_loss, z_loss, expert_load)``."""
+    from ..initializer import NormalInitializer
+    p = f"dec_{idx}"
+    d_head = cfg.d_model // cfg.n_head
+
+    def norm(v, name):
+        return layers.rms_norm(v, begin_norm_axis=2, epsilon=cfg.rms_eps,
+                               param_attr=ParamAttr(name=name))
+
+    def qk_hook(q, k):
+        return tuple(
+            layers.rope(norm(t, f"{p}.attn.{n}_norm.w"), d_head,
+                        cfg.rope_theta) for t, n in ((q, "q"), (k, "k")))
+
+    n = norm(x, f"{p}.ln1.w")
+    h = x + multi_head_attention(
+        n, n, n, cfg.d_model, cfg.n_head, is_test=is_test,
+        param_prefix=f"{p}.attn", attn_impl=attn_impl, causal=True,
+        bias=False, n_kv_head=cfg.n_kv_head, qk_hook=qk_hook)
+    moe, lb, z, load = layers.moe_ffn(
+        norm(h, f"{p}.ln2.w"), cfg.n_experts, cfg.top_k, cfg.d_expert,
+        norm_topk_prob=cfg.norm_topk_prob, param_prefix=f"{p}.moe",
+        initializer=NormalInitializer(0.0, cfg.init_std))
+    return h + moe, lb, z, load
+
+
+def build_olmoe_pretrain(cfg: OlmoeConfig, seq_len, is_test=False,
+                         attn_impl="flash", fused_head=True):
+    """Causal LM over OLMoE blocks: ids -> embedding (no position table) ->
+    ``n_layer`` pre-norm blocks -> final RMSNorm -> untied bias-free head.
+    Loss = mean next-token CE (``lm_label`` as the pipeline shifted it;
+    label 0 excluded, as in the other builders) + ``lb_coef`` * mean over
+    layers of the load-balancing loss + ``z_coef`` * mean over layers of the
+    router z-loss.  The model has no dropout, so ``is_test`` only reaches
+    the attention's choice of path.  Returns ``(feeds, parts, loss)`` with
+    ``parts`` = {"ce", "lb", "z", "expert_load": [per layer], "hidden": the
+    final norm's output}."""
+    src_ids = layers.data("src_ids", shape=[seq_len], dtype="int64")
+    lm_label = layers.data("lm_label", shape=[seq_len], dtype="int64")
+    x = layers.embedding(src_ids, size=[cfg.vocab_size, cfg.d_model],
+                         param_attr=ParamAttr(name="word_embedding"))
+    lbs, zs, loads = [], [], []
+    for i in range(cfg.n_layer):
+        x, lb, z, load = olmoe_decoder_layer(x, cfg, i, attn_impl, is_test)
+        lbs.append(lb)
+        zs.append(z)
+        loads.append(load)
+    x = layers.rms_norm(x, begin_norm_axis=2, epsilon=cfg.rms_eps,
+                        param_attr=ParamAttr(name="final_norm.w"))
+    _, ce = _lm_head_loss(x, cfg, lm_label, fused_head, "lm_out", bias=False)
+    lb = layers.sum(lbs) / float(cfg.n_layer)
+    z = layers.sum(zs) / float(cfg.n_layer)
+    loss = ce + cfg.lb_coef * lb + cfg.z_coef * z
+    return (src_ids, lm_label), {"ce": ce, "lb": lb, "z": z,
+                                 "expert_load": loads, "hidden": x}, loss
 
 
 def annotate_tensor_parallel(program=None):

@@ -59,3 +59,28 @@ def _ring_attention(ctx, ins, attrs):
     from ..pallas import flash_attention
     return {"Out": [flash_attention(q, k, v, causal=causal,
                                     sm_scale=sm_scale)]}
+
+
+@register_op("rope")
+def _rope(ctx, ins, attrs):
+    """Rotary position embedding (Su et al. 2021) in the rotate-half
+    convention over the whole head: X is [batch, T, n * head_dim], the
+    position is the index along axis 1, and within each head dimension ``i``
+    pairs with ``i + head_dim / 2`` at the angle ``pos * theta^(-2i /
+    head_dim)``.  Angles, sines and the rotation are float32; the output
+    has the input's dtype.  Applied before the head split so that it fuses
+    with the projection's epilogue and the QK-norm."""
+    x = X(ins, "X")
+    dh = int(attrs["head_dim"])
+    theta = float(attrs.get("theta", 10000.0))
+    b, t, d = x.shape
+    half = dh // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / dh)
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.cos(ang)[None, :, None, :]
+    sin = jnp.sin(ang)[None, :, None, :]
+    xf = x.astype(jnp.float32).reshape(b, t, d // dh, dh)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                          axis=-1)
+    return {"Out": [out.reshape(b, t, d).astype(x.dtype)]}

@@ -338,6 +338,25 @@ def _layer_norm(ctx, ins, attrs):
             "Mean": [m.reshape(lead)], "Variance": [v.reshape(lead)]}
 
 
+@register_op("rms_norm")
+def _rms_norm(ctx, ins, attrs):
+    """``Scale * x / sqrt(mean(x^2) + eps)`` over the trailing axes from
+    ``begin_norm_axis`` (Zhang & Sennrich 2019; the norm of the OLMo/OLMoE
+    block).  The whole expression is float32 whatever the input's dtype —
+    it is one elementwise pass after the reduction either way, so the
+    stream stays in the input's dtype at no extra traffic."""
+    x, scale = X(ins, "X"), X(ins, "Scale")
+    eps = attrs.get("epsilon", 1e-5)
+    begin = attrs.get("begin_norm_axis", 1)
+    axes = tuple(range(begin, x.ndim))
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=axes,
+                                    keepdims=True) + eps)
+    if scale is not None:
+        y = y * scale.astype(jnp.float32).reshape(x.shape[begin:])
+    return {"Y": [y.astype(x.dtype)]}
+
+
 @register_op("group_norm")
 def _group_norm(ctx, ins, attrs):
     x = X(ins, "X")  # NCHW

@@ -776,6 +776,14 @@ _FWD_DEFAULTS = {2048: (1024, 1024), 4096: (512, 2048),
                  8192: (512, 2048), 16384: (512, 2048)}
 _BWD_DEFAULTS = {2048: (1024, 512), 4096: (1024, 1024), 8192: (1024, 512),
                  16384: (1024, 1024)}
+# head_dim 128 (64 < d <= 128), swept on a v5e at causal [64, 4096, 128]
+# bf16 (tools/olmoe_kernel_sweep.py, PR 27): forward (1024, 1024) 3.17 ms
+# against the (512, 1024) baseline's 4.01; backward "combined" (1024, 512)
+# 8.75 ms against 11.05 at the forward's blocks (split (1024, 1024) 8.89);
+# (1024, 1024) combined and every 2048-wide backward block run out of VMEM.
+# Other lengths at this width keep the baseline until they are swept.
+_FWD_DEFAULTS_D128 = {4096: (1024, 1024)}
+_BWD_DEFAULTS_D128 = {4096: (1024, 512)}
 
 
 def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
@@ -791,8 +799,9 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
     ``bias`` broadcasts over (batch, heads): accepted shapes are
     [b, h, Tq, Tk], [1, 1, Tq, Tk] or [Tq, Tk].
 
-    Default blocks are per-sequence-length tables (below) at d≤64, else
-    (512, 1024) capped at the sequence lengths — measured on v5e: ahead
+    Default blocks are per-sequence-length tables (below) at d≤64 and, for
+    the lengths swept there, at 64<d≤128, else (512, 1024) capped at the
+    sequence lengths — measured on v5e: ahead
     of XLA's O(T²) attention from T≈1024, and the only runnable path
     beyond ~8k (r4 prior: 11.0 ms fwd / 45.1 ms f+b at [12,16384,64] —
     LONGCTX_ABLATION.md).
@@ -811,12 +820,15 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
     # per-length defaults from the r4 IN-GRAPH sweep on v5e (d=64,
     # bh 12–48, LONGCTX_ABLATION.md): standalone-kernel optima do NOT
     # transfer (XLA overlap + VMEM pressure shift the landscape), so the
-    # tables hold the end-to-end winners.  Swept at d=64 ONLY — wider
-    # heads double the tile VMEM (2048-wide K/V at d=128 matches configs
-    # that failed to compile), so d>64 keeps the long-validated baseline
-    use_tables = d <= 64
-    if block_q is None and block_k is None and use_tables:
-        block_q, block_k = _FWD_DEFAULTS.get(max(tq, tk), (512, 1024))
+    # tables hold the end-to-end winners.  Wider heads double the tile VMEM
+    # (2048-wide K/V at d=128 matches configs that failed to compile), so
+    # 64<d<=128 has tables of its own, filled only where swept, and d>128
+    # keeps the long-validated baseline
+    fwd_table, bwd_table = (
+        (_FWD_DEFAULTS, _BWD_DEFAULTS) if d <= 64 else
+        (_FWD_DEFAULTS_D128, _BWD_DEFAULTS_D128) if d <= 128 else ({}, {}))
+    if block_q is None and block_k is None:
+        block_q, block_k = fwd_table.get(max(tq, tk), (512, 1024))
     if block_q is None:
         block_q = min(512, tq)
     if block_k is None:
@@ -828,8 +840,8 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
                       min(block_k_bwd or block_k, tk))
     else:
         t = max(tq, tk)
-        if use_tables and t in _BWD_DEFAULTS:
-            bq_b, bk_b = _BWD_DEFAULTS[t]
+        if t in bwd_table:
+            bq_b, bk_b = bwd_table[t]
             bwd_blocks = (min(bq_b, tq), min(bk_b, tk))
     qc = q.reshape(b * h, tq, d)
     kc = k.reshape(b * h, tk, d)

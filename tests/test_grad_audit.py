@@ -449,6 +449,19 @@ def _configs(op):
             {"X": [f(2, 2, 3)], "GateW": [f(3, 2)], "W1": [f(2, 3, 5)],
              "B1": [f(2, 5)], "W2": [f(2, 5, 3)], "B2": [f(2, 3)]},
             {"capacity_factor": 2.0}, rtol=8e-2, atol=2e-2),
+        "rms_norm": lambda: _Cfg({"X": [f(2, 3, 4)], "Scale": [f(4)]},
+                         {"epsilon": 1e-5, "begin_norm_axis": 2}),
+        "rope": lambda: _Cfg({"X": [f(2, 3, 8)]},
+                     {"head_dim": 4, "theta": 10000.0}),
+        # dropless top-2 of 4: a perturbed row or router weight can flip a
+        # token's second expert mid-difference, as for switch_ffn; the
+        # integer outputs (ExpertLoad, TopExperts) carry no gradient
+        "moe_ffn": lambda: _Cfg(
+            {"X": [f(2, 3, 4)], "RouterW": [f(4, 4, lo=-2.0, hi=2.0)],
+             "GateW": [f(4, 4, 5)], "UpW": [f(4, 4, 5)],
+             "DownW": [f(4, 5, 4)]},
+            {"top_k": 2, "norm_topk_prob": False},
+            loss_outputs=["Out", "LbLoss", "ZLoss"], rtol=8e-2, atol=2e-2),
         "temporal_shift": lambda: _Cfg({"X": [f(4, 4, 2, 2)]},
                                {"seg_num": 2, "shift_ratio": 0.25}),
         "tile": lambda: _Cfg({"X": [f(2, 3)]}, {"repeat_times": [2, 1]}),

@@ -1,0 +1,69 @@
+"""Device time of the last traced benchmark run by program op.
+
+    python3 benchmark/run.py --workload <cell> ... --trace 1
+    python3 tools/trace_by_op.py <cell>
+
+Reads the newest ``.xplane.pb`` under ``.cache/bench_trace/<cell>/`` with the
+benchmark's own reducers (``benchmark/op_scopes.py``, ``part_scopes.py``) over
+the whole recording, prints shares of device-busy time by ``pt.<role>/<op>``
+scope, by part inside ``moe_ffn``, and which XLA operations make up each of
+the largest scopes, and writes the same to
+``chiprun_out/trace_by_op.<cell>.json``.  Shares, not milliseconds: multiply
+by the run's ``step_device_ms.train``.
+"""
+
+import glob
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import harness, op_scopes, part_scopes  # noqa: E402
+
+
+def main():
+    cell = sys.argv[1]
+    paths = sorted(glob.glob(os.path.join(
+        harness.TRACE_DIR, cell, "plugins", "profile", "*", "*.xplane.pb")))
+    if not paths:
+        sys.exit(f"trace_by_op: no trace of {cell} under {harness.TRACE_DIR}")
+    events = op_scopes.load_scoped_events(paths[-1])
+    red = op_scopes.reduce_scopes(events)
+    busy = red["busy_s"]
+    share = lambda d: {k: round(100 * v / busy, 3) for k, v in sorted(  # noqa
+        d.items(), key=lambda kv: -kv[1])}
+    lo = min(e["start_ns"] for e in events)
+    hi = max(e["start_ns"] + e["dur_ns"] for e in events)
+    parts = part_scopes.reduce_parts(paths[-1], (lo, hi),
+                                     part_scopes.MOE_PARTS)
+    moe = {}
+    for (role, op, part), s in parts.items():
+        if op.startswith("moe_ffn"):
+            moe[f"{role}/{part or '-'}"] = moe.get(f"{role}/{part or '-'}",
+                                                   0.0) + s
+    # XLA operation classes under each scope, by plain event time
+    by = {}
+    for e in events:
+        sc = op_scopes.program_scope(e["scope"])
+        key = "unscoped" if sc is None else f"{sc[0]}/{sc[1]}"
+        d = by.setdefault(key, {})
+        d[e["name"]] = d.get(e["name"], 0.0) + e["dur_ns"] / 1e9
+    top = sorted(red["scoped"], key=lambda k: -red["scoped"][k])[:12]
+    out = {"cell": cell, "busy_s": busy, "scoped_pct": share(red["scoped"]),
+           "unscoped_pct": share(red["unscoped"]),
+           "forward_again_pct": share(red["forward_again"]),
+           "moe_parts_pct": share(moe),
+           "xla_ops_pct": {k: dict(list(share(by[k]).items())[:6])
+                           for k in top + ["unscoped"] if k in by}}
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(ROOT, "chiprun_out",
+                           f"trace_by_op.{cell}.json"), "w") as f:
+        json.dump(out, f, indent=1)
+    print(json.dumps(out))
+
+
+if __name__ == "__main__":
+    main()
