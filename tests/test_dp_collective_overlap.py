@@ -1,0 +1,264 @@
+"""The data-parallel step asks XLA:TPU to hide its gradient all-reduces behind
+compute (``framework.executor.dp_overlap_options``): which meshes and
+platforms get the compile options, that nothing else gets a
+``compiler_options`` key at all, ``paddle_tpu_dp_overlap_compiles_total``,
+and — compiled here for a described v5e:2x2, no chip — that the TPU compiler
+takes every option and leaves all-reduces inside async collective fusions.
+
+The described-topology compile lives in this file alone and loads the TPU
+library inside a fixture (one process may hold it)."""
+
+import os
+import sys
+import types
+
+import jax
+import numpy as np
+import pytest
+
+import paddle_tpu as pt
+from paddle_tpu import optimizer as opt
+from paddle_tpu.framework import (Executor, Program, Scope, executor as E,
+                                  program_guard, scope_guard)
+from paddle_tpu.models import transformer as T
+from paddle_tpu.parallel import mesh as M
+
+sys.path.insert(0, os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools"))
+import dp_arith_check  # noqa: E402  (tools/: where the all-reduces sit)
+
+SEQ, N_MASK, BATCH, DP = 16, 4, 8, 4
+
+
+def _counts():
+    c = E.DP_OVERLAP_CTR
+    return {k: c.value(asked=k[0], reason=k[1]) for k in list(c._series)}
+
+
+def _delta(before):
+    return {k: v - before.get(k, 0) for k, v in _counts().items()
+            if v - before.get(k, 0)}
+
+
+def _mesh(axes):
+    n = int(np.prod(list(axes.values())))
+    return M.make_mesh(axes, jax.devices()[:n])
+
+
+def _bert(cfg=None):
+    cfg = cfg or T.BertConfig(vocab_size=64, d_model=16, n_layer=2, n_head=4,
+                              d_inner=32, max_pos=32, dropout=0.0)
+    scope, main, startup = Scope(), Program(), Program()
+    with scope_guard(scope), program_guard(main, startup):
+        _, _, loss = T.build_bert_pretrain(
+            cfg, SEQ, fused_head=True, arange_pos=True, masked_gather=N_MASK)
+        opt.AdamOptimizer(learning_rate=1e-3).minimize(loss)
+        exe = Executor()
+        exe.run(startup, scope=scope, seed=3)
+    return exe, scope, main, loss
+
+
+def _feed(vocab=64, batch=BATCH, seed=0):
+    rng = np.random.RandomState(seed)
+    return {"src_ids": rng.randint(1, vocab, (batch, SEQ)).astype(np.int32),
+            "mask_pos": np.stack(
+                [rng.choice(SEQ, N_MASK, replace=False) + i * SEQ
+                 for i in range(batch)]).astype(np.int32),
+            "lm_label": rng.randint(1, vocab,
+                                    (batch, N_MASK)).astype(np.int32)}
+
+
+@pytest.fixture
+def jit_calls(monkeypatch):
+    """The keyword arguments of every ``jax.jit`` the executor module makes
+    while the fixture is live."""
+    seen, real = [], jax.jit
+
+    def spy(fn, **kwargs):
+        seen.append(kwargs)
+        return real(fn, **kwargs)
+
+    monkeypatch.setattr(E.jax, "jit", spy)
+    return seen
+
+
+# the rule ------------------------------------------------------------------
+
+@pytest.mark.parametrize("axes,platform,reason", [
+    (None, "tpu", "no_mesh"),
+    (None, "cpu", "no_mesh"),
+    ({"dp": 1}, "tpu", "dp=1"),
+    ({"mp": 4}, "tpu", "dp=1"),
+    ({"dp": 1, "mp": 2}, "tpu", "dp=1"),
+    ({"dp": 4}, "cpu", "not_tpu"),
+    ({"dp": 4}, "gpu", "not_tpu"),
+    ({"dp": 2, "mp": 2}, "cpu", "not_tpu"),
+    ({"dp": 4}, "tpu", "dp_tpu"),
+    ({"dp": 2}, "tpu", "dp_tpu"),
+    ({"dp": 2, "mp": 2}, "tpu", "dp_tpu"),
+])
+def test_which_steps_get_the_options(axes, platform, reason):
+    """The platform is an argument: the meshes here are CPU devices."""
+    before = _counts()
+    options, why = E.dp_overlap_options(
+        None if axes is None else _mesh(axes), platform)
+    assert why == reason
+    if reason == "dp_tpu":
+        assert options == E._DP_OVERLAP_OPTIONS and options
+        assert options is not E._DP_OVERLAP_OPTIONS       # a copy
+        assert all(k.startswith("xla_") for k in options)
+    else:
+        assert options is None
+    assert _delta(before) == {}, "deciding counts nothing; building does"
+
+
+@pytest.mark.parametrize("platform,asked,reason", [
+    ("tpu", "1", "dp_tpu"), ("cpu", "0", "not_tpu")])
+def test_jit_step_hands_the_options_to_the_jit(jit_calls, platform, asked,
+                                               reason):
+    """``_jit_step`` over a mesh that says it is of ``platform`` (a stand-in:
+    no TPU here, and the CPU compiler rejects ``xla_tpu_*`` names, so the
+    jit is built and never called)."""
+    real = _mesh({"dp": 4})
+    fake = types.SimpleNamespace(
+        shape=real.shape, devices=np.array(
+            [types.SimpleNamespace(platform=platform)] * 4))
+    before = _counts()
+    E._jit_step(lambda x: x, fake, donate_argnums=(0,))
+    assert _delta(before) == {(asked, reason): 1}
+    kwargs, = jit_calls
+    assert kwargs.get("donate_argnums") == (0,)
+    if asked == "1":
+        assert kwargs["compiler_options"] == E._DP_OVERLAP_OPTIONS
+    else:
+        assert "compiler_options" not in kwargs
+
+
+# the blocks the executor builds ---------------------------------------------
+
+def _with_dp(n):
+    return lambda main, loss: pt.CompiledProgram(main).with_data_parallel(
+        loss_name=loss.name, places=n)
+
+
+def _gspmd(main, loss):
+    return pt.CompiledProgram(main).with_distributed(
+        mesh=_mesh({"dp": 2, "mp": 2}))
+
+
+@pytest.mark.parametrize("parallel,reason", [
+    (None, "no_mesh"),
+    (_with_dp(1), "dp=1"),
+    (_with_dp(DP), "not_tpu"),
+    (_gspmd, "not_tpu"),
+])
+def test_no_block_here_gets_a_compile_option(jit_calls, parallel, reason):
+    """One-device, dp = 1, dp = 4 and dp 2 x mp 2 steps on this CPU: one
+    compile counted under its reason, the jit built with no
+    ``compiler_options`` keyword, two steps run, and the losses are the
+    one-device program's."""
+    exe, scope, main, loss = _bert()
+    want = [float(np.asarray(exe.run(main, feed=_feed(seed=s), scope=scope,
+                                     fetch_list=[loss.name])[0]))
+            for s in (0, 1)]
+    exe, scope, main, loss = _bert()
+    prog = main if parallel is None else parallel(main, loss)
+    del jit_calls[:]
+    before = _counts()
+    got = [float(np.asarray(exe.run(prog, feed=_feed(seed=s), scope=scope,
+                                    fetch_list=[loss.name])[0]))
+           for s in (0, 1)]
+    assert _delta(before) == {("0", reason): 1}, "once a compile, not a step"
+    assert len(jit_calls) == 1
+    assert "compiler_options" not in jit_calls[0]
+    assert ("in_shardings" in jit_calls[0]) == (parallel is not None)
+    np.testing.assert_allclose(got, want, rtol=2e-5)
+
+
+def test_collective_shard_map_block_goes_through_the_same_rule(jit_calls):
+    from paddle_tpu import layers
+    from paddle_tpu.distributed import GradAllReduce
+    eps = ",".join(f"127.0.0.1:{6570 + i}" for i in range(DP))
+    main, startup = Program(), Program()
+    with program_guard(main, startup), scope_guard(Scope()):
+        x = layers.data("x", shape=[8], dtype="float32")
+        y = layers.data("y", shape=[1], dtype="int64")
+        loss = layers.mean(layers.cross_entropy(
+            layers.fc(layers.fc(x, size=16, act="relu"), size=4,
+                      act="softmax"), y))
+        opt.SGDOptimizer(0.1).minimize(loss)
+        GradAllReduce().transpile(rank=0, endpoints=eps,
+                                  current_endpoint="127.0.0.1:6570")
+        exe = Executor()
+        exe.run(startup, seed=42)
+        del jit_calls[:]
+        before = _counts()
+        rng = np.random.RandomState(1)
+        lv, = exe.run(feed={"x": rng.rand(16, 8).astype("float32"),
+                            "y": rng.randint(0, 4, (16, 1)).astype("int64")},
+                      fetch_list=[loss.name])
+    assert np.isfinite(np.asarray(lv)).all()
+    assert _delta(before) == {("0", "not_tpu"): 1}
+    assert len(jit_calls) == 1 and "compiler_options" not in jit_calls[0]
+
+
+def test_aot_compile_of_a_block_carries_the_jits_options():
+    """``_CompiledBlock.__call__``'s HBM-plan path compiles
+    ``self.jitted.lower(...).compile()``: the options given to ``jax.jit``
+    reach that executable too (shown with an option the CPU compiler knows)."""
+    jitted = jax.jit(lambda x: x + 1,
+                     compiler_options={"xla_embed_ir_in_executable": True})
+    lowered = jitted.lower(np.float32(1))
+    assert dict(lowered._lowering._compiler_options_kvs) == {
+        "xla_embed_ir_in_executable": True}
+    assert float(lowered.compile()(np.float32(1))) == 2.0
+
+
+# the TPU compiler, no chip --------------------------------------------------
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                                 # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+def test_tpu_compiler_takes_the_options_and_fuses_all_reduces(topo):
+    """A 2-layer BERT at widths whose weight gradients pass the combiner's
+    threshold, data parallel over the four described chips, compiled with
+    what ``dp_overlap_options`` gives a TPU mesh: the compiler knows every
+    option, and weight-gradient all-reduces sit inside async collective
+    fusions."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    cfg = T.BertConfig(vocab_size=512, d_model=768, n_layer=2, n_head=12,
+                       d_inner=1024, max_pos=32, dropout=0.0)
+    exe, scope, main, loss = _bert(cfg)
+    prog = pt.CompiledProgram(main).with_data_parallel(
+        loss_name=loss.name, places=list(topo.devices))
+
+    before = _counts()
+    cb, args = dp_arith_check.caught_step(lambda: exe.run(
+        prog, feed=_feed(512, 4 * BATCH), scope=scope,
+        fetch_list=[loss.name]))
+    assert _delta(before) == {("1", "dp_tpu"): 1}
+    shapes = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        args, tuple(cb.in_shardings))
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        lowered = cb.jitted.lower(*shapes)
+        assert dict(lowered._lowering._compiler_options_kvs) == \
+            E._DP_OVERLAP_OPTIONS
+        _, asked = dp_arith_check.all_reduce_schedule(
+            lowered.compile().as_text())
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+    fused = [r for r in asked if r[2].startswith("fused")]
+    assert fused and all(mb >= 1.0 for _, _, _, mb, _ in fused), asked

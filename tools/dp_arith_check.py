@@ -12,7 +12,8 @@ data-parallel step's HLO to ``DIR/dp_step.hlo.txt``, and that of the
 benchmark cell's own step (dropout on, 128 sequences a chip; compiled, never
 run) to ``DIR/dp_step.cell.txt``, each with a summary of where its collectives
 sit: which are inside a ``while`` body, and which computation holds the
-all-reduce of the head's ``dW``.
+all-reduce of the head's ``dW``, and where each all-reduce sits in the entry
+computation's schedule (synchronous, or inside an async collective fusion).
 
 Runs on whatever backend JAX has (a CPU with virtual devices rehearses it at
 ``--layers 2``); a number it prints is a device number only on a TPU.
@@ -77,17 +78,106 @@ def while_loops(hlo):
     return out
 
 
+class _Caught(Exception):
+    pass
+
+
+def caught_step(run):
+    """``(compiled block, (feeds, ro, rw, seed))`` of the first step that
+    ``run()`` would execute: the executor's own arguments, caught at the
+    call that would have run the step, which then does not run."""
+    from paddle_tpu.framework import executor as E
+    caught, call = {}, E._CompiledBlock.__call__
+
+    def catch(self, f, ro, rw, seed):
+        caught.update(cb=self, args=(f, ro, rw, seed))
+        raise _Caught()
+
+    E._CompiledBlock.__call__ = catch
+    try:
+        run()
+    except _Caught:
+        pass
+    finally:
+        E._CompiledBlock.__call__ = call
+    return caught["cb"], caught["args"]
+
+
+def _mbytes(shape):
+    size = {"f32": 4, "bf16": 2, "s32": 4, "u32": 4, "f16": 2}
+    tot = 0
+    for dt, dims in re.findall(r"(f32|bf16|s32|u32|f16)\[([\d,]*)\]", shape):
+        n = 1
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        tot += n * size[dt]
+    return tot / 1e6
+
+
+def all_reduce_schedule(hlo):
+    """``(number of entry instructions, rows)`` of a scheduled module: one
+    ``[first, last, form, MB, op_name]`` per all-reduce, in schedule order.
+    ``form`` is ``sync`` for an ``all-reduce`` instruction of the entry
+    computation, ``fused xN`` for one inside an async collective fusion
+    whose chain of N continuation fusions spans entry instructions
+    ``first..last`` (each fusion of the chain holds a clone of the
+    all-reduce with the same ``channel_id``)."""
+    comps = computations(hlo)
+    by_name = {k.replace("ENTRY ", ""): v for k, v in comps.items()}
+    entry = next((v for k, v in comps.items() if k.startswith("ENTRY ")), [])
+
+    def inside(comp, seen):
+        if comp in seen or comp not in by_name:
+            return []
+        seen.add(comp)
+        got = []
+        for line in by_name[comp]:
+            if " all-reduce(" in line:
+                got.append(line)
+            for c in re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)", line):
+                got += inside(c, seen)
+        return got
+
+    def describe(line):
+        body = line.split(" = ", 1)[1]
+        op = re.search(r'op_name="([^"]*)"', line)
+        return (_mbytes(body[:body.index(" all-reduce")]),
+                re.sub(r"^jit\(\w+\)/", "", op.group(1) if op else "?")[:64])
+
+    rows, chains = [], {}
+    for i, line in enumerate(entry):
+        if re.search(r" all-reduce(-start)?\(", line):
+            rows.append([i, i, "sync", *describe(line)])
+        call = re.search(r"calls=%?(async_collective_fusion[\w.\-]*)", line)
+        for inner in inside(call.group(1), set()) if call else ():
+            key = re.search(r"channel_id=(\d+)", inner).group(1)
+            if key in chains:
+                chains[key][1] = i
+                chains[key][2] += 1
+            else:
+                chains[key] = [i, i, 1, *describe(inner)]
+    rows += [[a, b, f"fused x{n}", mb, op]
+             for a, b, n, mb, op in chains.values()]
+    return len(entry), sorted(rows)
+
+
 def hlo_summary(hlo, vocab):
     """Lines saying where the collectives of a compiled step sit."""
     out = [f"while under {op} in {comp}: {len(inside)} collectives in its "
            "body" + "".join("\n    " + i[:100] for i in inside)
            for op, comp, inside in while_loops(hlo)]
-    n = {}
+    n, seen = {}, set()
     for comp, lines in computations(hlo).items():
         for line in lines:
             m = COLLECTIVE.search(line)
             if not m:
                 continue
+            # an async collective fusion's continuation fusions each hold a
+            # clone of their collective: one channel is one collective
+            channel = re.search(r"channel_id=(\d+)", line)
+            if channel and (m.group(1), channel.group(1)) in seen:
+                continue
+            seen.add((m.group(1), channel and channel.group(1)))
             n[m.group(1)] = n.get(m.group(1), 0) + 1
             result = line[:m.start()]
             if f",{vocab}]" in result or f"[{vocab}," in result \
@@ -97,6 +187,16 @@ def hlo_summary(hlo, vocab):
                            f"{line.strip()[:160]} ... op_name="
                            f"{op.group(1) if op else '?'}")
     out.append(f"collectives in the module: {n}")
+    # where the scheduler put each all-reduce, and in which form (PR 28)
+    n_entry, rows = all_reduce_schedule(hlo)
+    forms = {}
+    for _, _, form, mb, _ in rows:
+        key = "sync" if form.startswith("sync") else "fused"
+        forms[key] = forms.get(key, 0) + 1
+    out.append(f"all-reduces of the entry computation ({n_entry} "
+               f"instructions): {forms}")
+    out += [f"  @{a}..{b} {form} {mb:.2f} MB {op}"
+            for a, b, form, mb, op in rows if mb >= 0.5]
     return out
 
 
@@ -190,25 +290,12 @@ def main(argv=None):
 
     # the benchmark cell's own step (dropout on, 128 sequences a chip),
     # compiled from the arguments the executor hands it and never run
-    class _Compiled(Exception):
-        pass
-
-    def dump(self, f, ro, rw, seed):
-        texts["cell"] = self.jitted.lower(f, ro, rw, seed).compile().as_text()
-        raise _Compiled()
-
     m = bert_base.build_train(config, traffic, args.seed, args.chips,
                               dev.platform == "tpu")
-    E._CompiledBlock.__call__ = dump
-    try:
-        m["exe"].run(m["program"], feed=_train.put_ring(
-            m["ring"][:1], args.chips)[0], fetch_list=[m["loss"]],
-            scope=m["scope"], return_numpy=False)
-    except Exception:
-        if "cell" not in texts:
-            raise
-    finally:
-        E._CompiledBlock.__call__ = call
+    cell, cell_args = caught_step(lambda: m["exe"].run(
+        m["program"], feed=_train.put_ring(m["ring"][:1], args.chips)[0],
+        fetch_list=[m["loss"]], scope=m["scope"], return_numpy=False))
+    texts["cell"] = cell.jitted.lower(*cell_args).compile().as_text()
 
     rel = [abs(a - b) / abs(a) for a, b in zip(one, dp)]
     print(f"dp_arith_check: one chip x {batch}: losses {one}")
