@@ -17,13 +17,13 @@ time, before anything is lowered).
   planner: interval liveness over the dependency-ordered Graph,
   donation- and alias-aware, producing per-program estimated peak bytes
   with a top-K per-op attribution table.  Feeds the verifier's
-  ``memory_budget`` check, ``bench.py``'s ``memory:<workload>``
-  estimate-vs-measured lines, and ``tools/analyze.py``.
+  ``memory_budget`` check, ``tests/test_hbm.py``'s planner
+  estimate-vs-measured band, and ``tools/analyze.py``.
 - :mod:`paddle_tpu.analysis.cost` — the analytic per-op flops/bytes
   model: 2·MAC matmul/conv formulas, grad-op inheritance, per-op-class
   roofline shares, cached on the program fingerprint.  Feeds the
-  executor's live ``paddle_tpu_step_mfu`` gauge, ``bench.py``'s
-  ``mfu:<workload>`` runtime-vs-offline cross-check, the
+  executor's live ``paddle_tpu_step_mfu`` gauge, the
+  ``tests/test_device_attribution.py`` formula checks, the
   ``FLAGS_cost_crosscheck`` parity gate against XLA's own
   ``compiled.cost_analysis()``, and the fusion pass's candidate
   ranking.

@@ -53,7 +53,7 @@ Three layers:
   coordinator folds them into per-rank gauges and computes the straggler
   NET of comm wait (a rank stalled waiting on a peer must not read as
   the slow one), gangtop grows COMM/BW% columns with a
-  straggler-consistent COMM-BOUND flag, and ``bench.py`` /
+  straggler-consistent COMM-BOUND flag, and ``tests/test_comms.py`` /
   ``tools/comms_smoke.py`` gate analytic-vs-measured bytes and the wait
   decomposition in CI.
 

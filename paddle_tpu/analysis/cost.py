@@ -2,7 +2,7 @@
 ``framework.ir`` Graph — the device-time attribution half of the
 observability stack.
 
-``bench.py`` has always computed MFU offline from hand-written per-model
+``benchmark/flops.py`` computes MFU offline from hand-written per-model
 FLOP formulas; this module generalizes that accounting to ANY program:
 each op gets an analytic flop count and a logical byte-traffic estimate
 from its inferred shapes (TPP, arxiv 2104.05755, frames exactly this
@@ -18,10 +18,10 @@ Accounting rules:
 - **matmul family** (``mul``/``matmul``/``matmul_v2``): 2·M·K·N over the
   batch-resolved shapes (transpose attrs honored);
 - **conv2d**: 2·C_in·kh·kw per output element (the same 2·MAC rule
-  ``bench.py`` applies to ResNet);
+  ``benchmark/flops.py`` applies to ResNet);
 - **grad ops** inherit their forward op's formula ×2 (a matmul backward
   is two matmuls of the forward's size; conv backward likewise — the
-  standard fwd:bwd 1:2 flop ratio bench.py's ×3 total encodes);
+  standard fwd:bwd 1:2 flop ratio the benchmark's ×3 total encodes);
 - **normalization/softmax/activation/elementwise**: a small per-element
   factor (the VPU work is real but MXU-irrelevant; it matters for the
   bytes-bound ops the roofline flags);
@@ -158,8 +158,8 @@ def tpu_table_lookup(table, device, what):
 
 def device_peak_flops(device=None) -> float:
     """Peak dense bf16 FLOP/s of one chip — the MFU denominator shared by
-    ``bench.py``'s offline lines and the executor's live gauge (the two
-    accountings must divide by the SAME peak or the bench tolerance gate
+    every offline MFU line and the executor's live gauge (the two
+    accountings must divide by the SAME peak or comparing them
     is meaningless).  A TPU ``device_kind`` missing from
     :data:`TPU_PEAK_FLOPS` raises.  CPU backends get a nominal 1e12
     planning constant (the partitioner's ranking needs a finite number;

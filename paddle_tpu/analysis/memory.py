@@ -27,8 +27,8 @@ actually keeps live:
   already counted there).
 
 Symbolic (-1/None) dims resolve through ``batch_size`` (default 1 — the
-verifier's conservative per-example estimate; ``bench.py`` passes the
-real batch for its estimate-vs-measured lines).  Results are cached on
+verifier's conservative per-example estimate; the executor passes the
+real batch for the HBM plane's plan-drift gauge).  Results are cached on
 the program fingerprint, the same key as the verifier, so steady-state
 dispatch never re-plans.
 """

@@ -38,7 +38,7 @@ value-domain counterpart:
 
 The dynamic-range histograms are the enabling signal for the ROADMAP's
 quantized-collectives arc (EQuARX-style blockwise int8 needs per-tensor
-dynamic range to pick scales; ``bench.py``'s loss-trajectory sha1 line
+dynamic range to pick scales; :func:`loss_fingerprint`'s trajectory sha1
 is the matching loss-parity gate).
 """
 
@@ -821,13 +821,13 @@ def clear_quarantine() -> None:
 
 
 # ---------------------------------------------------------------------------
-# loss-trajectory fingerprint (bench.py's loss-parity gate)
+# loss-trajectory fingerprint (numerics_smoke.py's loss-parity gate)
 # ---------------------------------------------------------------------------
 
 def loss_fingerprint(losses, decimals: int = 5) -> str:
     """sha1 over the rounded loss trajectory — the loss-parity gate the
     quantized-collectives arc compares across codec configurations (and
-    bench.py compares across FLAGS_numerics modes: the stats outputs
+    numerics_smoke.py compares across FLAGS_numerics modes: stats outputs
     must never perturb the training math)."""
     a = np.round(np.asarray(list(losses), np.float64), decimals)
     return hashlib.sha1(a.tobytes()).hexdigest()

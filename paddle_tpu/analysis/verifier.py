@@ -987,7 +987,7 @@ def _verify_cached(program: Program, fetch_names) -> \
         # static comms model (batch=1 baseline): per-collective payload/
         # wire bytes, the analytic comm-time estimate at link peak, and
         # the comm-vs-compute bound verdict — what the executor's
-        # collective launch telemetry, bench.py's comms: lines, and the
+        # collective launch telemetry, tools/comms_smoke.py, and the
         # quantized-collectives gate read without re-planning
         "comms": _comms_attrs(result.comms_plan),
         # static GSPMD sharding model: propagated specs + priced reshard

@@ -6,7 +6,7 @@ machine every layer must agree on.
   error to raise, never a reason to run somewhere else quietly.
 * The persistent XLA compile cache has ONE placement rule
   (:func:`place_compile_cache`), applied when the package is imported so a
-  trainer, a server and ``bench.py`` all start with the same cache.
+  trainer, a server and the benchmark all start with the same cache.
 """
 
 from __future__ import annotations

@@ -1563,7 +1563,7 @@ class Executor:
             return _ps.run_pserver(lsv, scope)
         if compiled is None:
             # plain-Program dispatch gets the same fusion slot
-            # CompiledProgram._optimized runs (this is how bench.py's
+            # CompiledProgram._optimized runs (this is how a caller's
             # direct exe.run() loops reach the pass), at the REAL feed
             # batch; fuse_program's result cache makes the repeat entry
             # a dict probe

@@ -33,7 +33,7 @@ closes that gap with three pieces:
 - **One reader** — :func:`measure_live_bytes` is the canonical measured-
   bytes source: the executor's ``PADDLE_TPU_RECORD_HBM`` one-shot (env
   var kept as an alias of ``FLAGS_hbm_record_plans``) routes through
-  :func:`record_xla_plan`, and ``bench.py``'s ``memory:``/``hbm:`` lines
+  :func:`record_xla_plan`, and ``tools/hbm_smoke.py`` and the HBM tests
   read this module instead of a private measurement.
 
 Fleet-wide, the heartbeat digest carries ``hbm``/``hdrm`` keys folded
@@ -131,7 +131,7 @@ _CLASS_CELLS = {c: HBM_CLASS_GAUGE.labels(cls=c) for c in _CLASSES}
 
 def measure_live_bytes() -> int:
     """Canonical measured live device bytes: the sum over the process's
-    live jax arrays.  One reader for the accountant, bench.py, and the
+    live jax arrays.  One reader for the accountant, the tests, and the
     forensics dump — so every 'measured' number in the system is the
     same quantity the planner's band was established against."""
     return _memory.live_bytes()

@@ -1116,7 +1116,7 @@ def span(name: str, cat: str = "", **args):
 
 def telemetry_snapshot() -> Dict[str, float]:
     """Flatten the registry into {series_key: value} for easy diffing
-    (bench.py computes per-workload deltas this way).  Histograms
+    (two snapshots subtracted give a workload's deltas).  Histograms
     contribute ``<name>_sum`` and ``<name>_count`` per series."""
     flat: Dict[str, float] = {}
     for m in REGISTRY.collect():

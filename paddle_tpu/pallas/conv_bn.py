@@ -12,7 +12,7 @@ into the conv itself.  A bottleneck block's 1x1 convs ARE matmuls
   fold), and sum(Y)/sum(Y^2) accumulated per channel as the epilogue —
   Y is read exactly once and its stats cost no extra pass.
 
-Used experimentally by tools/rn50_fused_bench.py; the measured verdict
+Kept behind the conv1x1+BN fusion pattern; the measured verdict
 on whether this beats XLA's own fusion end-to-end lives in
 RN50_ABLATION.md (round-4 addendum).  Ref workload:
 /root/reference/python/paddle/fluid/tests/book/test_image_classification.py.

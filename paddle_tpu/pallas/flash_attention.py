@@ -116,7 +116,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
 
     At d=64 the per-tile VPU work rivals the MXU time (the round-5
     skeleton microbench measured the r4 kernel at 1.76x its matmul-only
-    skeleton, tools/attn_shape_ceiling.py), so the tile-wide extras are
+    skeleton, LONGCTX_ABLATION.md r5), so the tile-wide extras are
     elided wherever they are statically or block-wise unnecessary:
     sm_scale is folded into q (a [bq,d] row multiply, not [bq,bk]);
     padding masks vanish when the sequence divides the blocks (``pads``

@@ -4,7 +4,7 @@
 The setting is process-global, so every case is its own subprocess.  With
 ``JAX_COMPILATION_CACHE_DIR`` set, nothing in the package may override or
 clear it — not the import, not ``FLAGS_xla_compile_cache_dir`` (set or
-emptied), not ``bench.py``'s setup.  Unset, the cache is
+emptied), not ``chip_smoke.py``'s setup.  Unset, the cache is
 ``<checkout>/.cache/xla_compile`` whatever the working directory.  The
 probe also checks that ``import paddle_tpu`` places the cache without
 initialising a backend (a parent may import the package and still start a
@@ -35,8 +35,8 @@ pt.set_flags({{"FLAGS_xla_compile_cache_dir": {flag_dir!r}}})
 look()
 pt.set_flags({{"FLAGS_xla_compile_cache_dir": ""}})
 look()
-import bench
-bench._device_info()
+import chip_smoke
+chip_smoke.device_identity()
 look()
 print("SEEN", "|".join(str(s) for s in seen))
 """
@@ -57,7 +57,7 @@ def _probe(tmp_path, env_dir, cwd, flag_dir="/flag/dir"):
 
 
 def test_env_var_is_never_overridden_or_cleared(tmp_path):
-    """after import / flag set / flag emptied / bench's setup."""
+    """after import / flag set / flag emptied / chip_smoke's setup."""
     assert _probe(tmp_path, "/x", tmp_path) == ["/x"] * 4
 
 
@@ -65,7 +65,7 @@ def test_env_var_is_never_overridden_or_cleared(tmp_path):
 def test_default_is_the_checkout_whatever_the_cwd(tmp_path, cwd):
     seen = _probe(tmp_path, None, REPO if cwd == "repo" else tmp_path)
     # import -> checkout default; flag -> the flag's dir; emptied -> back
-    # to the default (never None); bench's setup leaves it alone
+    # to the default (never None); chip_smoke's setup leaves it alone
     assert seen == [DEFAULT, "/flag/dir", DEFAULT, DEFAULT]
 
 

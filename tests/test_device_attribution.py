@@ -56,7 +56,7 @@ def test_matmul_flops_exact_with_grad_inheritance():
 
 
 def test_conv_flops_match_bench_formula():
-    """conv2d uses the same 2·MAC rule bench.py applies to ResNet."""
+    """conv2d uses the 2·MAC rule ``benchmark/flops.py`` applies to ResNet."""
     with scope_guard(Scope()), program_guard(Program(), Program()):
         img = layers.data("img", shape=[3, 8, 8], dtype="float32")
         out = layers.conv2d(img, num_filters=4, filter_size=3, padding=1)

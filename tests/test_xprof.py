@@ -3,8 +3,8 @@ chrome-trace parser and dependency-free xplane.pb wire reader, the
 paddle_tpu.step step-join, HLO-kernel -> cost-model op-class
 attribution, measured MFU / idle fraction, the SamplingProfiler
 post-close summary hook (never raises, publishes
-paddle_tpu_step_mfu_measured + the mfu_m digest key), the manifest
-dedupe/prune fix, and the bench_history regression gate."""
+paddle_tpu_step_mfu_measured + the mfu_m digest key), and the manifest
+dedupe/prune fix."""
 
 import gzip
 import json
@@ -24,7 +24,6 @@ from paddle_tpu.framework.scope import Scope, scope_guard
 
 sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools"))
-import bench_history  # noqa: E402
 import xprof  # noqa: E402
 
 FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -308,7 +307,7 @@ def test_manifest_prunes_missing_dirs(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# xprof CLI + bench_history gate (the CI smoke's assertions, in-process)
+# xprof CLI (the CI smoke's assertions, in-process)
 # ---------------------------------------------------------------------------
 
 def test_xprof_cli_json_on_fixture(tmp_path, capsys):
@@ -334,47 +333,3 @@ def test_xprof_cli_unparseable_window_exits_1(tmp_path, capsys):
     wdir = str(tmp_path / "window_bad")
     os.makedirs(os.path.join(wdir, "plugins", "profile", "r1"))
     assert xprof.main(["--window", wdir]) == 1
-
-
-def test_bench_history_gate_passes_on_repo_trajectory():
-    rc = bench_history.main(["--gate", "--json"])
-    assert rc == 0
-
-
-def test_bench_history_gate_fails_on_injected_regression(capsys):
-    rc = bench_history.main(
-        ["--gate", "--json", "--inject", "bert_base_train_mfu=20"])
-    assert rc == 1
-    out = json.loads(capsys.readouterr().out)
-    assert "bert_base_train_mfu" in out["regressed"]
-
-
-def test_bench_history_zero_means_did_not_run():
-    rounds = [(1, {"m": 50.0}), (2, {"m": 0.0})]
-    rows = bench_history.compare(rounds)
-    (row,) = rows
-    # the zero round is not 'carrying' the metric: no comparison
-    assert "value" not in row
-    assert [p["round"] for p in row["trajectory"]] == [1]
-
-
-def test_bench_history_direction_classes():
-    assert bench_history._direction("telemetry:bert") == "lower"
-    assert bench_history._direction("decode_p99_ms") == "lower"
-    assert bench_history._direction("hbm:mlp_adam") == "band"
-    assert bench_history._direction("gspmd:transformer") == "band"
-    assert bench_history._direction("fusion:resnet50") == "skip"
-    assert bench_history._direction("bert_base_train_mfu") == "higher"
-    # band regresses on drift in EITHER direction
-    rows = bench_history.compare(
-        [(1, {"hbm:x": 1.0}), (2, {"hbm:x": 1.2})], tolerance=0.05)
-    assert rows[0]["regressed"]
-    rows = bench_history.compare(
-        [(1, {"hbm:x": 1.0}), (2, {"hbm:x": 0.8})], tolerance=0.05)
-    assert rows[0]["regressed"]
-
-
-def test_bench_history_truncated_tail_extraction():
-    tail = ('garbage {"metric": "a", "value": 1.5, "vs": "x"} mid '
-            '{"metric": "b", "value"')      # second record truncated
-    assert bench_history._extract_metrics(tail) == {"a": 1.5}

@@ -141,8 +141,8 @@ def _run_mode(mode):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     # DEVNULL: the server must NOT inherit the parent's stdout — when
-    # bench.py captures this tool's output, an orphaned server holding the
-    # pipe's write end would block the parent's communicate() forever
+    # a caller captures this tool's output, an orphaned server holding the
+    # pipe's write end would block the caller's communicate() forever
     server = subprocess.Popen(
         [sys.executable, __file__, "pserver", "0", str(port),
          str(N_TRAINERS), mode], env=env, stdout=subprocess.DEVNULL)

@@ -33,18 +33,12 @@ engaged, retries, never recounts the decision), and a primary-
 coordinator SIGKILL under the running autoscaler (the controller keeps
 ticking through the epoch-bumped promotion with zero scale flaps).
 
-``--bench`` runs a condensed numbers-only pass and prints one
-``FLEET BENCH {json}`` line (aggregate 2-replica QPS, p99 while the
-autoscaler absorbs a spike, p99 under a replica SIGKILL) — the
-``serving_fleet`` bench.py entry parses it.
-
 Subprocess protocol: this file re-invokes itself with ``--role
 replica`` / ``--role coordinator``; children print ``READY <addr>`` on
 stdout once serving.
 """
 
 import argparse
-import json
 import os
 import signal
 import subprocess
@@ -498,7 +492,7 @@ def _wait_until(cond, deadline_s, what):
 
 
 class _ScaleRig:
-    """Shared plumbing for the autoscaler drill + bench: a FleetRouter
+    """Plumbing for the autoscaler drills: a FleetRouter
     over subprocess replicas, with spawn/retire closures wired into a
     FleetAutoscaler.  The spawn closure speaks the same ``READY <addr>``
     protocol :class:`paddle_tpu.distributed.launch.ReplicaLauncher`
@@ -827,49 +821,6 @@ def scenario_scale_failover():
         _kill_all([prim])
 
 
-def bench_fleet():
-    """``--bench``: condensed numbers-only pass for bench.py's
-    ``serving_fleet`` line — aggregate 2-replica QPS, p99 while the
-    autoscaler absorbs a spike, p99 under a replica SIGKILL."""
-    rig = _ScaleRig()
-    try:
-        _, thresh = rig.calibrate_slo()
-        rig.scaler.start()
-
-        spike = OpenLoopLoad(rig.router, n_clients=24,
-                             think_s=0.002).start()
-        _wait_until(lambda: rig.live() >= 2, 120.0, "bench scale-up")
-        time.sleep(1.0)
-        spike.stop()
-        p99_spike = spike.p99_ms()
-
-        steady = OpenLoopLoad(rig.router, n_clients=6,
-                              think_s=0.005).start()
-        t0 = time.monotonic()
-        time.sleep(2.0)
-        steady.stop()
-        done, _ = steady.counts()
-        qps = done / max(time.monotonic() - t0, 1e-9)
-
-        kill_load = OpenLoopLoad(rig.router, n_clients=6,
-                                 think_s=0.005).start()
-        time.sleep(0.5)
-        rig.kill_replica(rig.live_addrs()[0])
-        time.sleep(2.5)
-        kill_load.stop()
-        p99_kill = kill_load.p99_ms()
-
-        print("FLEET BENCH " + json.dumps({
-            "aggregate_qps": round(qps, 2),
-            "p99_spike_ms": round(p99_spike, 2),
-            "p99_kill_ms": round(p99_kill, 2),
-            "slo_p99_ms": round(thresh, 2),
-            "replicas": 2}))
-    finally:
-        rig.close()
-    return 0
-
-
 # ---------------------------------------------------------------------------
 # entry
 # ---------------------------------------------------------------------------
@@ -884,9 +835,6 @@ def main(argv=None):
     ap.add_argument("--full", action="store_true",
                     help="run the full kill matrix incl. fault "
                          "injection (slow)")
-    ap.add_argument("--bench", action="store_true",
-                    help="condensed numbers-only pass; prints one "
-                         "'FLEET BENCH {json}' line (bench.py entry)")
     ap.add_argument("--port", type=int, default=0)
     ap.add_argument("--rank", type=int, default=0)
     ap.add_argument("--world", type=int, default=1)
@@ -901,8 +849,6 @@ def main(argv=None):
         return replica_main(args)
     if args.role == "coordinator":
         return coordinator_main(args)
-    if args.bench:
-        return bench_fleet()
     scenarios = {"drain": scenario_drain, "kill": scenario_kill,
                  "coord": scenario_coord, "scale": scenario_scale}
     if args.scenario:

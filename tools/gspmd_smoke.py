@@ -60,11 +60,6 @@ def feed_data(rng):
             "lm_label": rng.randint(0, 64, (8, 8)).astype("int64")}
 
 
-#: bench/smoke shared record — filled in by the gates, emitted as ONE
-#: ``GSPMD_SINGLE`` JSON line under --single-json so bench.py and CI
-#: measure through the same path (the comms_smoke.py pattern).
-RECORD = {}
-
 
 def pick_budget():
     """Gate 1: derive a budget the single-chip plan exceeds but a
@@ -110,16 +105,6 @@ def pick_budget():
     if peak_gauge != chosen["per_shard_peak_bytes"]:
         fail(f"per-shard peak gauge {peak_gauge} != chosen "
              f"{chosen['per_shard_peak_bytes']}")
-    RECORD.update({
-        "single_chip_peak_bytes": int(single_chip),
-        "budget_bytes": int(budget_bytes),
-        "chosen_rules": table.name,
-        "per_shard_peak_bytes": int(chosen["per_shard_peak_bytes"]),
-        "bound": chosen["bound"],
-        "est_comm_ms": chosen["est_comm_ms"],
-        "sharded_params": chosen["sharded_params"],
-        "mesh_axes": AXES,
-    })
     print(f"gspmd smoke 1 OK: single-chip plan {single_chip}B > budget "
           f"{budget_bytes}B -> planner chose {table.name!r} "
           f"(per-shard peak {chosen['per_shard_peak_bytes']}B, "
@@ -129,10 +114,7 @@ def pick_budget():
 
 def run_session(compiled_fn, steps=4):
     """One training session under fresh name generator + scope; returns
-    (losses, opt_state class bytes after drain, scope, program,
-    steps/s over the post-compile steps)."""
-    import time
-
+    (losses, opt_state class bytes after drain, scope, program)."""
     import paddle_tpu as pt
     from paddle_tpu import hbm, monitor
     from paddle_tpu.framework import (Executor, Program, program_guard,
@@ -149,22 +131,17 @@ def run_session(compiled_fn, steps=4):
         exe.run(pt.default_startup_program(), seed=11)
         rng = np.random.RandomState(3)
         out = []
-        t0 = None
         for _ in range(steps):
             lv, = exe.run(compiled, feed=feed_data(rng),
                           fetch_list=[loss.name])
             out.append(float(np.asarray(lv)))
-            if t0 is None:
-                t0 = time.perf_counter()   # exclude the compile step
-        dt = time.perf_counter() - t0
         exe.drain()
         if not hbm.ACCOUNTANT.drain(30):
             fail("accountant did not drain")
         cls = {lbl["cls"]: c.get() for lbl, c in
                monitor.REGISTRY.get(
                    "paddle_tpu_hbm_class_bytes").series()}
-        sps = (steps - 1) / dt if dt > 0 and steps > 1 else 0.0
-        return out, cls.get("opt_state", 0), global_scope(), main, sps
+        return out, cls.get("opt_state", 0), global_scope(), main
 
 
 def check_parity_and_gauges(budget_mb, expect_rules):
@@ -174,13 +151,13 @@ def check_parity_and_gauges(budget_mb, expect_rules):
     from paddle_tpu import monitor
 
     pt.set_flags({"FLAGS_hbm_telemetry": True})
-    base_losses, base_opt, _, _, base_sps = run_session(lambda m, l: None)
+    base_losses, base_opt, _, _ = run_session(lambda m, l: None)
     if base_opt <= 0:
         fail(f"baseline opt_state attribution missing: {base_opt}")
 
     pt.set_flags({"FLAGS_memory_budget_mb": max(int(budget_mb), 1)})
     try:
-        sh_losses, sh_opt, scope, prog, sh_sps = run_session(
+        sh_losses, sh_opt, scope, prog = run_session(
             lambda m, l: pt.CompiledProgram(m).with_gspmd(
                 axes=AXES, rules="auto", zero_stage=1,
                 fetch_names=[l.name], batch_size=8,
@@ -216,20 +193,6 @@ def check_parity_and_gauges(budget_mb, expect_rules):
                  f"{budget} - {live}")
     finally:
         pt.set_flags({"FLAGS_memory_budget_mb": 0})
-    RECORD.update({
-        "losses_single": base_losses,
-        "losses_sharded": sh_losses,
-        "max_rel_diff": max(
-            abs(a - b) / max(abs(a), 1e-9)
-            for a, b in zip(base_losses, sh_losses)),
-        "opt_state_bytes_single": int(base_opt),
-        "opt_state_bytes_sharded": int(sh_opt),
-        "opt_state_ratio": sh_opt / base_opt,
-        "steps_per_s_single": base_sps,
-        "steps_per_s_sharded": sh_sps,
-        "live_bytes": int(live),
-        "headroom_bytes": int(headroom),
-    })
     print(f"gspmd smoke 2 OK: parity over {len(sh_losses)} steps "
           f"(losses {sh_losses}), moment dp-sharded, opt_state "
           f"{int(sh_opt)}B vs single-chip {int(base_opt)}B "
@@ -238,13 +201,9 @@ def check_parity_and_gauges(budget_mb, expect_rules):
           f"({int(budget)} - {int(live)} = {int(headroom)})")
 
 
-def main(argv=None):
-    import json
-    argv = sys.argv[1:] if argv is None else argv
+def main():
     budget_mb, expect_rules = pick_budget()
     check_parity_and_gauges(budget_mb, expect_rules)
-    if "--single-json" in argv:
-        print("GSPMD_SINGLE " + json.dumps(RECORD))
     print("GSPMD SMOKE OK")
 
 

@@ -17,7 +17,7 @@ of the value-domain observability plane.
 
 3. **Loss parity**: the stats output is a pure observer — the loss
    trajectory fingerprints identically with the plane on and off
-   (bench.py tracks the same gate per round as ``numerics_loss_fp``).
+   (``numerics.loss_fingerprint`` of both trajectories must be equal).
 """
 
 import json

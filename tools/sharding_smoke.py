@@ -66,10 +66,6 @@ def feed_data(rng):
             "lm_label": rng.randint(0, 64, (8, 8)).astype("int64")}
 
 
-#: bench/smoke shared record — emitted as ONE ``SHARDING_SINGLE`` JSON
-#: line under --single-json (the comms_smoke.py pattern).
-RECORD = {}
-
 
 def _dispatched():
     from paddle_tpu import monitor
@@ -156,16 +152,6 @@ def check_blessed_and_measured():
         if any(not np.isfinite(v) for v in losses):
             fail(f"non-finite loss under mp_hidden: {losses}")
 
-    RECORD.update({
-        "mesh_axes": AXES, "rules": "mp_hidden",
-        "n_edges": len(plan.edges), "n_unexplained": 0,
-        "plan_payload_bytes": int(plan.payload_bytes),
-        "plan_wire_bytes": int(plan.wire_bytes),
-        "plan_est_ms": plan.est_ms,
-        "measured_bytes": int(db), "steps_measured": STEPS,
-        "reshard_fingerprint": plan.fingerprint,
-        "losses": losses,
-    })
     print(f"sharding smoke 1 OK: mp_hidden plan has {len(plan.edges)} "
           f"edge(s), 0 unexplained; verify stamp + fingerprint fold "
           f"carry #resh={plan1.resh_token}")
@@ -206,18 +192,13 @@ def check_conflicting_refused():
         dd = _dispatched() - d0
         if dd != 0:
             fail(f"refused program still dispatched {dd} step(s)")
-    RECORD["conflict_refused"] = True
     print("sharding smoke 2 OK: overcommitted table refused with "
           "mesh_axis_overuse at optimize time, 0 steps dispatched")
 
 
-def main(argv=None):
-    import json
-    argv = sys.argv[1:] if argv is None else argv
+def main():
     check_blessed_and_measured()
     check_conflicting_refused()
-    if "--single-json" in argv:
-        print("SHARDING_SINGLE " + json.dumps(RECORD))
     print("SHARDING SMOKE OK")
 
 
