@@ -91,29 +91,7 @@ def resnet50_train_flops_per_sample(image: int = 224, classes: int = 1000
     return 3.0 * resnet50_forward_flops_per_sample(image, classes)
 
 
-# -- the fused conv1x1 + BN-statistics Pallas kernel --------------------------
-
-def conv1x1_stats_sites(image: int = 224) -> List[Dict[str, int]]:
-    """The 1x1 convolutions of ResNet-50, each followed by a batch-statistics
-    BN: the sites the PR-9 fusion pass turns into ``conv1x1_stats_nchw``
-    Mosaic calls.  36 of them: b0 and b2 of all 16 blocks and the 4
-    projection shortcuts (a strided shortcut subsamples its input first, so
-    the kernel always runs at the output resolution ``hout``)."""
-    return [s for s in resnet50_conv_sites(image) if s["k"] == 1]
-
-
-def conv1x1_stats_flops_bytes(batch: int, cin: int, cout: int, hw: int,
-                              in_bytes: int = 2, out_bytes: int = 2
-                              ) -> Tuple[float, float]:
-    """One forward call of the fused kernel on [batch, cin, hw] -> [batch,
-    cout, hw] plus per-channel sum and sum of squares: the matmul's 2*MAC
-    (the statistics' 3 flops per output are counted too), and the bytes it
-    must move: input, weight, output, two f32 statistic vectors."""
-    flops = 2.0 * batch * hw * cin * cout + 3.0 * batch * hw * cout
-    nbytes = (batch * hw * cin * in_bytes + cin * cout * in_bytes
-              + batch * hw * cout * out_bytes + 2 * cout * 4)
-    return flops, float(nbytes)
-
+# -- rooflines ----------------------------------------------------------------
 
 def roofline_seconds(flops: float, nbytes: float, peaks: Dict[str, float]
                      ) -> Tuple[float, str]:

@@ -292,10 +292,14 @@ def run(ctx) -> dict:
     correct = (check["ok"] and failed == 0 and wrong_len == 0
                and engine.trace_count == 1 and compiled_in_window == 0
                and traced_in_window == 0 and len(lat) > 0)
+    verdict = (f"{'correct' if correct else 'NOT correct'}: "
+               f"logits_ok={check['ok']} failed={failed} (0) "
+               f"wrong_len={wrong_len} (0) trace_count={engine.trace_count} "
+               f"(1) compiled_in_window={compiled_in_window} (0) "
+               f"traced_in_window={traced_in_window} (0) "
+               f"latency_samples={len(lat)} (> 0)")
     if not correct:
-        harness.log(f"NOT correct: logits_ok={check['ok']} failed={failed} "
-                    f"wrong_len={wrong_len} trace_count={engine.trace_count} "
-                    f"compiled_in_window={compiled_in_window}")
+        harness.log(verdict)
 
     n_beyond = len(lat) - int(math.ceil(0.9 * len(lat))) if lat else 0
     harness.log(f"norm_latency_p90_ms (per layer) over {len(lat)} requests "
@@ -303,6 +307,7 @@ def run(ctx) -> dict:
                 f"{percentile(lat, 90) if lat else float('nan'):.3f} ms/token")
     return {
         "correct": correct,
+        "compared": [check["detail"], verdict],
         "attempted": len(in_window),
         "failed": failed,
         "setup_s": setup_s,

@@ -112,6 +112,17 @@ def run(ctx) -> dict:
         device_note = (f"; loss fetch spans {span} device(s), parameter "
                        f"{name} spans {w_span}, "
                        f"bytes in use per chip {in_use}")
+    # for a run that reads slow (PERF.md section 7, row 24): one long stall
+    # or every step slower?  The throttle paces the dispatches at the
+    # device's rate, so their intervals are the steps as the host saw them
+    starts = np.sort([s[1] for s in spans if s[0] == "executor.dispatch"])
+    if len(starts) > 2:
+        gaps = np.diff(starts) * 1e3
+        harness.log(
+            f"dispatch intervals: median {np.median(gaps):.2f} ms, longest "
+            f"{gaps.max():.2f} ms (after step {int(gaps.argmax()) + 1} of "
+            f"{len(starts)}), {int((gaps > 1.5 * np.median(gaps)).sum())} "
+            "over 1.5x the median")
     harness.log(
         f"window {window_s:.3f}s (asked {ctx.seconds}s, closed "
         f"{t_close - t_deadline:+.3f}s after the deadline); {steps} steps of "
@@ -121,12 +132,16 @@ def run(ctx) -> dict:
     correct = (all(c["ok"] for c in checks) and finite and steps > 0
                and compiled_in_window == 0 and traced_in_window == 0
                and ok_devices)
+    verdict = (f"{'correct' if correct else 'NOT correct'}: "
+               f"checks={[c['ok'] for c in checks]} finite={finite} "
+               f"steps={steps} (> 0) compiled_in_window={compiled_in_window}"
+               f" (0) traced_in_window={traced_in_window} (0) "
+               f"devices_ok={ok_devices}")
     if not correct:
-        harness.log(f"NOT correct: checks={[c['ok'] for c in checks]} "
-                    f"finite={finite} compiled_in_window="
-                    f"{compiled_in_window} devices_ok={ok_devices}")
+        harness.log(verdict)
     return {
         "correct": correct, "attempted": steps,
+        "compared": [c["detail"] for c in checks] + [verdict],
         "failed": int(sum(1 for x in losses if not np.isfinite(x))),
         "setup_s": setup_s,
         "e2e": {"train_samples_per_s": rate,

@@ -358,9 +358,12 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool,
     if on_chip:
         from . import flops
         peaks = flops.load_peaks(device["kind"])
+    # "compared": what decided ``correct``, each number beside its limit;
+    # run.py repeats it as the last lines of standard error
     result = {"correct": bool(out["correct"]),
               "attempted": int(out["attempted"]),
-              "failed": int(out["failed"]), "metrics": {}, "device": device}
+              "failed": int(out["failed"]), "metrics": {}, "device": device,
+              "compared": list(out.get("compared", []))}
     e2e = dict(out["e2e"], setup_s=out["setup_s"])
     if not trace:
         for m in metrics_of_cell(spec, "end_to_end", cell_name):

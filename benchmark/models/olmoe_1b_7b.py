@@ -63,12 +63,13 @@ def reference_loss(reference, params, feed, cfg, hidden=None):
     """The reference's loss of ``feed`` and its per-layer top-k choices, one
     sequence at a time (at published widths the chip holds one sequence's
     score matrix and logits, not four).  ``hidden`` [B, T, d]: a program's
-    final-norm output, compared with the reference's as ``|got - want| /
-    |want|`` (Frobenius) — unlike the loss, which averages rounding away
-    over thousands of tokens, this tells the precisions apart."""
+    final-norm output; then also, per token, the squared distance from the
+    reference's and the reference's own squared size, for
+    :func:`hidden_difference` — unlike the loss, which averages rounding away
+    over thousands of tokens, that tells the precisions apart."""
     import jax
     import jax.numpy as jnp
-    total, tops, off, size = None, [], 0.0, 0.0
+    total, tops, off2, size2 = None, [], [], []
     for i in range(feed["src_ids"].shape[0]):
         s = reference.sequence_sums(
             params, jnp.asarray(feed["src_ids"][i:i + 1]),
@@ -77,13 +78,32 @@ def reference_loss(reference, params, feed, cfg, hidden=None):
         want = s.pop("hidden").astype(jnp.float32)
         if hidden is not None:
             got = jnp.asarray(hidden[i:i + 1], jnp.float32)
-            off += float(jnp.sum(jnp.square(got - want)))
-            size += float(jnp.sum(jnp.square(want)))
+            off2.append(np.asarray(
+                jnp.sum(jnp.square(got - want), axis=-1), np.float64).ravel())
+            size2.append(np.asarray(
+                jnp.sum(jnp.square(want), axis=-1), np.float64).ravel())
         total = s if total is None else \
             jax.tree_util.tree_map(jnp.add, total, s)
     out = reference.loss_of_sums(total, cfg.lb_coef, cfg.z_coef)
     return (float(out["loss"]), np.concatenate(tops, axis=1),
-            (off / size) ** 0.5 if hidden is not None else None)
+            (np.concatenate(off2), np.concatenate(size2))
+            if hidden is not None else None)
+
+
+def hidden_difference(per_token, keep=None):
+    """``|got - want| / |want|`` (Frobenius) of the final-norm output over
+    the tokens of ``keep`` (a mask; all of them without one)."""
+    off2, size2 = per_token
+    if keep is not None:
+        off2, size2 = off2[keep], size2[keep]
+    return float(np.sqrt(off2.sum() / size2.sum()))
+
+
+def tokens_that_differ(top, ref_top):
+    """[S] bool from two [L, S, k]: the tokens whose set of experts is not
+    the reference's in some layer."""
+    return np.any(np.any(np.sort(top, -1) != np.sort(ref_top, -1), axis=-1),
+                  axis=0)
 
 
 def _forward_program(cfg, seq, scope, amp):
@@ -138,20 +158,28 @@ def build_train(config, traffic, seed, chips, on_chip):
 def check_before_window(config, traffic, built, seed, reference, chips):
     """The float32 forward program (no AMP, matmuls at ``highest``, the same
     weights in the same scope) on a small seeded batch, against the
-    reference: the loss and the final-norm output; the initial weights go to
-    the host for :func:`check_first_loss`."""
+    reference: the loss, each token's experts and the final-norm output; the
+    initial weights go to the host for :func:`check_first_loss`.
+
+    Top-8 of 64 is not continuous: where a token's 8th and 9th router
+    probabilities tie to float32's last bits, two sound float32 computations
+    choose differently and that token's output is 19-37 % off (1 such token
+    of 8192 in 2 of 40 seeds, PERF.md section 6, PR 30) — 2e-3 to 4e-3 over
+    the whole batch, which a limit of 1e-3 called not correct.  So the
+    output is compared over the tokens whose experts are the reference's,
+    and the share of the others is held to a limit of its own."""
     import jax
     import jax.numpy as jnp
     cfg, scope = built["cfg"], built["scope"]
     seq, n = traffic["seq_len"], traffic["check_batch"]
-    main, heads, loads, _ = _forward_program(cfg, seq, scope, amp=False)
+    main, heads, loads, tops = _forward_program(cfg, seq, scope, amp=False)
     feed = make_batch(_train.rng_of(seed, 7), cfg, n, seq)
     # on a TPU a float32 matmul multiplies in bf16 passes unless told
     # otherwise; this program is the float32 one, so it is told
     with jax.default_matmul_precision("highest"):
-        got, hidden, *load = built["exe"].run(
-            main, feed=feed, fetch_list=heads + loads, scope=scope)
-    want, _, hidden_off = reference_loss(
+        got, hidden, *rest = built["exe"].run(
+            main, feed=feed, fetch_list=heads + loads + tops, scope=scope)
+    want, ref_top, per_token = reference_loss(
         reference, reference_params(
             lambda name: jnp.asarray(scope.find_var(name), jnp.float32), cfg),
         feed, cfg, hidden=hidden)
@@ -159,18 +187,35 @@ def check_before_window(config, traffic, built, seed, reference, chips):
     # the device would count in the system's peak memory
     built["initial"] = {v.name: np.asarray(scope.find_var(v.name), np.float32)
                         for v in built["parameters"]}
-    err = _train.rel_err(np.asarray(got), want)
-    tol = config["loss_tolerance"]
-    rows = n * seq * cfg.top_k
-    dropless = all(int(np.asarray(v).sum()) == rows for v in load)
+    return before_window_verdict(
+        config["loss_tolerance"], np.asarray(got), want, per_token,
+        np.stack([np.asarray(v).reshape(-1, cfg.top_k)
+                  for v in rest[len(loads):]]), ref_top,
+        [np.asarray(v) for v in rest[:len(loads)]], n)
+
+
+def before_window_verdict(tol, got, want, per_token, top, ref_top, load, n):
+    """What :func:`check_before_window` decides from what it read: every
+    number beside its limit."""
+    err = _train.rel_err(got, want)
+    differ = tokens_that_differ(top, ref_top)
+    share = float(differ.mean())
+    hidden_off = hidden_difference(per_token, ~differ)
+    rows = top.shape[1] * top.shape[2]
+    dropless = all(int(v.sum()) == rows for v in load)
     return {"ok": bool(np.isfinite(err) and err <= tol["relative"]
-                       and hidden_off <= tol["hidden_relative"] and dropless),
-            "detail": f"float32 forward loss {float(np.asarray(got)):.6f} vs "
+                       and hidden_off <= tol["hidden_relative"]
+                       and share <= tol["top_k_differ_share"] and dropless),
+            "detail": f"float32 forward loss {float(got):.6f} vs "
             f"reference {want:.6f} on {n} sequences: relative difference "
-            f"{err:.2e} (tolerance {tol['relative']}); final-norm output "
-            f"{hidden_off:.2e} from the reference's (tolerance "
-            f"{tol['hidden_relative']}); every layer's ExpertLoad sums to "
-            f"{rows}: {dropless}"}
+            f"{err:.2e} (tolerance {tol['relative']}); tokens whose top-"
+            f"{top.shape[2]} differs from the reference's: "
+            f"{int(differ.sum())} of {differ.size}, a share of {share:.2e} "
+            f"(tolerance {tol['top_k_differ_share']}); final-norm output "
+            f"over the others {hidden_off:.2e} from the reference's "
+            f"(tolerance {tol['hidden_relative']}; over all tokens "
+            f"{hidden_difference(per_token):.2e}); every layer's ExpertLoad "
+            f"sums to {rows}: {dropless}"}
 
 
 def check_first_loss(config, traffic, built, first_loss, first_feed,
@@ -198,15 +243,15 @@ def check_first_loss(config, traffic, built, first_loss, first_feed,
     main, heads, loads, tops = _forward_program(cfg, seq, scope, amp=True)
     got, hidden, *rest = built["exe"].run(
         main, feed=first_feed, fetch_list=heads + loads + tops, scope=scope)
-    want, ref_top, hidden_off = reference_loss(
+    want, ref_top, per_token = reference_loss(
         reference, reference_params(initial.__getitem__, cfg), first_feed,
         cfg, hidden=hidden)
+    hidden_off = hidden_difference(per_token)
     load = [np.asarray(v) for v in rest[:len(loads)]]
     top = np.stack([np.asarray(v).reshape(-1, cfg.top_k)
                     for v in rest[len(loads):]])
     rows = top.shape[1] * cfg.top_k
-    differ = int(np.sum(np.any(np.sort(top, -1) != np.sort(ref_top, -1),
-                               axis=-1)))
+    differ = int(tokens_that_differ(top, ref_top).sum())
     err = _train.rel_err(first_loss, want)
     err_fwd = _train.rel_err(np.asarray(got), first_loss)
     tol = config["loss_tolerance"]
@@ -225,4 +270,4 @@ def check_first_loss(config, traffic, built, first_loss, first_feed,
             f"{tol['first_hidden_relative']}); ExpertLoad sums to {rows} in "
             f"every layer: {dropless}, max/mean {skew:.3f}; tokens whose "
             f"top-{cfg.top_k} differs from the reference's: {differ} of "
-            f"{top.shape[0] * top.shape[1]}"}
+            f"{top.shape[1]}"}

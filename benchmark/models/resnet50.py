@@ -1,6 +1,8 @@
 """ResNet-50 ImageNet training through the repo's public entry points:
 ``models.resnet.build_resnet_train`` + AMP momentum SGD + the Executor with
-default flags (so the conv1x1+BN fusion pass is on, as users get it)."""
+the program's default flags, whatever those lower the step to: that is what
+users get, and it is why a change of the default path is judged in this
+cell."""
 
 import numpy as np
 
@@ -71,7 +73,7 @@ def check_before_window(config, traffic, built, seed, reference, chips):
 
 def check_first_loss(config, traffic, built, first_loss, first_feed,
                      reference):
-    """The loss the compiled training step (fused kernels, bf16 AMP) fetched
+    """The loss the compiled training step (default flags, bf16 AMP) fetched
     for its first batch, against the reference's float32 forward pass with
     batch statistics over the same batch and the initial weights."""
     import jax.numpy as jnp

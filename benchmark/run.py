@@ -42,6 +42,9 @@ def main(argv=None) -> int:
     result = harness.run_cell(args.workload, args.seed, args.seconds,
                               bool(args.trace),
                               t_process_start=T_PROCESS_START, spec=spec)
+    for line in result["compared"]:
+        print(f"bench: {line}", file=sys.stderr)
+    sys.stderr.flush()
     sys.stdout.flush()
     print(json.dumps(result), flush=True)
     return 0

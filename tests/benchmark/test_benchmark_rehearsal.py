@@ -116,6 +116,9 @@ def test_cell_end_to_end_on_cpu(cell, trace):
     assert line["correct"] is True
     assert line["attempted"] > 0 and line["failed"] == 0
     assert line["device"]["platform"] == "cpu"     # and so: not a result
+    # what run.py repeats on standard error: the comparisons, then the verdict
+    assert len(line["compared"]) >= 2
+    assert line["compared"][-1].startswith("correct: ")
 
 
 def test_decode_traced_run_reads_the_schedulers_counts():
@@ -518,7 +521,8 @@ def test_flop_counts(what, got, want):
 def test_resnet50_sites():
     sites = flops.resnet50_conv_sites()
     assert len(sites) == 53                      # 1 stem + 16*3 + 4 shortcuts
-    assert len(flops.conv1x1_stats_sites()) == 36
+    # b0 and b2 of all 16 blocks and the 4 projection shortcuts
+    assert sum(s["k"] == 1 for s in sites) == 36
     by = {s["name"]: s for s in sites}
     assert by["stem"] == dict(name="stem", cin=3, cout=64, k=7, stride=2,
                               hout=112)
@@ -529,18 +533,18 @@ def test_resnet50_sites():
     assert 64 * 112 * 112 * 3 * 49 == 118013952
 
 
-def test_conv1x1_stats_flops_bytes_and_roofline():
-    # res0_0.b0 at batch 256: [256, 64, 3136] -> [256, 64, 3136], bf16
-    fl, by = flops.conv1x1_stats_flops_bytes(256, 64, 64, 3136)
-    assert fl == 2 * 256 * 3136 * 64 * 64 + 3 * 256 * 3136 * 64
-    assert by == 256 * 3136 * 64 * 2 + 64 * 64 * 2 + 256 * 3136 * 64 * 2 \
-        + 2 * 64 * 4
-    t, bound = flops.roofline_seconds(fl, by, flops.load_peaks("TPU v5 lite"))
-    assert bound == "memory"                      # 32 flops per byte
-    assert t == pytest.approx(by / 819e9)
-    t2, bound2 = flops.roofline_seconds(1e15, 1.0,
-                                        flops.load_peaks("TPU v5 lite"))
-    assert bound2 == "compute" and t2 == pytest.approx(1e15 / 197e12)
+@pytest.mark.parametrize("fl,by,bound", [
+    # a 1x1 convolution [256, 64, 3136] -> [256, 64, 3136] in bf16 with its
+    # weight: 32 flops per byte, under the chip's 240
+    (2 * 256 * 3136 * 64 * 64,
+     256 * 3136 * 64 * 2 + 64 * 64 * 2 + 256 * 3136 * 64 * 2, "memory"),
+    (1e15, 1.0, "compute"),
+])
+def test_roofline_seconds_is_the_larger_of_the_two_bounds(fl, by, bound):
+    t, got = flops.roofline_seconds(fl, by, flops.load_peaks("TPU v5 lite"))
+    assert got == bound
+    assert t == pytest.approx(by / 819e9 if bound == "memory"
+                              else fl / 197e12)
 
 
 def test_percentile_and_spread():

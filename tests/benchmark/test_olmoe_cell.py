@@ -34,6 +34,7 @@ def toy_olmoe():
     # at these widths the fused head's bf16 products move the loss by 1e-4
     # and bf16 AMP by 1e-2; the chip's tolerances are set at the real widths
     c["loss_tolerance"] = {"relative": 2e-3, "hidden_relative": 1e-3,
+                           "top_k_differ_share": 0.02,
                            "first_training_loss_relative": 5e-2,
                            "first_hidden_relative": 5e-2,
                            "reason": "toy widths"}
@@ -104,6 +105,116 @@ def test_cell_end_to_end_on_cpu(trace):
     assert line["correct"] is True
     assert line["attempted"] > 0 and line["failed"] == 0
     assert line["device"]["platform"] == "cpu"     # and so: not a result
+
+
+# -- what decides ``correct`` -----------------------------------------------------
+
+#: the configuration's own limits, at the cell's own size: 2 x 4096 tokens
+TOL = harness.load_json("benchmark/configs/olmoe_1b_7b.json")["loss_tolerance"]
+
+
+@pytest.mark.parametrize("flipped,off_flipped,off_rest,lost_rows,ok", [
+    (0, 0.0, 1.6e-6, 0, True),       # a sound run, as 38 of 40 seeds read
+    (1, 0.37, 1.6e-6, 0, True),      # one token at a tie (PR 30's refusal)
+    (3, 0.37, 1.6e-6, 0, True),
+    (30, 0.2, 1.6e-6, 0, False),     # more ties than chance gives
+    (433, 0.2, 1.1e-2, 0, False),    # the control: the reference in bf16
+    (0, 0.0, 1.1e-2, 0, False),      # bf16 arithmetic that flipped nobody
+    (0, 0.0, 1.6e-6, 8, False),      # a token dropped
+])
+def test_the_float32_verdict_at_the_cells_size(flipped, off_flipped,
+                                               off_rest, lost_rows, ok):
+    """``before_window_verdict`` on hand-made readings of 8192 tokens: a
+    token whose 8th and 9th router probabilities tie may choose the other
+    expert and be a third off without the run being called not correct (over
+    all tokens it reads 4e-3, above the limit of 1e-3 that used to hold
+    that number); many such tokens, a worse output on the others, or a
+    lost row may not."""
+    import numpy as np
+    model = harness.load_module("models", "olmoe_1b_7b")
+    tokens, k = 8192, 8
+    ref_top = np.tile(np.arange(k), (1, tokens, 1))
+    top = ref_top.copy()
+    top[0, :flipped, 0] = 63                   # another expert, same count
+    size2 = np.full(tokens, 2048.0)
+    off = np.full(tokens, off_rest)
+    off[:flipped] = off_flipped
+    load = np.zeros(64, np.int64)
+    load[0] = tokens * k - lost_rows
+    out = model.before_window_verdict(
+        TOL, 10.97, 10.97, (np.square(off) * size2, size2), top, ref_top,
+        [load], 2)
+    assert out["ok"] is ok, out["detail"]
+    if flipped == 1:
+        assert model.hidden_difference((np.square(off) * size2, size2)) \
+            > TOL["hidden_relative"]
+    for limit in ("relative", "hidden_relative", "top_k_differ_share"):
+        assert f"(tolerance {TOL[limit]}" in out["detail"]
+
+
+def test_the_reference_in_bf16_in_the_programs_place_is_not_correct():
+    """The control of the float32 check at toy widths: the reference with
+    every weight, and so every activation, in bf16 takes the program's
+    place and comes out not correct; the same reference in float32 comes
+    out correct."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from benchmark.models import _train
+    config, traffic = toy_olmoe()
+    model = harness.load_module("models", "olmoe_1b_7b")
+    reference = harness.load_module("reference", "olmoe_1b_7b")
+    for seed in (5, rehearsal.BIG_SEED, 3000000017):
+        m = model.build_train(config, traffic, seed, 1, False)
+        cfg, scope = m["cfg"], m["scope"]
+        feed = model.make_batch(_train.rng_of(seed, 7), cfg, 2,
+                                traffic["seq_len"])
+        params = model.reference_params(
+            lambda n: jnp.asarray(scope.find_var(n), jnp.float32), cfg)
+        verdicts = {}
+        for name, dtype in (("float32", jnp.float32),
+                            ("bfloat16", jnp.bfloat16)):
+            p = jax.tree_util.tree_map(lambda a: a.astype(dtype), params)
+            got, top, _ = model.reference_loss(reference, p, feed, cfg)
+            s = reference.batch_sums(
+                p, jnp.asarray(feed["src_ids"]),
+                jnp.asarray(feed["lm_label"]), **model._reference_kw(cfg))
+            want, ref_top, per_token = model.reference_loss(
+                reference, params, feed, cfg,
+                hidden=np.asarray(s["hidden"], np.float32))
+            load = [np.bincount(t.ravel(), minlength=cfg.n_experts)
+                    for t in top]
+            verdicts[name] = model.before_window_verdict(
+                config["loss_tolerance"], got, want, per_token, top, ref_top,
+                load, 2)
+        assert verdicts["float32"]["ok"], verdicts["float32"]["detail"]
+        assert not verdicts["bfloat16"]["ok"], verdicts["bfloat16"]["detail"]
+
+
+def test_a_step_over_other_weights_than_the_seeds_is_not_correct(monkeypatch):
+    """The rest of a run over a broken timed path: between the float32 check
+    and the first step the head's weight is scaled by 8, so the timed step
+    trains another model than the seed's, which the reference holds; every
+    reading before the window is sound and ``correct`` comes out false on
+    the step's own first loss.  (The labels will not do for this: the loss
+    of fresh weights is ln V plus little whatever they are.)"""
+    model = harness.load_module("models", "olmoe_1b_7b")
+    check = model.check_before_window
+
+    def then_break(config, traffic, built, *rest):
+        out = check(config, traffic, built, *rest)
+        w = built["scope"].find_var("lm_out.w")
+        built["scope"].set_vars({"lm_out.w": w * 8.0})
+        return out
+
+    monkeypatch.setattr(model, "check_before_window", then_break)
+    config, traffic = toy_olmoe()
+    result = harness.run_cell(CELL, seed=rehearsal.BIG_SEED + 1, seconds=0.5,
+                              trace=False, on_chip=False, config=config,
+                              traffic=traffic, spec=SPEC)
+    assert result["correct"] is False
+    assert result["attempted"] > 0 and result["failed"] == 0
+    assert "checks=[True, False]" in result["compared"][-1]
 
 
 def test_the_lowered_step_names_the_new_ops_and_the_parts_of_moe_ffn():
