@@ -80,8 +80,7 @@ _CLASS_OF = {
     "flash_attention": "attention", "fused_attention": "attention",
     # analysis.fusion rewrite targets keep their source chain's class so
     # the roofline shares (and the live MFU numerator) survive fusion
-    "fused_conv1x1_bn": "conv", "fused_dense_act": "matmul",
-    "fused_embedding_layer_norm": "embedding",
+    "fused_dense_act": "matmul", "fused_embedding_layer_norm": "embedding",
 }
 
 #: per-element flop factors for the cheap (VPU) classes; everything not
@@ -273,20 +272,6 @@ def _conv_flops(block, op, batch_size) -> Optional[int]:
     return 2 * _numel(out) * w[1] * w[2] * w[3]
 
 
-def _fused_conv1x1_flops(block, op, batch_size) -> Optional[int]:
-    """fused_conv1x1_bn: the 1x1 conv is 2·Cin MACs per output element
-    (the BN epilogue is VPU noise the conv formula dominates)."""
-    f = _slot(op, "Filter")
-    y = op.output("Y") or op.input("OG$Y")
-    if not f or not y:
-        return None
-    w = _shape(block, f[0], batch_size)
-    out = _shape(block, y[0], batch_size)
-    if not w or not out or len(w) < 2:
-        return None
-    return 2 * _numel(out) * w[1]
-
-
 def _fused_dense_flops(block, op, batch_size) -> Optional[int]:
     """fused_dense_act: 2·M·K·N over the flattened x (mul semantics at
     ``x_num_col_dims``; -1 = matmul over the trailing dim)."""
@@ -326,8 +311,6 @@ def _op_cost(block: Block, op, batch_size: int) -> Tuple[int, int, str]:
         flops = _matmul_flops(block, op, batch_size)
     elif fwd in ("conv2d", "depthwise_conv2d", "conv2d_transpose"):
         flops = _conv_flops(block, op, batch_size)
-    elif fwd == "fused_conv1x1_bn":
-        flops = _fused_conv1x1_flops(block, op, batch_size)
     elif fwd == "fused_dense_act":
         flops = _fused_dense_flops(block, op, batch_size)
     elif fwd in ("lookup_table", "lookup_table_v2", "gather", "gather_nd",

@@ -14,8 +14,6 @@ ideas into the PR-5 pass-before-lowering slot:
    ======================  =================================  ==========
    pattern                 subgraph                           fused op
    ======================  =================================  ==========
-   conv_bn_relu            conv2d(1x1) + batch_norm(train)    fused_conv1x1_bn
-                           [+ relu]
    dense_epilogue          mul/matmul + bias add +            fused_dense_act
                            gelu/relu [+ tagged dropout]
    embedding_layer_norm    lookup_table [+ adds] +            fused_embedding_
@@ -225,135 +223,6 @@ def _grad_consumer(graph, grad_name: str, type_: str, slot: str):
 # pattern matchers
 # ---------------------------------------------------------------------------
 
-def _match_conv_bn_relu(graph, program, fetch_names) -> List[_Candidate]:
-    """conv2d + batch_norm(train) [+ relu] → ``fused_conv1x1_bn``.
-
-    The structural spine matches via PDPattern; kernel-shape limits of
-    the Pallas target (1x1, stride-square, no pad/dilation/groups,
-    NCHW, bias-free) are LEGALITY rules so near-misses surface in the
-    report instead of silently not matching."""
-    from ..framework import ir
-
-    pat = ir.PDPattern()
-    conv = pat.new_op("conv2d")
-    conv_out = pat.new_var("conv_out").as_intermediate()
-    bn = pat.new_op("batch_norm")
-    pat.link(conv, conv_out)
-    pat.link(conv_out, bn)
-    cands = []
-    for m in ir.GraphPatternDetector(pat)(graph):
-        conv_n, bn_n, cout_n = m[conv], m[bn], m[conv_out]
-        y_node = next((v for v in bn_n.outputs
-                       if v.name in bn_n.op.output("Y")), None)
-        if y_node is None:
-            continue
-        cand = _Candidate("conv_bn_relu", "conv",
-                          anchor=y_node.name)
-        cand.fwd_ops = [conv_n, bn_n]
-        cand.internal = [cout_n]
-        a = bn_n.op.attrs
-        ca = conv_n.op.attrs
-        strides = ca.get("strides", [1, 1])
-        w_node = ir._input_node(conv_n, "Filter")
-        x_node = ir._input_node(conv_n, "Input")
-        wshape = getattr(getattr(w_node, "var", None), "shape", None) \
-            if w_node is not None else None
-        # structural legality of the Pallas target
-        if a.get("is_test") or a.get("use_global_stats") or \
-                a.get("data_layout", "NCHW") != "NCHW":
-            cand.reject_rule = "bn_mode_unsupported"
-        elif ca.get("groups", 1) != 1 or \
-                any(p != 0 for p in ca.get("paddings", [0, 0])) or \
-                any(d != 1 for d in ca.get("dilations", [1, 1])) or \
-                strides[0] != strides[1] or conv_n.op.input("Bias"):
-            cand.reject_rule = "kernel_unsupported"
-        elif not wshape or len(wshape) != 4 or wshape[2] != 1 or \
-                wshape[3] != 1:
-            cand.reject_rule = "kernel_unsupported"
-        elif w_node is None or x_node is None:
-            cand.reject_rule = "kernel_unsupported"
-        cands.append(cand)
-        if cand.reject_rule:
-            continue
-        # optional exclusive relu tail folds into the fused act
-        out_node, relu_n = y_node, None
-        y_fwd = _fwd_consumers(y_node)
-        if len(y_fwd) == 1 and y_fwd[0].is_op("relu") \
-                and y_node.name not in fetch_names:
-            relu_n = y_fwd[0]
-            cand.fwd_ops.append(relu_n)
-            cand.internal.append(y_node)
-            out_node = relu_n.outputs[0]
-        cand.anchor = out_node.name
-        by_name = {v.name: v for v in bn_n.inputs}
-
-        def bn_in(slot):
-            names = bn_n.op.input(slot)
-            return by_name.get(names[0]) if names else None
-
-        scale_n, bias_n = bn_in("Scale"), bn_in("Bias")
-        mean_n, var_n = bn_in("Mean"), bn_in("Variance")
-        if None in (scale_n, bias_n, mean_n, var_n):
-            cand.reject_rule = "kernel_unsupported"
-            continue
-        fused_attrs = {"momentum": a.get("momentum", 0.9),
-                       "epsilon": a.get("epsilon", 1e-5),
-                       "act": "relu" if relu_n is not None else "",
-                       "stride": int(strides[0]),
-                       "is_test": False, "use_global_stats": False}
-        outs = {"Y": [out_node]}
-        for slot in ("MeanOut", "VarianceOut", "SavedMean",
-                     "SavedVariance"):
-            names = bn_n.op.output(slot)
-            node = next((v for v in bn_n.outputs if names and
-                         v.name in names), None)
-            if node is not None:
-                outs[slot] = [node]
-        ins = {"X": [x_node], "Filter": [w_node], "Scale": [scale_n],
-               "Bias": [bias_n], "Mean": [mean_n], "Variance": [var_n]}
-
-        _finish_candidate(
-            graph, program, cand,
-            fused_type="fused_conv1x1_bn",
-            fused_ins=ins, fused_outs=outs, fused_attrs=fused_attrs,
-            out_node=out_node, og_slot_name="Y",
-            grad_chain=_conv_bn_grad_chain(graph, cand, conv_n, bn_n,
-                                           relu_n, out_node),
-            grad_ig={"X": ("conv2d_grad", "IG$Input"),
-                     "Filter": ("conv2d_grad", "IG$Filter"),
-                     "Scale": ("batch_norm_explicit_grad", "IG$Scale"),
-                     "Bias": ("batch_norm_explicit_grad", "IG$Bias")})
-    return cands
-
-
-def _conv_bn_grad_chain(graph, cand, conv_n, bn_n, relu_n, out_node):
-    """Locate the relu_grad → batch_norm_explicit_grad → conv2d_grad
-    chain for one matched forward, or None when absent/ineligible."""
-    chain = []
-    g = out_node.name + "@GRAD"
-    if relu_n is not None:
-        rg = _grad_consumer(graph, g, "relu_grad", "OG$Out")
-        if rg is None or rg.op.attrs.get("__fwd_type__") != "relu":
-            return None
-        chain.append(rg)
-        igx = rg.op.output("IG$X")
-        if not igx or not igx[0]:
-            return None
-        g = igx[0]
-    bg = _grad_consumer(graph, g, "batch_norm_explicit_grad", "OG$Y")
-    if bg is None:
-        return None
-    chain.append(bg)
-    igx = bg.op.output("IG$X")
-    if not igx or not igx[0]:
-        return None
-    cg = _grad_consumer(graph, igx[0], "conv2d_grad", "OG$Output")
-    if cg is None or cg.op.attrs.get("__fwd_type__") != "conv2d":
-        return None
-    chain.append(cg)
-    return chain
-
-
 def _match_dense_epilogue(graph, program, fetch_names) -> List[_Candidate]:
     """mul/matmul + elementwise_add(bias) + gelu/relu [+ tagged dropout]
     → ``fused_dense_act``."""
@@ -495,8 +364,7 @@ def _match_dense_epilogue(graph, program, fetch_names) -> List[_Candidate]:
                            "Bias": [bias_n]},
                 fused_outs={"Out": [out_node]},
                 fused_attrs=fused_attrs,
-                out_node=out_node, og_slot_name="Out",
-                grad_chain=grad_chain,
+                out_node=out_node, grad_chain=grad_chain,
                 grad_ig={"X": (mm_type + "_grad", "IG$X"),
                          "W": (mm_type + "_grad", "IG$Y"),
                          "Bias": ("elementwise_add_grad", "IG$Y")})
@@ -641,8 +509,7 @@ def _match_embedding_layer_norm(graph, program,
             graph, program, cand,
             fused_type="fused_embedding_layer_norm",
             fused_ins=ins, fused_outs=outs, fused_attrs=fused_attrs,
-            out_node=y_node, og_slot_name="Out",
-            grad_chain=grad, grad_ig=grad_ig,
+            out_node=y_node, grad_chain=grad, grad_ig=grad_ig,
             addend_grads=grad[1] if grad else None)
         cands.append(cand)
     return cands
@@ -690,8 +557,8 @@ def _embedding_ln_grad_chain(graph, y_node, ln_n, chain_ops, lt_n):
 # ---------------------------------------------------------------------------
 
 def _finish_candidate(graph, program, cand, *, fused_type, fused_ins,
-                      fused_outs, fused_attrs, out_node, og_slot_name,
-                      grad_chain, grad_ig, addend_grads=None):
+                      fused_outs, fused_attrs, out_node, grad_chain,
+                      grad_ig, addend_grads=None):
     """Attach the grad chain, autotune descs, and the build() closure to
     a structurally-matched candidate.  ``grad_ig`` maps fused input slot
     -> (original grad op type, its IG slot) for recovering the external
@@ -768,9 +635,7 @@ def _finish_candidate(graph, program, cand, *, fused_type, fused_ins,
          out_var.shape else ()),)
 
     def build(g, use_pallas=False):
-        attrs = dict(fused_attrs)
-        if "use_pallas" in attrs:
-            attrs["use_pallas"] = bool(use_pallas)
+        attrs = dict(fused_attrs, use_pallas=bool(use_pallas))
         fused_node = g.create_op_node(fused_type, inputs=fused_ins,
                                       outputs=fused_outs, attrs=attrs)
         doomed = list(cand.fwd_ops) + list(cand.internal) + \
@@ -789,7 +654,7 @@ def _finish_candidate(graph, program, cand, *, fused_type, fused_ins,
                 og_node = _node_by_name(gop, og_name)
                 if og_node is not None:
                     break
-            g_ins["OG$" + og_slot_name] = [og_node]
+            g_ins["OG$Out"] = [og_node]
             g_outs = {}
             by_type = {}
             for gop in cand.grad_ops:
@@ -849,7 +714,8 @@ def _finish_candidate(graph, program, cand, *, fused_type, fused_ins,
 # ---------------------------------------------------------------------------
 
 #: rules worth a user-facing warning (structural kernel limits are not —
-#: a 3x3 conv not matching the 1x1 Pallas target is expected, not a bug)
+#: a transposed matmul not matching the fused dense op is expected, not a
+#: bug)
 _WARN_RULES = frozenset({
     "fetched_internal", "multi_consumer", "persistable_internal",
     "subblock_ref", "missing_grad_rewrite", "alias_hazard",
@@ -1007,7 +873,7 @@ def _fill_value(name: str, shape, dtype, batch: int):
     d = str(dtype)
     if "int" in d:
         return jnp.zeros(rs, jnp.int32)
-    # positive fill: variance-like operands must survive rsqrt
+    # a constant, non-zero fill: the timing does not depend on the values
     return jnp.full(rs, np.float32(0.5),
                     jnp.bfloat16 if d == "bfloat16" else jnp.float32)
 
@@ -1079,10 +945,8 @@ def _autotune(cand: _Candidate, batch: int) -> Optional[dict]:
         ext_vals = {n: _fill_value(n, s, d, batch)
                     for n, (s, d) in cand.ext_inputs.items()}
         # the fused candidate benches its preferred kernel config
-        fused_descs = [
-            (t, i, o, dict(a, use_pallas=True) if "use_pallas" in a
-             else a)
-            for t, i, o, a in cand.fused_descs]
+        fused_descs = [(t, i, o, dict(a, use_pallas=True))
+                       for t, i, o, a in cand.fused_descs]
         base_ms = _time_chain(cand.base_descs, ext_vals, amp=amp)
         fused_ms = _time_chain(fused_descs, ext_vals, amp=amp)
     except Exception:
@@ -1106,7 +970,6 @@ def _autotune(cand: _Candidate, batch: int) -> Optional[dict]:
 # ---------------------------------------------------------------------------
 
 _MATCHERS = (
-    _match_conv_bn_relu,
     _match_dense_epilogue,
     _match_embedding_layer_norm,
 )

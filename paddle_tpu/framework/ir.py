@@ -760,99 +760,6 @@ class FuseElewiseAddActPass(Pass):
         return graph
 
 
-@register_pass("conv_bn_train_fuse_pass")
-class ConvBNTrainFusePass(Pass):
-    """conv2d(1x1) + batch_norm(TRAIN) [+ relu] → ``fused_conv1x1_bn``.
-
-    TPU-native TRAINING-time fusion with no reference counterpart (the
-    reference's conv_bn_fuse_pass.cc handles inference only — batch
-    statistics can't fold into weights).  The fused op's Pallas matmul
-    accumulates the BN sums in the conv's own output pass, deleting the
-    separate stat-reduction read of the (huge) conv output
-    (ops/conv_bn_ops.py; measured deltas in RN50_ABLATION.md)."""
-
-    def apply_impl(self, graph: Graph) -> Graph:
-        protected = self.protected_vars()
-        count = 0
-        for bn in list(graph.ops_of_type("batch_norm")):
-            if bn not in graph.op_nodes:
-                continue
-            a = bn.op.attrs
-            if a.get("is_test") or a.get("use_global_stats"):
-                continue
-            if a.get("data_layout", "NCHW") != "NCHW":
-                continue
-            by_name = {v.name: v for v in bn.inputs}
-            x_in = by_name.get(bn.op.input("X")[0])
-            if x_in is None or not x_in.inputs or \
-                    not x_in.inputs[0].is_op("conv2d"):
-                continue
-            if len(x_in.outputs) != 1 or x_in.name in protected:
-                continue                     # conv output must feed BN only
-            conv = x_in.inputs[0]
-            ca = conv.op.attrs
-            strides = ca.get("strides", [1, 1])
-            if ca.get("groups", 1) != 1 or \
-                    any(p != 0 for p in ca.get("paddings", [0, 0])) or \
-                    any(d != 1 for d in ca.get("dilations", [1, 1])) or \
-                    strides[0] != strides[1]:
-                continue
-            w_node = next((v for v in conv.inputs
-                           if v.name == conv.op.input("Filter")[0]), None)
-            x_node = next((v for v in conv.inputs
-                           if v.name == conv.op.input("Input")[0]), None)
-            if w_node is None or x_node is None:
-                continue
-            wshape = getattr(w_node.var, "shape", None)
-            if not wshape or len(wshape) != 4 or wshape[2] != 1 or \
-                    wshape[3] != 1:
-                continue
-            if conv.op.input("Bias"):
-                continue
-            y_node = next((v for v in bn.outputs
-                           if v.name in bn.op.output("Y")), None)
-            if y_node is None:
-                continue
-            # fold a following exclusive relu into the act attr (never
-            # when the BN output itself is fetched/protected)
-            act, doomed_act = "", []
-            if len(y_node.outputs) == 1 and \
-                    y_node.outputs[0].is_op("relu") and \
-                    y_node.name not in protected:
-                relu = y_node.outputs[0]
-                act = "relu"
-                out_node = relu.outputs[0]
-                doomed_act = [relu, y_node]
-            else:
-                out_node = y_node
-            outs = {"Y": [out_node]}
-            for slot in ("MeanOut", "VarianceOut", "SavedMean",
-                         "SavedVariance"):
-                names = bn.op.output(slot)
-                if names:
-                    node = next((v for v in bn.outputs
-                                 if v.name in names), None)
-                    if node is not None:
-                        outs[slot] = [node]
-            graph.create_op_node(
-                "fused_conv1x1_bn",
-                inputs={"X": [x_node], "Filter": [w_node],
-                        "Scale": [by_name[bn.op.input("Scale")[0]]],
-                        "Bias": [by_name[bn.op.input("Bias")[0]]],
-                        "Mean": [by_name[bn.op.input("Mean")[0]]],
-                        "Variance": [by_name[bn.op.input("Variance")[0]]]},
-                outputs=outs,
-                attrs={"momentum": a.get("momentum", 0.9),
-                       "epsilon": a.get("epsilon", 1e-5),
-                       "act": act, "stride": int(strides[0]),
-                       "is_test": False,
-                       "use_global_stats": False})
-            graph.safe_remove_nodes([conv, x_in, bn] + doomed_act)
-            count += 1
-        graph.attrs["conv_bn_train_fuse_count"] = count
-        return graph
-
-
 @register_pass("repeated_fc_relu_fuse_pass")
 class RepeatedFCReluFusePass(Pass):
     """Chains of fc(act=relu) → one ``fusion_repeated_fc_relu``

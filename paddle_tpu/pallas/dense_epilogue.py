@@ -14,7 +14,7 @@ Forward tiles rows into VMEM ([block_m, K] @ [K, N] on the MXU in bf16
 with f32 accumulation), applies bias + act on the accumulator, and
 writes the tile once.  Backward is plain XLA matmul math through the
 activation's local derivative — on the MXU there is nothing left for a
-hand backward to save (same verdict as ``conv_bn.mm_stats``).
+hand backward to save (what RN50_ABLATION.md found for its matmul too).
 """
 
 from __future__ import annotations

@@ -2,10 +2,11 @@
 
 Covers the PR-9 contract: per-pattern match + apply, legality
 near-misses (fetched intermediate, multi-consumer, missing grad
-rewrite), rank-threshold gating, loss parity fused-vs-unfused on
-resnet-shaped and bert-shaped toy training programs, collective-
-fingerprint stability through the rewrite, autotune cache hit/miss
-counters, and executor plan invalidation on a fusion-flag flip.
+rewrite), rank-threshold gating, loss parity fused-vs-unfused on a
+bert-shaped toy training program (a resnet-shaped one goes through the
+pass unchanged), collective-fingerprint stability through the rewrite,
+autotune cache hit/miss counters, and executor plan invalidation on a
+fusion-flag flip.
 """
 
 import numpy as np
@@ -42,23 +43,44 @@ def _fusion_defaults():
     fusion.clear_cache()
 
 
-def _build_conv_toy(train=True, side_consumer=False):
-    """conv2d(1x1)+bn+relu -> pool -> fc(softmax) -> ce loss [+ SGD]."""
+def _build_conv_toy():
+    """conv2d(1x1)+bn+relu -> pool -> fc(softmax) -> ce loss + SGD:
+    resnet-shaped, and no pattern's subject."""
     img = layers.data("image", shape=[3, 6, 6], dtype="float32")
     label = layers.data("label", shape=[1], dtype="int64")
     conv = layers.conv2d(img, num_filters=8, filter_size=1, padding=0,
                          bias_attr=False)
     bn = layers.batch_norm(conv, act="relu")
     pool = layers.pool2d(bn, global_pooling=True, pool_type="avg")
-    if side_consumer:
-        side = layers.relu(conv)      # second consumer of the conv out
-        pool = pool + layers.pool2d(side, global_pooling=True,
-                                    pool_type="avg")
     pred = layers.fc(pool, size=10, act="softmax")
+    loss = layers.mean(layers.cross_entropy(pred, label))
+    opt.SGDOptimizer(learning_rate=0.1).minimize(loss)
+
+
+def _build_dense_toy(train=True, side_consumer=False):
+    """fc(relu) -> fc(softmax) -> ce loss [+ SGD]: the first fc's
+    mul + bias add + relu is the ``dense_epilogue`` pattern's subject.
+    Returns the mul's output (an internal var of the match) and the
+    loss."""
+    x = layers.data("x", shape=[12], dtype="float32")
+    label = layers.data("label", shape=[1], dtype="int64")
+    h = layers.fc(x, size=16, act="relu")
+    block = pt.default_main_program().global_block()
+    mm_out = block.var(next(op for op in block.ops
+                            if op.type == "mul").output("Out")[0])
+    if side_consumer:
+        h = h + layers.relu(mm_out)   # second consumer of the mul out
+    pred = layers.fc(h, size=10, act="softmax")
     loss = layers.mean(layers.cross_entropy(pred, label))
     if train:
         opt.SGDOptimizer(learning_rate=0.1).minimize(loss)
-    return conv, bn, loss
+    return mm_out, loss
+
+
+def _dense_feed():
+    rng = np.random.RandomState(0)
+    return {"x": rng.rand(4, 12).astype(np.float32),
+            "label": rng.randint(0, 10, (4, 1)).astype(np.int64)}
 
 
 def _conv_feed(rng=None):
@@ -110,26 +132,6 @@ def _run_steps(prog, loss, scope, feed, steps=3):
 # ---------------------------------------------------------------------------
 # match + apply
 # ---------------------------------------------------------------------------
-
-def test_conv_bn_relu_applied_and_stamped():
-    scope = Scope()
-    with scope_guard(scope), program_guard(Program(), Program()):
-        _build_conv_toy()
-        prog = pt.default_main_program()
-        fused = fusion.fuse_program(prog, (),
-                                    feed_shapes={"image": (4, 3, 6, 6)})
-        assert fused is not prog
-        types = [op.type for op in fused.global_block().ops]
-        assert "fused_conv1x1_bn" in types
-        assert "fused_conv1x1_bn_grad" in types
-        assert "conv2d" not in types and "batch_norm" not in types
-        rep = fused._attrs["fusion"]
-        assert rep["applied"] >= 1 and rep["collective_fingerprint_ok"]
-        # the post-pass verify stamp rides the fused program
-        assert fused._attrs["verify"]["collective_fingerprint"] == \
-            prog._attrs["verify"]["collective_fingerprint"]
-        assert verify_program(fused, ()).ok
-
 
 def test_dense_epilogue_applied_with_tagged_dropout():
     scope = Scope()
@@ -208,32 +210,32 @@ def test_embedding_layer_norm_applied_bert_shaped():
 def test_reject_fetched_intermediate():
     scope = Scope()
     with scope_guard(scope), program_guard(Program(), Program()):
-        conv, bn, loss = _build_conv_toy(train=False)
+        mm_out, loss = _build_dense_toy(train=False)
         prog = pt.default_main_program()
-        rep = fusion.analyze_program(prog, (conv.name, loss.name))
+        rep = fusion.analyze_program(prog, (mm_out.name, loss.name))
         dec = {c.pattern: c for c in rep.decisions}
-        assert dec["conv_bn_relu"].verdict == "rejected"
-        assert dec["conv_bn_relu"].rule == "fetched_internal"
+        assert dec["dense_epilogue"].verdict == "rejected"
+        assert dec["dense_epilogue"].rule == "fetched_internal"
         # and fuse_program leaves the program untouched
         assert fusion.fuse_program(
-            prog, (conv.name, loss.name)) is prog
+            prog, (mm_out.name, loss.name)) is prog
 
 
 def test_reject_multi_consumer_intermediate():
     scope = Scope()
     with scope_guard(scope), program_guard(Program(), Program()):
-        _build_conv_toy(train=False, side_consumer=True)
+        _build_dense_toy(train=False, side_consumer=True)
         prog = pt.default_main_program()
         rep = fusion.analyze_program(prog, ())
         dec = {c.pattern: c for c in rep.decisions}
-        assert dec["conv_bn_relu"].verdict == "rejected"
-        assert dec["conv_bn_relu"].rule == "multi_consumer"
+        assert dec["dense_epilogue"].verdict == "rejected"
+        assert dec["dense_epilogue"].rule == "multi_consumer"
 
 
 def test_reject_missing_grad_rewrite():
     scope = Scope()
     with scope_guard(scope), program_guard(Program(), Program()):
-        _build_conv_toy(train=True)
+        _build_dense_toy(train=True)
         prog = pt.default_main_program()
         blk = prog.global_block()
         # amputate the relu_grad: the program still contains grad ops,
@@ -242,21 +244,21 @@ def test_reject_missing_grad_rewrite():
         prog._bump_version()
         rep = fusion.analyze_program(prog, ())
         dec = {c.pattern: c for c in rep.decisions}
-        assert dec["conv_bn_relu"].verdict == "rejected"
-        assert dec["conv_bn_relu"].rule == "missing_grad_rewrite"
+        assert dec["dense_epilogue"].verdict == "rejected"
+        assert dec["dense_epilogue"].rule == "missing_grad_rewrite"
 
 
 def test_rank_threshold_gates_rewrites():
     pt.set_flags({"FLAGS_fusion_rank_threshold": 1.1})  # nothing passes
     scope = Scope()
     with scope_guard(scope), program_guard(Program(), Program()):
-        _build_conv_toy()
+        _build_dense_toy()
         prog = pt.default_main_program()
         fused = fusion.fuse_program(prog, ())
         assert fused is prog
         rep = prog._attrs["fusion"]
         verdicts = {c["verdict"] for c in rep["candidates"]
-                    if c["pattern"] == "conv_bn_relu"}
+                    if c["pattern"] == "dense_epilogue"}
         assert "ranked_out" in verdicts
 
 
@@ -267,7 +269,7 @@ def test_rank_threshold_gates_rewrites():
 def test_collective_fingerprint_unchanged_by_fusion():
     scope = Scope()
     with scope_guard(scope), program_guard(Program(), Program()):
-        _conv, _bn, loss = _build_conv_toy(train=True)
+        _mm, loss = _build_dense_toy(train=True)
         prog = pt.default_main_program()
         blk = prog.global_block()
         blk.create_var(name="allr_out", shape=loss.shape,
@@ -317,7 +319,13 @@ def _parity(build, feed_fn, tol):
 
 
 def test_loss_parity_resnet_shaped():
-    _parity(_build_conv_toy, _conv_feed, tol=5e-3)
+    # no pattern has a conv + BN subject: the pass hands the program back
+    # and the two flag settings train the same step, to the bit
+    _parity(_build_conv_toy, _conv_feed, tol=1e-12)
+    with scope_guard(Scope()), program_guard(Program(), Program()):
+        _build_conv_toy()
+        prog = pt.default_main_program()
+        assert fusion.fuse_program(prog, ()) is prog
 
 
 def test_loss_parity_bert_shaped():
@@ -336,12 +344,11 @@ def test_autotune_cache_hit_miss_counters(tmp_path):
     try:
         scope = Scope()
         with scope_guard(scope), program_guard(Program(), Program()):
-            _build_conv_toy()
+            _build_dense_toy()
             prog = pt.default_main_program()
             miss0 = _counter("paddle_tpu_fusion_autotune_total",
                              cache="miss")
-            fusion.fuse_program(prog, (),
-                                feed_shapes={"image": (4, 3, 6, 6)})
+            fusion.fuse_program(prog, (), feed_shapes={"x": (4, 12)})
             miss1 = _counter("paddle_tpu_fusion_autotune_total",
                              cache="miss")
             assert miss1 > miss0
@@ -351,8 +358,7 @@ def test_autotune_cache_hit_miss_counters(tmp_path):
             fusion.clear_cache()
             hit0 = _counter("paddle_tpu_fusion_autotune_total",
                             cache="hit")
-            fusion.fuse_program(prog, (),
-                                feed_shapes={"image": (4, 3, 6, 6)})
+            fusion.fuse_program(prog, (), feed_shapes={"x": (4, 12)})
             hit1 = _counter("paddle_tpu_fusion_autotune_total",
                             cache="hit")
             assert hit1 > hit0
@@ -377,8 +383,8 @@ def test_autotune_cache_migrates_backend_keys(tmp_path):
     foreign = "tpu" if backend != "tpu" else "gpu"
     old_rec = {"base_ms": 1.0, "fused_ms": 0.5, "win": True}
     new_rec = {"base_ms": 1.0, "fused_ms": 2.0, "win": False}
-    old_key = _json.dumps(["conv1x1_bn_relu", "sk", 4, backend, "f32"])
-    new_key = _json.dumps(["conv1x1_bn_relu", "sk", 4,
+    old_key = _json.dumps(["dense_epilogue", "sk", 4, backend, "f32"])
+    new_key = _json.dumps(["dense_epilogue", "sk", 4,
                            fusion._device_key(), "f32"])
     other_old = _json.dumps(["dense_act", "sk2", 8, backend, "amp"])
     foreign_key = _json.dumps(["dense_act", "sk3", 8, foreign, "f32"])
@@ -411,11 +417,11 @@ def test_autotune_cache_migrates_backend_keys(tmp_path):
 def test_flag_flip_invalidates_executor_plan():
     scope = Scope()
     with scope_guard(scope), program_guard(Program(), Program()):
-        _conv, _bn, loss = _build_conv_toy()
+        _mm, loss = _build_dense_toy()
         prog = pt.default_main_program()
         exe = pt.Executor()
         exe.run(pt.default_startup_program(), scope=scope, seed=7)
-        feed = _conv_feed()
+        feed = _dense_feed()
         exe.reset_dispatch_stats()
         exe.run(prog, feed=feed, fetch_list=[loss.name], scope=scope,
                 seed=SEED)

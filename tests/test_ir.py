@@ -412,56 +412,6 @@ def test_attention_fuse_pass_keeps_noncausal_bias_and_axis_gates():
         assert g2.attrs["attention_fuse_count"] == 0
 
 
-def test_conv_bn_train_fuse_pass_parity():
-    """conv2d(1x1)+batch_norm(train)[+relu] pairs rewrite to
-    fused_conv1x1_bn (Pallas matmul with BN-stat epilogue) with EXACT
-    training-trajectory parity, via apply_to_program so minimize() stays
-    on one program.  (Kept opt-in: measured end-to-end on chip the fused
-    path LOSES to XLA's own layout/fusion — RN50_ABLATION.md r4.)"""
-    import numpy as np
-    import paddle_tpu as pt
-    from paddle_tpu import layers, optimizer as opt
-    from paddle_tpu.framework import Executor, Program, program_guard, ir
-    from paddle_tpu.framework.scope import Scope, scope_guard
-    from paddle_tpu.models.resnet import bottleneck_block
-
-    rng = np.random.RandomState(0)
-    xv = rng.rand(2, 8, 8, 8).astype(np.float32)
-    lv = rng.randint(0, 4, (2, 1)).astype(np.int64)
-
-    def run(fused):
-        scope = Scope()
-        with scope_guard(scope), program_guard(Program(), Program()):
-            img = layers.data("img", shape=[8, 8, 8], dtype="float32")
-            label = layers.data("label", shape=[1], dtype="int64")
-            h = bottleneck_block(img, 4, 1, "bb0")
-            h = bottleneck_block(h, 4, 2, "bb1")
-            pred = layers.fc(layers.flatten(
-                layers.pool2d(h, pool_type="avg", global_pooling=True)),
-                size=4, act="softmax")
-            loss = layers.mean(layers.cross_entropy(pred, label))
-            if fused:
-                g = ir.Graph(pt.default_main_program())
-                g = ir.get_pass("conv_bn_train_fuse_pass").apply(g)
-                # 2 blocks x (conv0 + conv2 + shortcut) 1x1 pairs
-                assert g.attrs["conv_bn_train_fuse_count"] == 6
-                g.apply_to_program()
-                types = [o.type for o in
-                         pt.default_main_program().global_block().ops]
-                assert types.count("fused_conv1x1_bn") == 6
-            opt.MomentumOptimizer(0.1, 0.9).minimize(loss)
-            exe = Executor()
-            exe.run(pt.default_startup_program(), scope=scope, seed=3)
-            out = []
-            for _ in range(4):
-                l, = exe.run(feed={"img": xv, "label": lv},
-                             fetch_list=[loss.name], scope=scope)
-                out.append(float(np.asarray(l)))
-            return out
-
-    np.testing.assert_allclose(run(True), run(False), rtol=2e-3, atol=2e-4)
-
-
 def test_serving_fusion_passes():
     """The four serving-path canonicalization passes (ref
     ir/*_fuse_pass.cc families): pattern counts + numeric parity."""

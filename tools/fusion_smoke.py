@@ -3,9 +3,9 @@
 
 1. rewrite NOTHING when ``FLAGS_graph_fusion`` is off (zero decisions,
    zero fused ops dispatched);
-2. with the flag on, apply >= 1 conv+bn+relu and >= 1 dense-epilogue
-   rewrite on the toy training program, with the fused program
-   verifier-clean and the collective fingerprint unchanged;
+2. with the flag on, apply >= 1 dense-epilogue rewrite on the toy
+   training program and leave its conv+bn+relu to XLA, with the fused
+   program verifier-clean and the collective fingerprint unchanged;
 3. keep loss parity fused-vs-unfused within float tolerance over
    several SGD steps (same params, same per-step seeds);
 4. with ``FLAGS_fusion_autotune`` on, record measured verdicts, persist
@@ -96,15 +96,14 @@ def main():
         for c in rep["candidates"]:
             if c["verdict"] == "applied":
                 by[c["pattern"]] = by.get(c["pattern"], 0) + 1
-        assert by.get("conv_bn_relu", 0) >= 1, rep
         assert by.get("dense_epilogue", 0) >= 1, rep
         assert rep["collective_fingerprint_ok"], rep
         from paddle_tpu.analysis import verify_program
         post = verify_program(fused_prog, (loss.name,))
         assert post.ok, post.diagnostics
         types = [op.type for op in fused_prog.global_block().ops]
-        assert "fused_conv1x1_bn" in types and \
-            "fused_dense_act" in types, types
+        assert "fused_dense_act" in types and "conv2d" in types and \
+            "batch_norm" in types, types
         print(f"gate 2 OK: applied={rep['applied']} ({by}), "
               "verifier clean, collective fingerprint unchanged")
 
