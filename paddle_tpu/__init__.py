@@ -15,7 +15,8 @@ from .clip import (GradientClipByGlobalNorm, GradientClipByNorm,  # noqa
 from .compiler import BuildStrategy, CompiledProgram, ExecutionStrategy  # noqa
 from .framework import (Program, Variable, append_backward,  # noqa
                         default_main_program, default_startup_program,
-                        global_scope, gradients, program_guard, scope_guard,
+                        global_scope, gradients, name_scope, program_guard,
+                        scope_guard,
                         Scope)
 from .framework.executor import Executor  # noqa
 from . import optimizer  # noqa

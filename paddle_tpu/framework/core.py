@@ -192,6 +192,9 @@ class Operator:
         role = getattr(block.program, "_current_role", None) if block else None
         if role is not None and "op_role" not in self.attrs:
             self.attrs["op_role"] = role
+        tag = getattr(block.program, "_name_scope", None) if block else None
+        if tag and "name_scope" not in self.attrs:
+            self.attrs["name_scope"] = tag
 
     def input(self, slot) -> List[str]:
         return self.inputs.get(slot, [])
@@ -369,6 +372,24 @@ class Program:
                 yield
             finally:
                 self._current_role = prev
+        return guard()
+
+    def _name_scope_guard(self, tag: str):
+        """Ops created inside carry attrs['name_scope']=tag (nested guards
+        join with '.'), and their grad ops with them: the executor lowers
+        such an op under ``pt.<role>/<op type>/<tag>``, so that a device
+        trace tells one part of a model from another that is built of the
+        same op types."""
+        import contextlib
+
+        @contextlib.contextmanager
+        def guard():
+            prev = getattr(self, "_name_scope", None)
+            self._name_scope = f"{prev}.{tag}" if prev else tag
+            try:
+                yield
+            finally:
+                self._name_scope = prev
         return guard()
 
     # -- blocks --------------------------------------------------------------
@@ -583,6 +604,13 @@ def switch_startup_program(p: Program) -> Program:
     global _startup_program
     old, _startup_program = _startup_program, p
     return old
+
+
+def name_scope(tag: str):
+    """``with name_scope("shared_expert"):`` — the ops the layer DSL appends
+    to the default main program inside carry ``attrs["name_scope"]`` (ref
+    fluid.name_scope), which the executor adds to their trace scope."""
+    return default_main_program()._name_scope_guard(str(tag))
 
 
 class program_guard:

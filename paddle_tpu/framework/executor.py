@@ -995,7 +995,7 @@ def op_scope(op: Operator) -> str:
     ``cond``), whose scope comes first in the name.  Metadata only: a
     context manager per op while tracing, nothing per step."""
     return "pt.%s/%s" % (_SCOPE_ROLES.get(op.attrs.get("op_role"), "fwd"),
-                         op.type)
+                         op.type) + _name_scope_of(op)
 
 
 def _run_op_inner(ctx, block, op, state) -> None:
@@ -2682,3 +2682,13 @@ def _jit_step(fn, mesh, **kwargs):
     if options is not None:
         kwargs["compiler_options"] = options
     return jax.jit(fn, **kwargs)
+
+
+def _name_scope_of(op: Operator) -> str:
+    """``/<tag>`` for an op built under ``framework.name_scope(tag)`` (and
+    for its grad op, which inherits the attr), else nothing: the part of
+    :func:`op_scope` that tells, say, a shared expert's dense ops from the
+    attention's.  At the end of the file so that no line above moves (the
+    compile-cache key holds them)."""
+    tag = op.attrs.get("name_scope")
+    return "/" + str(tag) if tag else ""

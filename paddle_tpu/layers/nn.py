@@ -284,7 +284,9 @@ def rms_norm(input, begin_norm_axis=1, epsilon=1e-5, param_attr=None,
 def rope(x, head_dim, theta=10000.0, name=None):
     """Rotary position embedding over [batch, T, n * head_dim], before the
     head split: position = index along axis 1, rotate-half pairing
-    (dimension ``i`` of a head with ``i + head_dim / 2``)."""
+    (dimension ``i`` of a head with ``i + head_dim / 2``).  A 4-D input is
+    [batch, heads, T, head_dim], after the split: position = index along
+    axis 2."""
     helper = LayerHelper("rope", name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
     helper.append_op("rope", inputs={"X": [x]}, outputs={"Out": [out]},
@@ -1182,8 +1184,13 @@ def lrn(input, n=5, k=1.0, alpha=1e-4, beta=0.75, name=None):
 
 
 def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
-                    block_q=None, block_k=None, name=None):
+                    block_q=None, block_k=None, name=None, window=None):
     """Fused online-softmax attention over [b, h, T, d] tensors.
+
+    ``window`` (with ``causal``): key ``j`` is visible to query ``i`` iff
+    ``0 <= i - j < window``; the kernels skip the blocks outside the band.
+    K and V may have fewer heads than Q ([b, h_kv, T, d], ``h % h_kv ==
+    0``): query head ``i`` reads KV head ``i // (h // h_kv)`` in the kernel.
 
     TPU-native replacement for the matmul→softmax→matmul chain of the
     reference Transformer recipe (ref dist_transformer.py:1034
@@ -1196,10 +1203,12 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if bias is not None:
         inputs["Bias"] = [bias]
+    attrs = {"causal": causal, "sm_scale": sm_scale or 0.0,
+             "block_q": block_q or 0, "block_k": block_k or 0}
+    if window:
+        attrs["window"] = int(window)
     helper.append_op("flash_attention", inputs=inputs,
-                     outputs={"Out": [out]},
-                     attrs={"causal": causal, "sm_scale": sm_scale or 0.0,
-                            "block_q": block_q or 0, "block_k": block_k or 0})
+                     outputs={"Out": [out]}, attrs=attrs)
     return out
 
 
@@ -1598,7 +1607,9 @@ def switch_moe_ffn(x, num_experts, d_inner, capacity_factor=1.25,
 
 
 def moe_ffn(x, num_experts, top_k, d_expert, norm_topk_prob=False,
-            param_prefix="moe", initializer=None, name=None):
+            param_prefix="moe", initializer=None, name=None,
+            score_func="softmax", select_bias=False, norm_eps=0.0,
+            route_scale=1.0, num_held=None, expert_offset=0):
     """Dropless top-k mixture of gated-SiLU experts over [b, t, d] input
     (``moe_ffn`` op: sorted rows + grouped matmuls, no capacity, no dropped
     token).  Returns ``(out, lb_loss, z_loss, expert_load)``: the
@@ -1608,11 +1619,21 @@ def moe_ffn(x, num_experts, top_k, d_expert, norm_topk_prob=False,
     The op's fifth output, ``TopExperts`` [b, t, k] (each token's experts),
     is a variable of the block for whoever wants to fetch it
     (``op.outputs["TopExperts"]``).  No bias anywhere.  Expert weights carry dist_spec ("ep", ...) like
-    ``switch_moe_ffn``'s."""
+    ``switch_moe_ffn``'s.
+
+    Routing options (``ops/moe_ops.py``): ``score_func`` ``softmax`` |
+    ``sigmoid``; ``select_bias=True`` creates ``<prefix>.select_bias``
+    [num_experts], zero, not trainable, added to the scores for the choice
+    of experts only; ``norm_eps`` joins the renormalising sum;
+    ``route_scale`` multiplies the weights.  ``num_held`` (default all):
+    the expert weights are [num_held, ...] and hold experts
+    ``expert_offset .. expert_offset + num_held - 1`` of the ``num_experts``
+    the router scores; the output is their part of the layer's."""
     from ..param_attr import ParamAttr
     helper = LayerHelper("moe_ffn", name=name)
     d = int(x.shape[-1])
     E, F = int(num_experts), int(d_expert)
+    H = E if num_held is None else int(num_held)
 
     def _p(suffix, shape, ep_spec):
         v = helper.create_parameter(
@@ -1623,9 +1644,14 @@ def moe_ffn(x, num_experts, top_k, d_expert, norm_topk_prob=False,
 
     ep = ("ep", None, None)
     inputs = {"X": [x], "RouterW": [_p("router.w", [d, E], None)],
-              "GateW": [_p("gate.w", [E, d, F], ep)],
-              "UpW": [_p("up.w", [E, d, F], ep)],
-              "DownW": [_p("down.w", [E, F, d], ep)]}
+              "GateW": [_p("gate.w", [H, d, F], ep)],
+              "UpW": [_p("up.w", [H, d, F], ep)],
+              "DownW": [_p("down.w", [H, F, d], ep)]}
+    if select_bias:
+        inputs["SelectBias"] = [helper.create_parameter(
+            ParamAttr(name=f"{param_prefix}.select_bias",
+                      initializer=ConstantInitializer(0.0), trainable=False),
+            [E], "float32")]
     out = helper.create_variable_for_type_inference(x.dtype)
     lb = helper.create_variable_for_type_inference("float32")
     z = helper.create_variable_for_type_inference("float32")
@@ -1635,9 +1661,18 @@ def moe_ffn(x, num_experts, top_k, d_expert, norm_topk_prob=False,
     # projections, the experts' output
     saved = [helper.create_variable_for_type_inference(t, True)
              for t in ("int32", x.dtype, x.dtype, x.dtype, x.dtype)]
+    attrs = {"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob)}
+    if score_func != "softmax":
+        attrs["score_func"] = str(score_func)
+    if norm_eps:
+        attrs["norm_eps"] = float(norm_eps)
+    if route_scale != 1.0:
+        attrs["route_scale"] = float(route_scale)
+    if expert_offset:
+        attrs["expert_offset"] = int(expert_offset)
     helper.append_op(
         "moe_ffn", inputs=inputs,
         outputs={"Out": [out], "LbLoss": [lb], "ZLoss": [z],
                  "ExpertLoad": [load], "TopExperts": [top], "Saved": saved},
-        attrs={"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob)})
+        attrs=attrs)
     return out, lb, z, load

@@ -17,6 +17,7 @@ from __future__ import annotations
 import numpy as np
 
 from .. import layers
+from ..framework import name_scope
 from ..param_attr import ParamAttr
 
 
@@ -24,7 +25,8 @@ def multi_head_attention(queries, keys, values, d_model, n_head,
                          dropout_rate=0.0, attn_bias=None, is_test=False,
                          param_prefix="attn", attn_impl="base",
                          causal=False, bias=True, n_kv_head=None,
-                         qk_hook=None):
+                         qk_hook=None, d_head=None, window=None,
+                         out_gate=False, head_hook=None):
     """ref dist_transformer.py:958 multi_head_attention.
 
     attn_impl: "base" (matmul→softmax→matmul chain, ref recipe),
@@ -38,9 +40,22 @@ def multi_head_attention(queries, keys, values, d_model, n_head,
     ``n_head``): K and V are projected to that many heads and each is
     shared by ``n_head // n_kv_head`` query heads.  ``qk_hook(q, k) ->
     (q, k)`` runs on the projected [b, t, heads * d_head] tensors before
-    the head split (QK-norm, rotary embedding).
+    the head split (QK-norm over the whole projection, rotary embedding).
+
+    ``d_head`` (default ``d_model // n_head``): the heads' width where the
+    model gives it apart from ``d_model``; Q, the gate and the attention
+    output are then ``n_head * d_head`` wide.  ``head_hook(q, k) -> (q, k)``
+    runs after the head split, on [b, n_head, t, d_head] and [b, n_kv_head,
+    t, d_head] (a QK-norm per head, weight ``[d_head]``, and the rotary
+    embedding after it).  ``window`` (flash, causal): key ``j`` is visible
+    to query ``i`` iff ``0 <= i - j < window``.  ``out_gate=True``: a
+    fourth slice ``[d_model, n_head * d_head]`` of the fused projection
+    gates the attention output, ``ctx * sigmoid(gate)``, before the output
+    projection (self-attention only).  On the flash path K and V reach the
+    kernel at their ``n_kv_head`` heads; the other paths expand them.
     """
-    d_head = d_model // n_head
+    d_head = d_head or d_model // n_head
+    d_q = n_head * d_head
     n_kv_head = n_kv_head or n_head
     d_kv = n_kv_head * d_head
     if attn_impl == "auto":
@@ -54,35 +69,51 @@ def multi_head_attention(queries, keys, values, d_model, n_head,
                          bias_attr=ParamAttr(name=f"{param_prefix}.{name}.b")
                          if bias else False)
 
+    gate = None
     if queries is keys and keys is values:
         # self-attention: one fused QKV projection — bigger MXU tile, one
         # HBM read of the activations instead of three
-        qkv = _proj(queries, d_model + 2 * d_kv, "qkv")
-        q, k, v = layers.split(
-            qkv, 3 if d_kv == d_model else [d_model, d_kv, d_kv], dim=2)
+        if out_gate:
+            q, k, v, gate = layers.split(
+                _proj(queries, 2 * d_q + 2 * d_kv, "qkv"),
+                [d_q, d_kv, d_kv, d_q], dim=2)
+        else:
+            qkv = _proj(queries, d_q + 2 * d_kv, "qkv")
+            q, k, v = layers.split(
+                qkv, 3 if d_kv == d_q else [d_q, d_kv, d_kv], dim=2)
     else:
-        q = _proj(queries, d_model, "q")
+        assert not out_gate, "out_gate= is for self-attention"
+        q = _proj(queries, d_q, "q")
         k = _proj(keys, d_kv, "k")
         v = _proj(values, d_kv, "v")
     if qk_hook is not None:
         q, k = qk_hook(q, k)
+    # the flash kernels read grouped K/V heads through their index maps
+    grouped = attn_impl == "flash" and n_kv_head != n_head
+    assert window is None or attn_impl == "flash", "window= needs flash"
 
     def _split_heads(x, heads=n_head):
         # [b, t, d] -> [b, h, t, dh]
         y = layers.reshape(x, shape=[0, 0, heads, d_head])
-        y = layers.transpose(y, perm=[0, 2, 1, 3])
-        if heads != n_head:
-            # each K/V head serves n_head // heads query heads
-            rep = n_head // heads
-            y = layers.expand(layers.unsqueeze(y, [2]), [1, 1, rep, 1, 1])
-            y = layers.reshape(y, shape=[0, n_head, -1, d_head])
-        return y
+        return layers.transpose(y, perm=[0, 2, 1, 3])
 
-    q = _split_heads(q)
-    k, v = _split_heads(k, n_kv_head), _split_heads(v, n_kv_head)
+    def _expand_kv(y):
+        # each K/V head serves n_head // n_kv_head query heads
+        if n_kv_head == n_head or grouped:
+            return y
+        rep = n_head // n_kv_head
+        y = layers.expand(layers.unsqueeze(y, [2]), [1, 1, rep, 1, 1])
+        return layers.reshape(y, shape=[0, n_head, -1, d_head])
+
+    q, k = _split_heads(q), _split_heads(k, n_kv_head)
+    if head_hook is not None:
+        q, k = head_hook(q, k)
+    k = _expand_kv(k)
+    v = _expand_kv(_split_heads(v, n_kv_head))
     if attn_impl == "flash":
         ctx = layers.flash_attention(q, k, v, bias=attn_bias, causal=causal,
-                                     sm_scale=float(d_head) ** -0.5)
+                                     sm_scale=float(d_head) ** -0.5,
+                                     window=window)
     elif attn_impl == "ring":
         assert attn_bias is None, "ring attention supports causal= only"
         ctx = layers.ring_attention(q, k, v, causal=causal,
@@ -110,7 +141,9 @@ def multi_head_attention(queries, keys, values, d_model, n_head,
                 dropout_implementation="upscale_in_train")
         ctx = layers.matmul(weights, v)                   # [b, h, t, dh]
     ctx = layers.transpose(ctx, perm=[0, 2, 1, 3])
-    ctx = layers.reshape(ctx, shape=[0, 0, d_model])
+    ctx = layers.reshape(ctx, shape=[0, 0, d_q])
+    if gate is not None:
+        ctx = ctx * layers.sigmoid(gate)
     return layers.fc(ctx, size=d_model, num_flatten_dims=2,
                      param_attr=ParamAttr(name=f"{param_prefix}.out.w"),
                      bias_attr=ParamAttr(name=f"{param_prefix}.out.b")
@@ -418,6 +451,155 @@ def build_olmoe_pretrain(cfg: OlmoeConfig, seq_len, is_test=False,
     loss = ce + cfg.lb_coef * lb + cfg.z_coef * z
     return (src_ids, lm_label), {"ce": ce, "lb": lb, "z": z,
                                  "expert_load": loads, "hidden": x}, loss
+
+
+# -- Trinity (afmoe): window and full attention mixed, gated, per-head -------
+# -- QK-norm, grouped-query; sigmoid routing beside a shared expert ----------
+
+class TrinityConfig:
+    """Trinity-Mini defaults (``arcee-ai/Trinity-Mini`` config.json,
+    ``model_type`` ``afmoe``).  ``layer_types[i]`` is ``sliding_attention``
+    or ``full_attention``; the first ``n_dense_layer`` layers have a dense
+    gated FFN of width ``d_inner``, the others ``n_experts`` routed experts
+    of width ``d_expert`` (``top_k`` a token) beside one shared expert of
+    width ``d_expert * n_shared``.  ``n_held``/``expert_offset``: the
+    experts whose weights this program holds (default all): a chip's share
+    under expert parallelism, see ``ops/moe_ops.py``."""
+
+    def __init__(self, vocab_size=200192, d_model=2048, n_layer=32,
+                 n_head=32, n_kv_head=4, d_head=128, d_inner=6144,
+                 d_expert=1024, n_experts=128, top_k=8, n_shared=1,
+                 n_dense_layer=2, layer_types=None, window=2048,
+                 score_func="sigmoid", route_norm=True, route_scale=2.826,
+                 rms_eps=1e-5, rope_theta=10000.0, mup=True, n_held=None,
+                 expert_offset=0, init_std=0.02):
+        self.vocab_size = vocab_size
+        self.d_model = d_model
+        self.n_layer = n_layer
+        self.n_head = n_head
+        self.n_kv_head = n_kv_head
+        self.d_head = d_head
+        self.d_inner = d_inner
+        self.d_expert = d_expert
+        self.n_experts = n_experts
+        self.top_k = top_k
+        self.n_shared = n_shared
+        self.n_dense_layer = n_dense_layer
+        self.layer_types = list(layer_types) if layer_types else [
+            "full_attention" if i % 4 == 3 else "sliding_attention"
+            for i in range(n_layer)]
+        assert len(self.layer_types) == n_layer
+        self.window = window
+        self.score_func = score_func
+        self.route_norm = route_norm
+        self.route_scale = route_scale
+        self.rms_eps = rms_eps
+        self.rope_theta = rope_theta
+        self.mup = mup
+        self.n_held = n_experts if n_held is None else n_held
+        self.expert_offset = expert_offset
+        self.init_std = init_std
+
+
+def gated_ffn(x, d_inner, d_model, param_prefix="ffn"):
+    """``down(silu(gate(x)) * up(x))`` out of the dense ops, no bias: gate
+    and up are one fused ``[d_model, 2 * d_inner]`` projection
+    (``<prefix>.gate_up.w``, gate first), down is ``<prefix>.down.w``."""
+    gu = layers.fc(x, size=2 * d_inner, num_flatten_dims=2, bias_attr=False,
+                   param_attr=ParamAttr(name=f"{param_prefix}.gate_up.w"))
+    g, u = layers.split(gu, 2, dim=2)
+    return layers.fc(layers.swish(g) * u, size=d_model, num_flatten_dims=2,
+                     bias_attr=False,
+                     param_attr=ParamAttr(name=f"{param_prefix}.down.w"))
+
+
+def trinity_decoder_layer(x, cfg: TrinityConfig, idx=0, attn_impl="flash",
+                          is_test=False):
+    """One afmoe block, four norms: ``h = x + RMS2(Attn(RMS1(x)))``, ``out =
+    h + RMS4(FFN(RMS3(h)))``; no bias anywhere.  Attention: grouped-query,
+    Q and K RMS-normed per head (weights ``[d_head]``), rotary on
+    ``sliding_attention`` layers only (``full_attention`` layers carry no
+    positional term), a ``window`` on the sliding layers, and the output
+    gated by ``sigmoid`` of a fourth slice of the fused projection.  FFN:
+    :func:`gated_ffn` in the first ``n_dense_layer`` layers; else the
+    shared expert (the same builder) plus ``moe_ffn`` with sigmoid scores,
+    a selection bias held at zero, the kept scores renormalised (``+
+    1e-20``) and scaled.  Returns ``(out, expert_load or None)``."""
+    from ..initializer import NormalInitializer
+    p = f"dec_{idx}"
+    sliding = cfg.layer_types[idx] == "sliding_attention"
+
+    def norm(v, name, axis=2):
+        return layers.rms_norm(v, begin_norm_axis=axis, epsilon=cfg.rms_eps,
+                               param_attr=ParamAttr(name=name))
+
+    def head_hook(q, k):
+        q = norm(q, f"{p}.attn.q_norm.w", 3)
+        k = norm(k, f"{p}.attn.k_norm.w", 3)
+        if sliding:
+            q = layers.rope(q, cfg.d_head, cfg.rope_theta)
+            k = layers.rope(k, cfg.d_head, cfg.rope_theta)
+        return q, k
+
+    n = norm(x, f"{p}.ln1.w")
+    attn = multi_head_attention(
+        n, n, n, cfg.d_model, cfg.n_head, is_test=is_test,
+        param_prefix=f"{p}.attn", attn_impl=attn_impl, causal=True,
+        bias=False, n_kv_head=cfg.n_kv_head, d_head=cfg.d_head,
+        window=cfg.window if sliding else None, out_gate=True,
+        head_hook=head_hook)
+    h = x + norm(attn, f"{p}.ln2.w")
+    m = norm(h, f"{p}.ln3.w")
+    load = None
+    if idx < cfg.n_dense_layer:
+        with name_scope("dense_ffn"):
+            f = gated_ffn(m, cfg.d_inner, cfg.d_model, f"{p}.ffn")
+    else:
+        with name_scope("shared_expert"):
+            f = gated_ffn(m, cfg.d_expert * cfg.n_shared, cfg.d_model,
+                          f"{p}.shared")
+        moe, _, _, load = layers.moe_ffn(
+            m, cfg.n_experts, cfg.top_k, cfg.d_expert,
+            norm_topk_prob=cfg.route_norm, param_prefix=f"{p}.moe",
+            initializer=NormalInitializer(0.0, cfg.init_std),
+            score_func=cfg.score_func, select_bias=True, norm_eps=1e-20,
+            route_scale=cfg.route_scale, num_held=cfg.n_held,
+            expert_offset=cfg.expert_offset)
+        f = f + moe
+    return h + norm(f, f"{p}.ln4.w"), load
+
+
+def build_trinity_pretrain(cfg: TrinityConfig, seq_len, is_test=False,
+                           attn_impl="flash", fused_head=True,
+                           checkpoints=None):
+    """Causal LM over afmoe blocks: ids -> embedding scaled by
+    ``sqrt(d_model)`` (``mup``) -> ``n_layer`` :func:`trinity_decoder_layer`
+    -> final RMSNorm -> untied bias-free head; loss = mean next-token CE
+    (label 0 excluded, as in the other builders) and nothing else: the
+    published recipe balances load through the selection bias, which is a
+    persistent variable held at its initial zero here, not through a loss
+    term.  ``checkpoints=[]`` collects the block outputs for
+    ``RecomputeOptimizer``.  Returns ``(feeds, parts, loss)`` with ``parts``
+    = {"expert_load": [per expert layer], "hidden": the final norm's
+    output}."""
+    src_ids = layers.data("src_ids", shape=[seq_len], dtype="int64")
+    lm_label = layers.data("lm_label", shape=[seq_len], dtype="int64")
+    x = layers.embedding(src_ids, size=[cfg.vocab_size, cfg.d_model],
+                         param_attr=ParamAttr(name="word_embedding"))
+    if cfg.mup:
+        x = layers.scale(x, scale=float(cfg.d_model) ** 0.5)
+    loads = []
+    for i in range(cfg.n_layer):
+        x, load = trinity_decoder_layer(x, cfg, i, attn_impl, is_test)
+        if load is not None:
+            loads.append(load)
+        if checkpoints is not None:
+            checkpoints.append(x)
+    x = layers.rms_norm(x, begin_norm_axis=2, epsilon=cfg.rms_eps,
+                        param_attr=ParamAttr(name="final_norm.w"))
+    _, loss = _lm_head_loss(x, cfg, lm_label, fused_head, "lm_out",
+                            bias=False)
+    return (src_ids, lm_label), {"expert_load": loads, "hidden": x}, loss
 
 
 def annotate_tensor_parallel(program=None):

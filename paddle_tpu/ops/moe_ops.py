@@ -6,9 +6,33 @@ into that order, each expert's rows multiplied as one group of a grouped
 matmul whose group sizes are data, and the results un-sorted.  Every shape is
 static, whatever the routing; no one-hot ``[S, E, C]`` dispatch tensor.
 
-- ``moe_ffn``: dropless top-k over gated-SiLU experts (OLMoE, Mixtral).
+- ``moe_ffn``: dropless top-k over gated-SiLU experts (OLMoE, Mixtral; with
+  ``score_func="sigmoid"``, a selection bias, renormalised and scaled
+  weights and a share of the experts, the DeepSeek-V3 / Trinity layer).
 - ``switch_ffn``: Switch-Transformer top-1 with biases and a capacity, which
   here is a cap on the rows of a group that count, not a tensor dimension.
+
+``moe_ffn``'s routing attributes (all optional; the defaults are the op as it
+was before they existed, bit for bit):
+
+- ``score_func``: ``softmax`` (over all experts) or ``sigmoid`` (each expert
+  by itself).  Either way ``_router`` computes logits, scores, top-k and
+  weights in float32 with the matmul at ``highest``, whatever AMP says.
+- input ``SelectBias`` [E_total]: added to the scores for the choice of the
+  ``k`` experts only; the weights are the unbiased scores.  Not
+  differentiated (the choice is not, and nothing else reads it).
+- ``norm_topk_prob`` with ``norm_eps``: the kept scores divided by their sum
+  plus ``norm_eps`` (published 1e-20 for sigmoid routers); ``route_scale``
+  multiplies the weights after that.
+- ``expert_offset``: the router stays ``[d, E_total]``, the expert weights
+  are ``[E_here, d, f]`` and hold experts ``offset .. offset + E_here - 1``.
+  The op computes ``sum_{e in top-k, offset <= e < offset + E_here} w_e *
+  expert_e(x)`` with ``w`` normalised over all ``k`` chosen: this chip's
+  part of the layer.  Only the slots routed to held experts are multiplied;
+  the sort puts them first, and the static row buffer is ``S * min(k,
+  E_here)`` rows, the most a routing can send here, so the held experts drop
+  nothing whatever the load.  ``ExpertLoad`` stays ``[E_total]``.  Nothing
+  stands in for the absent experts or their exchange.
 
 The layers annotate the expert weights with dist_spec ``("ep", ...)``.
 """
@@ -114,13 +138,41 @@ MOE_LOWERINGS_CTR = _monitor.REGISTRY.counter(
     "moe_ffn forward lowerings by the implementation of the expert matmuls, "
     "the number of experts and the experts per token — counted while "
     "tracing, once per compile of a block that holds the op, nothing per "
-    "step", ("impl", "experts", "top_k"))
+    "step; held = the experts whose weights the op holds, score_func = the "
+    "router's score", ("impl", "experts", "top_k", "held", "score_func"))
+
+
+MOE_ROUTED_ROWS_CTR = _monitor.REGISTRY.counter(
+    "paddle_tpu_moe_routed_rows_total",
+    "routed slots (tokens x experts per token) of the ExpertLoad outputs "
+    "handed to record_expert_load: where=all, every slot; where=held, those "
+    "that chose an expert whose weights the op holds.  Counted on the host "
+    "by whoever fetched the loads, nothing per step", ("where",))
+
+
+def record_expert_load(load, expert_offset=0, n_held=None):
+    """Add one fetched ``ExpertLoad`` [E_total] (a host array) to
+    ``paddle_tpu_moe_routed_rows_total``; ``n_held`` defaults to all."""
+    load = np.asarray(load).reshape(-1)
+    n_held = load.size if n_held is None else int(n_held)
+    MOE_ROUTED_ROWS_CTR.inc(int(load.sum()), where="all")
+    MOE_ROUTED_ROWS_CTR.inc(
+        int(load[expert_offset:expert_offset + n_held].sum()), where="held")
 
 
 #: megablox tile sizes (rows, contraction, columns) for the bf16 expert
 #: matmuls on a TPU; swept on a v5e at the OLMoE shapes
 #: (tools/olmoe_kernel_sweep.py, PERF.md)
 _GMM_TILING = (512, 1024, 1024)
+
+
+#: the same for a chip's share of the experts (``expert_offset``), whose
+#: groups are the few hundred rows of one sequence's slots and not
+#: thousands: megablox visits a row tile once for each group that touches it,
+#: so at 512 rows half the visits are of tiles that straddle two groups;
+#: measured in the Trinity-Mini step and kernel by kernel on a v5e
+#: (tools/trinity_experts_sweep.py, PERF.md)
+_GMM_TILING_HELD = (256, 1024, 1024)
 
 
 def _experts_impl(dt):
@@ -172,21 +224,64 @@ def gated_experts(xs, wg, wu, wd, load, dt, impl=None, tiling=None):
     return mm(_gate(g, u, dt), wd, load), g, u
 
 
-def _router(xt, wr, k, renorm):
-    """Float32 at full precision whatever AMP says: ``(top_p [S, k], lb [],
-    z [])`` and, not differentiated, ``(top_e [S, k], load [E])``."""
+def _router(xt, wr, k, renorm, score_func="softmax", bias=None,
+            norm_eps=0.0, scale=1.0):
+    """Float32 at full precision whatever AMP says, softmax or sigmoid:
+    ``(top_p [S, k], lb [], z [])`` and, not differentiated, ``(top_e
+    [S, k], load [E])``.  ``bias`` [E] moves the choice of the ``k`` only;
+    ``top_p`` are the unbiased scores of the chosen, renormalised (``/ (sum
+    + norm_eps)``) and scaled if asked.  Under ``sigmoid`` the
+    load-balancing loss reads the scores normalised over the experts."""
     f32 = jnp.float32
     S, E = xt.shape[0], wr.shape[-1]
     logits = jnp.dot(xt.astype(f32), wr.astype(f32),
                      precision=jax.lax.Precision.HIGHEST)           # [S, E]
-    p = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(p, k)
+    if score_func == "sigmoid":
+        p = jax.nn.sigmoid(logits)
+    elif score_func == "softmax":
+        p = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"moe_ffn score_func {score_func!r}")
+    if bias is None:
+        top_p, top_e = jax.lax.top_k(p, k)
+    else:
+        _, top_e = jax.lax.top_k(
+            p + jax.lax.stop_gradient(bias.astype(f32))[None, :], k)
+        top_p = jnp.take_along_axis(p, top_e, axis=-1)
     if renorm:
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        denom = jnp.sum(top_p, axis=-1, keepdims=True)
+        top_p = top_p / (denom + norm_eps if norm_eps else denom)
+    if scale != 1.0:
+        top_p = top_p * scale
     load = _expert_load(top_e.reshape(S * k), E)
+    if score_func == "sigmoid":
+        p = p / jnp.sum(p, axis=-1, keepdims=True)
     lb = E * jnp.sum(load.astype(f32) / S * jnp.mean(p, axis=0))
     z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
     return (top_p, lb, z), (top_e, load)
+
+
+def _router_of(attrs, k, bias):
+    """``(xt, wr) -> _router(...)`` with the op's routing attributes."""
+    kw = dict(renorm=bool(attrs.get("norm_topk_prob", False)),
+              score_func=attrs.get("score_func", "softmax") or "softmax",
+              bias=bias, norm_eps=float(attrs.get("norm_eps", 0.0) or 0.0),
+              scale=float(attrs.get("route_scale", 1.0) or 1.0))
+    return lambda xt, wr: _router(xt, wr, k, **kw)
+
+
+def _held_slots(top_e, offset, n_held, k):
+    """For a chip that holds experts ``offset .. offset + n_held - 1`` of a
+    wider router: ``held`` [R] bool (the slot's expert lives here), ``order``
+    [R] (the slots sorted by local expert, stable, the slots of absent
+    experts last), ``place`` [R] its inverse, and ``rows``, the static
+    length of the row buffer: ``S * min(k, n_held)``, the most slots a
+    routing can send here (a token's ``k`` experts are distinct)."""
+    S = top_e.shape[0]
+    local = top_e.reshape(S * k) - offset
+    held = (local >= 0) & (local < n_held)
+    order, place = _sorted_slots(jnp.where(held, local, n_held))
+    return held, order, place, S * min(k, n_held)
 
 
 def _moe_dtype(ctx, x):
@@ -211,6 +306,12 @@ def _moe_ffn(ctx, ins, attrs):
     Saved: what ``moe_ffn_grad`` reuses (the sort order, the sorted rows, the
     two projections and the experts' output).
 
+    Optional input SelectBias [E]; attributes ``score_func``, ``norm_eps``,
+    ``route_scale`` and ``expert_offset`` as the module docstring says.  With
+    fewer expert weights than router outputs (GateW [E_here, d, f]) the op
+    computes the part of ``Out`` that experts ``expert_offset .. + E_here -
+    1`` give; ExpertLoad and TopExperts stay over all ``E``.
+
     Four parts, each under its own scope for the device trace: ``router``
     (float32 at full precision, whatever AMP says), ``dispatch`` (stable sort
     of the ``S*k`` slot -> expert ids, one row gather), ``experts`` (three
@@ -222,28 +323,53 @@ def _moe_ffn(ctx, ins, attrs):
     wg, wu, wd = X(ins, "GateW"), X(ins, "UpW"), X(ins, "DownW")
     k = int(attrs["top_k"])
     B, T, d = x.shape
-    E = wr.shape[-1]
+    E, n_held = wr.shape[-1], wg.shape[0]
+    offset = int(attrs.get("expert_offset", 0) or 0)
+    if offset < 0 or offset + n_held > E:
+        raise ValueError(f"moe_ffn holds experts {offset}..{offset + n_held}"
+                         f" of a router over {E}")
     S = B * T
     dt = _moe_dtype(ctx, x)
     if not getattr(ctx, "is_abstract", False):
-        MOE_LOWERINGS_CTR.inc(impl=_experts_impl(dt), experts=str(E),
-                              top_k=str(k))
+        MOE_LOWERINGS_CTR.inc(
+            impl=_experts_impl(dt), experts=str(E), top_k=str(k),
+            held=str(n_held),
+            score_func=attrs.get("score_func", "softmax") or "softmax")
     xt = x.reshape(S, d)
 
     with jax.named_scope("router"):
-        (top_p, lb, z), (top_e, load) = _router(
-            xt, wr, k, bool(attrs.get("norm_topk_prob", False)))
+        (top_p, lb, z), (top_e, load) = _router_of(
+            attrs, k, X(ins, "SelectBias"))(xt, wr)
 
-    with jax.named_scope("dispatch"):
-        order, place = _sorted_slots(top_e.reshape(S * k))
-        xs = jnp.take(xt.astype(dt), order // k, axis=0)
+    if n_held == E:
+        with jax.named_scope("dispatch"):
+            order, place = _sorted_slots(top_e.reshape(S * k))
+            xs = jnp.take(xt.astype(dt), order // k, axis=0)
 
-    with jax.named_scope("experts"):
-        y, g, u = gated_experts(xs, wg, wu, wd, load, dt)
+        with jax.named_scope("experts"):
+            y, g, u = gated_experts(xs, wg, wu, wd, load, dt)
 
-    with jax.named_scope("combine"):
-        ys = jnp.take(y, place, axis=0).reshape(S, k, d)
-        out = jnp.sum(ys.astype(jnp.float32) * top_p[:, :, None], axis=1)
+        with jax.named_scope("combine"):
+            ys = jnp.take(y, place, axis=0).reshape(S, k, d)
+            out = jnp.sum(ys.astype(jnp.float32) * top_p[:, :, None], axis=1)
+    else:
+        with jax.named_scope("dispatch"):
+            held, order, place, rows = _held_slots(top_e, offset, n_held, k)
+            xs = jnp.take(xt.astype(dt), order[:rows] // k, axis=0)
+
+        with jax.named_scope("experts"):
+            # the grouped matmuls visit the held run lengths' rows and no
+            # other: what lies behind them in the buffer is never multiplied
+            # (and never written, so never read unmasked below)
+            y, g, u = gated_experts(
+                xs, wg, wu, wd,
+                jax.lax.dynamic_slice_in_dim(load, offset, n_held), dt,
+                tiling=_GMM_TILING_HELD)
+
+        with jax.named_scope("combine"):
+            ys = jnp.take(y, jnp.minimum(place, rows - 1), axis=0)
+            ys = jnp.where(held[:, None], ys.astype(jnp.float32), 0.0)
+            out = jnp.sum(ys.reshape(S, k, d) * top_p[:, :, None], axis=1)
     return {"Out": [out.astype(x.dtype).reshape(B, T, d)], "LbLoss": [lb],
             "ZLoss": [z], "ExpertLoad": [load],
             "TopExperts": [top_e.astype(jnp.int32).reshape(B, T, k)],
@@ -255,6 +381,8 @@ def _moe_ffn_grad_maker(op, block, no_grad_set):
         return [grad_var_name(n) for n in names]
     slots = ("X", "RouterW", "GateW", "UpW", "DownW")
     g_inputs = {"X$" + s: op.input(s) for s in slots}
+    if op.input("SelectBias"):
+        g_inputs["X$SelectBias"] = op.input("SelectBias")
     g_inputs["Saved"] = op.output("Saved")
     for s in ("Out", "LbLoss", "ZLoss"):
         g_inputs["OG$" + s] = grads(op.output(s))
@@ -286,22 +414,44 @@ def _moe_ffn_grad(ctx, ins, attrs):
     B, T, d = x.shape
     S, R = B * T, B * T * k
     f32, dt = jnp.float32, xs.dtype
-    mm = _grouped_matmul(dt)
     xt = x.reshape(S, d)
+    E, n_held = wr.shape[-1], weights[0].shape[0]
+    mm = _grouped_matmul(dt, tiling=None if n_held == E else _GMM_TILING_HELD)
+    offset = int(attrs.get("expert_offset", 0) or 0)
 
     with jax.named_scope("router"):
-        (top_p, _, _), router_vjp, (_, load) = jax.vjp(
-            lambda xt, wr: _router(xt, wr, k,
-                                   bool(attrs.get("norm_topk_prob", False))),
-            xt, wr, has_aux=True)
+        (top_p, _, _), router_vjp, (top_e, load) = jax.vjp(
+            _router_of(attrs, k, X(ins, "X$SelectBias")), xt, wr,
+            has_aux=True)
 
-    with jax.named_scope("combine"):
-        place = _inverse_permutation(order)
-        d_rows = jnp.zeros((R, d), f32) if d_out is None else jnp.take(
-            d_out.reshape(S, d), order // k, axis=0).astype(f32)
-        d_top_p = jnp.take(jnp.sum(d_rows * y.astype(f32), axis=-1),
-                           place).reshape(S, k)
-        dy = (d_rows * jnp.take(top_p.reshape(R), order)[:, None]).astype(dt)
+    if n_held == E:
+        with jax.named_scope("combine"):
+            place = _inverse_permutation(order)
+            d_rows = jnp.zeros((R, d), f32) if d_out is None else jnp.take(
+                d_out.reshape(S, d), order // k, axis=0).astype(f32)
+            d_top_p = jnp.take(jnp.sum(d_rows * y.astype(f32), axis=-1),
+                               place).reshape(S, k)
+            dy = (d_rows * jnp.take(top_p.reshape(R), order)[:, None]
+                  ).astype(dt)
+    else:
+        # the buffer's first rows are the held slots; what lies behind them
+        # was never written and is masked wherever it is read
+        with jax.named_scope("combine"):
+            rows = xs.shape[0]
+            slot_held = (top_e.reshape(R) >= offset) & \
+                (top_e.reshape(R) < offset + n_held)
+            place = _inverse_permutation(order)
+            order, clipped = order[:rows], jnp.minimum(place, rows - 1)
+            row_held = jnp.take(slot_held, order)[:, None]
+            d_rows = jnp.zeros((rows, d), f32) if d_out is None else \
+                jnp.where(row_held, jnp.take(d_out.reshape(S, d), order // k,
+                                             axis=0).astype(f32), 0.0)
+            d_top_p = jnp.where(slot_held, jnp.take(jnp.sum(
+                d_rows * jnp.where(row_held, y.astype(f32), 0.0), axis=-1),
+                clipped), 0.0).reshape(S, k)
+            dy = (d_rows * jnp.take(top_p.reshape(R), order)[:, None]
+                  ).astype(dt)
+            load = jax.lax.dynamic_slice_in_dim(load, offset, n_held)
 
     def transposed(rows, w, cot):
         return jax.vjp(lambda a, b: mm(a, b, load), rows, w)[1](cot)
@@ -316,8 +466,13 @@ def _moe_ffn_grad(ctx, ins, attrs):
         dxs_u, d_wu = transposed(xs, wu, (dhf * gf * sig).astype(dt))
 
     with jax.named_scope("dispatch"):
-        dx = jnp.take(dxs_g + dxs_u, place, axis=0).reshape(S, k, d) \
-            .astype(f32).sum(axis=1)
+        if n_held == E:
+            dx = jnp.take(dxs_g + dxs_u, place, axis=0).reshape(S, k, d) \
+                .astype(f32).sum(axis=1)
+        else:
+            dxs = jnp.where(row_held, (dxs_g + dxs_u).astype(f32), 0.0)
+            dx = jnp.where(slot_held[:, None], jnp.take(dxs, clipped, axis=0),
+                           0.0).reshape(S, k, d).sum(axis=1)
 
     with jax.named_scope("router"):
         zero = jnp.zeros((), f32)

@@ -29,10 +29,17 @@ NEG_INF = -1e30
 _LANE = 128      # TPU lane width: min last-dim tile
 
 
-def mha_reference(q, k, v, bias=None, causal=False, sm_scale=None):
-    """O(T^2) reference attention (the math the kernel must reproduce)."""
+def mha_reference(q, k, v, bias=None, causal=False, sm_scale=None,
+                  window=None):
+    """O(T^2) reference attention (the math the kernel must reproduce).
+    ``window``: with ``causal``, key ``j`` is visible to query ``i`` iff
+    ``0 <= i - j < window``.  K and V may have fewer heads than Q: query
+    head ``h`` reads KV head ``h // (n_q_heads // n_kv_heads)``."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    if k.shape[1] != q.shape[1]:
+        k = jnp.repeat(k, q.shape[1] // k.shape[1], axis=1)
+        v = jnp.repeat(v, q.shape[1] // v.shape[1], axis=1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * sm_scale
     if bias is not None:
@@ -40,6 +47,9 @@ def mha_reference(q, k, v, bias=None, causal=False, sm_scale=None):
     if causal:
         tq, tk = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((tq, tk), dtype=bool), k=tk - tq)
+        if window is not None:
+            mask = mask & ~jnp.tril(jnp.ones((tq, tk), dtype=bool),
+                                    k=tk - tq - int(window))
         s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)
@@ -51,9 +61,10 @@ def mha_reference(q, k, v, bias=None, causal=False, sm_scale=None):
 # ---------------------------------------------------------------------------
 
 def _pos_mask(iq, ik, block_q, block_k, causal, offset, tq_real, tk_real,
-              transposed=False):
+              transposed=False, window=None):
     """[bq, bk] (or [bk, bq]) validity mask for one block pair: padding
-    bounds + the causal triangle.  Shared by all four kernels."""
+    bounds + the causal triangle + the window's trailing edge.  Shared by
+    all four kernels."""
     import jax.lax as lax
 
     shape = (block_k, block_q) if transposed else (block_q, block_k)
@@ -65,18 +76,23 @@ def _pos_mask(iq, ik, block_q, block_k, causal, offset, tq_real, tk_real,
         mask = mask & (q_pos < tq_real)
     if causal:
         mask = mask & (q_pos + offset >= k_pos)
+    if window is not None:
+        mask = mask & (q_pos + offset - k_pos < window)
     return mask
 
 
 def _block_dispatch(causal, pads, iq, ik, block_q, block_k, offset,
-                    compute, on_dead=None):
+                    compute, on_dead=None, window=None):
     """The shared live/full block ladder (one definition for all four
     kernels): unpadded non-causal blocks take the mask-free path;
     unpadded causal grids run masks only on DIAGONAL blocks (fully-live
     blocks below the diagonal are mask-free, dead blocks above are
-    skipped); any padding falls back to masked-everywhere.  ``compute``
+    skipped); any padding falls back to masked-everywhere.  With a
+    ``window`` (causal only) the band has a second edge: blocks whose every
+    key is ``window`` or more behind every query are dead too, and the mask
+    also runs on the blocks that trailing edge crosses.  ``compute``
     receives masked: bool; ``on_dead`` (optional) must define outputs
-    for skipped causal blocks."""
+    for skipped blocks."""
     from jax.experimental import pallas as pl
 
     if not causal and not pads:
@@ -84,8 +100,16 @@ def _block_dispatch(causal, pads, iq, ik, block_q, block_k, offset,
         return
     if causal:
         live = iq * block_q + block_q - 1 + offset >= ik * block_k
+        if window is not None:
+            # nearest pair of the block: first query, last key
+            live = live & (iq * block_q + offset
+                           - ((ik + 1) * block_k - 1) < window)
         if not pads:
             full = (ik + 1) * block_k - 1 <= iq * block_q + offset
+            if window is not None:
+                # farthest pair: last query, first key
+                full = full & (iq * block_q + block_q - 1 + offset
+                               - ik * block_k < window)
 
             @pl.when(full)
             def _():
@@ -106,9 +130,52 @@ def _block_dispatch(causal, pads, iq, ik, block_q, block_k, offset,
     compute(True)
 
 
+def _kv_head(b, group):
+    """The collapsed K/V row a collapsed query row ``b`` reads: with
+    ``group`` query heads to a KV head, ``[batch * heads]`` collapses so
+    that it is ``b // group``."""
+    return b if group == 1 else b // group
+
+
+def _live_k(i, j, window, block_q, block_k, offset, nk):
+    """The key block grid step (query block ``i``, key step ``j``) names:
+    ``j`` itself, or under a window the nearest block of ``i``'s band, so
+    that the dead steps before and after the band name the block already
+    resident and copy nothing."""
+    if window is None:
+        return j
+    lo = jnp.maximum(i * block_q + offset - (window - 1), 0) // block_k
+    hi = jnp.minimum((i * block_q + block_q - 1 + offset) // block_k, nk - 1)
+    return jnp.clip(j, lo, hi)
+
+
+def _live_q(i, j, window, block_q, block_k, offset, nq):
+    """:func:`_live_k` for the grid that walks query blocks ``i`` inside a
+    key block ``j`` (the split backward's dk/dv pass)."""
+    if window is None:
+        return i
+    lo = jnp.maximum(j * block_k - offset, 0) // block_q
+    hi = jnp.minimum((window + (j + 1) * block_k - 2 - offset) // block_q,
+                     nq - 1)
+    return jnp.clip(i, lo, hi)
+
+
+def _k_spec(block_q, block_k, d, window, group, offset, nk):
+    """The K/V BlockSpec of a (bh, iq, ik) grid: the plain ``(b, j, 0)`` map
+    (lowered as before the arguments existed) unless a window or grouped
+    heads ask for :func:`_live_k` / :func:`_kv_head`."""
+    from jax.experimental import pallas as pl
+    if window is None and group == 1:
+        return pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
+    return pl.BlockSpec(
+        (1, block_k, d), lambda b, i, j: (
+            _kv_head(b, group),
+            _live_k(i, j, window, block_q, block_k, offset, nk), 0))
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
                 acc_sc, m_sc, l_sc, *, sm_scale, causal, block_q, block_k,
-                tk_real, offset, pads):
+                tk_real, offset, pads, window=None):
     """One (bh, iq, ik) grid step of online-softmax attention.
 
     Grid iterates ik innermost (sequentially on TPU), so the VMEM scratch
@@ -145,7 +212,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
             s = s + b_ref[0].astype(jnp.float32)
         if masked:
             s = jnp.where(_pos_mask(iq, ik, block_q, block_k, causal,
-                                    offset, None, tk_real), s, NEG_INF)
+                                    offset, None, tk_real, window=window),
+                          s, NEG_INF)
         m_prev = m_sc[:, :1]                         # (bq, 1)
         l_prev = l_sc[:, :1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -160,7 +228,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
         l_sc[...] = jnp.broadcast_to(l_new, l_sc.shape)
 
     _block_dispatch(causal, pads, iq, ik, block_q, block_k, offset,
-                    _compute)
+                    _compute, window=window)
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -172,8 +240,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
 
 
 def _flash_fwd_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
-                      offset, interpret):
-    """Returns (o [bh,Tq,d], lse [bh,Tq]) on padded collapsed inputs."""
+                      offset, interpret, window=None, group=1):
+    """Returns (o [bh,Tq,d], lse [bh,Tq]) on padded collapsed inputs; K and
+    V are [bh // group, Tk, d]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -194,10 +263,10 @@ def _flash_fwd_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
     tqp, tkp = tq + pad_q, tk + pad_k
     nq, nk = tqp // block_q, tkp // block_k
 
+    k_spec = _k_spec(block_q, block_k, d, window, group, offset, nk)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+        k_spec, k_spec,
     ]
     args = [q, k, v]
     if bias is not None:
@@ -216,7 +285,7 @@ def _flash_fwd_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
                     acc, m, l, sm_scale=sm_scale, causal=causal,
                     block_q=block_q, block_k=block_k,
                     tk_real=tk_real, offset=offset,
-                    pads=tkp != tk_real)
+                    pads=tkp != tk_real, window=window)
 
     lane = min(_LANE, block_k)
     o, lse = pl.pallas_call(
@@ -249,7 +318,7 @@ def _flash_fwd_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    dq_sc, *, sm_scale, causal, block_q, block_k,
-                   tq_real, tk_real, offset, pads):
+                   tq_real, tk_real, offset, pads, window=None):
     """Grid (bh, iq, ik): accumulate dq over k-blocks in VMEM scratch.
     Mask/scale elision as in _fwd_kernel (r5 skeleton microbench)."""
     import jax.lax as lax
@@ -273,7 +342,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                             preferred_element_type=jnp.float32)
         if masked:
             s = jnp.where(_pos_mask(iq, ik, block_q, block_k, causal,
-                                    offset, tq_real, tk_real), s, NEG_INF)
+                                    offset, tq_real, tk_real,
+                                    window=window), s, NEG_INF)
             p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - lse))
         else:
             p = jnp.exp(s - lse)
@@ -285,7 +355,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             preferred_element_type=jnp.float32)
 
     _block_dispatch(causal, pads, iq, ik, block_q, block_k, offset,
-                    _compute)
+                    _compute, window=window)
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -294,7 +364,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_sc, dv_sc, *, sm_scale, causal,
-                    block_q, block_k, tq_real, tk_real, offset, pads):
+                    block_q, block_k, tq_real, tk_real, offset, pads,
+                    window=None):
     """Grid (bh, ik, iq): accumulate dk/dv over q-blocks in VMEM scratch
     (transposed tiles: everything is (bk, ·) so the MXU contractions stay
     tall).  Mask/scale elision as in _fwd_kernel (r5 microbench)."""
@@ -323,7 +394,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if masked:
             s_t = jnp.where(_pos_mask(iq, ik, block_q, block_k, causal,
                                       offset, tq_real, tk_real,
-                                      transposed=True), s_t, NEG_INF)
+                                      transposed=True, window=window),
+                            s_t, NEG_INF)
             p_t = jnp.where(s_t <= NEG_INF / 2, 0.0, jnp.exp(s_t - lse))
         else:
             p_t = jnp.exp(s_t - lse)
@@ -338,7 +410,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32)
 
     _block_dispatch(causal, pads, iq, ik, block_q, block_k, offset,
-                    _compute)
+                    _compute, window=window)
 
     @pl.when(iq == nq - 1)
     def _finalize():
@@ -349,7 +421,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd_combined_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, dkp_ref, dvp_ref, dq_sc, *, sm_scale,
                          causal, block_q, block_k, tq_real, tk_real,
-                         offset, pads):
+                         offset, pads, window=None):
     """ONE recompute per (i, j) block pair: 5 MXU contractions instead of
     the split kernels' 9 (each pass recomputes S).  Grid (bh, iq, ik) —
     dq accumulates in VMEM scratch over the inner k axis exactly like
@@ -387,7 +459,8 @@ def _bwd_combined_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                             preferred_element_type=jnp.float32)
         if masked:
             s = jnp.where(_pos_mask(iq, ik, block_q, block_k, causal,
-                                    offset, tq_real, tk_real), s, NEG_INF)
+                                    offset, tq_real, tk_real,
+                                    window=window), s, NEG_INF)
             p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - lse))
         else:
             p = jnp.exp(s - lse)
@@ -410,7 +483,7 @@ def _bwd_combined_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dvp_ref[0, 0] = jnp.zeros_like(dvp_ref[0, 0])
 
     _block_dispatch(causal, pads, iq, ik, block_q, block_k, offset,
-                    _compute, on_dead=_zero_partials)
+                    _compute, on_dead=_zero_partials, window=window)
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -440,8 +513,11 @@ def _bwd_prologue(q, k, v, o, lse, do, block_q, block_k):
 
 
 def _flash_bwd_pallas_combined(q, k, v, o, lse, do, causal, sm_scale,
-                               block_q, block_k, offset, interpret):
-    """(dq, dk, dv) via the single-recompute combined kernel."""
+                               block_q, block_k, offset, interpret,
+                               window=None, group=1):
+    """(dq, dk, dv) via the single-recompute combined kernel; dk and dv are
+    summed over the ``group`` query heads of each KV head with the
+    per-q-block partials."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -455,7 +531,7 @@ def _flash_bwd_pallas_combined(q, k, v, o, lse, do, causal, sm_scale,
     lse3 = lse[..., None]
     delta3 = delta[..., None]
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    k_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
+    k_spec = _k_spec(block_q, block_k, d, window, group, offset, nk)
     row_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
     part_spec = pl.BlockSpec((1, 1, block_k, d),
                              lambda b, i, j: (b, i, j, 0))
@@ -463,7 +539,8 @@ def _flash_bwd_pallas_combined(q, k, v, o, lse, do, causal, sm_scale,
         functools.partial(_bwd_combined_kernel, sm_scale=sm_scale,
                           causal=causal, block_q=block_q, block_k=block_k,
                           tq_real=tq_real, tk_real=tk_real, offset=offset,
-                          pads=tqp != tq_real or tkp != tk_real),
+                          pads=tqp != tq_real or tkp != tk_real,
+                          window=window),
         grid=(bh, nq, nk),
         in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
         out_specs=[
@@ -477,6 +554,9 @@ def _flash_bwd_pallas_combined(q, k, v, o, lse, do, causal, sm_scale,
         interpret=interpret,
         name="flash_bwd_combined",
     )(q, k, v, do, lse3, delta3)
+    if group > 1:
+        dkp = dkp.reshape(bh // group, group * nq, tkp, d)
+        dvp = dvp.reshape(bh // group, group * nq, tkp, d)
     dk = jnp.sum(dkp, axis=1).astype(k.dtype)
     dv = jnp.sum(dvp, axis=1).astype(v.dtype)
     return dq[:, :tq], dk[:, :tk], dv[:, :tk]
@@ -494,7 +574,8 @@ _COMBINED_PARTIAL_BUDGET = 2 << 30
 
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale, block_q,
-                      block_k, offset, interpret, impl=None):
+                      block_k, offset, interpret, impl=None, window=None,
+                      group=1):
     impl = impl or _BWD_IMPL
     if impl == "combined":
         bh, tq, d = q.shape
@@ -504,14 +585,19 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale, block_q,
         if partial_bytes <= _COMBINED_PARTIAL_BUDGET:
             return _flash_bwd_pallas_combined(q, k, v, o, lse, do, causal,
                                               sm_scale, block_q, block_k,
-                                              offset, interpret)
+                                              offset, interpret, window,
+                                              group)
     return _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale,
-                                   block_q, block_k, offset, interpret)
+                                   block_q, block_k, offset, interpret,
+                                   window, group)
 
 
 def _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale, block_q,
-                            block_k, offset, interpret):
-    """(dq, dk, dv) via the two kernels above (no-bias path)."""
+                            block_k, offset, interpret, window=None,
+                            group=1):
+    """(dq, dk, dv) via the two kernels above (no-bias path); the dk/dv
+    pass writes one result per query head, summed over each KV head's
+    ``group`` outside."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -526,14 +612,16 @@ def _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale, block_q,
     # dim equal to the array's (mosaic tiling constraint)
     lse3 = lse[..., None]
     delta3 = delta[..., None]
+    plain = window is None and group == 1
     q_spec_q = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    k_spec_q = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
+    k_spec_q = _k_spec(block_q, block_k, d, window, group, offset, nk)
     row_spec_q = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k,
                           tq_real=tq_real, tk_real=tk_real, offset=offset,
-                          pads=tqp != tq_real or tkp != tk_real),
+                          pads=tqp != tq_real or tkp != tk_real,
+                          window=window),
         grid=(bh, nq, nk),
         in_specs=[q_spec_q, k_spec_q, k_spec_q, q_spec_q,
                   row_spec_q, row_spec_q],
@@ -548,14 +636,26 @@ def _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale, block_q,
     # TRANSPOSED [bh, 1, tq] so the kernel reads (1, bq) rows directly
     lse_t = lse[:, None, :]
     delta_t = delta[:, None, :]
-    q_spec_k = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
-    k_spec_k = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
-    row_spec_k = pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i))
+    if plain:
+        q_spec_k = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
+        k_spec_k = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
+        row_spec_k = pl.BlockSpec((1, 1, block_q),
+                                  lambda b, j, i: (b, 0, i))
+    else:
+        def iq_of(i, j):
+            return _live_q(i, j, window, block_q, block_k, offset, nq)
+        q_spec_k = pl.BlockSpec((1, block_q, d),
+                                lambda b, j, i: (b, iq_of(i, j), 0))
+        k_spec_k = pl.BlockSpec((1, block_k, d),
+                                lambda b, j, i: (_kv_head(b, group), j, 0))
+        row_spec_k = pl.BlockSpec((1, 1, block_q),
+                                  lambda b, j, i: (b, 0, iq_of(i, j)))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale,
                           causal=causal, block_q=block_q, block_k=block_k,
                           tq_real=tq_real, tk_real=tk_real, offset=offset,
-                          pads=tqp != tq_real or tkp != tk_real),
+                          pads=tqp != tq_real or tkp != tk_real,
+                          window=window),
         grid=(bh, nk, nq),
         in_specs=[q_spec_k, k_spec_k, k_spec_k, q_spec_k,
                   row_spec_k, row_spec_k],
@@ -570,6 +670,11 @@ def _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale, block_q,
         interpret=interpret,
         name="flash_bwd_dkv",
     )(q, k, v, do, lse_t, delta_t)
+    if group > 1:
+        dk = dk.reshape(bh // group, group, tkp, d).astype(
+            jnp.float32).sum(axis=1).astype(k.dtype)
+        dv = dv.reshape(bh // group, group, tkp, d).astype(
+            jnp.float32).sum(axis=1).astype(v.dtype)
     return dq[:, :tq], dk[:, :tk], dv[:, :tk]
 
 
@@ -577,8 +682,13 @@ def _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale, block_q,
 # Blockwise JAX fallback (same math, lax.scan over k-blocks)
 # ---------------------------------------------------------------------------
 
-def _flash_fwd_jax(q, k, v, bias, causal, sm_scale, block_k, offset):
-    """(o, lse) via scan over k chunks — O(T*block_k) memory on any backend."""
+def _flash_fwd_jax(q, k, v, bias, causal, sm_scale, block_k, offset,
+                   window=None, group=1):
+    """(o, lse) via scan over k chunks — O(T*block_k) memory on any backend.
+    No block is skipped here (this path runs where no TPU is): a window is
+    a mask, and grouped K/V heads are repeated."""
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=0), jnp.repeat(v, group, axis=0)
     bh, tq, d = q.shape
     tk = k.shape[1]
     block_k = min(block_k, tk)
@@ -612,6 +722,8 @@ def _flash_fwd_jax(q, k, v, bias, causal, sm_scale, block_k, offset):
         mask = k_pos < tk
         if causal:
             mask = mask & (q_pos >= k_pos)
+        if window is not None:
+            mask = mask & (q_pos - k_pos < window)
         s = jnp.where(mask[None], s, NEG_INF)
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -638,12 +750,24 @@ def _flash_fwd_jax(q, k, v, bias, causal, sm_scale, block_k, offset):
 
 
 def _flash_bwd_jax(q, k, v, bias, o, lse, do, causal, sm_scale, block_k,
-                   offset, delta=None, need_dbias=True):
+                   offset, delta=None, need_dbias=True, window=None,
+                   group=1):
     """Flash backward: scan over k chunks rebuilding P from saved lse.
 
     dq accumulates across chunks; dk/dv are emitted per chunk (stacked by
-    scan) — memory stays O(T*block_k).
+    scan) — memory stays O(T*block_k).  ``window``/``group`` as in
+    :func:`_flash_fwd_jax`; dk and dv come back summed over each group.
     """
+    if group > 1:
+        dq, dk, dv, db = _flash_bwd_jax(
+            q, jnp.repeat(k, group, axis=0), jnp.repeat(v, group, axis=0),
+            bias, o, lse, do, causal, sm_scale, block_k, offset, delta,
+            need_dbias, window)
+
+        def fold(g):
+            return g.astype(jnp.float32).reshape(
+                (-1, group) + g.shape[1:]).sum(axis=1).astype(g.dtype)
+        return dq, fold(dk), fold(dv), db
     bh, tq, d = q.shape
     tk = k.shape[1]
     block_k = min(block_k, tk)
@@ -679,6 +803,8 @@ def _flash_bwd_jax(q, k, v, bias, o, lse, do, causal, sm_scale, block_k,
         mask = k_pos < tk
         if causal:
             mask = mask & (q_pos >= k_pos)
+        if window is not None:
+            mask = mask & (q_pos - k_pos < window)
         s = jnp.where(mask[None], s, NEG_INF)
         # true softmax from saved lse; guard fully-masked rows (lse=-inf)
         p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - lse[..., None]))
@@ -720,43 +846,49 @@ def _flash_bwd_jax(q, k, v, bias, o, lse, do, causal, sm_scale, block_k,
 # Public custom-vjp op
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12))
 def _flash(q, k, v, bias, causal, sm_scale, block_q, block_k, bwd_blocks,
-           bwd_impl, interpret):
+           bwd_impl, interpret, window=None, group=1):
     o, _ = _flash_fwd(q, k, v, bias, causal, sm_scale, block_q, block_k,
-                      interpret)
+                      interpret, window, group)
     return o
 
 
-def _flash_fwd(q, k, v, bias, causal, sm_scale, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, bias, causal, sm_scale, block_q, block_k, interpret,
+               window=None, group=1):
     # end-aligned causal mask (matches jnp.tril(k=tk-tq)): the last query
     # attends to every key — the KV-cache decode convention
     offset = k.shape[1] - q.shape[1]
     if on_tpu() or interpret:
         return _flash_fwd_pallas(q, k, v, bias, causal, sm_scale,
-                                 block_q, block_k, offset, interpret)
-    return _flash_fwd_jax(q, k, v, bias, causal, sm_scale, block_k, offset)
+                                 block_q, block_k, offset, interpret,
+                                 window, group)
+    return _flash_fwd_jax(q, k, v, bias, causal, sm_scale, block_k, offset,
+                          window, group)
 
 
 def _flash_vjp_fwd(q, k, v, bias, causal, sm_scale, block_q, block_k,
-                   bwd_blocks, bwd_impl, interpret):
+                   bwd_blocks, bwd_impl, interpret, window=None, group=1):
     o, lse = _flash_fwd(q, k, v, bias, causal, sm_scale, block_q, block_k,
-                        interpret)
+                        interpret, window, group)
     return o, (q, k, v, bias, o, lse)
 
 
 def _flash_vjp_bwd(causal, sm_scale, block_q, block_k, bwd_blocks,
-                   bwd_impl, interpret, res, do):
+                   bwd_impl, interpret, window, group, res, do):
     q, k, v, bias, o, lse = res
     offset = k.shape[1] - q.shape[1]
     bq_b, bk_b = bwd_blocks if bwd_blocks is not None else (block_q, block_k)
     if bias is None and (on_tpu() or interpret):
         dq, dk, dv = _flash_bwd_pallas(q, k, v, o, lse, do, causal,
                                        sm_scale, bq_b, bk_b, offset,
-                                       interpret, impl=bwd_impl)
+                                       interpret, impl=bwd_impl,
+                                       window=window, group=group)
         return dq, dk, dv, None
     dq, dk, dv, db = _flash_bwd_jax(q, k, v, bias, o, lse, do, causal,
-                                    sm_scale, bk_b, offset)
+                                    sm_scale, bk_b, offset, window=window,
+                                    group=group)
     return dq, dk, dv, db
 
 
@@ -782,8 +914,19 @@ _BWD_DEFAULTS = {2048: (1024, 512), 4096: (1024, 1024), 8192: (1024, 512),
 # 8.75 ms against 11.05 at the forward's blocks (split (1024, 1024) 8.89);
 # (1024, 1024) combined and every 2048-wide backward block run out of VMEM.
 # Other lengths at this width keep the baseline until they are swept.
-_FWD_DEFAULTS_D128 = {4096: (1024, 1024)}
-_BWD_DEFAULTS_D128 = {4096: (1024, 512)}
+# 8192, on a v5e at [32, 8192, 128] bf16 over 4 KV heads
+# (tools/trinity_kernel_probe.py, PR 32; forward + backward ms): forward
+# (1024, 1024) 5.47 ms full and 3.58 under a window of 2048 ((512, 1024)
+# 7.08 / 4.46, (512, 512) 11.3 / 6.4).  Full backward: combined (1024, 512)
+# 20.67 with 2.15 GB of dK/dV partials, split (1024, 512) 20.92 with none
+# (split (512, 512) 21.77): the split one, a third entry here, for the
+# memory.  Under the window the partials are mostly zeros that are written
+# and summed all the same: combined (1024, 512) 16.18, split (1024, 512)
+# 22.71, split (512, 512) 14.33 (smaller query blocks hug the band), so a
+# window has a table of its own.
+_FWD_DEFAULTS_D128 = {4096: (1024, 1024), 8192: (1024, 1024)}
+_BWD_DEFAULTS_D128 = {4096: (1024, 512), 8192: (1024, 512, "split")}
+_BWD_WINDOW_DEFAULTS_D128 = {8192: (512, 512, "split")}
 
 
 def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
@@ -793,8 +936,21 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
                     block_q_bwd: Optional[int] = None,
                     block_k_bwd: Optional[int] = None,
                     bwd_impl: Optional[str] = None,
-                    interpret: bool = False):
+                    interpret: bool = False,
+                    window: Optional[int] = None):
     """Fused attention over [batch, heads, T, head_dim] tensors.
+
+    ``window`` (with ``causal=True``): key ``j`` is visible to query ``i``
+    iff ``0 <= i - j < window``.  The kernels skip the blocks wholly outside
+    that band as they skip the blocks above the diagonal (no MXU work, and
+    K/V index maps that name the resident block, so no copy), and run masks
+    only on the blocks the two edges cross.  ``window=None`` lowers exactly
+    as before the argument existed.
+
+    K and V may have fewer heads than Q (``[batch, kv_heads, T, d]``, with
+    ``heads % kv_heads == 0``): query head ``h`` reads KV head ``h //
+    (heads // kv_heads)`` through the kernels' index maps, nothing is
+    expanded in HBM, and dK/dV are summed over each group.
 
     ``bias`` broadcasts over (batch, heads): accepted shapes are
     [b, h, Tq, Tk], [1, 1, Tq, Tk] or [Tq, Tk].
@@ -811,10 +967,22 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
     ``bwd_impl``: "combined" (single-recompute, dk/dv partial sums;
     auto-falls back to split when the partials would exceed
     ``_COMBINED_PARTIAL_BUDGET`` HBM) or "split" (two-pass);
-    default = module `_BWD_IMPL`.
+    default = what the backward table says for this length, else module
+    `_BWD_IMPL`.
     """
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    hk, tk = k.shape[1], k.shape[2]
+    if h % hk or v.shape[1] != hk:
+        raise ValueError(f"{h} query heads over {hk}/{v.shape[1]} K/V heads")
+    group = h // hk
+    if window is not None:
+        if not causal:
+            raise ValueError("window= needs causal=True")
+        window = int(window)
+        if window < 1:
+            raise ValueError(f"window {window} < 1")
+        if window >= max(tq, tk):
+            window = None              # the band is the whole causal half
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     # per-length defaults from the r4 IN-GRAPH sweep on v5e (d=64,
@@ -840,12 +1008,16 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
                       min(block_k_bwd or block_k, tk))
     else:
         t = max(tq, tk)
+        if window is not None and 64 < d <= 128 and \
+                t in _BWD_WINDOW_DEFAULTS_D128:
+            bwd_table = _BWD_WINDOW_DEFAULTS_D128
         if t in bwd_table:
-            bq_b, bk_b = bwd_table[t]
+            bq_b, bk_b, *impl = bwd_table[t]
             bwd_blocks = (min(bq_b, tq), min(bk_b, tk))
+            bwd_impl = bwd_impl or (impl[0] if impl else None)
     qc = q.reshape(b * h, tq, d)
-    kc = k.reshape(b * h, tk, d)
-    vc = v.reshape(b * h, tk, d)
+    kc = k.reshape(b * hk, tk, d)
+    vc = v.reshape(b * hk, tk, d)
     bc = None
     if bias is not None:
         if bias.ndim == 2:
@@ -857,5 +1029,5 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
             bc = jnp.broadcast_to(bias, (b, h, tq, tk)).reshape(
                 b * h, tq, tk)
     o = _flash(qc, kc, vc, bc, causal, sm_scale, block_q, block_k,
-               bwd_blocks, bwd_impl, interpret)
+               bwd_blocks, bwd_impl, interpret, window, group)
     return o.reshape(b, h, tq, d)

@@ -1,0 +1,284 @@
+"""Trinity-Mini on the training path against the plain float32 reference of
+``benchmark/reference/trinity_mini.py``, at a toy size on the CPU: the
+windowed, grouped-query flash attention against the dense-mask oracle, the
+router's new attributes and the chip's share of the experts against the
+reference's masked dense experts, then the whole model's loss and every
+parameter's gradient against ``jax.grad`` of the reference's loss.
+
+Tolerances as ``tests/test_olmoe.py`` sets them and for its reasons: program
+and reference are float32 on the CPU and differ by summation order (loss
+1e-5, each gradient 1e-4 of its largest entry; with the fused head, which
+multiplies in bf16, 5e-4 and 2e-2).  Every structural fault this file plants
+(a window off by one, rotary on a full layer, the gate dropped, the
+renormalisation or the scale dropped, a QK-norm over the whole projection)
+moves some gradient of the dense-head model by more than ten times its
+tolerance (``tests/test_trinity_model.py::test_the_tolerance_catches``: the
+whole model's tests are in that file, so that the two run on two workers).
+Sizes are tiny on purpose.
+"""
+
+import hashlib
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+import paddle_tpu as pt  # noqa: E402
+import test_olmoe as olmoe_test  # noqa: E402
+from benchmark.models import trinity_mini as adapter  # noqa: E402
+from benchmark.reference import trinity_mini as ref  # noqa: E402
+from paddle_tpu import layers  # noqa: E402
+from paddle_tpu.framework import (Executor, Program, Scope,  # noqa: E402
+                                  program_guard, scope_guard)
+from paddle_tpu.framework.backward import append_backward  # noqa: E402
+from paddle_tpu.models import transformer as T  # noqa: E402
+_close, _run_op = olmoe_test._close, olmoe_test._run_op
+LOSS_TOL, GRAD_TOL = olmoe_test.LOSS_TOL, olmoe_test.GRAD_TOL
+TYPES = ("sliding_attention", "sliding_attention", "full_attention")
+
+
+def toy_cfg(**kw):
+    kw = dict(dict(vocab_size=96, d_model=32, n_layer=3, n_head=4,
+                   n_kv_head=2, d_head=16, d_inner=48, d_expert=24,
+                   n_experts=8, top_k=2, n_dense_layer=1, layer_types=TYPES,
+                   window=8, n_held=4, expert_offset=2), **kw)
+    return T.TrinityConfig(**kw)
+
+
+def _model(cfg, seq, amp=False, seed=3, fused_head=False):
+    scope, main, startup = Scope(), Program(), Program()
+    with scope_guard(scope), program_guard(main, startup):
+        _, parts, loss = T.build_trinity_pretrain(cfg, seq,
+                                                  fused_head=fused_head)
+        append_backward(loss)
+        if amp:
+            pt.amp.enable(main)
+        exe = Executor()
+        exe.run(startup, scope=scope, seed=seed)
+    # norm scales start at 1 and the bias at 0: they would hide a norm over
+    # the wrong axis and a bias that reached the weights
+    rng = np.random.RandomState(seed)
+    for p in main.all_parameters():
+        if p.name.endswith((".ln1.w", ".ln2.w", ".ln3.w", ".ln4.w",
+                            "_norm.w")):
+            scope.set_var(p.name, jnp.asarray(
+                rng.uniform(0.5, 1.5, p.shape).astype(np.float32)))
+        elif p.name.endswith(".select_bias"):
+            scope.set_var(p.name, jnp.asarray(
+                rng.randn(*p.shape).astype(np.float32) * 0.1))
+    return scope, main, exe, parts, loss
+
+
+def _batch(cfg, b, seq, seed=0):
+    return adapter.make_batch(np.random.RandomState(seed), cfg, b, seq)
+
+
+# -- counters ------------------------------------------------------------------
+
+def test_flash_lowerings_are_counted_by_window_and_groups():
+    from paddle_tpu.ops.attention_ops import FLASH_LOWERINGS_CTR as ctr
+    labels = dict(window="8", kv_groups="2", impl="jax")
+    full = dict(window="none", kv_groups="2", impl="jax")
+    before = ctr.value(**labels), ctr.value(**full)
+    cfg = toy_cfg(n_layer=2, layer_types=TYPES[1:], n_dense_layer=1)
+    scope, main, exe, _, loss = _model(cfg, 16)
+    exe.run(main, feed=_batch(cfg, 1, 16), scope=scope,
+            fetch_list=[loss.name])
+    assert (ctr.value(**labels), ctr.value(**full)) == \
+        (before[0] + 1, before[1] + 1)
+
+
+# -- the router's attributes and the chip's share ---------------------------------
+
+def _moe_weights(rng, d, e_total, held, f):
+    return {"moe.router.w": rng.randn(d, e_total).astype(np.float32) * 0.5,
+            "moe.select_bias": rng.randn(e_total).astype(np.float32) * 0.2,
+            "moe.gate.w": rng.randn(held, d, f).astype(np.float32) * 0.3,
+            "moe.up.w": rng.randn(held, d, f).astype(np.float32) * 0.3,
+            "moe.down.w": rng.randn(held, f, d).astype(np.float32) * 0.3}
+
+
+def _blk(w):
+    return {"router_w": w["moe.router.w"], "select_bias":
+            w["moe.select_bias"], "gate_w": w["moe.gate.w"],
+            "up_w": w["moe.up.w"], "down_w": w["moe.down.w"]}
+
+
+def _run_share(x, w, e_total, k, f, offset, scale=2.826, backward=True,
+               **kw):
+    held = w["moe.gate.w"].shape[0]
+
+    def build():
+        xv = layers.data("x", shape=list(x.shape[1:]), dtype="float32",
+                         stop_gradient=False)
+        out, _, _, load = layers.moe_ffn(
+            xv, e_total, k, f, norm_topk_prob=True, score_func="sigmoid",
+            select_bias=True, norm_eps=1e-20, route_scale=scale,
+            num_held=held, expert_offset=offset, **kw)
+        return [out, load], w
+
+    if not backward:
+        scope = Scope()
+        with scope_guard(scope), program_guard(Program(), Program()):
+            outs, params = build()
+            exe = Executor()
+            exe.run(pt.default_startup_program(), scope=scope, seed=5)
+            scope.set_vars({n: jnp.asarray(v) for n, v in params.items()})
+            out, load = exe.run(feed={"x": x}, scope=scope,
+                                fetch_list=[o.name for o in outs])
+        return np.asarray(out), np.asarray(load), {}
+    wrt = ["x", "moe.router.w", "moe.gate.w", "moe.up.w", "moe.down.w"]
+    out, load, *grads = _run_op(build, {"x": x}, wrt)
+    return out, load, dict(zip(wrt, grads))
+
+
+@pytest.mark.parametrize("offset,held", [
+    (0, 8), (2, 4), (0, 1), pytest.param(6, 2, marks=pytest.mark.slow)])
+def test_sigmoid_bias_scale_and_offset_match_the_masked_dense_reference(
+        offset, held):
+    """Output and the gradients of x, the router and the held experts'
+    weights: sigmoid scores, a selection bias that moves the choice and not
+    the weights, the kept scores renormalised (+ 1e-20) and scaled by 2.826,
+    experts ``offset .. offset + held - 1`` of 8 held."""
+    rng = np.random.RandomState(offset * 10 + held)
+    b, t, d, e, k, f = 2, 7, 16, 8, 3, 12
+    x = rng.randn(b, t, d).astype(np.float32)
+    w = _moe_weights(rng, d, e, held, f)
+    out, load, grads = _run_share(x, w, e, k, f, offset)
+    xs = jnp.asarray(x).reshape(b * t, d)
+    want, top_e = ref.routed_experts(xs, _blk(w), k, 2.826, offset)
+    _close(out.reshape(b * t, d), want, 1e-5, "moe_ffn share")
+    assert load.shape == (e,) and int(load.sum()) == b * t * k
+    np.testing.assert_array_equal(
+        load, np.bincount(np.asarray(top_e).ravel(), minlength=e))
+    gx, gw = jax.grad(lambda xs, blk: jnp.sum(ref.routed_experts(
+        xs, blk, k, 2.826, offset)[0] ** 2), (0, 1))(xs, _blk(w))
+    _close(grads["x"].reshape(b * t, d), gx, 1e-4, "d / d x")
+    for name, key in (("moe.router.w", "router_w"), ("moe.gate.w", "gate_w"),
+                      ("moe.up.w", "up_w"), ("moe.down.w", "down_w")):
+        _close(grads[name], gw[key], 1e-4, f"d / d {name}")
+
+
+def test_a_skewed_router_drops_nothing_on_the_held_experts():
+    """Every token's first choice is expert 3, held here: the buffer takes
+    all of them (no capacity), and the result is still the reference's."""
+    rng = np.random.RandomState(9)
+    b, t, d, e, k, f = 1, 24, 16, 8, 2, 12
+    x = np.abs(rng.randn(b, t, d)).astype(np.float32)
+    w = _moe_weights(rng, d, e, 2, f)
+    w["moe.router.w"][:, 3] = 4.0
+    w["moe.select_bias"][:] = 0.0
+    out, load, _ = _run_share(x, w, e, k, f, 2)
+    assert load[3] == b * t
+    want, _ = ref.routed_experts(jnp.asarray(x).reshape(b * t, d), _blk(w),
+                                 k, 2.826, 2)
+    _close(out.reshape(b * t, d), want, 1e-5, "skewed share")
+
+
+def test_eight_shares_and_the_shared_expert_once_are_the_uncut_layer():
+    """The share test: the routed parts that the 8 chips' ``moe_ffn`` ops
+    give (2 of 16 experts each), added up, plus the shared expert counted
+    once, are the uncut reference's FFN output for the whole layer."""
+    rng = np.random.RandomState(21)
+    b, t, d, e, k, f = 1, 12, 16, 16, 4, 12
+    x = rng.randn(b, t, d).astype(np.float32)
+    whole = _moe_weights(rng, d, e, e, f)
+    shared = {n: jnp.asarray(rng.randn(*s).astype(np.float32) * 0.3)
+              for n, s in (("shared_gate", (d, f)), ("shared_up", (d, f)),
+                           ("shared_down", (f, d)))}
+    total = 0.0
+    for chip in range(8):
+        w = dict(whole, **{n: whole[n][2 * chip:2 * chip + 2]
+                           for n in ("moe.gate.w", "moe.up.w", "moe.down.w")})
+        out, load, _ = _run_share(x, w, e, k, f, 2 * chip, backward=False)
+        total = total + out.reshape(b * t, d)
+    xs = jnp.asarray(x).reshape(b * t, d)
+    routed, _ = ref.routed_experts(xs, _blk(whole), k, 2.826, 0)
+    want = ref.gated(xs, shared["shared_gate"], shared["shared_up"],
+                     shared["shared_down"]) + routed
+    got = total + np.asarray(ref.gated(xs, shared["shared_gate"],
+                                       shared["shared_up"],
+                                       shared["shared_down"]))
+    _close(got, want, 1e-5, "8 shares + shared expert")
+    # one chip alone is a part, not the layer
+    assert np.abs(np.asarray(out.reshape(b * t, d)) - routed).max() > 1e-2
+
+
+def test_routed_rows_are_counted_from_the_loads_handed_over():
+    from paddle_tpu.ops import moe_ops
+    ctr = moe_ops.MOE_ROUTED_ROWS_CTR
+    before = ctr.value(where="all"), ctr.value(where="held")
+    moe_ops.record_expert_load(np.array([5, 1, 2, 0, 4, 4]), 1, 3)
+    assert ctr.value(where="all") == before[0] + 16
+    assert ctr.value(where="held") == before[1] + 3
+
+
+def test_moe_lowerings_carry_held_and_score_func():
+    from paddle_tpu.ops.moe_ops import MOE_LOWERINGS_CTR as ctr
+    labels = dict(impl="ragged_dot", experts="8", top_k="3", held="2",
+                  score_func="sigmoid")
+    before = ctr.value(**labels)
+    rng = np.random.RandomState(4)
+    _run_share(rng.randn(1, 5, 16).astype(np.float32),
+               _moe_weights(rng, 16, 8, 2, 12), 8, 3, 12, 4)
+    assert ctr.value(**labels) == before + 1
+
+
+# -- the old lowerings are the old lowerings ----------------------------------------
+
+#: sha256 of the StableHLO text of the OLMoE toy block's training step
+#: (forward, backward, no optimizer; CPU lowering: the blockwise flash
+#: fallback and ragged_dot) at the parent of PR 32, where ``window``,
+#: ``expert_offset`` and the other new attributes did not exist.  A PR that
+#: means to change OLMoE's lowering replaces it (print the text's hash from
+#: ``_olmoe_step_text``) and says so.
+OLMOE_TOY_STEP_SHA256 = (
+    "d6d2bc0bc6794f2f40f5938aa09b533745b8244b1b600ad10d4663d329ef0c45")
+
+
+def _olmoe_step_text():
+    cfg = olmoe_test.toy_cfg(n_layer=1)
+    scope, main, exe, _, loss = olmoe_test._model(cfg, 16)
+    feed = olmoe_test._batch(cfg, 1, 16)
+    exe.run(main, feed=feed, scope=scope, fetch_list=[loss.name])
+    cb = next(p for p in exe._plans.values()
+              if p.cb.fetch_names == (loss.name,)).cb
+    args = ([jnp.asarray(feed[n]) for n in cb.feed_names],
+            [scope.find_var(n) for n in cb.persist_ro],
+            [scope.find_var(n) for n in cb.persist_rw], jnp.uint32(1))
+    return re.sub(r"loc\(.*?\)", "", cb.jitted.lower(*args).as_text())
+
+
+def test_olmoes_toy_block_lowers_as_it_did_before_the_new_arguments():
+    """``window=None``, ``expert_offset=0``, every expert held, softmax and
+    no bias leave the old lowering unchanged: the lowered step's text is the
+    parent commit's, to the byte."""
+    text = _olmoe_step_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == OLMOE_TOY_STEP_SHA256
+
+
+def test_name_scope_rides_the_ops_and_their_grads_into_the_step():
+    """``framework.name_scope``: the shared expert's and the dense FFN's ops
+    carry the tag, their grad ops inherit it, the executor's scope ends in
+    it, and ops outside carry none."""
+    from paddle_tpu.framework import executor as E
+    cfg = toy_cfg()
+    scope, main, exe, _, loss = _model(cfg, 16)
+    ops = main.global_block().ops
+    tags = {(op.attrs.get("name_scope"), op.type.endswith("_grad"))
+            for op in ops if op.attrs.get("name_scope")}
+    assert tags == {("dense_ffn", False), ("dense_ffn", True),
+                    ("shared_expert", False), ("shared_expert", True)}
+    scoped = {E.op_scope(op) for op in ops}
+    assert "pt.fwd/mul/shared_expert" in scoped
+    assert "pt.bwd/mul_grad/dense_ffn" in scoped
+    assert "pt.fwd/moe_ffn" in scoped and "pt.fwd/flash_attention" in scoped

@@ -24,6 +24,11 @@ if ROOT not in sys.path:
 from benchmark import harness, op_scopes, part_scopes  # noqa: E402
 
 
+#: scopes a model nests inside an op's own (``flash_attention``'s ``window``,
+#: ``framework.name_scope`` tags)
+TAGS = ("window", "shared_expert", "dense_ffn")
+
+
 def main():
     cell = sys.argv[1]
     paths = sorted(glob.glob(os.path.join(
@@ -44,6 +49,14 @@ def main():
         if op.startswith("moe_ffn"):
             moe[f"{role}/{part or '-'}"] = moe.get(f"{role}/{part or '-'}",
                                                    0.0) + s
+    # what a model tags inside an op's scope: the windowed layers of
+    # flash_attention, the dense ops of a shared expert or a dense FFN
+    tagged = {}
+    for (role, op, part), sec in part_scopes.reduce_parts(
+            paths[-1], (lo, hi), TAGS).items():
+        if part or op.startswith("flash_attention"):
+            key = f"{role}/{op}/{part or '-'}"
+            tagged[key] = tagged.get(key, 0.0) + sec
     # XLA operation classes under each scope, by plain event time
     by = {}
     for e in events:
@@ -55,7 +68,7 @@ def main():
     out = {"cell": cell, "busy_s": busy, "scoped_pct": share(red["scoped"]),
            "unscoped_pct": share(red["unscoped"]),
            "forward_again_pct": share(red["forward_again"]),
-           "moe_parts_pct": share(moe),
+           "moe_parts_pct": share(moe), "tagged_pct": share(tagged),
            "xla_ops_pct": {k: dict(list(share(by[k]).items())[:6])
                            for k in top + ["unscoped"] if k in by}}
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)

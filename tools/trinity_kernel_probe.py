@@ -1,0 +1,117 @@
+"""Times the flash attention kernels alone at the Trinity-Mini cell's shapes
+on the chip: [1, 32, 8192, 128] queries over 4 K/V heads in bf16, the window
+of 2048 against the full causal half, the combined backward against the
+split one, and (``--blocks``) other block sizes.  Prints one JSON line per
+case: forward ms, forward + backward ms, the backward's temporaries and, for
+the block tables' own choice, how far the output and the three gradients are
+from ``mha_reference`` (the dense-mask oracle, float32 at ``highest`` over
+the same bf16 inputs, one K/V head's group of query heads at a time):
+``|x - x_ref| / |x_ref|``, where bf16 kernels read a few 1e-3 and a block
+skipped or summed wrongly reads 0.1 or more.
+
+    chiprun -- python3 tools/trinity_kernel_probe.py
+"""
+
+import argparse
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--seq", type=int, default=8192)
+    ap.add_argument("--heads", type=int, default=32)
+    ap.add_argument("--kv_heads", type=int, default=4)
+    ap.add_argument("--window", type=int, default=2048)
+    ap.add_argument("--blocks", default="",
+                    help="bq_fwd,bk_fwd,bq_bwd,bk_bwd[;...] beside defaults")
+    ap.add_argument("--iters", type=int, default=10)
+    args = ap.parse_args()
+    import importlib
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu  # noqa: F401
+    F = importlib.import_module("paddle_tpu.pallas.flash_attention")
+    interpret = jax.default_backend() != "tpu"
+    if interpret:                                  # a rehearsal of the path
+        args.seq, args.window, args.iters = 64, 16, 1
+    key = jax.random.PRNGKey(0)
+    shape = lambda h: (1, h, args.seq, 128 if not interpret else 16)  # noqa
+    q, do = (jax.random.normal(jax.random.fold_in(key, i), shape(args.heads),
+                               jnp.bfloat16) for i in (0, 1))
+    k, v = (jax.random.normal(jax.random.fold_in(key, i),
+                              shape(args.kv_heads), jnp.bfloat16)
+            for i in (2, 3))
+    blocks = [None] + [tuple(int(x) for x in b.split(","))
+                       for b in args.blocks.split(";") if b]
+
+    def timed(fn, *a):
+        jax.block_until_ready(fn(*a))
+        t0 = time.perf_counter()
+        for _ in range(args.iters):
+            out = fn(*a)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / args.iters * 1e3
+
+    def oracle(window):
+        """o, dq, dk, dv of ``mha_reference``, a group at a time (the dense
+        scores of 8 heads at 8192 are 2 GB)."""
+        group = args.heads // args.kv_heads
+
+        @jax.jit
+        def one(qg, kg, vg, dog):
+            f32 = [a.astype(jnp.float32) for a in (qg, kg, vg)]
+            with jax.default_matmul_precision("highest"):
+                o, back = jax.vjp(lambda q, k, v: F.mha_reference(
+                    q, k, v, causal=True, window=window), *f32)
+                return (o,) + back(dog.astype(jnp.float32))
+
+        parts = [one(q[:, i * group:(i + 1) * group], k[:, i:i + 1],
+                     v[:, i:i + 1], do[:, i * group:(i + 1) * group])
+                 for i in range(args.kv_heads)]
+        return [jnp.concatenate(x, axis=1) for x in zip(*parts)]
+
+    def off(got, want):
+        got = got.astype(jnp.float32)
+        return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+
+    for window in (args.window, None):
+        want = oracle(window)
+        for blk in blocks:
+            for impl in ("combined", "split"):
+                kw = dict(causal=True, window=window, bwd_impl=impl,
+                          interpret=interpret)
+                if blk:
+                    kw.update(block_q=blk[0], block_k=blk[1],
+                              block_q_bwd=blk[2], block_k_bwd=blk[3])
+                elif interpret:
+                    kw.update(block_q=16, block_k=16)
+                fwd = jax.jit(lambda q, k, v: F.flash_attention(q, k, v,
+                                                                **kw))
+                both = jax.jit(lambda q, k, v, do: jax.vjp(
+                    lambda q, k, v: F.flash_attention(q, k, v, **kw),
+                    q, k, v)[1](do))
+                try:
+                    row = {"window": window, "blocks": blk, "bwd": impl,
+                           "fwd_ms": timed(fwd, q, k, v),
+                           "fwd_bwd_ms": timed(both, q, k, v, do)}
+                    mem = both.lower(q, k, v, do).compile().memory_analysis()
+                    row["temp_gb"] = getattr(mem, "temp_size_in_bytes", 0) / 1e9
+                    if blk is None:
+                        got = (fwd(q, k, v),) + tuple(both(q, k, v, do))
+                        row.update({f"{n}_rel": off(g, w) for n, g, w in zip(
+                            ("o", "dq", "dk", "dv"), got, want)})
+                except Exception as e:             # VMEM, HBM: say and go on
+                    row = {"window": window, "blocks": blk, "bwd": impl,
+                           "error": str(e)[:200]}
+                print(json.dumps(row), flush=True)
+
+
+if __name__ == "__main__":
+    main()
