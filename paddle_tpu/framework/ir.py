@@ -703,9 +703,14 @@ class AttentionFusePass(Pass):
                 # mask var fed only this add: drop the orphan node too
                 doomed_mask.append(bias_node)
             out_node = mm2.outputs[0]
+            # the op's second output, as layers.flash_attention makes it
+            lse_node = graph.create_var_node(
+                out_node.name + ".lse", shape=tuple(shape[:3]),
+                dtype="float32")
+            lse_node.var.stop_gradient = True
             graph.create_op_node(
                 "flash_attention", inputs=inputs,
-                outputs={"Out": [out_node]},
+                outputs={"Out": [out_node], "Lse": [lse_node]},
                 attrs={"sm_scale": float(a.get("alpha", 1.0)),
                        "causal": causal})
             graph.safe_remove_nodes(

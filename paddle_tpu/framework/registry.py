@@ -8,13 +8,13 @@ function per op type that emits JAX ops while the surrounding Block is traced
 into one XLA computation.  Shape inference (ref ``shape_inference.h``) is the
 lowering itself run abstractly via ``jax.eval_shape`` — one source of truth.
 
-Gradients: every op gets a synthesized ``<type>_grad`` op desc
-(ref ``GradOpDescMakerBase``) whose lowering computes input grads with
-``jax.vjp`` of the forward lowering.  Ops can override with a hand-written
-grad maker where a cheaper formula exists (e.g. dropout reusing its saved
-mask, softmax_with_cross_entropy).  XLA CSE merges the vjp's recomputed
-forward with the original forward ops, so the generic path costs nothing
-after compilation.
+Gradients: an op without a grad maker gets a synthesized ``<type>_grad`` op
+desc (ref ``GradOpDescMakerBase``) lowered as ``jax.vjp`` of the forward
+lowering.  XLA's CSE merges the vjp's recomputed forward with the original
+forward ops, so that path costs nothing after compilation, except where the
+lowering holds a Pallas call: XLA merges no two Mosaic calls, so the kernel
+runs twice a step, and such an op has a hand-written grad maker over saved
+residuals (``moe_ffn``, ``flash_attention``; like dropout's saved mask).
 """
 
 from __future__ import annotations

@@ -1191,15 +1191,15 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
     ``0 <= i - j < window``; the kernels skip the blocks outside the band.
     K and V may have fewer heads than Q ([b, h_kv, T, d], ``h % h_kv ==
     0``): query head ``i`` reads KV head ``i // (h // h_kv)`` in the kernel.
-
     TPU-native replacement for the matmul→softmax→matmul chain of the
     reference Transformer recipe (ref dist_transformer.py:1034
     scaled_dot_product_attention) — Pallas kernel on TPU, O(T) memory.
-    block_q/block_k default to the kernel's tuned sizes (512/1024 capped
-    at T — the v5e-measured optimum).
+    block_q/block_k default to the kernel's tuned sizes.  Lse, the op's
+    second output ([b, h, T] float32), is what its grad op reads beside Out.
     """
     helper = LayerHelper("flash_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
+    lse = helper.create_variable_for_type_inference("float32", True)
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if bias is not None:
         inputs["Bias"] = [bias]
@@ -1208,7 +1208,7 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
     if window:
         attrs["window"] = int(window)
     helper.append_op("flash_attention", inputs=inputs,
-                     outputs={"Out": [out]}, attrs=attrs)
+                     outputs={"Out": [out], "Lse": [lse]}, attrs=attrs)
     return out
 
 

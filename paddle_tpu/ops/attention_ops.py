@@ -13,10 +13,13 @@ pattern so the [b, h, T, T] score matrix never reaches HBM.
 
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 
 from .. import monitor as _monitor
+from ..framework.core import grad_var_name
 from ..framework.registry import register_op
 from .common import X
 
@@ -28,39 +31,106 @@ FLASH_LOWERINGS_CTR = _monitor.REGISTRY.counter(
     "counted while tracing, once per compile of a block that holds the op, "
     "nothing per step", ("window", "kv_groups", "impl"))
 
+FLASH_GRAD_LOWERINGS_CTR = _monitor.REGISTRY.counter(
+    "paddle_tpu_flash_grad_lowerings_total",
+    "flash_attention_grad lowerings, labelled as the forward's: the grad op "
+    "that takes the forward op's Out and Lse and runs the backward kernels "
+    "alone (with a bias, the blockwise jax backward on every backend) — "
+    "counted while tracing, once per compile, nothing per step",
+    ("window", "kv_groups", "impl"))
 
-@register_op("flash_attention")
-def _flash_attention(ctx, ins, attrs):
-    """Q [b, h, Tq, d]; K, V [b, h_kv, Tk, d] with ``h % h_kv == 0`` (query
-    head ``i`` reads KV head ``i // (h // h_kv)``).  ``window`` > 0 with
-    ``causal``: key ``j`` is visible to query ``i`` iff ``0 <= i - j <
-    window``; such an op's device operations lie under a ``window`` scope
-    inside the op's own, so that a trace tells the windowed layers from the
-    full ones."""
+
+def _flash_call(ctx, attrs, q, k, counter, pallas):
+    """What the op and its grad op share: ``(window or None, the attributes
+    as keyword arguments of the kernel's entry points)``, and one count of
+    the lowering in ``counter`` (``pallas``: whether a TPU would run the
+    kernels here)."""
     from ..device import on_tpu
-    from ..pallas import flash_attention
-    q, k, v = X(ins, "Q"), X(ins, "K"), X(ins, "V")
-    bias = X(ins, "Bias")
     bq, bk = attrs.get("block_q"), attrs.get("block_k")
     window = int(attrs.get("window") or 0) or None
     if window is not None and window >= max(q.shape[2], k.shape[2]):
         window = None
-    # the generic grad op lowers this forward again for its vjp: not counted
-    if not getattr(ctx, "is_abstract", False) and \
-            getattr(ctx, "op_type", "flash_attention") == "flash_attention":
-        FLASH_LOWERINGS_CTR.inc(
-            window="none" if window is None else str(window),
-            kv_groups=str(q.shape[1] // k.shape[1]),
-            impl="pallas" if on_tpu() else "jax")
-    kw = dict(bias=bias, causal=bool(attrs.get("causal", False)),
-              sm_scale=attrs.get("sm_scale") or None,
-              block_q=int(bq) if bq else None,  # None → kernel's tuned default
-              block_k=int(bk) if bk else None,
-              bwd_impl=attrs.get("bwd_impl") or None)
-    if window is None:
-        return {"Out": [flash_attention(q, k, v, **kw)]}
-    with jax.named_scope("window"):
-        return {"Out": [flash_attention(q, k, v, window=window, **kw)]}
+    if not getattr(ctx, "is_abstract", False):
+        counter.inc(window="none" if window is None else str(window),
+                    kv_groups=str(q.shape[1] // k.shape[1]),
+                    impl="pallas" if pallas and on_tpu() else "jax")
+    return window, dict(
+        causal=bool(attrs.get("causal", False)),
+        sm_scale=attrs.get("sm_scale") or None,
+        block_q=int(bq) if bq else None,  # None → kernel's tuned default
+        block_k=int(bk) if bk else None,
+        bwd_impl=attrs.get("bwd_impl") or None, window=window)
+
+
+def _window_scope(window):
+    """A windowed layer's device operations lie under a ``window`` scope
+    inside the op's own, forward and backward, so that a trace tells the
+    windowed layers from the full ones; a full layer's lie under none."""
+    return contextlib.nullcontext() if window is None else \
+        jax.named_scope("window")
+
+
+def _flash_attention(ctx, ins, attrs):
+    """Q [b, h, Tq, d]; K, V [b, h_kv, Tk, d] with ``h % h_kv == 0`` (query
+    head ``i`` reads KV head ``i // (h // h_kv)``).  ``window`` > 0 with
+    ``causal``: key ``j`` is visible to query ``i`` iff ``0 <= i - j <
+    window``.  Outputs: Out [b, h, Tq, d] and Lse [b, h, Tq] float32, each
+    query's log-sum-exp over its visible keys, which ``flash_attention_grad``
+    rebuilds the probabilities from (an op without that slot still runs:
+    the executor binds the slots an op names)."""
+    from ..pallas.flash_attention import flash_attention_fwd
+    q, k, v = X(ins, "Q"), X(ins, "K"), X(ins, "V")
+    window, kw = _flash_call(ctx, attrs, q, k, FLASH_LOWERINGS_CTR,
+                             pallas=True)
+    with _window_scope(window):
+        out, lse = flash_attention_fwd(q, k, v, X(ins, "Bias"), **kw)
+        # Lse leaves with Out: without the barrier XLA:TPU sinks the slice
+        # that makes the [b, h, Tq] rows into the backward and keeps the
+        # kernel's lane-broadcast [b * h, Tq, 128] buffer until then
+        out, lse = jax.lax.optimization_barrier((out, lse))
+    return {"Out": [out], "Lse": [lse]}
+
+
+def _flash_attention_grad_maker(op, block, no_grad_set):
+    if not op.output("Lse"):
+        raise ValueError(
+            "flash_attention op without an Lse output (a program built "
+            "before the op saved its log-sum-exp): build it again with "
+            "layers.flash_attention to train it")
+    slots = [s for s in ("Q", "K", "V", "Bias") if op.input(s)]
+    g_inputs = {"X$" + s: op.input(s) for s in slots}
+    g_inputs["Out"], g_inputs["Lse"] = op.output("Out"), op.output("Lse")
+    g_inputs["OG$Out"] = [grad_var_name(n) for n in op.output("Out")]
+    g_outputs = {"IG$" + s: ["" if n in no_grad_set else grad_var_name(n)
+                             for n in op.input(s)] for s in slots}
+    # the [Tq, Tk] tiles of a bias's gradient are kept only for a reader
+    attrs = dict(op.attrs, need_dbias=any(g_outputs.get("IG$Bias", ())))
+    return [{"type": "flash_attention_grad", "inputs": g_inputs,
+             "outputs": g_outputs, "attrs": attrs}]
+
+
+register_op("flash_attention", _flash_attention,
+            grad_maker=_flash_attention_grad_maker)
+
+
+@register_op("flash_attention_grad")
+def _flash_attention_grad(ctx, ins, attrs):
+    """The backward of ``flash_attention`` from what the forward saved: the
+    backward kernels on (Q, K, V, Out, Lse, dOut) and no forward kernel (the
+    generic vjp ran it a second time for these two residuals; XLA does not
+    merge two Mosaic calls).  With a bias the blockwise jax backward, on
+    every backend, and dBias only where the grad maker found a reader."""
+    from ..pallas.flash_attention import flash_attention_bwd
+    q, k, v = X(ins, "X$Q"), X(ins, "X$K"), X(ins, "X$V")
+    bias, out, d_out = X(ins, "X$Bias"), X(ins, "Out"), X(ins, "OG$Out")
+    window, kw = _flash_call(ctx, attrs, q, k, FLASH_GRAD_LOWERINGS_CTR,
+                             pallas=bias is None)
+    d_out = jnp.zeros_like(out) if d_out is None else d_out.astype(out.dtype)
+    with _window_scope(window):
+        dq, dk, dv, db = flash_attention_bwd(
+            q, k, v, bias, out, X(ins, "Lse"), d_out,
+            need_dbias=bool(attrs.get("need_dbias", True)), **kw)
+    return {"IG$Q": [dq], "IG$K": [dk], "IG$V": [dv], "IG$Bias": [db]}
 
 
 @register_op("ring_attention")

@@ -876,7 +876,8 @@ def _flash_vjp_fwd(q, k, v, bias, causal, sm_scale, block_q, block_k,
 
 
 def _flash_vjp_bwd(causal, sm_scale, block_q, block_k, bwd_blocks,
-                   bwd_impl, interpret, window, group, res, do):
+                   bwd_impl, interpret, window, group, res, do,
+                   need_dbias=True):
     q, k, v, bias, o, lse = res
     offset = k.shape[1] - q.shape[1]
     bq_b, bk_b = bwd_blocks if bwd_blocks is not None else (block_q, block_k)
@@ -887,7 +888,8 @@ def _flash_vjp_bwd(causal, sm_scale, block_q, block_k, bwd_blocks,
                                        window=window, group=group)
         return dq, dk, dv, None
     dq, dk, dv, db = _flash_bwd_jax(q, k, v, bias, o, lse, do, causal,
-                                    sm_scale, bk_b, offset, window=window,
+                                    sm_scale, bk_b, offset,
+                                    need_dbias=need_dbias, window=window,
                                     group=group)
     return dq, dk, dv, db
 
@@ -929,47 +931,26 @@ _BWD_DEFAULTS_D128 = {4096: (1024, 512), 8192: (1024, 512, "split")}
 _BWD_WINDOW_DEFAULTS_D128 = {8192: (512, 512, "split")}
 
 
-def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
-                    causal: bool = False, sm_scale: Optional[float] = None,
-                    block_q: Optional[int] = None,
-                    block_k: Optional[int] = None,
-                    block_q_bwd: Optional[int] = None,
-                    block_k_bwd: Optional[int] = None,
-                    bwd_impl: Optional[str] = None,
-                    interpret: bool = False,
-                    window: Optional[int] = None):
-    """Fused attention over [batch, heads, T, head_dim] tensors.
+def _collapse_bias(bias, b, h, tq, tk):
+    """A bias of any accepted shape as the kernels read it: [1, Tq, Tk] where
+    it is one for all (batch, head), else [b * h, Tq, Tk]."""
+    if bias.ndim == 2:
+        bias = bias[None, None]
+    b0, h0 = bias.shape[:2]
+    if b0 == 1 and h0 == 1:
+        return bias.reshape(1, tq, tk)
+    # [b,1], [1,h] or [b,h]: materialize full batch*heads
+    return jnp.broadcast_to(bias, (b, h, tq, tk)).reshape(b * h, tq, tk)
 
-    ``window`` (with ``causal=True``): key ``j`` is visible to query ``i``
-    iff ``0 <= i - j < window``.  The kernels skip the blocks wholly outside
-    that band as they skip the blocks above the diagonal (no MXU work, and
-    K/V index maps that name the resident block, so no copy), and run masks
-    only on the blocks the two edges cross.  ``window=None`` lowers exactly
-    as before the argument existed.
 
-    K and V may have fewer heads than Q (``[batch, kv_heads, T, d]``, with
-    ``heads % kv_heads == 0``): query head ``h`` reads KV head ``h //
-    (heads // kv_heads)`` through the kernels' index maps, nothing is
-    expanded in HBM, and dK/dV are summed over each group.
-
-    ``bias`` broadcasts over (batch, heads): accepted shapes are
-    [b, h, Tq, Tk], [1, 1, Tq, Tk] or [Tq, Tk].
-
-    Default blocks are per-sequence-length tables (below) at d≤64 and, for
-    the lengths swept there, at 64<d≤128, else (512, 1024) capped at the
-    sequence lengths — measured on v5e: ahead
-    of XLA's O(T²) attention from T≈1024, and the only runnable path
-    beyond ~8k (r4 prior: 11.0 ms fwd / 45.1 ms f+b at [12,16384,64] —
-    LONGCTX_ABLATION.md).
-    The backward kernels take their own ``block_q_bwd``/``block_k_bwd``
-    (default: the ``_BWD_DEFAULTS`` table at d≤64 for 2k/4k/8k/16k, else
-    the forward blocks) — swept separately in LONGCTX_ABLATION.md.
-    ``bwd_impl``: "combined" (single-recompute, dk/dv partial sums;
-    auto-falls back to split when the partials would exceed
-    ``_COMBINED_PARTIAL_BUDGET`` HBM) or "split" (two-pass);
-    default = what the backward table says for this length, else module
-    `_BWD_IMPL`.
-    """
+def _plan(q, k, v, bias, causal, sm_scale, block_q, block_k, block_q_bwd,
+          block_k_bwd, bwd_impl, interpret, window):
+    """What :func:`flash_attention` and its two halves share: the checks,
+    the block choice from the tables, the window folded away where it is the
+    whole causal half, the heads collapsed into the batch and the bias
+    broadcast.  Returns ``((q, k, v, bias) collapsed, statics)``;
+    ``statics`` are ``_flash``'s non-differentiated arguments, in its
+    order."""
     b, h, tq, d = q.shape
     hk, tk = k.shape[1], k.shape[2]
     if h % hk or v.shape[1] != hk:
@@ -1018,16 +999,96 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
     qc = q.reshape(b * h, tq, d)
     kc = k.reshape(b * hk, tk, d)
     vc = v.reshape(b * hk, tk, d)
-    bc = None
-    if bias is not None:
-        if bias.ndim == 2:
-            bias = bias[None, None]
-        b0, h0 = bias.shape[:2]
-        if b0 == 1 and h0 == 1:
-            bc = bias.reshape(1, tq, tk)
-        else:  # [b,1], [1,h] or [b,h]: materialize full batch*heads
-            bc = jnp.broadcast_to(bias, (b, h, tq, tk)).reshape(
-                b * h, tq, tk)
-    o = _flash(qc, kc, vc, bc, causal, sm_scale, block_q, block_k,
-               bwd_blocks, bwd_impl, interpret, window, group)
-    return o.reshape(b, h, tq, d)
+    bc = None if bias is None else _collapse_bias(bias, b, h, tq, tk)
+    return (qc, kc, vc, bc), (causal, sm_scale, block_q, block_k, bwd_blocks,
+                              bwd_impl, interpret, window, group)
+
+
+def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
+                    causal: bool = False, sm_scale: Optional[float] = None,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
+                    block_q_bwd: Optional[int] = None,
+                    block_k_bwd: Optional[int] = None,
+                    bwd_impl: Optional[str] = None,
+                    interpret: bool = False,
+                    window: Optional[int] = None):
+    """Fused attention over [batch, heads, T, head_dim] tensors.
+
+    ``window`` (with ``causal=True``): key ``j`` is visible to query ``i``
+    iff ``0 <= i - j < window``.  The kernels skip the blocks wholly outside
+    that band as they skip the blocks above the diagonal (no MXU work, and
+    K/V index maps that name the resident block, so no copy), and run masks
+    only on the blocks the two edges cross.  ``window=None`` lowers exactly
+    as before the argument existed.
+
+    K and V may have fewer heads than Q (``[batch, kv_heads, T, d]``, with
+    ``heads % kv_heads == 0``): query head ``h`` reads KV head ``h //
+    (heads // kv_heads)`` through the kernels' index maps, nothing is
+    expanded in HBM, and dK/dV are summed over each group.
+
+    ``bias`` broadcasts over (batch, heads): accepted shapes are
+    [b, h, Tq, Tk], [1, 1, Tq, Tk] or [Tq, Tk].
+
+    Default blocks are per-sequence-length tables (below) at d≤64 and, for
+    the lengths swept there, at 64<d≤128, else (512, 1024) capped at the
+    sequence lengths — measured on v5e: ahead
+    of XLA's O(T²) attention from T≈1024, and the only runnable path
+    beyond ~8k (r4 prior: 11.0 ms fwd / 45.1 ms f+b at [12,16384,64] —
+    LONGCTX_ABLATION.md).
+    The backward kernels take their own ``block_q_bwd``/``block_k_bwd``
+    (default: the ``_BWD_DEFAULTS`` table at d≤64 for 2k/4k/8k/16k, else
+    the forward blocks) — swept separately in LONGCTX_ABLATION.md.
+    ``bwd_impl``: "combined" (single-recompute, dk/dv partial sums;
+    auto-falls back to split when the partials would exceed
+    ``_COMBINED_PARTIAL_BUDGET`` HBM) or "split" (two-pass);
+    default = what the backward table says for this length, else module
+    `_BWD_IMPL`.
+    """
+    (qc, kc, vc, bc), statics = _plan(
+        q, k, v, bias, causal, sm_scale, block_q, block_k, block_q_bwd,
+        block_k_bwd, bwd_impl, interpret, window)
+    return _flash(qc, kc, vc, bc, *statics).reshape(q.shape)
+
+
+def flash_attention_fwd(q, k, v, bias=None, causal=False, sm_scale=None,
+                        block_q=None, block_k=None, block_q_bwd=None,
+                        block_k_bwd=None, bwd_impl=None, interpret=False,
+                        window=None):
+    """The forward half of :func:`flash_attention` as a plain function, for
+    a caller that keeps the residuals itself (the ``flash_attention`` op of a
+    ``Program``): ``(o [b, h, Tq, d], lse [b, h, Tq] float32)``, the
+    log-sum-exp of each query's scaled, biased, masked scores.  Same
+    arguments and block choice; the ``custom_vjp``'s forward rule, called
+    plainly."""
+    b, h, tq, _ = q.shape
+    (qc, kc, vc, bc), statics = _plan(
+        q, k, v, bias, causal, sm_scale, block_q, block_k, block_q_bwd,
+        block_k_bwd, bwd_impl, interpret, window)
+    o, (*_, lse) = _flash_vjp_fwd(qc, kc, vc, bc, *statics)
+    return o.reshape(q.shape), lse.reshape(b, h, tq)
+
+
+def flash_attention_bwd(q, k, v, bias, o, lse, do, causal=False,
+                        sm_scale=None, block_q=None, block_k=None,
+                        block_q_bwd=None, block_k_bwd=None, bwd_impl=None,
+                        interpret=False, window=None, need_dbias=True):
+    """The backward half: ``(dq, dk, dv, dbias)`` from the inputs, what
+    :func:`flash_attention_fwd` returned for them and the output's gradient;
+    ``dbias`` has the bias's shape, and is ``None`` without a bias or where
+    ``need_dbias`` is false (nobody keeps its [Tq, Tk] tiles then).
+    The kernels and blocks are the ones ``jax.grad`` of
+    :func:`flash_attention` runs (``_flash_vjp_bwd``); nothing of the forward
+    is computed again but the scores, tile by tile, from ``lse``."""
+    b, h, tq, _ = q.shape
+    (qc, kc, vc, bc), statics = _plan(
+        q, k, v, bias, causal, sm_scale, block_q, block_k, block_q_bwd,
+        block_k_bwd, bwd_impl, interpret, window)
+    dq, dk, dv, db = _flash_vjp_bwd(
+        *statics, (qc, kc, vc, bc, o.reshape(qc.shape),
+                   lse.reshape(b * h, tq)), do.reshape(qc.shape), need_dbias)
+    if db is not None:
+        # the transpose of _collapse_bias: summed over what it broadcast
+        db, = jax.vjp(lambda x: _collapse_bias(x, b, h, tq, k.shape[2]),
+                      bias)[1](db.astype(bias.dtype))
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape), db

@@ -3,7 +3,12 @@
 ``mha_reference``: forward and dQ, dK, dV of the Pallas kernels in interpret
 mode (both backward implementations) and of the blockwise jax fallback, at
 T 64 in blocks of 16 (the suite runs at its time limit), and the K/V blocks
-the index maps name at the Trinity-Mini cell's real sizes."""
+the index maps name at the Trinity-Mini cell's real sizes.  And the
+``flash_attention`` op of a ``Program`` with its grad op over the forward's
+saved ``Out`` and ``Lse`` (``ops/attention_ops.py``): its gradients against
+``jax.grad`` of the oracle, the forward half's calls in a training step, an
+op without the ``Lse`` slot, and a recomputed segment around a flash
+layer."""
 
 import importlib
 
@@ -12,6 +17,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import paddle_tpu as pt
+from paddle_tpu import layers, optimizer as opt
+from paddle_tpu.framework import (Executor, Program, Scope, program_guard,
+                                  scope_guard)
+from paddle_tpu.framework.backward import append_backward
+from paddle_tpu.framework.core import grad_var_name
+from paddle_tpu.models import transformer as T
+from paddle_tpu.ops.attention_ops import (FLASH_GRAD_LOWERINGS_CTR,
+                                          FLASH_LOWERINGS_CTR)
 from paddle_tpu.pallas import mha_reference
 
 F = importlib.import_module("paddle_tpu.pallas.flash_attention")
@@ -125,3 +139,173 @@ def test_the_index_maps_name_the_bands_blocks_and_no_other(t, bq, bk, window):
             assert int(F._live_q(i, j, window, bq, bk, 0, nq)) == i
     if (t, bq, bk) == (8192, 1024, 1024):
         assert copied == 1 + 2 + 6 * 3          # of 36 under the diagonal
+
+
+# -- the op of a Program and its grad op from the saved Out and Lse -----------
+
+def _data(name, a, grad=True):
+    return layers.data(name, shape=list(a.shape), append_batch_size=False,
+                       dtype="float32", stop_gradient=not grad)
+
+
+def _flash_ops(program):
+    ops = program.global_block().ops
+    return ([op for op in ops if op.type == "flash_attention"],
+            [op for op in ops if op.type == "flash_attention_grad"])
+
+
+#: name -> (heads, KV heads, Tq, Tk, the op's keyword arguments, bias shape)
+GRAD_CASES = {
+    "causal": (4, 4, 48, 48, dict(causal=True), None),
+    "window": (4, 4, 48, 48, dict(causal=True, window=10), None),
+    "grouped_kv_4_to_1": (4, 1, 48, 48, dict(causal=True, window=20), None),
+    "additive_bias": (2, 2, 24, 24, dict(), (24, 24)),
+    "bias_per_batch": (2, 2, 24, 24, dict(causal=True), (2, 1, 24, 24)),
+    "tq_not_tk": (4, 2, 16, 40, dict(causal=True), None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GRAD_CASES))
+def test_the_registered_grad_op_against_jax_grad_of_the_oracle(case):
+    """``flash_attention_grad`` as ``append_backward`` writes it (inputs Q,
+    K, V, Bias where present, the forward's Out and Lse, dOut), run by the
+    executor: dQ, dK, dV and dBias against ``jax.grad`` of ``mha_reference``
+    in float32, within what this file holds the kernels' own vjp to."""
+    h, hk, tq, tk, kw, bias_shape = GRAD_CASES[case]
+    rng = np.random.RandomState(len(case))
+    feed = {"q": rng.randn(2, h, tq, 8), "k": rng.randn(2, hk, tk, 8),
+            "v": rng.randn(2, hk, tk, 8), "w": rng.randn(2, h, tq, 8)}
+    if bias_shape:
+        feed["bias"] = rng.randn(*bias_shape)
+    feed = {n: a.astype(np.float32) for n, a in feed.items()}
+    wrt = [n for n in ("q", "k", "v", "bias") if n in feed]
+    with scope_guard(Scope()), program_guard(Program(), Program()):
+        v = {n: _data(n, a, grad=n != "w") for n, a in feed.items()}
+        out = layers.flash_attention(v["q"], v["k"], v["v"],
+                                     bias=v.get("bias"), **kw)
+        loss = layers.reduce_sum(out * v["w"])
+        append_backward(loss)
+        (fwd,), (grad,) = _flash_ops(pt.default_main_program())
+        got = Executor().run(feed=feed, fetch_list=[
+            out.name] + [grad_var_name(n) for n in wrt])
+    assert grad.input("Out") == fwd.output("Out")
+    assert grad.input("Lse") == fwd.output("Lse") and fwd.output("Lse")
+    assert sorted(grad.inputs) == sorted(
+        ["X$" + s.capitalize() for s in wrt] + ["Out", "Lse", "OG$Out"])
+    assert "__fwd_type__" not in grad.attrs       # not the generic vjp
+
+    def oracle(*args):
+        a = dict(zip(wrt, args))
+        b = a.get("bias")
+        return mha_reference(a["q"], a["k"], a["v"],
+                             bias=b[None, None] if b is not None and
+                             b.ndim == 2 else b, **kw)
+    args = [jnp.asarray(feed[n]) for n in wrt]
+    with jax.default_matmul_precision("highest"):
+        want = oracle(*args)
+        g_want = jax.grad(lambda *a: jnp.sum(oracle(*a) * feed["w"]),
+                          tuple(range(len(wrt))))(*args)
+    _close(got[0], want, 1e-5, f"{case}: Out")
+    for name, a, b in zip(wrt, got[1:], g_want):
+        assert a.shape == b.shape, (name, a.shape, b.shape)
+        _close(a, b, 1e-5, f"{case}: d / d {name}")
+
+
+def _two_flash_layers(seq=32, checkpoints=False):
+    """x -> two self-attention blocks on the flash path (4 heads over 2 KV
+    heads, the second windowed) -> a scalar loss, with SGD."""
+    x = layers.data("x", shape=[2, seq, 32], append_batch_size=False,
+                    dtype="float32")
+    h, marks = x, []
+    for i, window in enumerate((None, 12)):
+        h = h + T.multi_head_attention(
+            h, h, h, 32, 4, param_prefix=f"l{i}.attn", attn_impl="flash",
+            causal=True, n_kv_head=2, window=window, bias=False)
+        marks.append(h)
+    loss = layers.reduce_mean(layers.square(h))
+    sgd = opt.SGDOptimizer(learning_rate=0.1)
+    if checkpoints:
+        sgd = opt.RecomputeOptimizer(sgd)
+        sgd._set_checkpoints(marks)
+    sgd.minimize(loss)
+    return loss
+
+
+def _train_steps(loss, scope, n=2, seq=32):
+    exe = Executor()
+    exe.run(pt.default_startup_program(), scope=scope, seed=3)
+    x = np.random.RandomState(0).randn(2, seq, 32).astype(np.float32)
+    return [float(np.asarray(exe.run(feed={"x": x}, scope=scope,
+                                     fetch_list=[loss.name])[0]))
+            for _ in range(n)]
+
+
+def test_a_training_step_runs_the_forward_half_once_a_layer(monkeypatch):
+    """Two flash layers, one compiled training step: the forward half of the
+    kernel pair is traced twice (under the generic vjp it was four times:
+    ``jax.vjp`` of the forward lowering ran it again for its residuals), and
+    the grad op counts itself once a layer a compile."""
+    calls = []
+    real = F._flash_fwd
+    scope = Scope()
+    with scope_guard(scope), program_guard(Program(), Program()):
+        loss = _two_flash_layers()
+        monkeypatch.setattr(
+            F, "_flash_fwd", lambda *a, **k: calls.append(1) or real(*a, **k))
+        before = (FLASH_LOWERINGS_CTR.value(window="12", kv_groups="2",
+                                            impl="jax"),
+                  FLASH_GRAD_LOWERINGS_CTR.value(window="none",
+                                                 kv_groups="2", impl="jax"),
+                  FLASH_GRAD_LOWERINGS_CTR.value(window="12", kv_groups="2",
+                                                 impl="jax"))
+        losses = _train_steps(loss, scope)
+    assert len(calls) == 2, len(calls)
+    assert (FLASH_LOWERINGS_CTR.value(window="12", kv_groups="2", impl="jax"),
+            FLASH_GRAD_LOWERINGS_CTR.value(window="none", kv_groups="2",
+                                           impl="jax"),
+            FLASH_GRAD_LOWERINGS_CTR.value(window="12", kv_groups="2",
+                                           impl="jax")) == \
+        tuple(b + 1 for b in before)
+    assert losses[1] < losses[0]
+
+
+def test_a_forward_program_whose_op_has_no_lse_slot_still_runs():
+    """A program saved before the op had its second output: the lowering
+    returns ``Lse``, the executor binds the slots the op names, and the
+    grad maker says what to do instead of writing a grad op that cannot
+    run."""
+    q, k, v, _ = _qkv(32)
+    with scope_guard(Scope()), program_guard(Program(), Program()):
+        vs = [_data(n, a) for n, a in zip("qkv", (q, k, v))]
+        out = layers.flash_attention(*vs, causal=True, window=9)
+        (op,), _ = _flash_ops(pt.default_main_program())
+        del op.outputs["Lse"]
+        got, = Executor().run(feed=dict(zip("qkv", map(np.asarray,
+                                                       (q, k, v)))),
+                              fetch_list=[out.name])
+        _close(got, mha_reference(q, k, v, causal=True, window=9), 1e-5)
+        with pytest.raises(ValueError, match="Lse"):
+            append_backward(layers.reduce_sum(out))
+
+
+def test_a_recomputed_segments_lse_feeds_the_grad_op():
+    """``RecomputeOptimizer`` with checkpoints at both block outputs: the
+    second flash layer is emitted again behind the loss's gradient, its grad
+    op reads that copy's ``Out`` and ``Lse``, and the step trains as it does
+    without recomputation."""
+    losses = {}
+    for ck in (False, True):
+        scope = Scope()
+        with scope_guard(scope), program_guard(Program(), Program()):
+            loss = _two_flash_layers(checkpoints=ck)
+            if ck:
+                fwd, grads = _flash_ops(pt.default_main_program())
+                assert len(fwd) == 3 and len(grads) == 2
+                again = fwd[2]
+                assert again.output("Lse")[0].endswith("@RECOMPUTE")
+                reads = [g for g in grads
+                         if g.input("Lse") == again.output("Lse")]
+                assert len(reads) == 1
+                assert reads[0].input("Out") == again.output("Out")
+            losses[ck] = _train_steps(loss, scope, n=3)
+    np.testing.assert_allclose(losses[True], losses[False], rtol=1e-5)

@@ -215,6 +215,54 @@ def test_rn50_step_batch256_default_flags(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# a toy OLMoE step: the flash forward kernel once a layer, not twice
+# ---------------------------------------------------------------------------
+
+@tpu_hw
+def test_olmoe_toy_step_holds_one_flash_fwd_a_layer(tmp_path):
+    """Two OLMoE blocks at the model's head width (16 x 128) over 1024
+    positions, AMP AdamW: ``flash_attention_grad`` takes the forward's Out
+    and Lse, so the lowered step holds the forward kernel once a layer (the
+    generic vjp lowered it a second time inside the grad op) beside each
+    layer's backward kernels."""
+    import collections
+
+    import paddle_tpu as pt
+    from paddle_tpu import optimizer as opt
+    from paddle_tpu.framework import (Program, Scope, program_guard,
+                                      scope_guard)
+    from paddle_tpu.models import transformer as T
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from benchmark.models.olmoe_1b_7b import make_batch
+    from chip_smoke import lowered_kernel_names
+
+    cfg = T.OlmoeConfig(vocab_size=1024, n_layer=2, n_experts=8, top_k=2)
+    scope = Scope()
+    with scope_guard(scope), program_guard(Program(), Program()):
+        _, _, loss = T.build_olmoe_pretrain(cfg, 1024)
+        pt.amp.decorate(opt.AdamWOptimizer(
+            learning_rate=1e-4, weight_decay=0.1)).minimize(loss)
+        exe = pt.Executor(pt.TPUPlace(0))
+        exe.run(pt.default_startup_program(), scope=scope, seed=3)
+        feed = make_batch(np.random.RandomState(0), cfg, 1, 1024)
+        jax.config.update("jax_dump_ir_to", str(tmp_path))
+        try:
+            l0, = exe.run(feed=feed, fetch_list=[loss.name], scope=scope)
+        finally:
+            jax.config.update("jax_dump_ir_to", None)
+        l1, = exe.run(feed=feed, fetch_list=[loss.name], scope=scope)
+    kernels = collections.Counter(lowered_kernel_names(str(tmp_path)))
+    _record("olmoe_toy_step", losses=[float(l0), float(l1)],
+            kernels=dict(kernels))
+    assert np.isfinite(l0) and np.isfinite(l1), (l0, l1)
+    assert kernels["flash_fwd"] == cfg.n_layer, kernels
+    assert sum(n for k, n in kernels.items()
+               if k.startswith("flash_bwd")) >= cfg.n_layer, kernels
+
+
+# ---------------------------------------------------------------------------
 # CPU: a TPU kind the peak tables do not know is an error, never a default
 # ---------------------------------------------------------------------------
 

@@ -40,6 +40,7 @@ from paddle_tpu import layers  # noqa: E402
 from paddle_tpu.framework import (Executor, Program, Scope,  # noqa: E402
                                   program_guard, scope_guard)
 from paddle_tpu.framework.backward import append_backward  # noqa: E402
+from paddle_tpu.framework.core import grad_var_name  # noqa: E402
 from paddle_tpu.models import transformer as T  # noqa: E402
 _close, _run_op = olmoe_test._close, olmoe_test._run_op
 LOSS_TOL, GRAD_TOL = olmoe_test.LOSS_TOL, olmoe_test.GRAD_TOL
@@ -236,22 +237,28 @@ def test_moe_lowerings_carry_held_and_score_func():
 # -- the old lowerings are the old lowerings ----------------------------------------
 
 #: sha256 of the StableHLO text of the OLMoE toy block's training step
-#: (forward, backward, no optimizer; CPU lowering: the blockwise flash
-#: fallback and ragged_dot) at the parent of PR 32, where ``window``,
-#: ``expert_offset`` and the other new attributes did not exist.  A PR that
-#: means to change OLMoE's lowering replaces it (print the text's hash from
+#: (forward and backward, the loss and every parameter's gradient fetched, no
+#: optimizer; CPU lowering: the blockwise flash fallback and ragged_dot) as
+#: PR 33 left it: ``flash_attention_grad`` over the forward's Out and Lse, so
+#: one forward scan a layer.  Until PR 33 the step fetched the loss alone,
+#: JAX dropped the unread backward before lowering, and the hash (PR 32's,
+#: taken at its parent: d6d2bc0b...) covered the forward only, which is why
+#: PR 33's change of the backward did not move it.  A PR that means to change
+#: OLMoE's lowering replaces it (print the text's hash from
 #: ``_olmoe_step_text``) and says so.
 OLMOE_TOY_STEP_SHA256 = (
-    "d6d2bc0bc6794f2f40f5938aa09b533745b8244b1b600ad10d4663d329ef0c45")
+    "fb53143a3ba4e6e01fbdd4dbed0d149f1c63d71b5633699f940939984c39c4ae")
 
 
 def _olmoe_step_text():
     cfg = olmoe_test.toy_cfg(n_layer=1)
     scope, main, exe, _, loss = olmoe_test._model(cfg, 16)
     feed = olmoe_test._batch(cfg, 1, 16)
-    exe.run(main, feed=feed, scope=scope, fetch_list=[loss.name])
+    fetch = [loss.name] + [grad_var_name(p.name)
+                           for p in main.all_parameters()]
+    exe.run(main, feed=feed, scope=scope, fetch_list=fetch)
     cb = next(p for p in exe._plans.values()
-              if p.cb.fetch_names == (loss.name,)).cb
+              if p.cb.fetch_names == tuple(fetch)).cb
     args = ([jnp.asarray(feed[n]) for n in cb.feed_names],
             [scope.find_var(n) for n in cb.persist_ro],
             [scope.find_var(n) for n in cb.persist_rw], jnp.uint32(1))
@@ -260,9 +267,10 @@ def _olmoe_step_text():
 
 def test_olmoes_toy_block_lowers_as_it_did_before_the_new_arguments():
     """``window=None``, ``expert_offset=0``, every expert held, softmax and
-    no bias leave the old lowering unchanged: the lowered step's text is the
-    parent commit's, to the byte."""
+    no bias leave OLMoE's lowering alone: the lowered step's text, forward
+    and backward, is the recorded one, to the byte."""
     text = _olmoe_step_text()
+    assert text.count("stablehlo.while") == 2      # flash: forward, backward
     assert hashlib.sha256(text.encode()).hexdigest() == OLMOE_TOY_STEP_SHA256
 
 
