@@ -281,17 +281,23 @@ def rms_norm(input, begin_norm_axis=1, epsilon=1e-5, param_attr=None,
     return out
 
 
-def rope(x, head_dim, theta=10000.0, name=None):
+def rope(x, head_dim, theta=10000.0, name=None, interleaved=False):
     """Rotary position embedding over [batch, T, n * head_dim], before the
     head split: position = index along axis 1, rotate-half pairing
     (dimension ``i`` of a head with ``i + head_dim / 2``).  A 4-D input is
     [batch, heads, T, head_dim], after the split: position = index along
-    axis 2."""
+    axis 2.  ``interleaved=True`` pairs adjacent dimensions ``(2i, 2i + 1)``
+    instead (``rope_interleave`` of the DeepSeek family); a model that
+    rotates only a slice of the head splits it off first
+    (``models.transformer.latent_attention``).  The default leaves the op
+    and its lowering as they were."""
     helper = LayerHelper("rope", name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
+    attrs = {"head_dim": int(head_dim), "theta": float(theta)}
+    if interleaved:
+        attrs["interleaved"] = True
     helper.append_op("rope", inputs={"X": [x]}, outputs={"Out": [out]},
-                     attrs={"head_dim": int(head_dim),
-                            "theta": float(theta)})
+                     attrs=attrs)
     return out
 
 

@@ -53,6 +53,11 @@ def multi_head_attention(queries, keys, values, d_model, n_head,
     gates the attention output, ``ctx * sigmoid(gate)``, before the output
     projection (self-attention only).  On the flash path K and V reach the
     kernel at their ``n_kv_head`` heads; the other paths expand them.
+
+    Q, K and V here are slices of one projection of the layer's input, all
+    ``d_head`` wide.  Attention through low-rank latents, with scores wider
+    than the values and a rotary slice shared by the heads, is another
+    builder: :func:`latent_attention`.
     """
     d_head = d_head or d_model // n_head
     d_q = n_head * d_head
@@ -600,6 +605,214 @@ def build_trinity_pretrain(cfg: TrinityConfig, seq_len, is_test=False,
     _, loss = _lm_head_loss(x, cfg, lm_label, fused_head, "lm_out",
                             bias=False)
     return (src_ids, lm_label), {"expert_load": loads, "hidden": x}, loss
+
+
+# -- JoyAI-LLM-Flash (DeepSeek-V3 family): latent attention, one multi-token --
+# -- prediction module over the shared embedding and head ---------------------
+
+class JoyaiConfig:
+    """JoyAI-LLM-Flash defaults (``jdopensource/JoyAI-LLM-Flash``
+    config.json, ``model_type`` ``joyai_llm_flash``, the DeepSeek-V3 family's
+    keys).  Latent attention: Q through a ``q_lora_rank`` latent, K's content
+    part and V through a ``kv_lora_rank`` latent, ``d_nope + d_rope`` wide
+    scores over ``d_v`` wide values, the rotary slice on adjacent pairs and
+    its key one head for all.  The first ``n_dense_layer`` layers have a
+    dense gated FFN of width ``d_inner``, the others ``n_experts`` routed
+    experts of width ``d_expert`` (``top_k`` a token, sigmoid scores, a
+    selection bias, renormalised and scaled) beside one shared expert;
+    ``n_mtp`` multi-token-prediction modules (0 or 1) follow the last
+    layer.  ``n_held``/``expert_offset``: the experts whose weights this
+    program holds (default all), as :class:`TrinityConfig` has them."""
+
+    def __init__(self, vocab_size=129280, d_model=2048, n_layer=40,
+                 n_head=32, q_lora_rank=1536, kv_lora_rank=512, d_nope=128,
+                 d_rope=64, d_v=128, d_inner=7168, d_expert=768,
+                 n_experts=256, top_k=8, n_dense_layer=1, n_mtp=1,
+                 route_scale=2.5, rms_eps=1e-6, rope_theta=32000000.0,
+                 n_held=None, expert_offset=0):
+        assert n_mtp in (0, 1), "one multi-token-prediction depth at most"
+        self.vocab_size = vocab_size
+        self.d_model = d_model
+        self.n_layer = n_layer
+        self.n_head = n_head
+        self.q_lora_rank = q_lora_rank
+        self.kv_lora_rank = kv_lora_rank
+        self.d_nope = d_nope
+        self.d_rope = d_rope
+        self.d_v = d_v
+        self.d_inner = d_inner
+        self.d_expert = d_expert
+        self.n_experts = n_experts
+        self.top_k = top_k
+        self.n_dense_layer = n_dense_layer
+        self.n_mtp = n_mtp
+        self.route_scale = route_scale
+        self.rms_eps = rms_eps
+        self.rope_theta = rope_theta
+        self.n_held = n_experts if n_held is None else n_held
+        self.expert_offset = expert_offset
+
+
+def latent_attention(x, cfg: JoyaiConfig, param_prefix="attn"):
+    """Multi-head latent attention (MLA, arXiv:2405.04434 / 2412.19437
+    §2.1.1) over ``x`` [b, t, d_model], causal, no bias, the training form
+    (K and V expanded from the latent; no cache)::
+
+        [c_q | c_kv | k_r] = x W_a          one fused [d, r_q + r_kv + d_rope]
+        c_q = RMS(c_q), c_kv = RMS(c_kv)    norms on the two latents
+        [q_nope | q_rope] = c_q W_qb        per head, d_nope | d_rope
+        [k_nope | v]      = c_kv W_kvb      per head, d_nope | d_v
+        q_rope, k_r = RoPE on adjacent pairs of the d_rope slice
+        k = [k_nope | k_r for every head],  q = [q_nope | q_rope]
+        out = flash(q, k, v, scale (d_nope + d_rope)^-1/2) W_o
+
+    ``k_r`` bypasses the latent and is ONE head that every query head reads:
+    it is broadcast over the heads and concatenated behind each head's
+    content part outside the kernel, which then takes one ``d_nope +
+    d_rope`` wide K (``tools/joyai_kernel_probe.py`` says what that costs).
+    The scores contract over ``d_nope + d_rope`` and the values are ``d_v``
+    wide: the flash kernels' two widths.  Everything but the flash op lies
+    under the ``mla_proj`` tag.  Parameters: ``<prefix>.a.w``,
+    ``.q_norm.w``, ``.kv_norm.w``, ``.q_b.w``, ``.kv_b.w``, ``.out.w``."""
+    h, dn, dr, dv = cfg.n_head, cfg.d_nope, cfg.d_rope, cfg.d_v
+
+    def proj(v, size, name):
+        return layers.fc(v, size=size, num_flatten_dims=2, bias_attr=False,
+                         param_attr=ParamAttr(name=f"{param_prefix}.{name}.w"))
+
+    def norm(v, name):
+        return layers.rms_norm(
+            v, begin_norm_axis=2, epsilon=cfg.rms_eps,
+            param_attr=ParamAttr(name=f"{param_prefix}.{name}.w"))
+
+    def heads(v, width):                      # [b, t, h * w] -> [b, h, t, w]
+        return layers.transpose(
+            layers.reshape(v, shape=[0, 0, h, width]), perm=[0, 2, 1, 3])
+
+    def rotate(v):
+        return layers.rope(v, dr, cfg.rope_theta, interleaved=True)
+
+    with name_scope("mla_proj"):
+        c_q, c_kv, k_r = layers.split(
+            proj(x, cfg.q_lora_rank + cfg.kv_lora_rank + dr, "a"),
+            [cfg.q_lora_rank, cfg.kv_lora_rank, dr], dim=2)
+        q_nope, q_rope = layers.split(
+            heads(proj(norm(c_q, "q_norm"), h * (dn + dr), "q_b"), dn + dr),
+            [dn, dr], dim=3)
+        k_nope, v = layers.split(
+            heads(proj(norm(c_kv, "kv_norm"), h * (dn + dv), "kv_b"),
+                  dn + dv), [dn, dv], dim=3)
+        k_r = rotate(layers.unsqueeze(k_r, [1]))            # [b, 1, t, dr]
+        q = layers.concat([q_nope, rotate(q_rope)], axis=3)
+        k = layers.concat([k_nope, layers.expand(k_r, [1, h, 1, 1])], axis=3)
+    ctx = layers.flash_attention(q, k, v, causal=True,
+                                 sm_scale=float(dn + dr) ** -0.5)
+    with name_scope("mla_proj"):
+        ctx = layers.reshape(layers.transpose(ctx, perm=[0, 2, 1, 3]),
+                             shape=[0, 0, h * dv])
+        return proj(ctx, cfg.d_model, "out")
+
+
+def joyai_decoder_layer(x, cfg: JoyaiConfig, idx=0, dense=None,
+                        param_prefix=None):
+    """Pre-norm block, two norms: ``h = x + MLA(RMS1(x))``, ``out = h +
+    FFN(RMS2(h))``; no bias anywhere.  FFN: :func:`gated_ffn` of width
+    ``d_inner`` in a dense layer (``dense``, default ``idx <
+    n_dense_layer``); else the one shared expert (the same builder, width
+    ``d_expert``) plus ``moe_ffn`` as Trinity's block calls it
+    (``noaux_tc`` with one group: sigmoid scores, a selection bias held at
+    zero, the kept scores renormalised with ``1e-20`` and scaled).
+    ``param_prefix`` (default ``dec_<idx>``) names the parameters.  Returns
+    ``(out, expert_load or None)``."""
+    from ..initializer import NormalInitializer
+    p = param_prefix or f"dec_{idx}"
+    dense = idx < cfg.n_dense_layer if dense is None else dense
+
+    def norm(v, name):
+        return layers.rms_norm(v, begin_norm_axis=2, epsilon=cfg.rms_eps,
+                               param_attr=ParamAttr(name=f"{p}.{name}.w"))
+
+    h = x + latent_attention(norm(x, "ln1"), cfg, f"{p}.attn")
+    m = norm(h, "ln2")
+    if dense:
+        with name_scope("dense_ffn"):
+            return h + gated_ffn(m, cfg.d_inner, cfg.d_model, f"{p}.ffn"), None
+    with name_scope("shared_expert"):
+        f = gated_ffn(m, cfg.d_expert, cfg.d_model, f"{p}.shared")
+    moe, _, _, load = layers.moe_ffn(
+        m, cfg.n_experts, cfg.top_k, cfg.d_expert,
+        norm_topk_prob=True, param_prefix=f"{p}.moe",
+        initializer=NormalInitializer(0.0, 0.02),
+        score_func="sigmoid", select_bias=True, norm_eps=1e-20,
+        route_scale=cfg.route_scale, num_held=cfg.n_held,
+        expert_offset=cfg.expert_offset)
+    return h + f + moe, load
+
+
+def build_joyai_pretrain(cfg: JoyaiConfig, seq_len, mtp_weight=0.3,
+                         checkpoints=None, fused_head=True):
+    """Causal LM over :func:`joyai_decoder_layer` blocks with one
+    multi-token-prediction module (arXiv:2412.19437 §2.2, depth 1): ids ->
+    embedding -> ``n_layer`` blocks -> final RMSNorm ``z`` -> untied
+    bias-free head, ``L_main`` = mean CE against ``lm_label`` (token ``i +
+    1``).  With ``cfg.n_mtp``: ``u = [RMS_e(E[lm_label]) | RMS_h(z)]
+    W_eh``, one whole expert-layer block over ``u`` (positions ``0 .. T -
+    1``), a norm, and THE SAME head: ``L_mtp`` = mean CE against
+    ``mtp_label`` (token ``i + 2``).  ``E`` (``word_embedding``) and the head
+    (``lm_out.w``) are the main model's parameters, read a second time by
+    name, so each one's gradient is the sum of its two uses.  Loss =
+    ``L_main + mtp_weight * L_mtp`` and nothing else (the selection bias is
+    held at zero, as in :func:`build_trinity_pretrain`).  The module lies
+    under the ``mtp`` tag.  ``checkpoints=[]`` collects the block outputs
+    (the module's among them) for ``RecomputeOptimizer``.  Returns ``(feeds,
+    parts, loss)`` with ``parts`` = {"expert_load": [per expert layer, the
+    module's last], "hidden": ``z``, "mtp_hidden": the module's normed
+    output, "main_loss", "mtp_loss"}."""
+    src_ids = layers.data("src_ids", shape=[seq_len], dtype="int64")
+    lm_label = layers.data("lm_label", shape=[seq_len], dtype="int64")
+    feeds = [src_ids, lm_label]
+
+    def embed(ids):
+        return layers.embedding(ids, size=[cfg.vocab_size, cfg.d_model],
+                                param_attr=ParamAttr(name="word_embedding"))
+
+    def norm(v, name):
+        return layers.rms_norm(v, begin_norm_axis=2, epsilon=cfg.rms_eps,
+                               param_attr=ParamAttr(name=name))
+
+    x = embed(src_ids)
+    loads = []
+    for i in range(cfg.n_layer):
+        x, load = joyai_decoder_layer(x, cfg, i)
+        if load is not None:
+            loads.append(load)
+        if checkpoints is not None:
+            checkpoints.append(x)
+    z = norm(x, "final_norm.w")
+    _, main_loss = _lm_head_loss(z, cfg, lm_label, fused_head, "lm_out",
+                                 bias=False)
+    parts = {"expert_load": loads, "hidden": z, "main_loss": main_loss}
+    loss = main_loss
+    if cfg.n_mtp:
+        mtp_label = layers.data("mtp_label", shape=[seq_len], dtype="int64")
+        feeds.append(mtp_label)
+        with name_scope("mtp"):
+            u = layers.fc(
+                layers.concat([norm(embed(lm_label), "mtp_0.enorm.w"),
+                               norm(z, "mtp_0.hnorm.w")], axis=2),
+                size=cfg.d_model, num_flatten_dims=2, bias_attr=False,
+                param_attr=ParamAttr(name="mtp_0.eh_proj.w"))
+            u, load = joyai_decoder_layer(u, cfg, dense=False,
+                                          param_prefix="mtp_0")
+            if checkpoints is not None:
+                checkpoints.append(u)
+            s = norm(u, "mtp_0.shared_head_norm.w")
+            _, mtp_loss = _lm_head_loss(s, cfg, mtp_label, fused_head,
+                                        "lm_out", bias=False)
+        loads.append(load)
+        parts.update(mtp_hidden=s, mtp_loss=mtp_loss)
+        loss = main_loss + float(mtp_weight) * mtp_loss
+    return tuple(feeds), parts, loss
 
 
 def annotate_tensor_parallel(program=None):

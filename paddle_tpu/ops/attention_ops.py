@@ -26,10 +26,12 @@ from .common import X
 FLASH_LOWERINGS_CTR = _monitor.REGISTRY.counter(
     "paddle_tpu_flash_lowerings_total",
     "flash_attention forward lowerings by the window (none = the whole "
-    "causal half or no mask), the query heads to a KV head and the "
-    "implementation (the Pallas kernels or the blockwise jax fallback) — "
-    "counted while tracing, once per compile of a block that holds the op, "
-    "nothing per step", ("window", "kv_groups", "impl"))
+    "causal half or no mask), the query heads to a KV head, the "
+    "implementation (the Pallas kernels or the blockwise jax fallback) and "
+    "the two widths (widths = d_qk/d_v, e.g. 128/128 or latent attention's "
+    "192/128) — counted while tracing, once per compile of a block that "
+    "holds the op, nothing per step",
+    ("window", "kv_groups", "impl", "widths"))
 
 FLASH_GRAD_LOWERINGS_CTR = _monitor.REGISTRY.counter(
     "paddle_tpu_flash_grad_lowerings_total",
@@ -37,10 +39,10 @@ FLASH_GRAD_LOWERINGS_CTR = _monitor.REGISTRY.counter(
     "that takes the forward op's Out and Lse and runs the backward kernels "
     "alone (with a bias, the blockwise jax backward on every backend) — "
     "counted while tracing, once per compile, nothing per step",
-    ("window", "kv_groups", "impl"))
+    ("window", "kv_groups", "impl", "widths"))
 
 
-def _flash_call(ctx, attrs, q, k, counter, pallas):
+def _flash_call(ctx, attrs, q, k, v, counter, pallas):
     """What the op and its grad op share: ``(window or None, the attributes
     as keyword arguments of the kernel's entry points)``, and one count of
     the lowering in ``counter`` (``pallas``: whether a TPU would run the
@@ -53,7 +55,8 @@ def _flash_call(ctx, attrs, q, k, counter, pallas):
     if not getattr(ctx, "is_abstract", False):
         counter.inc(window="none" if window is None else str(window),
                     kv_groups=str(q.shape[1] // k.shape[1]),
-                    impl="pallas" if pallas and on_tpu() else "jax")
+                    impl="pallas" if pallas and on_tpu() else "jax",
+                    widths=f"{q.shape[3]}/{v.shape[3]}")
     return window, dict(
         causal=bool(attrs.get("causal", False)),
         sm_scale=attrs.get("sm_scale") or None,
@@ -71,16 +74,19 @@ def _window_scope(window):
 
 
 def _flash_attention(ctx, ins, attrs):
-    """Q [b, h, Tq, d]; K, V [b, h_kv, Tk, d] with ``h % h_kv == 0`` (query
-    head ``i`` reads KV head ``i // (h // h_kv)``).  ``window`` > 0 with
-    ``causal``: key ``j`` is visible to query ``i`` iff ``0 <= i - j <
-    window``.  Outputs: Out [b, h, Tq, d] and Lse [b, h, Tq] float32, each
+    """Q [b, h, Tq, d_qk]; K [b, h_kv, Tk, d_qk] and V [b, h_kv, Tk, d_v]
+    with ``h % h_kv == 0`` (query head ``i`` reads KV head ``i // (h //
+    h_kv)``); ``d_v`` is V's own and may differ from ``d_qk`` (latent
+    attention: 192 over 128).  ``window`` > 0 with ``causal``: key ``j`` is
+    visible to query ``i`` iff ``0 <= i - j < window``.  Outputs: Out [b, h,
+    Tq, d_v] (shape inference runs this lowering abstractly, so Out's and
+    the grad op's shapes follow V's) and Lse [b, h, Tq] float32, each
     query's log-sum-exp over its visible keys, which ``flash_attention_grad``
     rebuilds the probabilities from (an op without that slot still runs:
     the executor binds the slots an op names)."""
     from ..pallas.flash_attention import flash_attention_fwd
     q, k, v = X(ins, "Q"), X(ins, "K"), X(ins, "V")
-    window, kw = _flash_call(ctx, attrs, q, k, FLASH_LOWERINGS_CTR,
+    window, kw = _flash_call(ctx, attrs, q, k, v, FLASH_LOWERINGS_CTR,
                              pallas=True)
     with _window_scope(window):
         out, lse = flash_attention_fwd(q, k, v, X(ins, "Bias"), **kw)
@@ -123,7 +129,7 @@ def _flash_attention_grad(ctx, ins, attrs):
     from ..pallas.flash_attention import flash_attention_bwd
     q, k, v = X(ins, "X$Q"), X(ins, "X$K"), X(ins, "X$V")
     bias, out, d_out = X(ins, "X$Bias"), X(ins, "Out"), X(ins, "OG$Out")
-    window, kw = _flash_call(ctx, attrs, q, k, FLASH_GRAD_LOWERINGS_CTR,
+    window, kw = _flash_call(ctx, attrs, q, k, v, FLASH_GRAD_LOWERINGS_CTR,
                              pallas=bias is None)
     d_out = jnp.zeros_like(out) if d_out is None else d_out.astype(out.dtype)
     with _window_scope(window):
@@ -169,7 +175,13 @@ def _rope(ctx, ins, attrs):
     has the input's dtype.  Applied before the head split so that it fuses
     with the projection's epilogue and the QK-norm; a 4-D X is [batch,
     heads, T, head_dim], after the split (where a per-head norm comes
-    first), with the position along axis 2."""
+    first), with the position along axis 2.  ``interleaved`` (default
+    false: the lowering above, unchanged): dimension ``2i`` pairs with ``2i +
+    1`` at the same angle, the pairing of the DeepSeek family's
+    ``rope_interleave`` (its code permutes each pair's members to the two
+    halves and rotates halves; permuted alike on Q and K the scores are
+    those of this pairwise rotation).  ``head_dim`` may be a slice of the
+    head: the caller splits the rotary part off and hands that over."""
     x = X(ins, "X")
     dh = int(attrs["head_dim"])
     theta = float(attrs.get("theta", 10000.0))
@@ -184,6 +196,11 @@ def _rope(ctx, ins, attrs):
         cos = jnp.cos(ang)[None, :, None, :]
         sin = jnp.sin(ang)[None, :, None, :]
         xf = x.astype(jnp.float32).reshape(b, t, d // dh, dh)
+    if attrs.get("interleaved"):
+        # adjacent pairs: (2i, 2i + 1) turn by the angle of frequency i
+        x1, x2 = xf[..., 0::2], xf[..., 1::2]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+        return {"Out": [out.reshape(x.shape).astype(x.dtype)]}
     x1, x2 = xf[..., :half], xf[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                           axis=-1)

@@ -1,5 +1,12 @@
 """Flash attention: fused online-softmax attention with O(T) memory.
 
+Two widths: Q and K are ``d_qk`` wide (the scores contract over it), V and
+the output ``d_v`` wide, read from V's shape.  ``d_qk == d_v`` is every
+caller before latent attention (192 over 128) and lowers exactly as before
+the second width existed; nothing is padded to the wider of the two in HBM:
+V's, O's and dO's blocks, the accumulators and dV are ``d_v`` wide, Q's, K's,
+dQ and dK ``d_qk``.
+
 Forward on TPU runs a Pallas kernel tiled for the MXU (grid over
 (batch*heads, q-blocks, k-blocks), f32 accumulators in VMEM scratch);
 elsewhere (CPU tests, interpret debugging) a blockwise ``lax.scan``
@@ -31,7 +38,8 @@ _LANE = 128      # TPU lane width: min last-dim tile
 
 def mha_reference(q, k, v, bias=None, causal=False, sm_scale=None,
                   window=None):
-    """O(T^2) reference attention (the math the kernel must reproduce).
+    """O(T^2) reference attention (the math the kernel must reproduce): Q
+    and K ``[b, h, T, d_qk]``, V ``[b, h, T, d_v]``, the result ``d_v`` wide.
     ``window``: with ``causal``, key ``j`` is visible to query ``i`` iff
     ``0 <= i - j < window``.  K and V may have fewer heads than Q: query
     head ``h`` reads KV head ``h // (n_q_heads // n_kv_heads)``."""
@@ -241,12 +249,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
 
 def _flash_fwd_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
                       offset, interpret, window=None, group=1):
-    """Returns (o [bh,Tq,d], lse [bh,Tq]) on padded collapsed inputs; K and
-    V are [bh // group, Tk, d]."""
+    """Returns (o [bh,Tq,dv], lse [bh,Tq]) on padded collapsed inputs; K is
+    [bh // group, Tk, d] and V [bh // group, Tk, dv]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, tq, d = q.shape
+    d_v = v.shape[2]
     tk = k.shape[1]
     tk_real = tk
     block_q = min(block_q, tq)
@@ -263,10 +272,10 @@ def _flash_fwd_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
     tqp, tkp = tq + pad_q, tk + pad_k
     nq, nk = tqp // block_q, tkp // block_k
 
-    k_spec = _k_spec(block_q, block_k, d, window, group, offset, nk)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        k_spec, k_spec,
+        _k_spec(block_q, block_k, d, window, group, offset, nk),
+        _k_spec(block_q, block_k, d_v, window, group, offset, nk),
     ]
     args = [q, k, v]
     if bias is not None:
@@ -293,15 +302,15 @@ def _flash_fwd_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
         grid=(bh, nq, nk),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, lane), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tqp, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, tqp, d_v), q.dtype),
             jax.ShapeDtypeStruct((bh, tqp, lane), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
             pltpu.VMEM((block_q, lane), jnp.float32),
             pltpu.VMEM((block_q, lane), jnp.float32),
         ],
@@ -522,6 +531,7 @@ def _flash_bwd_pallas_combined(q, k, v, o, lse, do, causal, sm_scale,
     from jax.experimental.pallas import tpu as pltpu
 
     bh, tq, d = q.shape
+    d_v = v.shape[2]
     tk = k.shape[1]
     tq_real, tk_real = tq, tk
     (q, k, v, do, lse, delta, block_q, block_k, tqp, tkp) = \
@@ -530,11 +540,16 @@ def _flash_bwd_pallas_combined(q, k, v, o, lse, do, causal, sm_scale,
 
     lse3 = lse[..., None]
     delta3 = delta[..., None]
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    k_spec = _k_spec(block_q, block_k, d, window, group, offset, nk)
+
+    def q_spec(w):
+        return pl.BlockSpec((1, block_q, w), lambda b, i, j: (b, i, 0))
+
+    def k_spec(w):
+        return _k_spec(block_q, block_k, w, window, group, offset, nk)
+
+    def part_spec(w):
+        return pl.BlockSpec((1, 1, block_k, w), lambda b, i, j: (b, i, j, 0))
     row_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
-    part_spec = pl.BlockSpec((1, 1, block_k, d),
-                             lambda b, i, j: (b, i, j, 0))
     dq, dkp, dvp = pl.pallas_call(
         functools.partial(_bwd_combined_kernel, sm_scale=sm_scale,
                           causal=causal, block_q=block_q, block_k=block_k,
@@ -542,21 +557,19 @@ def _flash_bwd_pallas_combined(q, k, v, o, lse, do, causal, sm_scale,
                           pads=tqp != tq_real or tkp != tk_real,
                           window=window),
         grid=(bh, nq, nk),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            part_spec, part_spec,
-        ],
+        in_specs=[q_spec(d), k_spec(d), k_spec(d_v), q_spec(d_v), row_spec,
+                  row_spec],
+        out_specs=[q_spec(d), part_spec(d), part_spec(d_v)],
         out_shape=[jax.ShapeDtypeStruct((bh, tqp, d), q.dtype),
                    jax.ShapeDtypeStruct((bh, nq, tkp, d), jnp.float32),
-                   jax.ShapeDtypeStruct((bh, nq, tkp, d), jnp.float32)],
+                   jax.ShapeDtypeStruct((bh, nq, tkp, d_v), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_combined",
     )(q, k, v, do, lse3, delta3)
     if group > 1:
         dkp = dkp.reshape(bh // group, group * nq, tkp, d)
-        dvp = dvp.reshape(bh // group, group * nq, tkp, d)
+        dvp = dvp.reshape(bh // group, group * nq, tkp, d_v)
     dk = jnp.sum(dkp, axis=1).astype(k.dtype)
     dv = jnp.sum(dvp, axis=1).astype(v.dtype)
     return dq[:, :tq], dk[:, :tk], dv[:, :tk]
@@ -581,7 +594,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale, block_q,
         bh, tq, d = q.shape
         tk = k.shape[1]
         nq = -(-tq // min(block_q, tq))
-        partial_bytes = 2 * bh * nq * tk * d * 4
+        partial_bytes = bh * nq * tk * (d + v.shape[2]) * 4
         if partial_bytes <= _COMBINED_PARTIAL_BUDGET:
             return _flash_bwd_pallas_combined(q, k, v, o, lse, do, causal,
                                               sm_scale, block_q, block_k,
@@ -602,6 +615,7 @@ def _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale, block_q,
     from jax.experimental.pallas import tpu as pltpu
 
     bh, tq, d = q.shape
+    d_v = v.shape[2]
     tk = k.shape[1]
     tq_real, tk_real = tq, tk
     (q, k, v, do, lse, delta, block_q, block_k, tqp, tkp) = \
@@ -613,8 +627,11 @@ def _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale, block_q,
     lse3 = lse[..., None]
     delta3 = delta[..., None]
     plain = window is None and group == 1
-    q_spec_q = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    k_spec_q = _k_spec(block_q, block_k, d, window, group, offset, nk)
+    def q_spec_q(w):
+        return pl.BlockSpec((1, block_q, w), lambda b, i, j: (b, i, 0))
+
+    def k_spec_q(w):
+        return _k_spec(block_q, block_k, w, window, group, offset, nk)
     row_spec_q = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
@@ -623,9 +640,9 @@ def _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale, block_q,
                           pads=tqp != tq_real or tkp != tk_real,
                           window=window),
         grid=(bh, nq, nk),
-        in_specs=[q_spec_q, k_spec_q, k_spec_q, q_spec_q,
+        in_specs=[q_spec_q(d), k_spec_q(d), k_spec_q(d_v), q_spec_q(d_v),
                   row_spec_q, row_spec_q],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        out_specs=q_spec_q(d),
         out_shape=jax.ShapeDtypeStruct((bh, tqp, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
@@ -637,16 +654,23 @@ def _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale, block_q,
     lse_t = lse[:, None, :]
     delta_t = delta[:, None, :]
     if plain:
-        q_spec_k = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
-        k_spec_k = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
+        def q_spec_k(w):
+            return pl.BlockSpec((1, block_q, w), lambda b, j, i: (b, i, 0))
+
+        def k_spec_k(w):
+            return pl.BlockSpec((1, block_k, w), lambda b, j, i: (b, j, 0))
         row_spec_k = pl.BlockSpec((1, 1, block_q),
                                   lambda b, j, i: (b, 0, i))
     else:
         def iq_of(i, j):
             return _live_q(i, j, window, block_q, block_k, offset, nq)
-        q_spec_k = pl.BlockSpec((1, block_q, d),
+
+        def q_spec_k(w):
+            return pl.BlockSpec((1, block_q, w),
                                 lambda b, j, i: (b, iq_of(i, j), 0))
-        k_spec_k = pl.BlockSpec((1, block_k, d),
+
+        def k_spec_k(w):
+            return pl.BlockSpec((1, block_k, w),
                                 lambda b, j, i: (_kv_head(b, group), j, 0))
         row_spec_k = pl.BlockSpec((1, 1, block_q),
                                   lambda b, j, i: (b, 0, iq_of(i, j)))
@@ -657,23 +681,23 @@ def _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale, block_q,
                           pads=tqp != tq_real or tkp != tk_real,
                           window=window),
         grid=(bh, nk, nq),
-        in_specs=[q_spec_k, k_spec_k, k_spec_k, q_spec_k,
+        in_specs=[q_spec_k(d), k_spec_k(d), k_spec_k(d_v), q_spec_k(d_v),
                   row_spec_k, row_spec_k],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d_v), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[jax.ShapeDtypeStruct((bh, tkp, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, tkp, d), v.dtype)],
+                   jax.ShapeDtypeStruct((bh, tkp, d_v), v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+                        pltpu.VMEM((block_k, d_v), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dkv",
     )(q, k, v, do, lse_t, delta_t)
     if group > 1:
         dk = dk.reshape(bh // group, group, tkp, d).astype(
             jnp.float32).sum(axis=1).astype(k.dtype)
-        dv = dv.reshape(bh // group, group, tkp, d).astype(
+        dv = dv.reshape(bh // group, group, tkp, d_v).astype(
             jnp.float32).sum(axis=1).astype(v.dtype)
     return dq[:, :tq], dk[:, :tk], dv[:, :tk]
 
@@ -690,6 +714,7 @@ def _flash_fwd_jax(q, k, v, bias, causal, sm_scale, block_k, offset,
     if group > 1:
         k, v = jnp.repeat(k, group, axis=0), jnp.repeat(v, group, axis=0)
     bh, tq, d = q.shape
+    d_v = v.shape[2]
     tk = k.shape[1]
     block_k = min(block_k, tk)
     pad_k = (-tk) % block_k
@@ -701,7 +726,7 @@ def _flash_fwd_jax(q, k, v, bias, causal, sm_scale, block_k, offset,
                            constant_values=NEG_INF)
     nk = (tk + pad_k) // block_k
     kc = k.reshape(bh, nk, block_k, d).transpose(1, 0, 2, 3)
-    vc = v.reshape(bh, nk, block_k, d).transpose(1, 0, 2, 3)
+    vc = v.reshape(bh, nk, block_k, d_v).transpose(1, 0, 2, 3)
     if bias is not None:
         bc = bias.reshape(bias.shape[0], tq, nk, block_k
                           ).transpose(2, 0, 1, 3)
@@ -739,7 +764,7 @@ def _flash_fwd_jax(q, k, v, bias, causal, sm_scale, block_k, offset,
     zero = (q32[0, 0, 0] + k[0, 0, 0].astype(jnp.float32)) * 0.0
     init = (jnp.full((bh, tq, 1), NEG_INF, jnp.float32) + zero,
             jnp.zeros((bh, tq, 1), jnp.float32) + zero,
-            jnp.zeros((bh, tq, d), jnp.float32) + zero)
+            jnp.zeros((bh, tq, d_v), jnp.float32) + zero)
     xs = (kc, vc, bc, jnp.arange(nk)) if bias is not None else \
          (kc, vc, jnp.arange(nk))
     (m, l, acc), _ = jax.lax.scan(step, init, xs)
@@ -769,6 +794,7 @@ def _flash_bwd_jax(q, k, v, bias, o, lse, do, causal, sm_scale, block_k,
                 (-1, group) + g.shape[1:]).sum(axis=1).astype(g.dtype)
         return dq, fold(dk), fold(dv), db
     bh, tq, d = q.shape
+    d_v = v.shape[2]
     tk = k.shape[1]
     block_k = min(block_k, tk)
     pad_k = (-tk) % block_k
@@ -780,7 +806,7 @@ def _flash_bwd_jax(q, k, v, bias, o, lse, do, causal, sm_scale, block_k,
                            constant_values=NEG_INF)
     nk = (tk + pad_k) // block_k
     kc = k.reshape(bh, nk, block_k, d).transpose(1, 0, 2, 3)
-    vc = v.reshape(bh, nk, block_k, d).transpose(1, 0, 2, 3)
+    vc = v.reshape(bh, nk, block_k, d_v).transpose(1, 0, 2, 3)
     if bias is not None:
         bc = bias.reshape(bias.shape[0], tq, nk, block_k
                           ).transpose(2, 0, 1, 3)
@@ -834,7 +860,7 @@ def _flash_bwd_jax(q, k, v, bias, o, lse, do, causal, sm_scale, block_k,
         dkc, dvc = outs
         dbc = None
     dk = dkc.transpose(1, 0, 2, 3).reshape(bh, tk + pad_k, d)[:, :tk]
-    dv = dvc.transpose(1, 0, 2, 3).reshape(bh, tk + pad_k, d)[:, :tk]
+    dv = dvc.transpose(1, 0, 2, 3).reshape(bh, tk + pad_k, d_v)[:, :tk]
     db = None
     if dbc is not None:
         db = dbc.transpose(1, 2, 0, 3).reshape(
@@ -929,6 +955,25 @@ _BWD_DEFAULTS = {2048: (1024, 512), 4096: (1024, 1024), 8192: (1024, 512),
 _FWD_DEFAULTS_D128 = {4096: (1024, 1024), 8192: (1024, 1024)}
 _BWD_DEFAULTS_D128 = {4096: (1024, 512), 8192: (1024, 512, "split")}
 _BWD_WINDOW_DEFAULTS_D128 = {8192: (512, 512, "split")}
+# score width 128 < d_qk <= 256 (the values may be narrower: 192 over 128 is
+# latent attention's pair), on a v5e at causal [32, 8192, 192 | 128] bf16,
+# every head its own K/V (tools/joyai_kernel_probe.py, PR 34): forward
+# (1024, 1024) 8.28 ms ((512, 2048) 9.65, (512, 1024) 10.15, (1024, 512)
+# 13.46, (256, 1024) 13.47, (512, 512) 14.21; all compile).  Backward,
+# forward (512, 1024) + backward ms: split (1024, 512) 34.18, split (512,
+# 1024) 34.53, split (512, 512) 35.62, split (1024, 256) 37.08, split (512,
+# 256) 39.97, split (256, 512) 40.57; split (1024, 1024) runs out of VMEM;
+# "combined" would keep 2.68 GB of float32 dK/dV partials here, past
+# _COMBINED_PARTIAL_BUDGET, so it IS the split kernels (34.19).  The same
+# kernels at 128 | 128 read 5.46 and 21.26: a 192-wide contraction fills
+# two 128-deep MXU passes, so the scores cost what 256 would.  Other
+# lengths at this width keep the baseline until they are swept, and so do
+# float32 inputs (blocks twice the bytes: in the cell's float32 forward
+# program, where XLA fuses the operands' producers into the call, (1024,
+# 1024) passed the 16 MiB of scoped VMEM by 12 KiB, though the kernel alone
+# compiles; my chip run, PR 34).
+_FWD_DEFAULTS_D256 = {8192: (1024, 1024)}
+_BWD_DEFAULTS_D256 = {8192: (1024, 512, "split")}
 
 
 def _collapse_bias(bias, b, h, tq, tk):
@@ -955,6 +1000,9 @@ def _plan(q, k, v, bias, causal, sm_scale, block_q, block_k, block_q_bwd,
     hk, tk = k.shape[1], k.shape[2]
     if h % hk or v.shape[1] != hk:
         raise ValueError(f"{h} query heads over {hk}/{v.shape[1]} K/V heads")
+    if k.shape[3] != d:
+        raise ValueError(f"Q is {d} wide and K {k.shape[3]}: the scores "
+                         "contract over one width (V's may differ)")
     group = h // hk
     if window is not None:
         if not causal:
@@ -971,11 +1019,16 @@ def _plan(q, k, v, bias, causal, sm_scale, block_q, block_k, block_q_bwd,
     # transfer (XLA overlap + VMEM pressure shift the landscape), so the
     # tables hold the end-to-end winners.  Wider heads double the tile VMEM
     # (2048-wide K/V at d=128 matches configs that failed to compile), so
-    # 64<d<=128 has tables of its own, filled only where swept, and d>128
-    # keeps the long-validated baseline
+    # 64<d<=128 and 128<d<=256 have tables of their own, filled only where
+    # swept (4096 and 8192; 8192, bf16 inputs only), keyed by the SCORE
+    # width d (Q's and K's; V's may differ and was swept at 128 under 192
+    # only); d>256 and every other length keep the long-validated (512,
+    # 1024) baseline
     fwd_table, bwd_table = (
         (_FWD_DEFAULTS, _BWD_DEFAULTS) if d <= 64 else
-        (_FWD_DEFAULTS_D128, _BWD_DEFAULTS_D128) if d <= 128 else ({}, {}))
+        (_FWD_DEFAULTS_D128, _BWD_DEFAULTS_D128) if d <= 128 else
+        (_FWD_DEFAULTS_D256, _BWD_DEFAULTS_D256)
+        if d <= 256 and q.dtype.itemsize <= 2 else ({}, {}))
     if block_q is None and block_k is None:
         block_q, block_k = fwd_table.get(max(tq, tk), (512, 1024))
     if block_q is None:
@@ -998,7 +1051,7 @@ def _plan(q, k, v, bias, causal, sm_scale, block_q, block_k, block_q_bwd,
             bwd_impl = bwd_impl or (impl[0] if impl else None)
     qc = q.reshape(b * h, tq, d)
     kc = k.reshape(b * hk, tk, d)
-    vc = v.reshape(b * hk, tk, d)
+    vc = v.reshape(b * hk, tk, v.shape[3])   # V's own width, the output's
     bc = None if bias is None else _collapse_bias(bias, b, h, tq, tk)
     return (qc, kc, vc, bc), (causal, sm_scale, block_q, block_k, bwd_blocks,
                               bwd_impl, interpret, window, group)
@@ -1013,7 +1066,11 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
                     bwd_impl: Optional[str] = None,
                     interpret: bool = False,
                     window: Optional[int] = None):
-    """Fused attention over [batch, heads, T, head_dim] tensors.
+    """Fused attention over [batch, heads, T, head_dim] tensors: Q and K
+    ``[.., d_qk]``, V ``[.., d_v]``, the result ``[batch, heads, Tq, d_v]``
+    (``d_v`` read from V; the two are one width for every model but latent
+    attention's, and then the lowering is what it always was).  ``sm_scale``
+    defaults to ``d_qk ** -0.5``.
 
     ``window`` (with ``causal=True``): key ``j`` is visible to query ``i``
     iff ``0 <= i - j < window``.  The kernels skip the blocks wholly outside
@@ -1030,8 +1087,9 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
     ``bias`` broadcasts over (batch, heads): accepted shapes are
     [b, h, Tq, Tk], [1, 1, Tq, Tk] or [Tq, Tk].
 
-    Default blocks are per-sequence-length tables (below) at d≤64 and, for
-    the lengths swept there, at 64<d≤128, else (512, 1024) capped at the
+    Default blocks are per-sequence-length tables (below) at d_qk≤64 and,
+    for the lengths swept there, at 64<d_qk≤128 (4096, 8192) and
+    128<d_qk≤256 (8192, at 192 over 128, bf16 inputs), else (512, 1024) capped at the
     sequence lengths — measured on v5e: ahead
     of XLA's O(T²) attention from T≈1024, and the only runnable path
     beyond ~8k (r4 prior: 11.0 ms fwd / 45.1 ms f+b at [12,16384,64] —
@@ -1048,7 +1106,8 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
     (qc, kc, vc, bc), statics = _plan(
         q, k, v, bias, causal, sm_scale, block_q, block_k, block_q_bwd,
         block_k_bwd, bwd_impl, interpret, window)
-    return _flash(qc, kc, vc, bc, *statics).reshape(q.shape)
+    return _flash(qc, kc, vc, bc, *statics).reshape(
+        q.shape[:3] + v.shape[3:])
 
 
 def flash_attention_fwd(q, k, v, bias=None, causal=False, sm_scale=None,
@@ -1066,7 +1125,7 @@ def flash_attention_fwd(q, k, v, bias=None, causal=False, sm_scale=None,
         q, k, v, bias, causal, sm_scale, block_q, block_k, block_q_bwd,
         block_k_bwd, bwd_impl, interpret, window)
     o, (*_, lse) = _flash_vjp_fwd(qc, kc, vc, bc, *statics)
-    return o.reshape(q.shape), lse.reshape(b, h, tq)
+    return o.reshape(b, h, tq, v.shape[3]), lse.reshape(b, h, tq)
 
 
 def flash_attention_bwd(q, k, v, bias, o, lse, do, causal=False,
@@ -1085,8 +1144,9 @@ def flash_attention_bwd(q, k, v, bias, o, lse, do, causal=False,
         q, k, v, bias, causal, sm_scale, block_q, block_k, block_q_bwd,
         block_k_bwd, bwd_impl, interpret, window)
     dq, dk, dv, db = _flash_vjp_bwd(
-        *statics, (qc, kc, vc, bc, o.reshape(qc.shape),
-                   lse.reshape(b * h, tq)), do.reshape(qc.shape), need_dbias)
+        *statics, (qc, kc, vc, bc, o.reshape(b * h, tq, v.shape[3]),
+                   lse.reshape(b * h, tq)),
+        do.reshape(b * h, tq, v.shape[3]), need_dbias)
     if db is not None:
         # the transpose of _collapse_bias: summed over what it broadcast
         db, = jax.vjp(lambda x: _collapse_bias(x, b, h, tq, k.shape[2]),

@@ -253,18 +253,18 @@ def test_a_training_step_runs_the_forward_half_once_a_layer(monkeypatch):
         monkeypatch.setattr(
             F, "_flash_fwd", lambda *a, **k: calls.append(1) or real(*a, **k))
         before = (FLASH_LOWERINGS_CTR.value(window="12", kv_groups="2",
-                                            impl="jax"),
+                                            impl="jax", widths="8/8"),
                   FLASH_GRAD_LOWERINGS_CTR.value(window="none",
-                                                 kv_groups="2", impl="jax"),
+                                                 kv_groups="2", impl="jax", widths="8/8"),
                   FLASH_GRAD_LOWERINGS_CTR.value(window="12", kv_groups="2",
-                                                 impl="jax"))
+                                                 impl="jax", widths="8/8"))
         losses = _train_steps(loss, scope)
     assert len(calls) == 2, len(calls)
-    assert (FLASH_LOWERINGS_CTR.value(window="12", kv_groups="2", impl="jax"),
+    assert (FLASH_LOWERINGS_CTR.value(window="12", kv_groups="2", impl="jax", widths="8/8"),
             FLASH_GRAD_LOWERINGS_CTR.value(window="none", kv_groups="2",
-                                           impl="jax"),
+                                           impl="jax", widths="8/8"),
             FLASH_GRAD_LOWERINGS_CTR.value(window="12", kv_groups="2",
-                                           impl="jax")) == \
+                                           impl="jax", widths="8/8")) == \
         tuple(b + 1 for b in before)
     assert losses[1] < losses[0]
 

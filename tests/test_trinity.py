@@ -87,8 +87,9 @@ def _batch(cfg, b, seq, seed=0):
 
 def test_flash_lowerings_are_counted_by_window_and_groups():
     from paddle_tpu.ops.attention_ops import FLASH_LOWERINGS_CTR as ctr
-    labels = dict(window="8", kv_groups="2", impl="jax")
-    full = dict(window="none", kv_groups="2", impl="jax")
+    labels = dict(window="8", kv_groups="2", impl="jax", widths="16/16")
+    full = dict(window="none", kv_groups="2", impl="jax",
+                widths="16/16")
     before = ctr.value(**labels), ctr.value(**full)
     cfg = toy_cfg(n_layer=2, layer_types=TYPES[1:], n_dense_layer=1)
     scope, main, exe, _, loss = _model(cfg, 16)

@@ -1,0 +1,223 @@
+"""Times the flash attention kernels alone at the JoyAI-LLM-Flash cell's
+shapes on the chip: latent attention's [1, 32, 8192, 192] queries and keys
+over [1, 32, 8192, 128] values in bf16, the causal half, forward and
+forward + backward, over block sizes and both backward kernels; and what the
+rotary key costs as the program ships it: the 64-wide rotary key is one head
+for all 32, broadcast and concatenated behind each head's 128-wide content
+part outside the kernel (``build``: Q's and K's concatenation, forward, and
+their transposes, backward, as XLA fuses them alone), which is the most a
+kernel that took the score as two products could save.  ``128/128`` rows are
+the same kernels at Trinity's width, for the rate.  Prints one JSON line per
+case: forward ms, forward + backward ms, the backward's temporaries and, for
+the block tables' own choice, how far the output and the gradients are from
+``mha_reference`` (float32 at ``highest`` over the same bf16 inputs, eight
+heads at a time).
+
+    chiprun -- python3 tools/joyai_kernel_probe.py
+    JAX_PLATFORMS=cpu python3 tools/joyai_kernel_probe.py --aot   # compiles
+        each block choice for a described v5e, runs nothing: which fit VMEM
+"""
+
+import argparse
+import importlib
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+FWD_BLOCKS = "512,1024;1024,1024;512,512;1024,512;256,1024;512,2048"
+# "combined" at [32, 8192, 192 | 128] would keep 2.68 GB of float32 dK/dV
+# partials at 1024-row query blocks: past _COMBINED_PARTIAL_BUDGET, so the
+# entry point runs the split kernels whatever is asked (one row shows it)
+BWD_BLOCKS = ("combined,1024,512;split,1024,512;split,512,512;"
+              "split,512,1024;split,1024,1024;split,256,512;split,1024,256;"
+              "split,512,256")
+
+
+def _blocks(text):
+    return [tuple(x if i == 0 and not x.isdigit() else int(x)
+                  for i, x in enumerate(b.split(",")))
+            for b in text.split(";") if b]
+
+
+def aot(args, F):
+    """Every block choice compiled for a described v5e chip (nothing runs):
+    the Mosaic compiler's verdict on the VMEM a block takes."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    one = SingleDeviceSharding(topo.devices[0])
+    bh, t = args.heads, args.seq
+
+    def s(w, dtype=jnp.dtype(args.dtype)):
+        return jax.ShapeDtypeStruct((bh, t, w), dtype, sharding=one)
+    sm = args.d_qk ** -0.5
+    for bq, bk in _blocks(args.fwd_blocks):
+        fn = jax.jit(lambda q, k, v: F._flash_fwd_pallas(
+            q, k, v, None, True, sm, bq, bk, 0, False))
+        try:
+            fn.lower(s(args.d_qk), s(args.d_qk), s(args.d_v)).compile()
+            row = {"fwd": [bq, bk], "compiles": True}
+        except Exception as e:
+            row = {"fwd": [bq, bk], "compiles": False,
+                   "error": str(e).strip().splitlines()[-1][:160]}
+        print(json.dumps(row), flush=True)
+    lse = jax.ShapeDtypeStruct((bh, t), jnp.float32, sharding=one)
+    for impl, bq, bk in _blocks(args.bwd_blocks):
+        fn = jax.jit(lambda q, k, v, o, lse, do: F._flash_bwd_pallas(
+            q, k, v, o, lse, do, True, sm, bq, bk, 0, False, impl=impl))
+        try:
+            c = fn.lower(s(args.d_qk), s(args.d_qk), s(args.d_v),
+                         s(args.d_v), lse, s(args.d_v)).compile()
+            row = {"bwd": [impl, bq, bk], "compiles": True, "temp_gb":
+                   c.memory_analysis().temp_size_in_bytes / 1e9}
+        except Exception as e:
+            row = {"bwd": [impl, bq, bk], "compiles": False,
+                   "error": str(e).strip().splitlines()[-1][:160]}
+        print(json.dumps(row), flush=True)
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--seq", type=int, default=8192)
+    ap.add_argument("--heads", type=int, default=32)
+    ap.add_argument("--d_qk", type=int, default=192)
+    ap.add_argument("--d_v", type=int, default=128)
+    ap.add_argument("--d_rope", type=int, default=64)
+    ap.add_argument("--fwd_blocks", default=FWD_BLOCKS)
+    ap.add_argument("--bwd_blocks", default=BWD_BLOCKS)
+    ap.add_argument("--iters", type=int, default=10)
+    ap.add_argument("--aot", action="store_true")
+    ap.add_argument("--dtype", default="bfloat16",
+                    help="--aot: the inputs' type (the cell's float32 "
+                    "forward check runs the kernels on float32)")
+    args = ap.parse_args()
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu  # noqa: F401
+    F = importlib.import_module("paddle_tpu.pallas.flash_attention")
+    if args.aot:
+        return aot(args, F)
+    interpret = jax.default_backend() != "tpu"
+    if interpret:                                  # a rehearsal of the path
+        args.seq, args.heads, args.iters = 64, 4, 1
+        args.d_qk, args.d_v, args.d_rope = 24, 16, 8
+        args.fwd_blocks, args.bwd_blocks = "16,16", "combined,16,16"
+    h, t = args.heads, args.seq
+    d_nope = args.d_qk - args.d_rope
+    key = jax.random.PRNGKey(0)
+
+    def rand(i, heads, w):
+        return jax.random.normal(jax.random.fold_in(key, i),
+                                 (1, heads, t, w), jnp.bfloat16)
+    q_nope, q_rope = rand(0, h, d_nope), rand(1, h, args.d_rope)
+    k_nope, k_rope = rand(2, h, d_nope), rand(3, 1, args.d_rope)
+    v, do = rand(4, h, args.d_v), rand(5, h, args.d_v)
+    sm = args.d_qk ** -0.5
+
+    def build(q_nope, q_rope, k_nope, k_rope):
+        """Q and K as the kernel reads them: the program's concat and
+        broadcast."""
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope, k_nope.shape[:3]
+                                      + (args.d_rope,))], axis=-1)
+        return q, k
+    q, k = jax.jit(build)(q_nope, q_rope, k_nope, k_rope)
+
+    def timed(fn, *a):
+        jax.block_until_ready(fn(*a))
+        t0 = time.perf_counter()
+        for _ in range(args.iters):
+            out = fn(*a)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / args.iters * 1e3
+
+    def say(row, fn):
+        try:
+            fn(row)
+        except Exception as e:                     # VMEM, HBM: say and go on
+            row["error"] = str(e).strip().splitlines()[-1][:200]
+        print(json.dumps(row), flush=True)
+
+    # what building Q and K costs alone, forward and with its transpose
+    def build_row(row):
+        dq, dk = jnp.ones_like(q), jnp.ones_like(k)
+        row["fwd_ms"] = timed(jax.jit(build), q_nope, q_rope, k_nope, k_rope)
+        row["fwd_bwd_ms"] = timed(jax.jit(lambda *a: jax.vjp(build, *a[:4])[
+            1](a[4:])), q_nope, q_rope, k_nope, k_rope, dq, dk)
+        row["bytes_written_fwd"] = int(q.size + k.size) * 2
+    say({"case": "build q and k"}, build_row)
+
+    def attn(kw):
+        return lambda q, k, v: F.flash_attention(
+            q, k, v, causal=True, sm_scale=sm, interpret=interpret, **kw)
+
+    def vjp_of(f):
+        return jax.jit(lambda q, k, v, do: jax.vjp(f, q, k, v)[1](do))
+
+    def oracle():
+        @jax.jit
+        def one(qg, kg, vg, dog):
+            f32 = [a.astype(jnp.float32) for a in (qg, kg, vg)]
+            with jax.default_matmul_precision("highest"):
+                o, back = jax.vjp(lambda q, k, v: F.mha_reference(
+                    q, k, v, causal=True, sm_scale=sm), *f32)
+                return (o,) + back(dog.astype(jnp.float32))
+        g = min(8, h)
+        parts = [one(*(a[:, i:i + g] for a in (q, k, v, do)))
+                 for i in range(0, h, g)]
+        return [jnp.concatenate(x, axis=1) for x in zip(*parts)]
+
+    def off(got, want):
+        got = got.astype(jnp.float32)
+        return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+
+    # the tables' own choice, against the oracle
+    def default_row(row):
+        kw = dict(block_q=16, block_k=16) if interpret else {}
+        fwd, both = jax.jit(attn(kw)), vjp_of(attn(kw))
+        row["fwd_ms"] = timed(fwd, q, k, v)
+        row["fwd_bwd_ms"] = timed(both, q, k, v, do)
+        row["temp_gb"] = both.lower(q, k, v, do).compile(
+        ).memory_analysis().temp_size_in_bytes / 1e9
+        got = (fwd(q, k, v),) + tuple(both(q, k, v, do))
+        row.update({f"{n}_rel": off(g, w) for n, g, w in zip(
+            ("o", "dq", "dk", "dv"), got, oracle())})
+    say({"case": "tables", "d_qk": args.d_qk, "d_v": args.d_v}, default_row)
+
+    for bq, bk in _blocks(args.fwd_blocks):
+        say({"case": "fwd", "blocks": [bq, bk]}, lambda row: row.update(
+            fwd_ms=timed(jax.jit(attn(dict(block_q=bq, block_k=bk))),
+                         q, k, v)))
+    fq, fk = _blocks(args.fwd_blocks)[0]
+    for impl, bq, bk in _blocks(args.bwd_blocks):
+        def bwd_row(row):
+            both = vjp_of(attn(dict(block_q=fq, block_k=fk, block_q_bwd=bq,
+                                    block_k_bwd=bk, bwd_impl=impl)))
+            row["fwd_bwd_ms"] = timed(both, q, k, v, do)
+            row["temp_gb"] = both.lower(q, k, v, do).compile(
+            ).memory_analysis().temp_size_in_bytes / 1e9
+        say({"case": "bwd", "fwd_blocks": [fq, fk], "bwd": impl,
+             "blocks": [bq, bk]}, bwd_row)
+
+    # the same kernels at 128/128 (Trinity's width, every head its own K/V)
+    if not interpret:
+        def narrow(row):
+            q8, k8 = q[..., :128], k[..., :128]
+            f = lambda q, k, v: F.flash_attention(q, k, v, causal=True)  # noqa
+            row["fwd_ms"] = timed(jax.jit(f), q8, k8, v)
+            row["fwd_bwd_ms"] = timed(vjp_of(f), q8, k8, v, do)
+        say({"case": "tables", "d_qk": 128, "d_v": 128}, narrow)
+
+
+if __name__ == "__main__":
+    main()

@@ -25,8 +25,10 @@ from benchmark import harness, op_scopes, part_scopes  # noqa: E402
 
 
 #: scopes a model nests inside an op's own (``flash_attention``'s ``window``,
-#: ``framework.name_scope`` tags)
-TAGS = ("window", "shared_expert", "dense_ffn")
+#: ``framework.name_scope`` tags; a tag nested in another is joined to it by
+#: a dot, and the longer name comes first so that it is the one found)
+TAGS = ("window", "mtp.mla_proj", "mtp.shared_expert", "mtp", "mla_proj",
+        "shared_expert", "dense_ffn")
 
 
 def main():
@@ -50,7 +52,8 @@ def main():
             moe[f"{role}/{part or '-'}"] = moe.get(f"{role}/{part or '-'}",
                                                    0.0) + s
     # what a model tags inside an op's scope: the windowed layers of
-    # flash_attention, the dense ops of a shared expert or a dense FFN
+    # flash_attention, the dense ops of a shared expert or a dense FFN,
+    # latent attention's projections, a multi-token-prediction module
     tagged = {}
     for (role, op, part), sec in part_scopes.reduce_parts(
             paths[-1], (lo, hi), TAGS).items():
