@@ -4,7 +4,9 @@ No reference counterpart (the 2019 snapshot has no MoE).  Two ops over one
 routing scheme: the slot -> expert ids are sorted (stable), the rows gathered
 into that order, each expert's rows multiplied as one group of a grouped
 matmul whose group sizes are data, and the results un-sorted.  Every shape is
-static, whatever the routing; no one-hot ``[S, E, C]`` dispatch tensor.
+static, whatever the routing (a chip's share of the experts picks its row
+buffer's length from a short ladder of static lengths, below); no one-hot
+``[S, E, C]`` dispatch tensor.
 
 - ``moe_ffn``: dropless top-k over gated-SiLU experts (OLMoE, Mixtral; with
   ``score_func="sigmoid"``, a selection bias, renormalised and scaled
@@ -29,15 +31,26 @@ was before they existed, bit for bit):
   The op computes ``sum_{e in top-k, offset <= e < offset + E_here} w_e *
   expert_e(x)`` with ``w`` normalised over all ``k`` chosen: this chip's
   part of the layer.  Only the slots routed to held experts are multiplied;
-  the sort puts them first, and the static row buffer is ``S * min(k,
-  E_here)`` rows, the most a routing can send here, so the held experts drop
-  nothing whatever the load.  ``ExpertLoad`` stays ``[E_total]``.  Nothing
-  stands in for the absent experts or their exchange.
+  the sort puts them first.  The row buffer's length follows ``ExpertLoad``:
+  ``held_ladder`` gives a few static lengths from the shapes alone (from
+  about twice even routing's share, doubling, up to ``S * min(k, E_here)``,
+  the most a routing can send here), and the router's own count of the held
+  rows picks the shortest that holds them all (``jax.lax.switch`` over
+  copies of each row gather, of the gate's pass and of each weighted sum,
+  one a length; the grouped matmuls stay outside at the longest length,
+  since they visit the held rows' tiles and no other whatever lies behind).
+  Every rung holds every held row, so the held experts drop nothing
+  whatever the load; ``moe_ffn_grad`` picks its rung from the same count.
+  ``Saved`` keeps the longest rung's shapes: a shorter rung writes the
+  front.  ``ExpertLoad`` stays ``[E_total]``.  Nothing stands in for the
+  absent experts or their exchange.
 
 The layers annotate the expert weights with dist_spec ``("ep", ...)``.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -139,7 +152,9 @@ MOE_LOWERINGS_CTR = _monitor.REGISTRY.counter(
     "the number of experts and the experts per token — counted while "
     "tracing, once per compile of a block that holds the op, nothing per "
     "step; held = the experts whose weights the op holds, score_func = the "
-    "router's score", ("impl", "experts", "top_k", "held", "score_func"))
+    "router's score, ladder = the static lengths the row buffer of a chip's "
+    "share chooses from, shortest first ('' where every expert is held)",
+    ("impl", "experts", "top_k", "held", "score_func", "ladder"))
 
 
 MOE_ROUTED_ROWS_CTR = _monitor.REGISTRY.counter(
@@ -150,14 +165,35 @@ MOE_ROUTED_ROWS_CTR = _monitor.REGISTRY.counter(
     "by whoever fetched the loads, nothing per step", ("where",))
 
 
+MOE_HELD_BUFFER_CTR = _monitor.REGISTRY.counter(
+    "paddle_tpu_moe_held_buffer_total",
+    "of the ExpertLoad outputs handed to record_expert_load for a chip's "
+    "share of the experts, how many selected a row buffer of `rows` rows: "
+    "the rung of held_ladder that the lowering's own rule (held_rung) picks "
+    "for that load.  Counted on the host by whoever fetched the loads, "
+    "nothing per step", ("rows",))
+
+
+#: {(S * k, E, E_here): ladder} of the held-path lowerings traced in this
+#: process: what record_expert_load cannot read off a load (``load.sum()`` is
+#: ``S * k`` and ``load.size`` is ``E``; the ladder also needs ``S``)
+_TRACED_LADDERS = {}
+
+
 def record_expert_load(load, expert_offset=0, n_held=None):
     """Add one fetched ``ExpertLoad`` [E_total] (a host array) to
-    ``paddle_tpu_moe_routed_rows_total``; ``n_held`` defaults to all."""
+    ``paddle_tpu_moe_routed_rows_total``; ``n_held`` defaults to all.  For a
+    share of the experts whose lowering this process traced, also count the
+    buffer length that load selects (``paddle_tpu_moe_held_buffer_total``)."""
     load = np.asarray(load).reshape(-1)
     n_held = load.size if n_held is None else int(n_held)
+    held_rows = int(load[expert_offset:expert_offset + n_held].sum())
     MOE_ROUTED_ROWS_CTR.inc(int(load.sum()), where="all")
-    MOE_ROUTED_ROWS_CTR.inc(
-        int(load[expert_offset:expert_offset + n_held].sum()), where="held")
+    MOE_ROUTED_ROWS_CTR.inc(held_rows, where="held")
+    ladder = _TRACED_LADDERS.get((int(load.sum()), load.size, n_held))
+    if ladder is not None:
+        MOE_HELD_BUFFER_CTR.inc(rows=str(ladder[held_rung(held_rows,
+                                                          ladder)]))
 
 
 #: megablox tile sizes (rows, contraction, columns) for the bf16 expert
@@ -213,15 +249,17 @@ def _gate(g, u, dt):
             ).astype(dt)
 
 
-def gated_experts(xs, wg, wu, wd, load, dt, impl=None, tiling=None):
+def gated_experts(xs, wg, wu, wd, load, dt, impl=None, tiling=None,
+                  gate=_gate):
     """``Wd_e (silu(Wg_e x) * Wu_e x)`` for sorted rows ``xs`` [R, d] whose
     expert is given by the run lengths ``load`` [E]; operands in ``dt``,
     accumulation and the gate's arithmetic in float32.  Returns ``(y, g,
-    u)``: the result and the two projections a backward needs."""
+    u)``: the result and the two projections a backward needs.  ``gate(g,
+    u, dt)``: the held path's, which passes over a rung's rows only."""
     mm = _grouped_matmul(dt, impl, tiling)
     g = mm(xs, wg, load)
     u = mm(xs, wu, load)
-    return mm(_gate(g, u, dt), wd, load), g, u
+    return mm(gate(g, u, dt), wd, load), g, u
 
 
 def _router(xt, wr, k, renorm, score_func="softmax", bias=None,
@@ -274,14 +312,110 @@ def _held_slots(top_e, offset, n_held, k):
     """For a chip that holds experts ``offset .. offset + n_held - 1`` of a
     wider router: ``held`` [R] bool (the slot's expert lives here), ``order``
     [R] (the slots sorted by local expert, stable, the slots of absent
-    experts last), ``place`` [R] its inverse, and ``rows``, the static
-    length of the row buffer: ``S * min(k, n_held)``, the most slots a
-    routing can send here (a token's ``k`` experts are distinct)."""
+    experts last) and ``place`` [R] its inverse.  All three are over slot
+    ids, whatever the row buffer's length turns out to be."""
     S = top_e.shape[0]
     local = top_e.reshape(S * k) - offset
     held = (local >= 0) & (local < n_held)
     order, place = _sorted_slots(jnp.where(held, local, n_held))
-    return held, order, place, S * min(k, n_held)
+    return held, order, place
+
+
+def held_ladder(S, k, n_held, E):
+    """The static lengths, shortest first, that the row buffer of a chip's
+    share of the experts (``n_held`` of ``E``, ``S`` tokens, ``k`` experts a
+    token) chooses from; a function of these four alone.  The last is ``S *
+    min(k, n_held)``, the most slots a routing can send here (a token's ``k``
+    experts are distinct); before it, from twice even routing's share ``S *
+    k * n_held / E`` (fresh weights send 0.7 to 1.5 of it, PERF.md) doubling
+    up to a quarter of the last, each a multiple of the held path's row tile
+    so that a rung multiplies the same rows in the same tiles as the full
+    buffer does.  A quarter: on a v5e at both cells' sizes a rung of half
+    the buffer is no faster than the whole (the un-sorts cost per slot
+    unless their source is short, and every rung has its copies to the
+    front to pay: tools/trinity_experts_sweep.py --lengths, PERF.md section
+    6, PR 35); so at most three lengths under the last, here two."""
+    full, tile = S * min(k, n_held), _GMM_TILING_HELD[0]
+    rung = -(-2 * S * k * n_held // (E * tile)) * tile
+    ladder = []
+    while 4 * rung <= full:
+        ladder.append(rung)
+        rung *= 2
+    return tuple(ladder[:3]) + (full,)
+
+
+def held_rung(held_rows, ladder):
+    """The index of the shortest rung of ``ladder`` that holds ``held_rows``
+    rows (a traced scalar in the lowerings, a number on the host): the one
+    rule ``moe_ffn``, ``moe_ffn_grad`` and ``record_expert_load`` pick by."""
+    return sum((held_rows > rows) * 1 for rows in ladder[:-1])
+
+
+def _over_rungs(ladder, held_rows, part, *operands):
+    """``part(rows, *operands)`` at the rung of ``ladder`` that holds
+    ``held_rows``; no switch where the ladder has one rung.  A branch's
+    results are pinned inside it: XLA's conditional code motion otherwise
+    lifts the sum that ends every branch out of the switch and makes the
+    ``[S * k, d]`` float32 rows before it a result of the switch (537 MB a
+    layer at Trinity-Mini's sizes, in the TPU compiler's output for the
+    step, PR 35)."""
+    if len(ladder) == 1:
+        return part(ladder[0], *operands)
+
+    def pinned(rows):
+        return lambda *a: jax.lax.optimization_barrier(part(rows, *a))
+    return jax.lax.switch(held_rung(held_rows, ladder),
+                          [pinned(rows) for rows in ladder], *operands)
+
+
+def _front(a, rows):
+    """``a`` [L, n] as the front of a ``rows``-long buffer whose other rows
+    nobody writes (they are never read unmasked).  On a TPU a Pallas copy
+    over ``a``'s row tiles into an output of the full length: the blocks it
+    does not visit stay as they were allocated.  (``jax.lax.empty`` + an
+    in-place update reads half a millisecond a layer faster in the op alone
+    and is left for later: PERF.md section 7, row 28.)  Elsewhere zeros."""
+    if a.shape[0] == rows:
+        return a
+    from ..device import on_tpu
+    if not on_tpu():
+        return jnp.pad(a, ((0, rows - a.shape[0]), (0, 0)))
+    return _front_copy(*a.shape, rows, a.dtype, _GMM_TILING_HELD[0])(a)
+
+
+@functools.lru_cache(maxsize=None)
+def _front_copy(length, width, rows, dtype, tile):
+    """``_front``'s Pallas copy in row tiles of ``tile`` (every shorter
+    rung's divisor), jitted and kept by shape: a step holds some dozens of
+    fronts of two to four shapes (six a layer and rung, the forward's again
+    under recomputation), and each one traced and lowered for itself was
+    1.5 s of set-up in JoyAI-LLM-Flash's cell (PERF.md section 6, PR 35)."""
+    from jax.experimental import pallas as pl
+
+    def copy(src, dst):
+        dst[...] = src[...]
+    block = pl.BlockSpec((tile, width), lambda i: (i, 0))
+    return jax.jit(pl.pallas_call(
+        copy, grid=(length // tile,), in_specs=[block], out_specs=block,
+        out_shape=jax.ShapeDtypeStruct((rows, width), dtype),
+        name="moe_front"))
+
+
+def _gate_front(ladder, held_rows, g, u, dt):
+    """``_gate`` over the rows of the rung that holds ``held_rows``, at the
+    front of a buffer of the longest rung's length."""
+    return _over_rungs(ladder, held_rows, lambda rows, g, u: _front(
+        _gate(g[:rows], u[:rows], dt), ladder[-1]), g, u)
+
+
+def _gate_backward(g, u, dh, dt):
+    """``(dg, du)`` of ``_gate(g, u)`` given ``dh``: float32 arithmetic,
+    stored in ``dt``."""
+    f32 = jnp.float32
+    gf, uf, dhf = g.astype(f32), u.astype(f32), dh.astype(f32)
+    sig = jax.nn.sigmoid(gf)
+    return ((dhf * uf * sig * (1.0 + gf * (1.0 - sig))).astype(dt),
+            (dhf * gf * sig).astype(dt))
 
 
 def _moe_dtype(ctx, x):
@@ -317,8 +451,15 @@ def _moe_ffn(ctx, ins, attrs):
     of the ``S*k`` slot -> expert ids, one row gather), ``experts`` (three
     grouped matmuls whose group sizes are data), ``combine`` (un-sort, weight
     by ``p_e``, sum the ``k``).  Every shape is static; no ``[S, E, C]``
-    tensor exists.  Under AMP the rows and the expert weights are bf16 with
-    float32 accumulation."""
+    tensor exists.  With a share of the experts the row buffer's length is
+    one of ``held_ladder``'s static lengths, the shortest that holds the
+    rows the router counted for the held experts (the held slots are sorted
+    to the front, so every rung is dropless and gives what the longest
+    gives); the row gather, the gate's pass and the weighted sum are each
+    lowered once a rung under a ``jax.lax.switch``; the sort, the router and
+    the grouped matmuls once (these visit the held rows' tiles and no other,
+    whatever the buffer's length).  Under AMP the rows and the expert
+    weights are bf16 with float32 accumulation."""
     x, wr = X(ins, "X"), X(ins, "RouterW")
     wg, wu, wd = X(ins, "GateW"), X(ins, "UpW"), X(ins, "DownW")
     k = int(attrs["top_k"])
@@ -330,11 +471,15 @@ def _moe_ffn(ctx, ins, attrs):
                          f" of a router over {E}")
     S = B * T
     dt = _moe_dtype(ctx, x)
+    ladder = () if n_held == E else held_ladder(S, k, n_held, E)
     if not getattr(ctx, "is_abstract", False):
         MOE_LOWERINGS_CTR.inc(
             impl=_experts_impl(dt), experts=str(E), top_k=str(k),
             held=str(n_held),
-            score_func=attrs.get("score_func", "softmax") or "softmax")
+            score_func=attrs.get("score_func", "softmax") or "softmax",
+            ladder=".".join(map(str, ladder)))
+        if ladder:
+            _TRACED_LADDERS[S * k, E, n_held] = ladder
     xt = x.reshape(S, d)
 
     with jax.named_scope("router"):
@@ -353,23 +498,35 @@ def _moe_ffn(ctx, ins, attrs):
             ys = jnp.take(y, place, axis=0).reshape(S, k, d)
             out = jnp.sum(ys.astype(jnp.float32) * top_p[:, :, None], axis=1)
     else:
+        # the buffer's length at each stage is the rung's: the front of the
+        # longest (Saved's shape), what lies behind not written.  The
+        # grouped matmuls visit the held run lengths' rows and no other:
+        # what lies behind them in the buffer is never multiplied (and
+        # never written, so never read unmasked below)
+        full = ladder[-1]
         with jax.named_scope("dispatch"):
-            held, order, place, rows = _held_slots(top_e, offset, n_held, k)
-            xs = jnp.take(xt.astype(dt), order[:rows] // k, axis=0)
+            held, order, place = _held_slots(top_e, offset, n_held, k)
+            load_here = jax.lax.dynamic_slice_in_dim(load, offset, n_held)
+            held_rows = jnp.sum(load_here)
+            xs = _over_rungs(
+                ladder, held_rows, lambda rows, xt, order: _front(jnp.take(
+                    xt.astype(dt), order[:rows] // k, axis=0), full),
+                xt, order)
 
         with jax.named_scope("experts"):
-            # the grouped matmuls visit the held run lengths' rows and no
-            # other: what lies behind them in the buffer is never multiplied
-            # (and never written, so never read unmasked below)
             y, g, u = gated_experts(
-                xs, wg, wu, wd,
-                jax.lax.dynamic_slice_in_dim(load, offset, n_held), dt,
-                tiling=_GMM_TILING_HELD)
+                xs, wg, wu, wd, load_here, dt, tiling=_GMM_TILING_HELD,
+                gate=lambda g, u, dt: _gate_front(ladder, held_rows, g, u,
+                                                  dt))
+
+        def weighted_sum(rows, y, place, held, top_p):
+            ys = jnp.take(y[:rows], jnp.minimum(place, rows - 1), axis=0)
+            ys = jnp.where(held[:, None], ys.astype(jnp.float32), 0.0)
+            return jnp.sum(ys.reshape(S, k, d) * top_p[:, :, None], axis=1)
 
         with jax.named_scope("combine"):
-            ys = jnp.take(y, jnp.minimum(place, rows - 1), axis=0)
-            ys = jnp.where(held[:, None], ys.astype(jnp.float32), 0.0)
-            out = jnp.sum(ys.reshape(S, k, d) * top_p[:, :, None], axis=1)
+            out = _over_rungs(ladder, held_rows, weighted_sum,
+                              y, place, held, top_p)
     return {"Out": [out.astype(x.dtype).reshape(B, T, d)], "LbLoss": [lb],
             "ZLoss": [z], "ExpertLoad": [load],
             "TopExperts": [top_e.astype(jnp.int32).reshape(B, T, k)],
@@ -424,6 +581,10 @@ def _moe_ffn_grad(ctx, ins, attrs):
             _router_of(attrs, k, X(ins, "X$SelectBias")), xt, wr,
             has_aux=True)
 
+    def transposed(rows, w, cot):
+        return jax.vjp(lambda a, b: mm(a, b, load), rows, w)[1](cot)
+
+    wg, wu, wd = weights
     if n_held == E:
         with jax.named_scope("combine"):
             place = _inverse_permutation(order)
@@ -433,46 +594,78 @@ def _moe_ffn_grad(ctx, ins, attrs):
                                place).reshape(S, k)
             dy = (d_rows * jnp.take(top_p.reshape(R), order)[:, None]
                   ).astype(dt)
+
+        with jax.named_scope("experts"):
+            dh, d_wd = transposed(_gate(g, u, dt), wd, dy)
+            gf, uf, dhf = g.astype(f32), u.astype(f32), dh.astype(f32)
+            sig = jax.nn.sigmoid(gf)
+            dxs_g, d_wg = transposed(
+                xs, wg, (dhf * uf * sig * (1.0 + gf * (1.0 - sig))).astype(dt))
+            dxs_u, d_wu = transposed(xs, wu, (dhf * gf * sig).astype(dt))
+
+        with jax.named_scope("dispatch"):
+            dx = jnp.take(dxs_g + dxs_u, place, axis=0).reshape(S, k, d) \
+                .astype(f32).sum(axis=1)
     else:
         # the buffer's first rows are the held slots; what lies behind them
-        # was never written and is masked wherever it is read
-        with jax.named_scope("combine"):
-            rows = xs.shape[0]
-            slot_held = (top_e.reshape(R) >= offset) & \
-                (top_e.reshape(R) < offset + n_held)
-            place = _inverse_permutation(order)
-            order, clipped = order[:rows], jnp.minimum(place, rows - 1)
+        # was never written and is masked wherever it is read.  The rung is
+        # the forward's: the same rule over the same count
+        ladder = held_ladder(S, k, n_held, E)
+        full = xs.shape[0]
+
+        def cotangents(rows, order, place, slot_held, top_p, d_out, y):
+            order, y = order[:rows], y[:rows]
             row_held = jnp.take(slot_held, order)[:, None]
             d_rows = jnp.zeros((rows, d), f32) if d_out is None else \
                 jnp.where(row_held, jnp.take(d_out.reshape(S, d), order // k,
                                              axis=0).astype(f32), 0.0)
             d_top_p = jnp.where(slot_held, jnp.take(jnp.sum(
                 d_rows * jnp.where(row_held, y.astype(f32), 0.0), axis=-1),
-                clipped), 0.0).reshape(S, k)
+                jnp.minimum(place, rows - 1)), 0.0).reshape(S, k)
             dy = (d_rows * jnp.take(top_p.reshape(R), order)[:, None]
                   ).astype(dt)
+            return d_top_p, _front(dy, full)
+
+        with jax.named_scope("combine"):
+            slot_held = (top_e.reshape(R) >= offset) & \
+                (top_e.reshape(R) < offset + n_held)
+            place = _inverse_permutation(order)
             load = jax.lax.dynamic_slice_in_dim(load, offset, n_held)
+            held_rows = jnp.sum(load)
+            d_top_p, dy = _over_rungs(ladder, held_rows, cotangents, order,
+                                      place, slot_held, top_p, d_out, y)
 
-    def transposed(rows, w, cot):
-        return jax.vjp(lambda a, b: mm(a, b, load), rows, w)[1](cot)
+        with jax.named_scope("experts"):
+            # the gate's output again, and not the forward's kept: XLA would
+            # merge this switch with the forward's and hold 134 MB a layer
+            # from forward to backward that it cannot rematerialise (a
+            # switch's result), at the price of rematerialising elsewhere
+            h = _gate_front(ladder, held_rows,
+                            *jax.lax.optimization_barrier((g, u)), dt)
+            dh, d_wd = transposed(h, wd, dy)
+            dg, du = _over_rungs(
+                ladder, held_rows, lambda rows, g, u, dh: tuple(
+                    _front(a, full) for a in _gate_backward(
+                        g[:rows], u[:rows], dh[:rows], dt)), g, u, dh)
+            dxs_g, d_wg = transposed(xs, wg, dg)
+            dxs_u, d_wu = transposed(xs, wu, du)
 
-    with jax.named_scope("experts"):
-        wg, wu, wd = weights
-        dh, d_wd = transposed(_gate(g, u, dt), wd, dy)
-        gf, uf, dhf = g.astype(f32), u.astype(f32), dh.astype(f32)
-        sig = jax.nn.sigmoid(gf)
-        dxs_g, d_wg = transposed(
-            xs, wg, (dhf * uf * sig * (1.0 + gf * (1.0 - sig))).astype(dt))
-        dxs_u, d_wu = transposed(xs, wu, (dhf * gf * sig).astype(dt))
+        def back_to_tokens(rows, dxs, place, slot_held):
+            # gathered as stored, widened after: the same numbers as
+            # widening all the rows first, at half the bytes
+            return jnp.where(slot_held[:, None], jnp.take(
+                dxs[:rows], jnp.minimum(place, rows - 1), axis=0).astype(f32),
+                0.0).reshape(S, k, d).sum(axis=1)
 
-    with jax.named_scope("dispatch"):
-        if n_held == E:
-            dx = jnp.take(dxs_g + dxs_u, place, axis=0).reshape(S, k, d) \
-                .astype(f32).sum(axis=1)
-        else:
-            dxs = jnp.where(row_held, (dxs_g + dxs_u).astype(f32), 0.0)
-            dx = jnp.where(slot_held[:, None], jnp.take(dxs, clipped, axis=0),
-                           0.0).reshape(S, k, d).sum(axis=1)
+        with jax.named_scope("dispatch"):
+            # the two parts are added over the whole buffer, outside the
+            # switch: with the sum inside it the TPU compiler scheduled the
+            # last block's step so that a forward matmul it rematerialised
+            # read its weight behind that weight's AdamW update (one
+            # gradient leaf a third off; tools/joyai_step_aot.py's
+            # reads_after_update, PERF.md section 6, PR 35)
+            dx = _over_rungs(ladder, held_rows, back_to_tokens,
+                             dxs_g + dxs_u, place, slot_held)
 
     with jax.named_scope("router"):
         zero = jnp.zeros((), f32)

@@ -262,3 +262,57 @@ def test_tpu_compiler_takes_the_options_and_fuses_all_reduces(topo):
         cc.reset_cache()
     fused = [r for r in asked if r[2].startswith("fused")]
     assert fused and all(mb >= 1.0 for _, _, _, mb, _ in fused), asked
+
+
+@pytest.mark.parametrize("router_outputs,width,ladder", [
+    (128, 1024, (16384, 65536)),                   # Trinity-Mini's share
+    (256, 768, (8192, 16384, 65536))])             # JoyAI-LLM-Flash's
+def test_tpu_compiler_takes_the_held_paths_ladder(
+        topo, monkeypatch, router_outputs, width, ladder):
+    """``moe_ffn`` + ``moe_ffn_grad`` over a chip's 16 experts at the two
+    cells' real sizes, bf16 through megablox, compiled for one described
+    chip: the ladder is the shapes', each row movement sits under a
+    conditional of that many branches, the rows reach the full-length
+    buffers through the ``moe_front`` kernel (the compiler takes it at both
+    widths), and the grouped matmuls are lowered once, not once a rung."""
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu import device
+    from paddle_tpu.ops import moe_ops
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    S, d, k, held = 8192, 2048, 8, 16
+    assert moe_ops.held_ladder(S, k, held, router_outputs) == ladder
+    ctx = types.SimpleNamespace(amp=False, is_abstract=True)
+    attrs = {"top_k": k, "score_func": "sigmoid", "norm_topk_prob": True,
+             "norm_eps": 1e-20, "route_scale": 2.5, "expert_offset": 0}
+
+    def step(x, d_out, wr, wg, wu, wd):
+        ins = {"X": [x], "RouterW": [wr], "GateW": [wg], "UpW": [wu],
+               "DownW": [wd]}
+        fwd = moe_ops._moe_ffn(ctx, ins, attrs)
+        g_ins = {"X$" + n: v for n, v in ins.items()}
+        g_ins.update({"Saved": fwd["Saved"], "OG$Out": [d_out]})
+        bwd = moe_ops._moe_ffn_grad(ctx, g_ins, attrs)
+        return fwd["Out"][0], [v[0] for v in bwd.values()]
+
+    one = SingleDeviceSharding(topo.devices[0])
+    shapes = [jax.ShapeDtypeStruct(s, t, sharding=one) for s, t in (
+        ((1, S, d), jnp.bfloat16), ((1, S, d), jnp.bfloat16),
+        ((d, router_outputs), jnp.float32), ((held, d, width), jnp.float32),
+        ((held, d, width), jnp.float32), ((held, width, d), jnp.float32))]
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        text = jax.jit(step).lower(*shapes).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+    import re
+    conds = re.findall(r"branch_computations=\{([^}]*)\}", text)
+    assert [c.count("%") for c in conds] == [len(ladder)] * 7   # 3 + 4
+    # six fronts a rung below the longest; nine grouped matmuls in all
+    assert len(re.findall(r"%moe_front[\w.]* = ", text)) == \
+        6 * (len(ladder) - 1)
+    assert len(re.findall(r"%(?:jvp_jit_)?t?gmm[\w.]* = ", text)) == 9

@@ -257,11 +257,14 @@ def test_rotate_half_pairing_is_another_function():
 
 #: sha256 of the StableHLO text of Trinity's toy training step (forward and
 #: backward, the loss and every trainable parameter's gradient fetched; CPU
-#: lowering) taken at this PR's parent, 496be36: window and grouped K/V
-#: heads, rotate-half rope, ``d_qk == d_v``.  OLMoE's is
-#: ``test_trinity.OLMOE_TOY_STEP_SHA256``, unchanged by this PR.
+#: lowering): window and grouped K/V heads, rotate-half rope, ``d_qk ==
+#: d_v``.  Taken at PR 34's parent, 496be36 (80aad17d...), and again in PR 35,
+#: which meant to change it: ``moe_ffn``'s held path gathers the rows'
+#: gradient back to tokens as stored (bf16, widened after) and takes its
+#: buffer's length from a ladder (one rung at the toy's sizes: no switch).
+#: OLMoE's is ``test_trinity.OLMOE_TOY_STEP_SHA256``, unchanged by both.
 TRINITY_TOY_STEP_SHA256 = (
-    "80aad17d42221c6e59bc3874c8e73b6a05cdd7033d3623839131b275fe01091b")
+    "ad196f981981175b28153f4f34ddf4cbef8f8325098fa248e3daee88402f213c")
 
 
 def _step_text(mod, cfg, seq=16):
@@ -281,7 +284,7 @@ def _step_text(mod, cfg, seq=16):
 
 def test_one_width_and_the_default_rope_lower_as_at_the_parent():
     """``d_qk == d_v`` and ``interleaved=False``: OLMoE's and Trinity's toy
-    steps lower to the parent's StableHLO text, byte for byte."""
+    steps lower to the recorded StableHLO text, byte for byte."""
     import test_olmoe
     import test_trinity
     text = _step_text(test_trinity, test_trinity.toy_cfg())

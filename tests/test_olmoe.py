@@ -200,7 +200,7 @@ def test_moe_lowerings_are_counted_once_per_compile():
     from paddle_tpu.ops.moe_ops import MOE_LOWERINGS_CTR
     rng = np.random.RandomState(4)
     labels = dict(impl="ragged_dot", experts="4", top_k="2", held="4",
-                  score_func="softmax")
+                  score_func="softmax", ladder="")
     before = MOE_LOWERINGS_CTR.value(**labels)
     x = rng.randn(1, 6, 8).astype(np.float32)
     _run_moe(x, _moe_weights(rng, 8, 4, 8), 4, 2, 8)   # forward + its grad op

@@ -170,20 +170,124 @@ def test_sigmoid_bias_scale_and_offset_match_the_masked_dense_reference(
         _close(grads[name], gw[key], 1e-4, f"d / d {name}")
 
 
-def test_a_skewed_router_drops_nothing_on_the_held_experts():
-    """Every token's first choice is expert 3, held here: the buffer takes
-    all of them (no capacity), and the result is still the reference's."""
-    rng = np.random.RandomState(9)
-    b, t, d, e, k, f = 1, 24, 16, 8, 2, 12
-    x = np.abs(rng.randn(b, t, d)).astype(np.float32)
-    w = _moe_weights(rng, d, e, 2, f)
-    w["moe.router.w"][:, 3] = 4.0
-    w["moe.select_bias"][:] = 0.0
-    out, load, _ = _run_share(x, w, e, k, f, 2)
-    assert load[3] == b * t
-    want, _ = ref.routed_experts(jnp.asarray(x).reshape(b * t, d), _blk(w),
-                                 k, 2.826, 2)
-    _close(out.reshape(b * t, d), want, 1e-5, "skewed share")
+#: a toy ladder: 24 tokens, 2 experts a token, 2 of 16 experts held, row
+#: tiles of 4 -> twice even routing's share is 12 rows, the most 48
+TOY_LADDER = (12, 48)
+
+
+@pytest.fixture
+def toy_tiles(monkeypatch):
+    """Row tiles of 4 on the held path, so that toy shapes have a ladder."""
+    from paddle_tpu.ops import moe_ops
+    monkeypatch.setattr(moe_ops, "_GMM_TILING_HELD", (4, 1024, 1024))
+    return moe_ops
+
+
+def _steered(n_first, n_both=0, t=24, d=16, seed=9):
+    """Input [1, t, d] and weights of a share that holds experts 2 and 3 of
+    16, two experts a token, with the choice steered: the router's own
+    weights are small (every sigmoid score near a half), ``SelectBias`` sends
+    a token to the absent experts 0 and 1, and two flag features of ``x``
+    (through two large router rows) send the first ``n_first`` tokens to
+    expert 3 and the first ``n_both`` of them to expert 2 besides.  Held
+    rows: ``n_first + n_both``."""
+    rng = np.random.RandomState(seed)
+    x = rng.randn(1, t, d).astype(np.float32)
+    w = _moe_weights(rng, d, 16, 2, 12)
+    w["moe.router.w"] *= 0.01
+    w["moe.router.w"][-2:] = 0.0
+    w["moe.router.w"][-2, 3] = w["moe.router.w"][-1, 2] = 50.0
+    w["moe.select_bias"] = np.array([.2, .1] + [0.] * 14, np.float32)
+    x[..., -2:] = 0.0
+    x[0, :n_first, -2] = 1.0
+    x[0, :n_both, -1] = 1.0
+    return x, w
+
+
+def _run_steered(x, w, **kw):
+    return _run_share(x, w, 16, 2, 12, 2, **kw)
+
+
+@pytest.mark.parametrize("n_first,n_both,rung", [
+    (0, 0, 0), (11, 0, 0), (12, 0, 0), (12, 1, 1), (24, 24, 1)],
+    ids=["none", "L-1", "L", "L+1", "all-here"])
+def test_a_skewed_router_drops_nothing_on_the_held_experts(
+        toy_tiles, n_first, n_both, rung):
+    """Held rows of ``L - 1``, ``L`` and ``L + 1`` around the toy ladder's
+    first rung, none at all, and the worst case (every token's two experts
+    held here: the last rung, full): the buffer takes all of them (no
+    capacity), the rung is the shortest that holds them, the counter says
+    which, and the result is the reference's."""
+    x, w = _steered(n_first, n_both)
+    assert toy_tiles.held_ladder(24, 2, 2, 16) == TOY_LADDER
+    ctr = toy_tiles.MOE_HELD_BUFFER_CTR
+    before = ctr.value(rows=str(TOY_LADDER[rung]))
+    out, load, _ = _run_steered(x, w, backward=False)
+    assert load[3] == n_first and load[2] == n_both
+    assert toy_tiles.held_rung(n_first + n_both, TOY_LADDER) == rung
+    toy_tiles.record_expert_load(load, 2, 2)
+    assert ctr.value(rows=str(TOY_LADDER[rung])) == before + 1
+    want, _ = ref.routed_experts(jnp.asarray(x).reshape(24, 16), _blk(w), 2,
+                                 2.826, 2)
+    _close(out.reshape(24, 16), want, 1e-5, "steered share")
+
+
+@pytest.mark.parametrize("what", ["Out", "ExpertLoad", "x", "moe.router.w",
+                                  "moe.gate.w", "moe.up.w", "moe.down.w"])
+@pytest.mark.parametrize("n_first,n_both", [(5, 0), (12, 0), (12, 7)],
+                         ids=["few", "L", "beyond-L"])
+def test_every_rung_gives_the_full_buffer_to_the_bit(
+        monkeypatch, toy_tiles, n_first, n_both, what):
+    """Output, load and the gradients of x, the router and the held experts'
+    weights, as the ladder's own rung gives them and as each longer rung and
+    the full buffer alone give them for the same routing: equal exactly (a
+    rung multiplies the same rows in the same groups; what lies behind them
+    is masked)."""
+    x, w = _steered(n_first, n_both)
+    results = {}
+    for ladder in (TOY_LADDER, (20, 48), (48,)):
+        monkeypatch.setattr(toy_tiles, "held_ladder",
+                            lambda *a, ladder=ladder: ladder)
+        if toy_tiles.held_rung(n_first + n_both, ladder) == len(ladder) - 1 \
+                and len(ladder) > 1:
+            continue                     # the full buffer: (48,) covers it
+        out, load, grads = _run_steered(x, w)
+        results[ladder] = dict(grads, Out=out, ExpertLoad=load)
+    full = results.pop((48,))
+    assert results or n_first + n_both > 20
+    for ladder, got in results.items():
+        np.testing.assert_array_equal(got[what], full[what], str(ladder))
+
+
+def test_forward_and_grad_op_choose_the_same_rung(monkeypatch, toy_tiles):
+    """Every switch of both ops (three in the forward, four in the grad op)
+    takes its index from the same rule (``held_rung``) over the same ladder
+    and the same count, the sum of the held experts' loads."""
+    x, w = _steered(9, 2)
+    seen = []
+    rung = toy_tiles.held_rung
+    monkeypatch.setattr(toy_tiles, "held_rung", lambda rows, ladder:
+                        seen.append(ladder) or rung(rows, ladder))
+    _run_steered(x, w)
+    # shape inference traces the forward at a stand-in batch first; the
+    # step's own forward and grad op come last: 3 + 4 switches, one ladder
+    assert len(seen) >= 7 and set(seen[-7:]) == {TOY_LADDER}
+
+
+@pytest.mark.parametrize("shape,want", [
+    ((8192, 8, 16, 128), (16384, 65536)),              # Trinity-Mini's share
+    ((8192, 8, 16, 256), (8192, 16384, 65536)),        # JoyAI-LLM-Flash's
+    ((8192, 8, 16, 1024), (2048, 4096, 8192, 65536)),  # at most four rungs
+    ((8192, 8, 4, 128), (4096, 8192, 32768)),          # min(k, held) rows
+    ((24, 2, 2, 8), (48,)),                             # under a tile: one
+    ((8192, 8, 64, 128), (65536,))])                    # twice even > a quarter
+def test_the_ladder_is_a_function_of_the_four_shapes(shape, want):
+    from paddle_tpu.ops import moe_ops
+    assert moe_ops.held_ladder(*shape) == want
+    assert all(r % moe_ops._GMM_TILING_HELD[0] == 0 for r in want[:-1])
+    assert list(want) == sorted(set(want))
+    S, k, n_held, E = shape
+    assert want[-1] == S * min(k, n_held)
 
 
 def test_eight_shares_and_the_shared_expert_once_are_the_uncut_layer():
@@ -227,7 +331,7 @@ def test_routed_rows_are_counted_from_the_loads_handed_over():
 def test_moe_lowerings_carry_held_and_score_func():
     from paddle_tpu.ops.moe_ops import MOE_LOWERINGS_CTR as ctr
     labels = dict(impl="ragged_dot", experts="8", top_k="3", held="2",
-                  score_func="sigmoid")
+                  score_func="sigmoid", ladder="10")
     before = ctr.value(**labels)
     rng = np.random.RandomState(4)
     _run_share(rng.randn(1, 5, 16).astype(np.float32),

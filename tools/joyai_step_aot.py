@@ -4,10 +4,11 @@ builds the cell as ``benchmark/models/joyai_llm_flash.py`` does, catches the
 executor's first step before it runs, and hands its lowering (the Pallas
 kernels and megablox as a TPU would take them: ``device.on_tpu`` is steered
 here, in the tool) to the TPU compiler.  Prints the executable's temporaries,
-arguments and the count of XLA's own rematerialised instructions
-(``.remat`` in the compiled text), with and without ``--recompute``: whether
-the step fits beside its state, and what fitting costs (PERF.md section 7,
-row 31).  Nothing runs: no time comes from this.  The adapter has the
+arguments, the count of XLA's own rematerialised instructions (``.remat`` in
+the compiled text) and ``reads_after_update`` (must be empty: a donated
+parameter read again behind its optimizer update), with and without
+``--recompute``: whether the step fits beside its state, and what fitting
+costs (PERF.md section 7, row 31).  Nothing runs: no time comes from this.  The adapter has the
 recomputing step only (the traffic file's); without ``--recompute`` this
 tool puts a pass-through in ``RecomputeOptimizer``'s place.
 
@@ -46,6 +47,54 @@ class _NoRecompute:
         return getattr(self._inner, name)
 
 
+#: cell -> (configuration, which is also its adapter module; traffic mix)
+CELLS = {"joyai": ("joyai_llm_flash", "lm_mtp_s8192"),
+         "trinity": ("trinity_mini", "lm_s8192")}
+
+
+def reads_after_update(text):
+    """In a scheduled compiled step: the donated entry parameters (state
+    aliased with an output) that some instruction reads again after the
+    instruction that writes that output in place.  None is sound; one is a
+    forward matmul that XLA rematerialised behind its weight's optimizer
+    update, which then multiplies by the *updated* weight (PERF.md section
+    6, PR 35: Trinity's step read one gradient leaf a third off)."""
+    lines = text.split("\n")
+    alias = {int(o): int(p) for o, p in
+             re.findall(r"\{(\d+)\}: \((\d+), \{\}", lines[0])}
+    entry = next(i for i, line in enumerate(lines)
+                 if line.startswith("ENTRY"))
+    end = next(i for i in range(entry, len(lines)) if lines[i] == "}")
+    lines = lines[:end]
+    defs, params, root = {}, {}, None
+    for i in range(entry, end):
+        m = re.match(r"\s*(ROOT )?%([\w.\-]+) = ", lines[i])
+        if not m:
+            continue
+        defs[m.group(2)] = i
+        p = re.search(r" parameter\((\d+)\)", lines[i])
+        if p:
+            params[int(p.group(1))] = m.group(2)
+        root = i if m.group(1) else root
+    outs = [o.strip().lstrip("%") for o in re.search(
+        r"tuple\((.*)\)", re.sub(r"/\*index=\d+\*/", "", lines[root])
+    ).group(1).split(", ")]
+    found = []
+    for out, pno in sorted(alias.items()):
+        if outs[out] == params[pno]:
+            continue
+        gte = re.search(r"get-tuple-element\(%([\w.\-]+)\)",
+                        lines[defs[outs[out]]])
+        written = defs[gte.group(1)] if gte else defs[outs[out]]
+        use = re.compile("%" + re.escape(params[pno]) + r"[,)]")
+        late = [re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", lines[i]).group(1)
+                for i in range(written + 1, len(lines))
+                if use.search(lines[i])]
+        if late:
+            found.append({"parameter": params[pno], "read_by": late[0]})
+    return found
+
+
 def run_on_chip(args):
     """The step built as the cell builds it, compiled by the chip's own
     compiler and run once."""
@@ -81,6 +130,10 @@ def main():
     ap.add_argument("--layers", type=int, default=0)
     ap.add_argument("--seq", type=int, default=0)
     ap.add_argument("--dump", default="")
+    ap.add_argument("--cell", default="joyai", choices=sorted(CELLS),
+                    help="the other cell that runs moe_ffn's held path: "
+                    "trinity (its step has no recomputation: leave "
+                    "--recompute out)")
     args = ap.parse_args()
     if args.run:
         return run_on_chip(args)
@@ -100,11 +153,12 @@ def main():
     for mod in (device, fused_ops, layer_norm, flash):
         mod.on_tpu = lambda: True
     from benchmark import harness
-    from benchmark.models import joyai_llm_flash as adapter
     import dp_arith_check
 
-    config = harness.load_json("benchmark/configs/joyai_llm_flash.json")
-    traffic = harness.load_traffic("lm_mtp_s8192")
+    config_name, traffic_name = CELLS[args.cell]
+    adapter = importlib.import_module("benchmark.models." + config_name)
+    config = harness.load_json(f"benchmark/configs/{config_name}.json")
+    traffic = harness.load_traffic(traffic_name)
     if not args.recompute:
         from paddle_tpu import optimizer as opt
         opt.RecomputeOptimizer = _NoRecompute
@@ -135,13 +189,15 @@ def main():
         with open(args.dump, "w") as f:
             f.write(text)
     print(json.dumps({
-        "recompute": args.recompute, "compiles": True,
+        "cell": args.cell, "recompute": args.recompute, "compiles": True,
         "layers": config["num_hidden_layers"], "seq": traffic["seq_len"],
         "temp_gb": mem.temp_size_in_bytes / 1e9,
+        "peak_gb": mem.peak_memory_in_bytes / 1e9,
         "argument_gb": mem.argument_size_in_bytes / 1e9,
         "output_gb": mem.output_size_in_bytes / 1e9,
         "alias_gb": mem.alias_size_in_bytes / 1e9,
         "remat_instructions": len(re.findall(r"\.remat\d* = ", text)),
+        "reads_after_update": reads_after_update(text),
         "parameters_m": sum(int(np.prod(p.shape))
                             for p in m["parameters"]) / 1e6}))
     return 0
