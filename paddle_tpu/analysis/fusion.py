@@ -66,6 +66,7 @@ from typing import Dict, List, Optional, Tuple
 
 from .. import monitor as _monitor
 from ..framework.core import Block, Program
+from ..framework.recompute import RECOMPUTED_ATTR
 
 __all__ = [
     "FusionDecision", "FusionReport", "analyze_program", "clear_cache",
@@ -634,10 +635,20 @@ def _finish_candidate(graph, program, cand, *, fused_type, fused_ins,
         ("out", tuple(out_var.shape) if out_var is not None and
          out_var.shape else ()),)
 
+    # an op fused from what apply_recompute emitted is itself recomputed
+    # work (the executor names it ``pt.rc/...`` by this mark, as it names a
+    # fused grad op ``pt.bwd/...`` by the role below); a clone reads stored
+    # values through barriers only, so no chain holds both kinds
+    marks = {bool(n.op.attrs.get(RECOMPUTED_ATTR)) for n in cand.fwd_ops}
+    assert len(marks) == 1, \
+        f"{fused_type}: fused from recomputed and first-run ops"
+    mark = {RECOMPUTED_ATTR: True} if marks.pop() else {}
+
     def build(g, use_pallas=False):
         attrs = dict(fused_attrs, use_pallas=bool(use_pallas))
         fused_node = g.create_op_node(fused_type, inputs=fused_ins,
-                                      outputs=fused_outs, attrs=attrs)
+                                      outputs=fused_outs,
+                                      attrs=dict(attrs, **mark))
         doomed = list(cand.fwd_ops) + list(cand.internal) + \
             list(cand.dead_outputs)
         if cand.grad_ops:

@@ -988,14 +988,14 @@ def op_scope(op: Operator) -> str:
     """``pt.<role>/<op type>``: the ``jax.named_scope`` every op of a block
     is lowered under, so that each device operation of the compiled step
     carries, in its HLO metadata, the program op it came from (read back by
-    ``benchmark/op_scopes.py``).  The type is the one written in the
-    optimised program; a grad op lowered by the generic vjp keeps the grad
-    op's scope, so forward work lowered again inside the backward reads as
-    ``bwd``.  Ops of a sub-block nest under their parent (``while``,
-    ``cond``), whose scope comes first in the name.  Metadata only: a
-    context manager per op while tracing, nothing per step."""
-    return "pt.%s/%s" % (_SCOPE_ROLES.get(op.attrs.get("op_role"), "fwd"),
-                         op.type) + _name_scope_of(op)
+    ``benchmark/op_scopes.py``).  Roles: ``fwd``, ``bwd``, ``opt``, ``lr``
+    by ``op_role``, and ``rc`` for what ``apply_recompute`` emits again
+    (:func:`_scope_role`).  The type is the one written in the optimised
+    program; a grad op lowered by the generic vjp keeps its scope, so forward
+    work lowered again inside it reads as ``bwd``.  Ops of a sub-block nest
+    under their parent (``while``, ``cond``).  Metadata only: a context
+    manager per op while tracing, nothing per step."""
+    return "pt.%s/%s" % (_scope_role(op), op.type) + _name_scope_of(op)
 
 
 def _run_op_inner(ctx, block, op, state) -> None:
@@ -2692,3 +2692,15 @@ def _name_scope_of(op: Operator) -> str:
     compile-cache key holds them)."""
     tag = op.attrs.get("name_scope")
     return "/" + str(tag) if tag else ""
+
+
+def _scope_role(op: Operator) -> str:
+    """The role part of :func:`op_scope`: ``rc`` for an op that
+    ``framework/recompute.py:apply_recompute`` emitted (its clones of forward
+    ops and the barriers that feed them, marked by an attr of their own),
+    else by ``op_role`` (``_SCOPE_ROLES``; none: ``fwd``).  At the end of the
+    file for the reason :func:`_name_scope_of` gives."""
+    from .recompute import RECOMPUTED_ATTR
+    if op.attrs.get(RECOMPUTED_ATTR):
+        return "rc"
+    return _SCOPE_ROLES.get(op.attrs.get("op_role"), "fwd")

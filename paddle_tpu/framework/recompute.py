@@ -17,6 +17,15 @@ Under XLA's liveness this makes segment intermediates die at the end of the
 forward pass and re-materialize during backward — the effect of
 ``jax.checkpoint``, expressed in the Program IR.
 
+Every op the transform emits, clone or barrier, carries the attr
+``RECOMPUTED_ATTR`` — not a value of ``op_role``, whose meanings
+(``clone(for_test)``'s pruning, the verifier's and the fusion pass's
+``== "backward"``) stay as they are — and the executor lowers a marked op
+under ``pt.rc/<op type>`` (``executor.op_scope``), so that on a device trace
+the second forward has a name of its own beside ``pt.fwd/*``.
+``paddle_tpu_recompute_ops_total{op}`` counts the clones by op type, once per
+transform.
+
 RNG-stateful ops are NOT recomputed UNLESS their draw is replay-safe:
 tagged dropout (a nonzero ``seed`` attr) derives its bits purely from
 (per-step key, tag), so re-evaluating it reproduces the identical mask and
@@ -27,13 +36,25 @@ and feed the recomputed chain through barriers.
 
 from __future__ import annotations
 
+import collections
 from typing import Dict, List, Sequence
 
+from .. import monitor as _monitor
 from . import registry
 from .core import Operator, Program
 
 RECOMPUTE_SUFFIX = "@RECOMPUTE"
 BARRIER_SUFFIX = "@RBAR"
+#: attr of every op ``apply_recompute`` emits (clones and their barriers)
+RECOMPUTED_ATTR = "recomputed"
+
+RECOMPUTE_OPS_CTR = _monitor.REGISTRY.counter(
+    "paddle_tpu_recompute_ops_total",
+    "forward ops apply_recompute emitted a second time (the clones whose "
+    "outputs are the @RECOMPUTE values; barriers not counted), by op type — "
+    "counted where the segments are made, once per transform of a program, "
+    "nothing per step: what a trace's pt.rc/<op> scopes can be checked "
+    "against", ("op",))
 
 
 def _is_rng_op(op: Operator) -> bool:
@@ -99,7 +120,8 @@ def apply_recompute(program: Program,
             continue
         # clone with renamed inputs/outputs; every stored value entering
         # the chain passes through a CSE fence
-        clone = Operator(block, op.type, attrs=dict(op.attrs))
+        clone = Operator(block, op.type,
+                         attrs=dict(op.attrs, **{RECOMPUTED_ATTR: True}))
         clone.inputs = {
             slot: [rename.get(n, barrier_name(n) if n else n)
                    for n in names]
@@ -132,7 +154,8 @@ def apply_recompute(program: Program,
             block.create_var(name=dst, shape=v.shape if v else None,
                              dtype=v.dtype if v else "float32")
         b = Operator(block, "optimization_barrier",
-                     inputs={"X": [src]}, outputs={"Out": [dst]})
+                     inputs={"X": [src]}, outputs={"Out": [dst]},
+                     attrs={RECOMPUTED_ATTR: True})
         barrier_ops.append(b)
     for clone in recompute_ops:
         for names in clone.outputs.values():
@@ -154,4 +177,7 @@ def apply_recompute(program: Program,
     block.ops = fwd_ops + [bwd_ops[0]] + barrier_ops + \
         recompute_ops + bwd_ops[1:]
     program._bump_version()
+    for typ, n in collections.Counter(
+            c.type for c in recompute_ops).items():
+        RECOMPUTE_OPS_CTR.inc(n, op=typ)
     return program

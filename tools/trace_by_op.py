@@ -9,7 +9,12 @@ the whole recording, prints shares of device-busy time by ``pt.<role>/<op>``
 scope, by part inside ``moe_ffn``, and which XLA operations make up each of
 the largest scopes, and writes the same to
 ``chiprun_out/trace_by_op.<cell>.json``.  Shares, not milliseconds: multiply
-by the run's ``step_device_ms.train``.
+by the run's ``step_device_ms.train``.  The three kinds of repeated work each
+have their rows: the program's own recomputation is the role ``rc/`` among
+the scopes (``framework/recompute.py``), XLA's rematerialised instructions
+are ``remat_pct`` by the program op they belong to (``benchmark/
+remat_scopes.py``: events named ``*.remat*``; "-" without a scope), and
+forward work a generic vjp lowered again is ``forward_again_pct``.
 """
 
 import glob
@@ -21,7 +26,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import harness, op_scopes, part_scopes  # noqa: E402
+from benchmark import (harness, op_scopes, part_scopes,  # noqa: E402
+                       remat_scopes)
 
 
 #: scopes a model nests inside an op's own (``flash_attention``'s ``window``,
@@ -71,6 +77,9 @@ def main():
     out = {"cell": cell, "busy_s": busy, "scoped_pct": share(red["scoped"]),
            "unscoped_pct": share(red["unscoped"]),
            "forward_again_pct": share(red["forward_again"]),
+           "remat_pct": share({k or "-": v for k, v in
+                               remat_scopes.reduce_remat(
+                                   paths[-1], (lo, hi)).items()}),
            "moe_parts_pct": share(moe), "tagged_pct": share(tagged),
            "xla_ops_pct": {k: dict(list(share(by[k]).items())[:6])
                            for k in top + ["unscoped"] if k in by}}
