@@ -41,6 +41,16 @@ FLASH_GRAD_LOWERINGS_CTR = _monitor.REGISTRY.counter(
     "counted while tracing, once per compile, nothing per step",
     ("window", "kv_groups", "impl", "widths"))
 
+FLASH_BWD_KERNEL_CTR = _monitor.REGISTRY.counter(
+    "paddle_tpu_flash_bwd_kernel_total",
+    "flash_attention_grad lowerings by the backward they got: fused (one "
+    "pass, a head's dQ resident in VMEM), combined (one pass, dK/dV "
+    "partials in HBM), split (a dQ pass and a dK/dV pass: what a sequence "
+    "too long for the fused accumulator runs) or jax (the blockwise "
+    "fallback: a bias, or no TPU) — counted beside "
+    "paddle_tpu_flash_grad_lowerings_total, once per compile, nothing per "
+    "step", ("kernel", "window", "widths"))
+
 
 def _flash_call(ctx, attrs, q, k, v, counter, pallas):
     """What the op and its grad op share: ``(window or None, the attributes
@@ -126,11 +136,17 @@ def _flash_attention_grad(ctx, ins, attrs):
     generic vjp ran it a second time for these two residuals; XLA does not
     merge two Mosaic calls).  With a bias the blockwise jax backward, on
     every backend, and dBias only where the grad maker found a reader."""
-    from ..pallas.flash_attention import flash_attention_bwd
+    from ..pallas.flash_attention import (flash_attention_bwd,
+                                          flash_bwd_kernel)
     q, k, v = X(ins, "X$Q"), X(ins, "X$K"), X(ins, "X$V")
     bias, out, d_out = X(ins, "X$Bias"), X(ins, "Out"), X(ins, "OG$Out")
     window, kw = _flash_call(ctx, attrs, q, k, v, FLASH_GRAD_LOWERINGS_CTR,
                              pallas=bias is None)
+    if not getattr(ctx, "is_abstract", False):
+        FLASH_BWD_KERNEL_CTR.inc(
+            kernel=flash_bwd_kernel(q, k, v, bias, **kw),
+            window="none" if window is None else str(window),
+            widths=f"{q.shape[3]}/{v.shape[3]}")
     d_out = jnp.zeros_like(out) if d_out is None else d_out.astype(out.dtype)
     with _window_scope(window):
         dq, dk, dv, db = flash_attention_bwd(

@@ -12,7 +12,20 @@ Forward on TPU runs a Pallas kernel tiled for the MXU (grid over
 elsewhere (CPU tests, interpret debugging) a blockwise ``lax.scan``
 computes the same math.  The backward pass is the standard flash
 recomputation: no O(T^2) attention matrix is ever materialized — only
-per-(q-block, k-block) tiles, rebuilt from the saved logsumexp.
+per-(q-block, k-block) tiles, rebuilt from the saved logsumexp.  Three
+Pallas backwards share that arithmetic and differ in where dQ (summed over
+key blocks) and dK/dV (summed over query blocks) accumulate: "fused"
+rebuilds each live tile pair once and keeps both sums on the chip (dK/dV of
+the key block in scratch, the head's whole dQ in a (Tq, d_qk) float32
+scratch: 5 products a pair, no HBM that grows with T² — what the 8192-token
+tables name, and the one call here that asks for more than Mosaic's default
+16 MiB of scoped VMEM); "combined" rebuilds once too but writes dK/dV as
+float32 partials per query block and sums them outside (5 products and
+2·bh·nq·Tk·(d_qk + d_v)·4 B of HBM: the shorter lengths' choice); "split"
+runs a dQ pass and a dK/dV pass that each rebuild the tile (7 products, no
+memory that grows: what a length too long for the fused accumulator, or for
+the partials' budget, falls back to).  ``_flash_bwd_pallas`` picks from the
+shapes; a bias takes the blockwise jax backward on every backend.
 
 Capability anchor in the reference: attention assembled from separate
 matmul/softmax/dropout ops in its Transformer recipe
@@ -372,17 +385,35 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_sc, dv_sc, *, sm_scale, causal,
-                    block_q, block_k, tq_real, tk_real, offset, pads,
-                    window=None):
+                    dk_ref, dv_ref, *more, sm_scale, causal, block_q,
+                    block_k, tq_real, tk_real, offset, pads, window=None):
     """Grid (bh, ik, iq): accumulate dk/dv over q-blocks in VMEM scratch
     (transposed tiles: everything is (bk, ·) so the MXU contractions stay
-    tall).  Mask/scale elision as in _fwd_kernel (r5 microbench)."""
+    tall).  Mask/scale elision as in _fwd_kernel (r5 microbench).  ``more``
+    is the scratch ``(dk_sc, dv_sc)`` of the split backward's dk/dv pass,
+    or ``(dq_ref, dk_sc, dv_sc, dq_sc)`` of the FUSED backward: the same
+    pass, ONE recompute per live (i, j) pair, with a fifth product, dq's
+    ds_tᵀ·k (the one contraction over a tile's first axis), into a (Tq,
+    d_qk) float32 scratch that stays in VMEM for the whole head.  dq's
+    output block is the head's whole dQ, one index for both inner axes, so
+    it is written back once, at the head's last step.  5 MXU products a
+    pair for the split kernels' 7 and no partials in HBM; operand types,
+    precision and the order of both accumulations (dq over key blocks
+    ascending, dk/dv over query blocks ascending) are the split kernels'
+    own.  Fused, it needs more than Mosaic's default scoped VMEM:
+    _fused_vmem_bytes."""
     import jax.lax as lax
     from jax.experimental import pallas as pl
 
+    dq_ref, dk_sc, dv_sc, dq_sc = more if len(more) == 4 else \
+        (None, *more, None)
     ik, iq = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
+    nk, nq = pl.num_programs(1), pl.num_programs(2)
+
+    if dq_sc is not None:
+        @pl.when((ik == 0) & (iq == 0))
+        def _init_dq():
+            dq_sc[...] = jnp.zeros_like(dq_sc)
 
     @pl.when(iq == 0)
     def _init():
@@ -391,7 +422,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     def _compute(masked):
         # sm_scale folds into q: s_t = k @ (q·scale) and
-        # dk = ds_t @ (q·scale) each carry exactly one scale factor
+        # dk = ds_t @ (q·scale) each carry exactly one scale factor (dq
+        # takes its factor on the accumulated head, at its end)
         q = q_ref[0].astype(jnp.float32) * sm_scale
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
@@ -417,6 +449,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_sc[...] = dk_sc[...] + lax.dot_general(
             ds_t, q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if dq_sc is not None:
+            rows = pl.ds(pl.multiple_of(iq * block_q, block_q), block_q)
+            dq_sc[rows, :] = dq_sc[rows, :] + lax.dot_general(
+                ds_t, k, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     _block_dispatch(causal, pads, iq, ik, block_q, block_k, offset,
                     _compute, window=window)
@@ -425,6 +462,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _finalize():
         dk_ref[0] = dk_sc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
+
+    if dq_sc is not None:
+        @pl.when((ik == nk - 1) & (iq == nq - 1))
+        def _finalize_dq():
+            dq_ref[0] = (dq_sc[...] * sm_scale).astype(dq_ref.dtype)
 
 
 def _bwd_combined_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -575,125 +617,177 @@ def _flash_bwd_pallas_combined(q, k, v, o, lse, do, causal, sm_scale,
     return dq[:, :tq], dk[:, :tk], dv[:, :tk]
 
 
-# default pallas backward: "combined" (one recompute, dk/dv partial sums —
-# the r4 winner at long T) or "split" (the two-pass r2 kernels).
-# Overridable per call via flash_attention(bwd_impl=...).
+# default pallas backward where no table names one: "combined" (one
+# recompute, dk/dv partial sums — the r4 winner at long T and d <= 64),
+# "split" (the two-pass r2 kernels) or "fused" (one recompute, every
+# accumulation in VMEM).  Overridable per call via
+# flash_attention(bwd_impl=...).
 _BWD_IMPL = "combined"
 
 # the combined kernel's dk/dv partials cost 2·bh·nq·Tk·d·4 B of HBM —
 # QUADRATIC in T (nq = Tq/block_q).  Past this budget the split kernels'
-# O(bh·T·d) memory wins by not OOMing; fall back automatically.
+# O(bh·T·d) memory wins by not OOMing; fall back automatically.  (The 8k
+# shapes at d > 64 are past it, 2.15 to 2.68 GB a layer: they run "fused".)
 _COMBINED_PARTIAL_BUDGET = 2 << 30
+
+# A v5e TensorCore's VMEM and the share of it the fused backward may ask for.
+# Mosaic scopes a kernel to 16 MiB unless the call says otherwise
+# (vmem_limit_bytes); the fused backward says what _fused_vmem_bytes reckons
+# from its shapes, and where that passes the share (a head's dQ accumulator
+# grows with Tq · d_qk) the split kernels run instead.
+_VMEM_BYTES = 128 << 20
+_FUSED_VMEM_SHARE = 0.75
+
+
+def _fused_vmem_bytes(tq, d, d_v, block_q, block_k, itemsize):
+    """The VMEM the fused backward asks for, from its shapes: the head's dQ
+    accumulator and its output block, the operand and dK/dV blocks (every
+    block double-buffered), the (1, block_q) lse/delta rows at a sublane
+    tile each, the dK/dV scratch, the float32 tiles (s_t, p_t, dp_t, ds_t
+    and ds_t transposed for dQ's first-axis contraction) and the operands'
+    float32 copies; a quarter more for what XLA fuses into the call inside
+    a step.  [32, 8192, 192 | 128] bf16 asks 34 MiB at (1024, 512) and 49.5
+    at (1024, 1024), where 28 and 40 are the least that compile alone (20
+    for the 43 asked at [32, 8192, 128 | 128] (1024, 1024): the compiler
+    shares tiles this sum counts apart)."""
+    tq = -(-tq // block_q) * block_q
+    wide = d + d_v
+    acc = tq * d * (4 + 2 * itemsize)
+    blocks = 2 * itemsize * wide * (block_q + 2 * block_k)
+    rows = 2 * 2 * 8 * block_q * 4
+    scratch = block_k * wide * 4
+    tiles = 5 * block_q * block_k * 4 + (block_q + block_k) * wide * 4
+    return int(1.25 * (acc + blocks + rows + scratch + tiles))
+
+
+def _bwd_kernel_name(q, k, v, block_q, block_k, impl=None):
+    """Which of the three Pallas backwards runs for collapsed ``q``, ``k``,
+    ``v`` (anything with a shape and a dtype) at these blocks: what ``impl``
+    asks for where it fits, else the split kernels, which fit everywhere."""
+    impl = impl or _BWD_IMPL
+    bh, tq, d = q.shape
+    tk, d_v = k.shape[1], v.shape[2]
+    block_q, block_k = min(block_q, tq), min(block_k, tk)
+    if impl == "fused":
+        need = _fused_vmem_bytes(tq, d, d_v, block_q, block_k,
+                                 jnp.dtype(q.dtype).itemsize)
+        return "fused" if need <= _FUSED_VMEM_SHARE * _VMEM_BYTES \
+            else "split"
+    if impl == "combined":
+        partial_bytes = bh * -(-tq // block_q) * tk * (d + d_v) * 4
+        if partial_bytes <= _COMBINED_PARTIAL_BUDGET:
+            return "combined"
+    return "split"
 
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale, block_q,
                       block_k, offset, interpret, impl=None, window=None,
                       group=1):
-    impl = impl or _BWD_IMPL
-    if impl == "combined":
-        bh, tq, d = q.shape
-        tk = k.shape[1]
-        nq = -(-tq // min(block_q, tq))
-        partial_bytes = bh * nq * tk * (d + v.shape[2]) * 4
-        if partial_bytes <= _COMBINED_PARTIAL_BUDGET:
-            return _flash_bwd_pallas_combined(q, k, v, o, lse, do, causal,
-                                              sm_scale, block_q, block_k,
-                                              offset, interpret, window,
-                                              group)
+    name = _bwd_kernel_name(q, k, v, block_q, block_k, impl)
+    if name == "combined":
+        return _flash_bwd_pallas_combined(q, k, v, o, lse, do, causal,
+                                          sm_scale, block_q, block_k,
+                                          offset, interpret, window, group)
     return _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale,
                                    block_q, block_k, offset, interpret,
-                                   window, group)
+                                   window, group, fused=name == "fused")
 
 
 def _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale, block_q,
                             block_k, offset, interpret, window=None,
-                            group=1):
-    """(dq, dk, dv) via the two kernels above (no-bias path); the dk/dv
-    pass writes one result per query head, summed over each KV head's
-    ``group`` outside."""
+                            group=1, fused=False):
+    """(dq, dk, dv) via the dq pass and the dk/dv pass (no-bias path), or,
+    ``fused``, via the dk/dv pass alone with the head's dq accumulated
+    beside them: no HBM temporaries but delta and the lse view either way.
+    The dk/dv pass writes one result per query head, summed over each KV
+    head's ``group`` outside."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, tq, d = q.shape
     d_v = v.shape[2]
     tk = k.shape[1]
-    tq_real, tk_real = tq, tk
     (q, k, v, do, lse, delta, block_q, block_k, tqp, tkp) = \
         _bwd_prologue(q, k, v, o, lse, do, block_q, block_k)
     nq, nk = tqp // block_q, tkp // block_k
+    statics = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
+                   block_k=block_k, tq_real=tq, tk_real=tk, offset=offset,
+                   pads=tqp != tq or tkp != tk, window=window)
 
-    # lse/delta ride as [bh, tq, 1]: block (1, block_q, 1) keeps the last
-    # dim equal to the array's (mosaic tiling constraint)
-    lse3 = lse[..., None]
-    delta3 = delta[..., None]
-    plain = window is None and group == 1
-    def q_spec_q(w):
-        return pl.BlockSpec((1, block_q, w), lambda b, i, j: (b, i, 0))
+    if not fused:
+        # lse/delta ride as [bh, tq, 1]: block (1, block_q, 1) keeps the
+        # last dim equal to the array's (mosaic tiling constraint)
+        def q_spec_q(w):
+            return pl.BlockSpec((1, block_q, w), lambda b, i, j: (b, i, 0))
 
-    def k_spec_q(w):
-        return _k_spec(block_q, block_k, w, window, group, offset, nk)
-    row_spec_q = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k,
-                          tq_real=tq_real, tk_real=tk_real, offset=offset,
-                          pads=tqp != tq_real or tkp != tk_real,
-                          window=window),
-        grid=(bh, nq, nk),
-        in_specs=[q_spec_q(d), k_spec_q(d), k_spec_q(d_v), q_spec_q(d_v),
-                  row_spec_q, row_spec_q],
-        out_specs=q_spec_q(d),
-        out_shape=jax.ShapeDtypeStruct((bh, tqp, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(q, k, v, do, lse3, delta3)
+        def k_spec_q(w):
+            return _k_spec(block_q, block_k, w, window, group, offset, nk)
+        row_spec_q = pl.BlockSpec((1, block_q, 1),
+                                  lambda b, i, j: (b, i, 0))
+        dq = pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, **statics),
+            grid=(bh, nq, nk),
+            in_specs=[q_spec_q(d), k_spec_q(d), k_spec_q(d_v),
+                      q_spec_q(d_v), row_spec_q, row_spec_q],
+            out_specs=q_spec_q(d),
+            out_shape=jax.ShapeDtypeStruct((bh, tqp, d), q.dtype),
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            interpret=interpret,
+            name="flash_bwd_dq",
+        )(q, k, v, do, lse[..., None], delta[..., None])
 
     # dk/dv pass: grid iterates q innermost per k-block; lse/delta ride
-    # TRANSPOSED [bh, 1, tq] so the kernel reads (1, bq) rows directly
-    lse_t = lse[:, None, :]
-    delta_t = delta[:, None, :]
-    if plain:
-        def q_spec_k(w):
-            return pl.BlockSpec((1, block_q, w), lambda b, j, i: (b, i, 0))
-
-        def k_spec_k(w):
-            return pl.BlockSpec((1, block_k, w), lambda b, j, i: (b, j, 0))
-        row_spec_k = pl.BlockSpec((1, 1, block_q),
-                                  lambda b, j, i: (b, 0, i))
+    # TRANSPOSED [bh, 1, tq] so the kernel reads (1, bq) rows directly (a
+    # [bh, tq, 1] array is tiled to 128 lanes in HBM, 134 MB each at [32,
+    # 8192], and its (bq, 1) blocks to as many in VMEM).  The plain maps
+    # lower as before the window and the groups existed
+    if window is None and group == 1:
+        def iq_of(i, j):
+            return i
     else:
         def iq_of(i, j):
             return _live_q(i, j, window, block_q, block_k, offset, nq)
 
-        def q_spec_k(w):
-            return pl.BlockSpec((1, block_q, w),
-                                lambda b, j, i: (b, iq_of(i, j), 0))
+    def q_spec(w):
+        return pl.BlockSpec((1, block_q, w),
+                            lambda b, j, i: (b, iq_of(i, j), 0))
 
-        def k_spec_k(w):
-            return pl.BlockSpec((1, block_k, w),
-                                lambda b, j, i: (_kv_head(b, group), j, 0))
-        row_spec_k = pl.BlockSpec((1, 1, block_q),
-                                  lambda b, j, i: (b, 0, iq_of(i, j)))
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale,
-                          causal=causal, block_q=block_q, block_k=block_k,
-                          tq_real=tq_real, tk_real=tk_real, offset=offset,
-                          pads=tqp != tq_real or tkp != tk_real,
-                          window=window),
+    def k_spec(w):
+        return pl.BlockSpec((1, block_k, w),
+                            lambda b, j, i: (_kv_head(b, group), j, 0))
+
+    def out_spec(w):
+        return pl.BlockSpec((1, block_k, w), lambda b, j, i: (b, j, 0))
+    row_spec = pl.BlockSpec((1, 1, block_q),
+                            lambda b, j, i: (b, 0, iq_of(i, j)))
+    out_specs = [out_spec(d), out_spec(d_v)]
+    out_shape = [jax.ShapeDtypeStruct((bh, tkp, d), k.dtype),
+                 jax.ShapeDtypeStruct((bh, tkp, d_v), v.dtype)]
+    scratch = [pltpu.VMEM((block_k, d), jnp.float32),
+               pltpu.VMEM((block_k, d_v), jnp.float32)]
+    extra = {}
+    if fused:
+        # the head's whole dQ: one block for every (ik, iq) of a head
+        out_specs.append(pl.BlockSpec((1, tqp, d), lambda b, j, i: (b, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((bh, tqp, d), q.dtype))
+        scratch.append(pltpu.VMEM((tqp, d), jnp.float32))
+        extra["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=_fused_vmem_bytes(
+                tqp, d, d_v, block_q, block_k, q.dtype.itemsize))
+    dk, dv, *dqs = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, **statics),
         grid=(bh, nk, nq),
-        in_specs=[q_spec_k(d), k_spec_k(d), k_spec_k(d_v), q_spec_k(d_v),
-                  row_spec_k, row_spec_k],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d_v), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((bh, tkp, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, tkp, d_v), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d_v), jnp.float32)],
+        in_specs=[q_spec(d), k_spec(d), k_spec(d_v), q_spec(d_v), row_spec,
+                  row_spec],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         interpret=interpret,
-        name="flash_bwd_dkv",
-    )(q, k, v, do, lse_t, delta_t)
+        name="flash_bwd_fused" if fused else "flash_bwd_dkv",
+        **extra,
+    )(q, k, v, do, lse[:, None, :], delta[:, None, :])
+    if fused:
+        dq, = dqs
     if group > 1:
         dk = dk.reshape(bh // group, group, tkp, d).astype(
             jnp.float32).sum(axis=1).astype(k.dtype)
@@ -940,40 +1034,61 @@ _BWD_DEFAULTS = {2048: (1024, 512), 4096: (1024, 1024), 8192: (1024, 512),
 # bf16 (tools/olmoe_kernel_sweep.py, PR 27): forward (1024, 1024) 3.17 ms
 # against the (512, 1024) baseline's 4.01; backward "combined" (1024, 512)
 # 8.75 ms against 11.05 at the forward's blocks (split (1024, 1024) 8.89);
-# (1024, 1024) combined and every 2048-wide backward block run out of VMEM.
+# (1024, 1024) combined and every 2048-wide backward block ran out of the
+# default 16 MiB of VMEM.  Since PR 37 "fused" (1024, 1024): alone, forward
+# (512, 1024) + backward, 9.85 ms against combined (1024, 512) 12.77 and
+# split (1024, 1024) 12.87 (fused (512, 1024) 10.03, (512, 512) 10.20,
+# (1024, 512) 10.28, 2048-wide 10.8 to 11.0); in OLMoE's step 19.41
+# samples/s against combined (1024, 512)'s 19.14, fused (512, 1024) 19.38,
+# fused (1024, 512) 19.35, and 133 MB less at the peak (the partials; the
+# window alone, tools/window_stalls.py, one seed; my chip runs, PR 37).
 # Other lengths at this width keep the baseline until they are swept.
 # 8192, on a v5e at [32, 8192, 128] bf16 over 4 KV heads
-# (tools/trinity_kernel_probe.py, PR 32; forward + backward ms): forward
-# (1024, 1024) 5.47 ms full and 3.58 under a window of 2048 ((512, 1024)
-# 7.08 / 4.46, (512, 512) 11.3 / 6.4).  Full backward: combined (1024, 512)
-# 20.67 with 2.15 GB of dK/dV partials, split (1024, 512) 20.92 with none
-# (split (512, 512) 21.77): the split one, a third entry here, for the
-# memory.  Under the window the partials are mostly zeros that are written
-# and summed all the same: combined (1024, 512) 16.18, split (1024, 512)
-# 22.71, split (512, 512) 14.33 (smaller query blocks hug the band), so a
-# window has a table of its own.
+# (tools/trinity_kernel_probe.py, PRs 32 and 37; backward ms = forward +
+# backward less the forward): forward (1024, 1024) 5.45 ms full and 3.57
+# under a window of 2048 ((512, 1024) 7.08 / 4.46, (512, 512) 11.3 / 6.4).
+# Full backward: fused (1024, 1024) 10.10, (512, 1024) 10.43, (1024, 512)
+# 10.79, (512, 512) 11.19, (2048, 512) 11.47, (1024, 256) 12.31; combined
+# (1024, 512) 15.19 with 2.15 GB of dK/dV partials; split (1024, 512) 15.42.
+# Under the window (the combined kernel's partials are mostly zeros that
+# are written and summed all the same, 12.6 in PR 32): fused (1024, 1024)
+# 7.11, (512, 512) 7.25, (512, 1024) 7.31, (1024, 512) 7.61, (256, 1024)
+# 8.04, (1024, 256) 8.81, (256, 256) 14.57; split (512, 512) 10.73.  In
+# Trinity's step (the window alone, samples/s): full (1024, 1024) with the
+# window at (512, 512) 3.941, at (1024, 1024) 3.939, at (512, 1024) 3.927;
+# full (1024, 512) 3.926; the split kernels 3.642.  The two best differ by
+# less than a seed does (0.3 %): the window keeps the smaller blocks, which
+# hug the band and ask for 20 MiB of VMEM, not 43.
 _FWD_DEFAULTS_D128 = {4096: (1024, 1024), 8192: (1024, 1024)}
-_BWD_DEFAULTS_D128 = {4096: (1024, 512), 8192: (1024, 512, "split")}
-_BWD_WINDOW_DEFAULTS_D128 = {8192: (512, 512, "split")}
+_BWD_DEFAULTS_D128 = {4096: (1024, 1024, "fused"),
+                      8192: (1024, 1024, "fused")}
+_BWD_WINDOW_DEFAULTS_D128 = {8192: (512, 512, "fused")}
 # score width 128 < d_qk <= 256 (the values may be narrower: 192 over 128 is
 # latent attention's pair), on a v5e at causal [32, 8192, 192 | 128] bf16,
-# every head its own K/V (tools/joyai_kernel_probe.py, PR 34): forward
-# (1024, 1024) 8.28 ms ((512, 2048) 9.65, (512, 1024) 10.15, (1024, 512)
-# 13.46, (256, 1024) 13.47, (512, 512) 14.21; all compile).  Backward,
-# forward (512, 1024) + backward ms: split (1024, 512) 34.18, split (512,
-# 1024) 34.53, split (512, 512) 35.62, split (1024, 256) 37.08, split (512,
-# 256) 39.97, split (256, 512) 40.57; split (1024, 1024) runs out of VMEM;
-# "combined" would keep 2.68 GB of float32 dK/dV partials here, past
-# _COMBINED_PARTIAL_BUDGET, so it IS the split kernels (34.19).  The same
-# kernels at 128 | 128 read 5.46 and 21.26: a 192-wide contraction fills
-# two 128-deep MXU passes, so the scores cost what 256 would.  Other
+# every head its own K/V (tools/joyai_kernel_probe.py, PRs 34 and 37):
+# forward (1024, 1024) 8.28 ms ((512, 2048) 9.65, (512, 1024) 10.15, (1024,
+# 512) 13.46, (256, 1024) 13.47, (512, 512) 14.21; all compile).  Backward
+# ms: fused (1024, 1024) 15.74, (512, 1024) 16.09, (1024, 512) 16.79, (512,
+# 512) 16.90, (512, 2048) 16.91, (256, 1024) 16.98, (2048, 512) 17.85,
+# (1024, 256) 18.94, (2048, 1024) 37.07 (it spills); split (1024, 512) 24.03,
+# (512, 512) 25.49, and split (1024, 1024) does not fit the default 16 MiB
+# of VMEM; "combined" would keep 2.68 GB of float32 dK/dV partials here,
+# past _COMBINED_PARTIAL_BUDGET, so it IS the split kernels.  In JoyAI's
+# step (the window alone, samples/s): fused (1024, 1024) 2.361, (512, 1024)
+# 2.350, (1024, 512) 2.328, split (1024, 512) 2.120.  The fused kernel on
+# (bq, bk) tiles with (bq, 1) lse/delta rows, tried first, read 16.73 at
+# (1024, 1024) and 19.13 at (1024, 512) and kept 268 MB more in HBM (a [bh,
+# Tq, 1] float32 array is tiled to 128 lanes): the transposed tiles ship.
+# The split kernels at 128 | 128 read 5.46 forward and 15.8 backward: a
+# 192-wide contraction fills two 128-deep MXU passes, so the scores cost
+# what 256 would.  Other
 # lengths at this width keep the baseline until they are swept, and so do
 # float32 inputs (blocks twice the bytes: in the cell's float32 forward
 # program, where XLA fuses the operands' producers into the call, (1024,
 # 1024) passed the 16 MiB of scoped VMEM by 12 KiB, though the kernel alone
 # compiles; my chip run, PR 34).
 _FWD_DEFAULTS_D256 = {8192: (1024, 1024)}
-_BWD_DEFAULTS_D256 = {8192: (1024, 512, "split")}
+_BWD_DEFAULTS_D256 = {8192: (1024, 1024, "fused")}
 
 
 def _collapse_bias(bias, b, h, tq, tk):
@@ -988,14 +1103,12 @@ def _collapse_bias(bias, b, h, tq, tk):
     return jnp.broadcast_to(bias, (b, h, tq, tk)).reshape(b * h, tq, tk)
 
 
-def _plan(q, k, v, bias, causal, sm_scale, block_q, block_k, block_q_bwd,
-          block_k_bwd, bwd_impl, interpret, window):
-    """What :func:`flash_attention` and its two halves share: the checks,
-    the block choice from the tables, the window folded away where it is the
-    whole causal half, the heads collapsed into the batch and the bias
-    broadcast.  Returns ``((q, k, v, bias) collapsed, statics)``;
-    ``statics`` are ``_flash``'s non-differentiated arguments, in its
-    order."""
+def _statics(q, k, v, causal, sm_scale, block_q, block_k, block_q_bwd,
+             block_k_bwd, bwd_impl, interpret, window):
+    """``_flash``'s non-differentiated arguments, in its order, from the
+    shapes and types of ``q``, ``k``, ``v`` alone: the checks, the block
+    choice from the tables and the window folded away where it is the whole
+    causal half."""
     b, h, tq, d = q.shape
     hk, tk = k.shape[1], k.shape[2]
     if h % hk or v.shape[1] != hk:
@@ -1049,12 +1162,24 @@ def _plan(q, k, v, bias, causal, sm_scale, block_q, block_k, block_q_bwd,
             bq_b, bk_b, *impl = bwd_table[t]
             bwd_blocks = (min(bq_b, tq), min(bk_b, tk))
             bwd_impl = bwd_impl or (impl[0] if impl else None)
+    return (causal, sm_scale, block_q, block_k, bwd_blocks, bwd_impl,
+            interpret, window, group)
+
+
+def _plan(q, k, v, bias, causal, sm_scale, block_q, block_k, block_q_bwd,
+          block_k_bwd, bwd_impl, interpret, window):
+    """What :func:`flash_attention` and its two halves share: :func:`_statics`,
+    the heads collapsed into the batch and the bias broadcast.  Returns
+    ``((q, k, v, bias) collapsed, statics)``."""
+    statics = _statics(q, k, v, causal, sm_scale, block_q, block_k,
+                       block_q_bwd, block_k_bwd, bwd_impl, interpret, window)
+    b, h, tq, d = q.shape
+    hk, tk = k.shape[1], k.shape[2]
     qc = q.reshape(b * h, tq, d)
     kc = k.reshape(b * hk, tk, d)
     vc = v.reshape(b * hk, tk, v.shape[3])   # V's own width, the output's
     bc = None if bias is None else _collapse_bias(bias, b, h, tq, tk)
-    return (qc, kc, vc, bc), (causal, sm_scale, block_q, block_k, bwd_blocks,
-                              bwd_impl, interpret, window, group)
+    return (qc, kc, vc, bc), statics
 
 
 def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
@@ -1097,11 +1222,15 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
     The backward kernels take their own ``block_q_bwd``/``block_k_bwd``
     (default: the ``_BWD_DEFAULTS`` table at d≤64 for 2k/4k/8k/16k, else
     the forward blocks) — swept separately in LONGCTX_ABLATION.md.
-    ``bwd_impl``: "combined" (single-recompute, dk/dv partial sums;
-    auto-falls back to split when the partials would exceed
-    ``_COMBINED_PARTIAL_BUDGET`` HBM) or "split" (two-pass);
-    default = what the backward table says for this length, else module
-    `_BWD_IMPL`.
+    ``bwd_impl``: "fused" (single-recompute, dk/dv and the head's dq
+    accumulated in VMEM; falls back to split where the accumulator and the
+    blocks pass ``_FUSED_VMEM_SHARE`` of a core's VMEM: ``Tq · d_qk`` some
+    4 to 8 times the cells'), "combined" (single-recompute, dk/dv partial
+    sums in HBM; falls back to split when the partials would exceed
+    ``_COMBINED_PARTIAL_BUDGET``) or "split" (two-pass);
+    default = what the backward table says for this length ("fused" at
+    8192 for 64 < d_qk ≤ 256), else module `_BWD_IMPL`.
+    :func:`flash_bwd_kernel` says which one a call gets.
     """
     (qc, kc, vc, bc), statics = _plan(
         q, k, v, bias, causal, sm_scale, block_q, block_k, block_q_bwd,
@@ -1152,3 +1281,24 @@ def flash_attention_bwd(q, k, v, bias, o, lse, do, causal=False,
         db, = jax.vjp(lambda x: _collapse_bias(x, b, h, tq, k.shape[2]),
                       bias)[1](db.astype(bias.dtype))
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape), db
+
+
+def flash_bwd_kernel(q, k, v, bias=None, causal=False, sm_scale=None,
+                     block_q=None, block_k=None, block_q_bwd=None,
+                     block_k_bwd=None, bwd_impl=None, interpret=False,
+                     window=None):
+    """Which backward :func:`flash_attention_bwd` (and ``jax.grad`` of
+    :func:`flash_attention`) runs for these arguments, from their shapes
+    alone: ``"fused"``, ``"combined"`` or ``"split"`` of the Pallas kernels,
+    or ``"jax"``, the blockwise fallback (a bias, or no TPU)."""
+    *_, block_q, block_k, bwd_blocks, bwd_impl, interpret, _, _ = _statics(
+        q, k, v, causal, sm_scale, block_q, block_k, block_q_bwd,
+        block_k_bwd, bwd_impl, interpret, window)
+    if bias is not None or not (on_tpu() or interpret):
+        return "jax"
+
+    def collapsed(x):
+        return jax.ShapeDtypeStruct(
+            (x.shape[0] * x.shape[1],) + tuple(x.shape[2:]), x.dtype)
+    return _bwd_kernel_name(collapsed(q), collapsed(k), collapsed(v),
+                            *(bwd_blocks or (block_q, block_k)), bwd_impl)

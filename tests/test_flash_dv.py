@@ -59,6 +59,11 @@ def _value_and_grads(fn, q, k, v, w):
     (96, 64, 64, 4, None, None),           # the blockwise jax fallbacks
     (192, 128, 100, 2, 40, None),
     (64, 96, 32, 4, None, "combined"),     # values wider than the scores
+    (96, 64, 32, 4, None, "fused"),        # one pass, dQ resident (PR 37)
+    (96, 64, 40, 2, None, "fused"),        # padded blocks, grouped K/V heads
+    (96, 64, 48, 2, 24, "fused"),          # a window over grouped heads
+    (192, 128, 128, 4, None, "fused"),     # latent attention's pair
+    (64, 96, 32, 4, None, "fused"),        # values wider than the scores
 ])
 def test_two_width_flash_matches_the_oracle(d_qk, d_v, t, hk, window, impl):
     """Forward and every gradient at ``d_qk != d_v``, the scale the caller's
@@ -82,6 +87,16 @@ def test_two_width_flash_matches_the_oracle(d_qk, d_v, t, hk, window, impl):
     for a, b, name in zip(g_got, g_want, "qkv"):
         assert a.shape == b.shape
         _close(a, b, 2e-5, f"{d_qk}/{d_v} d / d {name}")
+    if impl == "fused":
+        # the split kernels' products, accumulated in their order: dQ to the
+        # bit, dK and dV to the rounding of a first-axis contraction
+        _, g_split = _value_and_grads(
+            lambda q, k, v: F.flash_attention(
+                q, k, v, causal=True, sm_scale=sm, window=window,
+                block_q=blk, block_k=blk, bwd_impl="split", interpret=True),
+            q, k, v, w)
+        for a, b, name in zip(g_got, g_split, "qkv"):
+            _close(a, b, 2e-6, f"fused against split, d / d {name}")
 
 
 def test_the_two_halves_keep_their_widths_and_k_must_match_q():
@@ -118,8 +133,8 @@ def test_block_tables_at_the_wide_score_width():
                        None, False, None)[1]
     st = plan(192, 128)
     assert st[1] == pytest.approx(192 ** -0.5)
-    assert st[2:6] == (1024, 1024, (1024, 512), "split")
-    assert plan(128, 128)[2:6] == (1024, 1024, (1024, 512), "split")
+    assert st[2:6] == (1024, 1024, (1024, 1024), "fused")
+    assert plan(128, 128)[2:6] == (1024, 1024, (1024, 1024), "fused")
     assert plan(320, 128)[2:4] == (512, 1024)           # the baseline
     # float32 blocks are twice the bytes: the wide table is bf16's alone
     assert plan(192, 128, jnp.float32)[2:5] == (512, 1024, None)
@@ -132,6 +147,66 @@ def test_block_tables_at_the_wide_score_width():
 
 
 # -- the op of a Program ----------------------------------------------------------
+
+#: name -> (heads, KV heads, T, d_qk, d_v, window, bwd_impl asked of the grad
+#: op, the backward kernel a TPU runs there)
+CHOICE_CASES = {
+    "joyai_32x8192x192_over_128": (32, 32, 8192, 192, 128, None, None,
+                                   "fused"),
+    "trinity_full_32_over_4x8192x128": (32, 4, 8192, 128, 128, None, None,
+                                        "fused"),
+    "trinity_window_2048": (32, 4, 8192, 128, 128, 2048, None, "fused"),
+    "olmoe_64x4096x128": (64, 64, 4096, 128, 128, None, None, "fused"),
+    # a head's dQ accumulator at 65536 x 192 is 100 MB: past the share of
+    # VMEM the fused backward may ask for, whatever is asked of the op
+    "too_long_for_the_accumulator": (2, 2, 65536, 192, 128, None, "fused",
+                                     "split"),
+    "combined_past_its_budget": (32, 32, 8192, 192, 128, None, "combined",
+                                 "split"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CHOICE_CASES))
+def test_the_backward_kernel_follows_the_shapes(case, monkeypatch):
+    """Which backward ``flash_attention_grad`` lowers to on a TPU, traced
+    abstractly at the three flash cells' shapes in bf16 (the tables name the
+    fused kernel at 8192), at a length whose dQ accumulator passes the share
+    of VMEM (the split kernels, which keep nothing that grows with T), and
+    for "combined" past its partial budget; ``paddle_tpu_flash_bwd_kernel_
+    total`` says which was taken, and the fused call asks for the VMEM its
+    shapes need and no more than the share."""
+    from paddle_tpu.ops import attention_ops as A
+    h, hk, t, d_qk, d_v, window, asked, kernel = CHOICE_CASES[case]
+    monkeypatch.setattr(F, "on_tpu", lambda: True)
+
+    def arg(heads, width):
+        return jax.ShapeDtypeStruct((1, heads, t, width), jnp.bfloat16)
+    q, k, v, o = arg(h, d_qk), arg(hk, d_qk), arg(hk, d_v), arg(h, d_v)
+    lse = jax.ShapeDtypeStruct((1, h, t), jnp.float32)
+    attrs = {"causal": True, "window": window or 0, "bwd_impl": asked or ""}
+    assert F.flash_bwd_kernel(q, k, v, causal=True, window=window,
+                              bwd_impl=asked) == kernel
+    labels = dict(kernel=kernel, widths=f"{d_qk}/{d_v}",
+                  window="none" if window is None else str(window))
+    before = A.FLASH_BWD_KERNEL_CTR.value(**labels)
+    limits = []
+    from jax.experimental.pallas import tpu as pltpu
+    real_params = pltpu.CompilerParams
+    monkeypatch.setattr(pltpu, "CompilerParams", lambda **kw: limits.append(
+        kw.get("vmem_limit_bytes")) or real_params(**kw))
+    got = jax.eval_shape(
+        lambda q, k, v, o, lse, do: A._flash_attention_grad(None, {
+            "X$Q": [q], "X$K": [k], "X$V": [v], "Out": [o], "Lse": [lse],
+            "OG$Out": [do]}, attrs), q, k, v, o, lse, o)
+    assert [got[s][0].shape for s in ("IG$Q", "IG$K", "IG$V")] == \
+        [q.shape, k.shape, v.shape]
+    assert A.FLASH_BWD_KERNEL_CTR.value(**labels) == before + 1
+    if kernel == "fused":
+        assert len(limits) == 1 and 16 << 20 < limits[0] <= \
+            F._FUSED_VMEM_SHARE * F._VMEM_BYTES
+    else:
+        assert limits == []
+
 
 def _op_program(t, d_qk, d_v, h=4, hk=2):
     q, k, v, w = (np.asarray(a) for a in _qkv(t, d_qk, d_v, h=h, hk=hk))

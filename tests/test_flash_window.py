@@ -24,7 +24,8 @@ from paddle_tpu.framework import (Executor, Program, Scope, program_guard,
 from paddle_tpu.framework.backward import append_backward
 from paddle_tpu.framework.core import grad_var_name
 from paddle_tpu.models import transformer as T
-from paddle_tpu.ops.attention_ops import (FLASH_GRAD_LOWERINGS_CTR,
+from paddle_tpu.ops.attention_ops import (FLASH_BWD_KERNEL_CTR,
+                                          FLASH_GRAD_LOWERINGS_CTR,
                                           FLASH_LOWERINGS_CTR)
 from paddle_tpu.pallas import mha_reference
 
@@ -56,6 +57,10 @@ def _value_and_grads(fn, q, k, v, w):
     (40, "split"),
     (2, "combined"),      # the diagonal and its neighbour
     (23, None),           # the blockwise jax fallback (no TPU, no interpret)
+    (8, "fused"),         # one pass, dQ resident in VMEM (PR 37)
+    (16, "fused"),
+    (40, "fused"),
+    (2, "fused"),
 ])
 def test_window_flash_matches_the_dense_mask_oracle(window, impl):
     """Forward and dQ, dK, dV of the Pallas kernels (interpret mode) over 4
@@ -72,6 +77,13 @@ def test_window_flash_matches_the_dense_mask_oracle(window, impl):
     assert abs(float(got - want)) <= 1e-4 * abs(float(want)) + 1e-4
     for a, b, name in zip(g_got, g_want, "qkv"):
         _close(a, b, 1e-5, f"window {window} d / d {name}")
+    if impl == "fused":                # and the split kernels, same inputs
+        _, g_split = _value_and_grads(
+            lambda q, k, v: F.flash_attention(
+                q, k, v, causal=True, window=window, block_q=16, block_k=16,
+                bwd_impl="split", interpret=True), q, k, v, w)
+        for a, b, name in zip(g_got, g_split, "qkv"):
+            _close(a, b, 2e-6, f"fused against split, d / d {name}")
 
 
 def test_a_window_off_by_one_is_another_function():
@@ -96,12 +108,14 @@ def test_a_window_as_long_as_the_sequence_is_the_causal_lowering():
         F.flash_attention(q, k, v, window=8)          # not causal
 
 
-def test_grouped_kv_heads_equal_repeated_kv_heads():
+@pytest.mark.parametrize("impl", [None, "split", "fused"])
+def test_grouped_kv_heads_equal_repeated_kv_heads(impl):
     """4 query heads over 2 KV heads through the kernels' index maps against
     the same K and V repeated to 4 heads outside; dK and dV summed over each
-    group."""
+    group (``None``: the module's default backward, "combined")."""
     q, k, v, w = _qkv(32)
-    kw = dict(causal=True, window=12, block_q=16, block_k=16, interpret=True)
+    kw = dict(causal=True, window=12, block_q=16, block_k=16, interpret=True,
+              bwd_impl=impl)
     got, (gq, gk, gv) = _value_and_grads(
         lambda q, k, v: F.flash_attention(q, k, v, **kw), q, k, v, w)
     rep = lambda x: jnp.repeat(x, 2, axis=1)  # noqa: E731
@@ -154,7 +168,9 @@ def _flash_ops(program):
             [op for op in ops if op.type == "flash_attention_grad"])
 
 
-#: name -> (heads, KV heads, Tq, Tk, the op's keyword arguments, bias shape)
+#: name -> (heads, KV heads, Tq, Tk, the op's keyword arguments, bias shape[,
+#: (d_qk, d_v)]); a ``fused_`` case asks its grad op for the fused backward
+#: and runs the op's kernels as a TPU would take them, in interpret mode
 GRAD_CASES = {
     "causal": (4, 4, 48, 48, dict(causal=True), None),
     "window": (4, 4, 48, 48, dict(causal=True, window=10), None),
@@ -162,32 +178,63 @@ GRAD_CASES = {
     "additive_bias": (2, 2, 24, 24, dict(), (24, 24)),
     "bias_per_batch": (2, 2, 24, 24, dict(causal=True), (2, 1, 24, 24)),
     "tq_not_tk": (4, 2, 16, 40, dict(causal=True), None),
+    "fused_causal": (4, 4, 48, 48, dict(causal=True), None),
+    "fused_window": (4, 4, 48, 48, dict(causal=True, window=10), None),
+    "fused_grouped_kv_4_to_1": (4, 1, 48, 48,
+                                dict(causal=True, window=20), None),
+    "fused_192_over_128": (2, 2, 32, 32, dict(causal=True), None, (192, 128)),
+    "fused_padded_length": (2, 2, 40, 40, dict(causal=True), None),
+    "fused_tq_not_tk": (4, 2, 16, 40, dict(causal=True), None),
 }
 
 
+def _kernels_in_interpret_mode(monkeypatch):
+    """The flash ops' Pallas path on the CPU: the module believes it is on a
+    TPU and both kernel entry points are forced to interpret mode."""
+    monkeypatch.setattr(F, "on_tpu", lambda: True)
+    for name, at in (("_flash_fwd_pallas", 9), ("_flash_bwd_pallas", 11)):
+        def forced(*a, _real=getattr(F, name), _at=at, **kw):
+            return _real(*a[:_at], True, *a[_at + 1:], **kw)
+        monkeypatch.setattr(F, name, forced)
+
+
 @pytest.mark.parametrize("case", sorted(GRAD_CASES))
-def test_the_registered_grad_op_against_jax_grad_of_the_oracle(case):
+def test_the_registered_grad_op_against_jax_grad_of_the_oracle(
+        case, monkeypatch):
     """``flash_attention_grad`` as ``append_backward`` writes it (inputs Q,
     K, V, Bias where present, the forward's Out and Lse, dOut), run by the
     executor: dQ, dK, dV and dBias against ``jax.grad`` of ``mha_reference``
-    in float32, within what this file holds the kernels' own vjp to."""
-    h, hk, tq, tk, kw, bias_shape = GRAD_CASES[case]
+    in float32, within what this file holds the kernels' own vjp to; the
+    ``fused_`` cases through the fused backward kernel (blocks of 16), which
+    ``paddle_tpu_flash_bwd_kernel_total`` says was taken."""
+    h, hk, tq, tk, kw, bias_shape, *widths = GRAD_CASES[case]
+    d_qk, d_v = widths[0] if widths else (8, 8)
+    fused = case.startswith("fused_")
     rng = np.random.RandomState(len(case))
-    feed = {"q": rng.randn(2, h, tq, 8), "k": rng.randn(2, hk, tk, 8),
-            "v": rng.randn(2, hk, tk, 8), "w": rng.randn(2, h, tq, 8)}
+    feed = {"q": rng.randn(2, h, tq, d_qk), "k": rng.randn(2, hk, tk, d_qk),
+            "v": rng.randn(2, hk, tk, d_v), "w": rng.randn(2, h, tq, d_v)}
     if bias_shape:
         feed["bias"] = rng.randn(*bias_shape)
     feed = {n: a.astype(np.float32) for n, a in feed.items()}
     wrt = [n for n in ("q", "k", "v", "bias") if n in feed]
+    window = kw.get("window")
+    labels = dict(kernel="fused" if fused else "jax", widths=f"{d_qk}/{d_v}",
+                  window="none" if window is None else str(window))
+    before = FLASH_BWD_KERNEL_CTR.value(**labels)
     with scope_guard(Scope()), program_guard(Program(), Program()):
         v = {n: _data(n, a, grad=n != "w") for n, a in feed.items()}
-        out = layers.flash_attention(v["q"], v["k"], v["v"],
-                                     bias=v.get("bias"), **kw)
+        out = layers.flash_attention(
+            v["q"], v["k"], v["v"], bias=v.get("bias"),
+            **dict(kw, block_q=16, block_k=16) if fused else kw)
         loss = layers.reduce_sum(out * v["w"])
         append_backward(loss)
         (fwd,), (grad,) = _flash_ops(pt.default_main_program())
+        if fused:
+            grad.attrs["bwd_impl"] = "fused"
+            _kernels_in_interpret_mode(monkeypatch)
         got = Executor().run(feed=feed, fetch_list=[
             out.name] + [grad_var_name(n) for n in wrt])
+    assert FLASH_BWD_KERNEL_CTR.value(**labels) == before + 1
     assert grad.input("Out") == fwd.output("Out")
     assert grad.input("Lse") == fwd.output("Lse") and fwd.output("Lse")
     assert sorted(grad.inputs) == sorted(

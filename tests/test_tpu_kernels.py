@@ -114,6 +114,54 @@ def test_flash_with_bias():
     _flash_case(1024, 64, 2, jnp.float32, False, "combined", bias=True)
 
 
+#: the three flash cells' shapes, fewer heads (the dense oracle holds
+#: [heads, T, T] float32 scores): name -> (T, d_qk, d_v, heads, KV heads,
+#: window)
+CELL_SHAPES = {
+    "joyai_192_over_128": (8192, 192, 128, 2, 2, None),
+    "trinity_full": (8192, 128, 128, 4, 1, None),
+    "trinity_window_2048": (8192, 128, 128, 4, 1, 2048),
+    "olmoe_4096": (4096, 128, 128, 4, 4, None),
+    "padded_length_192_over_128": (3000, 192, 128, 2, 1, None),
+}
+
+
+@tpu_hw
+@pytest.mark.parametrize("bwd_impl", ["fused", "split", None])
+@pytest.mark.parametrize("case", sorted(CELL_SHAPES))
+def test_flash_backwards_at_the_cells_shapes(case, bwd_impl):
+    """The backward that ships at each flash cell's shape (``None``: the
+    tables' own choice) and the two it is chosen from, bf16 as in the cells,
+    against ``mha_reference`` in float32; the fused kernel also against the
+    split kernels on the same inputs (the same products in the same order:
+    within bf16's rounding of the results)."""
+    from paddle_tpu.pallas import flash_attention, mha_reference
+
+    t, d_qk, d_v, heads, kv_heads, window = CELL_SHAPES[case]
+    q, w = _rand((1, heads, t, d_qk), 0, jnp.bfloat16, 0.5), \
+        _rand((1, heads, t, d_v), 3, jnp.bfloat16, 0.5)
+    k, v = _rand((1, kv_heads, t, d_qk), 1, jnp.bfloat16, 0.5), \
+        _rand((1, kv_heads, t, d_v), 2, jnp.bfloat16, 0.5)
+
+    def grads(fn, *args, **kw):
+        return jax.grad(lambda q, k, v: jnp.sum(
+            fn(q, k, v, causal=True, window=window, **kw).astype(jnp.float32)
+            * w.astype(jnp.float32)), argnums=(0, 1, 2))(*args)
+
+    got = grads(flash_attention, q, k, v, bwd_impl=bwd_impl)
+    with jax.default_matmul_precision("highest"):
+        want = grads(mha_reference, *(a.astype(jnp.float32)
+                                      for a in (q, k, v)))
+    errs = {n: _rel_err(g, r) for n, g, r in zip(("dq", "dk", "dv"), got,
+                                                 want)}
+    if bwd_impl == "fused":
+        split = grads(flash_attention, q, k, v, bwd_impl="split")
+        errs.update({n + "_vs_split": _rel_err(g, r) for n, g, r in zip(
+            ("dq", "dk", "dv"), got, split)})
+    _record("flash_attention_cells", case=case, bwd_impl=bwd_impl, **errs)
+    assert max(errs.values()) < TOL[jnp.bfloat16], errs
+
+
 # ---------------------------------------------------------------------------
 # layer norm, dense epilogue
 # ---------------------------------------------------------------------------

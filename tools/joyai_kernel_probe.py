@@ -1,8 +1,9 @@
 """Times the flash attention kernels alone at the JoyAI-LLM-Flash cell's
 shapes on the chip: latent attention's [1, 32, 8192, 192] queries and keys
 over [1, 32, 8192, 128] values in bf16, the causal half, forward and
-forward + backward, over block sizes and both backward kernels; and what the
-rotary key costs as the program ships it: the 64-wide rotary key is one head
+forward + backward, over block sizes and the three backward kernels (fused,
+combined, split); and what the rotary key costs as the program ships it:
+the 64-wide rotary key is one head
 for all 32, broadcast and concatenated behind each head's 128-wide content
 part outside the kernel (``build``: Q's and K's concatenation, forward, and
 their transposes, backward, as XLA fuses them alone), which is the most a
@@ -15,7 +16,8 @@ heads at a time).
 
     chiprun -- python3 tools/joyai_kernel_probe.py
     JAX_PLATFORMS=cpu python3 tools/joyai_kernel_probe.py --aot   # compiles
-        each block choice for a described v5e, runs nothing: which fit VMEM
+        each block choice for a described v5e, runs nothing: which fit VMEM,
+        which backward the entry point runs and the VMEM limit it asks for
 """
 
 import argparse
@@ -32,10 +34,13 @@ if ROOT not in sys.path:
 FWD_BLOCKS = "512,1024;1024,1024;512,512;1024,512;256,1024;512,2048"
 # "combined" at [32, 8192, 192 | 128] would keep 2.68 GB of float32 dK/dV
 # partials at 1024-row query blocks: past _COMBINED_PARTIAL_BUDGET, so the
-# entry point runs the split kernels whatever is asked (one row shows it)
-BWD_BLOCKS = ("combined,1024,512;split,1024,512;split,512,512;"
-              "split,512,1024;split,1024,1024;split,256,512;split,1024,256;"
-              "split,512,256")
+# entry point runs the split kernels whatever is asked (one row shows it);
+# "fused" keeps none and asks for the VMEM its shapes need (PR 37), which is
+# what let the 1024 x 1024 and 2048-wide blocks compile at all
+BWD_BLOCKS = ("fused,1024,512;fused,512,512;fused,1024,1024;fused,512,1024;"
+              "fused,2048,512;fused,512,2048;fused,1024,256;fused,256,1024;"
+              "combined,1024,512;split,1024,512;split,512,512;"
+              "split,512,1024;split,1024,1024")
 
 
 def _blocks(text):
@@ -82,6 +87,13 @@ def aot(args, F):
         except Exception as e:
             row = {"bwd": [impl, bq, bk], "compiles": False,
                    "error": str(e).strip().splitlines()[-1][:160]}
+        # what the entry point runs for this request, and the VMEM it asks
+        # for (the split and combined kernels: Mosaic's default 16 MiB)
+        row["runs"] = F._bwd_kernel_name(s(args.d_qk), s(args.d_qk),
+                                         s(args.d_v), bq, bk, impl)
+        row["vmem_limit_mib"] = F._fused_vmem_bytes(
+            t, args.d_qk, args.d_v, bq, bk, jnp.dtype(args.dtype).itemsize
+        ) / 2 ** 20 if row["runs"] == "fused" else 16
         print(json.dumps(row), flush=True)
 
 

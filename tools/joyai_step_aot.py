@@ -5,8 +5,10 @@ executor's first step before it runs, and hands its lowering (the Pallas
 kernels and megablox as a TPU would take them: ``device.on_tpu`` is steered
 here, in the tool) to the TPU compiler.  Prints the executable's temporaries,
 arguments, the count of XLA's own rematerialised instructions (``.remat`` in
-the compiled text) and ``reads_after_update`` (must be empty: a donated
-parameter read again behind its optimizer update), with and without
+the compiled text), ``reads_after_update`` (must be empty: a donated
+parameter read again behind its optimizer update) and which backward kernel
+each ``flash_attention_grad`` of the step got
+(``paddle_tpu_flash_bwd_kernel_total``), with and without
 ``--recompute``: whether the step fits beside its state, and what fitting
 costs (PERF.md section 7, row 31).  Nothing runs: no time comes from this.  The adapter has the
 recomputing step only (the traffic file's); without ``--recompute`` this
@@ -49,7 +51,8 @@ class _NoRecompute:
 
 #: cell -> (configuration, which is also its adapter module; traffic mix)
 CELLS = {"joyai": ("joyai_llm_flash", "lm_mtp_s8192"),
-         "trinity": ("trinity_mini", "lm_s8192")}
+         "trinity": ("trinity_mini", "lm_s8192"),
+         "olmoe": ("olmoe_1b_7b", "lm_s4096")}
 
 
 def reads_after_update(text):
@@ -132,7 +135,8 @@ def main():
     ap.add_argument("--dump", default="")
     ap.add_argument("--cell", default="joyai", choices=sorted(CELLS),
                     help="the other cell that runs moe_ffn's held path: "
-                    "trinity (its step has no recomputation: leave "
+                    "trinity, or the third that runs the flash kernels: "
+                    "olmoe (their steps have no recomputation: leave "
                     "--recompute out)")
     args = ap.parse_args()
     if args.run:
@@ -146,7 +150,7 @@ def main():
     from jax.sharding import SingleDeviceSharding
     import paddle_tpu  # noqa: F401
     from paddle_tpu import device
-    from paddle_tpu.ops import fused_ops
+    from paddle_tpu.ops import attention_ops, fused_ops
     from paddle_tpu.pallas import layer_norm
     import importlib
     flash = importlib.import_module("paddle_tpu.pallas.flash_attention")
@@ -198,6 +202,11 @@ def main():
         "alias_gb": mem.alias_size_in_bytes / 1e9,
         "remat_instructions": len(re.findall(r"\.remat\d* = ", text)),
         "reads_after_update": reads_after_update(text),
+        # which backward each flash_attention_grad lowering of the step got
+        "flash_bwd_kernels": {
+            "/".join(labels[n] for n in ("kernel", "window", "widths")):
+            int(cell.get()) for labels, cell in
+            attention_ops.FLASH_BWD_KERNEL_CTR.series() if cell.get()},
         "parameters_m": sum(int(np.prod(p.shape))
                             for p in m["parameters"]) / 1e6}))
     return 0

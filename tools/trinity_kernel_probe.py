@@ -1,7 +1,7 @@
 """Times the flash attention kernels alone at the Trinity-Mini cell's shapes
 on the chip: [1, 32, 8192, 128] queries over 4 K/V heads in bf16, the window
-of 2048 against the full causal half, the combined backward against the
-split one, and (``--blocks``) other block sizes.  Prints one JSON line per
+of 2048 against the full causal half, the fused backward against the
+combined and the split ones, and (``--blocks``) other block sizes.  Prints one JSON line per
 case: forward ms, forward + backward ms, the backward's temporaries and, for
 the block tables' own choice, how far the output and the three gradients are
 from ``mha_reference`` (the dense-mask oracle, float32 at ``highest`` over
@@ -31,6 +31,8 @@ def main():
     ap.add_argument("--window", type=int, default=2048)
     ap.add_argument("--blocks", default="",
                     help="bq_fwd,bk_fwd,bq_bwd,bk_bwd[;...] beside defaults")
+    ap.add_argument("--impls", default="fused,combined,split",
+                    help="the backward kernels to time at each block choice")
     ap.add_argument("--iters", type=int, default=10)
     args = ap.parse_args()
     import importlib
@@ -84,7 +86,7 @@ def main():
     for window in (args.window, None):
         want = oracle(window)
         for blk in blocks:
-            for impl in ("combined", "split"):
+            for impl in args.impls.split(","):
                 kw = dict(causal=True, window=window, bwd_impl=impl,
                           interpret=interpret)
                 if blk:
