@@ -1615,8 +1615,10 @@ def switch_moe_ffn(x, num_experts, d_inner, capacity_factor=1.25,
 def moe_ffn(x, num_experts, top_k, d_expert, norm_topk_prob=False,
             param_prefix="moe", initializer=None, name=None,
             score_func="softmax", select_bias=False, norm_eps=0.0,
-            route_scale=1.0, num_held=None, expert_offset=0):
-    """Dropless top-k mixture of gated-SiLU experts over [b, t, d] input
+            route_scale=1.0, num_held=None, expert_offset=0, act="silu",
+            router_x=None):
+    """Dropless top-k mixture of gated experts (SiLU on the gate branch, or
+    ReLU with ``act="relu"``) over [b, t, d] input
     (``moe_ffn`` op: sorted rows + grouped matmuls, no capacity, no dropped
     token).  Returns ``(out, lb_loss, z_loss, expert_load)``: the
     load-balancing loss ``E * sum_e f_e P_e``, the router z-loss (mean
@@ -1634,8 +1636,13 @@ def moe_ffn(x, num_experts, top_k, d_expert, norm_topk_prob=False,
     ``route_scale`` multiplies the weights.  ``num_held`` (default all):
     the expert weights are [num_held, ...] and hold experts
     ``expert_offset .. expert_offset + num_held - 1`` of the ``num_experts``
-    the router scores; the output is their part of the layer's."""
+    the router scores; the output is their part of the layer's.
+    ``router_x`` [b, t, d]: what the router reads where that is not ``x`` (a
+    router placed before attention); the experts read ``x`` either way, and
+    the router's gradient goes to ``router_x`` alone."""
     from ..param_attr import ParamAttr
+    if act not in ("silu", "relu"):     # at build, not at the first run
+        raise ValueError(f"moe_ffn act {act!r}")
     helper = LayerHelper("moe_ffn", name=name)
     d = int(x.shape[-1])
     E, F = int(num_experts), int(d_expert)
@@ -1653,6 +1660,8 @@ def moe_ffn(x, num_experts, top_k, d_expert, norm_topk_prob=False,
               "GateW": [_p("gate.w", [H, d, F], ep)],
               "UpW": [_p("up.w", [H, d, F], ep)],
               "DownW": [_p("down.w", [H, F, d], ep)]}
+    if router_x is not None:
+        inputs["RouterX"] = [router_x]
     if select_bias:
         inputs["SelectBias"] = [helper.create_parameter(
             ParamAttr(name=f"{param_prefix}.select_bias",
@@ -1676,6 +1685,8 @@ def moe_ffn(x, num_experts, top_k, d_expert, norm_topk_prob=False,
         attrs["route_scale"] = float(route_scale)
     if expert_offset:
         attrs["expert_offset"] = int(expert_offset)
+    if act != "silu":
+        attrs["act"] = str(act)
     helper.append_op(
         "moe_ffn", inputs=inputs,
         outputs={"Out": [out], "LbLoss": [lb], "ZLoss": [z],

@@ -815,6 +815,114 @@ def build_joyai_pretrain(cfg: JoyaiConfig, seq_len, mtp_weight=0.3,
     return tuple(feeds), parts, loss
 
 
+# -- SmallThinker: a router that reads the layer's input before attention, ----
+# -- ReLU-gated experts, window layers with rotary, full layers without -------
+
+class SmallThinkerConfig:
+    """SmallThinker-21BA3B-Instruct defaults
+    (``PowerInfer/SmallThinker-21BA3B-Instruct`` config.json).  Every layer
+    is an expert layer.  ``sliding_window_layout[i]`` 1: layer ``i`` sees
+    ``window`` keys back, 0: the whole causal half; ``rope_layout[i]`` 1:
+    rotary on Q and K, 0: no positional term (published: the two layouts are
+    one, full layers first of every four).  ``n_held``/``expert_offset``: the
+    experts whose weights this program holds (default all), as
+    :class:`TrinityConfig`."""
+
+    def __init__(self, vocab_size=151936, d_model=2560, n_layer=52,
+                 n_head=28, n_kv_head=4, d_head=128, d_expert=768,
+                 n_experts=64, top_k=6, window=4096,
+                 sliding_window_layout=None, rope_layout=None,
+                 rms_eps=1e-6, rope_theta=1.5e6, n_held=None,
+                 expert_offset=0, init_std=0.02):
+        self.vocab_size = vocab_size
+        self.d_model = d_model
+        self.n_layer = n_layer
+        self.n_head = n_head
+        self.n_kv_head = n_kv_head
+        self.d_head = d_head
+        self.d_expert = d_expert
+        self.n_experts = n_experts
+        self.top_k = top_k
+        self.window = window
+        self.sliding_window_layout = list(sliding_window_layout) \
+            if sliding_window_layout is not None else \
+            [int(i % 4 != 0) for i in range(n_layer)]
+        self.rope_layout = list(rope_layout) if rope_layout is not None \
+            else list(self.sliding_window_layout)
+        assert len(self.sliding_window_layout) == n_layer
+        assert len(self.rope_layout) == n_layer
+        self.rms_eps = rms_eps
+        self.rope_theta = rope_theta
+        self.n_held = n_experts if n_held is None else n_held
+        self.expert_offset = expert_offset
+        self.init_std = init_std
+
+
+def smallthinker_decoder_layer(x, cfg: SmallThinkerConfig, idx=0,
+                               attn_impl="flash", is_test=False):
+    """One SmallThinker block, two norms, no bias anywhere: ``n = RMS1(x)``;
+    the router scores ``n``, the layer's input, BEFORE attention (six of 64,
+    softmax over the six kept logits); ``h = x + Attn(n)`` (grouped-query,
+    no QK-norm, no gate; rotate-half rotary where ``rope_layout`` says, a
+    ``window`` where ``sliding_window_layout`` says); ``out = h + sum_e p_e
+    Wd_e (relu(Wg_e m) * Wu_e m)`` over ``m = RMS2(h)``: the scores are
+    taken before attention and consumed after it, so ``moe_ffn`` gets
+    ``router_x=n`` beside its rows ``m``.  Returns ``(out, expert_load)``."""
+    from ..initializer import NormalInitializer
+    p = f"dec_{idx}"
+
+    def norm(v, name):
+        return layers.rms_norm(v, begin_norm_axis=2, epsilon=cfg.rms_eps,
+                               param_attr=ParamAttr(name=f"{p}.{name}.w"))
+
+    def rotate(q, k):
+        return (layers.rope(q, cfg.d_head, cfg.rope_theta),
+                layers.rope(k, cfg.d_head, cfg.rope_theta))
+
+    n = norm(x, "ln1")
+    with name_scope("attn"):
+        h = x + multi_head_attention(
+            n, n, n, cfg.d_model, cfg.n_head, is_test=is_test,
+            param_prefix=f"{p}.attn", attn_impl=attn_impl, causal=True,
+            bias=False, n_kv_head=cfg.n_kv_head, d_head=cfg.d_head,
+            window=cfg.window if cfg.sliding_window_layout[idx] else None,
+            head_hook=rotate if cfg.rope_layout[idx] else None)
+    moe, _, _, load = layers.moe_ffn(
+        norm(h, "ln2"), cfg.n_experts, cfg.top_k, cfg.d_expert,
+        norm_topk_prob=True, param_prefix=f"{p}.moe",
+        initializer=NormalInitializer(0.0, cfg.init_std),
+        num_held=cfg.n_held, expert_offset=cfg.expert_offset, act="relu",
+        router_x=n)
+    return h + moe, load
+
+
+def build_smallthinker_pretrain(cfg: SmallThinkerConfig, seq_len,
+                                is_test=False, attn_impl="flash",
+                                fused_head=True, checkpoints=None):
+    """Causal LM over :func:`smallthinker_decoder_layer` blocks: ids ->
+    embedding -> ``n_layer`` blocks -> final RMSNorm -> untied bias-free
+    head; loss = mean next-token CE (label 0 excluded, as in the other
+    builders) and nothing else (``config.json`` names no auxiliary loss).
+    ``checkpoints=[]`` collects the block outputs for ``RecomputeOptimizer``.
+    Returns ``(feeds, parts, loss)`` with ``parts`` = {"expert_load": [per
+    layer], "hidden": the final norm's output}."""
+    src_ids = layers.data("src_ids", shape=[seq_len], dtype="int64")
+    lm_label = layers.data("lm_label", shape=[seq_len], dtype="int64")
+    x = layers.embedding(src_ids, size=[cfg.vocab_size, cfg.d_model],
+                         param_attr=ParamAttr(name="word_embedding"))
+    loads = []
+    for i in range(cfg.n_layer):
+        x, load = smallthinker_decoder_layer(x, cfg, i, attn_impl, is_test)
+        loads.append(load)
+        if checkpoints is not None:
+            checkpoints.append(x)
+    x = layers.rms_norm(x, begin_norm_axis=2, epsilon=cfg.rms_eps,
+                        param_attr=ParamAttr(name="final_norm.w"))
+    _, loss = _lm_head_loss(x, cfg, lm_label, fused_head, "lm_out",
+                            bias=False)
+    return (src_ids, lm_label), {"expert_load": loads, "hidden": x}, loss
+
+
 def annotate_tensor_parallel(program=None):
     """Megatron-style TP layout via dist_spec (SURVEY §2.5: TP is a
     capability the reference LACKS — first-class here)."""

@@ -204,6 +204,13 @@ class Counter(_Metric):
          else self._default_cell()).inc(n)
 
     def value(self, **labels) -> float:
+        """The series' count; given some of the label names only, the sum
+        over the series that match them (a reader written before a label
+        was added still reads its total)."""
+        if labels and set(labels) < set(self.labelnames):
+            want = {k: str(v) for k, v in labels.items()}
+            return sum(cell.get() for kv, cell in self.series()
+                       if all(kv[k] == v for k, v in want.items()))
         return (self.labels(**labels) if labels or self.labelnames
                 else self._default_cell()).get()
 

@@ -8,9 +8,11 @@ static, whatever the routing (a chip's share of the experts picks its row
 buffer's length from a short ladder of static lengths, below); no one-hot
 ``[S, E, C]`` dispatch tensor.
 
-- ``moe_ffn``: dropless top-k over gated-SiLU experts (OLMoE, Mixtral; with
-  ``score_func="sigmoid"``, a selection bias, renormalised and scaled
-  weights and a share of the experts, the DeepSeek-V3 / Trinity layer).
+- ``moe_ffn``: dropless top-k over gated experts, SiLU on the gate branch
+  (OLMoE, Mixtral; with ``score_func="sigmoid"``, a selection bias,
+  renormalised and scaled weights and a share of the experts, the
+  DeepSeek-V3 / Trinity layer) or ReLU (``act="relu"``; with a router that
+  reads an input of its own, ``RouterX``, the SmallThinker layer).
 - ``switch_ffn``: Switch-Transformer top-1 with biases and a capacity, which
   here is a cap on the rows of a group that count, not a tensor dimension.
 
@@ -44,6 +46,14 @@ was before they existed, bit for bit):
   ``Saved`` keeps the longest rung's shapes: a shorter rung writes the
   front.  ``ExpertLoad`` stays ``[E_total]``.  Nothing stands in for the
   absent experts or their exchange.
+- ``act``: the gate branch's activation, ``silu`` (default) or ``relu``
+  (``relu(Wg x) * Wu x``, "ReGLU"), forward and backward, with every expert
+  held and on every rung of a share's ladder.
+- input ``RouterX`` [B, T, d]: what the router reads where that is not what
+  the experts read (a router placed before attention scores the layer's
+  input; the experts get the post-attention rows).  Only the ``router``
+  scope reads it, and ``moe_ffn_grad`` returns its cotangent apart from
+  ``X``'s.  Without it the router reads ``X``, the lowering as it was.
 
 The layers annotate the expert weights with dist_spec ``("ep", ...)``.
 """
@@ -153,8 +163,11 @@ MOE_LOWERINGS_CTR = _monitor.REGISTRY.counter(
     "tracing, once per compile of a block that holds the op, nothing per "
     "step; held = the experts whose weights the op holds, score_func = the "
     "router's score, ladder = the static lengths the row buffer of a chip's "
-    "share chooses from, shortest first ('' where every expert is held)",
-    ("impl", "experts", "top_k", "held", "score_func", "ladder"))
+    "share chooses from, shortest first ('' where every expert is held), "
+    "act = the gate branch's activation, router_input = x where the router "
+    "reads the experts' rows and own where it has an input of its own",
+    ("impl", "experts", "top_k", "held", "score_func", "ladder", "act",
+     "router_input"))
 
 
 MOE_ROUTED_ROWS_CTR = _monitor.REGISTRY.counter(
@@ -243,19 +256,30 @@ def _grouped_matmul(dt, impl=None, tiling=None):
     return mm
 
 
-def _gate(g, u, dt):
-    """``silu(g) * u`` in float32, stored in ``dt``."""
-    return (jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
+_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _act_of(attrs):
+    act = attrs.get("act", "silu") or "silu"
+    if act not in _ACTS:
+        raise ValueError(f"moe_ffn act {act!r}")
+    return act
+
+
+def _gate(g, u, dt, act="silu"):
+    """``act(g) * u`` in float32, stored in ``dt``."""
+    return (_ACTS[act](g.astype(jnp.float32)) * u.astype(jnp.float32)
             ).astype(dt)
 
 
 def gated_experts(xs, wg, wu, wd, load, dt, impl=None, tiling=None,
                   gate=_gate):
-    """``Wd_e (silu(Wg_e x) * Wu_e x)`` for sorted rows ``xs`` [R, d] whose
+    """``Wd_e (act(Wg_e x) * Wu_e x)`` for sorted rows ``xs`` [R, d] whose
     expert is given by the run lengths ``load`` [E]; operands in ``dt``,
     accumulation and the gate's arithmetic in float32.  Returns ``(y, g,
     u)``: the result and the two projections a backward needs.  ``gate(g,
-    u, dt)``: the held path's, which passes over a rung's rows only."""
+    u, dt)``: :func:`_gate` (SiLU) unless the caller binds another ``act``, or
+    the held path's, which passes over a rung's rows only."""
     mm = _grouped_matmul(dt, impl, tiling)
     g = mm(xs, wg, load)
     u = mm(xs, wu, load)
@@ -401,18 +425,21 @@ def _front_copy(length, width, rows, dtype, tile):
         name="moe_front"))
 
 
-def _gate_front(ladder, held_rows, g, u, dt):
+def _gate_front(ladder, held_rows, g, u, dt, act="silu"):
     """``_gate`` over the rows of the rung that holds ``held_rows``, at the
     front of a buffer of the longest rung's length."""
     return _over_rungs(ladder, held_rows, lambda rows, g, u: _front(
-        _gate(g[:rows], u[:rows], dt), ladder[-1]), g, u)
+        _gate(g[:rows], u[:rows], dt, act), ladder[-1]), g, u)
 
 
-def _gate_backward(g, u, dh, dt):
+def _gate_backward(g, u, dh, dt, act="silu"):
     """``(dg, du)`` of ``_gate(g, u)`` given ``dh``: float32 arithmetic,
-    stored in ``dt``."""
+    stored in ``dt``.  ReLU: ``dg = dh u [g > 0]``, ``du = dh relu(g)``."""
     f32 = jnp.float32
     gf, uf, dhf = g.astype(f32), u.astype(f32), dh.astype(f32)
+    if act == "relu":
+        return (jnp.where(gf > 0, dhf * uf, 0.0).astype(dt),
+                (dhf * jax.nn.relu(gf)).astype(dt))
     sig = jax.nn.sigmoid(gf)
     return ((dhf * uf * sig * (1.0 + gf * (1.0 - sig))).astype(dt),
             (dhf * gf * sig).astype(dt))
@@ -425,17 +452,21 @@ def _moe_dtype(ctx, x):
 
 
 def _moe_ffn(ctx, ins, attrs):
-    """Dropless top-k mixture of gated-SiLU experts (the OLMoE / Mixtral
-    layer): ``Out = sum_{e in topk} p_e * Wd_e (silu(Wg_e x) * Wu_e x)``,
-    ``p = softmax(x Wr)`` over all experts, the ``k`` largest kept as they
-    are or renormalised to sum 1 (``norm_topk_prob``).  No capacity: every
-    token reaches its ``k`` experts whatever the load.
+    """Dropless top-k mixture of gated experts (the OLMoE / Mixtral layer):
+    ``Out = sum_{e in topk} p_e * Wd_e (act(Wg_e x) * Wu_e x)``, ``act`` SiLU
+    or (``act="relu"``) ReLU; ``p = softmax(r Wr)`` over all experts (or
+    ``sigmoid``, ``score_func``), ``r`` being ``x`` or the router's own
+    input ``RouterX``, the ``k`` largest kept as they are or renormalised to
+    sum 1 (``norm_topk_prob``; under softmax that is the softmax over the
+    ``k`` kept logits).  No capacity: every token reaches its ``k`` experts
+    whatever the load.
 
     Inputs: X [B,T,d], RouterW [d,E], GateW [E,d,f], UpW [E,d,f],
-    DownW [E,f,d].  Outputs: Out [B,T,d]; LbLoss [] = ``E * sum_e f_e P_e``
+    DownW [E,f,d]; optional RouterX [B,T,d].  Outputs: Out [B,T,d];
+    LbLoss [] = ``E * sum_e f_e P_e``
     (``f_e``: slots that chose ``e`` over tokens, so it sums to ``k``;
     ``P_e``: mean of ``p_e`` over tokens); ZLoss [] = mean over tokens of
-    ``logsumexp(x Wr)^2``; ExpertLoad [E] int32 rows per expert;
+    ``logsumexp(r Wr)^2``; ExpertLoad [E] int32 rows per expert;
     TopExperts [B,T,k] int32, each token's experts by falling ``p``;
     Saved: what ``moe_ffn_grad`` reuses (the sort order, the sorted rows, the
     two projections and the experts' output).
@@ -471,20 +502,24 @@ def _moe_ffn(ctx, ins, attrs):
                          f" of a router over {E}")
     S = B * T
     dt = _moe_dtype(ctx, x)
+    act = _act_of(attrs)
+    router_x = X(ins, "RouterX")
     ladder = () if n_held == E else held_ladder(S, k, n_held, E)
     if not getattr(ctx, "is_abstract", False):
         MOE_LOWERINGS_CTR.inc(
             impl=_experts_impl(dt), experts=str(E), top_k=str(k),
             held=str(n_held),
             score_func=attrs.get("score_func", "softmax") or "softmax",
-            ladder=".".join(map(str, ladder)))
+            ladder=".".join(map(str, ladder)), act=act,
+            router_input="x" if router_x is None else "own")
         if ladder:
             _TRACED_LADDERS[S * k, E, n_held] = ladder
     xt = x.reshape(S, d)
 
     with jax.named_scope("router"):
         (top_p, lb, z), (top_e, load) = _router_of(
-            attrs, k, X(ins, "SelectBias"))(xt, wr)
+            attrs, k, X(ins, "SelectBias"))(
+                xt if router_x is None else router_x.reshape(S, d), wr)
 
     if n_held == E:
         with jax.named_scope("dispatch"):
@@ -492,7 +527,9 @@ def _moe_ffn(ctx, ins, attrs):
             xs = jnp.take(xt.astype(dt), order // k, axis=0)
 
         with jax.named_scope("experts"):
-            y, g, u = gated_experts(xs, wg, wu, wd, load, dt)
+            y, g, u = gated_experts(
+                xs, wg, wu, wd, load, dt,
+                gate=functools.partial(_gate, act=act))
 
         with jax.named_scope("combine"):
             ys = jnp.take(y, place, axis=0).reshape(S, k, d)
@@ -517,7 +554,7 @@ def _moe_ffn(ctx, ins, attrs):
             y, g, u = gated_experts(
                 xs, wg, wu, wd, load_here, dt, tiling=_GMM_TILING_HELD,
                 gate=lambda g, u, dt: _gate_front(ladder, held_rows, g, u,
-                                                  dt))
+                                                  dt, act))
 
         def weighted_sum(rows, y, place, held, top_p):
             ys = jnp.take(y[:rows], jnp.minimum(place, rows - 1), axis=0)
@@ -540,6 +577,9 @@ def _moe_ffn_grad_maker(op, block, no_grad_set):
     g_inputs = {"X$" + s: op.input(s) for s in slots}
     if op.input("SelectBias"):
         g_inputs["X$SelectBias"] = op.input("SelectBias")
+    if op.input("RouterX"):
+        slots += ("RouterX",)
+        g_inputs["X$RouterX"] = op.input("RouterX")
     g_inputs["Saved"] = op.output("Saved")
     for s in ("Out", "LbLoss", "ZLoss"):
         g_inputs["OG$" + s] = grads(op.output(s))
@@ -558,7 +598,9 @@ register_op("moe_ffn", _moe_ffn, grad_maker=_moe_ffn_grad_maker)
 def _moe_ffn_grad(ctx, ins, attrs):
     """The backward of ``moe_ffn`` from what the forward saved: no second
     sort, no second gather of the rows, no second forward matmul.  The router
-    (a [S, d] x [d, E] product) is computed again for its vjp; each grouped
+    (a [S, d] x [d, E] product, of ``RouterX`` where the forward had one: its
+    cotangent then is ``IG$RouterX``'s and ``X`` gets the experts' alone) is
+    computed again for its vjp; each grouped
     matmul is transposed by ``jax.vjp`` at its saved operands, whose unused
     primal XLA removes; the transposes of the two row gathers are gathers
     (every row of ``x`` is read exactly ``k`` times).  An output whose
@@ -572,13 +614,16 @@ def _moe_ffn_grad(ctx, ins, attrs):
     S, R = B * T, B * T * k
     f32, dt = jnp.float32, xs.dtype
     xt = x.reshape(S, d)
+    router_x = X(ins, "X$RouterX")
+    act = _act_of(attrs)
     E, n_held = wr.shape[-1], weights[0].shape[0]
     mm = _grouped_matmul(dt, tiling=None if n_held == E else _GMM_TILING_HELD)
     offset = int(attrs.get("expert_offset", 0) or 0)
 
     with jax.named_scope("router"):
         (top_p, _, _), router_vjp, (top_e, load) = jax.vjp(
-            _router_of(attrs, k, X(ins, "X$SelectBias")), xt, wr,
+            _router_of(attrs, k, X(ins, "X$SelectBias")),
+            xt if router_x is None else router_x.reshape(S, d), wr,
             has_aux=True)
 
     def transposed(rows, w, cot):
@@ -596,12 +641,21 @@ def _moe_ffn_grad(ctx, ins, attrs):
                   ).astype(dt)
 
         with jax.named_scope("experts"):
-            dh, d_wd = transposed(_gate(g, u, dt), wd, dy)
-            gf, uf, dhf = g.astype(f32), u.astype(f32), dh.astype(f32)
-            sig = jax.nn.sigmoid(gf)
-            dxs_g, d_wg = transposed(
-                xs, wg, (dhf * uf * sig * (1.0 + gf * (1.0 - sig))).astype(dt))
-            dxs_u, d_wu = transposed(xs, wu, (dhf * gf * sig).astype(dt))
+            dh, d_wd = transposed(_gate(g, u, dt, act), wd, dy)
+            if act == "silu":
+                # _gate_backward's arithmetic in the order it has been
+                # lowered in since PR 27 (dg, its product, then du), so that
+                # the SiLU lowering stays what it was, to the byte
+                gf, uf, dhf = g.astype(f32), u.astype(f32), dh.astype(f32)
+                sig = jax.nn.sigmoid(gf)
+                dxs_g, d_wg = transposed(
+                    xs, wg,
+                    (dhf * uf * sig * (1.0 + gf * (1.0 - sig))).astype(dt))
+                dxs_u, d_wu = transposed(xs, wu, (dhf * gf * sig).astype(dt))
+            else:
+                dg, du = _gate_backward(g, u, dh, dt, act)
+                dxs_g, d_wg = transposed(xs, wg, dg)
+                dxs_u, d_wu = transposed(xs, wu, du)
 
         with jax.named_scope("dispatch"):
             dx = jnp.take(dxs_g + dxs_u, place, axis=0).reshape(S, k, d) \
@@ -641,12 +695,12 @@ def _moe_ffn_grad(ctx, ins, attrs):
             # from forward to backward that it cannot rematerialise (a
             # switch's result), at the price of rematerialising elsewhere
             h = _gate_front(ladder, held_rows,
-                            *jax.lax.optimization_barrier((g, u)), dt)
+                            *jax.lax.optimization_barrier((g, u)), dt, act)
             dh, d_wd = transposed(h, wd, dy)
             dg, du = _over_rungs(
                 ladder, held_rows, lambda rows, g, u, dh: tuple(
                     _front(a, full) for a in _gate_backward(
-                        g[:rows], u[:rows], dh[:rows], dt)), g, u, dh)
+                        g[:rows], u[:rows], dh[:rows], dt, act)), g, u, dh)
             dxs_g, d_wg = transposed(xs, wg, dg)
             dxs_u, d_wu = transposed(xs, wu, du)
 
@@ -671,6 +725,11 @@ def _moe_ffn_grad(ctx, ins, attrs):
         zero = jnp.zeros((), f32)
         dx_r, d_wr = router_vjp((d_top_p, zero if d_lb is None else d_lb,
                                  zero if d_z is None else d_z))
-    return {"IG$X": [(dx + dx_r).astype(x.dtype).reshape(B, T, d)],
-            "IG$RouterW": [d_wr.astype(wr.dtype)], "IG$GateW": [d_wg],
-            "IG$UpW": [d_wu], "IG$DownW": [d_wd]}
+    out = {"IG$RouterW": [d_wr.astype(wr.dtype)], "IG$GateW": [d_wg],
+           "IG$UpW": [d_wu], "IG$DownW": [d_wd]}
+    if router_x is None:
+        out["IG$X"] = [(dx + dx_r).astype(x.dtype).reshape(B, T, d)]
+    else:
+        out["IG$X"] = [dx.astype(x.dtype).reshape(B, T, d)]
+        out["IG$RouterX"] = [dx_r.astype(router_x.dtype).reshape(B, T, d)]
+    return out

@@ -1042,7 +1042,8 @@ _BWD_DEFAULTS = {2048: (1024, 512), 4096: (1024, 1024), 8192: (1024, 512),
 # samples/s against combined (1024, 512)'s 19.14, fused (512, 1024) 19.38,
 # fused (1024, 512) 19.35, and 133 MB less at the peak (the partials; the
 # window alone, tools/window_stalls.py, one seed; my chip runs, PR 37).
-# Other lengths at this width keep the baseline until they are swept.
+# Lengths other than 4096, 8192 and 16384 at this width keep the baseline
+# until they are swept.
 # 8192, on a v5e at [32, 8192, 128] bf16 over 4 KV heads
 # (tools/trinity_kernel_probe.py, PRs 32 and 37; backward ms = forward +
 # backward less the forward): forward (1024, 1024) 5.45 ms full and 3.57
@@ -1059,10 +1060,33 @@ _BWD_DEFAULTS = {2048: (1024, 512), 4096: (1024, 1024), 8192: (1024, 512),
 # full (1024, 512) 3.926; the split kernels 3.642.  The two best differ by
 # less than a seed does (0.3 %): the window keeps the smaller blocks, which
 # hug the band and ask for 20 MiB of VMEM, not 43.
-_FWD_DEFAULTS_D128 = {4096: (1024, 1024), 8192: (1024, 1024)}
+# 16384, on a v5e at [28, 16384, 128] bf16 over 4 KV heads, groups of 7
+# (tools/trinity_kernel_probe.py --seq 16384 --heads 28 --window 4096, PR 38;
+# ms, under the window of 4096 / full): forward (1024, 1024) 9.70 / 17.69,
+# (1024, 2048) 11.13 / 17.19, (512, 2048) 12.37 / 20.18, (512, 1024) 12.38 /
+# 22.82, (2048, 512) 16.45 / 27.62, (1024, 512) 18.13 / 33.91, and (2048,
+# 1024) runs out of VMEM; the forward table does not know the window, and
+# three window layers to one full make (1024, 1024) the better row by 8 %.
+# Backward: fused (1024, 1024) 19.26 / 32.30, (512, 1024) 21.27 / 33.80,
+# (1024, 512) 21.68 / 36.07, (512, 2048) 22.89 / 33.04, (512, 512) 23.80 /
+# 38.31, (256, 1024) 25.67 / 39.79; split (1024, 512) 31.36 / 50.43, (512,
+# 512) 34.90 / 57.35; "combined" would keep 15 GB of partials and IS the
+# split kernels.  A head's dQ accumulator and the (1024, 1024) blocks ask
+# for 52.7 MiB of VMEM (_fused_vmem_bytes), of the 96 the fused backward
+# may ask for.  At 16384 the band of 4096 is four blocks of 1024 wide, and
+# the larger blocks win under the window too (at 8192 the band of 2048 was
+# two).  In SmallThinker's step (the window alone, samples/s, one seed):
+# the rows as shipped 2.146; the window's backward at (512, 512) 2.108, at
+# (512, 1024) stalled, no reading; forward (1024, 2048) 2.132; split at (1024,
+# 512) for both kinds of layer, which is what no row at this length gave
+# (the 15 GB of partials are past _COMBINED_PARTIAL_BUDGET), 1.925.
+_FWD_DEFAULTS_D128 = {4096: (1024, 1024), 8192: (1024, 1024),
+                      16384: (1024, 1024)}
 _BWD_DEFAULTS_D128 = {4096: (1024, 1024, "fused"),
-                      8192: (1024, 1024, "fused")}
-_BWD_WINDOW_DEFAULTS_D128 = {8192: (512, 512, "fused")}
+                      8192: (1024, 1024, "fused"),
+                      16384: (1024, 1024, "fused")}
+_BWD_WINDOW_DEFAULTS_D128 = {8192: (512, 512, "fused"),
+                             16384: (1024, 1024, "fused")}
 # score width 128 < d_qk <= 256 (the values may be narrower: 192 over 128 is
 # latent attention's pair), on a v5e at causal [32, 8192, 192 | 128] bf16,
 # every head its own K/V (tools/joyai_kernel_probe.py, PRs 34 and 37):
@@ -1133,7 +1157,7 @@ def _statics(q, k, v, causal, sm_scale, block_q, block_k, block_q_bwd,
     # tables hold the end-to-end winners.  Wider heads double the tile VMEM
     # (2048-wide K/V at d=128 matches configs that failed to compile), so
     # 64<d<=128 and 128<d<=256 have tables of their own, filled only where
-    # swept (4096 and 8192; 8192, bf16 inputs only), keyed by the SCORE
+    # swept (4096, 8192 and 16384; 8192, bf16 inputs only), keyed by the SCORE
     # width d (Q's and K's; V's may differ and was swept at 128 under 192
     # only); d>256 and every other length keep the long-validated (512,
     # 1024) baseline
@@ -1213,7 +1237,7 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
     [b, h, Tq, Tk], [1, 1, Tq, Tk] or [Tq, Tk].
 
     Default blocks are per-sequence-length tables (below) at d_qk≤64 and,
-    for the lengths swept there, at 64<d_qk≤128 (4096, 8192) and
+    for the lengths swept there, at 64<d_qk≤128 (4096, 8192, 16384) and
     128<d_qk≤256 (8192, at 192 over 128, bf16 inputs), else (512, 1024) capped at the
     sequence lengths — measured on v5e: ahead
     of XLA's O(T²) attention from T≈1024, and the only runnable path
@@ -1229,7 +1253,8 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
     sums in HBM; falls back to split when the partials would exceed
     ``_COMBINED_PARTIAL_BUDGET``) or "split" (two-pass);
     default = what the backward table says for this length ("fused" at
-    8192 for 64 < d_qk ≤ 256), else module `_BWD_IMPL`.
+    8192 for 64 < d_qk ≤ 256, at 4096 and 16384 for d_qk ≤ 128), else
+    module `_BWD_IMPL`.
     :func:`flash_bwd_kernel` says which one a call gets.
     """
     (qc, kc, vc, bc), statics = _plan(

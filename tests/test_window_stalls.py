@@ -49,3 +49,47 @@ def test_a_stall_is_laid_to_what_overlapped_it():
 def test_too_few_steps_to_say():
     assert window_stalls.stalls(_dispatches()[:2], [], []) == \
         {"steps": 2, "stalls": []}
+
+
+def _tick(t, threads, **counters):
+    return (t, threads, dict({"cpu.steal": 0, "pgfault": 0}, **counters))
+
+
+def test_the_kernels_view_of_a_stall():
+    """``--threads 1``: a runtime thread that sleeps in the driver's ioctl
+    through the stall and not outside it is named, with the counters that ran
+    faster inside; ticks that did not fall are counted as such."""
+    busy = {("python3", "S", "futex_wait_queue"): 3,
+            ("tpu_worker", "S", "futex_wait_queue"): 1}
+    stuck = {("python3", "S", "futex_wait_queue"): 3,
+             ("tpu_worker", "D", "accel_ioctl"): 1}
+    inside = [1.3 < 0.5 * i < 3.6 for i in range(12)]
+    steal = [sum(4000 if on else 100 for on in inside[:i + 1])
+             for i in range(12)]
+    ticks = [_tick(0.5 * i, stuck if inside[i] else busy,
+                   **{"cpu.steal": steal[i]}) for i in range(12)]
+    got = window_stalls.stalls(
+        _dispatches(4, 2.0) + [("executor.throttle_wait", 1.25, 3.19, {})],
+        [], [], ticks=ticks)
+    kernel = got["stalls"][0]["kernel"]
+    assert kernel["ticks_inside"] == 5 and kernel["ticks_outside"] == 7
+    assert ["tpu_worker D accel_ioctl", 1.0, 0.0] in \
+        kernel["threads_inside_vs_outside"]
+    assert kernel["threads_inside_not_in_futex"] == \
+        [["tpu_worker D accel_ioctl", 1.0, 0.0]]
+    assert [r[0] for r in kernel["counters_per_s_inside_vs_rest"]] == \
+        ["cpu.steal"]
+    none = window_stalls.kernel_over([t for t in ticks
+                                      if not 1.3 < t[0] < 3.6], 1.2, 3.5)
+    assert none == {"ticks_inside": 0, "ticks_outside": 7}
+
+
+def test_one_look_at_proc_names_this_thread():
+    t, threads, counters = window_stalls.kernel_tick()
+    assert sum(threads.values()) >= 1 and counters["cpu.user"] >= 0
+    assert all(len(key) == 3 for key in threads)
+
+
+def test_device_gaps_need_a_trace():
+    assert window_stalls.device_gaps(None) is None
+    assert window_stalls.device_gaps({"devices": {}, "path": "x"}) is None

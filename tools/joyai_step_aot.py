@@ -52,7 +52,8 @@ class _NoRecompute:
 #: cell -> (configuration, which is also its adapter module; traffic mix)
 CELLS = {"joyai": ("joyai_llm_flash", "lm_mtp_s8192"),
          "trinity": ("trinity_mini", "lm_s8192"),
-         "olmoe": ("olmoe_1b_7b", "lm_s4096")}
+         "olmoe": ("olmoe_1b_7b", "lm_s4096"),
+         "smallthinker": ("smallthinker_21b_a3b", "lm_s16384")}
 
 
 def reads_after_update(text):
@@ -133,11 +134,14 @@ def main():
     ap.add_argument("--layers", type=int, default=0)
     ap.add_argument("--seq", type=int, default=0)
     ap.add_argument("--dump", default="")
+    ap.add_argument("--lowered", default="", help="write the step's lowered "
+                    "StableHLO text there, locations stripped, and compile "
+                    "nothing: what two commits' steps are diffed by")
     ap.add_argument("--cell", default="joyai", choices=sorted(CELLS),
                     help="the other cell that runs moe_ffn's held path: "
                     "trinity, or the third that runs the flash kernels: "
                     "olmoe (their steps have no recomputation: leave "
-                    "--recompute out)")
+                    "--recompute out, as for smallthinker)")
     args = ap.parse_args()
     if args.run:
         return run_on_chip(args)
@@ -181,6 +185,13 @@ def main():
         a.shape, a.dtype, sharding=one), step_args)
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
+    if args.lowered:
+        text = re.sub(r"loc\(.*?\)", "", cb.jitted.lower(*shapes).as_text())
+        with open(args.lowered, "w") as f:
+            f.write(text)
+        print(json.dumps({"cell": args.cell, "lowered": args.lowered,
+                          "bytes": len(text)}))
+        return 0
     try:
         compiled = cb.jitted.lower(*shapes).compile()
     except Exception as e:                       # noqa: BLE001

@@ -1,0 +1,145 @@
+"""The readings that set the SmallThinker cell's loss tolerances, on the chip
+at published widths and on the timed sequence (the weights from the traffic's
+``weights_seed``, the ids from ``--seed``): how far the reference moves when
+it is computed in the nearest precision below the one a check states (as
+``tools/trinity_tolerance_probe.py`` reads them for Trinity's cell), and the
+cell's own decision on each: ``control``, not correct and by which limits.
+
+* the float32 forward check: the reference with every parameter, and so
+  every activation, in bf16 in the program's place, against the float32
+  reference at ``highest``: loss, share of tokens whose 6 of 64 experts
+  differ in some layer, final-norm output over the other tokens;
+* the AMP first-loss check: the same bf16 reference with its weights rounded
+  through float8_e4m3 first: loss and final-norm output over all tokens;
+* the AMP first-gradient check: ``jax.grad`` of both of those against
+  ``jax.grad`` of the float32 reference, leaf by leaf, as
+  ``models/smallthinker_21b_a3b.py:gradient_difference`` compares the step's
+  (each kind as ``[its leaves together, its worst leaf, that leaf's name]``).
+
+    chiprun -- python3 tools/smallthinker_tolerance_probe.py --seeds 7,11
+"""
+
+import argparse
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--seeds", default="7")
+    args = ap.parse_args()
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from benchmark import harness
+    from benchmark.models import (_train, olmoe_1b_7b as olmoe,
+                                  smallthinker_21b_a3b as adapter)
+    from benchmark.reference import smallthinker_21b_a3b as reference
+    on_chip = jax.default_backend() == "tpu"
+    config = harness.load_json("benchmark/configs/smallthinker_21b_a3b.json")
+    traffic = harness.load_traffic("lm_s16384")
+    if not on_chip:                      # a rehearsal of the path, no reading
+        sys.path.insert(0, os.path.join(ROOT, "tests", "benchmark"))
+        import test_smallthinker_cell
+        config, toy = test_smallthinker_cell.toy_smallthinker()
+        traffic.update(seq_len=toy["seq_len"],
+                       reference_q_block=toy["reference_q_block"])
+    tol = config["loss_tolerance"]
+    # the weights alone: the startup program of the forward-only model
+    from paddle_tpu.framework import (Program, Scope, program_guard,
+                                      scope_guard)
+    from paddle_tpu.models import transformer as T
+    cfg = adapter.smallthinker_config(config)
+    q_block = traffic["reference_q_block"]
+    for seed in map(int, args.seeds.split(",")):
+        scope, main_p, startup = Scope(), Program(), Program()
+        with scope_guard(scope), program_guard(main_p, startup):
+            T.build_smallthinker_pretrain(cfg, traffic["seq_len"],
+                                          is_test=True)
+            _train.executor(on_chip).run(
+                startup, scope=scope, seed=harness.exe_seed(
+                    traffic["weights_seed"]))
+        feed = adapter.make_batch(_train.rng_of(seed), cfg, 1,
+                                  traffic["seq_len"])
+        params = adapter.reference_params(
+            lambda n: jnp.asarray(scope.find_var(n), jnp.float32), cfg)
+        for name in list(scope.local_var_names()):   # the reference's stay
+            scope.erase(name)
+
+        def cast(through=None):
+            def one(a):
+                a = a if through is None else a.astype(through)
+                return a.astype(jnp.bfloat16)
+            return jax.tree_util.tree_map(one, params)
+
+        def against_float32(p):
+            s = reference.sequence_sums(
+                p, jnp.asarray(feed["src_ids"]),
+                jnp.asarray(feed["lm_label"]),
+                **adapter.reference_kw(cfg, q_block))
+            got = float(reference.loss_of_sums(s)["loss"])
+            top = np.asarray(s["top_e"])
+            want, ref_top, per_token = adapter.reference_loss(
+                reference, params, feed, cfg,
+                hidden=np.asarray(s["hidden"], np.float32), q_block=q_block)
+            differ = olmoe.tokens_that_differ(top, ref_top)
+            return {"loss_rel": _train.rel_err(got, want),
+                    "top_k_differ_share": float(differ.mean()),
+                    "hidden_rel_others": olmoe.hidden_difference(per_token,
+                                                                 ~differ),
+                    "hidden_rel_all": olmoe.hidden_difference(per_token)}
+
+        _, g_ref = adapter.reference_gradient(reference, params, feed, cfg,
+                                              q_block)
+
+        def gradient_against_float32(p):
+            _, g = adapter.reference_gradient(reference, p, feed, cfg,
+                                              q_block)
+            off = adapter.gradient_difference(g_ref, g)
+            return {"gradient": {k: v if k == "all" else list(v)
+                                 for k, v in off.items()}}
+
+        out = {"device": jax.devices()[0].device_kind, "seed": seed}
+        for name, through in (("bf16", None),
+                              ("fp8_weights_bf16", jnp.float8_e4m3fn)):
+            r = against_float32(cast(through))
+            r.update(gradient_against_float32(cast(through)))
+            out[name] = r
+        # the cell's own decision, with the control in the program's place:
+        # the float32 forward check reads the bf16 reference, the AMP checks
+        # the bf16 reference over float8 weights
+        f, a = out["bf16"], out["fp8_weights_bf16"]
+        failed = {
+            "relative": f["loss_rel"] > tol["relative"],
+            "top_k_differ_share":
+                f["top_k_differ_share"] > tol["top_k_differ_share"],
+            "hidden_relative": f["hidden_rel_others"] > tol["hidden_relative"],
+            "first_training_loss_relative":
+                a["loss_rel"] > tol["first_training_loss_relative"],
+            "first_hidden_relative":
+                a["hidden_rel_all"] > tol["first_hidden_relative"]}
+        for kind in ("rest", "experts", "router"):
+            failed[f"first_gradient_{kind}_relative"] = \
+                a["gradient"][kind][adapter.DECIDES[kind]] > \
+                tol[f"first_gradient_{kind}_relative"]
+        failed["first_gradient_all_relative"] = \
+            a["gradient"]["all"] > tol["first_gradient_all_relative"]
+        out["control"] = {"correct": not any(failed.values()),
+                          "not_correct_by": sorted(k for k, v in
+                                                   failed.items() if v)}
+        print(json.dumps(out), flush=True)
+        os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+        with open(os.path.join(ROOT, "chiprun_out",
+                               "smallthinker_tolerance_probe.jsonl"),
+                  "a") as fh:
+            fh.write(json.dumps(out) + "\n")
+        del params, g_ref
+
+
+if __name__ == "__main__":
+    main()

@@ -34,6 +34,10 @@ def main():
     ap.add_argument("--impls", default="fused,combined,split",
                     help="the backward kernels to time at each block choice")
     ap.add_argument("--iters", type=int, default=10)
+    ap.add_argument("--oracle_heads", type=int, default=0,
+                    help="query heads of a group the oracle takes at a time "
+                    "(default the whole group; at 16384 one head's dense "
+                    "scores are 1 GB)")
     args = ap.parse_args()
     import importlib
     import jax
@@ -63,8 +67,10 @@ def main():
 
     def oracle(window):
         """o, dq, dk, dv of ``mha_reference``, a group at a time (the dense
-        scores of 8 heads at 8192 are 2 GB)."""
+        scores of 8 heads at 8192 are 2 GB) or ``--oracle_heads`` of a
+        group at a time, their dK and dV added up."""
         group = args.heads // args.kv_heads
+        some = args.oracle_heads or group
 
         @jax.jit
         def one(qg, kg, vg, dog):
@@ -74,9 +80,16 @@ def main():
                     q, k, v, causal=True, window=window), *f32)
                 return (o,) + back(dog.astype(jnp.float32))
 
-        parts = [one(q[:, i * group:(i + 1) * group], k[:, i:i + 1],
-                     v[:, i:i + 1], do[:, i * group:(i + 1) * group])
-                 for i in range(args.kv_heads)]
+        def of_group(i):
+            end = (i + 1) * group
+            subs = [one(q[:, h:min(h + some, end)], k[:, i:i + 1],
+                        v[:, i:i + 1], do[:, h:min(h + some, end)])
+                    for h in range(i * group, end, some)]
+            o, dq, dk, dv = zip(*subs)
+            return (jnp.concatenate(o, axis=1), jnp.concatenate(dq, axis=1),
+                    sum(dk), sum(dv))
+
+        parts = [of_group(i) for i in range(args.kv_heads)]
         return [jnp.concatenate(x, axis=1) for x in zip(*parts)]
 
     def off(got, want):
