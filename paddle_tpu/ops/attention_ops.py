@@ -29,9 +29,12 @@ FLASH_LOWERINGS_CTR = _monitor.REGISTRY.counter(
     "causal half or no mask), the query heads to a KV head, the "
     "implementation (the Pallas kernels or the blockwise jax fallback) and "
     "the two widths (widths = d_qk/d_v, e.g. 128/128 or latent attention's "
-    "192/128) — counted while tracing, once per compile of a block that "
-    "holds the op, nothing per step",
-    ("window", "kv_groups", "impl", "widths"))
+    "192/128) and the form the forward kernel writes lse in at these shapes "
+    "(lse = row: [bh, 1, Tq], what the backward reads, wherever a query "
+    "block fills lanes; lanes: the [bh, Tq, 128] broadcast of which one "
+    "lane is kept, at ragged toy blocks) — counted while tracing, once per "
+    "compile of a block that holds the op, nothing per step",
+    ("window", "kv_groups", "impl", "widths", "lse"))
 
 FLASH_GRAD_LOWERINGS_CTR = _monitor.REGISTRY.counter(
     "paddle_tpu_flash_grad_lowerings_total",
@@ -52,27 +55,30 @@ FLASH_BWD_KERNEL_CTR = _monitor.REGISTRY.counter(
     "step", ("kernel", "window", "widths"))
 
 
-def _flash_call(ctx, attrs, q, k, v, counter, pallas):
+def _flash_call(ctx, attrs, q, k, v, counter, pallas, more_labels=None):
     """What the op and its grad op share: ``(window or None, the attributes
     as keyword arguments of the kernel's entry points)``, and one count of
     the lowering in ``counter`` (``pallas``: whether a TPU would run the
-    kernels here)."""
+    kernels here; ``more_labels``: a function of those keyword arguments
+    that gives the counter's further labels)."""
     from ..device import on_tpu
     bq, bk = attrs.get("block_q"), attrs.get("block_k")
     window = int(attrs.get("window") or 0) or None
     if window is not None and window >= max(q.shape[2], k.shape[2]):
         window = None
-    if not getattr(ctx, "is_abstract", False):
-        counter.inc(window="none" if window is None else str(window),
-                    kv_groups=str(q.shape[1] // k.shape[1]),
-                    impl="pallas" if pallas and on_tpu() else "jax",
-                    widths=f"{q.shape[3]}/{v.shape[3]}")
-    return window, dict(
+    kw = dict(
         causal=bool(attrs.get("causal", False)),
         sm_scale=attrs.get("sm_scale") or None,
         block_q=int(bq) if bq else None,  # None → kernel's tuned default
         block_k=int(bk) if bk else None,
         bwd_impl=attrs.get("bwd_impl") or None, window=window)
+    if not getattr(ctx, "is_abstract", False):
+        counter.inc(window="none" if window is None else str(window),
+                    kv_groups=str(q.shape[1] // k.shape[1]),
+                    impl="pallas" if pallas and on_tpu() else "jax",
+                    widths=f"{q.shape[3]}/{v.shape[3]}",
+                    **(more_labels(kw) if more_labels else {}))
+    return window, kw
 
 
 def _window_scope(window):
@@ -94,15 +100,18 @@ def _flash_attention(ctx, ins, attrs):
     query's log-sum-exp over its visible keys, which ``flash_attention_grad``
     rebuilds the probabilities from (an op without that slot still runs:
     the executor binds the slots an op names)."""
-    from ..pallas.flash_attention import flash_attention_fwd
+    from ..pallas.flash_attention import (flash_attention_fwd,
+                                          flash_lse_layout)
     q, k, v = X(ins, "Q"), X(ins, "K"), X(ins, "V")
-    window, kw = _flash_call(ctx, attrs, q, k, v, FLASH_LOWERINGS_CTR,
-                             pallas=True)
+    window, kw = _flash_call(
+        ctx, attrs, q, k, v, FLASH_LOWERINGS_CTR, pallas=True,
+        more_labels=lambda kw: {"lse": flash_lse_layout(q, k, v, **kw)})
     with _window_scope(window):
         out, lse = flash_attention_fwd(q, k, v, X(ins, "Bias"), **kw)
-        # Lse leaves with Out: without the barrier XLA:TPU sinks the slice
-        # that makes the [b, h, Tq] rows into the backward and keeps the
-        # kernel's lane-broadcast [b * h, Tq, 128] buffer until then
+        # Lse leaves with Out: where the kernel still writes the lane-
+        # broadcast [b * h, Tq, 128] form (ragged blocks), XLA:TPU without
+        # the barrier sinks the slice that makes the [b, h, Tq] rows into
+        # the backward and keeps that buffer until then
         out, lse = jax.lax.optimization_barrier((out, lse))
     return {"Out": [out], "Lse": [lse]}
 

@@ -194,22 +194,48 @@ def _k_spec(block_q, block_k, d, window, group, offset, nk):
             _live_k(i, j, window, block_q, block_k, offset, nk), 0))
 
 
+# A v5e TensorCore's VMEM and the share of it one kernel may ask for.  Mosaic
+# scopes a kernel to 16 MiB unless the call says otherwise (vmem_limit_bytes);
+# the forward says what _fwd_vmem_bytes reckons from its shapes and the fused
+# backward what _fused_vmem_bytes does, and where the latter passes the share
+# (a head's dQ accumulator grows with Tq · d_qk) the split kernels run instead.
+_VMEM_BYTES = 128 << 20
+_FUSED_VMEM_SHARE = 0.75
+
+
+def _lse_rows(block_q, tqp):
+    """Whether the forward writes ``lse`` as ``[bh, 1, Tq]`` rows, lane-dense
+    along ``Tq`` (the form the backward's dK/dV pass reads), or as the
+    ``[bh, Tq, lanes]`` columns of its running-max scratch: rows wherever a
+    query block fills whole lanes, or is the whole padded length and fills
+    whole sublanes (both are shapes Mosaic transposes a (block_q, 128) tile
+    at), columns at the ragged toy blocks that are left."""
+    return block_q % _LANE == 0 or (block_q == tqp and block_q % 8 == 0)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
-                acc_sc, m_sc, l_sc, *, sm_scale, causal, block_q, block_k,
-                tk_real, offset, pads, window=None):
+                acc_sc, m_sc, l_sc, q_sc, *, sm_scale, causal, block_q,
+                block_k, tk_real, offset, pads, window=None, lse_rows=False):
     """One (bh, iq, ik) grid step of online-softmax attention.
 
-    Grid iterates ik innermost (sequentially on TPU), so the VMEM scratch
-    accumulators carry the running max/denominator across k-blocks.
-
-    At d=64 the per-tile VPU work rivals the MXU time (the round-5
-    skeleton microbench measured the r4 kernel at 1.76x its matmul-only
-    skeleton, LONGCTX_ABLATION.md r5), so the tile-wide extras are
-    elided wherever they are statically or block-wise unnecessary:
-    sm_scale is folded into q (a [bq,d] row multiply, not [bq,bk]);
-    padding masks vanish when the sequence divides the blocks (``pads``
-    is a trace-time constant); causal masks run only on DIAGONAL blocks —
-    fully-live blocks below the diagonal take the mask-free path.
+    The grid walks ik innermost (sequentially on TPU), so the VMEM scratch
+    carries a query block's state across its key blocks: the float32 output
+    accumulator, the running max and denominator (a (block_q, 1) column
+    each: one live lane's worth of loads, stores and rescaling a step, where
+    the 128-lane broadcast of them cost 3.5 to 6.5 % of the kernel), and the
+    query block itself, cast to float32 and scaled ONCE, at its first key
+    step (sm_scale folds into q: a [bq, d] multiply, never a [bq, bk] one).  What a step does is
+    its two products and the tile-wide softmax work, and nothing else where
+    it can be told apart statically or by block: padding masks vanish when
+    the sequence divides the blocks (``pads`` is a trace-time constant),
+    the causal and window masks run only on the blocks an edge crosses, and
+    blocks wholly outside the band are skipped (:func:`_block_dispatch`).
+    At the last key step the block's output is normalised and ``lse = m +
+    log l`` leaves as a (1, block_q) row of ``[bh, 1, Tq]`` (``lse_rows``:
+    the column transposed on the XLU, an exact move, one row stored) or, at
+    the ragged blocks :func:`_lse_rows` leaves, as the lane-broadcast
+    column.  What is left in it (float32 operands, the 192-wide
+    contraction's two MXU passes): PERF.md section 7, row 18.
     """
     import jax.lax as lax
     from jax.experimental import pallas as pl
@@ -222,12 +248,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
         m_sc[...] = jnp.full_like(m_sc, NEG_INF)
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
+        q_sc[...] = q_ref[0].astype(jnp.float32) * sm_scale
 
     def _compute(masked):
-        q = q_ref[0].astype(jnp.float32) * sm_scale
         k = k_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q_sc[...], k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         if b_ref is not None:
             s = s + b_ref[0].astype(jnp.float32)
@@ -235,8 +261,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
             s = jnp.where(_pos_mask(iq, ik, block_q, block_k, causal,
                                     offset, None, tk_real, window=window),
                           s, NEG_INF)
-        m_prev = m_sc[:, :1]                         # (bq, 1)
-        l_prev = l_sc[:, :1]
+        m_prev = m_sc[...]                         # (bq, 1)
+        l_prev = l_sc[...]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)
@@ -245,19 +271,45 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
         acc_sc[...] = acc_sc[...] * alpha + jax.lax.dot_general(
             p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
-        l_sc[...] = jnp.broadcast_to(l_new, l_sc.shape)
+        m_sc[...] = m_new
+        l_sc[...] = l_new
 
     _block_dispatch(causal, pads, iq, ik, block_q, block_k, offset,
                     _compute, window=window)
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        l = l_sc[:, :1]
+        l = l_sc[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)          # fully-masked rows
         o_ref[0] = (acc_sc[...] / l_safe).astype(o_ref.dtype)
-        lse = m_sc[:, :1] + jnp.log(l_safe)
-        lse_ref[0] = jnp.broadcast_to(lse, lse_ref[0].shape)
+        lse = m_sc[...] + jnp.log(l_safe)
+        if lse_rows:
+            lse_ref[0] = jnp.broadcast_to(lse, (block_q, _LANE)).T[:1]
+        else:
+            lse_ref[0] = jnp.broadcast_to(lse, lse_ref[0].shape)
+
+
+def _fwd_vmem_bytes(d, d_v, block_q, block_k, itemsize, bias_itemsize=0):
+    """The VMEM the forward asks for, from its shapes, as
+    :func:`_fused_vmem_bytes` reckons the fused backward's: the operand and
+    output blocks (double-buffered; ``lse``'s at the lane-broadcast form's
+    size, the larger), the scratch (accumulator, float32 query block, max
+    and denominator: a (block_q, 1) float32 column takes whole 128-lane
+    tiles of VMEM all the same), the float32 tiles (s, p, the mask and what the
+    reductions hold) and K's and V's float32 copies; a quarter more for
+    what XLA fuses into the call inside a step.  Never under Mosaic's
+    default 16 MiB (small blocks compiled into it before the call asked)
+    and never over the share of a core's VMEM a kernel may ask for.  [32,
+    8192, 192 | 128] bf16 asks 28.8 MiB at (1024, 1024), 51.9 at (1024,
+    2048) and 54.4 at (2048, 1024), where 12, 20 and 24 are the least that
+    compile alone (tools/joyai_kernel_probe.py --aot --forward)."""
+    wide = d + d_v
+    blocks = 2 * (itemsize * wide * (block_q + block_k)
+                  + bias_itemsize * block_q * block_k + block_q * _LANE * 4)
+    scratch = block_q * (wide + 2 * _LANE) * 4
+    tiles = 4 * block_q * block_k * 4 + block_k * wide * 4
+    return min(max(int(1.25 * (blocks + scratch + tiles)), 16 << 20),
+               int(_FUSED_VMEM_SHARE * _VMEM_BYTES))
 
 
 def _flash_fwd_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
@@ -299,38 +351,51 @@ def _flash_fwd_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
             (lambda b, i, j: (0, i, j))))
         args.append(bias)
 
+    lse_rows = _lse_rows(block_q, tqp)
+
     def kernel(q_ref, k_ref, v_ref, *rest):
-        # rest = ([b_ref,] o_ref, lse_ref, acc, m, l) depending on bias
+        # rest = ([b_ref,] o_ref, lse_ref, acc, m, l, q32) depending on bias
         b_ref = rest[0] if bias is not None else None
-        o_ref, lse_ref, acc, m, l = rest[-5:]
-        _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
-                    acc, m, l, sm_scale=sm_scale, causal=causal,
+        _fwd_kernel(q_ref, k_ref, v_ref, b_ref, *rest[-6:],
+                    sm_scale=sm_scale, causal=causal,
                     block_q=block_q, block_k=block_k,
                     tk_real=tk_real, offset=offset,
-                    pads=tkp != tk_real, window=window)
+                    pads=tkp != tk_real, window=window, lse_rows=lse_rows)
 
-    lane = min(_LANE, block_k)
+    if lse_rows:
+        lse_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
+        lse_shape = (bh, 1, tqp)
+    else:
+        lane = min(_LANE, block_k)
+        lse_spec = pl.BlockSpec((1, block_q, lane), lambda b, i, j: (b, i, 0))
+        lse_shape = (bh, tqp, lane)
     o, lse = pl.pallas_call(
         kernel,
         grid=(bh, nq, nk),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, lane), lambda b, i, j: (b, i, 0)),
+            lse_spec,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, tqp, d_v), q.dtype),
-            jax.ShapeDtypeStruct((bh, tqp, lane), jnp.float32),
+            jax.ShapeDtypeStruct(lse_shape, jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d_v), jnp.float32),
-            pltpu.VMEM((block_q, lane), jnp.float32),
-            pltpu.VMEM((block_q, lane), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, d), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_fwd_vmem_bytes(
+                d, d_v, block_q, block_k, q.dtype.itemsize,
+                0 if bias is None else bias.dtype.itemsize)),
         interpret=interpret,
         name="flash_fwd",
     )(*args)
-    return o[:, :tq], lse[:, :tq, 0]
+    lse = lse.reshape(bh, tqp) if lse_rows else lse[..., 0]
+    return o[:, :tq], lse[:, :tq]
 
 
 # ---------------------------------------------------------------------------
@@ -629,15 +694,6 @@ _BWD_IMPL = "combined"
 # O(bh·T·d) memory wins by not OOMing; fall back automatically.  (The 8k
 # shapes at d > 64 are past it, 2.15 to 2.68 GB a layer: they run "fused".)
 _COMBINED_PARTIAL_BUDGET = 2 << 30
-
-# A v5e TensorCore's VMEM and the share of it the fused backward may ask for.
-# Mosaic scopes a kernel to 16 MiB unless the call says otherwise
-# (vmem_limit_bytes); the fused backward says what _fused_vmem_bytes reckons
-# from its shapes, and where that passes the share (a head's dQ accumulator
-# grows with Tq · d_qk) the split kernels run instead.
-_VMEM_BYTES = 128 << 20
-_FUSED_VMEM_SHARE = 0.75
-
 
 def _fused_vmem_bytes(tq, d, d_v, block_q, block_k, itemsize):
     """The VMEM the fused backward asks for, from its shapes: the head's dQ
@@ -1327,3 +1383,15 @@ def flash_bwd_kernel(q, k, v, bias=None, causal=False, sm_scale=None,
             (x.shape[0] * x.shape[1],) + tuple(x.shape[2:]), x.dtype)
     return _bwd_kernel_name(collapsed(q), collapsed(k), collapsed(v),
                             *(bwd_blocks or (block_q, block_k)), bwd_impl)
+
+
+def flash_lse_layout(q, k, v, causal=False, sm_scale=None, block_q=None,
+                     block_k=None, interpret=False, window=None, **_):
+    """How the forward kernel writes ``lse`` for these arguments, from their
+    shapes alone (:func:`_lse_rows` at the blocks the tables give):
+    ``"row"``, ``[bh, 1, Tq]`` as the backward reads it, or ``"lanes"``, the
+    lane-broadcast ``[bh, Tq, 128]`` columns of which one lane is kept."""
+    block_q = _statics(q, k, v, causal, sm_scale, block_q, block_k, None,
+                       None, None, interpret, window)[2]
+    tq = q.shape[2]
+    return "row" if _lse_rows(block_q, tq + (-tq) % block_q) else "lanes"

@@ -8,6 +8,7 @@ takes every option and leaves all-reduces inside async collective fusions.
 The described-topology compile lives in this file alone and loads the TPU
 library inside a fixture (one process may hold it)."""
 
+import contextlib
 import os
 import sys
 import types
@@ -227,13 +228,27 @@ def topo():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
+@contextlib.contextmanager
+def _no_compile_cache():
+    """Compile for the described chips without reading or writing the
+    persistent cache (an executable of a topology is nobody's to reuse)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
 def test_tpu_compiler_takes_the_options_and_fuses_all_reduces(topo):
     """A 2-layer BERT at widths whose weight gradients pass the combiner's
     threshold, data parallel over the four described chips, compiled with
     what ``dp_overlap_options`` gives a TPU mesh: the compiler knows every
     option, and weight-gradient all-reduces sit inside async collective
     fusions."""
-    from jax.experimental.compilation_cache import compilation_cache as cc
     cfg = T.BertConfig(vocab_size=512, d_model=768, n_layer=2, n_head=12,
                        d_inner=1024, max_pos=32, dropout=0.0)
     exe, scope, main, loss = _bert(cfg)
@@ -248,18 +263,12 @@ def test_tpu_compiler_takes_the_options_and_fuses_all_reduces(topo):
     shapes = jax.tree.map(
         lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
         args, tuple(cb.in_shardings))
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    try:
+    with _no_compile_cache():
         lowered = cb.jitted.lower(*shapes)
         assert dict(lowered._lowering._compiler_options_kvs) == \
             E._DP_OVERLAP_OPTIONS
         _, asked = dp_arith_check.all_reduce_schedule(
             lowered.compile().as_text())
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        cc.reset_cache()
     fused = [r for r in asked if r[2].startswith("fused")]
     assert fused and all(mb >= 1.0 for _, _, _, mb, _ in fused), asked
 
@@ -276,7 +285,6 @@ def test_tpu_compiler_takes_the_held_paths_ladder(
     buffers through the ``moe_front`` kernel (the compiler takes it at both
     widths), and the grouped matmuls are lowered once, not once a rung."""
     import jax.numpy as jnp
-    from jax.experimental.compilation_cache import compilation_cache as cc
     from jax.sharding import SingleDeviceSharding
     from paddle_tpu import device
     from paddle_tpu.ops import moe_ops
@@ -301,14 +309,8 @@ def test_tpu_compiler_takes_the_held_paths_ladder(
         ((1, S, d), jnp.bfloat16), ((1, S, d), jnp.bfloat16),
         ((d, router_outputs), jnp.float32), ((held, d, width), jnp.float32),
         ((held, d, width), jnp.float32), ((held, width, d), jnp.float32))]
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    try:
+    with _no_compile_cache():
         text = jax.jit(step).lower(*shapes).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        cc.reset_cache()
     import re
     conds = re.findall(r"branch_computations=\{([^}]*)\}", text)
     assert [c.count("%") for c in conds] == [len(ladder)] * 7   # 3 + 4
@@ -316,3 +318,54 @@ def test_tpu_compiler_takes_the_held_paths_ladder(
     assert len(re.findall(r"%moe_front[\w.]* = ", text)) == \
         6 * (len(ladder) - 1)
     assert len(re.findall(r"%(?:jvp_jit_)?t?gmm[\w.]* = ", text)) == 9
+
+
+#: name -> (heads, KV heads, T, d_qk, d_v, window, forward blocks or None for
+#: the tables' row): the four flash cells' forward calls, and one 2048-wide
+#: row that Mosaic's default 16 MiB of scoped VMEM refuses (24 is the least)
+FORWARD_CASES = {
+    "joyai": (32, 32, 8192, 192, 128, None, None),
+    "trinity_full": (32, 4, 8192, 128, 128, None, None),
+    "trinity_window": (32, 4, 8192, 128, 128, 2048, None),
+    "olmoe": (64, 64, 4096, 128, 128, None, None),
+    "smallthinker_window": (28, 4, 16384, 128, 128, 4096, None),
+    "joyai_2048_wide": (32, 32, 8192, 192, 128, None, (2048, 1024)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FORWARD_CASES))
+def test_tpu_compiler_takes_the_flash_forward_with_lse_rows(
+        topo, monkeypatch, case):
+    """``flash_attention_fwd`` at the cells' real shapes, bf16, compiled for
+    one described chip: ``lse`` leaves the kernel as ``[bh, 1, Tq]`` float32
+    rows (Mosaic takes the in-kernel transposition), no ``[bh, Tq, 128]``
+    float32 buffer is left in the module, and the call asks for the VMEM its
+    blocks need — which is what lets a 2048-wide row compile at all."""
+    import importlib
+    import re
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    F = importlib.import_module("paddle_tpu.pallas.flash_attention")
+    monkeypatch.setattr(F, "on_tpu", lambda: True)
+    h, hk, t, d_qk, d_v, window, blocks = FORWARD_CASES[case]
+    asked = []
+    reckon = F._fwd_vmem_bytes
+    monkeypatch.setattr(F, "_fwd_vmem_bytes", lambda *a, **kw: asked.append(
+        reckon(*a, **kw)) or asked[-1])
+    one = SingleDeviceSharding(topo.devices[0])
+    q, k, v = (jax.ShapeDtypeStruct((1, n, t, w), jnp.bfloat16, sharding=one)
+               for n, w in ((h, d_qk), (hk, d_qk), (hk, d_v)))
+    kw = dict(causal=True, window=window)
+    if blocks:
+        kw.update(block_q=blocks[0], block_k=blocks[1])
+    assert F.flash_lse_layout(q, k, v, **kw) == "row"
+    with _no_compile_cache():
+        compiled = jax.jit(lambda q, k, v: F.flash_attention_fwd(
+            q, k, v, **kw)).lower(q, k, v).compile()
+    text = compiled.as_text()
+    call, = [line for line in text.split("\n")
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert f"f32[{h},1,{t}]" in call.split(" custom-call(")[0]
+    assert not re.search(rf"f32\[{h},{t},128\]", text)
+    assert asked == [reckon(d_qk, d_v, *(blocks or (1024, 1024)), 2)]
+    assert 16 << 20 < asked[0] <= F._FUSED_VMEM_SHARE * F._VMEM_BYTES

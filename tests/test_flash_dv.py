@@ -208,7 +208,103 @@ def test_the_backward_kernel_follows_the_shapes(case, monkeypatch):
         assert limits == []
 
 
-def _op_program(t, d_qk, d_v, h=4, hk=2):
+def _dense_lse(q, k, bias, causal, sm, window):
+    """Each query's log-sum-exp over its visible keys, from the dense
+    scores ``mha_reference`` builds (its masks, end-aligned), in float32 at
+    ``highest``."""
+    group = q.shape[1] // k.shape[1]
+    with jax.default_matmul_precision("highest"):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                       jnp.repeat(k, group, axis=1).astype(jnp.float32)) * sm
+    if bias is not None:
+        s = s + bias
+    tq, tk = s.shape[-2:]
+    i = jnp.arange(tq)[:, None] + tk - tq
+    j = jnp.arange(tk)[None, :]
+    mask = jnp.ones((tq, tk), bool)
+    if causal:
+        mask = mask & (i >= j)
+    if window is not None:
+        mask = mask & (i - j < window)
+    return jax.nn.logsumexp(jnp.where(mask, s, -jnp.inf), axis=-1)
+
+
+#: name -> (d_qk, d_v, KV heads of 4, causal, window, bias, ragged, dtype):
+#: what the cells and the long-sequence recipes send through the forward
+LSE_CASES = {
+    "causal": (32, 32, 4, True, None, False, False, jnp.bfloat16),
+    "window": (32, 32, 4, True, 72, False, False, jnp.float32),
+    "grouped_kv": (32, 32, 2, True, None, False, False, jnp.float32),
+    "192_over_128": (192, 128, 4, True, None, False, False, jnp.float32),
+    "float32_noncausal": (32, 32, 4, False, None, False, False, jnp.float32),
+    "bias": (32, 32, 4, False, None, True, False, jnp.float32),
+    "ragged_tq": (32, 32, 2, True, None, False, True, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LSE_CASES))
+@pytest.mark.parametrize("form", ["row", "lanes"])
+def test_the_forwards_lse_in_both_forms_and_the_gradients_from_it(form, case):
+    """The forward kernel (interpret mode) writes ``lse`` as ``[bh, 1, Tq]``
+    rows where a query block fills lanes and as the lane-broadcast columns
+    at ragged toy blocks: either way its readers get ``[b, h, Tq]``, each
+    query's log-sum-exp over its visible keys as the dense scores give it,
+    and the backward kernels (the blockwise jax backward under a bias)
+    rebuild from it the gradients ``jax.grad`` of the oracle gives."""
+    d_qk, d_v, hk, causal, window, biased, ragged, dtype = LSE_CASES[case]
+    blk, t = (128, 256) if form == "row" else (16, 48)
+    if ragged:
+        t -= blk // 2 + 3                # a padded last block: 189, 37
+    q, k, v, w = (a.astype(dtype) for a in _qkv(t, d_qk, d_v, hk=hk, seed=3))
+    bias = jnp.asarray(np.random.RandomState(5).randn(1, 1, t, t),
+                       jnp.float32) if biased else None
+    sm = d_qk ** -0.5
+    kw = dict(causal=causal, sm_scale=sm, window=window, block_q=blk,
+              block_k=blk, interpret=True)
+    assert F.flash_lse_layout(q, k, v, **kw) == form
+    bh, tqp = 4, -(-t // blk) * blk
+    text = str(jax.make_jaxpr(lambda q, k, v: F.flash_attention_fwd(
+        q, k, v, bias, **kw))(q, k, v))
+    assert (f"f32[{bh},1,{tqp}]" in text) == (form == "row")
+    out, lse = F.flash_attention_fwd(q, k, v, bias, **kw)
+    assert lse.shape == (1, 4, t) and lse.dtype == jnp.float32
+    half = dtype == jnp.bfloat16
+    _close(lse, _dense_lse(q, k, bias, causal, sm, window),
+           1e-5, "lse")                  # float32 from the same bf16 inputs
+    f32 = [a.astype(jnp.float32) for a in (q, k, v, w)]
+    want, g_want = _value_and_grads(
+        lambda q, k, v: mha_reference(q, k, v, bias, causal=causal,
+                                      sm_scale=sm, window=window), *f32)
+    _close(out, mha_reference(*f32[:3], bias, causal=causal, sm_scale=sm,
+                              window=window), 1e-2 if half else 2e-5, "out")
+    _, g_got = _value_and_grads(
+        lambda q, k, v: F.flash_attention(q, k, v, bias, **kw).astype(
+            jnp.float32), q, k, v, f32[3])
+    for a, b, name in zip(g_got, g_want, "qkv"):
+        assert a.shape == b.shape
+        _close(a, b, 2e-2 if half else 2e-5, f"{form} {case} d / d {name}")
+
+
+@pytest.mark.parametrize("t,block,form", [(32, 16, "lanes"), (16, 16, "row"),
+                                          (128, 128, "row")])
+def test_the_forward_counter_says_which_form_lse_left_in(t, block, form):
+    """``paddle_tpu_flash_lowerings_total{lse}``: ``row`` where the op's
+    query block fills lanes (or is the whole length, in whole sublanes),
+    ``lanes`` at the ragged blocks left; a reader that names no ``lse``
+    still reads the total."""
+    from paddle_tpu.ops.attention_ops import FLASH_LOWERINGS_CTR
+    labels = dict(window="none", kv_groups="2", impl="jax", widths="24/16")
+    other = "lanes" if form == "row" else "row"
+    before = [FLASH_LOWERINGS_CTR.value(**labels, lse=x)
+              for x in (form, other)] + [FLASH_LOWERINGS_CTR.value(**labels)]
+    scope, main, out, loss, feed = _op_program(t, 24, 16, block=block)
+    Executor().run(main, feed=feed, scope=scope, fetch_list=[loss.name])
+    assert [FLASH_LOWERINGS_CTR.value(**labels, lse=x)
+            for x in (form, other)] + [FLASH_LOWERINGS_CTR.value(**labels)] \
+        == [before[0] + 1, before[1], before[2] + 1]
+
+
+def _op_program(t, d_qk, d_v, h=4, hk=2, block=16):
     q, k, v, w = (np.asarray(a) for a in _qkv(t, d_qk, d_v, h=h, hk=hk))
     scope, main = Scope(), Program()
     with scope_guard(scope), program_guard(main, Program()):
@@ -218,8 +314,8 @@ def _op_program(t, d_qk, d_v, h=4, hk=2):
         wv = layers.data("w", shape=list(w.shape), dtype="float32",
                          append_batch_size=False)
         out = layers.flash_attention(*vs, causal=True,
-                                     sm_scale=d_qk ** -0.5, block_q=16,
-                                     block_k=16)
+                                     sm_scale=d_qk ** -0.5, block_q=block,
+                                     block_k=block)
         loss = layers.reduce_sum(out * wv)
         append_backward(loss)
     return scope, main, out, loss, dict(q=q, k=k, v=v, w=w)

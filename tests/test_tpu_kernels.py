@@ -262,6 +262,39 @@ def test_rn50_step_batch256_default_flags(tmp_path):
     assert kernels == [], kernels
 
 
+@tpu_hw
+@pytest.mark.parametrize("case", sorted(CELL_SHAPES))
+def test_flash_forwards_lse_at_the_cells_shapes(case):
+    """The compiled forward at each flash cell's shape hands on ``lse`` as
+    ``[b, h, Tq]`` float32 — out of the kernel as ``[bh, 1, Tq]`` rows at
+    every table row (a padded length too: its blocks fill lanes) — equal to
+    the log-sum-exp of the dense scores over the same bf16 inputs, and the
+    output to ``mha_reference``'s."""
+    from paddle_tpu.pallas import mha_reference
+    from paddle_tpu.pallas.flash_attention import (flash_attention_fwd,
+                                                   flash_lse_layout)
+
+    t, d_qk, d_v, heads, kv_heads, window = CELL_SHAPES[case]
+    q = _rand((1, heads, t, d_qk), 0, jnp.bfloat16, 0.5)
+    k = _rand((1, kv_heads, t, d_qk), 1, jnp.bfloat16, 0.5)
+    v = _rand((1, kv_heads, t, d_v), 2, jnp.bfloat16, 0.5)
+    assert flash_lse_layout(q, k, v, causal=True, window=window) == "row"
+    out, lse = jax.jit(functools.partial(
+        flash_attention_fwd, causal=True, window=window))(q, k, v)
+    assert lse.shape == (1, heads, t) and lse.dtype == jnp.float32
+    f32 = [a.astype(jnp.float32) for a in (q, k, v)]
+    with jax.default_matmul_precision("highest"):
+        s = jnp.einsum("bhqd,bhkd->bhqk", f32[0], jnp.repeat(
+            f32[1], heads // kv_heads, axis=1)) * d_qk ** -0.5
+        i, j = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+        mask = (i >= j) if window is None else (i >= j) & (i - j < window)
+        want_lse = jax.nn.logsumexp(jnp.where(mask, s, -jnp.inf), axis=-1)
+        want = mha_reference(*f32, causal=True, window=window)
+    errs = {"o": _rel_err(out, want), "lse": _rel_err(lse, want_lse)}
+    _record("flash_attention_fwd_cells", case=case, **errs)
+    assert errs["o"] < TOL[jnp.bfloat16] and errs["lse"] < 1e-2, errs
+
+
 # ---------------------------------------------------------------------------
 # a toy OLMoE step: the flash forward kernel once a layer, not twice
 # ---------------------------------------------------------------------------

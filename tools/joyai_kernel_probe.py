@@ -17,7 +17,17 @@ heads at a time).
     chiprun -- python3 tools/joyai_kernel_probe.py
     JAX_PLATFORMS=cpu python3 tools/joyai_kernel_probe.py --aot   # compiles
         each block choice for a described v5e, runs nothing: which fit VMEM,
-        which backward the entry point runs and the VMEM limit it asks for
+        which backward the entry point runs, the VMEM limit each call asks
+        for and, for the forward, the least limit that compiles the row
+
+``--forward`` (PR 39) times the forward half alone (``flash_attention_fwd``:
+the kernel and what hands ``lse`` on) over ``--fwd_blocks``, the 2048-wide
+rows among them, each against the dense oracle's output; with ``--parent
+PATH`` (another checkout of this repo, e.g. ``git archive`` of the parent
+commit unpacked in an ignored directory) the same rows through that
+checkout's kernel too, and whether ``Out`` and ``Lse`` are its bits.
+
+    chiprun -- python3 tools/joyai_kernel_probe.py --forward --parent .scratch/parent
 """
 
 import argparse
@@ -31,7 +41,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-FWD_BLOCKS = "512,1024;1024,1024;512,512;1024,512;256,1024;512,2048"
+FWD_BLOCKS = ("512,1024;1024,1024;512,512;1024,512;256,1024;512,2048;"
+              "1024,2048;2048,1024;2048,512;2048,2048")
 # "combined" at [32, 8192, 192 | 128] would keep 2.68 GB of float32 dK/dV
 # partials at 1024-row query blocks: past _COMBINED_PARTIAL_BUDGET, so the
 # entry point runs the split kernels whatever is asked (one row shows it);
@@ -47,6 +58,94 @@ def _blocks(text):
     return [tuple(x if i == 0 and not x.isdigit() else int(x)
                   for i, x in enumerate(b.split(",")))
             for b in text.split(";") if b]
+
+
+def load_parent(path):
+    """``pallas/flash_attention.py`` of another checkout as a module beside
+    this tree's (its relative imports are this tree's ``paddle_tpu``)."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "paddle_tpu.pallas._parent_flash_attention", os.path.join(
+            path, "paddle_tpu", "pallas", "flash_attention.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def forward_sweep(F, P, q, k, v, rows, timed, want_o=None, **kw):
+    """One JSON line a block row: ``flash_attention_fwd`` of this tree timed
+    alone, how far its output is from the oracle's ``want_o`` and, with the
+    parent's module ``P``, the parent's time and whether ``Out`` and ``Lse``
+    are the parent's to the bit (a row the parent's kernel cannot compile
+    says so and compares nothing)."""
+    import jax
+    import jax.numpy as jnp
+
+    def half(mod, bq, bk):
+        return jax.jit(lambda q, k, v: mod.flash_attention_fwd(
+            q, k, v, block_q=bq, block_k=bk, **kw))
+    for bq, bk in rows:
+        row = {"case": "fwd", "blocks": [bq, bk], "window": kw.get("window")}
+        try:
+            fwd = half(F, bq, bk)
+            row["fwd_ms"] = timed(fwd, q, k, v)
+            o, lse = fwd(q, k, v)
+            if want_o is not None:
+                row["o_rel"] = float(
+                    jnp.linalg.norm(o.astype(jnp.float32) - want_o)
+                    / jnp.linalg.norm(want_o))
+        except Exception as e:                     # VMEM: say and go on
+            row["error"] = str(e).strip().splitlines()[-1][:200]
+            print(json.dumps(row), flush=True)
+            continue
+        if P is not None:
+            try:
+                was = half(P, bq, bk)
+                row["parent_fwd_ms"] = timed(was, q, k, v)
+                po, plse = was(q, k, v)
+                row["out_bits_equal"] = bool(jnp.array_equal(o, po))
+                row["lse_bits_equal"] = bool(jnp.array_equal(lse, plse))
+            except Exception as e:
+                row["parent_error"] = str(e).strip().splitlines()[-1][:200]
+        print(json.dumps(row), flush=True)
+
+
+#: MiB of scoped VMEM tried in turn for a forward row (Mosaic's default: 16)
+VMEM_LADDER = (8, 12, 16, 20, 24, 28, 32, 40, 48, 64, 96)
+
+
+def aot_forward(F, rows, lower):
+    """For each forward block row: does it compile under the limit the call
+    asks for (``_fwd_vmem_bytes``), and the least of ``VMEM_LADDER`` that
+    compiles it.  ``lower(bq, bk)`` lowers a fresh jit of the forward for a
+    described chip."""
+    asks = F._fwd_vmem_bytes
+    for bq, bk in rows:
+        row = {"fwd": [bq, bk]}
+        limits = []
+
+        def spy(*a, **kw):
+            limits.append(asks(*a, **kw))
+            return limits[-1]
+        F._fwd_vmem_bytes = spy
+        try:
+            lower(bq, bk).compile()
+            row["compiles"] = True
+        except Exception as e:
+            row.update(compiles=False,
+                       error=str(e).strip().splitlines()[-1][:160])
+        row["vmem_limit_mib"] = limits[-1] / 2 ** 20
+        row["least_mib"] = None
+        for mib in VMEM_LADDER:
+            F._fwd_vmem_bytes = lambda *a, **kw: mib << 20
+            try:
+                lower(bq, bk).compile()
+                row["least_mib"] = mib
+                break
+            except Exception:
+                pass
+        F._fwd_vmem_bytes = asks
+        print(json.dumps(row), flush=True)
 
 
 def aot(args, F):
@@ -65,16 +164,12 @@ def aot(args, F):
     def s(w, dtype=jnp.dtype(args.dtype)):
         return jax.ShapeDtypeStruct((bh, t, w), dtype, sharding=one)
     sm = args.d_qk ** -0.5
-    for bq, bk in _blocks(args.fwd_blocks):
-        fn = jax.jit(lambda q, k, v: F._flash_fwd_pallas(
-            q, k, v, None, True, sm, bq, bk, 0, False))
-        try:
-            fn.lower(s(args.d_qk), s(args.d_qk), s(args.d_v)).compile()
-            row = {"fwd": [bq, bk], "compiles": True}
-        except Exception as e:
-            row = {"fwd": [bq, bk], "compiles": False,
-                   "error": str(e).strip().splitlines()[-1][:160]}
-        print(json.dumps(row), flush=True)
+    aot_forward(F, _blocks(args.fwd_blocks), lambda bq, bk: jax.jit(
+        lambda q, k, v: F._flash_fwd_pallas(
+            q, k, v, None, True, sm, bq, bk, 0, False)
+    ).lower(s(args.d_qk), s(args.d_qk), s(args.d_v)))
+    if args.forward:
+        return
     lse = jax.ShapeDtypeStruct((bh, t), jnp.float32, sharding=one)
     for impl, bq, bk in _blocks(args.bwd_blocks):
         fn = jax.jit(lambda q, k, v, o, lse, do: F._flash_bwd_pallas(
@@ -108,6 +203,11 @@ def main():
     ap.add_argument("--bwd_blocks", default=BWD_BLOCKS)
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--aot", action="store_true")
+    ap.add_argument("--forward", action="store_true",
+                    help="the forward half alone over --fwd_blocks")
+    ap.add_argument("--parent", default="", help="--forward: another "
+                    "checkout of this repo whose forward runs the same rows, "
+                    "Out and Lse compared to the bit")
     ap.add_argument("--dtype", default="bfloat16",
                     help="--aot: the inputs' type (the cell's float32 "
                     "forward check runs the kernels on float32)")
@@ -167,7 +267,8 @@ def main():
         row["fwd_bwd_ms"] = timed(jax.jit(lambda *a: jax.vjp(build, *a[:4])[
             1](a[4:])), q_nope, q_rope, k_nope, k_rope, dq, dk)
         row["bytes_written_fwd"] = int(q.size + k.size) * 2
-    say({"case": "build q and k"}, build_row)
+    if not args.forward:
+        say({"case": "build q and k"}, build_row)
 
     def attn(kw):
         return lambda q, k, v: F.flash_attention(
@@ -192,6 +293,13 @@ def main():
     def off(got, want):
         got = got.astype(jnp.float32)
         return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+
+    if args.forward:
+        rows = _blocks(args.fwd_blocks)
+        return forward_sweep(
+            F, load_parent(args.parent) if args.parent else None, q, k, v,
+            rows, timed, oracle()[0], causal=True, sm_scale=sm,
+            interpret=interpret)
 
     # the tables' own choice, against the oracle
     def default_row(row):

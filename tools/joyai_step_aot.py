@@ -8,7 +8,8 @@ arguments, the count of XLA's own rematerialised instructions (``.remat`` in
 the compiled text), ``reads_after_update`` (must be empty: a donated
 parameter read again behind its optimizer update) and which backward kernel
 each ``flash_attention_grad`` of the step got
-(``paddle_tpu_flash_bwd_kernel_total``), with and without
+(``paddle_tpu_flash_bwd_kernel_total``) and the form each forward lowering
+writes ``lse`` in (``paddle_tpu_flash_lowerings_total{lse}``), with and without
 ``--recompute``: whether the step fits beside its state, and what fitting
 costs (PERF.md section 7, row 31).  Nothing runs: no time comes from this.  The adapter has the
 recomputing step only (the traffic file's); without ``--recompute`` this
@@ -24,6 +25,7 @@ took (prints whether it ran, the loss and the peak memory).
 """
 
 import argparse
+import collections
 import json
 import os
 import re
@@ -185,12 +187,25 @@ def main():
         a.shape, a.dtype, sharding=one), step_args)
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
+
+    def flash_fwd_lse():
+        """The step's forward lowerings (a recomputed clone counts) by the
+        form lse leaves the kernel in, window and widths."""
+        return {"/".join(labels[n] for n in ("lse", "window", "widths")):
+                int(cell.get()) for labels, cell in
+                attention_ops.FLASH_LOWERINGS_CTR.series() if cell.get()}
     if args.lowered:
         text = re.sub(r"loc\(.*?\)", "", cb.jitted.lower(*shapes).as_text())
         with open(args.lowered, "w") as f:
             f.write(text)
+        # what each flash_fwd call of the step returns: (Out, lse), the
+        # latter [bh, 1, Tq] rows or the [bh, Tq, 128] lane broadcast
+        results = collections.Counter(
+            line.rsplit("->", 1)[1].strip() for line in text.split("\n")
+            if "custom_call" in line and '"flash_fwd"' in line)
         print(json.dumps({"cell": args.cell, "lowered": args.lowered,
-                          "bytes": len(text)}))
+                          "bytes": len(text), "flash_fwd_results": results,
+                          "flash_fwd_lse": flash_fwd_lse()}))
         return 0
     try:
         compiled = cb.jitted.lower(*shapes).compile()
@@ -218,6 +233,7 @@ def main():
             "/".join(labels[n] for n in ("kernel", "window", "widths")):
             int(cell.get()) for labels, cell in
             attention_ops.FLASH_BWD_KERNEL_CTR.series() if cell.get()},
+        "flash_fwd_lse": flash_fwd_lse(),
         "parameters_m": sum(int(np.prod(p.shape))
                             for p in m["parameters"]) / 1e6}))
     return 0

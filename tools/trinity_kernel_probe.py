@@ -10,6 +10,18 @@ the same bf16 inputs, one K/V head's group of query heads at a time):
 skipped or summed wrongly reads 0.1 or more.
 
     chiprun -- python3 tools/trinity_kernel_probe.py
+
+``--forward`` (PR 39; the sweep itself is ``tools/joyai_kernel_probe.py``'s)
+times the forward half alone over ``--fwd_blocks`` under the window and
+full, each row against the oracle's output and, with ``--parent PATH``
+(another checkout of this repo), against that checkout's kernel: its time,
+and whether ``Out`` and ``Lse`` are its bits.  ``--aot``, no chip: each
+forward row compiled for a described v5e, the VMEM limit the call asks for
+and the least that compiles it.  ``--window 0``: the full causal half alone
+(OLMoE's ``--seq 4096 --heads 64 --kv_heads 64 --window 0``).
+
+    chiprun -- python3 tools/trinity_kernel_probe.py --forward --parent .scratch/parent
+    JAX_PLATFORMS=cpu python3 tools/trinity_kernel_probe.py --aot
 """
 
 import argparse
@@ -21,6 +33,37 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.join(ROOT, "tools"))
+import joyai_kernel_probe as probe  # noqa: E402  (the forward sweep)
+
+
+def _windows(args):
+    """The window and the full causal half; ``--window 0``: the latter."""
+    return (args.window, None) if args.window else (None,)
+
+
+def aot(args, F):
+    """The forward rows at this shape compiled for a described v5e chip."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    one = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0])
+    group = args.heads // args.kv_heads
+
+    def s(heads):
+        return jax.ShapeDtypeStruct((heads, args.seq, 128), jnp.bfloat16,
+                                    sharding=one)
+    for window in _windows(args):
+        print(json.dumps({"window": window}), flush=True)
+        probe.aot_forward(
+            F, probe._blocks(args.fwd_blocks), lambda bq, bk: jax.jit(
+                lambda q, k, v: F._flash_fwd_pallas(
+                    q, k, v, None, True, 128 ** -0.5, bq, bk, 0, False,
+                    window, group)
+            ).lower(s(args.heads), s(args.kv_heads), s(args.kv_heads)))
 
 
 def main():
@@ -33,6 +76,13 @@ def main():
                     help="bq_fwd,bk_fwd,bq_bwd,bk_bwd[;...] beside defaults")
     ap.add_argument("--impls", default="fused,combined,split",
                     help="the backward kernels to time at each block choice")
+    ap.add_argument("--forward", action="store_true",
+                    help="the forward half alone over --fwd_blocks")
+    ap.add_argument("--fwd_blocks", default=probe.FWD_BLOCKS)
+    ap.add_argument("--parent", default="", help="--forward: another "
+                    "checkout of this repo, its forward on the same rows")
+    ap.add_argument("--aot", action="store_true", help="compile each "
+                    "--fwd_blocks row for a described v5e, run nothing")
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--oracle_heads", type=int, default=0,
                     help="query heads of a group the oracle takes at a time "
@@ -44,9 +94,12 @@ def main():
     import jax.numpy as jnp
     import paddle_tpu  # noqa: F401
     F = importlib.import_module("paddle_tpu.pallas.flash_attention")
+    if args.aot:
+        return aot(args, F)
     interpret = jax.default_backend() != "tpu"
     if interpret:                                  # a rehearsal of the path
         args.seq, args.window, args.iters = 64, 16, 1
+        args.fwd_blocks = "16,16;32,16"
     key = jax.random.PRNGKey(0)
     shape = lambda h: (1, h, args.seq, 128 if not interpret else 16)  # noqa
     q, do = (jax.random.normal(jax.random.fold_in(key, i), shape(args.heads),
@@ -96,8 +149,15 @@ def main():
         got = got.astype(jnp.float32)
         return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
 
-    for window in (args.window, None):
+    parent = probe.load_parent(args.parent) if args.parent else None
+    for window in _windows(args):
         want = oracle(window)
+        if args.forward:
+            probe.forward_sweep(F, parent, q, k, v,
+                                probe._blocks(args.fwd_blocks), timed,
+                                want[0], causal=True, window=window,
+                                interpret=interpret)
+            continue
         for blk in blocks:
             for impl in args.impls.split(","):
                 kw = dict(causal=True, window=window, bwd_impl=impl,
