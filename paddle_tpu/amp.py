@@ -47,6 +47,8 @@ BF16_IF_BIG = {
     "softmax", "layer_norm", "batch_norm", "group_norm", "scale", "concat",
     # float32 inside (statistics, angles), the stream in bf16
     "rms_norm", "rope",
+    # float32 inside too; the [d, L] filter is a master weight and stays so
+    "short_conv",
 }
 
 _COMPUTE = jnp.bfloat16
@@ -55,7 +57,8 @@ _FLOATS = (jnp.float32, jnp.bfloat16, jnp.float16)
 # norm ops carry f32 STATE inputs (running mean/var, scale/bias) that must
 # not be rounded to bf16 every step — only the activation slot is cast
 _SLOT_RESTRICT = {"batch_norm": {"X"}, "layer_norm": {"X"},
-                  "group_norm": {"X"}, "rms_norm": {"X"}}
+                  "group_norm": {"X"}, "rms_norm": {"X"},
+                  "short_conv": {"X"}}
 
 # NOTE: the analysis.fusion targets (fused_dense_act,
 # fused_embedding_layer_norm) appear in NO list above on purpose: one

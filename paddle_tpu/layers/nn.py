@@ -1551,16 +1551,37 @@ def fake_quantize_dequantize_abs_max(x, bit_length=8):
 
 
 def fused_lm_head_ce(x, size, label, param_attr=None, bias_attr=None,
-                     ignore_index=-100, chunk_size=1024):
+                     ignore_index=-100, chunk_size=1024, table=None):
     """Chunked LM-head + cross-entropy: O(chunk × vocab) memory instead of
     materializing [tokens, vocab] logits (TPU-native; no fluid analog).
-    Owns its projection parameters like ``fc`` (same weight orientation
-    [d_in, size])."""
+    Two weight orientations.  Without ``table`` it owns its projection
+    parameters like ``fc``: a [d_in, size] weight (and a bias unless
+    ``bias_attr=False``).  ``table``: an embedding's [size, d_in] parameter
+    (``layers.embedding``'s, tied input and output embeddings) read as the
+    head's weight, ``logits = x table^T``; nothing is created, there is no
+    bias, and the table's gradient is the sum of the lookup's and the
+    head's (``backward.py`` adds up a parameter's readers).  The op then
+    carries ``w_layout="vd"`` and ``table_reads``, the forward ops of the
+    block that read the table, this one among them; without ``table`` the op
+    and its lowering are what they were."""
     helper = LayerHelper("fused_lm_head_ce", param_attr=param_attr,
                          bias_attr=bias_attr)
     d_in = int(x.shape[-1])
-    w = helper.create_parameter(param_attr, shape=[d_in, size],
-                                dtype=x.dtype)
+    attrs = {"ignore_index": ignore_index, "chunk_size": chunk_size}
+    if table is not None:
+        if tuple(table.shape) != (size, d_in):
+            raise ValueError(f"table {tuple(table.shape)} is not [size, "
+                             f"d_in] = [{size}, {d_in}]")
+        if bias_attr is not False:
+            raise ValueError("a head that reads a table has no bias: pass "
+                             "bias_attr=False")
+        w = table
+        block = helper.main_program.current_block()
+        attrs.update(w_layout="vd", table_reads=1 + sum(
+            table.name in op.input_arg_names() for op in block.ops))
+    else:
+        w = helper.create_parameter(param_attr, shape=[d_in, size],
+                                    dtype=x.dtype)
     inputs = {"X": [x], "W": [w], "Label": [label]}
     if bias_attr is not False:
         b = helper.create_parameter(bias_attr, shape=[size], dtype=x.dtype,
@@ -1569,8 +1590,28 @@ def fused_lm_head_ce(x, size, label, param_attr=None, bias_attr=None,
     loss = helper.create_variable_for_type_inference("float32")
     helper.append_op(
         "fused_lm_head_ce", inputs=inputs, outputs={"Loss": [loss]},
-        attrs={"ignore_index": ignore_index, "chunk_size": chunk_size})
+        attrs=attrs)
     return loss
+
+
+def short_conv(x, filter_size=3, param_attr=None, name=None):
+    """The core of a gated short-convolution operator over ``x`` [b, t, 3 d],
+    an input projection split in three ``B | C | u``: ``C * conv(B * u)``
+    with ``conv`` a causal depthwise convolution of ``filter_size`` taps over
+    the ``d`` channels (``c[t] = sum_j w[:, j] * g[t - (filter_size - 1) +
+    j]``, zeros before the sequence starts), no bias, no activation
+    (``short_conv`` op; its filter ``w`` is [d, filter_size]).  Returns
+    [b, t, d]; the projections before and after are the caller's ``fc``."""
+    helper = LayerHelper("short_conv", name=name)
+    d3 = int(x.shape[-1])
+    if d3 % 3:
+        raise ValueError(f"the last axis ({d3}) is not three equal parts")
+    w = helper.create_parameter(param_attr, shape=[d3 // 3, int(filter_size)],
+                                dtype=x.dtype)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op("short_conv", inputs={"X": [x], "Filter": [w]},
+                     outputs={"Out": [out]})
+    return out
 
 
 def switch_moe_ffn(x, num_experts, d_inner, capacity_factor=1.25,

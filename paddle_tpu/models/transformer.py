@@ -256,20 +256,25 @@ class BertConfig:
         return V * D + P * D + 2 * D + L * per_layer
 
 
-def _lm_head_loss(enc, cfg, lm_label, fused_head, param_name, bias=True):
+def _lm_head_loss(enc, cfg, lm_label, fused_head, param_name, bias=True,
+                  table=None):
     """Shared LM head + masked-mean CE (label 0 = [PAD] excluded) used by
     the MLM and the causal-LM builders; ``bias=False`` for a bias-free
-    head."""
+    head.  ``table``: the embedding's [vocab, d] parameter read as the
+    head's weight (tied embeddings; no ``<param_name>.w`` exists then)."""
     w_attr = ParamAttr(name=f"{param_name}.w")
     b_attr = ParamAttr(name=f"{param_name}.b") if bias else False
     if fused_head:
         loss = layers.fused_lm_head_ce(
             enc, cfg.vocab_size, lm_label, param_attr=w_attr,
-            bias_attr=b_attr, ignore_index=0)
+            bias_attr=b_attr, ignore_index=0, table=table)
         logits = None
     else:
-        logits = layers.fc(enc, size=cfg.vocab_size, num_flatten_dims=2,
-                           param_attr=w_attr, bias_attr=b_attr)
+        if table is not None:
+            logits = layers.matmul(enc, table, transpose_y=True)
+        else:
+            logits = layers.fc(enc, size=cfg.vocab_size, num_flatten_dims=2,
+                               param_attr=w_attr, bias_attr=b_attr)
         loss = layers.softmax_with_cross_entropy(
             logits, layers.unsqueeze(lm_label, [2]), ignore_index=0)
     mask = layers.cast(lm_label > 0, "float32")
@@ -920,6 +925,150 @@ def build_smallthinker_pretrain(cfg: SmallThinkerConfig, seq_len,
                         param_attr=ParamAttr(name="final_norm.w"))
     _, loss = _lm_head_loss(x, cfg, lm_label, fused_head, "lm_out",
                             bias=False)
+    return (src_ids, lm_label), {"expert_load": loads, "hidden": x}, loss
+
+
+# -- LFM2 (lfm2_moe): gated short-convolution operators beside grouped-query --
+# -- attention, sigmoid routing, one table for the embedding and the head -----
+
+class Lfm2Config:
+    """LFM2-8B-A1B defaults (``LiquidAI/LFM2-8B-A1B`` config.json,
+    ``model_type`` ``lfm2_moe``).  ``layer_types[i]`` is ``conv`` (the gated
+    short convolution of ``conv_taps`` taps) or ``full_attention``
+    (grouped-query, per-head QK-norm, rotary); the first ``n_dense_layer``
+    layers have a dense gated FFN of width ``d_inner``, the others
+    ``n_experts`` routed experts of width ``d_expert`` (``top_k`` a token,
+    sigmoid scores, a selection bias, the kept scores renormalised with
+    ``1e-6`` and scaled) and no shared expert; the head reads the embedding
+    table.  ``n_held``/``expert_offset``: the experts whose weights this
+    program holds (default all), as :class:`TrinityConfig`."""
+
+    def __init__(self, vocab_size=65536, d_model=2048, n_layer=24, n_head=32,
+                 n_kv_head=8, d_head=64, d_inner=7168, d_expert=1792,
+                 n_experts=32, top_k=4, n_dense_layer=2, layer_types=None,
+                 conv_taps=3, route_scale=1.0, rms_eps=1e-5, rope_theta=1e6,
+                 n_held=None, expert_offset=0, init_std=0.02):
+        self.vocab_size = vocab_size
+        self.d_model = d_model
+        self.n_layer = n_layer
+        self.n_head = n_head
+        self.n_kv_head = n_kv_head
+        self.d_head = d_head
+        self.d_inner = d_inner
+        self.d_expert = d_expert
+        self.n_experts = n_experts
+        self.top_k = top_k
+        self.n_dense_layer = n_dense_layer
+        # published: attention at layers 2, 6, 10, 14, 18 and 21 of 24
+        self.layer_types = list(layer_types) if layer_types else [
+            "full_attention" if i in (2, 6, 10, 14, 18, 21) else "conv"
+            for i in range(n_layer)]
+        assert len(self.layer_types) == n_layer
+        self.conv_taps = conv_taps
+        self.route_scale = route_scale
+        self.rms_eps = rms_eps
+        self.rope_theta = rope_theta
+        self.n_held = n_experts if n_held is None else n_held
+        self.expert_offset = expert_offset
+        self.init_std = init_std
+
+
+def short_conv_operator(x, d_model, taps=3, param_prefix="conv"):
+    """``W_out (C * conv(B * u))`` with ``[B, C, u] = split3(W_in x)``: the
+    input projection ``<prefix>.in_proj.w`` [d, 3 d], ``layers.short_conv``
+    (filter ``<prefix>.filter`` [d, taps], drawn as a depthwise Conv1d's:
+    uniform in ``+-taps^-0.5``) and the output projection
+    ``<prefix>.out_proj.w`` [d, d]; no bias."""
+    from ..initializer import UniformInitializer
+    bcu = layers.fc(x, size=3 * d_model, num_flatten_dims=2, bias_attr=False,
+                    param_attr=ParamAttr(name=f"{param_prefix}.in_proj.w"))
+    bound = float(taps) ** -0.5
+    y = layers.short_conv(bcu, taps, param_attr=ParamAttr(
+        name=f"{param_prefix}.filter",
+        initializer=UniformInitializer(-bound, bound)))
+    return layers.fc(y, size=d_model, num_flatten_dims=2, bias_attr=False,
+                     param_attr=ParamAttr(name=f"{param_prefix}.out_proj.w"))
+
+
+def lfm2_decoder_layer(x, cfg: Lfm2Config, idx=0, attn_impl="flash",
+                       is_test=False):
+    """One lfm2_moe block, pre-norm, two norms, no bias anywhere: ``h = x +
+    Op(RMS1(x))``, ``out = h + FF(RMS2(h))``.  ``Op``: where ``layer_types``
+    says ``full_attention``, grouped-query attention with Q and K RMS-normed
+    per head (weights ``[d_head]``) and then rotated (rotate-half), under
+    the ``attention_operator`` tag; else :func:`short_conv_operator` under
+    ``conv_operator``.  ``FF``: :func:`gated_ffn` in the first
+    ``n_dense_layer`` layers; else ``moe_ffn`` with sigmoid scores, a
+    selection bias held at zero, the kept scores renormalised (``+ 1e-6``)
+    and scaled, no shared expert.  Returns ``(out, expert_load or None)``."""
+    from ..initializer import NormalInitializer
+    p = f"dec_{idx}"
+
+    def norm(v, name, axis=2):
+        return layers.rms_norm(v, begin_norm_axis=axis, epsilon=cfg.rms_eps,
+                               param_attr=ParamAttr(name=f"{p}.{name}.w"))
+
+    def head_hook(q, k):
+        return tuple(layers.rope(norm(t, f"attn.{n}_norm", 3), cfg.d_head,
+                                 cfg.rope_theta)
+                     for t, n in ((q, "q"), (k, "k")))
+
+    n = norm(x, "ln1")
+    if cfg.layer_types[idx] == "full_attention":
+        with name_scope("attention_operator"):
+            op = multi_head_attention(
+                n, n, n, cfg.d_model, cfg.n_head, is_test=is_test,
+                param_prefix=f"{p}.attn", attn_impl=attn_impl, causal=True,
+                bias=False, n_kv_head=cfg.n_kv_head, d_head=cfg.d_head,
+                head_hook=head_hook)
+    else:
+        with name_scope("conv_operator"):
+            op = short_conv_operator(n, cfg.d_model, cfg.conv_taps,
+                                     f"{p}.conv")
+    h = x + op
+    m = norm(h, "ln2")
+    if idx < cfg.n_dense_layer:
+        with name_scope("dense_ffn"):
+            return h + gated_ffn(m, cfg.d_inner, cfg.d_model, f"{p}.ffn"), None
+    moe, _, _, load = layers.moe_ffn(
+        m, cfg.n_experts, cfg.top_k, cfg.d_expert, norm_topk_prob=True,
+        param_prefix=f"{p}.moe",
+        initializer=NormalInitializer(0.0, cfg.init_std),
+        score_func="sigmoid", select_bias=True, norm_eps=1e-6,
+        route_scale=cfg.route_scale, num_held=cfg.n_held,
+        expert_offset=cfg.expert_offset)
+    return h + moe, load
+
+
+def build_lfm2_pretrain(cfg: Lfm2Config, seq_len, is_test=False,
+                        attn_impl="flash", fused_head=True, checkpoints=None):
+    """Causal LM over :func:`lfm2_decoder_layer` blocks: ids -> embedding ->
+    ``n_layer`` blocks -> final RMSNorm -> logits over the embedding table
+    itself (``word_embedding`` is read by the lookup and by the head, and
+    its gradient is the sum of the two; there is no ``lm_out.w``); loss =
+    mean next-token CE (label 0 excluded, as in the
+    other builders) and nothing else (the selection bias is held at zero, as
+    in :func:`build_trinity_pretrain`).  ``checkpoints=[]`` collects the
+    block outputs for ``RecomputeOptimizer``.  Returns ``(feeds, parts,
+    loss)`` with ``parts`` = {"expert_load": [per expert layer], "hidden":
+    the final norm's output}."""
+    from ..framework.core import default_main_program
+    src_ids = layers.data("src_ids", shape=[seq_len], dtype="int64")
+    lm_label = layers.data("lm_label", shape=[seq_len], dtype="int64")
+    x = layers.embedding(src_ids, size=[cfg.vocab_size, cfg.d_model],
+                         param_attr=ParamAttr(name="word_embedding"))
+    loads = []
+    for i in range(cfg.n_layer):
+        x, load = lfm2_decoder_layer(x, cfg, i, attn_impl, is_test)
+        if load is not None:
+            loads.append(load)
+        if checkpoints is not None:
+            checkpoints.append(x)
+    x = layers.rms_norm(x, begin_norm_axis=2, epsilon=cfg.rms_eps,
+                        param_attr=ParamAttr(name="final_norm.w"))
+    table = default_main_program().global_block().var("word_embedding")
+    _, loss = _lm_head_loss(x, cfg, lm_label, fused_head, "lm_out",
+                            bias=False, table=table)
     return (src_ids, lm_label), {"expert_load": loads, "hidden": x}, loss
 
 

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import monitor as _monitor
 from ..framework.core import grad_var_name
 from ..framework.registry import register_op
 from ..framework.executor import per_dp_shard
@@ -956,6 +957,16 @@ def _fused_elemwise_activation(ctx, ins, attrs):
     return {"Out": [out]}
 
 
+TIED_HEAD_LOWERINGS_CTR = _monitor.REGISTRY.counter(
+    "paddle_tpu_tied_head_lowerings_total",
+    "fused_lm_head_ce lowerings (forward, and the generic vjp's forward "
+    "inside the grad op) that read an embedding's [vocab, d] table as the "
+    "head's weight, by table_reads: the forward ops of the program that "
+    "read that table, the head among them (2: one lookup and the head) — "
+    "counted while tracing, once per compile, nothing per step",
+    ("table_reads",))
+
+
 @register_op("fused_lm_head_ce")
 def _fused_lm_head_ce(ctx, ins, attrs):
     """LM head projection + softmax cross-entropy, scanned over token
@@ -965,12 +976,22 @@ def _fused_lm_head_ce(ctx, ins, attrs):
     dense, operators/softmax_with_cross_entropy_op.cc).  jax.checkpoint on
     the chunk body makes the backward recompute each chunk's logits, so
     training memory stays O(chunk * vocab).  No reference counterpart —
-    TPU-native capability."""
+    TPU-native capability.
+
+    ``w_layout`` (absent: W is [d, vocab], like ``fc``'s, and the lowering is
+    what it was before the attribute existed): ``"vd"``, W is an embedding's
+    [vocab, d] table and the logits contract over its second axis (tied
+    input and output embeddings: one parameter read both ways; its gradient
+    leaves here [vocab, d] and ``backward.py`` adds the lookup's to it)."""
     x, w = X(ins, "X"), X(ins, "W")
     b = X(ins, "Bias")
     label = X(ins, "Label")
     ignore = attrs.get("ignore_index", -100)
     chunk = int(attrs.get("chunk_size", 1024))
+    tied = attrs.get("w_layout") == "vd"
+    if tied and not getattr(ctx, "is_abstract", False):
+        TIED_HEAD_LOWERINGS_CTR.inc(
+            table_reads=str(int(attrs.get("table_reads", 0))))
 
     def head(shard, x, label, w, *bias):
         b = bias[0] if bias else None
@@ -990,8 +1011,13 @@ def _fused_lm_head_ce(ctx, ins, attrs):
 
         def body(carry, inp):
             xi, li = inp
-            logits = (xi.astype(jnp.bfloat16) @ w.astype(jnp.bfloat16)
-                      ).astype(jnp.float32)
+            if tied:                    # [chunk, d] x [vocab, d]^T
+                logits = jax.lax.dot_general(
+                    xi.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
+                    (((1,), (1,)), ((), ()))).astype(jnp.float32)
+            else:
+                logits = (xi.astype(jnp.bfloat16) @ w.astype(jnp.bfloat16)
+                          ).astype(jnp.float32)
             if b is not None:
                 logits = logits + b.astype(jnp.float32)
             m = jax.lax.stop_gradient(

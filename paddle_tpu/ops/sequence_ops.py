@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..framework.core import grad_var_name
 from ..framework.registry import register_op
 from .common import X, XS, ids_dtype, canon_dtype
 
@@ -179,3 +180,75 @@ def _sequence_erase(ctx, ins, attrs):
         keep &= (x != tk)
     # static shape: replace erased with 0 and compact is not possible; mask out
     return {"Out": [jnp.where(keep, x, 0)]}
+
+
+# -- short_conv: a gated causal depthwise convolution over [b, t, d] -----------
+
+def _causal_depthwise(g, filt):
+    """``c[t] = sum_j filt[:, j] * g[t - (L - 1) + j]`` over [b, t, d], zeros
+    before the sequence starts (``filt`` [d, L]): L shifted multiplies."""
+    taps, t = filt.shape[1], g.shape[1]
+    pads = jnp.pad(g, [(0, 0), (taps - 1, 0), (0, 0)])
+    return sum(pads[:, j:j + t] * filt[:, j] for j in range(taps))
+
+
+def _short_conv(ctx, ins, attrs):
+    """The core of a gated short-convolution operator (LFM2's ``conv``
+    layers): X [b, t, 3 d] is the input projection, split in three ``B | C |
+    u``; ``g = B * u``; ``c[t] = sum_j Filter[:, j] * g[t - (L - 1) + j]``, a
+    causal depthwise convolution over the ``d`` channels with zeros before
+    the sequence starts (Filter [d, L], no bias); ``Out = C * c`` [b, t, d].
+    No activation, no positional term; the two projections round it are the
+    program's own ``mul`` ops.  Bandwidth-bound: three [t, d] streams in, one
+    out.  Float32 inside (a v5e has no bf16 vector unit: the products would
+    be widened anyway), the output in X's dtype."""
+    x, filt = X(ins, "X"), X(ins, "Filter")
+    f32 = jnp.float32
+    b_, c_, u = jnp.split(x.astype(f32), 3, axis=-1)
+    out = c_ * _causal_depthwise(b_ * u, filt.astype(f32))
+    return {"Out": [out.astype(x.dtype)]}
+
+
+def _short_conv_grad_maker(op, block, no_grad_set):
+    def wanted(n):
+        v = block.var(n) if block.has_var(n) else None
+        return n not in no_grad_set and not (v is not None
+                                             and v.stop_gradient)
+    g_inputs = {"X$X": op.input("X"), "X$Filter": op.input("Filter"),
+                "OG$Out": [grad_var_name(n) for n in op.output("Out")]}
+    g_outputs = {"IG$" + s: [grad_var_name(n) if wanted(n) else ""
+                             for n in op.input(s)] for s in ("X", "Filter")}
+    return [{"type": "short_conv_grad", "inputs": g_inputs,
+             "outputs": g_outputs, "attrs": dict(op.attrs)}]
+
+
+register_op("short_conv", _short_conv, grad_maker=_short_conv_grad_maker)
+
+
+@register_op("short_conv_grad")
+def _short_conv_grad(ctx, ins, attrs):
+    """The backward of ``short_conv`` from its inputs alone (the gate product
+    and the convolution are three multiplies a channel: computed again, not
+    saved): ``dC = dOut * c``; ``dc = dOut * C``; ``dg[s] = sum_j Filter[:,
+    j] * dc[s + (L - 1) - j]`` (the same taps, run towards the past);
+    ``dB = dg * u``, ``du = dg * B``; ``dFilter[:, j] = sum_{b, t} dc[t] *
+    g[t - (L - 1) + j]`` in float32 (the filter is a master weight).  Reads
+    X and dOut, writes dX: seven [t, d] streams."""
+    x, filt, d_out = X(ins, "X$X"), X(ins, "X$Filter"), X(ins, "OG$Out")
+    f32 = jnp.float32
+    taps, t = filt.shape[1], x.shape[1]
+    b_, c_, u = jnp.split(x.astype(f32), 3, axis=-1)
+    w = filt.astype(f32)
+    g = b_ * u
+    dy = jnp.zeros_like(g) if d_out is None else d_out.astype(f32)
+    dc = dy * c_
+    ahead = jnp.pad(dc, [(0, 0), (0, taps - 1), (0, 0)])
+    dg = sum(ahead[:, taps - 1 - j:taps - 1 - j + t] * w[:, j]
+             for j in range(taps))
+    behind = jnp.pad(g, [(0, 0), (taps - 1, 0), (0, 0)])
+    d_filt = jnp.stack([jnp.sum(behind[:, j:j + t] * dc, axis=(0, 1))
+                        for j in range(taps)], axis=1)
+    dx = jnp.concatenate([dg * u, dy * _causal_depthwise(g, w), dg * b_],
+                         axis=-1)
+    return {"IG$X": [dx.astype(x.dtype)],
+            "IG$Filter": [d_filt.astype(filt.dtype)]}

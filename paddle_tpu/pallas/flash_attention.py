@@ -682,11 +682,13 @@ def _flash_bwd_pallas_combined(q, k, v, o, lse, do, causal, sm_scale,
     return dq[:, :tq], dk[:, :tk], dv[:, :tk]
 
 
-# default pallas backward where no table names one: "combined" (one
-# recompute, dk/dv partial sums — the r4 winner at long T and d <= 64),
-# "split" (the two-pass r2 kernels) or "fused" (one recompute, every
-# accumulation in VMEM).  Overridable per call via
-# flash_attention(bwd_impl=...).
+# default pallas backward where no table row names one: "combined" (one
+# recompute, dk/dv partial sums in HBM: the r4 winner at d <= 64 up to 8192,
+# where the partials fit _COMBINED_PARTIAL_BUDGET; past it, which every
+# 16384 shape of a model is, asking for it gets the split kernels), "split"
+# (the two-pass r2 kernels) or "fused" (one recompute, every accumulation in
+# VMEM: what the table rows swept since PR 37 name).  Overridable per call
+# via flash_attention(bwd_impl=...).
 _BWD_IMPL = "combined"
 
 # the combined kernel's dk/dv partials cost 2·bh·nq·Tk·d·4 B of HBM —
@@ -1075,17 +1077,32 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 # End-to-end-validated block defaults per sequence length (r4 sweep,
 # LONGCTX_ABLATION.md).  Keys are max(Tq, Tk); anything else takes the
-# (512, 1024) baseline.  The bwd table feeds the combined single-recompute
-# kernel: big q-blocks keep its dk/dv partial-sum traffic low.
+# (512, 1024) baseline.  The bwd rows without a third entry feed the combined
+# single-recompute kernel (big q-blocks keep its dk/dv partial-sum traffic
+# low) where its partials fit _COMBINED_PARTIAL_BUDGET; a third entry names
+# the backward, as in the wider tables.
 # re-swept IN-GRAPH after the r5 mask/scale elision (the r4 optima moved:
 # wide 2048 k-blocks now win the non-causal fwd at 4k/8k — less per-block
 # bookkeeping per element once the masks are gone; measured e2e on v5e:
 # 4k 275→267 ms, 8k 436→422 ms, 16k 693→681 ms; the 2k causal table
 # re-validated unchanged)
+# 16384, re-swept on a v5e at causal [32, 16384, 64] bf16 over 8 K/V heads,
+# groups of 4 (tools/trinity_kernel_probe.py --seq 16384 --heads 32
+# --kv_heads 8 --head_dim 64 --window 0, PR 40; the r4 sweep had every head
+# its own K/V and no fused backward): forward (1024, 1024) 18.38 ms, (1024,
+# 2048) 19.01, (512, 2048) 21.19 (the r4 row), (512, 1024) 21.46.  Backward
+# (forward + backward less the forward): fused (1024, 1024) 33.6 to 34.8,
+# (512, 1024) 34.97, (2048, 512) 39.61, (1024, 512) 39.05, and (512, 512)
+# runs out of VMEM; split (1024, 1024) 51.08, (512, 1024) 53.82, (1024, 512)
+# 56.17, (512, 512) 60.07.  "combined", what the row's missing third entry
+# asked for until PR 40, would keep 4.3 GB of partials here, past the budget,
+# and was the split kernels.  A head's [16384, 64] dQ accumulator and the
+# (1024, 1024) blocks ask for 40.8 MiB of VMEM (_fused_vmem_bytes).  Against
+# the dense-mask oracle: o 2.1e-3, dq 2.5e-3, dk 3.0e-3, dv 2.4e-3.
 _FWD_DEFAULTS = {2048: (1024, 1024), 4096: (512, 2048),
-                 8192: (512, 2048), 16384: (512, 2048)}
+                 8192: (512, 2048), 16384: (1024, 1024)}
 _BWD_DEFAULTS = {2048: (1024, 512), 4096: (1024, 1024), 8192: (1024, 512),
-                 16384: (1024, 1024)}
+                 16384: (1024, 1024, "fused")}
 # head_dim 128 (64 < d <= 128), swept on a v5e at causal [64, 4096, 128]
 # bf16 (tools/olmoe_kernel_sweep.py, PR 27): forward (1024, 1024) 3.17 ms
 # against the (512, 1024) baseline's 4.01; backward "combined" (1024, 512)

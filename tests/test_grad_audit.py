@@ -393,6 +393,7 @@ def _configs(op):
         "reshape2": lambda: _Cfg({"X": [f(2, 3)]}, {"shape": [3, 2]}),
         "reverse": lambda: _Cfg({"X": [f(2, 3)]}, {"axis": [0]}),
         "row_conv": lambda: _Cfg({"X": [f(2, 4, 3)], "Filter": [f(2, 3)]}),
+        "short_conv": lambda: _Cfg({"X": [f(2, 5, 9)], "Filter": [f(3, 3)]}),
         "sample_logits": lambda: _Cfg(
             {"Logits": [f(3, 5)], "Labels": [i(3, 1, n=5)]},
             {"num_samples": 2, "seed": 3}, loss_outputs=["SampledLogits"]),

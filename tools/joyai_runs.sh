@@ -1,9 +1,10 @@
 #!/bin/bash
 # usage: tools/joyai_runs.sh <tag> <trace seed or -> <untraced seeds...>
-# runs the JoyAI cell on the chip, one process a run, outputs under chiprun_out/
+# runs the JoyAI cell (or the cell $CELL names) on the chip, one process a
+# run, outputs under chiprun_out/
 tag=$1; traced=$2; shift 2
 mkdir -p chiprun_out
-CELL=joyai_llm_flash_lm_mtp_s8192
+CELL=${CELL:-joyai_llm_flash_lm_mtp_s8192}
 if [ "$traced" != "-" ]; then
   python3 benchmark/run.py --workload $CELL --seed $traced --seconds 20 --trace 1 > chiprun_out/${tag}_t${traced}.txt 2> chiprun_out/${tag}_t${traced}.err
   echo "traced $traced rc=$?"; tail -n 1 chiprun_out/${tag}_t${traced}.txt | cut -c1-3000

@@ -55,7 +55,8 @@ class _NoRecompute:
 CELLS = {"joyai": ("joyai_llm_flash", "lm_mtp_s8192"),
          "trinity": ("trinity_mini", "lm_s8192"),
          "olmoe": ("olmoe_1b_7b", "lm_s4096"),
-         "smallthinker": ("smallthinker_21b_a3b", "lm_s16384")}
+         "smallthinker": ("smallthinker_21b_a3b", "lm_s16384"),
+         "lfm2": ("lfm2_8b_a1b", "lm_s16384_r64")}
 
 
 def reads_after_update(text):
@@ -143,7 +144,9 @@ def main():
                     help="the other cell that runs moe_ffn's held path: "
                     "trinity, or the third that runs the flash kernels: "
                     "olmoe (their steps have no recomputation: leave "
-                    "--recompute out, as for smallthinker)")
+                    "--recompute out, as for smallthinker and lfm2; lfm2's "
+                    "adapter builds ISSUE 40's fallback where the traffic "
+                    "says recompute, which --recompute sets)")
     args = ap.parse_args()
     if args.run:
         return run_on_chip(args)
@@ -176,6 +179,8 @@ def main():
         config["num_hidden_layers"] = args.layers
     if args.seq:
         traffic["seq_len"] = args.seq
+    if args.recompute and args.cell == "lfm2":
+        traffic["recompute"] = True      # the adapter builds the fallback
     m = adapter.build_train(config, traffic, 7, 1, False)
     cb, step_args = dp_arith_check.caught_step(lambda: m["exe"].run(
         m["program"], feed=m["ring"][0], fetch_list=[m["loss"]],

@@ -17,6 +17,10 @@ cell's own decision on each: ``control``, not correct and by which limits.
   (each kind as ``[its leaves together, its worst leaf, that leaf's name]``).
 
     chiprun -- python3 tools/smallthinker_tolerance_probe.py --seeds 7,11
+
+``--cell lfm2`` (PR 40): the same three readings for the LFM2 cell, its
+adapter, reference, configuration and traffic in SmallThinker's place (4 of
+32 experts; the table read by the lookup and the head).
 """
 
 import argparse
@@ -29,24 +33,35 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 
+#: cell -> (configuration = adapter = reference, traffic, the adapter's
+#: configuration function, the builder, the cell's test module and its toy)
+CELLS = {"smallthinker": ("smallthinker_21b_a3b", "lm_s16384",
+                          "smallthinker_config", "build_smallthinker_pretrain",
+                          "test_smallthinker_cell", "toy_smallthinker"),
+         "lfm2": ("lfm2_8b_a1b", "lm_s16384_r64", "lfm2_config",
+                  "build_lfm2_pretrain", "test_lfm2_cell", "toy_lfm2")}
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--seeds", default="7")
+    ap.add_argument("--cell", default="smallthinker", choices=sorted(CELLS))
     args = ap.parse_args()
+    import importlib
     import jax
     import jax.numpy as jnp
     import numpy as np
     from benchmark import harness
-    from benchmark.models import (_train, olmoe_1b_7b as olmoe,
-                                  smallthinker_21b_a3b as adapter)
-    from benchmark.reference import smallthinker_21b_a3b as reference
+    from benchmark.models import _train, olmoe_1b_7b as olmoe
+    name, mix, config_of, builder, test_module, toy_of = CELLS[args.cell]
+    adapter = importlib.import_module("benchmark.models." + name)
+    reference = importlib.import_module("benchmark.reference." + name)
     on_chip = jax.default_backend() == "tpu"
-    config = harness.load_json("benchmark/configs/smallthinker_21b_a3b.json")
-    traffic = harness.load_traffic("lm_s16384")
+    config = harness.load_json(f"benchmark/configs/{name}.json")
+    traffic = harness.load_traffic(mix)
     if not on_chip:                      # a rehearsal of the path, no reading
         sys.path.insert(0, os.path.join(ROOT, "tests", "benchmark"))
-        import test_smallthinker_cell
-        config, toy = test_smallthinker_cell.toy_smallthinker()
+        config, toy = getattr(importlib.import_module(test_module), toy_of)()
         traffic.update(seq_len=toy["seq_len"],
                        reference_q_block=toy["reference_q_block"])
     tol = config["loss_tolerance"]
@@ -54,13 +69,12 @@ def main():
     from paddle_tpu.framework import (Program, Scope, program_guard,
                                       scope_guard)
     from paddle_tpu.models import transformer as T
-    cfg = adapter.smallthinker_config(config)
+    cfg = getattr(adapter, config_of)(config)
     q_block = traffic["reference_q_block"]
     for seed in map(int, args.seeds.split(",")):
         scope, main_p, startup = Scope(), Program(), Program()
         with scope_guard(scope), program_guard(main_p, startup):
-            T.build_smallthinker_pretrain(cfg, traffic["seq_len"],
-                                          is_test=True)
+            getattr(T, builder)(cfg, traffic["seq_len"], is_test=True)
             _train.executor(on_chip).run(
                 startup, scope=scope, seed=harness.exe_seed(
                     traffic["weights_seed"]))
@@ -120,7 +134,8 @@ def main():
                 f["top_k_differ_share"] > tol["top_k_differ_share"],
             "hidden_relative": f["hidden_rel_others"] > tol["hidden_relative"],
             "first_training_loss_relative":
-                a["loss_rel"] > tol["first_training_loss_relative"],
+                a["loss_rel"] > tol.get("first_training_loss_relative",
+                                        float("inf")),
             "first_hidden_relative":
                 a["hidden_rel_all"] > tol["first_hidden_relative"]}
         for kind in ("rest", "experts", "router"):
@@ -135,7 +150,7 @@ def main():
         print(json.dumps(out), flush=True)
         os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
         with open(os.path.join(ROOT, "chiprun_out",
-                               "smallthinker_tolerance_probe.jsonl"),
+                               f"{args.cell}_tolerance_probe.jsonl"),
                   "a") as fh:
             fh.write(json.dumps(out) + "\n")
         del params, g_ref

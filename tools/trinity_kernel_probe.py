@@ -54,14 +54,15 @@ def aot(args, F):
     group = args.heads // args.kv_heads
 
     def s(heads):
-        return jax.ShapeDtypeStruct((heads, args.seq, 128), jnp.bfloat16,
-                                    sharding=one)
+        return jax.ShapeDtypeStruct((heads, args.seq, args.head_dim),
+                                    jnp.bfloat16, sharding=one)
     for window in _windows(args):
         print(json.dumps({"window": window}), flush=True)
         probe.aot_forward(
             F, probe._blocks(args.fwd_blocks), lambda bq, bk: jax.jit(
                 lambda q, k, v: F._flash_fwd_pallas(
-                    q, k, v, None, True, 128 ** -0.5, bq, bk, 0, False,
+                    q, k, v, None, True, args.head_dim ** -0.5, bq, bk, 0,
+                    False,
                     window, group)
             ).lower(s(args.heads), s(args.kv_heads), s(args.kv_heads)))
 
@@ -72,6 +73,9 @@ def main():
     ap.add_argument("--heads", type=int, default=32)
     ap.add_argument("--kv_heads", type=int, default=4)
     ap.add_argument("--window", type=int, default=2048)
+    ap.add_argument("--head_dim", type=int, default=128,
+                    help="the heads' width (LFM2: --seq 16384 --heads 32 "
+                    "--kv_heads 8 --head_dim 64 --window 0)")
     ap.add_argument("--blocks", default="",
                     help="bq_fwd,bk_fwd,bq_bwd,bk_bwd[;...] beside defaults")
     ap.add_argument("--impls", default="fused,combined,split",
@@ -101,7 +105,8 @@ def main():
         args.seq, args.window, args.iters = 64, 16, 1
         args.fwd_blocks = "16,16;32,16"
     key = jax.random.PRNGKey(0)
-    shape = lambda h: (1, h, args.seq, 128 if not interpret else 16)  # noqa
+    shape = lambda h: (1, h, args.seq,  # noqa
+                       args.head_dim if not interpret else 16)
     q, do = (jax.random.normal(jax.random.fold_in(key, i), shape(args.heads),
                                jnp.bfloat16) for i in (0, 1))
     k, v = (jax.random.normal(jax.random.fold_in(key, i),
