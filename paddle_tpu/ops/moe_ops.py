@@ -38,9 +38,16 @@ was before they existed, bit for bit):
   about twice even routing's share, doubling, up to ``S * min(k, E_here)``,
   the most a routing can send here), and the router's own count of the held
   rows picks the shortest that holds them all (``jax.lax.switch`` over
-  copies of each row gather, of the gate's pass and of each weighted sum,
-  one a length; the grouped matmuls stay outside at the longest length,
-  since they visit the held rows' tiles and no other whatever lies behind).
+  copies of each row gather and of the gate's pass, one a length; the
+  grouped matmuls stay outside at the longest length, since they visit the
+  held rows' tiles and no other whatever lies behind).  The two un-sorts
+  (the forward's weighted sum over a token's slots, the backward's gather
+  back to tokens) read the held rows alone on a TPU, whatever the rung:
+  ``pallas/held_rows.py`` copies a row for each held slot and none for a
+  slot held elsewhere, so they cost by the rows routed here and not by the
+  ``S * k`` slots (PR 42).  A ladder with a rung short enough for XLA's own
+  gather to read fast (``_SHORT_SOURCE_BYTES``: Trinity's, JoyAI's) keeps
+  that gather, a copy a rung in a switch, as every ladder does off the TPU.
   Every rung holds every held row, so the held experts drop nothing
   whatever the load; ``moe_ffn_grad`` picks its rung from the same count.
   ``Saved`` keeps the longest rung's shapes: a shorter rung writes the
@@ -165,9 +172,12 @@ MOE_LOWERINGS_CTR = _monitor.REGISTRY.counter(
     "router's score, ladder = the static lengths the row buffer of a chip's "
     "share chooses from, shortest first ('' where every expert is held), "
     "act = the gate branch's activation, router_input = x where the router "
-    "reads the experts' rows and own where it has an input of its own",
+    "reads the experts' rows and own where it has an input of its own, "
+    "unsort = what the two un-sorts (the forward's weighted sum, the "
+    "backward's gather back to tokens) cost by: rows, a share's held rows "
+    "alone (pallas/held_rows.py), or slots, XLA's gather over every slot",
     ("impl", "experts", "top_k", "held", "score_func", "ladder", "act",
-     "router_input"))
+     "router_input", "unsort"))
 
 
 MOE_ROUTED_ROWS_CTR = _monitor.REGISTRY.counter(
@@ -354,11 +364,16 @@ def held_ladder(S, k, n_held, E):
     k * n_held / E`` (fresh weights send 0.7 to 1.5 of it, PERF.md) doubling
     up to a quarter of the last, each a multiple of the held path's row tile
     so that a rung multiplies the same rows in the same tiles as the full
-    buffer does.  A quarter: on a v5e at both cells' sizes a rung of half
-    the buffer is no faster than the whole (the un-sorts cost per slot
-    unless their source is short, and every rung has its copies to the
-    front to pay: tools/trinity_experts_sweep.py --lengths, PERF.md section
-    6, PR 35); so at most three lengths under the last, here two."""
+    buffer does.  A quarter: on a v5e at Trinity's and JoyAI's sizes a rung
+    of half the buffer was no faster than the whole when the rule was set
+    (PR 35: XLA's un-sorts cost per slot unless their source is short, and
+    every rung has its copies to the front to pay:
+    tools/trinity_experts_sweep.py --lengths, PERF.md section 6).  Since PR
+    42 the un-sorts of a long rung read the held rows alone and no longer
+    cost by the slot; what a rung still buys is the row gathers, the gate's
+    passes and the cotangents at its length, and the rule was not measured
+    again (PERF.md section 7, row 28); so at most three lengths under the
+    last, here two."""
     full, tile = S * min(k, n_held), _GMM_TILING_HELD[0]
     rung = -(-2 * S * k * n_held // (E * tile)) * tile
     ladder = []
@@ -445,6 +460,31 @@ def _gate_backward(g, u, dh, dt, act="silu"):
             (dhf * gf * sig).astype(dt))
 
 
+#: the longest source XLA's row gather reads fast, 16384 rows of 2048 bf16:
+#: on a v5e it moves a slot in 14.5 ns from a source up to that long and in 41
+#: ns from a longer one (PR 35), and the kernel that reads the held rows alone
+#: beats the former only where next to no slot is held (PR 42:
+#: tools/trinity_experts_sweep.py --unsorts, PERF.md section 6)
+_SHORT_SOURCE_BYTES = 16384 * 2048 * 2
+
+
+def _rows_unsort(S, k, d, ladder, dt):
+    """What brings a share's held rows back to their tokens at the cost of
+    those rows (``pallas/held_rows.py``'s ``held_rows_to_tokens``), where the
+    two un-sorts go through it: on a TPU, at shapes the kernel takes, and
+    where even the ladder's first rung is a longer source than XLA's gather
+    reads fast (LFM2's one rung, SmallThinker's two).  None elsewhere: the
+    un-sorts stay XLA's gather over every slot from the rung's rows, the
+    lowering as it was (Trinity's and JoyAI's first rungs are its fast
+    side).  A function of the shapes alone."""
+    from ..device import on_tpu
+    from ..pallas import held_rows
+    if on_tpu() and held_rows.fits(S, k, d, ladder[-1], dt) and \
+            ladder[0] * d * jnp.dtype(dt).itemsize > _SHORT_SOURCE_BYTES:
+        return held_rows.held_rows_to_tokens
+    return None
+
+
 def _moe_dtype(ctx, x):
     amp = getattr(ctx, "amp", False) and x.dtype in (jnp.float32,
                                                      jnp.bfloat16)
@@ -486,11 +526,14 @@ def _moe_ffn(ctx, ins, attrs):
     one of ``held_ladder``'s static lengths, the shortest that holds the
     rows the router counted for the held experts (the held slots are sorted
     to the front, so every rung is dropless and gives what the longest
-    gives); the row gather, the gate's pass and the weighted sum are each
-    lowered once a rung under a ``jax.lax.switch``; the sort, the router and
-    the grouped matmuls once (these visit the held rows' tiles and no other,
-    whatever the buffer's length).  Under AMP the rows and the expert
-    weights are bf16 with float32 accumulation."""
+    gives); the row gather and the gate's pass are each lowered once a rung
+    under a ``jax.lax.switch``; the sort, the router and the grouped matmuls
+    once (these visit the held rows' tiles and no other, whatever the
+    buffer's length), and so is the weighted sum where every rung is a long
+    source on a TPU: ``pallas/held_rows.py`` reads the held rows alone
+    (``_rows_unsort``; elsewhere it is a gather over every slot, a copy a
+    rung in a switch of its own).  Under AMP the rows and the expert weights
+    are bf16 with float32 accumulation."""
     x, wr = X(ins, "X"), X(ins, "RouterW")
     wg, wu, wd = X(ins, "GateW"), X(ins, "UpW"), X(ins, "DownW")
     k = int(attrs["top_k"])
@@ -505,13 +548,15 @@ def _moe_ffn(ctx, ins, attrs):
     act = _act_of(attrs)
     router_x = X(ins, "RouterX")
     ladder = () if n_held == E else held_ladder(S, k, n_held, E)
+    unsort = _rows_unsort(S, k, d, ladder, dt) if ladder else None
     if not getattr(ctx, "is_abstract", False):
         MOE_LOWERINGS_CTR.inc(
             impl=_experts_impl(dt), experts=str(E), top_k=str(k),
             held=str(n_held),
             score_func=attrs.get("score_func", "softmax") or "softmax",
             ladder=".".join(map(str, ladder)), act=act,
-            router_input="x" if router_x is None else "own")
+            router_input="x" if router_x is None else "own",
+            unsort="slots" if unsort is None else "rows")
         if ladder:
             _TRACED_LADDERS[S * k, E, n_held] = ladder
     xt = x.reshape(S, d)
@@ -562,8 +607,11 @@ def _moe_ffn(ctx, ins, attrs):
             return jnp.sum(ys.reshape(S, k, d) * top_p[:, :, None], axis=1)
 
         with jax.named_scope("combine"):
-            out = _over_rungs(ladder, held_rows, weighted_sum,
-                              y, place, held, top_p)
+            if unsort is None:
+                out = _over_rungs(ladder, held_rows, weighted_sum,
+                                  y, place, held, top_p)
+            else:                  # whatever the rung: it reads the held rows
+                out = unsort((y,), place, held, k, top_p)
     return {"Out": [out.astype(x.dtype).reshape(B, T, d)], "LbLoss": [lb],
             "ZLoss": [z], "ExpertLoad": [load],
             "TopExperts": [top_e.astype(jnp.int32).reshape(B, T, k)],
@@ -603,8 +651,11 @@ def _moe_ffn_grad(ctx, ins, attrs):
     computed again for its vjp; each grouped
     matmul is transposed by ``jax.vjp`` at its saved operands, whose unused
     primal XLA removes; the transposes of the two row gathers are gathers
-    (every row of ``x`` is read exactly ``k`` times).  An output whose
-    gradient nobody produced counts as zero."""
+    (every row of ``x`` is read exactly ``k`` times; with a share of the
+    experts the gather back to tokens reads the held rows alone where the
+    forward's weighted sum does, and then adds each row's two parts as it
+    reads them).  An output whose gradient nobody produced counts as
+    zero."""
     x, wr = X(ins, "X$X"), X(ins, "X$RouterW")
     weights = [X(ins, "X$" + s) for s in ("GateW", "UpW", "DownW")]
     order, xs, g, u, y = ins["Saved"]
@@ -666,19 +717,39 @@ def _moe_ffn_grad(ctx, ins, attrs):
         # the forward's: the same rule over the same count
         ladder = held_ladder(S, k, n_held, E)
         full = xs.shape[0]
+        unsort = _rows_unsort(S, k, d, ladder, dt)
 
-        def cotangents(rows, order, place, slot_held, top_p, d_out, y):
-            order, y = order[:rows], y[:rows]
+        def cot_rows(rows, order, slot_held, d_out):
             row_held = jnp.take(slot_held, order)[:, None]
             d_rows = jnp.zeros((rows, d), f32) if d_out is None else \
                 jnp.where(row_held, jnp.take(d_out.reshape(S, d), order // k,
                                              axis=0).astype(f32), 0.0)
-            d_top_p = jnp.where(slot_held, jnp.take(jnp.sum(
+            return row_held, d_rows
+
+        def weights_cot(rows, row_held, d_rows, place, slot_held, y):
+            return jnp.where(slot_held, jnp.take(jnp.sum(
                 d_rows * jnp.where(row_held, y.astype(f32), 0.0), axis=-1),
                 jnp.minimum(place, rows - 1)), 0.0).reshape(S, k)
+
+        def rows_cot(d_rows, order, top_p):
             dy = (d_rows * jnp.take(top_p.reshape(R), order)[:, None]
                   ).astype(dt)
-            return d_top_p, _front(dy, full)
+            return _front(dy, full)
+
+        def cotangents(rows, order, place, slot_held, top_p, d_out, y):
+            order, y = order[:rows], y[:rows]
+            row_held, d_rows = cot_rows(rows, order, slot_held, d_out)
+            return weights_cot(rows, row_held, d_rows, place, slot_held, y), \
+                rows_cot(d_rows, order, top_p)
+
+        def weights_cot_alone(rows, order, place, slot_held, d_out, y):
+            row_held, d_rows = cot_rows(rows, order[:rows], slot_held, d_out)
+            return weights_cot(rows, row_held, d_rows, place, slot_held,
+                               y[:rows])
+
+        def rows_cot_alone(rows, order, slot_held, top_p, d_out):
+            _, d_rows = cot_rows(rows, order[:rows], slot_held, d_out)
+            return rows_cot(d_rows, order[:rows], top_p)
 
         with jax.named_scope("combine"):
             slot_held = (top_e.reshape(R) >= offset) & \
@@ -686,8 +757,26 @@ def _moe_ffn_grad(ctx, ins, attrs):
             place = _inverse_permutation(order)
             load = jax.lax.dynamic_slice_in_dim(load, offset, n_held)
             held_rows = jnp.sum(load)
-            d_top_p, dy = _over_rungs(ladder, held_rows, cotangents, order,
-                                      place, slot_held, top_p, d_out, y)
+            if unsort is None:
+                d_top_p, dy = _over_rungs(ladder, held_rows, cotangents,
+                                          order, place, slot_held, top_p,
+                                          d_out, y)
+            else:
+                # the weights' cotangent in a switch of its own and dy's
+                # behind it, so that y's last reader is done before dy's
+                # full-length buffer exists: in one switch the two buffers
+                # stood beside every layer's Saved at the step's peak, which
+                # SmallThinker's step showed once its un-sorts' temporaries
+                # no longer made XLA rematerialise (15.04 GB for the parent's
+                # 14.59: PERF.md section 6, PR 42).  One rung: one
+                # computation, as it was
+                d_top_p = _over_rungs(ladder, held_rows, weights_cot_alone,
+                                      order, place, slot_held, d_out, y)
+                if len(ladder) > 1:
+                    d_top_p, order, d_out = jax.lax.optimization_barrier(
+                        (d_top_p, order, d_out))
+                dy = _over_rungs(ladder, held_rows, rows_cot_alone, order,
+                                 slot_held, top_p, d_out)
 
         with jax.named_scope("experts"):
             # the gate's output again, and not the forward's kept: XLA would
@@ -697,12 +786,27 @@ def _moe_ffn_grad(ctx, ins, attrs):
             h = _gate_front(ladder, held_rows,
                             *jax.lax.optimization_barrier((g, u)), dt, act)
             dh, d_wd = transposed(h, wd, dy)
+            if unsort is not None:
+                # both readers of dy before the gate's backward, and below
+                # the weights' gradients (the last readers of xs) before the
+                # rows': with the split above, this op's full-length
+                # buffers no longer stand beside every layer's Saved at the
+                # step's peak (SmallThinker's step 14.41 GB for the parent's
+                # 14.59, the compiler's count: PERF.md section 6, PR 42)
+                dh, d_wd = jax.lax.optimization_barrier((dh, d_wd))
             dg, du = _over_rungs(
                 ladder, held_rows, lambda rows, g, u, dh: tuple(
                     _front(a, full) for a in _gate_backward(
                         g[:rows], u[:rows], dh[:rows], dt, act)), g, u, dh)
-            dxs_g, d_wg = transposed(xs, wg, dg)
-            dxs_u, d_wu = transposed(xs, wu, du)
+            if unsort is None:
+                dxs_g, d_wg = transposed(xs, wg, dg)
+                dxs_u, d_wu = transposed(xs, wu, du)
+            else:
+                d_wg, d_wu, dg, du = jax.lax.optimization_barrier(
+                    (transposed(xs, wg, dg)[1], transposed(xs, wu, du)[1],
+                     dg, du))
+                dxs_g, dxs_u = (transposed(xs, w, c)[0]
+                                for w, c in ((wg, dg), (wu, du)))
 
         def back_to_tokens(rows, dxs, place, slot_held):
             # gathered as stored, widened after: the same numbers as
@@ -712,14 +816,20 @@ def _moe_ffn_grad(ctx, ins, attrs):
                 0.0).reshape(S, k, d).sum(axis=1)
 
         with jax.named_scope("dispatch"):
-            # the two parts are added over the whole buffer, outside the
-            # switch: with the sum inside it the TPU compiler scheduled the
-            # last block's step so that a forward matmul it rematerialised
-            # read its weight behind that weight's AdamW update (one
-            # gradient leaf a third off; tools/joyai_step_aot.py's
-            # reads_after_update, PERF.md section 6, PR 35)
-            dx = _over_rungs(ladder, held_rows, back_to_tokens,
-                             dxs_g + dxs_u, place, slot_held)
+            if unsort is None:
+                # the two parts are added over the whole buffer, outside the
+                # switch: with the sum inside it the TPU compiler scheduled
+                # the last block's step so that a forward matmul it
+                # rematerialised read its weight behind that weight's AdamW
+                # update (one gradient leaf a third off;
+                # tools/joyai_step_aot.py's reads_after_update, PERF.md
+                # section 6, PR 35)
+                dx = _over_rungs(ladder, held_rows, back_to_tokens,
+                                 dxs_g + dxs_u, place, slot_held)
+            else:
+                # each held row's two parts added as it is read: no pass
+                # over the whole buffer, and no switch
+                dx = unsort((dxs_g, dxs_u), place, slot_held, k)
 
     with jax.named_scope("router"):
         zero = jnp.zeros((), f32)

@@ -273,23 +273,35 @@ def test_tpu_compiler_takes_the_options_and_fuses_all_reduces(topo):
     assert fused and all(mb >= 1.0 for _, _, _, mb, _ in fused), asked
 
 
-@pytest.mark.parametrize("router_outputs,width,ladder", [
-    (128, 1024, (16384, 65536)),                   # Trinity-Mini's share
-    (256, 768, (8192, 16384, 65536))])             # JoyAI-LLM-Flash's
-def test_tpu_compiler_takes_the_held_paths_ladder(
-        topo, monkeypatch, router_outputs, width, ladder):
-    """``moe_ffn`` + ``moe_ffn_grad`` over a chip's 16 experts at the two
-    cells' real sizes, bf16 through megablox, compiled for one described
-    chip: the ladder is the shapes', each row movement sits under a
-    conditional of that many branches, the rows reach the full-length
-    buffers through the ``moe_front`` kernel (the compiler takes it at both
-    widths), and the grouped matmuls are lowered once, not once a rung."""
+#: name -> (tokens, model width, experts a token, held experts, router
+#: outputs, expert width, the ladder): the four shares that run the held path
+HELD_SHARES = {
+    "trinity": (8192, 2048, 8, 16, 128, 1024, (16384, 65536)),
+    "joyai": (8192, 2048, 8, 16, 256, 768, (8192, 16384, 65536)),
+    "lfm2": (16384, 2048, 4, 8, 32, 1792, (65536,)),
+    "smallthinker": (16384, 2560, 6, 8, 64, 768, (24576, 98304)),
+}
+
+
+@pytest.mark.parametrize("share", sorted(HELD_SHARES))
+def test_tpu_compiler_takes_the_held_paths_ladder(topo, monkeypatch, share):
+    """``moe_ffn`` + ``moe_ffn_grad`` over a chip's share of the experts at
+    the four cells' real sizes, bf16 through megablox, compiled for one
+    described chip: the ladder is the shapes', each row movement that walks
+    a rung sits under a conditional of that many branches (none where the
+    ladder has one rung: LFM2's), the rows reach the full-length buffers
+    through the ``moe_front`` kernel, the grouped matmuls are lowered once,
+    not once a rung, and the two un-sorts are the ``moe_held_rows`` kernel
+    (PR 42: the compiler takes its row-group copies and SMEM tables), one a
+    direction and outside any conditional, where every rung is a long source
+    (LFM2's, SmallThinker's); where the first rung is short (Trinity's,
+    JoyAI's) the un-sorts keep XLA's gather in two conditionals more."""
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
     from paddle_tpu import device
     from paddle_tpu.ops import moe_ops
     monkeypatch.setattr(device, "on_tpu", lambda: True)
-    S, d, k, held = 8192, 2048, 8, 16
+    S, d, k, held, router_outputs, width, ladder = HELD_SHARES[share]
     assert moe_ops.held_ladder(S, k, held, router_outputs) == ladder
     ctx = types.SimpleNamespace(amp=False, is_abstract=True)
     attrs = {"top_k": k, "score_func": "sigmoid", "norm_topk_prob": True,
@@ -313,11 +325,22 @@ def test_tpu_compiler_takes_the_held_paths_ladder(
         text = jax.jit(step).lower(*shapes).compile().as_text()
     import re
     conds = re.findall(r"branch_computations=\{([^}]*)\}", text)
-    assert [c.count("%") for c in conds] == [len(ladder)] * 7   # 3 + 4
+    # forward: the row gather, the gate's pass and the weighted sum; grad op:
+    # the cotangents, the gate's pass again, its backward and the gather back
+    # to tokens.  By the row the two un-sorts leave their switches and the
+    # cotangents take two (the weights' before dy's)
+    by_rows = moe_ops._rows_unsort(S, k, d, ladder, jnp.bfloat16) is not None
+    assert by_rows == (ladder[0] * d * 2 > 16384 * 2048 * 2)
+    assert [c.count("%") for c in conds] == [len(ladder)] * (
+        0 if len(ladder) == 1 else 6 if by_rows else 7)
     # six fronts a rung below the longest; nine grouped matmuls in all
     assert len(re.findall(r"%moe_front[\w.]* = ", text)) == \
         6 * (len(ladder) - 1)
     assert len(re.findall(r"%(?:jvp_jit_)?t?gmm[\w.]* = ", text)) == 9
+    entry = text[text.index("\nENTRY"):]
+    assert len(re.findall(r"%moe_held_rows[\w.]* = ", entry)) == \
+        len(re.findall(r"%moe_held_rows[\w.]* = ", text)) == \
+        (2 if by_rows else 0)
 
 
 #: name -> (heads, KV heads, T, d_qk, d_v, window, forward blocks or None for

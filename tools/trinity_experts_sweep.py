@@ -18,14 +18,19 @@ where the compiler refuses the tiling), also in
 ``chiprun_out/trinity_experts_sweep.jsonl``.
 
 With ``--lengths`` it sweeps the row buffer's length instead (PR 35: the
-table ``ops/moe_ops.py:held_ladder`` was chosen from), at Trinity-Mini's
-share (16 of 128 experts of width 1024) and JoyAI-LLM-Flash's (16 of 256 of
-width 768), 8192 tokens of 2048, 8 experts a token.  Per length, in
-milliseconds: the held path's four row movements alone as the lowering
+table ``ops/moe_ops.py:held_ladder`` was chosen from), at the four shares
+that run the held path (``SHARES``: Trinity-Mini's 16 of 128 experts of width
+1024 and JoyAI-LLM-Flash's 16 of 256 of width 768, 8192 tokens of 2048, 8
+experts a token; LFM2's 8 of 32 of width 1792, 16384 tokens of 2048, 4 a
+token; SmallThinker's 8 of 64 of width 768, 16384 tokens of 2560, 6 a token;
+``--shares`` picks among them).  Per length, in
+milliseconds: the held path's four row movements alone as XLA
 writes them (``gather_rows``: the forward's row gather; ``unsort_sum``:
 un-sort, mask, weighted sum; ``cot_gather``: the backward's cotangent gather
 with the weights' gradient and ``dy``; ``gather_back``: the gather back to
-tokens), ``empty_gmm`` (the nine grouped-matmul calls of a layer's forward
+tokens), the two un-sorts by the held row (PR 42, ``pallas/held_rows.py``:
+``rows_sum`` beside ``unsort_sum``, ``rows_back`` beside ``gather_back``, which
+do not depend on the length), ``empty_gmm`` (the nine grouped-matmul calls of a layer's forward
 and backward with no row routed here, at that many buffer rows),
 ``fronts`` (the six copies that put a rung's rows at the front of a
 full-length buffer: the rows, the gate's output twice, ``dy`` and the gate's
@@ -36,6 +41,21 @@ share (``even``) and one that sends them nothing (``none``).
 
     chiprun -- python3 tools/trinity_experts_sweep.py \\
         --lengths 4096,8192,16384,32768,65536
+
+With ``--unsorts`` it times the two un-sorts alone, XLA's by the slot against
+the kernel's by the held row (PR 42), per share and load: no slot held, a
+fresh router's even share, one and a half times it, and every slot of every
+token held.  ``slots_*``: XLA's gather, mask and sum at the rung of
+``held_ladder`` that load selects (``slots_back`` with the whole-buffer
+``dxs_g + dxs_u`` before it, as the lowering had it); ``rows_*``: the kernel;
+``same_*``: whether the two gave the same float32 to the bit on this device
+(where not, ``ulps_*``: the largest distance in units in the last place of
+the sum of the terms' magnitudes, and ``differ_*``: the share of the elements
+that differ).
+One more row at OLMoE's sizes (131072 slots of 2048, every expert held),
+timed only: no lowering takes the kernel there.
+
+    chiprun -- python3 tools/trinity_experts_sweep.py --unsorts
 """
 
 import argparse
@@ -64,9 +84,103 @@ def loads(n_held, seed=0):
             "even": even.astype(np.int32), "heavy": heavy.astype(np.int32)}
 
 
-#: (name, router outputs, expert width) of the two shares that run the held
-#: path; tokens, model width, experts a token and held experts are shared
-SHARES = (("trinity_mini", 128, 1024), ("joyai_llm_flash", 256, 768))
+#: (name, router outputs, expert width, tokens, model width, experts a token,
+#: held experts) of the four shares that run the held path
+SHARES = (("trinity_mini", 128, 1024, 8192, 2048, 8, 16),
+          ("joyai_llm_flash", 256, 768, 8192, 2048, 8, 16),
+          ("lfm2_8b_a1b", 32, 1792, 16384, 2048, 4, 8),
+          ("smallthinker_21b_a3b", 64, 768, 16384, 2560, 6, 8))
+#: OLMoE's layer, every expert held: the un-sorts timed only (--unsorts)
+ALL_HELD = ("olmoe_1b_7b", 64, 1024, 16384, 2048, 8, 64)
+TOY = ("toy", 32, 128, 64, 128, 4, 4)
+
+
+def routing(rng, S, k, E, G, share):
+    """``top_e`` [S, k] int32 whose slots choose a held expert (``< G``) with
+    probability ``share`` (a token's experts distinct), or uniformly over all
+    ``E`` where ``share`` is None."""
+    import numpy as np
+    if share is None:
+        return np.stack([rng.choice(E, k, replace=False)
+                         for _ in range(S)]).astype(np.int32)
+    n = np.clip(rng.binomial(k, share, S), max(0, k - (E - G)), min(k, G))
+    return np.stack([np.concatenate([
+        rng.choice(G, h, replace=False),
+        G + rng.choice(E - G, k - h, replace=False)]) for h in n]
+    ).astype(np.int32)
+
+
+def sweep_unsorts(args, timed, emit):
+    """The two un-sorts alone, by the slot and by the held row, per share and
+    load (module docstring)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.ops import moe_ops
+    from paddle_tpu.pallas import held_rows as hr
+    interpret = jax.default_backend() != "tpu"
+    f32, bf = jnp.float32, jnp.bfloat16
+    rng = np.random.default_rng(0)
+    shares = (TOY,) if interpret else \
+        tuple(s for s in SHARES + (ALL_HELD,) if s[0] in args.shares)
+    for name, E, _, S, d, k, G in shares:
+        full = S * min(k, G)
+        ladder = (full,) if G == E else moe_ops.held_ladder(S, k, G, E)
+        ks = jax.random.split(jax.random.key(2), 4)
+        y, a, b = (jax.random.normal(kk, (full, d), bf) for kk in ks[:3])
+        top_p = jax.random.uniform(ks[3], (S, k), f32)
+        even = G / E
+        loads = {"all": 1.0} if G == E else {
+            "none": 0.0, "even": None, "heavy": 1.5 * even, "all": 1.0}
+        for load, share in loads.items():
+            held, _, place = jax.jit(
+                lambda t: moe_ops._held_slots(t, 0, G, k))(
+                    jnp.asarray(routing(rng, S, k, E, G, share)))
+            held_rows = int(held.sum())
+            rows = ladder[int(moe_ops.held_rung(held_rows, ladder))]
+
+            def slots_sum(y, place, held, top_p):
+                ys = jnp.take(y[:rows], jnp.minimum(place, rows - 1), axis=0)
+                ys = jnp.where(held[:, None], ys.astype(f32), 0.0)
+                return jnp.sum(ys.reshape(S, k, d) * top_p[:, :, None],
+                               axis=1)
+
+            def slots_back(a, b, place, held):
+                return jnp.where(held[:, None], jnp.take(
+                    (a + b)[:rows], jnp.minimum(place, rows - 1),
+                    axis=0).astype(f32), 0.0).reshape(S, k, d).sum(axis=1)
+
+            def rows_sum(y, place, held, top_p):
+                return hr.held_rows_to_tokens((y,), place, held, k, top_p,
+                                              interpret=interpret)
+
+            def rows_back(a, b, place, held):
+                return hr.held_rows_to_tokens((a, b), place, held, k,
+                                              interpret=interpret)
+
+            rec = {"share": name, "load": load, "slots": S * k,
+                   "held_rows": held_rows, "rung": rows}
+            calls = {"sum": (slots_sum, rows_sum, (y, place, held, top_p)),
+                     "back": (slots_back, rows_back, (a, b, place, held))}
+            for what, (old, new, operands) in calls.items():
+                old, new = jax.jit(old), jax.jit(new)
+                rec["slots_" + what] = timed(old, *operands)
+                want, got = (np.asarray(f(*operands), np.float64)
+                             for f in (old, new))
+                rec["same_" + what] = bool((want == got).all())
+                if not rec["same_" + what]:
+                    # how far apart, in units in the last place of the sum
+                    # of the terms' magnitudes (a token's terms cancel: the
+                    # sum's own last place says nothing), and in how many
+                    # of the elements
+                    size = np.asarray(jax.jit(old)(*(
+                        jnp.abs(a) if a.dtype == bf else a
+                        for a in operands)), np.float32)
+                    rec["ulps_" + what] = float((np.abs(got - want) /
+                                                 np.spacing(size)).max())
+                    rec["differ_" + what] = float((want != got).mean())
+                rec["rows_" + what] = timed(new, *operands)
+            emit(rec)
 
 
 def sweep_lengths(args, timed, emit):
@@ -80,15 +194,16 @@ def sweep_lengths(args, timed, emit):
     from paddle_tpu.ops import moe_ops
     mb = importlib.import_module(
         "jax.experimental.pallas.ops.tpu.megablox.gmm")
+    from paddle_tpu.pallas import held_rows as hr
     interpret = jax.default_backend() != "tpu"
-    S, d, k, G = (64, 128, 4, 4) if interpret else (8192, 2048, 8, args.held)
-    shares = (("toy", 32, 128),) if interpret else SHARES
-    full = S * min(k, G)
+    shares = (TOY,) if interpret else \
+        tuple(s for s in SHARES if s[0] in args.shares)
     f32, bf = jnp.float32, jnp.bfloat16
     ctx = types.SimpleNamespace(amp=False, is_abstract=True)
     tiling = (128, 128, 128) if interpret else moe_ops._GMM_TILING_HELD
     rng = np.random.default_rng(0)
-    for name, E, f in shares:
+    for name, E, f, S, d, k, G in shares:
+        full = S * min(k, G)
         ks = jax.random.split(jax.random.key(1), 8)
         xt = jax.random.normal(ks[0], (1, S, d), bf)
         d_out = jax.random.normal(ks[1], (1, S, d), bf)
@@ -114,8 +229,7 @@ def sweep_lengths(args, timed, emit):
                 [v[0] for v in bwd.values()]
 
         # one routing's slot tables, for the pieces
-        top_e = jnp.asarray(np.stack([rng.choice(E, k, replace=False)
-                                      for _ in range(S)]).astype(np.int32))
+        top_e = jnp.asarray(routing(rng, S, k, E, G, None))
         held, order, place = jax.jit(
             lambda t: moe_ops._held_slots(t, 0, G, k))(top_e)
         top_p = jax.random.uniform(ks[6], (S, k), f32)
@@ -152,6 +266,14 @@ def sweep_lengths(args, timed, emit):
                     dxs[:rows], jnp.minimum(place, rows - 1),
                     axis=0).astype(f32), 0.0).reshape(S, k, d).sum(axis=1)
 
+            def rows_sum(y, place, held, top_p):
+                return hr.held_rows_to_tokens((y,), place, held, k, top_p,
+                                              interpret=interpret)
+
+            def rows_back(dxs, place, held):
+                return hr.held_rows_to_tokens((dxs, dxs), place, held, k,
+                                              interpret=interpret)
+
             def empty_gmm(y, h, wg, wu, wd, load):
                 kw = dict(interpret=interpret)
                 wgb, wub, wdb = wg.astype(bf), wu.astype(bf), wd.astype(bf)
@@ -178,6 +300,8 @@ def sweep_lengths(args, timed, emit):
                 "cot_gather": (cot_gather, d_out, y, order, place, held,
                                top_p),
                 "gather_back": (gather_back, y, place, held),
+                "rows_sum": (rows_sum, y, place, held, top_p),
+                "rows_back": (rows_back, y, place, held),
                 "empty_gmm": (empty_gmm, y, h, wg, wu, wd, zero),
                 "fronts": (fronts, y, h)}
             for piece, (fn, *a) in pieces.items():
@@ -211,6 +335,15 @@ def main():
                                                  s.split(",")],
                     help="sweep the row buffer's length (comma-separated "
                     "rows) instead of the tiles")
+    ap.add_argument("--unsorts", action="store_true",
+                    help="time the two un-sorts alone, by the slot (XLA) "
+                    "and by the held row (pallas/held_rows.py), per share "
+                    "and load, instead of the tiles")
+    ap.add_argument("--shares", type=lambda s: s.split(","),
+                    default=[s[0] for s in SHARES + (ALL_HELD,)],
+                    help="the shares --lengths and --unsorts run "
+                    "(comma-separated names of SHARES; olmoe_1b_7b: "
+                    "--unsorts' row with every expert held)")
     args = ap.parse_args()
     import jax
     import jax.numpy as jnp
@@ -250,6 +383,11 @@ def main():
         out.write(line + "\n")
         out.flush()
 
+    if args.unsorts:
+        if interpret:
+            args.calls = 1
+        sweep_unsorts(args, timed, emit)
+        return 0
     if args.lengths:
         if interpret:
             args.calls, args.lengths = 1, [128, 256]
