@@ -289,8 +289,13 @@ def rope(x, head_dim, theta=10000.0, name=None, interleaved=False):
     axis 2.  ``interleaved=True`` pairs adjacent dimensions ``(2i, 2i + 1)``
     instead (``rope_interleave`` of the DeepSeek family); a model that
     rotates only a slice of the head splits it off first
-    (``models.transformer.latent_attention``).  The default leaves the op
-    and its lowering as they were."""
+    (``models.transformer.latent_attention``).  Neither form fuses with the
+    projection or the norm round it: on a TPU the op is one pass of
+    ``pallas/rope.py`` over the tensor where its shape allows (a head of 64
+    lanes, 4-D, or of whole 128-lane tiles; a length that divides into
+    blocks; one device) and XLA's four to nine passes elsewhere (the op's
+    docstring, ``ops/attention_ops.py``); its gradient is ``rope_grad``, the
+    same rotation turned back."""
     helper = LayerHelper("rope", name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
     attrs = {"head_dim": int(head_dim), "theta": float(theta)}

@@ -190,30 +190,24 @@ def _ring_attention(ctx, ins, attrs):
                                     sm_scale=sm_scale)]}
 
 
-@register_op("rope")
-def _rope(ctx, ins, attrs):
-    """Rotary position embedding (Su et al. 2021) in the rotate-half
-    convention over the whole head: X is [batch, T, n * head_dim], the
-    position is the index along axis 1, and within each head dimension ``i``
-    pairs with ``i + head_dim / 2`` at the angle ``pos * theta^(-2i /
-    head_dim)``.  Angles, sines and the rotation are float32; the output
-    has the input's dtype.  Applied before the head split so that it fuses
-    with the projection's epilogue and the QK-norm; a 4-D X is [batch,
-    heads, T, head_dim], after the split (where a per-head norm comes
-    first), with the position along axis 2.  ``interleaved`` (default
-    false: the lowering above, unchanged): dimension ``2i`` pairs with ``2i +
-    1`` at the same angle, the pairing of the DeepSeek family's
-    ``rope_interleave`` (its code permutes each pair's members to the two
-    halves and rotates halves; permuted alike on Q and K the scores are
-    those of this pairwise rotation).  ``head_dim`` may be a slice of the
-    head: the caller splits the rotary part off and hands that over."""
-    x = X(ins, "X")
-    dh = int(attrs["head_dim"])
-    theta = float(attrs.get("theta", 10000.0))
+ROPE_LOWERINGS_CTR = _monitor.REGISTRY.counter(
+    "paddle_tpu_rope_lowerings_total",
+    "rope and rope_grad lowerings by the form they got (kernel: one pass of "
+    "pallas/rope.py over the tensor; xla: the jnp form, wherever the "
+    "kernel's conditions fail: no TPU, a head that is not 64 (4-D) or whole "
+    "128-lane tiles wide, a ragged length, a mesh of several devices), the "
+    "pairing (half: i with i + head_dim / 2; interleaved: 2i with 2i + 1) "
+    "and the width of what is rotated (head_dim) — counted while tracing, "
+    "once per compile of a block that holds the op, nothing per step",
+    ("form", "pairing", "width"))
+
+
+def _rope_xla(x, dh, theta, interleaved):
+    """The jnp form: float32 angles, sines and rotation, X's dtype out."""
+    from ..pallas.rope import angles
     half = dh // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / dh)
     t = x.shape[2] if x.ndim == 4 else x.shape[1]
-    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    ang = angles(t, dh, theta)
     if x.ndim == 4:                              # [b, heads, t, dh]
         cos, sin, xf = jnp.cos(ang), jnp.sin(ang), x.astype(jnp.float32)
     else:
@@ -221,12 +215,95 @@ def _rope(ctx, ins, attrs):
         cos = jnp.cos(ang)[None, :, None, :]
         sin = jnp.sin(ang)[None, :, None, :]
         xf = x.astype(jnp.float32).reshape(b, t, d // dh, dh)
-    if attrs.get("interleaved"):
+    if interleaved:
         # adjacent pairs: (2i, 2i + 1) turn by the angle of frequency i
         x1, x2 = xf[..., 0::2], xf[..., 1::2]
         out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-        return {"Out": [out.reshape(x.shape).astype(x.dtype)]}
+        return out.reshape(x.shape).astype(x.dtype)
     x1, x2 = xf[..., :half], xf[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                           axis=-1)
-    return {"Out": [out.reshape(x.shape).astype(x.dtype)]}
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _rope_call(ctx, x, attrs, transpose):
+    """What the op and its grad op share: ``x`` turned (``transpose``: turned
+    back) in the form its shape gets, and one count of the lowering.  The
+    kernel where everything the lowering can see allows it: a TPU, one
+    device, ``pallas.rope.fits``; else the jnp form, whose transpose is
+    ``jax.vjp``'s."""
+    from ..device import on_tpu
+    from ..pallas import rope as kernel
+    dh = int(attrs["head_dim"])
+    theta = float(attrs.get("theta", 10000.0))
+    interleaved = bool(attrs.get("interleaved"))
+    # shape inference runs this lowering abstractly: the jnp form, uncounted
+    abstract = getattr(ctx, "is_abstract", False)
+    mesh = ctx.mesh
+    use_kernel = not abstract and (mesh is None or mesh.size == 1) and \
+        kernel.fits(x.shape, dh, x.dtype) and on_tpu()
+    if not abstract:
+        ROPE_LOWERINGS_CTR.inc(
+            form="kernel" if use_kernel else "xla",
+            pairing="interleaved" if interleaved else "half", width=str(dh))
+    if use_kernel:
+        return kernel.rope(x, dh, theta, interleaved, transpose=transpose)
+    if transpose:
+        return jax.vjp(lambda v: _rope_xla(v, dh, theta, interleaved),
+                       x)[1](x)[0]
+    return _rope_xla(x, dh, theta, interleaved)
+
+
+def _rope(ctx, ins, attrs):
+    """Rotary position embedding (Su et al. 2021) in the rotate-half
+    convention over the whole head: X is [batch, T, n * head_dim], the
+    position is the index along axis 1, and within each head dimension ``i``
+    pairs with ``i + head_dim / 2`` at the angle ``pos * theta^(-2i /
+    head_dim)``.  Angles, sines and the rotation are float32; the output
+    has the input's dtype.  A 4-D X is [batch, heads, T, head_dim], after
+    the head split (where a per-head norm comes first), with the position
+    along axis 2.  ``interleaved`` (default false): dimension ``2i`` pairs
+    with ``2i + 1`` at the same angle, the pairing of the DeepSeek family's
+    ``rope_interleave`` (its code permutes each pair's members to the two
+    halves and rotates halves; permuted alike on Q and K the scores are
+    those of this pairwise rotation).  ``head_dim`` may be a slice of the
+    head: the caller splits the rotary part off and hands that over.
+
+    Neither form fuses with what is round it.  On a TPU, on one device, a
+    head of 64 (4-D) or whole 128-lane tiles and a length that divides into
+    blocks get ``pallas/rope.py``: one pass over the tensor, a custom call
+    that the norm before it and the flash kernel behind it end at.  Every
+    other shape keeps the jnp form, which XLA:TPU compiles to a float32
+    copy of the tensor, its two halves and a pad that glues them (or two
+    gathers and a relayout for ``interleaved``): four to nine times the
+    tensor's bytes (PERF.md section 5, PR 43), with the norm before the 3-D
+    form riding its first fusion and nothing of the projection's matmul.
+    ``paddle_tpu_rope_lowerings_total`` says which form each lowering got.
+    The gradient is the op's own, ``rope_grad``: the same rotation turned
+    back over Out's gradient, and nothing of the forward."""
+    return {"Out": [_rope_call(ctx, X(ins, "X"), attrs, transpose=False)]}
+
+
+def _rope_grad_maker(op, block, no_grad_set):
+    # a rotation is linear in X: its gradient reads Out's gradient alone
+    def wanted(n):
+        v = block.var(n) if block.has_var(n) else None
+        return n not in no_grad_set and not (v is not None
+                                             and v.stop_gradient)
+    return [{"type": "rope_grad",
+             "inputs": {"OG$Out": [grad_var_name(n)
+                                   for n in op.output("Out")]},
+             "outputs": {"IG$X": [grad_var_name(n) if wanted(n) else ""
+                                  for n in op.input("X")]},
+             "attrs": dict(op.attrs)}]
+
+
+register_op("rope", _rope, grad_maker=_rope_grad_maker)
+
+
+@register_op("rope_grad")
+def _rope_grad(ctx, ins, attrs):
+    """The transpose of ``rope``: Out's gradient turned back by the same
+    angles, in the form the forward's shape got."""
+    return {"IG$X": [_rope_call(ctx, X(ins, "OG$Out"), attrs,
+                                transpose=True)]}

@@ -392,3 +392,44 @@ def test_tpu_compiler_takes_the_flash_forward_with_lse_rows(
     assert not re.search(rf"f32\[{h},{t},128\]", text)
     assert asked == [reckon(d_qk, d_v, *(blocks or (1024, 1024)), 2)]
     assert 16 << 20 < asked[0] <= F._FUSED_VMEM_SHARE * F._VMEM_BYTES
+
+
+#: name -> (shape, head_dim, interleaved): what the five decoder cells' steps
+#: hand the ``rope`` op, bf16
+ROPE_CASES = {
+    "trinity_q": ((1, 32, 8192, 128), 128, False),
+    "joyai_q_rope": ((1, 32, 8192, 64), 64, True),
+    "olmoe_q": ((4, 4096, 2048), 128, False),
+    "lfm2_k": ((1, 8, 16384, 64), 64, False),
+    "smallthinker_q": ((1, 28, 16384, 128), 128, False),
+}
+
+
+@pytest.mark.parametrize("transpose", [False, True])
+@pytest.mark.parametrize("case", sorted(ROPE_CASES))
+def test_tpu_compiler_takes_the_rope_kernel(topo, case, transpose):
+    """``pallas/rope.py`` at the cells' real shapes, compiled for one
+    described chip, the rotation and its transpose: Mosaic takes the lane
+    rotations at 64 and 128 lanes, both pairings and both ranks; the module
+    is one custom call beside the tables' fusions, and where a head fills
+    whole tiles it has no copy, no stand-alone convert and no temporary (a
+    64-wide tensor alone lies with T in the lanes at the module's edge,
+    which a step's neighbours do not ask for: PERF.md section 5, PR 43)."""
+    import re
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu.pallas import rope as kernel
+    shape, head_dim, interleaved = ROPE_CASES[case]
+    assert kernel.fits(shape, head_dim, jnp.bfloat16)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                             sharding=SingleDeviceSharding(topo.devices[0]))
+    with _no_compile_cache():
+        compiled = jax.jit(lambda v: kernel.rope(
+            v, head_dim, 10000.0, interleaved, transpose=transpose)
+        ).lower(x).compile()
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY"):]
+    assert len(re.findall(r" custom-call\(.*tpu_custom_call", entry)) == 1
+    if head_dim % 128 == 0:
+        assert not re.search(r" = \S+ (copy|convert)\(", entry)
+        assert compiled.memory_analysis().temp_size_in_bytes == 0

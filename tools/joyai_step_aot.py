@@ -8,8 +8,9 @@ arguments, the count of XLA's own rematerialised instructions (``.remat`` in
 the compiled text), ``reads_after_update`` (must be empty: a donated
 parameter read again behind its optimizer update) and which backward kernel
 each ``flash_attention_grad`` of the step got
-(``paddle_tpu_flash_bwd_kernel_total``) and the form each forward lowering
-writes ``lse`` in (``paddle_tpu_flash_lowerings_total{lse}``), with and without
+(``paddle_tpu_flash_bwd_kernel_total``), the form each forward lowering
+writes ``lse`` in (``paddle_tpu_flash_lowerings_total{lse}``) and the form each
+``rope`` and ``rope_grad`` got (``paddle_tpu_rope_lowerings_total``), with and without
 ``--recompute``: whether the step fits beside its state, and what fitting
 costs (PERF.md section 7, row 31).  Nothing runs: no time comes from this.  The adapter has the
 recomputing step only (the traffic file's); without ``--recompute`` this
@@ -193,12 +194,22 @@ def main():
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
 
+    def counted(counter, *names):
+        """A counter's series that this process moved, by those labels."""
+        return {"/".join(labels[n] for n in names): int(cell.get())
+                for labels, cell in counter.series() if cell.get()}
+
     def flash_fwd_lse():
         """The step's forward lowerings (a recomputed clone counts) by the
         form lse leaves the kernel in, window and widths."""
-        return {"/".join(labels[n] for n in ("lse", "window", "widths")):
-                int(cell.get()) for labels, cell in
-                attention_ops.FLASH_LOWERINGS_CTR.series() if cell.get()}
+        return counted(attention_ops.FLASH_LOWERINGS_CTR,
+                       "lse", "window", "widths")
+
+    def rope_lowerings():
+        """The step's rope and rope_grad lowerings (a recomputed clone
+        counts) by form (kernel | xla), pairing and width."""
+        return counted(attention_ops.ROPE_LOWERINGS_CTR,
+                       "form", "pairing", "width")
     if args.lowered:
         text = re.sub(r"loc\(.*?\)", "", cb.jitted.lower(*shapes).as_text())
         with open(args.lowered, "w") as f:
@@ -210,7 +221,8 @@ def main():
             if "custom_call" in line and '"flash_fwd"' in line)
         print(json.dumps({"cell": args.cell, "lowered": args.lowered,
                           "bytes": len(text), "flash_fwd_results": results,
-                          "flash_fwd_lse": flash_fwd_lse()}))
+                          "flash_fwd_lse": flash_fwd_lse(),
+                          "rope_lowerings": rope_lowerings()}))
         return 0
     try:
         compiled = cb.jitted.lower(*shapes).compile()
@@ -234,11 +246,10 @@ def main():
         "remat_instructions": len(re.findall(r"\.remat\d* = ", text)),
         "reads_after_update": reads_after_update(text),
         # which backward each flash_attention_grad lowering of the step got
-        "flash_bwd_kernels": {
-            "/".join(labels[n] for n in ("kernel", "window", "widths")):
-            int(cell.get()) for labels, cell in
-            attention_ops.FLASH_BWD_KERNEL_CTR.series() if cell.get()},
+        "flash_bwd_kernels": counted(attention_ops.FLASH_BWD_KERNEL_CTR,
+                                     "kernel", "window", "widths"),
         "flash_fwd_lse": flash_fwd_lse(),
+        "rope_lowerings": rope_lowerings(),
         "parameters_m": sum(int(np.prod(p.shape))
                             for p in m["parameters"]) / 1e6}))
     return 0
