@@ -179,9 +179,9 @@ def phase_train(cfg, seq_len, batch, steps, place, on_chip, meter):
         # one forward per layer at least: the grad op's generic vjp lowers
         # the forward kernel a second time (24 calls for 12 layers)
         check(kernels.count("flash_fwd") >= cfg.n_layer
-              and kernels.count("flash_bwd_combined") == cfg.n_layer,
+              and kernels.count("flash_bwd_fused") == cfg.n_layer,
               "the lowered step must hold a Mosaic flash forward and a "
-              f"combined backward kernel for every layer, found {kernels}")
+              f"fused backward kernel for every layer, found {kernels}")
         report["mosaic_kernels"] = {n: kernels.count(n)
                                     for n in sorted(set(kernels))}
     report["attention_max_err"] = _attention_parity(on_chip)

@@ -47,10 +47,10 @@ time, before anything is lowered).
   dependency.
 - :mod:`paddle_tpu.analysis.fusion` — the cost-guided training-safe
   graph fusion pass (``FLAGS_graph_fusion``): PDPattern-matched
-  candidates (conv+bn+relu, dense epilogues, embedding+layernorm),
-  static legality analysis with grad-chain rewrite-or-reject, roofline
-  ranking, and the ``FLAGS_fusion_autotune`` measured fallback; runs in
-  ``compiler.optimize``'s pass slot with the verifier before and after.
+  candidates (dense epilogues, embedding+layernorm), static legality
+  analysis with grad-chain rewrite-or-reject and roofline ranking; runs
+  in ``compiler.optimize``'s pass slot with the verifier before and
+  after.
 """
 
 from .comms import (  # noqa: F401

@@ -334,6 +334,8 @@ def attribute(trace: Dict[str, Any]) -> Dict[str, Any]:
         "per_class_share": {c: round(v, 6)
                             for c, v in sorted(share.items())},
         "device_ms_total": round(total_ms, 6),
+        "device_busy_ms": round(_union_ms(
+            [(e["ts"], e["ts"] + e["dur"]) for e in kernels]), 6),
         "unattributed_ms": round(unattributed_ms, 6),
         "idle_frac": round(idle, 6) if idle is not None else None,
         "kernels": sorted(
@@ -561,13 +563,22 @@ def summarize_window(window_dir: str,
 
     # measured MFU: analytic flops/step over measured device-busy time
     # per step x peak.  Steps with zero measured device time drop out
-    # (a window tail can clip a step's kernels).
+    # (a window tail can clip a step's kernels).  The join is by the
+    # step's HOST span, and dispatch is asynchronous: where every kernel
+    # of the window ran after its step's span had closed (a loaded host;
+    # basis "window") the window's own busy time over its steps is what
+    # there is to divide by.
     busy = [r["device_ms"] for r in summary["steps"]
             if r["device_ms"] > 0]
+    mean_busy_ms, basis = None, None
+    if busy:
+        mean_busy_ms, basis = sum(busy) / len(busy), "steps"
+    elif summary["steps"] and summary["device_busy_ms"] > 0:
+        mean_busy_ms = summary["device_busy_ms"] / len(summary["steps"])
+        basis = "window"
     mfu_measured = None
-    if busy and flops_per_step and peak_flops:
-        mean_busy_s = sum(busy) / len(busy) / 1e3
-        mfu_measured = flops_per_step / mean_busy_s / peak_flops
+    if mean_busy_ms and flops_per_step and peak_flops:
+        mfu_measured = flops_per_step / (mean_busy_ms / 1e3) / peak_flops
     spans = [r["span_ms"] for r in summary["steps"] if r["span_ms"] > 0]
     mfu_analytic = None
     if spans and flops_per_step and peak_flops:
@@ -578,6 +589,7 @@ def summarize_window(window_dir: str,
         "peak_flops": peak_flops,
         "mfu_measured": round(mfu_measured, 6)
         if mfu_measured is not None else None,
+        "mfu_basis": basis if mfu_measured is not None else None,
         "mfu_analytic_over_span": round(mfu_analytic, 6)
         if mfu_analytic is not None else None,
     }

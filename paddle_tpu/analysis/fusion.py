@@ -4,9 +4,9 @@ The reference repo's fusion passes (``ir/conv_bn_fuse_pass.cc``,
 ``ir/fc_fuse_pass.cc``, ...) fire unconditionally on any structural
 match; TVM (arxiv 1802.04799) showed cost-driven candidate selection
 beats fixed rewrite rules, and Tensor Processing Primitives
-(arxiv 2104.05755) motivates the fused micro-kernel target shape the
-``paddle_tpu.pallas`` library provides.  This pass combines the three
-ideas into the PR-5 pass-before-lowering slot:
+(arxiv 2104.05755) motivates a few fused op shapes over many small
+ones.  This pass combines the three ideas into the PR-5
+pass-before-lowering slot:
 
 1. **Match** candidate subgraphs with the existing
    ``PDPattern``/``GraphPatternDetector`` machinery:
@@ -36,14 +36,7 @@ ideas into the PR-5 pass-before-lowering slot:
    class is below ``FLAGS_fusion_rank_threshold`` of the step's
    flop+byte budget is not worth a rewrite ("ranked_out").
 
-4. **Autotune** (``FLAGS_fusion_autotune``): a fingerprint+shape-keyed
-   cached micro-benchmark lowers the matched chain and the fused op
-   side by side (both jitted) and applies the rewrite only when the
-   fused kernel measurably beats the XLA default; verdicts persist next
-   to the XLA compile cache (``<FLAGS_xla_compile_cache_dir>/
-   fusion_autotune.json``), so a process restart re-decides nothing.
-   With autotune OFF (the default) the pass applies on static legality
-   + rank alone.
+The pass is static: legality and rank decide, nothing is measured.
 
 Safety rails: the verifier runs before and after the pass, the
 collective fingerprint must be UNCHANGED by fusion (fusion never
@@ -57,10 +50,7 @@ flipping any fusion flag invalidates stale plans.
 
 from __future__ import annotations
 
-import json
-import os
 import threading
-import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -76,14 +66,8 @@ __all__ = [
 _CAND_CTR = _monitor.REGISTRY.counter(
     "paddle_tpu_fusion_candidates_total",
     "graph-fusion candidate decisions by pattern and verdict "
-    "(applied / rejected / ranked_out / autotune_lost / overlapped / "
-    "verify_failed)", ("pattern", "verdict"))
-_AUTOTUNE_CTR = _monitor.REGISTRY.counter(
-    "paddle_tpu_fusion_autotune_total",
-    "fusion autotune micro-benchmark lookups by cache outcome",
-    ("cache",))
-_AUTOTUNE_HIT = _AUTOTUNE_CTR.labels(cache="hit")
-_AUTOTUNE_MISS = _AUTOTUNE_CTR.labels(cache="miss")
+    "(applied / rejected / ranked_out / overlapped / verify_failed)",
+    ("pattern", "verdict"))
 
 #: collective op prefixes fusion must never touch (the fingerprint
 #: invariance check backstops this structurally)
@@ -98,10 +82,8 @@ def config_token() -> tuple:
     plans and ``compiler.optimize`` results keyed on this token are
     invalidated by any fusion-flag change."""
     from ..flags import get_flags
-    fl = get_flags(["FLAGS_graph_fusion", "FLAGS_fusion_autotune",
-                    "FLAGS_fusion_rank_threshold"])
+    fl = get_flags(["FLAGS_graph_fusion", "FLAGS_fusion_rank_threshold"])
     return (bool(fl["FLAGS_graph_fusion"]),
-            bool(fl["FLAGS_fusion_autotune"]),
             float(fl["FLAGS_fusion_rank_threshold"]))
 
 
@@ -115,15 +97,12 @@ class FusionDecision:
     verdict: str                # applied|rejected|ranked_out|...
     rule: Optional[str] = None  # failing legality rule for 'rejected'
     rank: float = 0.0           # per-class roofline share in [0, 1]
-    autotune: Optional[dict] = None   # {fused_ms, base_ms, cached}
 
     def as_dict(self) -> dict:
         out = {"pattern": self.pattern, "anchor": self.anchor,
                "verdict": self.verdict, "rank": round(self.rank, 4)}
         if self.rule:
             out["rule"] = self.rule
-        if self.autotune:
-            out["autotune"] = dict(self.autotune)
         return out
 
 
@@ -153,9 +132,7 @@ class _Candidate:
     ``fwd_ops``/``grad_ops`` are the op Nodes the rewrite removes;
     ``internal`` the var Nodes that disappear (their consumers must all
     be inside the candidate); ``build(graph)`` applies the forward AND
-    grad rewrite; ``base_descs``/``fused_descs`` are
-    (type, inputs, outputs, attrs) op descs the autotuner replays;
-    ``ext_inputs`` maps external input names to (shape, dtype)."""
+    grad rewrite."""
 
     def __init__(self, pattern: str, op_class: str, anchor: str):
         self.pattern = pattern
@@ -167,21 +144,9 @@ class _Candidate:
         self.dead_outputs: List = []    # side-output var nodes that die
         self.reject_rule: Optional[str] = None   # structural pre-reject
         self.build = None               # set by the matcher when legal
-        self.base_descs: List[tuple] = []
-        self.fused_descs: List[tuple] = []
-        self.ext_inputs: Dict[str, tuple] = {}
-        self.shape_key: tuple = ()
 
     def all_ops(self) -> List:
         return self.fwd_ops + self.grad_ops
-
-
-def _desc(op) -> tuple:
-    """Autotune replay desc of one Operator."""
-    return (op.type,
-            {s: list(n) for s, n in op.inputs.items()},
-            {s: list(n) for s, n in op.outputs.items()},
-            {k: v for k, v in op.attrs.items()})
 
 
 def _has_grad_ops(program: Program) -> bool:
@@ -354,7 +319,6 @@ def _match_dense_epilogue(graph, program, fetch_names) -> List[_Candidate]:
                     str(drop_n.op.attrs.get("dropout_implementation",
                                             "downgrade_in_infer"))
                 if drop_n is not None else "downgrade_in_infer",
-                "use_pallas": False,
             }
             grad_chain = _dense_grad_chain(graph, mm_type, out_node,
                                            drop_n, act_n)
@@ -416,8 +380,7 @@ def _match_embedding_layer_norm(graph, program,
 
     The BERT-shaped chain is ``emb + pos [+ sent] -> layer_norm``; the
     fused op gathers the rows, applies the adds, and normalizes in one
-    op (the Pallas fused LN backward becomes reachable via autotune).
-    The chain side must be each add's X slot with default axis, and
+    op.  The chain side must be each add's X slot with default axis, and
     every collapsed intermediate is legality-checked like any other
     internal var."""
     from ..framework import ir
@@ -485,7 +448,6 @@ def _match_embedding_layer_norm(graph, program,
             "padding_idx": la.get("padding_idx", -1),
             "epsilon": ln_n.op.attrs.get("epsilon", 1e-5),
             "begin_norm_axis": ln_n.op.attrs.get("begin_norm_axis", 1),
-            "use_pallas": False,
         }
         ins = {"Ids": [ids_n], "W": [w_node], "Addends": list(addends)}
         if scale_n is not None:
@@ -554,14 +516,14 @@ def _embedding_ln_grad_chain(graph, y_node, ln_n, chain_ops, lt_n):
 
 
 # ---------------------------------------------------------------------------
-# shared candidate finishing: grads, descs, shapes, build closure
+# shared candidate finishing: grads, build closure
 # ---------------------------------------------------------------------------
 
 def _finish_candidate(graph, program, cand, *, fused_type, fused_ins,
                       fused_outs, fused_attrs, out_node, grad_chain,
                       grad_ig, addend_grads=None):
-    """Attach the grad chain, autotune descs, and the build() closure to
-    a structurally-matched candidate.  ``grad_ig`` maps fused input slot
+    """Attach the grad chain and the build() closure to a
+    structurally-matched candidate.  ``grad_ig`` maps fused input slot
     -> (original grad op type, its IG slot) for recovering the external
     gradient names the fused grad op must keep producing."""
     if cand.reject_rule:
@@ -598,43 +560,6 @@ def _finish_candidate(graph, program, cand, *, fused_type, fused_ins,
                 grad_internal.append(v)
     cand.grad_internal = grad_internal
 
-    # autotune replay material
-    block = program.global_block()
-    # the micro-benchmark must replay in the SAME dtype regime the real
-    # dispatch will use: an amp program runs its chains through bf16
-    # casts, and benching them in f32 would hand the (internally
-    # bf16-casting) Pallas kernels a dtype advantage they won't have
-    cand.amp = bool(program._attrs.get("amp", False))
-    cand.base_descs = [_desc(n.op) for n in cand.fwd_ops]
-    fused_in_names = {s: [v.name for v in vs]
-                      for s, vs in fused_ins.items()}
-    fused_out_names = {s: [v.name for v in vs]
-                       for s, vs in fused_outs.items()}
-    cand.fused_descs = [(fused_type, fused_in_names, fused_out_names,
-                         dict(fused_attrs))]
-    ext = {}
-    internal_names = {v.name for v in cand.internal}
-    for n in cand.fwd_ops:
-        for v in n.inputs:
-            if v.name in internal_names or v.name in ext:
-                continue
-            var = v.var if v.var is not None else (
-                block.var(v.name) if block.has_var(v.name) else None)
-            if var is None or var.shape is None:
-                cand.ext_inputs = {}
-                break
-            ext[v.name] = (tuple(var.shape), str(var.dtype or "float32"))
-        else:
-            continue
-        break
-    else:
-        cand.ext_inputs = ext
-    out_var = getattr(out_node, "var", None)
-    cand.shape_key = tuple(sorted(
-        (n, s) for n, (s, _) in (cand.ext_inputs or {}).items())) + (
-        ("out", tuple(out_var.shape) if out_var is not None and
-         out_var.shape else ()),)
-
     # an op fused from what apply_recompute emitted is itself recomputed
     # work (the executor names it ``pt.rc/...`` by this mark, as it names a
     # fused grad op ``pt.bwd/...`` by the role below); a clone reads stored
@@ -644,11 +569,10 @@ def _finish_candidate(graph, program, cand, *, fused_type, fused_ins,
         f"{fused_type}: fused from recomputed and first-run ops"
     mark = {RECOMPUTED_ATTR: True} if marks.pop() else {}
 
-    def build(g, use_pallas=False):
-        attrs = dict(fused_attrs, use_pallas=bool(use_pallas))
+    def build(g):
         fused_node = g.create_op_node(fused_type, inputs=fused_ins,
                                       outputs=fused_outs,
-                                      attrs=dict(attrs, **mark))
+                                      attrs=dict(fused_attrs, **mark))
         doomed = list(cand.fwd_ops) + list(cand.internal) + \
             list(cand.dead_outputs)
         if cand.grad_ops:
@@ -694,7 +618,7 @@ def _finish_candidate(graph, program, cand, *, fused_type, fused_ins,
                 real = [n for n in addend_nodes if n is not None]
                 if real:
                     g_outs["IG$Addends"] = real
-            g_attrs = dict(attrs)
+            g_attrs = dict(fused_attrs)
             g_attrs["__fwd_type__"] = fused_type
             # a grad op like the ones it replaces (backward.py tags those
             # through _op_role_guard): clone(for_test) prunes by this role
@@ -779,204 +703,6 @@ def _legality(cand: _Candidate, graph, program, fetch_names,
 
 
 # ---------------------------------------------------------------------------
-# autotune
-# ---------------------------------------------------------------------------
-
-_AUTOTUNE_MEM: Dict[str, dict] = {}     # guarded-by: _AUTOTUNE_LOCK
-_AUTOTUNE_LOADED = [False]              # guarded-by: _AUTOTUNE_LOCK
-_AUTOTUNE_LOCK = threading.Lock()
-
-
-def _autotune_path() -> Optional[str]:
-    from ..flags import get_flags
-    d = get_flags("FLAGS_xla_compile_cache_dir")[
-        "FLAGS_xla_compile_cache_dir"]
-    return os.path.join(str(d), "fusion_autotune.json") if d else None
-
-
-def _device_key() -> str:
-    """Autotune cache key component naming the ACTUAL hardware:
-    ``<device_kind>x<device_count>`` (e.g. ``TPU_v5ex4``, ``cpux8``).
-    A backend name alone ("tpu") would let a v4 verdict steer a v5e —
-    different MXU shapes, different winners (ROADMAP carried-over
-    follow-on)."""
-    import jax
-    try:
-        devs = jax.devices()
-        kind = str(devs[0].device_kind).replace(" ", "_")
-        return f"{kind}x{len(devs)}"
-    except Exception:
-        return str(jax.default_backend())
-
-
-def _migrate_autotune_key(key: str) -> str:
-    """Re-key a pre-device-kind cache entry: old keys carried the bare
-    backend name ("cpu"/"gpu"/"tpu") in slot 3; entries recorded on THIS
-    backend migrate to the current :func:`_device_key` (best available
-    interpretation — the measurements came from some device of this
-    backend), foreign-backend entries are kept as-is for their own
-    process to migrate."""
-    import jax
-    try:
-        parts = json.loads(key)
-    except ValueError:
-        return key
-    if (isinstance(parts, list) and len(parts) == 5
-            and parts[3] in ("cpu", "gpu", "tpu")
-            and parts[3] == jax.default_backend()):
-        parts[3] = _device_key()
-        return json.dumps(parts, default=str)
-    return key
-
-
-def _autotune_load_locked():   # guarded-by-caller: _AUTOTUNE_LOCK
-    if _AUTOTUNE_LOADED[0]:
-        return
-    _AUTOTUNE_LOADED[0] = True
-    path = _autotune_path()
-    if not path:
-        return
-    try:
-        with open(path) as f:
-            data = json.load(f)
-        if not isinstance(data, dict):
-            return
-        # two passes so a measurement already taken under a new-style
-        # key is never clobbered by a migrated old one, regardless of
-        # the entries' order in the file
-        migrated = False
-        deferred = []
-        for k, v in data.items():
-            if not isinstance(v, dict):
-                continue
-            nk = _migrate_autotune_key(k)
-            if nk != k:
-                migrated = True
-                deferred.append((nk, v))
-            else:
-                _AUTOTUNE_MEM.setdefault(k, v)
-        for nk, v in deferred:
-            _AUTOTUNE_MEM.setdefault(nk, v)
-        if migrated:
-            _autotune_persist_locked()   # one-shot cache migration
-    except (OSError, ValueError):
-        pass
-
-
-def _autotune_persist_locked():   # guarded-by-caller: _AUTOTUNE_LOCK
-    path = _autotune_path()
-    if not path:
-        return
-    try:
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        tmp = path + f".tmp.{os.getpid()}"
-        with open(tmp, "w") as f:
-            json.dump(_AUTOTUNE_MEM, f, indent=1, sort_keys=True)
-        os.replace(tmp, path)
-    except OSError:
-        pass          # a read-only cache dir must not fail the compile
-
-
-def _fill_value(name: str, shape, dtype, batch: int):
-    import jax.numpy as jnp
-    import numpy as np
-    rs = tuple(batch if d in (-1, None) else int(d) for d in shape)
-    d = str(dtype)
-    if "int" in d:
-        return jnp.zeros(rs, jnp.int32)
-    # a constant, non-zero fill: the timing does not depend on the values
-    return jnp.full(rs, np.float32(0.5),
-                    jnp.bfloat16 if d == "bfloat16" else jnp.float32)
-
-
-def _replay(descs, env, ctx):
-    """Run a straight-line chain of op descs through the registered
-    lowerings on a value environment — the autotuner's common harness
-    for the base chain and the fused op."""
-    from .. import amp as _amp
-    from ..framework import registry as _reg
-    outs_all = []
-    for typ, ins_names, outs_names, attrs in descs:
-        info = _reg.get_op_info(typ)
-        ins = {s: [env.get(n) for n in names]
-               for s, names in ins_names.items()}
-        if ctx.amp:
-            # the executor's per-op cast (run_op) — the fused lowerings
-            # handle amp internally, exactly as in real dispatch
-            ins = _amp.cast_ins(typ, ins)
-        outs = info.lower(ctx, ins, attrs) or {}
-        for s, names in outs_names.items():
-            for n, v in zip(names, outs.get(s, [])):
-                if n:
-                    env[n] = v
-                    outs_all.append(v)
-    return outs_all
-
-
-def _time_chain(descs, ext_vals, reps=3, amp=False):
-    import jax
-
-    from ..framework.executor import LowerCtx
-
-    names = sorted(ext_vals)
-
-    def run(*arrs):
-        env = dict(zip(names, arrs))
-        return _replay(descs, env, LowerCtx(0, amp=amp))
-
-    fn = jax.jit(run)
-    args = [ext_vals[n] for n in names]
-    jax.block_until_ready(fn(*args))            # compile + warm
-    best = float("inf")
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args))
-        best = min(best, time.perf_counter() - t0)
-    return best * 1e3
-
-
-def _autotune(cand: _Candidate, batch: int) -> Optional[dict]:
-    """Measured fused-vs-base verdict for one candidate, cached on
-    (pattern, shape key, batch, device kind x topology, amp regime).
-    None when the candidate cannot be
-    replayed (unknown shapes) — callers fall back to rank-only."""
-    if not cand.ext_inputs or not cand.base_descs:
-        return None
-    amp = bool(getattr(cand, "amp", False))
-    key = json.dumps([cand.pattern, cand.shape_key, batch, _device_key(),
-                      "amp" if amp else "f32"], default=str)
-    with _AUTOTUNE_LOCK:
-        _autotune_load_locked()
-        hit = _AUTOTUNE_MEM.get(key)
-    if hit is not None:
-        _AUTOTUNE_HIT.inc()
-        return dict(hit, cached=True)
-    _AUTOTUNE_MISS.inc()
-    try:
-        ext_vals = {n: _fill_value(n, s, d, batch)
-                    for n, (s, d) in cand.ext_inputs.items()}
-        # the fused candidate benches its preferred kernel config
-        fused_descs = [(t, i, o, dict(a, use_pallas=True))
-                       for t, i, o, a in cand.fused_descs]
-        base_ms = _time_chain(cand.base_descs, ext_vals, amp=amp)
-        fused_ms = _time_chain(fused_descs, ext_vals, amp=amp)
-    except Exception:
-        return None              # unbenchable: caller falls back
-    rec = {"base_ms": round(base_ms, 4), "fused_ms": round(fused_ms, 4),
-           "win": bool(fused_ms <= base_ms), "cached": False}
-    with _AUTOTUNE_LOCK:
-        _AUTOTUNE_MEM[key] = {k: rec[k] for k in
-                              ("base_ms", "fused_ms", "win")}
-        _autotune_persist_locked()
-    if _monitor.TRACER.enabled:
-        _monitor.TRACER.instant(
-            "fusion.autotune", "compile",
-            {"pattern": cand.pattern, "base_ms": rec["base_ms"],
-             "fused_ms": rec["fused_ms"], "win": rec["win"]})
-    return rec
-
-
-# ---------------------------------------------------------------------------
 # main entry
 # ---------------------------------------------------------------------------
 
@@ -1000,16 +726,13 @@ def clear_cache() -> None:
     with _RESULT_LOCK:
         _RESULT_CACHE.clear()
         _WARNED.clear()
-    with _AUTOTUNE_LOCK:
-        _AUTOTUNE_MEM.clear()
-        _AUTOTUNE_LOADED[0] = False
 
 
 def analyze_program(program: Program, fetch_names=(),
                     batch_size: int = 1) -> FusionReport:
     """Report-only mode for ``tools/analyze.py --fusion``: candidates,
-    legality verdicts, cost ranks and autotune decisions, with NO
-    rewrite applied and no caching."""
+    legality verdicts and cost ranks, with NO rewrite applied and no
+    caching."""
     fetch_names = tuple(
         f.name if hasattr(f, "name") else f for f in (fetch_names or ()))
     _, report = _fuse(program, fetch_names, batch_size, dry_run=True)
@@ -1101,10 +824,8 @@ def _fuse(program: Program, fetch_names, batch: int,
     from . import cost as _cost
     from . import verifier as _verifier
 
-    fl = get_flags(["FLAGS_fusion_autotune",
-                    "FLAGS_fusion_rank_threshold"])
-    autotune_on = bool(fl["FLAGS_fusion_autotune"])
-    threshold = float(fl["FLAGS_fusion_rank_threshold"])
+    threshold = float(get_flags("FLAGS_fusion_rank_threshold")[
+        "FLAGS_fusion_rank_threshold"])
 
     report = FusionReport()
     with _monitor.TRACER.span("fusion.plan", "compile",
@@ -1146,7 +867,7 @@ def _fuse(program: Program, fetch_names, batch: int,
             return max(fshare.get(c.op_class, 0.0),
                        bshare.get(c.op_class, 0.0))
 
-        applied: List[Tuple[_Candidate, bool]] = []
+        applied: List[_Candidate] = []
         taken: set = set()
         for cand in sorted(candidates, key=rank_of, reverse=True):
             rank = rank_of(cand)
@@ -1164,18 +885,9 @@ def _fuse(program: Program, fetch_names, batch: int,
             if rank < threshold:
                 dec.verdict = "ranked_out"
                 continue
-            use_pallas = False
-            if autotune_on:
-                verdict = _autotune(cand, batch)
-                if verdict is not None:
-                    dec.autotune = verdict
-                    if not verdict["win"]:
-                        dec.verdict = "autotune_lost"
-                        continue
-                    use_pallas = True
             dec.verdict = "applied"
             taken.update(n.id for n in cand.all_ops())
-            applied.append((cand, use_pallas))
+            applied.append(cand)
 
         if dry_run or not applied:
             report.applied = len(applied) if dry_run else 0
@@ -1183,8 +895,8 @@ def _fuse(program: Program, fetch_names, batch: int,
                 program._attrs["fusion"] = report.as_dict()
             return program, report
 
-        for cand, use_pallas in applied:
-            cand.build(graph, use_pallas=use_pallas)
+        for cand in applied:
+            cand.build(graph)
         fused = graph.to_program()
         report.applied = len(applied)
 

@@ -181,21 +181,13 @@ _DEFAULTS: Dict[str, Any] = {
     "FLAGS_profile_sample_max_windows": 8,
     # cost-guided graph fusion (analysis.fusion): the master gate for
     # the training-safe fusion pass in compiler.optimize's
-    # pass-before-lowering slot (conv+bn+relu, matmul+bias+act+dropout,
-    # embedding+layernorm -> fused Pallas-backed ops).  Default on:
-    # with autotune off the pass applies on static legality + roofline
-    # rank alone, and every fused lowering is an exact composition of
-    # the unfused ops.  Executor dispatch plans and compiled programs
-    # key on the fusion config, so flipping any of these invalidates
-    # stale plans.
+    # pass-before-lowering slot (matmul+bias+act+dropout,
+    # embedding+layernorm -> one fused op each).  Default on: the pass
+    # applies on static legality + roofline rank alone, and every fused
+    # lowering is an exact composition of the unfused ops.  Executor
+    # dispatch plans and compiled programs key on the fusion config, so
+    # flipping either of these invalidates stale plans.
     "FLAGS_graph_fusion": True,
-    # measured fallback: micro-benchmark each legal candidate (fused op
-    # vs the XLA default chain, fingerprint+shape-keyed, persisted next
-    # to the XLA compile cache) and rewrite only when the fused kernel
-    # wins — makes a fused-program regression structurally impossible.
-    # Off by default: the first encounter of each (pattern, shape) pays
-    # two small jit compiles.
-    "FLAGS_fusion_autotune": False,
     # roofline rank threshold: a candidate whose op class is below this
     # share of the program's analytic flop AND byte budget
     # (analysis.cost per-class shares) is not worth a rewrite

@@ -1527,7 +1527,7 @@ class Executor:
         collective = program._attrs.get("collective")
         coll_tok = (tuple(sorted(collective.items()))
                     if collective else None)
-        # fusion config in the key: a FLAGS_graph_fusion/_autotune/
+        # fusion config in the key: a FLAGS_graph_fusion/
         # _rank_threshold flip changes what _optimized/fuse_program
         # produce without touching the program fingerprint — stale plans
         # would silently run the old rewrite

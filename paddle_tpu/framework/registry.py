@@ -53,9 +53,17 @@ def register_op(type: str, lower: Callable = None, *, infer: Callable = None,
     """Register an op lowering.  Usable as decorator or call.
 
     lower(ctx, ins, attrs) -> outs, where ins/outs are {slot: [jax arrays]}.
-    Raw ops instead get lower(ctx, block, op, state).
+    Raw ops instead get lower(ctx, block, op, state).  One type has one
+    lowering: a second registration by another function raises (import
+    order would otherwise pick which one a program runs).
     """
     def deco(fn):
+        held = _REGISTRY.get(type)
+        if held is not None and held.lower is not fn:
+            raise ValueError(
+                f"op {type!r} is already registered by "
+                f"{held.lower.__module__}.{held.lower.__qualname__}; "
+                f"{fn.__module__}.{fn.__qualname__} may not replace it")
         _REGISTRY[type] = OpInfo(type, fn, infer, grad_maker, no_grad,
                                  stateful_rng, raw)
         return fn

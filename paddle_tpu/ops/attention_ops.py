@@ -47,10 +47,9 @@ FLASH_GRAD_LOWERINGS_CTR = _monitor.REGISTRY.counter(
 FLASH_BWD_KERNEL_CTR = _monitor.REGISTRY.counter(
     "paddle_tpu_flash_bwd_kernel_total",
     "flash_attention_grad lowerings by the backward they got: fused (one "
-    "pass, a head's dQ resident in VMEM), combined (one pass, dK/dV "
-    "partials in HBM), split (a dQ pass and a dK/dV pass: what a sequence "
-    "too long for the fused accumulator runs) or jax (the blockwise "
-    "fallback: a bias, or no TPU) — counted beside "
+    "pass, a head's dQ resident in VMEM), split (a dQ pass and a dK/dV "
+    "pass: what a sequence too long for the fused accumulator runs) or "
+    "jax (the blockwise fallback: a bias, or no TPU) — counted beside "
     "paddle_tpu_flash_grad_lowerings_total, once per compile, nothing per "
     "step", ("kernel", "window", "widths"))
 

@@ -3,12 +3,9 @@
 Both ops are EXACT compositions of the unfused lowerings they replace
 (same jnp calls, same broadcast/cast order, same tagged-dropout RNG
 stream), so a fused program's loss trajectory matches the unfused one
-bit-for-bit on the default path — the rewrite is then purely a
-canonicalization plus an accounting win.  The Pallas kernels
-(``pallas/dense_epilogue.py``, ``pallas/layer_norm.py``) engage only
-when the fusion autotuner measured them faster for the shape at hand
-(``use_pallas`` attr), which is what makes a fused-program regression
-structurally impossible.
+bit-for-bit and its lowered text is the chain's: the rewrite is a
+canonicalization (fewer program ops to walk, one scope to account a
+dense layer under), not a kernel.
 
 AMP note: the unfused chain casts per op (``amp.cast_ins``: matmul
 white-list → bf16 always; add/act/dropout/LN → bf16 only for ndim≥3
@@ -27,7 +24,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..device import on_tpu
 from ..framework.registry import register_op
 from .common import X, XS, broadcast_to_x
 from .nn_ops import _dropout_keep
@@ -69,26 +65,18 @@ def _fused_dense_act(ctx, ins, attrs):
         x2 = x_c.reshape(int(np.prod(xs[:-1])), xs[-1])
         w2 = w_c
         out_shape = xs[:-1] + w_c.shape[1:]
-    from ..pallas.dense_epilogue import matmul_bias_act, tileable
-    if attrs.get("use_pallas") and act in ("", "relu", "gelu") and \
-            tileable(x2.shape[0]):
-        # compiled on a TPU (a Mosaic refusal raises); interpreted on the
-        # CPU test platform
-        out = matmul_bias_act(x2, w2, b, act=act, approximate=approximate,
-                              interpret=not on_tpu())
-    else:
-        out = x2 @ w2
-        # stage 2 — bias add (+act): AMP casts only 'big' activations
-        big = len(out_shape) >= 3
-        if big:
-            out, b = _amp_pair(ctx, out, b)
-        out = out + broadcast_to_x(out, b,
-                                   int(attrs.get("bias_axis", -1))
-                                   if len(out_shape) == out.ndim else -1)
-        if act == "gelu":
-            out = jax.nn.gelu(out, approximate=approximate)
-        elif act == "relu":
-            out = jax.nn.relu(out)
+    out = x2 @ w2
+    # stage 2 — bias add (+act): AMP casts only 'big' activations
+    big = len(out_shape) >= 3
+    if big:
+        out, b = _amp_pair(ctx, out, b)
+    out = out + broadcast_to_x(out, b,
+                               int(attrs.get("bias_axis", -1))
+                               if len(out_shape) == out.ndim else -1)
+    if act == "gelu":
+        out = jax.nn.gelu(out, approximate=approximate)
+    elif act == "relu":
+        out = jax.nn.relu(out)
     out = out.reshape(out_shape)
 
     # stage 3 — tagged dropout (_dropout_lower's arithmetic on
@@ -112,9 +100,8 @@ def _fused_dense_act(ctx, ins, attrs):
 @register_op("fused_embedding_layer_norm")
 def _fused_embedding_layer_norm(ctx, ins, attrs):
     """lookup_table [+ elementwise_adds] + layer_norm in one op (pattern
-    ``embedding_layer_norm``): the row gather, the embedding-sum adds,
-    and the normalization happen in one lowering, with the Pallas
-    one-pass LN kernel engaged when the autotuner measured it faster."""
+    ``embedding_layer_norm``): the row gather, the embedding-sum adds
+    and the normalization in one lowering."""
     w, ids = X(ins, "W"), X(ins, "Ids")
     addends = XS(ins, "Addends")
     scale, bias = X(ins, "Scale"), X(ins, "Bias")
@@ -141,17 +128,11 @@ def _fused_embedding_layer_norm(ctx, ins, attrs):
     xf = x2.astype(jnp.float32)
     m = jnp.mean(xf, axis=1, keepdims=True)
     v = jnp.var(xf, axis=1, keepdims=True)
-    if attrs.get("use_pallas") and begin == x.ndim - 1 and \
-            scale is not None and bias is not None and \
-            x2.shape[0] % 8 == 0:        # the kernel's row blocks are >= 8
-        from ..pallas.layer_norm import fused_layer_norm
-        y = fused_layer_norm(x, scale, bias, eps=eps).reshape(x2.shape)
-    else:                                # exact _layer_norm replica
-        inv = jax.lax.rsqrt(v + eps)
-        y = (x2 - m.astype(x2.dtype)) * inv.astype(x2.dtype)
-        if scale is not None:
-            y = y * scale.astype(y.dtype).reshape(1, -1)
-        if bias is not None:
-            y = y + bias.astype(y.dtype).reshape(1, -1)
+    inv = jax.lax.rsqrt(v + eps)         # exact _layer_norm replica
+    y = (x2 - m.astype(x2.dtype)) * inv.astype(x2.dtype)
+    if scale is not None:
+        y = y * scale.astype(y.dtype).reshape(1, -1)
+    if bias is not None:
+        y = y + bias.astype(y.dtype).reshape(1, -1)
     return {"Out": [y.reshape(x.shape).astype(x.dtype)],
             "Mean": [m.reshape(lead)], "Variance": [v.reshape(lead)]}

@@ -311,12 +311,12 @@ def _batch_norm_explicit_grad(ctx, ins, attrs):
 
 @register_op("layer_norm")
 def _layer_norm(ctx, ins, attrs):
-    # NOTE: a fused one-pass Pallas LN exists (pallas/layer_norm.py) and
-    # is numerically verified, but end-to-end it LOSES on this model
-    # class: the kernel boundary breaks XLA's producer/consumer fusion
-    # and compute overlap, costing more than the one-pass saves
-    # (BERT-base: 132.7 ms fused vs 127.3 ms XLA — BERT_ABLATION.md).
-    # The XLA lowering below stays the default.
+    # NOTE: a fused one-pass Pallas LN was written, numerically verified,
+    # and LOST end to end on this model class: the kernel boundary breaks
+    # XLA's producer/consumer fusion and compute overlap, costing more
+    # than the one pass saves (BERT-base: 132.7 ms against 127.3 ms XLA,
+    # BERT_ABLATION.md).  It is gone; this XLA lowering is the only one:
+    # measure before writing another.
     x = X(ins, "X")
     scale, bias = X(ins, "Scale"), X(ins, "Bias")
     eps = attrs.get("epsilon", 1e-5)

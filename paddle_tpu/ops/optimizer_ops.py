@@ -75,56 +75,6 @@ def _adam(ctx, ins, attrs):
             "Beta1PowOut": [b1p * b1], "Beta2PowOut": [b2p * b2]}
 
 
-@register_op("fused_adam", no_grad=True)
-def _fused_adam(ctx, ins, attrs):
-    """All-params Adam in ONE update over a flattened concatenation.
-
-    The per-param `adam` op costs ~7.3 ms on the BERT-base step vs a
-    ~3.8 ms HBM floor (BERT_ABLATION.md): ~200 small fused loops, each
-    reading 4 arrays + writing 3, plus 400 scalar beta-pow updates.
-    Concatenating the flat views lets XLA emit a handful of large
-    elementwise kernels (the concat/split reads fuse into the update),
-    and ONE shared beta-pow pair replaces the per-param scalars (all
-    params step together — identical semantics).  No reference
-    counterpart (the 2019 codebase updates per param,
-    operators/optimizers/adam_op.h); TPU-native addition."""
-    from .common import XS
-    ps, gs = XS(ins, "Param"), XS(ins, "Grad")
-    m1s, m2s = XS(ins, "Moment1"), XS(ins, "Moment2")
-    b1p = X(ins, "Beta1Pow").reshape(())
-    b2p = X(ins, "Beta2Pow").reshape(())
-    lr = _lr(ins)
-    b1 = attrs.get("beta1", 0.9)
-    b2 = attrs.get("beta2", 0.999)
-    eps = attrs.get("epsilon", 1e-8)
-
-    def flat(xs, dt=jnp.float32):
-        return jnp.concatenate([x.reshape(-1).astype(dt) for x in xs])
-
-    p = flat(ps)
-    g = flat(gs)
-    m1 = flat(m1s)
-    m2 = flat(m2s)
-    m1n = b1 * m1 + (1 - b1) * g
-    m2n = b2 * m2 + (1 - b2) * jnp.square(g)
-    lr_t = lr * jnp.sqrt(1 - b2p) / (1 - b1p)
-    pn = p - lr_t * m1n / (jnp.sqrt(m2n) + eps)
-
-    def unflat(v, like):
-        outs, off = [], 0
-        for x in like:
-            n = int(x.size)
-            outs.append(v[off:off + n].reshape(x.shape).astype(x.dtype))
-            off += n
-        return outs
-
-    return {"ParamOut": unflat(pn, ps),
-            "Moment1Out": unflat(m1n, m1s),
-            "Moment2Out": unflat(m2n, m2s),
-            "Beta1PowOut": [(b1p * b1).reshape(1)],
-            "Beta2PowOut": [(b2p * b2).reshape(1)]}
-
-
 @register_op("adamw", no_grad=True)
 def _adamw(ctx, ins, attrs):
     p = X(ins, "Param")
@@ -280,11 +230,6 @@ def _proximal_adagrad(ctx, ins, attrs):
     prox = p - eff_lr * g
     p_new = jnp.sign(prox) * jnp.maximum(jnp.abs(prox) - eff_lr * l1, 0.0) / (1 + eff_lr * l2)
     return {"ParamOut": [p_new.astype(p.dtype)], "MomentOut": [m_new]}
-
-
-@register_op("dgc_momentum", no_grad=True)
-def _dgc_momentum(ctx, ins, attrs):
-    return _momentum(ctx, ins, attrs)
 
 
 # -- EMA / model-average support ops ----------------------------------------

@@ -259,64 +259,24 @@ class LarsMomentumOptimizer(Optimizer):
 
 
 class AdamOptimizer(Optimizer):
-    """ref optimizer.py:1271.
-
-    ``fused_flat=True`` replaces the ~N per-param ``adam`` ops with ONE
-    ``fused_adam`` op over all params (flat-concat update, one shared
-    beta-pow pair) — measured lever from BERT_ABLATION.md: the per-param
-    form pays per-array kernel overhead on hundreds of small tensors."""
+    """ref optimizer.py:1271."""
 
     def __init__(self, learning_rate=0.001, beta1=0.9, beta2=0.999,
-                 epsilon=1e-8, lazy_mode=False, fused_flat=False,
-                 fused_max_numel=None, **kw):
+                 epsilon=1e-8, lazy_mode=False, **kw):
         super().__init__(learning_rate, **kw)
         self._beta1, self._beta2, self._epsilon = beta1, beta2, epsilon
-        self._fused_flat = fused_flat
-        # only params up to this size join the flat group: concatenating
-        # the big matrices materializes full copies (measured +27 ms on
-        # BERT-base), while the per-kernel overhead the fusion removes
-        # lives in the hundreds of tiny LN scales/biases
-        self._fused_max_numel = fused_max_numel
-        self._pending_fused = []
-
-    def _use_fused(self, block):
-        from .dygraph import base as _dy_base
-        return self._fused_flat and not _dy_base.in_dygraph_mode()
-
-    def _in_flat_group(self, p):
-        if self._fused_max_numel is None:
-            return True
-        n = 1
-        for d in (p.shape or ()):
-            n *= max(int(d), 1)
-        return n <= self._fused_max_numel
 
     def _create_accumulators(self, block, parameters):
-        flat_first = next((p for p in parameters
-                           if self._in_flat_group(p)), None)
         for p in parameters:
             self._add_accumulator("moment1", p)
             self._add_accumulator("moment2", p)
-            if self._use_fused(block) and self._in_flat_group(p) and \
-                    flat_first is not None:
-                # one shared beta-pow pair: every param steps together
-                self._accumulators.setdefault("beta1_pow_acc", {})[p.name] = \
-                    self._add_accumulator("beta1_pow_acc", flat_first,
-                                          fill_value=self._beta1, shape=[1])
-                self._accumulators.setdefault("beta2_pow_acc", {})[p.name] = \
-                    self._add_accumulator("beta2_pow_acc", flat_first,
-                                          fill_value=self._beta2, shape=[1])
-            else:
-                self._add_accumulator("beta1_pow_acc", p,
-                                      fill_value=self._beta1, shape=[1])
-                self._add_accumulator("beta2_pow_acc", p,
-                                      fill_value=self._beta2, shape=[1])
+            self._add_accumulator("beta1_pow_acc", p,
+                                  fill_value=self._beta1, shape=[1])
+            self._add_accumulator("beta2_pow_acc", p,
+                                  fill_value=self._beta2, shape=[1])
 
     def _append_optimize_op(self, block, param_and_grad):
         p, g = param_and_grad
-        if self._use_fused(block) and self._in_flat_group(p):
-            self._pending_fused.append((p, g))
-            return
         block.append_op(
             "adam",
             inputs={"Param": [p], "Grad": [g],
@@ -330,27 +290,6 @@ class AdamOptimizer(Optimizer):
                      "Moment2Out": [self._get_accumulator("moment2", p)],
                      "Beta1PowOut": [self._get_accumulator("beta1_pow_acc", p)],
                      "Beta2PowOut": [self._get_accumulator("beta2_pow_acc", p)]},
-            attrs={"beta1": self._beta1, "beta2": self._beta2,
-                   "epsilon": self._epsilon})
-
-    def _finish_update(self, block, parameters_and_grads):
-        if not self._pending_fused:
-            return
-        pending, self._pending_fused = self._pending_fused, []
-        ps = [p for p, _ in pending]
-        gs = [g for _, g in pending]
-        m1 = [self._get_accumulator("moment1", p) for p in ps]
-        m2 = [self._get_accumulator("moment2", p) for p in ps]
-        b1p = self._get_accumulator("beta1_pow_acc", ps[0])
-        b2p = self._get_accumulator("beta2_pow_acc", ps[0])
-        block.append_op(
-            "fused_adam",
-            inputs={"Param": ps, "Grad": gs,
-                    "LearningRate": [self._global_learning_rate()],
-                    "Moment1": m1, "Moment2": m2,
-                    "Beta1Pow": [b1p], "Beta2Pow": [b2p]},
-            outputs={"ParamOut": ps, "Moment1Out": m1, "Moment2Out": m2,
-                     "Beta1PowOut": [b1p], "Beta2PowOut": [b2p]},
             attrs={"beta1": self._beta1, "beta2": self._beta2,
                    "epsilon": self._epsilon})
 

@@ -15,7 +15,5 @@ is the dominant one, so this package provides
   and the replacement for its LoD ``sequence_ops`` machinery.
 """
 
-from .dense_epilogue import matmul_bias_act  # noqa
 from .flash_attention import flash_attention, mha_reference  # noqa
-from .layer_norm import fused_layer_norm  # noqa
 from .ring_attention import ring_attention  # noqa

@@ -12,20 +12,16 @@ Forward on TPU runs a Pallas kernel tiled for the MXU (grid over
 elsewhere (CPU tests, interpret debugging) a blockwise ``lax.scan``
 computes the same math.  The backward pass is the standard flash
 recomputation: no O(T^2) attention matrix is ever materialized — only
-per-(q-block, k-block) tiles, rebuilt from the saved logsumexp.  Three
+per-(q-block, k-block) tiles, rebuilt from the saved logsumexp.  Two
 Pallas backwards share that arithmetic and differ in where dQ (summed over
-key blocks) and dK/dV (summed over query blocks) accumulate: "fused"
-rebuilds each live tile pair once and keeps both sums on the chip (dK/dV of
-the key block in scratch, the head's whole dQ in a (Tq, d_qk) float32
-scratch: 5 products a pair, no HBM that grows with T² — what the 8192-token
-tables name, and the one call here that asks for more than Mosaic's default
-16 MiB of scoped VMEM); "combined" rebuilds once too but writes dK/dV as
-float32 partials per query block and sums them outside (5 products and
-2·bh·nq·Tk·(d_qk + d_v)·4 B of HBM: the shorter lengths' choice); "split"
-runs a dQ pass and a dK/dV pass that each rebuild the tile (7 products, no
-memory that grows: what a length too long for the fused accumulator, or for
-the partials' budget, falls back to).  ``_flash_bwd_pallas`` picks from the
-shapes; a bias takes the blockwise jax backward on every backend.
+key blocks) accumulates: "fused" rebuilds each live tile pair once and keeps
+both sums on the chip (dK/dV of the key block in scratch, the head's whole
+dQ in a (Tq, d_qk) float32 scratch: 5 products a pair, no HBM that grows
+with T², and a call that asks for more than Mosaic's default 16 MiB of
+scoped VMEM); "split" runs a dQ pass and a dK/dV pass that each rebuild the
+tile (7 products, no memory that grows: what a length too long for the fused
+accumulator falls back to).  ``_flash_bwd_pallas`` picks from the shapes; a
+bias takes the blockwise jax backward on every backend.
 
 Capability anchor in the reference: attention assembled from separate
 matmul/softmax/dropout ops in its Transformer recipe
@@ -103,8 +99,8 @@ def _pos_mask(iq, ik, block_q, block_k, causal, offset, tq_real, tk_real,
 
 
 def _block_dispatch(causal, pads, iq, ik, block_q, block_k, offset,
-                    compute, on_dead=None, window=None):
-    """The shared live/full block ladder (one definition for all four
+                    compute, window=None):
+    """The shared live/full block ladder (one definition for all three
     kernels): unpadded non-causal blocks take the mask-free path;
     unpadded causal grids run masks only on DIAGONAL blocks (fully-live
     blocks below the diagonal are mask-free, dead blocks above are
@@ -112,8 +108,7 @@ def _block_dispatch(causal, pads, iq, ik, block_q, block_k, offset,
     ``window`` (causal only) the band has a second edge: blocks whose every
     key is ``window`` or more behind every query are dead too, and the mask
     also runs on the blocks that trailing edge crosses.  ``compute``
-    receives masked: bool; ``on_dead`` (optional) must define outputs
-    for skipped blocks."""
+    receives masked: bool."""
     from jax.experimental import pallas as pl
 
     if not causal and not pads:
@@ -143,10 +138,6 @@ def _block_dispatch(causal, pads, iq, ik, block_q, block_k, offset,
             @pl.when(live)
             def _():
                 compute(True)
-        if on_dead is not None:
-            @pl.when(jnp.logical_not(live))
-            def _():
-                on_dead()
         return
     compute(True)
 
@@ -534,80 +525,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dq_ref[0] = (dq_sc[...] * sm_scale).astype(dq_ref.dtype)
 
 
-def _bwd_combined_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, dkp_ref, dvp_ref, dq_sc, *, sm_scale,
-                         causal, block_q, block_k, tq_real, tk_real,
-                         offset, pads, window=None):
-    """ONE recompute per (i, j) block pair: 5 MXU contractions instead of
-    the split kernels' 9 (each pass recomputes S).  Grid (bh, iq, ik) —
-    dq accumulates in VMEM scratch over the inner k axis exactly like
-    _bwd_dq_kernel; dk/dv come out as PER-q-BLOCK PARTIALS (written once
-    per grid step, no revisiting constraint) and are summed over the nq
-    axis by XLA outside.  The partial-sum HBM round trip costs
-    2·bh·nq·Tk·d·4 B — quadratic in T, so big bwd q-blocks matter (the
-    (512,1024)-block first attempt LOST 20 ms at 8k; (1024,512) wins by
-    5–7%, LONGCTX_ABLATION.md), and _flash_bwd_pallas falls back to the
-    split kernels past _COMBINED_PARTIAL_BUDGET."""
-    import jax.lax as lax
-    from jax.experimental import pallas as pl
-
-    iq, ik = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(ik == 0)
-    def _init():
-        dq_sc[...] = jnp.zeros_like(dq_sc)
-
-    def _compute(masked):
-        # sm_scale rides on q (one [bq,d] row multiply): s picks it up
-        # through the contraction, and dk = ds @ (q·scale) carries the
-        # single scale factor dk needs; dq takes its factor on the
-        # accumulated [bq,d] block at finalize — no [bq,bk] tile-wide
-        # multiplies remain (the r5 skeleton microbench showed the
-        # VPU tile work rivals the d=64 MXU time)
-        q = q_ref[0].astype(jnp.float32) * sm_scale
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]                             # (bq, 1)
-        delta = delta_ref[0]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-        if masked:
-            s = jnp.where(_pos_mask(iq, ik, block_q, block_k, causal,
-                                    offset, tq_real, tk_real,
-                                    window=window), s, NEG_INF)
-            p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - lse))
-        else:
-            p = jnp.exp(s - lse)
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dq_sc[...] = dq_sc[...] + lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dvp_ref[0, 0] = lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dvp_ref.dtype)
-        dkp_ref[0, 0] = lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dkp_ref.dtype)
-
-    def _zero_partials():
-        # skipped blocks must still define their partial outputs
-        dkp_ref[0, 0] = jnp.zeros_like(dkp_ref[0, 0])
-        dvp_ref[0, 0] = jnp.zeros_like(dvp_ref[0, 0])
-
-    _block_dispatch(causal, pads, iq, ik, block_q, block_k, offset,
-                    _compute, on_dead=_zero_partials, window=window)
-
-    @pl.when(ik == nk - 1)
-    def _finalize():
-        dq_ref[0] = (dq_sc[...] * sm_scale).astype(dq_ref.dtype)
-
-
 def _bwd_prologue(q, k, v, o, lse, do, block_q, block_k):
-    """Shared pad/delta setup for both backward implementations."""
+    """Shared pad/delta setup of the backward kernels."""
     bh, tq, d = q.shape
     tk = k.shape[1]
     block_q = min(block_q, tq)
@@ -627,75 +546,6 @@ def _bwd_prologue(q, k, v, o, lse, do, block_q, block_k):
     return (q, k, v, do, lse, delta, block_q, block_k,
             tq + pad_q, tk + pad_k)
 
-
-def _flash_bwd_pallas_combined(q, k, v, o, lse, do, causal, sm_scale,
-                               block_q, block_k, offset, interpret,
-                               window=None, group=1):
-    """(dq, dk, dv) via the single-recompute combined kernel; dk and dv are
-    summed over the ``group`` query heads of each KV head with the
-    per-q-block partials."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    bh, tq, d = q.shape
-    d_v = v.shape[2]
-    tk = k.shape[1]
-    tq_real, tk_real = tq, tk
-    (q, k, v, do, lse, delta, block_q, block_k, tqp, tkp) = \
-        _bwd_prologue(q, k, v, o, lse, do, block_q, block_k)
-    nq, nk = tqp // block_q, tkp // block_k
-
-    lse3 = lse[..., None]
-    delta3 = delta[..., None]
-
-    def q_spec(w):
-        return pl.BlockSpec((1, block_q, w), lambda b, i, j: (b, i, 0))
-
-    def k_spec(w):
-        return _k_spec(block_q, block_k, w, window, group, offset, nk)
-
-    def part_spec(w):
-        return pl.BlockSpec((1, 1, block_k, w), lambda b, i, j: (b, i, j, 0))
-    row_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
-    dq, dkp, dvp = pl.pallas_call(
-        functools.partial(_bwd_combined_kernel, sm_scale=sm_scale,
-                          causal=causal, block_q=block_q, block_k=block_k,
-                          tq_real=tq_real, tk_real=tk_real, offset=offset,
-                          pads=tqp != tq_real or tkp != tk_real,
-                          window=window),
-        grid=(bh, nq, nk),
-        in_specs=[q_spec(d), k_spec(d), k_spec(d_v), q_spec(d_v), row_spec,
-                  row_spec],
-        out_specs=[q_spec(d), part_spec(d), part_spec(d_v)],
-        out_shape=[jax.ShapeDtypeStruct((bh, tqp, d), q.dtype),
-                   jax.ShapeDtypeStruct((bh, nq, tkp, d), jnp.float32),
-                   jax.ShapeDtypeStruct((bh, nq, tkp, d_v), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-        name="flash_bwd_combined",
-    )(q, k, v, do, lse3, delta3)
-    if group > 1:
-        dkp = dkp.reshape(bh // group, group * nq, tkp, d)
-        dvp = dvp.reshape(bh // group, group * nq, tkp, d_v)
-    dk = jnp.sum(dkp, axis=1).astype(k.dtype)
-    dv = jnp.sum(dvp, axis=1).astype(v.dtype)
-    return dq[:, :tq], dk[:, :tk], dv[:, :tk]
-
-
-# default pallas backward where no table row names one: "combined" (one
-# recompute, dk/dv partial sums in HBM: the r4 winner at d <= 64 up to 8192,
-# where the partials fit _COMBINED_PARTIAL_BUDGET; past it, which every
-# 16384 shape of a model is, asking for it gets the split kernels), "split"
-# (the two-pass r2 kernels) or "fused" (one recompute, every accumulation in
-# VMEM: what the table rows swept since PR 37 name).  Overridable per call
-# via flash_attention(bwd_impl=...).
-_BWD_IMPL = "combined"
-
-# the combined kernel's dk/dv partials cost 2·bh·nq·Tk·d·4 B of HBM —
-# QUADRATIC in T (nq = Tq/block_q).  Past this budget the split kernels'
-# O(bh·T·d) memory wins by not OOMing; fall back automatically.  (The 8k
-# shapes at d > 64 are past it, 2.15 to 2.68 GB a layer: they run "fused".)
-_COMBINED_PARTIAL_BUDGET = 2 << 30
 
 def _fused_vmem_bytes(tq, d, d_v, block_q, block_k, itemsize):
     """The VMEM the fused backward asks for, from its shapes: the head's dQ
@@ -718,34 +568,29 @@ def _fused_vmem_bytes(tq, d, d_v, block_q, block_k, itemsize):
     return int(1.25 * (acc + blocks + rows + scratch + tiles))
 
 
+_BWD_IMPLS = ("fused", "split")
+
+
 def _bwd_kernel_name(q, k, v, block_q, block_k, impl=None):
-    """Which of the three Pallas backwards runs for collapsed ``q``, ``k``,
-    ``v`` (anything with a shape and a dtype) at these blocks: what ``impl``
-    asks for where it fits, else the split kernels, which fit everywhere."""
-    impl = impl or _BWD_IMPL
-    bh, tq, d = q.shape
+    """Which of the two Pallas backwards runs for collapsed ``q``, ``k``,
+    ``v`` (anything with a shape and a dtype) at these blocks: the fused
+    kernel where a head's dQ accumulator and the blocks fit
+    ``_FUSED_VMEM_SHARE`` of a core's VMEM, else the split kernels, which
+    fit everywhere.  ``impl="split"`` asks for the split kernels outright."""
+    if impl == "split":
+        return "split"
+    tq, d = q.shape[1:]
     tk, d_v = k.shape[1], v.shape[2]
     block_q, block_k = min(block_q, tq), min(block_k, tk)
-    if impl == "fused":
-        need = _fused_vmem_bytes(tq, d, d_v, block_q, block_k,
-                                 jnp.dtype(q.dtype).itemsize)
-        return "fused" if need <= _FUSED_VMEM_SHARE * _VMEM_BYTES \
-            else "split"
-    if impl == "combined":
-        partial_bytes = bh * -(-tq // block_q) * tk * (d + d_v) * 4
-        if partial_bytes <= _COMBINED_PARTIAL_BUDGET:
-            return "combined"
-    return "split"
+    need = _fused_vmem_bytes(tq, d, d_v, block_q, block_k,
+                             jnp.dtype(q.dtype).itemsize)
+    return "fused" if need <= _FUSED_VMEM_SHARE * _VMEM_BYTES else "split"
 
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale, block_q,
                       block_k, offset, interpret, impl=None, window=None,
                       group=1):
     name = _bwd_kernel_name(q, k, v, block_q, block_k, impl)
-    if name == "combined":
-        return _flash_bwd_pallas_combined(q, k, v, o, lse, do, causal,
-                                          sm_scale, block_q, block_k,
-                                          offset, interpret, window, group)
     return _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale,
                                    block_q, block_k, offset, interpret,
                                    window, group, fused=name == "fused")
@@ -1077,15 +922,30 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 # End-to-end-validated block defaults per sequence length (r4 sweep,
 # LONGCTX_ABLATION.md).  Keys are max(Tq, Tk); anything else takes the
-# (512, 1024) baseline.  The bwd rows without a third entry feed the combined
-# single-recompute kernel (big q-blocks keep its dk/dv partial-sum traffic
-# low) where its partials fit _COMBINED_PARTIAL_BUDGET; a third entry names
-# the backward, as in the wider tables.
+# (512, 1024) baseline.  Every backward row is a block pair for the fused
+# kernel (``_bwd_kernel_name`` falls back to split where it does not fit).
 # re-swept IN-GRAPH after the r5 mask/scale elision (the r4 optima moved:
 # wide 2048 k-blocks now win the non-causal fwd at 4k/8k — less per-block
 # bookkeeping per element once the masks are gone; measured e2e on v5e:
 # 4k 275→267 ms, 8k 436→422 ms, 16k 693→681 ms; the 2k causal table
 # re-validated unchanged)
+# 2048, 4096, 8192 at d <= 64: the backward rows are the r4 sweep's for the
+# "combined" kernel (one recompute, dK/dV as float32 partials per query block
+# in HBM, summed outside), which went in PR 44: on a v5e at causal [1, 16 | 32
+# over 16 | 8, T, 64] bf16 (tools/trinity_kernel_probe.py --head_dim 64
+# --window 0, PR 44; backward ms = forward + backward less the forward, 16
+# heads / 32 over 8), fused against combined at the row's blocks:
+#   2048 (1024, 512)   0.437 / 0.870 against 0.493 / 1.217 (split 0.603 / 1.243)
+#   4096 (1024, 1024)  1.321 / 2.958 against 1.968 / 4.244 (split 1.981 / 4.128)
+#   8192 (1024, 512)   4.884 / 10.57 against 7.776 / 15.59 and 1.3 / 2.6 GB
+#                      of partials (split at (1024, 1024) 7.086 / 15.36)
+# and fused at (1024, 1024) and (512, 512) within 4 % of the row's blocks at
+# 4096 and 8192 (at 2048 (512, 512) reads 0.386 / 0.760: not taken, no step
+# has run it).  At 1024 (no row: the baseline's blocks; 64 passes chained in
+# one jit, since a single call is mostly the host's dispatch; forward +
+# backward ms) fused 0.242 / 0.472 against 0.278 / 0.519, at batch 8 1.877 /
+# 3.971 against 2.359 / 5.929; at (1024, 1024) blocks and batch 1 the two
+# tie within 1 % (0.244 / 0.472 against 0.247 / 0.477).
 # 16384, re-swept on a v5e at causal [32, 16384, 64] bf16 over 8 K/V heads,
 # groups of 4 (tools/trinity_kernel_probe.py --seq 16384 --heads 32
 # --kv_heads 8 --head_dim 64 --window 0, PR 40; the r4 sweep had every head
@@ -1094,27 +954,22 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 # (forward + backward less the forward): fused (1024, 1024) 33.6 to 34.8,
 # (512, 1024) 34.97, (2048, 512) 39.61, (1024, 512) 39.05, and (512, 512)
 # runs out of VMEM; split (1024, 1024) 51.08, (512, 1024) 53.82, (1024, 512)
-# 56.17, (512, 512) 60.07.  "combined", what the row's missing third entry
-# asked for until PR 40, would keep 4.3 GB of partials here, past the budget,
-# and was the split kernels.  A head's [16384, 64] dQ accumulator and the
-# (1024, 1024) blocks ask for 40.8 MiB of VMEM (_fused_vmem_bytes).  Against
-# the dense-mask oracle: o 2.1e-3, dq 2.5e-3, dk 3.0e-3, dv 2.4e-3.
+# 56.17, (512, 512) 60.07 ("combined" would have kept 4.3 GB of partials:
+# past its budget, it was the split kernels).  A head's [16384, 64] dQ
+# accumulator and the (1024, 1024) blocks ask for 40.8 MiB of VMEM
+# (_fused_vmem_bytes).  Against the dense-mask oracle: o 2.1e-3, dq 2.5e-3,
+# dk 3.0e-3, dv 2.4e-3.
 _FWD_DEFAULTS = {2048: (1024, 1024), 4096: (512, 2048),
                  8192: (512, 2048), 16384: (1024, 1024)}
 _BWD_DEFAULTS = {2048: (1024, 512), 4096: (1024, 1024), 8192: (1024, 512),
-                 16384: (1024, 1024, "fused")}
+                 16384: (1024, 1024)}
 # head_dim 128 (64 < d <= 128), swept on a v5e at causal [64, 4096, 128]
-# bf16 (tools/olmoe_kernel_sweep.py, PR 27): forward (1024, 1024) 3.17 ms
-# against the (512, 1024) baseline's 4.01; backward "combined" (1024, 512)
-# 8.75 ms against 11.05 at the forward's blocks (split (1024, 1024) 8.89);
-# (1024, 1024) combined and every 2048-wide backward block ran out of the
-# default 16 MiB of VMEM.  Since PR 37 "fused" (1024, 1024): alone, forward
-# (512, 1024) + backward, 9.85 ms against combined (1024, 512) 12.77 and
-# split (1024, 1024) 12.87 (fused (512, 1024) 10.03, (512, 512) 10.20,
-# (1024, 512) 10.28, 2048-wide 10.8 to 11.0); in OLMoE's step 19.41
-# samples/s against combined (1024, 512)'s 19.14, fused (512, 1024) 19.38,
-# fused (1024, 512) 19.35, and 133 MB less at the peak (the partials; the
-# window alone, tools/window_stalls.py, one seed; my chip runs, PR 37).
+# bf16 (tools/olmoe_kernel_sweep.py, PRs 27 and 37): forward (1024, 1024)
+# 3.17 ms against the (512, 1024) baseline's 4.01; fused (1024, 1024) alone,
+# forward (512, 1024) + backward, 9.85 ms (combined (1024, 512) 12.77, split
+# (1024, 1024) 12.87; fused (512, 1024) 10.03, (512, 512) 10.20, (1024, 512)
+# 10.28, 2048-wide 10.8 to 11.0); in OLMoE's step 19.41 samples/s against
+# combined's 19.14 and 133 MB less at the peak (my chip runs, PR 37).
 # Lengths other than 4096, 8192 and 16384 at this width keep the baseline
 # until they are swept.
 # 8192, on a v5e at [32, 8192, 128] bf16 over 4 KV heads
@@ -1122,17 +977,16 @@ _BWD_DEFAULTS = {2048: (1024, 512), 4096: (1024, 1024), 8192: (1024, 512),
 # backward less the forward): forward (1024, 1024) 5.45 ms full and 3.57
 # under a window of 2048 ((512, 1024) 7.08 / 4.46, (512, 512) 11.3 / 6.4).
 # Full backward: fused (1024, 1024) 10.10, (512, 1024) 10.43, (1024, 512)
-# 10.79, (512, 512) 11.19, (2048, 512) 11.47, (1024, 256) 12.31; combined
-# (1024, 512) 15.19 with 2.15 GB of dK/dV partials; split (1024, 512) 15.42.
-# Under the window (the combined kernel's partials are mostly zeros that
-# are written and summed all the same, 12.6 in PR 32): fused (1024, 1024)
-# 7.11, (512, 512) 7.25, (512, 1024) 7.31, (1024, 512) 7.61, (256, 1024)
-# 8.04, (1024, 256) 8.81, (256, 256) 14.57; split (512, 512) 10.73.  In
-# Trinity's step (the window alone, samples/s): full (1024, 1024) with the
-# window at (512, 512) 3.941, at (1024, 1024) 3.939, at (512, 1024) 3.927;
-# full (1024, 512) 3.926; the split kernels 3.642.  The two best differ by
-# less than a seed does (0.3 %): the window keeps the smaller blocks, which
-# hug the band and ask for 20 MiB of VMEM, not 43.
+# 10.79, (512, 512) 11.19, (2048, 512) 11.47, (1024, 256) 12.31 (combined
+# (1024, 512) 15.19 with 2.15 GB of partials; split (1024, 512) 15.42).
+# Under the window: fused (1024, 1024) 7.11, (512, 512) 7.25, (512, 1024)
+# 7.31, (1024, 512) 7.61, (256, 1024) 8.04, (1024, 256) 8.81, (256, 256)
+# 14.57 (combined 12.6; split (512, 512) 10.73).  In Trinity's step (the
+# window alone, samples/s): full (1024, 1024) with the window at (512, 512)
+# 3.941, at (1024, 1024) 3.939, at (512, 1024) 3.927; full (1024, 512)
+# 3.926; the split kernels 3.642.  The two best differ by less than a seed
+# does (0.3 %): the window keeps the smaller blocks, which hug the band and
+# ask for 20 MiB of VMEM, not 43.
 # 16384, on a v5e at [28, 16384, 128] bf16 over 4 KV heads, groups of 7
 # (tools/trinity_kernel_probe.py --seq 16384 --heads 28 --window 4096, PR 38;
 # ms, under the window of 4096 / full): forward (1024, 1024) 9.70 / 17.69,
@@ -1143,23 +997,20 @@ _BWD_DEFAULTS = {2048: (1024, 512), 4096: (1024, 1024), 8192: (1024, 512),
 # Backward: fused (1024, 1024) 19.26 / 32.30, (512, 1024) 21.27 / 33.80,
 # (1024, 512) 21.68 / 36.07, (512, 2048) 22.89 / 33.04, (512, 512) 23.80 /
 # 38.31, (256, 1024) 25.67 / 39.79; split (1024, 512) 31.36 / 50.43, (512,
-# 512) 34.90 / 57.35; "combined" would keep 15 GB of partials and IS the
-# split kernels.  A head's dQ accumulator and the (1024, 1024) blocks ask
+# 512) 34.90 / 57.35 (combined: 15 GB of partials, so it was the split
+# kernels).  A head's dQ accumulator and the (1024, 1024) blocks ask
 # for 52.7 MiB of VMEM (_fused_vmem_bytes), of the 96 the fused backward
 # may ask for.  At 16384 the band of 4096 is four blocks of 1024 wide, and
 # the larger blocks win under the window too (at 8192 the band of 2048 was
 # two).  In SmallThinker's step (the window alone, samples/s, one seed):
 # the rows as shipped 2.146; the window's backward at (512, 512) 2.108, at
 # (512, 1024) stalled, no reading; forward (1024, 2048) 2.132; split at (1024,
-# 512) for both kinds of layer, which is what no row at this length gave
-# (the 15 GB of partials are past _COMBINED_PARTIAL_BUDGET), 1.925.
+# 512) for both kinds of layer 1.925.
 _FWD_DEFAULTS_D128 = {4096: (1024, 1024), 8192: (1024, 1024),
                       16384: (1024, 1024)}
-_BWD_DEFAULTS_D128 = {4096: (1024, 1024, "fused"),
-                      8192: (1024, 1024, "fused"),
-                      16384: (1024, 1024, "fused")}
-_BWD_WINDOW_DEFAULTS_D128 = {8192: (512, 512, "fused"),
-                             16384: (1024, 1024, "fused")}
+_BWD_DEFAULTS_D128 = {4096: (1024, 1024), 8192: (1024, 1024),
+                      16384: (1024, 1024)}
+_BWD_WINDOW_DEFAULTS_D128 = {8192: (512, 512), 16384: (1024, 1024)}
 # score width 128 < d_qk <= 256 (the values may be narrower: 192 over 128 is
 # latent attention's pair), on a v5e at causal [32, 8192, 192 | 128] bf16,
 # every head its own K/V (tools/joyai_kernel_probe.py, PRs 34 and 37):
@@ -1169,13 +1020,13 @@ _BWD_WINDOW_DEFAULTS_D128 = {8192: (512, 512, "fused"),
 # 512) 16.90, (512, 2048) 16.91, (256, 1024) 16.98, (2048, 512) 17.85,
 # (1024, 256) 18.94, (2048, 1024) 37.07 (it spills); split (1024, 512) 24.03,
 # (512, 512) 25.49, and split (1024, 1024) does not fit the default 16 MiB
-# of VMEM; "combined" would keep 2.68 GB of float32 dK/dV partials here,
-# past _COMBINED_PARTIAL_BUDGET, so it IS the split kernels.  In JoyAI's
-# step (the window alone, samples/s): fused (1024, 1024) 2.361, (512, 1024)
-# 2.350, (1024, 512) 2.328, split (1024, 512) 2.120.  The fused kernel on
-# (bq, bk) tiles with (bq, 1) lse/delta rows, tried first, read 16.73 at
-# (1024, 1024) and 19.13 at (1024, 512) and kept 268 MB more in HBM (a [bh,
-# Tq, 1] float32 array is tiled to 128 lanes): the transposed tiles ship.
+# of VMEM ("combined": 2.68 GB of partials, so it was the split kernels).
+# In JoyAI's step (the window alone, samples/s): fused (1024, 1024) 2.361,
+# (512, 1024) 2.350, (1024, 512) 2.328, split (1024, 512) 2.120.  The fused
+# kernel on (bq, bk) tiles with (bq, 1) lse/delta rows, tried first, read
+# 16.73 at (1024, 1024) and 19.13 at (1024, 512) and kept 268 MB more in HBM
+# (a [bh, Tq, 1] float32 array is tiled to 128 lanes): the transposed tiles
+# ship.
 # The split kernels at 128 | 128 read 5.46 forward and 15.8 backward: a
 # 192-wide contraction fills two 128-deep MXU passes, so the scores cost
 # what 256 would.  Other
@@ -1185,7 +1036,7 @@ _BWD_WINDOW_DEFAULTS_D128 = {8192: (512, 512, "fused"),
 # 1024) passed the 16 MiB of scoped VMEM by 12 KiB, though the kernel alone
 # compiles; my chip run, PR 34).
 _FWD_DEFAULTS_D256 = {8192: (1024, 1024)}
-_BWD_DEFAULTS_D256 = {8192: (1024, 1024, "fused")}
+_BWD_DEFAULTS_D256 = {8192: (1024, 1024)}
 
 
 def _collapse_bias(bias, b, h, tq, tk):
@@ -1208,6 +1059,9 @@ def _statics(q, k, v, causal, sm_scale, block_q, block_k, block_q_bwd,
     causal half."""
     b, h, tq, d = q.shape
     hk, tk = k.shape[1], k.shape[2]
+    if bwd_impl is not None and bwd_impl not in _BWD_IMPLS:
+        raise ValueError(f"bwd_impl {bwd_impl!r}: the Pallas backwards are "
+                         f"{_BWD_IMPLS} (None: fused where it fits VMEM)")
     if h % hk or v.shape[1] != hk:
         raise ValueError(f"{h} query heads over {hk}/{v.shape[1]} K/V heads")
     if k.shape[3] != d:
@@ -1256,9 +1110,8 @@ def _statics(q, k, v, causal, sm_scale, block_q, block_k, block_q_bwd,
                 t in _BWD_WINDOW_DEFAULTS_D128:
             bwd_table = _BWD_WINDOW_DEFAULTS_D128
         if t in bwd_table:
-            bq_b, bk_b, *impl = bwd_table[t]
+            bq_b, bk_b = bwd_table[t]
             bwd_blocks = (min(bq_b, tq), min(bk_b, tk))
-            bwd_impl = bwd_impl or (impl[0] if impl else None)
     return (causal, sm_scale, block_q, block_k, bwd_blocks, bwd_impl,
             interpret, window, group)
 
@@ -1319,16 +1172,11 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
     The backward kernels take their own ``block_q_bwd``/``block_k_bwd``
     (default: the ``_BWD_DEFAULTS`` table at d≤64 for 2k/4k/8k/16k, else
     the forward blocks) — swept separately in LONGCTX_ABLATION.md.
-    ``bwd_impl``: "fused" (single-recompute, dk/dv and the head's dq
-    accumulated in VMEM; falls back to split where the accumulator and the
-    blocks pass ``_FUSED_VMEM_SHARE`` of a core's VMEM: ``Tq · d_qk`` some
-    4 to 8 times the cells'), "combined" (single-recompute, dk/dv partial
-    sums in HBM; falls back to split when the partials would exceed
-    ``_COMBINED_PARTIAL_BUDGET``) or "split" (two-pass);
-    default = what the backward table says for this length ("fused" at
-    8192 for 64 < d_qk ≤ 256, at 4096 and 16384 for d_qk ≤ 128), else
-    module `_BWD_IMPL`.
-    :func:`flash_bwd_kernel` says which one a call gets.
+    ``bwd_impl``: ``None`` or "fused" (single-recompute, dk/dv and the
+    head's dq accumulated in VMEM; falls back to split where the accumulator
+    and the blocks pass ``_FUSED_VMEM_SHARE`` of a core's VMEM: ``Tq · d_qk``
+    some 4 to 8 times the cells') or "split" (two-pass, outright); anything
+    else raises.  :func:`flash_bwd_kernel` says which one a call gets.
     """
     (qc, kc, vc, bc), statics = _plan(
         q, k, v, bias, causal, sm_scale, block_q, block_k, block_q_bwd,
@@ -1387,7 +1235,7 @@ def flash_bwd_kernel(q, k, v, bias=None, causal=False, sm_scale=None,
                      window=None):
     """Which backward :func:`flash_attention_bwd` (and ``jax.grad`` of
     :func:`flash_attention`) runs for these arguments, from their shapes
-    alone: ``"fused"``, ``"combined"`` or ``"split"`` of the Pallas kernels,
+    alone: ``"fused"`` or ``"split"`` of the Pallas kernels,
     or ``"jax"``, the blockwise fallback (a bias, or no TPU)."""
     *_, block_q, block_k, bwd_blocks, bwd_impl, interpret, _, _ = _statics(
         q, k, v, causal, sm_scale, block_q, block_k, block_q_bwd,

@@ -164,61 +164,12 @@ def test_flash_pallas_backward_kernels_interpret(causal):
                                    rtol=2e-4, atol=2e-4)
 
 
-# -- fused LayerNorm kernel (pallas/layer_norm.py) ---------------------------
-
-def test_fused_layer_norm_matches_reference():
-    from paddle_tpu.pallas.layer_norm import _ln_ref, fused_layer_norm
-    rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(4, 8, 256).astype(np.float32))
-    s = jnp.asarray(rng.randn(256).astype(np.float32))
-    b = jnp.asarray(rng.randn(256).astype(np.float32))
-    got = fused_layer_norm(x, s, b, interpret=True)
-    want = _ln_ref(x, s, b, 1e-5)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_fused_layer_norm_grads_match_reference():
-    from paddle_tpu.pallas.layer_norm import _ln_ref, fused_layer_norm
-    rng = np.random.RandomState(1)
-    x = jnp.asarray(rng.randn(2, 16, 128).astype(np.float32))
-    s = jnp.asarray(rng.randn(128).astype(np.float32))
-    b = jnp.asarray(rng.randn(128).astype(np.float32))
-    w = jnp.asarray(rng.randn(2, 16, 128).astype(np.float32))
-
-    def lk(x, s, b):
-        return jnp.sum(fused_layer_norm(x, s, b, interpret=True) * w)
-
-    def lr(x, s, b):
-        return jnp.sum(_ln_ref(x, s, b, 1e-5) * w)
-
-    gk = jax.grad(lk, argnums=(0, 1, 2))(x, s, b)
-    gr = jax.grad(lr, argnums=(0, 1, 2))(x, s, b)
-    for a, c in zip(gk, gr):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
-                                   rtol=2e-4, atol=2e-4)
-
-
-def test_fused_layer_norm_bf16_input():
-    from paddle_tpu.pallas.layer_norm import _ln_ref, fused_layer_norm
-    rng = np.random.RandomState(2)
-    x = jnp.asarray(rng.randn(8, 128).astype(np.float32)).astype(jnp.bfloat16)
-    s = jnp.asarray(rng.randn(128).astype(np.float32))
-    b = jnp.asarray(rng.randn(128).astype(np.float32))
-    got = fused_layer_norm(x, s, b, interpret=True)
-    want = _ln_ref(x, s, b, 1e-5)
-    assert got.dtype == jnp.bfloat16
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=2e-2, atol=2e-2)
-
-
-@pytest.mark.parametrize("impl", ["combined", "split"])
+@pytest.mark.parametrize("impl", ["fused", "split"])
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_pallas_bwd_kernels_interpret(impl, causal):
-    """Both Pallas backward implementations (single-recompute combined
-    kernel with dk/dv partial sums, and the two-pass split kernels) match
-    the dense reference gradients in interpret mode — including a
+    """Both Pallas backward implementations (the single-recompute fused
+    kernel with the head's dq in scratch, and the two-pass split kernels)
+    match the dense reference gradients in interpret mode — including a
     non-multiple sequence length (padding path)."""
     q, k, v = (_rand((1, 2, 20, 8), i) for i in range(3))
 
@@ -237,31 +188,43 @@ def test_flash_pallas_bwd_kernels_interpret(impl, causal):
                                    rtol=2e-4, atol=2e-4)
 
 
-def test_flash_bwd_partial_budget_fallback(monkeypatch):
-    """Past _COMBINED_PARTIAL_BUDGET the combined backward must fall back
-    to the split kernels (its dk/dv partials are quadratic in T); an
-    explicit impl override always wins."""
+def test_flash_bwd_falls_back_to_split_past_the_vmem_share(monkeypatch):
+    """The one fall-back left: the fused backward where a head's dQ
+    accumulator and the blocks fit ``_FUSED_VMEM_SHARE`` of a core's VMEM,
+    the split kernels past it, asked for by name or not; ``impl="split"``
+    is the split kernels outright."""
     import importlib
     FA = importlib.import_module("paddle_tpu.pallas.flash_attention")
     calls = []
-    orig_comb = FA._flash_bwd_pallas_combined
-    orig_split = FA._flash_bwd_pallas_split
-    monkeypatch.setattr(
-        FA, "_flash_bwd_pallas_combined",
-        lambda *a, **k: calls.append("combined") or orig_comb(*a, **k))
+    orig = FA._flash_bwd_pallas_split
     monkeypatch.setattr(
         FA, "_flash_bwd_pallas_split",
-        lambda *a, **k: calls.append("split") or orig_split(*a, **k))
+        lambda *a, fused=False, **k: calls.append(
+            "fused" if fused else "split") or orig(*a, fused=fused, **k))
     r = np.random.RandomState(0)
     q = jnp.asarray(r.randn(2, 32, 8).astype(np.float32) * 0.3)
     o = jnp.asarray(r.randn(2, 32, 8).astype(np.float32) * 0.3)
     lse = jnp.asarray(r.randn(2, 32).astype(np.float32))
     do = jnp.asarray(r.randn(2, 32, 8).astype(np.float32) * 0.3)
-    FA._flash_bwd_pallas(q, q, q, o, lse, do, False, 1.0, 8, 8, 0, True)
-    assert calls[-1] == "combined"
-    monkeypatch.setattr(FA, "_COMBINED_PARTIAL_BUDGET", 0)
-    FA._flash_bwd_pallas(q, q, q, o, lse, do, False, 1.0, 8, 8, 0, True)
+    args = (q, q, q, o, lse, do, False, 1.0, 8, 8, 0, True)
+    want = FA._flash_bwd_pallas(*args)
+    assert calls == ["fused"]
+    FA._flash_bwd_pallas(*args, impl="split")
     assert calls[-1] == "split"
-    FA._flash_bwd_pallas(q, q, q, o, lse, do, False, 1.0, 8, 8, 0, True,
-                         impl="split")
-    assert calls[-1] == "split"
+    monkeypatch.setattr(FA, "_FUSED_VMEM_SHARE", 0.0)
+    for impl in (None, "fused"):
+        got = FA._flash_bwd_pallas(*args, impl=impl)
+        assert calls[-1] == "split"
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-5, atol=2e-5)
+
+
+def test_flash_bwd_impl_names_the_two_kernels():
+    """``bwd_impl="combined"`` (the kernel that went in PR 44) and any other
+    name raise, naming the two that are left."""
+    q = _rand((1, 2, 16, 8), 0)
+    for name in ("combined", "fast"):
+        with pytest.raises(ValueError, match="fused.*split"):
+            flash_attention(q, q, q, causal=True, bwd_impl=name,
+                            interpret=True)

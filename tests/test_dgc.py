@@ -121,3 +121,25 @@ def test_dgc_eager_mode_degrades_to_momentum():
         loss = t.trace_op("mean", {"X": [layer(x)]}, {})["Out"][0]
         o = opt.DGCMomentumOptimizer(0.1, 0.9)
         o.minimize(loss, parameter_list=layer.parameters())
+
+
+def test_an_op_type_has_one_lowering():
+    """A second ``register_op`` of a registered type by another function
+    raises and leaves the first in place (import order used to pick between
+    two ``dgc_momentum`` lowerings); the same function may register again,
+    as a reloaded module does."""
+    import pytest
+
+    from paddle_tpu.framework import registry
+    from paddle_tpu.parallel import dgc
+
+    held = registry.get_op_info("dgc_momentum")
+    assert held.lower is dgc._dgc_momentum       # plain SGD on the u buffer
+
+    def other(ctx, ins, attrs):
+        return {}
+    with pytest.raises(ValueError, match="dgc_momentum.*already registered"):
+        registry.register_op("dgc_momentum", other, no_grad=True)
+    assert registry.get_op_info("dgc_momentum").lower is dgc._dgc_momentum
+    registry.register_op("dgc_momentum", dgc._dgc_momentum, no_grad=True)
+    assert registry.get_op_info("dgc_momentum").no_grad

@@ -1,7 +1,7 @@
 """The flash attention kernels' two widths (``paddle_tpu/pallas/
 flash_attention.py``: Q and K ``d_qk`` wide, V and the output ``d_v``) against
 ``mha_reference``: forward and dQ, dK, dV of the Pallas kernels in interpret
-mode (combined and split backward) and of the blockwise jax fallbacks, at
+mode (fused and split backward) and of the blockwise jax fallbacks, at
 96/64 and latent attention's 192/128, T <= 256, full and grouped K/V heads and
 a window; the ``flash_attention`` op of a ``Program`` and its grad op at two
 widths (shape inference, gradients, the counters' ``widths`` label);
@@ -50,15 +50,15 @@ def _value_and_grads(fn, q, k, v, w):
 
 
 @pytest.mark.parametrize("d_qk,d_v,t,hk,window,impl", [
-    (96, 64, 32, 4, None, "combined"),
+    (96, 64, 56, 4, None, "fused"),        # a padded length, no groups
     (96, 64, 32, 4, None, "split"),
-    (96, 64, 40, 2, None, "combined"),     # padded blocks, grouped K/V heads
+    (96, 64, 88, 2, None, "fused"),        # padded blocks of 64, grouped
     (96, 64, 48, 2, 24, "split"),          # a window over grouped heads
-    (192, 128, 128, 4, None, "combined"),  # latent attention's pair
+    (192, 128, 96, 2, None, "fused"),      # latent's pair, padded, grouped
     (192, 128, 128, 4, None, "split"),
     (96, 64, 64, 4, None, None),           # the blockwise jax fallbacks
     (192, 128, 100, 2, 40, None),
-    (64, 96, 32, 4, None, "combined"),     # values wider than the scores
+    (64, 96, 48, 2, 20, "fused"),          # wider values under a window
     (96, 64, 32, 4, None, "fused"),        # one pass, dQ resident (PR 37)
     (96, 64, 40, 2, None, "fused"),        # padded blocks, grouped K/V heads
     (96, 64, 48, 2, 24, "fused"),          # a window over grouped heads
@@ -133,8 +133,8 @@ def test_block_tables_at_the_wide_score_width():
                        None, False, None)[1]
     st = plan(192, 128)
     assert st[1] == pytest.approx(192 ** -0.5)
-    assert st[2:6] == (1024, 1024, (1024, 1024), "fused")
-    assert plan(128, 128)[2:6] == (1024, 1024, (1024, 1024), "fused")
+    assert st[2:6] == (1024, 1024, (1024, 1024), None)   # None: the rule
+    assert plan(128, 128)[2:6] == (1024, 1024, (1024, 1024), None)
     assert plan(320, 128)[2:4] == (512, 1024)           # the baseline
     # float32 blocks are twice the bytes: the wide table is bf16's alone
     assert plan(192, 128, jnp.float32)[2:5] == (512, 1024, None)
@@ -149,7 +149,8 @@ def test_block_tables_at_the_wide_score_width():
 # -- the op of a Program ----------------------------------------------------------
 
 #: name -> (heads, KV heads, T, d_qk, d_v, window, bwd_impl asked of the grad
-#: op, the backward kernel a TPU runs there)
+#: op, the backward kernel a TPU runs there[, the share of VMEM the fused
+#: backward may ask for, where the case sets it])
 CHOICE_CASES = {
     "joyai_32x8192x192_over_128": (32, 32, 8192, 192, 128, None, None,
                                    "fused"),
@@ -161,8 +162,14 @@ CHOICE_CASES = {
     # VMEM the fused backward may ask for, whatever is asked of the op
     "too_long_for_the_accumulator": (2, 2, 65536, 192, 128, None, "fused",
                                      "split"),
-    "combined_past_its_budget": (32, 32, 8192, 192, 128, None, "combined",
-                                 "split"),
+    # the same rule with the share made smaller under JoyAI's shape, where
+    # the fused call asks for 49.5 MiB: the fall-back that is left
+    "fused_past_a_smaller_share": (32, 32, 8192, 192, 128, None, "fused",
+                                   "split", 0.25),
+    # d <= 64 at a length whose row names blocks only: what got the kernel
+    # with partials in HBM until PR 44 gets the rule
+    "gpt_16x4096x64_nothing_asked": (16, 16, 4096, 64, 64, None, None,
+                                     "fused"),
 }
 
 
@@ -171,13 +178,15 @@ def test_the_backward_kernel_follows_the_shapes(case, monkeypatch):
     """Which backward ``flash_attention_grad`` lowers to on a TPU, traced
     abstractly at the three flash cells' shapes in bf16 (the tables name the
     fused kernel at 8192), at a length whose dQ accumulator passes the share
-    of VMEM (the split kernels, which keep nothing that grows with T), and
-    for "combined" past its partial budget; ``paddle_tpu_flash_bwd_kernel_
-    total`` says which was taken, and the fused call asks for the VMEM its
-    shapes need and no more than the share."""
+    of VMEM (the split kernels, which keep nothing that grows with T), under
+    a smaller share, and at 64-wide heads with nothing asked; ``paddle_tpu_
+    flash_bwd_kernel_total`` says which was taken, and the fused call asks
+    for the VMEM its shapes need and no more than the share."""
     from paddle_tpu.ops import attention_ops as A
-    h, hk, t, d_qk, d_v, window, asked, kernel = CHOICE_CASES[case]
+    h, hk, t, d_qk, d_v, window, asked, kernel, *share = CHOICE_CASES[case]
     monkeypatch.setattr(F, "on_tpu", lambda: True)
+    if share:
+        monkeypatch.setattr(F, "_FUSED_VMEM_SHARE", share[0])
 
     def arg(heads, width):
         return jax.ShapeDtypeStruct((1, heads, t, width), jnp.bfloat16)
@@ -206,6 +215,37 @@ def test_the_backward_kernel_follows_the_shapes(case, monkeypatch):
             F._FUSED_VMEM_SHARE * F._VMEM_BYTES
     else:
         assert limits == []
+
+
+#: every row of the four backward block tables: (table, length, the widest
+#: d_qk and d_v the table serves, a window for the window table)
+TABLE_ROWS = [(name, t, d_qk, d_v, window)
+              for name, d_qk, d_v, window in (
+                  ("_BWD_DEFAULTS", 64, 64, None),
+                  ("_BWD_DEFAULTS_D128", 128, 128, None),
+                  ("_BWD_WINDOW_DEFAULTS_D128", 128, 128, 2048),
+                  ("_BWD_DEFAULTS_D256", 256, 256, None))
+              for t in sorted(getattr(F, name))]
+
+
+@pytest.mark.parametrize("name,t,d_qk,d_v,window", TABLE_ROWS,
+                         ids=[f"{r[0]}-{r[1]}" for r in TABLE_ROWS])
+def test_every_table_row_is_a_block_pair_the_fused_backward_fits(
+        name, t, d_qk, d_v, window, monkeypatch):
+    """A row of a backward table is a pair of blocks and nothing else (the
+    kernel is the rule's), and at the widest bf16 head the table serves the
+    rule gives the fused kernel: its VMEM ask stays under the share."""
+    monkeypatch.setattr(F, "on_tpu", lambda: True)
+    bq, bk = getattr(F, name)[t]
+    q = jax.ShapeDtypeStruct((1, 8, t, d_qk), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 2, t, d_qk), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 2, t, d_v), jnp.bfloat16)
+    statics = F._statics(q, k, v, True, None, None, None, None, None, None,
+                         False, window)
+    assert statics[4:6] == ((bq, bk), None)
+    assert F.flash_bwd_kernel(q, k, v, causal=True, window=window) == "fused"
+    assert F._fused_vmem_bytes(t, d_qk, d_v, bq, bk, 2) \
+        <= F._FUSED_VMEM_SHARE * F._VMEM_BYTES
 
 
 def _dense_lse(q, k, bias, causal, sm, window):

@@ -50,23 +50,23 @@ def _value_and_grads(fn, q, k, v, w):
             lambda q, k, v: jnp.sum(w * fn(q, k, v)), (0, 1, 2))(q, k, v)
 
 
-@pytest.mark.parametrize("window,impl", [
-    (8, "combined"),      # smaller than a block (16)
-    (16, "split"),        # a block exactly
-    (40, "combined"),     # more than two blocks
-    (40, "split"),
-    (2, "combined"),      # the diagonal and its neighbour
-    (23, None),           # the blockwise jax fallback (no TPU, no interpret)
-    (8, "fused"),         # one pass, dQ resident in VMEM (PR 37)
-    (16, "fused"),
-    (40, "fused"),
-    (2, "fused"),
+@pytest.mark.parametrize("window,impl,t,hk", [
+    (8, "fused", 56, 2),    # smaller than a block (16); a padded length
+    (16, "split", 64, 2),   # a block exactly
+    (40, "fused", 64, 1),   # more than two blocks; one K/V head for all four
+    (40, "split", 64, 2),
+    (2, "fused", 72, 4),    # the diagonal and its neighbour; padded, no group
+    (23, None, 64, 2),      # the blockwise jax fallback (no TPU, no interpret)
+    (8, "fused", 64, 2),    # one pass, dQ resident in VMEM (PR 37)
+    (16, "fused", 64, 2),
+    (40, "fused", 64, 2),
+    (2, "fused", 64, 2),
 ])
-def test_window_flash_matches_the_dense_mask_oracle(window, impl):
+def test_window_flash_matches_the_dense_mask_oracle(window, impl, t, hk):
     """Forward and dQ, dK, dV of the Pallas kernels (interpret mode) over 4
-    query heads on 2 KV heads, T 64 in blocks of 16, against
+    query heads on ``hk`` KV heads, T in blocks of 16, against
     ``mha_reference`` under the dense ``0 <= i - j < window`` mask."""
-    q, k, v, w = _qkv(64)
+    q, k, v, w = _qkv(t, hk=hk)
     got, g_got = _value_and_grads(
         lambda q, k, v: F.flash_attention(
             q, k, v, causal=True, window=window, block_q=16, block_k=16,
@@ -112,7 +112,7 @@ def test_a_window_as_long_as_the_sequence_is_the_causal_lowering():
 def test_grouped_kv_heads_equal_repeated_kv_heads(impl):
     """4 query heads over 2 KV heads through the kernels' index maps against
     the same K and V repeated to 4 heads outside; dK and dV summed over each
-    group (``None``: the module's default backward, "combined")."""
+    group (``None``: the rule, which is "fused" where it fits)."""
     q, k, v, w = _qkv(32)
     kw = dict(causal=True, window=12, block_q=16, block_k=16, interpret=True,
               bwd_impl=impl)

@@ -5,8 +5,8 @@ near-misses (fetched intermediate, multi-consumer, missing grad
 rewrite), rank-threshold gating, loss parity fused-vs-unfused on a
 bert-shaped toy training program (a resnet-shaped one goes through the
 pass unchanged), collective-fingerprint stability through the rewrite,
-autotune cache hit/miss counters, and executor plan invalidation on a
-fusion-flag flip.
+the fused lowerings against the chains they replace, and executor plan
+invalidation on a fusion-flag flip.
 """
 
 import numpy as np
@@ -33,12 +33,10 @@ def _counter(name, **labels):
 @pytest.fixture(autouse=True)
 def _fusion_defaults():
     pt.set_flags({"FLAGS_graph_fusion": True,
-                  "FLAGS_fusion_autotune": False,
                   "FLAGS_fusion_rank_threshold": 0.02})
     fusion.clear_cache()
     yield
     pt.set_flags({"FLAGS_graph_fusion": True,
-                  "FLAGS_fusion_autotune": False,
                   "FLAGS_fusion_rank_threshold": 0.02})
     fusion.clear_cache()
 
@@ -335,84 +333,99 @@ def test_loss_parity_bert_shaped():
 
 
 # ---------------------------------------------------------------------------
-# autotune cache + executor plan invalidation
+# the fused lowerings are the chains they replace
 # ---------------------------------------------------------------------------
 
-def test_autotune_cache_hit_miss_counters(tmp_path):
-    pt.set_flags({"FLAGS_fusion_autotune": True,
-                  "FLAGS_xla_compile_cache_dir": str(tmp_path)})
-    try:
-        scope = Scope()
-        with scope_guard(scope), program_guard(Program(), Program()):
-            _build_dense_toy()
-            prog = pt.default_main_program()
-            miss0 = _counter("paddle_tpu_fusion_autotune_total",
-                             cache="miss")
-            fusion.fuse_program(prog, (), feed_shapes={"x": (4, 12)})
-            miss1 = _counter("paddle_tpu_fusion_autotune_total",
-                             cache="miss")
-            assert miss1 > miss0
-            assert (tmp_path / "fusion_autotune.json").exists()
-            # a fresh process (cleared in-memory caches) hits the
-            # persisted verdicts instead of re-benchmarking
-            fusion.clear_cache()
-            hit0 = _counter("paddle_tpu_fusion_autotune_total",
-                            cache="hit")
-            fusion.fuse_program(prog, (), feed_shapes={"x": (4, 12)})
-            hit1 = _counter("paddle_tpu_fusion_autotune_total",
-                            cache="hit")
-            assert hit1 > hit0
-            assert _counter("paddle_tpu_fusion_autotune_total",
-                            cache="miss") == miss1
-    finally:
-        pt.set_flags({"FLAGS_fusion_autotune": False,
-                      "FLAGS_xla_compile_cache_dir": ""})
+def _chain_of(prog, fused_op):
+    """The forward ops of ``prog`` that ``fused_op`` stands for, in program
+    order: walked back from its output to its inputs."""
+    ops = prog.global_block().ops
+    producer = {n: op for op in ops if not op.type.endswith("_grad")
+                for n in op.output_arg_names()}
+    stop = set(fused_op.input_arg_names())
+    chain, todo = [], [fused_op.output("Out")[0]]
+    while todo:
+        op = producer.get(todo.pop())
+        if op is None or op in chain:
+            continue
+        chain.append(op)
+        todo += [n for n in op.input_arg_names() if n not in stop]
+    return sorted(chain, key=ops.index)
 
 
-def test_autotune_cache_migrates_backend_keys(tmp_path):
-    """Pre-device-kind caches keyed the device slot on the bare backend
-    name; loading one now re-keys entries of THIS backend onto the
-    ``device_kind x count`` key (a v4 verdict must not steer a v5e), a
-    one-shot migration persisted back to disk.  Foreign-backend entries
-    stay for their own process to migrate, and an existing new-style
-    entry is never clobbered by a migrated old one."""
-    import json as _json
+def _stablehlo_ops(text):
+    """The lowered module as (op, element types) rows: shapes, attributes
+    and the reshapes dropped (the fused dense op adds the bias and the
+    activation on the matmul's 2-D result and reshapes last, where the chain
+    reshapes first; nothing else may differ)."""
+    import re
+    rows = []
+    for line in text.splitlines():
+        m = re.search(r"= (\w+\.\w+|call) ", line)
+        if m and m.group(1) != "stablehlo.reshape":
+            rows.append((m.group(1),) + tuple(
+                re.findall(r"x?(bf16|f32|i32|i64|ui8|ui32|ui64|i1)>", line)))
+    return rows
 
+
+@pytest.mark.parametrize("amp", [False, True], ids=["f32", "amp"])
+@pytest.mark.parametrize("fused_type", ["fused_dense_act",
+                                        "fused_embedding_layer_norm"])
+def test_fused_lowering_is_the_chain_it_replaces(fused_type, amp):
+    """What ``ops/fused_ops.py`` promises: each fused op lowers to the
+    StableHLO ops of the chain the pass removed, in the chain's order and
+    types (under AMP the chain's per-op casts), and gives its bits."""
     import jax
-    backend = jax.default_backend()
-    foreign = "tpu" if backend != "tpu" else "gpu"
-    old_rec = {"base_ms": 1.0, "fused_ms": 0.5, "win": True}
-    new_rec = {"base_ms": 1.0, "fused_ms": 2.0, "win": False}
-    old_key = _json.dumps(["dense_epilogue", "sk", 4, backend, "f32"])
-    new_key = _json.dumps(["dense_epilogue", "sk", 4,
-                           fusion._device_key(), "f32"])
-    other_old = _json.dumps(["dense_act", "sk2", 8, backend, "amp"])
-    foreign_key = _json.dumps(["dense_act", "sk3", 8, foreign, "f32"])
-    (tmp_path / "fusion_autotune.json").write_text(_json.dumps({
-        old_key: old_rec,          # migrates
-        new_key: new_rec,          # already new-style: must WIN
-        other_old: old_rec,        # migrates (no new-style sibling)
-        foreign_key: old_rec,      # other backend: untouched
-    }))
-    pt.set_flags({"FLAGS_xla_compile_cache_dir": str(tmp_path)})
-    try:
-        fusion.clear_cache()
-        with fusion._AUTOTUNE_LOCK:
-            fusion._autotune_load_locked()
-            mem = dict(fusion._AUTOTUNE_MEM)
-        other_new = _json.dumps(["dense_act", "sk2", 8,
-                                 fusion._device_key(), "amp"])
-        assert mem[new_key] == new_rec            # not clobbered
-        assert mem[other_new] == old_rec          # re-keyed
-        assert old_key not in mem and other_old not in mem
-        assert mem[foreign_key] == old_rec        # left as-is
-        on_disk = _json.loads(
-            (tmp_path / "fusion_autotune.json").read_text())
-        assert set(on_disk) == set(mem)           # migration persisted
-    finally:
-        fusion.clear_cache()
-        pt.set_flags({"FLAGS_xla_compile_cache_dir": ""})
 
+    from paddle_tpu import amp as _amp
+    from paddle_tpu.framework import registry
+    from paddle_tpu.framework.executor import LowerCtx
+
+    with scope_guard(Scope()), program_guard(Program(), Program()):
+        _build_bert_toy()
+        prog = pt.default_main_program()
+        fused_prog = fusion.fuse_program(prog, ())
+    fused_op = next(op for op in fused_prog.global_block().ops
+                    if op.type == fused_type)
+    chain = _chain_of(prog, fused_op)
+    assert len(chain) >= 3, [op.type for op in chain]
+    out_name = fused_op.output("Out")[0]
+    block = prog.global_block()
+    rng = np.random.RandomState(3)
+    vals = {}
+    for n in sorted(fused_op.input_arg_names()):
+        var = block.var(n)
+        shape = tuple(3 if d == -1 else int(d) for d in var.shape)
+        vals[n] = (rng.randint(0, 6, shape).astype(np.int64)
+                   if "int" in str(var.dtype)
+                   else rng.randn(*shape).astype(np.float32))
+
+    def lower(ops):
+        def run(*arrs):
+            env, ctx = dict(zip(vals, arrs)), LowerCtx(SEED, amp=amp)
+            for op in ops:
+                ins = {s: [env[n] for n in ns]
+                       for s, ns in op.inputs.items()}
+                if amp:
+                    ins = _amp.cast_ins(op.type, ins)
+                outs = registry.get_op_info(op.type).lower(
+                    ctx, ins, op.attrs)
+                for s, ns in op.outputs.items():
+                    env.update(zip(ns, outs.get(s, ())))
+            return env[out_name]
+        fn = jax.jit(run)
+        return (_stablehlo_ops(fn.lower(*vals.values()).as_text()),
+                np.asarray(fn(*vals.values())))
+
+    chain_ops, chain_out = lower(chain)
+    fused_ops, fused_out = lower([fused_op])
+    assert fused_ops == chain_ops
+    np.testing.assert_array_equal(fused_out, chain_out)
+
+
+# ---------------------------------------------------------------------------
+# executor plan invalidation
+# ---------------------------------------------------------------------------
 
 def test_flag_flip_invalidates_executor_plan():
     scope = Scope()

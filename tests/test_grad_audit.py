@@ -206,15 +206,13 @@ def _configs(op):
             {"x_num_col_dims": 1, "bias_axis": 1, "act": "gelu",
              "approximate": False, "dropout_prob": 0.25, "seed": 7,
              "is_test": False,
-             "dropout_implementation": "upscale_in_train",
-             "use_pallas": False}),
+             "dropout_implementation": "upscale_in_train"}),
         # analysis.fusion rewrite target: gather + add + layer_norm;
         # like layer_norm, only Y's gradient is the op contract
         "fused_embedding_layer_norm": lambda: _Cfg(
             {"Ids": [i(3, 1, n=8)], "W": [f(8, 6)],
              "Addends": [f(3, 6)], "Scale": [f(6)], "Bias": [f(6)]},
-            {"padding_idx": -1, "epsilon": 1e-5, "begin_norm_axis": 1,
-             "use_pallas": False},
+            {"padding_idx": -1, "epsilon": 1e-5, "begin_norm_axis": 1},
             loss_outputs=["Out"]),
         "fused_elemwise_activation": lambda: _Cfg(
             {"X": [f(2, 3)], "Y": [f(2, 3)]},

@@ -349,8 +349,13 @@ def test_sharded_snapshot_restore_parity(tmp_path):
                               fetch_list=[loss.name])
                 out.append(float(np.asarray(lv)))
                 if save_at is not None and step + 1 == save_at:
-                    ckpt.save(step + 1, program=main)
-                    return out
+                    assert ckpt.save(step + 1, program=main)
+                    break
+            # a process drains its asynchronous save before it exits: until
+            # the step's directory is renamed into place the next session
+            # sees a torn save, and rightly starts from nothing
+            ckpt.close()
+            assert save_at is None or ckpt_dir.joinpath(str(save_at)).is_dir()
             return out
 
     full = session(tmp_path / "never")

@@ -300,23 +300,23 @@ def test_flash_at_width_64_over_groups_of_4_matches_the_oracle(impl):
         _close(a, b, 1e-5, f"width 64, groups of 4, d / d {name}")
 
 
-def test_the_tables_name_the_fused_backward_at_width_64_and_16384():
-    """The d <= 64 table's 16384 row names ``fused``; the kernel that runs
+def test_the_fused_backward_runs_at_width_64_and_16384():
+    """The d <= 64 table's 16384 row gives the blocks; the kernel that runs
     is reckoned from the shapes: a head's [16384, 64] dQ accumulator fits
-    VMEM.  8192 and under keep their rows (no third entry: ``combined``
-    where its partials fit)."""
+    VMEM, so nothing asked is the fused kernel (until PR 40 it was the split
+    kernels: the kernel the row's missing name asked for kept 4.3 GB of
+    partials)."""
     q = jax.ShapeDtypeStruct((1, 32, 16384, 64), jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((1, 8, 16384, 64), jnp.bfloat16)
     statics = F._statics(q, kv, kv, True, None, None, None, None, None, None,
                          False, None)
-    assert statics[2:6] == (1024, 1024, (1024, 1024), "fused")
+    assert statics[2:6] == (1024, 1024, (1024, 1024), None)
     assert statics[8] == 4
     qc = jax.ShapeDtypeStruct((32, 16384, 64), jnp.bfloat16)
     kc = jax.ShapeDtypeStruct((8, 16384, 64), jnp.bfloat16)
-    assert F._bwd_kernel_name(qc, kc, kc, 1024, 1024, "fused") == "fused"
-    # what the row's missing third entry asked for until PR 40: "combined",
-    # whose 4.3 GB of partials are past the budget, so the split kernels
-    assert F._bwd_kernel_name(qc, kc, kc, 1024, 1024, None) == "split"
+    for asked in (None, "fused"):
+        assert F._bwd_kernel_name(qc, kc, kc, 1024, 1024, asked) == "fused"
+    assert F._bwd_kernel_name(qc, kc, kc, 1024, 1024, "split") == "split"
     assert F._BWD_DEFAULTS[8192] == (1024, 512)
     assert F._fused_vmem_bytes(16384, 64, 64, 1024, 1024, 2) \
         <= F._FUSED_VMEM_SHARE * F._VMEM_BYTES

@@ -146,46 +146,3 @@ def test_global_norm_clip():
     w1 = np.asarray(global_scope().find_var("fc_0.w_0"))
     # update magnitude bounded by clip norm
     assert np.abs(w1 - w0).sum() <= 0.01
-
-
-def test_fused_flat_adam_matches_per_param():
-    """AdamOptimizer(fused_flat=True) — one fused_adam op over all params
-    with a shared beta-pow pair — must track the per-param form exactly."""
-    import numpy as np
-    import paddle_tpu as pt
-    from paddle_tpu import layers
-    from paddle_tpu import optimizer as opt
-    from paddle_tpu.framework import Executor, Program, program_guard
-    from paddle_tpu.framework.scope import Scope, scope_guard
-
-    def run(fused, max_numel=None):
-        scope = Scope()
-        with scope_guard(scope), program_guard(Program(), Program()):
-            x = layers.data("x", shape=[8], dtype="float32")
-            y = layers.data("y", shape=[1], dtype="float32")
-            h = layers.fc(x, size=16, act="tanh")
-            pred = layers.fc(h, size=1)
-            loss = layers.mean(layers.square_error_cost(pred, y))
-            opt.AdamOptimizer(0.01, fused_flat=fused,
-                              fused_max_numel=max_numel).minimize(loss)
-            if fused:
-                types = [o.type for o in
-                         pt.default_main_program().global_block().ops]
-                assert "fused_adam" in types
-            exe = Executor()
-            exe.run(pt.default_startup_program(), scope=scope, seed=5)
-            rng = np.random.RandomState(0)
-            traj = []
-            for _ in range(5):
-                xv = rng.rand(16, 8).astype(np.float32)
-                yv = xv.sum(1, keepdims=True).astype(np.float32)
-                lv, = exe.run(feed={"x": xv, "y": yv},
-                              fetch_list=[loss.name], scope=scope)
-                traj.append(float(np.asarray(lv)))
-            return traj
-
-    base = run(False)
-    np.testing.assert_allclose(run(True), base, rtol=1e-6, atol=1e-7)
-    # bucketed: big params per-param, small ones fused — same trajectory
-    np.testing.assert_allclose(run(True, max_numel=20), base,
-                               rtol=1e-6, atol=1e-7)

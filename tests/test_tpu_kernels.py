@@ -80,7 +80,7 @@ def _flash_case(t, d, heads, dtype, causal, bwd_impl, bias=False):
 
 
 @tpu_hw
-@pytest.mark.parametrize("bwd_impl", ["combined", "split"])
+@pytest.mark.parametrize("bwd_impl", ["fused", "split"])
 @pytest.mark.parametrize("t,causal,heads", [
     (2048, True, 4),      # _FWD_DEFAULTS (1024,1024) / _BWD (1024,512)
     (4096, False, 2),     # (512,2048) / (1024,1024)
@@ -93,7 +93,7 @@ def test_flash_table_entries_d64(t, causal, heads, bwd_impl):
 
 
 @tpu_hw
-@pytest.mark.parametrize("bwd_impl", ["combined", "split"])
+@pytest.mark.parametrize("bwd_impl", ["fused", "split"])
 def test_flash_d128_baseline_blocks(bwd_impl):
     """d > 64 skips the tables: the (512, 1024) baseline blocks."""
     _flash_case(2048, 128, 2, jnp.bfloat16, True, bwd_impl)
@@ -103,7 +103,7 @@ def test_flash_d128_baseline_blocks(bwd_impl):
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_padded_length(causal):
     """T = 1000 divides no block: the padding masks run."""
-    _flash_case(1000, 64, 2, jnp.float32, causal, "combined")
+    _flash_case(1000, 64, 2, jnp.float32, causal, "fused")
     _flash_case(1000, 64, 2, jnp.float32, causal, "split")
 
 
@@ -111,7 +111,7 @@ def test_flash_padded_length(causal):
 def test_flash_with_bias():
     """A [1, 1, T, T] bias rides the forward kernel (the backward with a
     bias is the blockwise-jax path by design)."""
-    _flash_case(1024, 64, 2, jnp.float32, False, "combined", bias=True)
+    _flash_case(1024, 64, 2, jnp.float32, False, "fused", bias=True)
 
 
 #: the three flash cells' shapes, fewer heads (the dense oracle holds
@@ -160,64 +160,6 @@ def test_flash_backwards_at_the_cells_shapes(case, bwd_impl):
             ("dq", "dk", "dv"), got, split)})
     _record("flash_attention_cells", case=case, bwd_impl=bwd_impl, **errs)
     assert max(errs.values()) < TOL[jnp.bfloat16], errs
-
-
-# ---------------------------------------------------------------------------
-# layer norm, dense epilogue
-# ---------------------------------------------------------------------------
-
-@tpu_hw
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
-def test_fused_layer_norm_d768(dtype):
-    from paddle_tpu.pallas.layer_norm import _ln_ref, fused_layer_norm
-
-    x = _rand((128, 128, 768), 0, dtype) + 3.0
-    s, b = _rand((768,), 1) + 1.0, _rand((768,), 2)
-    w = _rand((128, 128, 768), 3)
-
-    def loss(fn):
-        return lambda x, s, b: jnp.sum(fn(x, s, b).astype(jnp.float32) * w)
-
-    ln_ref = functools.partial(_ln_ref, eps=1e-5)
-    errs = {"y": _rel_err(fused_layer_norm(x, s, b), ln_ref(x, s, b))}
-    g_got = jax.grad(loss(fused_layer_norm), argnums=(0, 1, 2))(x, s, b)
-    g_want = jax.grad(loss(ln_ref), argnums=(0, 1, 2))(x, s, b)
-    errs.update({n: _rel_err(g, r) for n, g, r in
-                 zip(("dx", "dscale", "dbias"), g_got, g_want)})
-    _record("fused_layer_norm", d=768, dtype=jnp.dtype(dtype).name, **errs)
-    assert max(errs.values()) < TOL[dtype], errs
-
-
-@tpu_hw
-@pytest.mark.parametrize("k,n,act", [(768, 3072, "gelu"), (3072, 768, ""),
-                                     (768, 768, "relu")])
-def test_matmul_bias_act_bert_ffn(k, n, act):
-    """BERT-base FFN shapes at batch 128 x seq 128, bf16 as under AMP."""
-    from paddle_tpu.pallas import matmul_bias_act
-
-    m = 128 * 128
-    x = _rand((m, k), 0, jnp.bfloat16)
-    w = _rand((k, n), 1, jnp.bfloat16, k ** -0.5)
-    b = _rand((n,), 2)
-
-    act_fn = {"gelu": functools.partial(jax.nn.gelu, approximate=False),
-              "relu": jax.nn.relu, "": lambda v: v}[act]
-
-    def ref(x, w, b):
-        y = jnp.dot(x, w, preferred_element_type=jnp.float32) + b
-        return act_fn(y).astype(x.dtype)
-
-    def loss(fn):
-        return lambda x, w, b: jnp.sum(fn(x, w, b).astype(jnp.float32) ** 2)
-
-    fused = functools.partial(matmul_bias_act, act=act)
-    errs = {"y": _rel_err(fused(x, w, b), ref(x, w, b))}
-    g_got = jax.grad(loss(fused), argnums=(0, 1, 2))(x, w, b)
-    g_want = jax.grad(loss(ref), argnums=(0, 1, 2))(x, w, b)
-    errs.update({n_: _rel_err(g, r) for n_, g, r in
-                 zip(("dx", "dw", "db"), g_got, g_want)})
-    _record("matmul_bias_act", k=k, n=n, act=act, **errs)
-    assert max(errs.values()) < 3e-2, errs
 
 
 # ---------------------------------------------------------------------------

@@ -33,8 +33,7 @@ dims in the memory plan and the fusion cost ranking (default 1: a
 per-example lower bound).
 
 ``--fusion`` is REPORT-ONLY (no rewrite is applied): every candidate
-with its legality verdict, per-class roofline rank, and — when
-``FLAGS_fusion_autotune`` is on — the cached micro-benchmark decision.
+with its legality verdict and per-class roofline rank.
 
 Exit status: 0 clean, 1 when ``--verify`` finds error-severity
 diagnostics, 2 on usage errors.
@@ -283,11 +282,6 @@ def main(argv=None) -> int:
               f"{len(r['candidates'])} matched ==")
         for c in r["candidates"]:
             extra = f" rule={c['rule']}" if c.get("rule") else ""
-            tune = c.get("autotune")
-            if tune:
-                extra += (f" autotune: fused {tune['fused_ms']} ms vs "
-                          f"base {tune['base_ms']} ms"
-                          + (" (cached)" if tune.get("cached") else ""))
             print(f"  [{c['verdict']:>13}] {c['pattern']:<22} "
                   f"@ {c['anchor']} rank={c['rank']:.3f}{extra}")
     return rc

@@ -1,8 +1,8 @@
 """Times the flash attention kernels alone at the JoyAI-LLM-Flash cell's
 shapes on the chip: latent attention's [1, 32, 8192, 192] queries and keys
 over [1, 32, 8192, 128] values in bf16, the causal half, forward and
-forward + backward, over block sizes and the three backward kernels (fused,
-combined, split); and what the rotary key costs as the program ships it:
+forward + backward, over block sizes and the two backward kernels (fused,
+split); and what the rotary key costs as the program ships it:
 the 64-wide rotary key is one head
 for all 32, broadcast and concatenated behind each head's 128-wide content
 part outside the kernel (``build``: Q's and K's concatenation, forward, and
@@ -43,14 +43,12 @@ if ROOT not in sys.path:
 
 FWD_BLOCKS = ("512,1024;1024,1024;512,512;1024,512;256,1024;512,2048;"
               "1024,2048;2048,1024;2048,512;2048,2048")
-# "combined" at [32, 8192, 192 | 128] would keep 2.68 GB of float32 dK/dV
-# partials at 1024-row query blocks: past _COMBINED_PARTIAL_BUDGET, so the
-# entry point runs the split kernels whatever is asked (one row shows it);
-# "fused" keeps none and asks for the VMEM its shapes need (PR 37), which is
-# what let the 1024 x 1024 and 2048-wide blocks compile at all
+# "fused" keeps nothing in HBM that grows with T² and asks for the VMEM its
+# shapes need (PR 37), which is what let the 1024 x 1024 and 2048-wide blocks
+# compile at all
 BWD_BLOCKS = ("fused,1024,512;fused,512,512;fused,1024,1024;fused,512,1024;"
               "fused,2048,512;fused,512,2048;fused,1024,256;fused,256,1024;"
-              "combined,1024,512;split,1024,512;split,512,512;"
+              "split,1024,512;split,512,512;"
               "split,512,1024;split,1024,1024")
 
 
@@ -183,7 +181,7 @@ def aot(args, F):
             row = {"bwd": [impl, bq, bk], "compiles": False,
                    "error": str(e).strip().splitlines()[-1][:160]}
         # what the entry point runs for this request, and the VMEM it asks
-        # for (the split and combined kernels: Mosaic's default 16 MiB)
+        # for (the split kernels: Mosaic's default 16 MiB)
         row["runs"] = F._bwd_kernel_name(s(args.d_qk), s(args.d_qk),
                                          s(args.d_v), bq, bk, impl)
         row["vmem_limit_mib"] = F._fused_vmem_bytes(
@@ -222,7 +220,7 @@ def main():
     if interpret:                                  # a rehearsal of the path
         args.seq, args.heads, args.iters = 64, 4, 1
         args.d_qk, args.d_v, args.d_rope = 24, 16, 8
-        args.fwd_blocks, args.bwd_blocks = "16,16", "combined,16,16"
+        args.fwd_blocks, args.bwd_blocks = "16,16", "fused,16,16"
     h, t = args.heads, args.seq
     d_nope = args.d_qk - args.d_rope
     key = jax.random.PRNGKey(0)

@@ -160,11 +160,10 @@ def main():
     from jax.sharding import SingleDeviceSharding
     import paddle_tpu  # noqa: F401
     from paddle_tpu import device
-    from paddle_tpu.ops import attention_ops, fused_ops
-    from paddle_tpu.pallas import layer_norm
+    from paddle_tpu.ops import attention_ops
     import importlib
     flash = importlib.import_module("paddle_tpu.pallas.flash_attention")
-    for mod in (device, fused_ops, layer_norm, flash):
+    for mod in (device, flash):
         mod.on_tpu = lambda: True
     from benchmark import harness
     import dp_arith_check

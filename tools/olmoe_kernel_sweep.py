@@ -101,7 +101,7 @@ def sweep_flash(f, bh=64, t=4096, dh=128):
         except Exception as e:
             rec["error"] = str(e).splitlines()[0][:300]
         emit(f, rec)
-    for impl in ("fused", "combined", "split"):
+    for impl in ("fused", "split"):
         for bq, bk in blocks:
             def loss(q, k, v):
                 o = flash_attention(q, k, v, causal=True, block_q=512,

@@ -1,7 +1,7 @@
 """Times the flash attention kernels alone at the Trinity-Mini cell's shapes
 on the chip: [1, 32, 8192, 128] queries over 4 K/V heads in bf16, the window
 of 2048 against the full causal half, the fused backward against the
-combined and the split ones, and (``--blocks``) other block sizes.  Prints one JSON line per
+split one, and (``--blocks``) other block sizes.  Prints one JSON line per
 case: forward ms, forward + backward ms, the backward's temporaries and, for
 the block tables' own choice, how far the output and the three gradients are
 from ``mha_reference`` (the dense-mask oracle, float32 at ``highest`` over
@@ -78,7 +78,7 @@ def main():
                     "--kv_heads 8 --head_dim 64 --window 0)")
     ap.add_argument("--blocks", default="",
                     help="bq_fwd,bk_fwd,bq_bwd,bk_bwd[;...] beside defaults")
-    ap.add_argument("--impls", default="fused,combined,split",
+    ap.add_argument("--impls", default="fused,split",
                     help="the backward kernels to time at each block choice")
     ap.add_argument("--forward", action="store_true",
                     help="the forward half alone over --fwd_blocks")
