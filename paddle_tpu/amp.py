@@ -49,6 +49,9 @@ BF16_IF_BIG = {
     "rms_norm", "rope",
     # float32 inside too; the [d, L] filter is a master weight and stays so
     "short_conv",
+    # the streams in bf16; Phi, Alpha, Bias and the maps float32 (the
+    # coefficients and Sinkhorn-Knopp are float32 inside: ops/hc_ops.py)
+    "hc_pre", "hc_post",
 }
 
 _COMPUTE = jnp.bfloat16
@@ -58,7 +61,8 @@ _FLOATS = (jnp.float32, jnp.bfloat16, jnp.float16)
 # not be rounded to bf16 every step — only the activation slot is cast
 _SLOT_RESTRICT = {"batch_norm": {"X"}, "layer_norm": {"X"},
                   "group_norm": {"X"}, "rms_norm": {"X"},
-                  "short_conv": {"X"}}
+                  "short_conv": {"X"}, "hc_pre": {"X"},
+                  "hc_post": {"X", "Y"}}
 
 # NOTE: the analysis.fusion targets (fused_dense_act,
 # fused_embedding_layer_norm) appear in NO list above on purpose: one

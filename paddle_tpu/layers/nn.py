@@ -281,10 +281,16 @@ def rms_norm(input, begin_norm_axis=1, epsilon=1e-5, param_attr=None,
     return out
 
 
-def rope(x, head_dim, theta=10000.0, name=None, interleaved=False):
+def rope(x, head_dim, theta=10000.0, name=None, interleaved=False,
+         rope_scaling=None):
     """Rotary position embedding over [batch, T, n * head_dim], before the
     head split: position = index along axis 1, rotate-half pairing
-    (dimension ``i`` of a head with ``i + head_dim / 2``).  A 4-D input is
+    (dimension ``i`` of a head with ``i + head_dim / 2``), pair ``i`` turning
+    by ``pos * theta^(-2i / head_dim)`` or, with ``rope_scaling`` (a
+    configuration's YaRN group, a dict), by ``pos *`` a per-pair frequency
+    table worked out on the host from it (``pallas.rope.yarn_frequencies``;
+    the softmax's length scaling is the attention's ``sm_scale``, not in
+    here).  A 4-D input is
     [batch, heads, T, head_dim], after the split: position = index along
     axis 2.  ``interleaved=True`` pairs adjacent dimensions ``(2i, 2i + 1)``
     instead (``rope_interleave`` of the DeepSeek family); a model that
@@ -301,6 +307,9 @@ def rope(x, head_dim, theta=10000.0, name=None, interleaved=False):
     attrs = {"head_dim": int(head_dim), "theta": float(theta)}
     if interleaved:
         attrs["interleaved"] = True
+    if rope_scaling is not None:
+        attrs["rope_scaling"] = {k: rope_scaling[k]
+                                 for k in sorted(rope_scaling)}
     helper.append_op("rope", inputs={"X": [x]}, outputs={"Out": [out]},
                      attrs=attrs)
     return out
@@ -1617,6 +1626,70 @@ def short_conv(x, filter_size=3, param_attr=None, name=None):
     helper.append_op("short_conv", inputs={"X": [x], "Filter": [w]},
                      outputs={"Out": [out]})
     return out
+
+
+def hc_pre(xs, sinkhorn_iters=20, eps=1e-6, rms_eps=1e-6,
+           res_clamp=(-30.0, 30.0), param_prefix="hc", init_std=0.02,
+           name=None):
+    """The read side of a manifold-constrained hyper-connection
+    (arXiv:2512.24880 §4; ``ops/hc_ops.py`` has the equations) over a
+    residual stream that is ``n = len(xs)`` streams wide, each a variable [b,
+    t, C]: returns ``(u, h_post, h_res)``, ``u`` [b, t, C] the sublayer's
+    input (before its norm), a token-dependent mix of the streams, and the
+    two float32 maps :func:`hc_post` writes the sublayer's output back with:
+    ``h_post`` [b, t, n] and ``h_res`` [b, t, n * n], doubly stochastic by
+    ``sinkhorn_iters`` Sinkhorn-Knopp iterations (``eps`` in both
+    denominators, the logits clamped to ``res_clamp`` before the
+    exponential).  Parameters, float32: ``<prefix>.phi`` [n C, 2 n + n^2]
+    (N(0, ``init_std``); rows ``j C .. (j + 1) C`` meet stream ``j``),
+    ``<prefix>.alpha`` [3] (0.01) and ``<prefix>.bias`` [2 n + n^2], which
+    starts the block as the plain residual it replaces: ``logit(1 / n)`` for
+    the read (the streams' mean), 0 for the write (``h_post`` = 1) and 4 on
+    the diagonal of the streams' own map (near the identity)."""
+    from ..initializer import NormalInitializer, NumpyArrayInitializer
+    from ..param_attr import ParamAttr
+    helper = LayerHelper("hc_pre", name=name)
+    xs = list(xs)
+    n = len(xs)
+    width, m = n * int(xs[0].shape[-1]), 2 * n + n * n
+
+    def param(what, shape, init):
+        return helper.create_parameter(
+            ParamAttr(name=f"{param_prefix}.{what}", initializer=init),
+            shape=shape, dtype="float32")
+
+    bias = np.zeros(m, np.float32)
+    bias[:n] = -np.log(n - 1.0) if n > 1 else 30.0
+    bias[2 * n:] = 4.0 * np.eye(n, dtype=np.float32).ravel()
+    phi = param("phi", [width, m], NormalInitializer(0.0, init_std))
+    alpha = param("alpha", [3], ConstantInitializer(0.01))
+    b = param("bias", [m], NumpyArrayInitializer(bias))
+    u = helper.create_variable_for_type_inference(xs[0].dtype)
+    h_post = helper.create_variable_for_type_inference("float32")
+    h_res = helper.create_variable_for_type_inference("float32")
+    helper.append_op(
+        "hc_pre", inputs={"X": xs, "Phi": [phi], "Alpha": [alpha],
+                          "Bias": [b]},
+        outputs={"U": [u], "HPost": [h_post], "HRes": [h_res]},
+        attrs={"n": n, "sinkhorn_iters": int(sinkhorn_iters),
+               "eps": float(eps), "rms_eps": float(rms_eps),
+               "res_clamp": [float(res_clamp[0]), float(res_clamp[1])]})
+    return u, h_post, h_res
+
+
+def hc_post(xs, y, h_post, h_res, sinkhorn_iters=20, name=None):
+    """The write side of a hyper-connection: the ``n`` next streams (a list
+    of variables [b, t, C]) from the streams ``xs``, the sublayer's output
+    ``y`` [b, t, C] and :func:`hc_pre`'s two maps: stream ``i`` is ``sum_j
+    h_res[i, j] xs[j] + h_post[i] y`` (``hc_post`` op, no parameters)."""
+    helper = LayerHelper("hc_post", name=name)
+    xs = list(xs)
+    outs = [helper.create_variable_for_type_inference(x.dtype) for x in xs]
+    helper.append_op(
+        "hc_post", inputs={"X": xs, "Y": [y], "HPost": [h_post],
+                           "HRes": [h_res]}, outputs={"Out": outs},
+        attrs={"n": len(xs), "sinkhorn_iters": int(sinkhorn_iters)})
+    return outs
 
 
 def switch_moe_ffn(x, num_experts, d_inner, capacity_factor=1.25,

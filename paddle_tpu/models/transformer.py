@@ -627,7 +627,14 @@ class JoyaiConfig:
     selection bias, renormalised and scaled) beside one shared expert;
     ``n_mtp`` multi-token-prediction modules (0 or 1) follow the last
     layer.  ``n_held``/``expert_offset``: the experts whose weights this
-    program holds (default all), as :class:`TrinityConfig` has them."""
+    program holds (default all), as :class:`TrinityConfig` has them.  What
+    the family's later members add is :class:`XingConfig`'s; here it is
+    absent: ``hc_mult`` 1 (the residual is the plain add) and
+    ``rope_scaling`` None (frequencies ``rope_theta^(-2i / d_rope)``, the
+    softmax scale ``(d_nope + d_rope)^-1/2``)."""
+
+    hc_mult = 1
+    rope_scaling = None
 
     def __init__(self, vocab_size=129280, d_model=2048, n_layer=40,
                  n_head=32, q_lora_rank=1536, kv_lora_rank=512, d_nope=128,
@@ -658,6 +665,64 @@ class JoyaiConfig:
         self.expert_offset = expert_offset
 
 
+class XingConfig(JoyaiConfig):
+    """Xing4.0-29B-A4B defaults (``XingChen-AGI/Xing4.0-29B-A4B``
+    config.json, ``model_type`` ``xing4_0``): the DeepSeek-V3 family's
+    sublayers as :class:`JoyaiConfig` has them, and two things round them.
+    The residual stream is ``hc_mult`` streams wide and every sublayer sits
+    in a manifold-constrained hyper-connection (:func:`hyper_connection`;
+    ``hc_sinkhorn_iters``, ``hc_eps`` and ``hc_res_clamp`` =
+    ``(mhc_h_res_clamp_min, mhc_h_res_clamp_max)`` are its Sinkhorn-Knopp's).
+    ``rope_scaling`` is the configuration's YaRN group: the rotary slice
+    turns by a per-pair frequency table and the softmax scale is multiplied
+    by ``(0.1 mscale_all_dim ln(factor) + 1)^2``.  No multi-token-prediction
+    module over a widened stream: ``n_mtp`` has to be 0 with ``hc_mult`` >
+    1."""
+
+    YARN = {"type": "yarn", "factor": 64, "beta_fast": 32, "beta_slow": 1,
+            "mscale": 1, "mscale_all_dim": 1,
+            "original_max_position_embeddings": 4096}
+
+    def __init__(self, vocab_size=131072, d_model=3584, n_layer=40,
+                 n_head=32, q_lora_rank=768, kv_lora_rank=512, d_nope=128,
+                 d_rope=64, d_v=128, d_inner=9216, d_expert=1024,
+                 n_experts=64, top_k=4, n_dense_layer=2, n_mtp=0,
+                 route_scale=2.0, rms_eps=1e-6, rope_theta=10000.0,
+                 n_held=None, expert_offset=0, hc_mult=4,
+                 hc_sinkhorn_iters=20, hc_eps=1e-6,
+                 hc_res_clamp=(-30.0, 30.0), rope_scaling=YARN):
+        super().__init__(
+            vocab_size=vocab_size, d_model=d_model, n_layer=n_layer,
+            n_head=n_head, q_lora_rank=q_lora_rank,
+            kv_lora_rank=kv_lora_rank, d_nope=d_nope, d_rope=d_rope,
+            d_v=d_v, d_inner=d_inner, d_expert=d_expert,
+            n_experts=n_experts, top_k=top_k, n_dense_layer=n_dense_layer,
+            n_mtp=n_mtp, route_scale=route_scale, rms_eps=rms_eps,
+            rope_theta=rope_theta, n_held=n_held,
+            expert_offset=expert_offset)
+        assert hc_mult == 1 or not n_mtp, \
+            "how the MTP module reads a widened stream is in no source"
+        self.hc_mult = int(hc_mult)
+        self.hc_sinkhorn_iters = int(hc_sinkhorn_iters)
+        self.hc_eps = float(hc_eps)
+        self.hc_res_clamp = (float(hc_res_clamp[0]), float(hc_res_clamp[1]))
+        self.rope_scaling = None if rope_scaling is None \
+            else dict(rope_scaling)
+
+
+def yarn_softmax_factor(rope_scaling):
+    """What YaRN multiplies the softmax scale by (the DeepSeek family's
+    ``yarn_get_mscale(factor, mscale_all_dim)`` squared): ``(0.1
+    mscale_all_dim ln(factor) + 1)^2``; 1 without a scaling group, without
+    ``mscale_all_dim`` or at a factor of 1 or less."""
+    import math
+    if not rope_scaling or not rope_scaling.get("mscale_all_dim") \
+            or rope_scaling["factor"] <= 1:
+        return 1.0
+    return (0.1 * rope_scaling["mscale_all_dim"]
+            * math.log(rope_scaling["factor"]) + 1.0) ** 2
+
+
 def latent_attention(x, cfg: JoyaiConfig, param_prefix="attn"):
     """Multi-head latent attention (MLA, arXiv:2405.04434 / 2412.19437
     §2.1.1) over ``x`` [b, t, d_model], causal, no bias, the training form
@@ -676,8 +741,11 @@ def latent_attention(x, cfg: JoyaiConfig, param_prefix="attn"):
     content part outside the kernel, which then takes one ``d_nope +
     d_rope`` wide K (``tools/joyai_kernel_probe.py`` says what that costs).
     The scores contract over ``d_nope + d_rope`` and the values are ``d_v``
-    wide: the flash kernels' two widths.  Everything but the flash op lies
-    under the ``mla_proj`` tag.  Parameters: ``<prefix>.a.w``,
+    wide: the flash kernels' two widths.  With ``cfg.rope_scaling`` (YaRN)
+    the rotary slice turns by ``layers.rope``'s frequency-table form and the
+    softmax scale is multiplied by :func:`yarn_softmax_factor`.  Everything
+    but the flash op lies under the ``mla_proj`` tag.  Parameters:
+    ``<prefix>.a.w``,
     ``.q_norm.w``, ``.kv_norm.w``, ``.q_b.w``, ``.kv_b.w``, ``.out.w``."""
     h, dn, dr, dv = cfg.n_head, cfg.d_nope, cfg.d_rope, cfg.d_v
 
@@ -695,7 +763,8 @@ def latent_attention(x, cfg: JoyaiConfig, param_prefix="attn"):
             layers.reshape(v, shape=[0, 0, h, width]), perm=[0, 2, 1, 3])
 
     def rotate(v):
-        return layers.rope(v, dr, cfg.rope_theta, interleaved=True)
+        return layers.rope(v, dr, cfg.rope_theta, interleaved=True,
+                           rope_scaling=cfg.rope_scaling)
 
     with name_scope("mla_proj"):
         c_q, c_kv, k_r = layers.split(
@@ -710,16 +779,45 @@ def latent_attention(x, cfg: JoyaiConfig, param_prefix="attn"):
         k_r = rotate(layers.unsqueeze(k_r, [1]))            # [b, 1, t, dr]
         q = layers.concat([q_nope, rotate(q_rope)], axis=3)
         k = layers.concat([k_nope, layers.expand(k_r, [1, h, 1, 1])], axis=3)
-    ctx = layers.flash_attention(q, k, v, causal=True,
-                                 sm_scale=float(dn + dr) ** -0.5)
+    ctx = layers.flash_attention(
+        q, k, v, causal=True, sm_scale=float(dn + dr) ** -0.5
+        * yarn_softmax_factor(cfg.rope_scaling))
     with name_scope("mla_proj"):
         ctx = layers.reshape(layers.transpose(ctx, perm=[0, 2, 1, 3]),
                              shape=[0, 0, h * dv])
         return proj(ctx, cfg.d_model, "out")
 
 
+def plain_residual(x, sublayer, name):
+    """The residual rule every block here had hard-wired: ``x + F(norm(x))``.
+    ``sublayer(x)`` norms its input and returns ``F``'s terms, which are
+    added to ``x`` one by one, in their order."""
+    for term in sublayer(x):
+        x = x + term
+    return x
+
+
+def hyper_connection(cfg: XingConfig):
+    """The residual rule of a stream ``cfg.hc_mult`` wide, held as a list
+    of that many [b, t, d] variables (manifold-constrained
+    hyper-connections, arXiv:2512.24880 §4; ``layers.hc_pre`` /
+    ``layers.hc_post``): the sublayer reads ``u``, a learned token-dependent
+    mix of the streams, and its output is written back to every stream
+    beside a doubly stochastic mix of the streams themselves.  Parameters
+    ``<name>.phi``, ``.alpha``, ``.bias``, one set a sublayer."""
+    def rule(x, sublayer, name):
+        u, h_post, h_res = layers.hc_pre(
+            x, cfg.hc_sinkhorn_iters, cfg.hc_eps, cfg.rms_eps,
+            cfg.hc_res_clamp, param_prefix=name)
+        y = None
+        for term in sublayer(u):
+            y = term if y is None else y + term
+        return layers.hc_post(x, y, h_post, h_res, cfg.hc_sinkhorn_iters)
+    return rule
+
+
 def joyai_decoder_layer(x, cfg: JoyaiConfig, idx=0, dense=None,
-                        param_prefix=None):
+                        param_prefix=None, residual=plain_residual):
     """Pre-norm block, two norms: ``h = x + MLA(RMS1(x))``, ``out = h +
     FFN(RMS2(h))``; no bias anywhere.  FFN: :func:`gated_ffn` of width
     ``d_inner`` in a dense layer (``dense``, default ``idx <
@@ -727,31 +825,44 @@ def joyai_decoder_layer(x, cfg: JoyaiConfig, idx=0, dense=None,
     ``d_expert``) plus ``moe_ffn`` as Trinity's block calls it
     (``noaux_tc`` with one group: sigmoid scores, a selection bias held at
     zero, the kept scores renormalised with ``1e-20`` and scaled).
+    ``residual(x, sublayer, name)`` is the rule that puts a sublayer's
+    output back into the stream, :func:`plain_residual` (the ``+`` above) by
+    default; :func:`hyper_connection` makes the one of a widened stream,
+    whose parameters are ``<prefix>.hc_attn.*`` and ``<prefix>.hc_ffn.*``.
     ``param_prefix`` (default ``dec_<idx>``) names the parameters.  Returns
     ``(out, expert_load or None)``."""
     from ..initializer import NormalInitializer
     p = param_prefix or f"dec_{idx}"
     dense = idx < cfg.n_dense_layer if dense is None else dense
+    loads = []
 
     def norm(v, name):
         return layers.rms_norm(v, begin_norm_axis=2, epsilon=cfg.rms_eps,
                                param_attr=ParamAttr(name=f"{p}.{name}.w"))
 
-    h = x + latent_attention(norm(x, "ln1"), cfg, f"{p}.attn")
-    m = norm(h, "ln2")
-    if dense:
-        with name_scope("dense_ffn"):
-            return h + gated_ffn(m, cfg.d_inner, cfg.d_model, f"{p}.ffn"), None
-    with name_scope("shared_expert"):
-        f = gated_ffn(m, cfg.d_expert, cfg.d_model, f"{p}.shared")
-    moe, _, _, load = layers.moe_ffn(
-        m, cfg.n_experts, cfg.top_k, cfg.d_expert,
-        norm_topk_prob=True, param_prefix=f"{p}.moe",
-        initializer=NormalInitializer(0.0, 0.02),
-        score_func="sigmoid", select_bias=True, norm_eps=1e-20,
-        route_scale=cfg.route_scale, num_held=cfg.n_held,
-        expert_offset=cfg.expert_offset)
-    return h + f + moe, load
+    def attention(u):
+        return [latent_attention(norm(u, "ln1"), cfg, f"{p}.attn")]
+
+    def ffn(u):
+        m = norm(u, "ln2")
+        if dense:
+            with name_scope("dense_ffn"):
+                return [gated_ffn(m, cfg.d_inner, cfg.d_model, f"{p}.ffn")]
+        with name_scope("shared_expert"):
+            f = gated_ffn(m, cfg.d_expert, cfg.d_model, f"{p}.shared")
+        moe, _, _, load = layers.moe_ffn(
+            m, cfg.n_experts, cfg.top_k, cfg.d_expert,
+            norm_topk_prob=True, param_prefix=f"{p}.moe",
+            initializer=NormalInitializer(0.0, 0.02),
+            score_func="sigmoid", select_bias=True, norm_eps=1e-20,
+            route_scale=cfg.route_scale, num_held=cfg.n_held,
+            expert_offset=cfg.expert_offset)
+        loads.append(load)
+        return [f, moe]
+
+    h = residual(x, attention, f"{p}.hc_attn")
+    out = residual(h, ffn, f"{p}.hc_ffn")
+    return out, (loads[0] if loads else None)
 
 
 def build_joyai_pretrain(cfg: JoyaiConfig, seq_len, mtp_weight=0.3,
@@ -768,8 +879,13 @@ def build_joyai_pretrain(cfg: JoyaiConfig, seq_len, mtp_weight=0.3,
     name, so each one's gradient is the sum of its two uses.  Loss =
     ``L_main + mtp_weight * L_mtp`` and nothing else (the selection bias is
     held at zero, as in :func:`build_trinity_pretrain`).  The module lies
-    under the ``mtp`` tag.  ``checkpoints=[]`` collects the block outputs
-    (the module's among them) for ``RecomputeOptimizer``.  Returns ``(feeds,
+    under the ``mtp`` tag.  With ``cfg.hc_mult`` > 1 (:class:`XingConfig`)
+    the stream between the blocks is ``hc_mult`` variables [b, t, d_model]
+    and the blocks' residual rule is :func:`hyper_connection`; its entry and
+    exit are here (arXiv:2409.19606 §3): the embedding copied to every
+    stream, and the streams' sum before the final norm.  ``checkpoints=[]``
+    collects the block outputs (the module's among them; every stream of a
+    widened one) for ``RecomputeOptimizer``.  Returns ``(feeds,
     parts, loss)`` with ``parts`` = {"expert_load": [per expert layer, the
     module's last], "hidden": ``z``, "mtp_hidden": the module's normed
     output, "main_loss", "mtp_loss"}."""
@@ -786,13 +902,20 @@ def build_joyai_pretrain(cfg: JoyaiConfig, seq_len, mtp_weight=0.3,
                                param_attr=ParamAttr(name=name))
 
     x = embed(src_ids)
+    residual = plain_residual
+    if cfg.hc_mult > 1:
+        residual = hyper_connection(cfg)
+        # a variable a stream: the same embedding read hc_mult times
+        x = [layers.scale(x, scale=1.0) for _ in range(cfg.hc_mult)]
     loads = []
     for i in range(cfg.n_layer):
-        x, load = joyai_decoder_layer(x, cfg, i)
+        x, load = joyai_decoder_layer(x, cfg, i, residual=residual)
         if load is not None:
             loads.append(load)
         if checkpoints is not None:
-            checkpoints.append(x)
+            checkpoints.extend(x if cfg.hc_mult > 1 else [x])
+    if cfg.hc_mult > 1:
+        x = layers.sums(x)
     z = norm(x, "final_norm.w")
     _, main_loss = _lm_head_loss(z, cfg, lm_label, fused_head, "lm_out",
                                  bias=False)

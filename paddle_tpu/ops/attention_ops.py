@@ -196,17 +196,20 @@ ROPE_LOWERINGS_CTR = _monitor.REGISTRY.counter(
     "kernel's conditions fail: no TPU, a head that is not 64 (4-D) or whole "
     "128-lane tiles wide, a ragged length, a mesh of several devices), the "
     "pairing (half: i with i + head_dim / 2; interleaved: 2i with 2i + 1) "
-    "and the width of what is rotated (head_dim) — counted while tracing, "
-    "once per compile of a block that holds the op, nothing per step",
-    ("form", "pairing", "width"))
+    "the width of what is rotated (head_dim) and where the frequencies come "
+    "from (theta: theta^(-2i / head_dim) computed in the program; table: a "
+    "constant worked out on the host from the op's rope_scaling attribute) — "
+    "counted while tracing, once per compile of a block that holds the op, "
+    "nothing per step",
+    ("form", "pairing", "width", "frequencies"))
 
 
-def _rope_xla(x, dh, theta, interleaved):
+def _rope_xla(x, dh, theta, interleaved, rope_scaling=None):
     """The jnp form: float32 angles, sines and rotation, X's dtype out."""
     from ..pallas.rope import angles
     half = dh // 2
     t = x.shape[2] if x.ndim == 4 else x.shape[1]
-    ang = angles(t, dh, theta)
+    ang = angles(t, dh, theta, rope_scaling)
     if x.ndim == 4:                              # [b, heads, t, dh]
         cos, sin, xf = jnp.cos(ang), jnp.sin(ang), x.astype(jnp.float32)
     else:
@@ -236,6 +239,7 @@ def _rope_call(ctx, x, attrs, transpose):
     dh = int(attrs["head_dim"])
     theta = float(attrs.get("theta", 10000.0))
     interleaved = bool(attrs.get("interleaved"))
+    scaling = attrs.get("rope_scaling")
     # shape inference runs this lowering abstractly: the jnp form, uncounted
     abstract = getattr(ctx, "is_abstract", False)
     mesh = ctx.mesh
@@ -244,21 +248,31 @@ def _rope_call(ctx, x, attrs, transpose):
     if not abstract:
         ROPE_LOWERINGS_CTR.inc(
             form="kernel" if use_kernel else "xla",
-            pairing="interleaved" if interleaved else "half", width=str(dh))
+            pairing="interleaved" if interleaved else "half", width=str(dh),
+            frequencies="theta" if scaling is None else "table")
     if use_kernel:
-        return kernel.rope(x, dh, theta, interleaved, transpose=transpose)
+        return kernel.rope(x, dh, theta, interleaved, transpose=transpose,
+                           rope_scaling=scaling)
     if transpose:
-        return jax.vjp(lambda v: _rope_xla(v, dh, theta, interleaved),
-                       x)[1](x)[0]
-    return _rope_xla(x, dh, theta, interleaved)
+        return jax.vjp(lambda v: _rope_xla(v, dh, theta, interleaved,
+                                           scaling), x)[1](x)[0]
+    return _rope_xla(x, dh, theta, interleaved, scaling)
 
 
 def _rope(ctx, ins, attrs):
     """Rotary position embedding (Su et al. 2021) in the rotate-half
     convention over the whole head: X is [batch, T, n * head_dim], the
     position is the index along axis 1, and within each head dimension ``i``
-    pairs with ``i + head_dim / 2`` at the angle ``pos * theta^(-2i /
-    head_dim)``.  Angles, sines and the rotation are float32; the output
+    pairs with ``i + head_dim / 2`` at the angle ``pos * f_i``.  The
+    frequencies have two forms: ``f_i = theta^(-2i / head_dim)``, computed in
+    the program from the attribute ``theta`` (the default), or, with the
+    attribute ``rope_scaling`` (a configuration's YaRN group: ``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``), a
+    ``[head_dim / 2]`` table worked out on the host and held as a constant
+    (``pallas.rope.yarn_frequencies``: each pair's blend of the original and
+    the ``factor`` times slower frequency; the same table at every length,
+    sines and cosines unscaled).  Both forms go through the same kernel or
+    the same jnp.  Angles, sines and the rotation are float32; the output
     has the input's dtype.  A 4-D X is [batch, heads, T, head_dim], after
     the head split (where a per-head norm comes first), with the position
     along axis 2.  ``interleaved`` (default false): dimension ``2i`` pairs

@@ -50,18 +50,55 @@ def _lanes(n):
     return -(-n // _LANE) * _LANE
 
 
-def angles(t, head_dim, theta):
+def yarn_frequencies(head_dim, theta, rope_scaling):
+    """``[head_dim / 2]`` float32 on the host: YaRN's per-pair blend of the
+    original and the interpolated frequencies (arXiv:2309.00071 §3.2, as the
+    DeepSeek family's code builds its static table).  With ``f_i = theta^(-2i
+    / head_dim)`` and ``c(beta) = head_dim ln(original / (2 pi beta)) / (2 ln
+    theta)``: ``lo = floor(c(beta_fast))``, ``hi = ceil(c(beta_slow))`` (both
+    kept inside the table), ``g_i = clip((i - lo) / (hi - lo), 0, 1)`` and
+    ``f'_i = (1 - g_i) f_i + g_i f_i / factor``: the fast pairs turn as they
+    did, the slow ones ``factor`` times slower, at every length.  The sines
+    and cosines are not scaled: ``mscale / mscale_all_dim`` has to be 1 (the
+    length scaling of the softmax is the attention's ``sm_scale``)."""
+    import numpy as np
+    s = rope_scaling
+    if s.get("type", s.get("rope_type")) != "yarn":
+        raise ValueError(f"rope_scaling {s!r}: yarn is the one form here")
+    if float(s.get("mscale", 1)) != float(s.get("mscale_all_dim", 1)):
+        raise ValueError("rope_scaling with mscale != mscale_all_dim would "
+                         "scale the sines and cosines: not implemented")
+    half = head_dim // 2
+
+    def correction(beta):
+        return head_dim * np.log(
+            s["original_max_position_embeddings"] / (2 * np.pi * beta)) / (
+                2 * np.log(theta))
+
+    lo = max(int(np.floor(correction(s["beta_fast"]))), 0)
+    hi = min(int(np.ceil(correction(s["beta_slow"]))), head_dim - 1)
+    ramp = np.clip((np.arange(half) - lo) / (max(hi - lo, 1e-3)), 0.0, 1.0)
+    f = float(theta) ** (-2.0 * np.arange(half) / head_dim)
+    return ((1.0 - ramp) * f + ramp * f / float(s["factor"])).astype(
+        np.float32)
+
+
+def angles(t, head_dim, theta, rope_scaling=None):
     """``[t, head_dim / 2]`` float32: ``pos * theta^(-2i / head_dim)``, what
-    both forms of the op turn by."""
-    inv = theta ** (-jnp.arange(head_dim // 2, dtype=jnp.float32) * 2.0
-                    / head_dim)
+    both forms of the op turn by; with ``rope_scaling`` ``pos *`` the table
+    of :func:`yarn_frequencies`, a constant of the program."""
+    if rope_scaling is not None:
+        inv = jnp.asarray(yarn_frequencies(head_dim, theta, rope_scaling))
+    else:
+        inv = theta ** (-jnp.arange(head_dim // 2, dtype=jnp.float32) * 2.0
+                        / head_dim)
     return jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
 
 
-def tables(t, head_dim, theta, interleaved):
+def tables(t, head_dim, theta, interleaved, rope_scaling=None):
     """``(C, S)``, ``[t, head_dim]`` float32: the cosine of each lane's angle
     and its sine, signed: ``out = x * C + partner(x) * S``."""
-    ang = angles(t, head_dim, theta)
+    ang = angles(t, head_dim, theta, rope_scaling)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     if interleaved:
         return (jnp.repeat(cos, 2, axis=1),
@@ -164,12 +201,15 @@ def _call(shape, dtype, head_dim, interleaved, interpret):
 
 
 def rope(x, head_dim, theta=10000.0, interleaved=False, transpose=False,
-         interpret=False):
+         interpret=False, rope_scaling=None):
     """``x`` rotated by its position: 3-D ``[b, t, heads * head_dim]`` or 4-D
     ``[b, heads, t, head_dim]``, in ``x``'s dtype.  ``transpose``: turned
     back, which is the gradient of the rotation with respect to ``x`` at the
-    cotangent ``x``.  The shapes have to pass :func:`fits`."""
-    c, s = tables(x.shape[-2], head_dim, theta, bool(interleaved))
+    cotangent ``x``.  ``rope_scaling``: the frequencies of
+    :func:`yarn_frequencies` in ``theta^(-2i / head_dim)``'s place.  The
+    shapes have to pass :func:`fits`."""
+    c, s = tables(x.shape[-2], head_dim, theta, bool(interleaved),
+                  rope_scaling)
     call = _call(tuple(x.shape), jnp.dtype(x.dtype), int(head_dim),
                  bool(interleaved), bool(interpret))
     return call(x, c, -s if transpose else s)

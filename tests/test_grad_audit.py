@@ -392,6 +392,16 @@ def _configs(op):
         "reverse": lambda: _Cfg({"X": [f(2, 3)]}, {"axis": [0]}),
         "row_conv": lambda: _Cfg({"X": [f(2, 4, 3)], "Filter": [f(2, 3)]}),
         "short_conv": lambda: _Cfg({"X": [f(2, 5, 9)], "Filter": [f(3, 3)]}),
+        # two streams of width 4: Phi [2 * 4, 2 * 2 + 4], three iterations
+        "hc_pre": lambda: _Cfg(
+            {"X": [f(2, 3, 4), f(2, 3, 4)], "Phi": [f(8, 8, lo=-0.3, hi=0.3)],
+             "Alpha": [f(3, lo=0.3, hi=0.9)], "Bias": [f(8, lo=-0.5, hi=0.5)]},
+            {"n": 2, "sinkhorn_iters": 3, "eps": 1e-6, "rms_eps": 1e-6,
+             "res_clamp": [-30.0, 30.0]}),
+        "hc_post": lambda: _Cfg(
+            {"X": [f(2, 3, 4), f(2, 3, 4)], "Y": [f(2, 3, 4)],
+             "HPost": [f(2, 3, 2)], "HRes": [f(2, 3, 4)]},
+            {"n": 2, "sinkhorn_iters": 3}),
         "sample_logits": lambda: _Cfg(
             {"Logits": [f(3, 5)], "Labels": [i(3, 1, n=5)]},
             {"num_samples": 2, "seed": 3}, loss_outputs=["SampledLogits"]),

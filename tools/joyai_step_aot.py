@@ -10,8 +10,10 @@ parameter read again behind its optimizer update) and which backward kernel
 each ``flash_attention_grad`` of the step got
 (``paddle_tpu_flash_bwd_kernel_total``), the form each forward lowering
 writes ``lse`` in (``paddle_tpu_flash_lowerings_total{lse}``) and the form each
-``rope`` and ``rope_grad`` got (``paddle_tpu_rope_lowerings_total``), with and without
-``--recompute``: whether the step fits beside its state, and what fitting
+``rope`` and ``rope_grad`` got (``paddle_tpu_rope_lowerings_total``, the
+frequency-table form told from ``theta``'s) and, for ``--cell xing4``, the
+hyper-connection ops' lowerings (``paddle_tpu_hc_lowerings_total``), with
+and without ``--recompute``: whether the step fits beside its state, and what fitting
 costs (PERF.md section 7, row 31).  Nothing runs: no time comes from this.  The adapter has the
 recomputing step only (the traffic file's); without ``--recompute`` this
 tool puts a pass-through in ``RecomputeOptimizer``'s place.
@@ -57,7 +59,8 @@ CELLS = {"joyai": ("joyai_llm_flash", "lm_mtp_s8192"),
          "trinity": ("trinity_mini", "lm_s8192"),
          "olmoe": ("olmoe_1b_7b", "lm_s4096"),
          "smallthinker": ("smallthinker_21b_a3b", "lm_s16384"),
-         "lfm2": ("lfm2_8b_a1b", "lm_s16384_r64")}
+         "lfm2": ("lfm2_8b_a1b", "lm_s16384_r64"),
+         "xing4": ("xing4_29b_a4b", "lm_s4096_r64")}
 
 
 def reads_after_update(text):
@@ -147,7 +150,8 @@ def main():
                     "olmoe (their steps have no recomputation: leave "
                     "--recompute out, as for smallthinker and lfm2; lfm2's "
                     "adapter builds ISSUE 40's fallback where the traffic "
-                    "says recompute, which --recompute sets)")
+                    "says recompute, which --recompute sets, and so does "
+                    "xing4's, whose timed step is the plain one)")
     args = ap.parse_args()
     if args.run:
         return run_on_chip(args)
@@ -179,7 +183,7 @@ def main():
         config["num_hidden_layers"] = args.layers
     if args.seq:
         traffic["seq_len"] = args.seq
-    if args.recompute and args.cell == "lfm2":
+    if args.recompute and args.cell in ("lfm2", "xing4"):
         traffic["recompute"] = True      # the adapter builds the fallback
     m = adapter.build_train(config, traffic, 7, 1, False)
     cb, step_args = dp_arith_check.caught_step(lambda: m["exe"].run(
@@ -206,9 +210,17 @@ def main():
 
     def rope_lowerings():
         """The step's rope and rope_grad lowerings (a recomputed clone
-        counts) by form (kernel | xla), pairing and width."""
+        counts) by form (kernel | xla), pairing, width and where the
+        frequencies come from (theta | table)."""
         return counted(attention_ops.ROPE_LOWERINGS_CTR,
-                       "form", "pairing", "width")
+                       "form", "pairing", "width", "frequencies")
+
+    def hc_lowerings():
+        """The step's hc_pre / hc_post lowerings and their grads' (a
+        recomputed clone counts) by op, streams, iterations and form."""
+        from paddle_tpu.ops import hc_ops
+        return counted(hc_ops.HC_LOWERINGS_CTR, "op", "n", "sinkhorn_iters",
+                       "impl")
     if args.lowered:
         text = re.sub(r"loc\(.*?\)", "", cb.jitted.lower(*shapes).as_text())
         with open(args.lowered, "w") as f:
@@ -221,7 +233,8 @@ def main():
         print(json.dumps({"cell": args.cell, "lowered": args.lowered,
                           "bytes": len(text), "flash_fwd_results": results,
                           "flash_fwd_lse": flash_fwd_lse(),
-                          "rope_lowerings": rope_lowerings()}))
+                          "rope_lowerings": rope_lowerings(),
+                          "hc_lowerings": hc_lowerings()}))
         return 0
     try:
         compiled = cb.jitted.lower(*shapes).compile()
@@ -249,6 +262,7 @@ def main():
                                      "kernel", "window", "widths"),
         "flash_fwd_lse": flash_fwd_lse(),
         "rope_lowerings": rope_lowerings(),
+        "hc_lowerings": hc_lowerings(),
         "parameters_m": sum(int(np.prod(p.shape))
                             for p in m["parameters"]) / 1e6}))
     return 0

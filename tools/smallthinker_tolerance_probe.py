@@ -20,7 +20,11 @@ cell's own decision on each: ``control``, not correct and by which limits.
 
 ``--cell lfm2`` (PR 40): the same three readings for the LFM2 cell, its
 adapter, reference, configuration and traffic in SmallThinker's place (4 of
-32 experts; the table read by the lookup and the head).
+32 experts; the table read by the lookup and the head).  ``--cell xing4`` (PR
+45): the Xing4.0 cell's (4 of 64 experts; a fourth kind of gradient leaf,
+the hyper-connections' ``maps``), and one reading more: the first
+hyper-connection's ``H_res`` by the reference in float32 and in bf16, row and
+column sums against 1 (``h_res_sums``: Sinkhorn-Knopp run in bf16).
 """
 
 import argparse
@@ -34,12 +38,18 @@ if ROOT not in sys.path:
 
 
 #: cell -> (configuration = adapter = reference, traffic, the adapter's
-#: configuration function, the builder, the cell's test module and its toy)
+#: configuration function, the builder, the cell's test module and its toy,
+#: what the builder takes to build the forward alone)
 CELLS = {"smallthinker": ("smallthinker_21b_a3b", "lm_s16384",
                           "smallthinker_config", "build_smallthinker_pretrain",
-                          "test_smallthinker_cell", "toy_smallthinker"),
+                          "test_smallthinker_cell", "toy_smallthinker",
+                          {"is_test": True}),
          "lfm2": ("lfm2_8b_a1b", "lm_s16384_r64", "lfm2_config",
-                  "build_lfm2_pretrain", "test_lfm2_cell", "toy_lfm2")}
+                  "build_lfm2_pretrain", "test_lfm2_cell", "toy_lfm2",
+                  {"is_test": True}),
+         "xing4": ("xing4_29b_a4b", "lm_s4096_r64", "xing_config",
+                   "build_joyai_pretrain", "test_xing4_cell", "toy_xing",
+                   {})}
 
 
 def main():
@@ -53,7 +63,8 @@ def main():
     import numpy as np
     from benchmark import harness
     from benchmark.models import _train, olmoe_1b_7b as olmoe
-    name, mix, config_of, builder, test_module, toy_of = CELLS[args.cell]
+    name, mix, config_of, builder, test_module, toy_of, forward_kw = \
+        CELLS[args.cell]
     adapter = importlib.import_module("benchmark.models." + name)
     reference = importlib.import_module("benchmark.reference." + name)
     on_chip = jax.default_backend() == "tpu"
@@ -71,10 +82,11 @@ def main():
     from paddle_tpu.models import transformer as T
     cfg = getattr(adapter, config_of)(config)
     q_block = traffic["reference_q_block"]
+    kinds = getattr(adapter, "KINDS", ("rest", "experts", "router"))
     for seed in map(int, args.seeds.split(",")):
         scope, main_p, startup = Scope(), Program(), Program()
         with scope_guard(scope), program_guard(main_p, startup):
-            getattr(T, builder)(cfg, traffic["seq_len"], is_test=True)
+            getattr(T, builder)(cfg, traffic["seq_len"], **forward_kw)
             _train.executor(on_chip).run(
                 startup, scope=scope, seed=harness.exe_seed(
                     traffic["weights_seed"]))
@@ -138,7 +150,20 @@ def main():
                                         float("inf")),
             "first_hidden_relative":
                 a["hidden_rel_all"] > tol["first_hidden_relative"]}
-        for kind in ("rest", "experts", "router"):
+        if hasattr(reference, "hc_maps"):
+            # Sinkhorn-Knopp in the precision below: the first
+            # hyper-connection's H_res over the timed tokens' embeddings
+            kw = adapter.reference_kw(cfg, q_block)
+            for what, p in (("float32", params), ("bf16", cast())):
+                x = reference.entry(p["wte"][jnp.asarray(
+                    feed["src_ids"][0])], kw["hc_mult"])
+                h_res = reference.hc_maps(
+                    x, p["blocks"][0]["hc_attn"], kw["eps"], kw["hc_iters"],
+                    kw["hc_eps"], kw["hc_clamp"])[2]
+                out[f"h_res_sums_{what}"] = adapter.stochastic_off(
+                    [np.asarray(h_res, np.float32)], kw["hc_mult"])
+            failed["h_res_sums"] = out["h_res_sums_bf16"] > tol["h_res_sums"]
+        for kind in kinds:
             failed[f"first_gradient_{kind}_relative"] = \
                 a["gradient"][kind][adapter.DECIDES[kind]] > \
                 tol[f"first_gradient_{kind}_relative"]
