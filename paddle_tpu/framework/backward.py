@@ -151,7 +151,12 @@ def _append_backward_tagged(block, program, loss, no_grad, relevant, needed,
         contribs[n] = [("value", rd(n))]
 
     for di, d in enumerate(descs):
-        raw_ins = {n for names in d["inputs"].values() for n in names if n}
+        # walked in the desc's own slot order, each name once: the walk's
+        # order is the order of the ``sum`` ops in the program, and the
+        # program is the compile cache's key — a ``set`` here gives every
+        # process (every string-hash seed) another step
+        raw_ins = dict.fromkeys(
+            n for names in d["inputs"].values() for n in names if n)
         for n in raw_ins:
             _materialize(n, di)
         for slot, names in d["inputs"].items():

@@ -2,13 +2,17 @@
 the OpTest check_grad pattern (ref tests/unittests/op_test.py:767,
 get_numeric_gradient:46)."""
 
+import itertools
+
 import numpy as np
 import pytest
 
 import paddle_tpu as pt
 from paddle_tpu import layers
-from paddle_tpu.framework import Executor, append_backward, grad_var_name
+from paddle_tpu.framework import (Executor, Program, append_backward,
+                                  grad_var_name, program_guard)
 from paddle_tpu.framework.core import default_main_program
+from paddle_tpu.framework.registry import register_op
 
 
 def _numeric_grad(run_loss, x0, eps=1e-3):
@@ -121,3 +125,46 @@ def test_fanout_with_consuming_grad_op():
     out, = exe.run(feed={"x": xv}, fetch_list=[grad_var_name("x")])
     # d/dx [ mean(2x) + mean(6x) + mean(4x^2) ] = (2 + 6 + 8x)/3
     np.testing.assert_allclose(out, (8.0 + 8.0 * xv) / 3.0, rtol=1e-5)
+
+
+def _four_streams(ctx, ins, attrs):
+    x, = ins["X"]
+    return {"Out": [x * float(i + 1) for i in range(4)]}
+
+
+def test_a_slots_gradient_sums_precede_their_reader_in_slot_order():
+    """One grad op reading a four-variable slot whose gradients each have
+    two contributions (Xing4.0's ``hc_post_grad`` over the four residual
+    streams): the four ``sum`` ops enter the program in the slot's order
+    whatever the variables are called.  Permuting the names permutes their
+    hashes, so a walk over a ``set`` of them fails for some permutation in
+    any process, and the program's op order is the compile cache's key."""
+    register_op("toy_four_streams", _four_streams)
+    for names in itertools.permutations(["s_a", "s_b", "s_c", "s_d"]):
+        main = Program()
+        with program_guard(main, Program()):
+            x = layers.data("x", shape=[3], dtype="float32")
+            x.stop_gradient = False
+            block = main.global_block()
+            streams = [block.create_var(name=n, shape=x.shape,
+                                        dtype="float32") for n in names]
+            block.append_op("toy_four_streams", inputs={"X": [x.name]},
+                            outputs={"Out": list(names)})
+            loss = layers.mean(layers.sums(
+                [layers.scale(s, scale=k) for s in streams
+                 for k in (2.0, 3.0)]))
+            append_backward(loss)
+        ops = block.ops
+        at = next(i for i, op in enumerate(ops)
+                  if op.type == "toy_four_streams_grad")
+        assert ops[at].input("OG$Out") == [grad_var_name(n) for n in names]
+        sums = ops[at - 4:at]
+        assert [op.type for op in sums] == ["sum"] * 4, names
+        assert [op.output("Out") for op in sums] == \
+            [[grad_var_name(n)] for n in names], names
+        assert all(len(op.input("X")) == 2 for op in sums)
+    # the last permutation's numbers: d/dx mean(5 * (1+2+3+4) * x) = 50 / 3
+    exe = Executor()
+    out, = exe.run(main, feed={"x": np.ones((1, 3), np.float32)},
+                   fetch_list=[grad_var_name("x")])
+    np.testing.assert_allclose(out, np.full((1, 3), 50.0 / 3.0), rtol=1e-5)

@@ -25,10 +25,17 @@ run once, to see the chip refuse or take what the compiler here refused or
 took (prints whether it ran, the loss and the peak memory).
 
     chiprun -- python3 tools/joyai_step_aot.py --run
+
+``--fingerprint``: the sha256 of the step's lowered text with its debug
+info, which is what the persistent compile cache keys on; two processes
+under different ``PYTHONHASHSEED`` must print the same one (ISSUE 47).
+
+    PYTHONHASHSEED=1 python3 tools/joyai_step_aot.py --cell xing4 --fingerprint
 """
 
 import argparse
 import collections
+import hashlib
 import json
 import os
 import re
@@ -144,6 +151,12 @@ def main():
     ap.add_argument("--lowered", default="", help="write the step's lowered "
                     "StableHLO text there, locations stripped, and compile "
                     "nothing: what two commits' steps are diffed by")
+    ap.add_argument("--fingerprint", action="store_true", help="print the "
+                    "sha256 of the step's lowered text WITH its debug info "
+                    "(locations and pt.<role>/<op> scopes: what the "
+                    "persistent compile cache keys on, which --lowered "
+                    "strips) and compile nothing: run it under two "
+                    "PYTHONHASHSEEDs, two processes must print one key")
     ap.add_argument("--cell", default="joyai", choices=sorted(CELLS),
                     help="the other cell that runs moe_ffn's held path: "
                     "trinity, or the third that runs the flash kernels: "
@@ -221,6 +234,14 @@ def main():
         from paddle_tpu.ops import hc_ops
         return counted(hc_ops.HC_LOWERINGS_CTR, "op", "n", "sinkhorn_iters",
                        "impl")
+    if args.fingerprint:
+        text = cb.jitted.lower(*shapes).as_text(debug_info=True)
+        print(json.dumps({
+            "cell": args.cell, "layers": config["num_hidden_layers"],
+            "seq": traffic["seq_len"], "bytes": len(text),
+            "hash_seed": os.environ.get("PYTHONHASHSEED", "random"),
+            "fingerprint": hashlib.sha256(text.encode()).hexdigest()}))
+        return 0
     if args.lowered:
         text = re.sub(r"loc\(.*?\)", "", cb.jitted.lower(*shapes).as_text())
         with open(args.lowered, "w") as f:
