@@ -1204,7 +1204,8 @@ def lrn(input, n=5, k=1.0, alpha=1e-4, beta=0.75, name=None):
 
 
 def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
-                    block_q=None, block_k=None, name=None, window=None):
+                    block_q=None, block_k=None, name=None, window=None,
+                    q_rope=None, k_rope=None):
     """Fused online-softmax attention over [b, h, T, d] tensors.
 
     ``window`` (with ``causal``): key ``j`` is visible to query ``i`` iff
@@ -1216,6 +1217,12 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
     scaled_dot_product_attention) — Pallas kernel on TPU, O(T) memory.
     block_q/block_k default to the kernel's tuned sizes.  Lse, the op's
     second output ([b, h, T] float32), is what its grad op reads beside Out.
+    ``q_rope`` [b, h, Tq, d_r] and ``k_rope`` [b, h_r, Tk, d_r] (``h % h_r
+    == 0``; both or neither): the score of a pair becomes ``(q·k + q_rope·
+    k_rope) · sm_scale``, a second product inside the kernels, so that a
+    rotary part kept apart (latent attention's one 64-wide rotary key for
+    every head) is neither concatenated nor broadcast in HBM; ``sm_scale``
+    then defaults to ``(d + d_r) ** -0.5``.
     """
     helper = LayerHelper("flash_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
@@ -1223,6 +1230,10 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if bias is not None:
         inputs["Bias"] = [bias]
+    if (q_rope is None) != (k_rope is None):
+        raise ValueError("q_rope and k_rope come together")
+    if q_rope is not None:
+        inputs["QRope"], inputs["KRope"] = [q_rope], [k_rope]
     attrs = {"causal": causal, "sm_scale": sm_scale or 0.0,
              "block_q": block_q or 0, "block_k": block_k or 0}
     if window:

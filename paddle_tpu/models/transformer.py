@@ -733,13 +733,16 @@ def latent_attention(x, cfg: JoyaiConfig, param_prefix="attn"):
         [q_nope | q_rope] = c_q W_qb        per head, d_nope | d_rope
         [k_nope | v]      = c_kv W_kvb      per head, d_nope | d_v
         q_rope, k_r = RoPE on adjacent pairs of the d_rope slice
-        k = [k_nope | k_r for every head],  q = [q_nope | q_rope]
-        out = flash(q, k, v, scale (d_nope + d_rope)^-1/2) W_o
+        score = (q_nope k_nope^T + q_rope k_r^T) (d_nope + d_rope)^-1/2
+        out = flash(q_nope, k_nope, v, q_rope, k_r) W_o
 
-    ``k_r`` bypasses the latent and is ONE head that every query head reads:
-    it is broadcast over the heads and concatenated behind each head's
-    content part outside the kernel, which then takes one ``d_nope +
-    d_rope`` wide K (``tools/joyai_kernel_probe.py`` says what that costs).
+    ``k_r`` bypasses the latent and is ONE head that every query head reads.
+    The flash op takes the pieces as the projections make them
+    (``layers.flash_attention(q_rope=, k_rope=)``): the score is two
+    products inside the kernels, the rotary key's ``[t, d_rope]`` read once
+    for all heads through an index map and its gradient summed over them, so
+    no ``d_nope + d_rope`` wide Q or K and no per-head copy of ``k_r`` is
+    built in HBM (``tools/joyai_kernel_probe.py`` times both forms).
     The scores contract over ``d_nope + d_rope`` and the values are ``d_v``
     wide: the flash kernels' two widths.  With ``cfg.rope_scaling`` (YaRN)
     the rotary slice turns by ``layers.rope``'s frequency-table form and the
@@ -777,10 +780,10 @@ def latent_attention(x, cfg: JoyaiConfig, param_prefix="attn"):
             heads(proj(norm(c_kv, "kv_norm"), h * (dn + dv), "kv_b"),
                   dn + dv), [dn, dv], dim=3)
         k_r = rotate(layers.unsqueeze(k_r, [1]))            # [b, 1, t, dr]
-        q = layers.concat([q_nope, rotate(q_rope)], axis=3)
-        k = layers.concat([k_nope, layers.expand(k_r, [1, h, 1, 1])], axis=3)
+        q_rope = rotate(q_rope)
     ctx = layers.flash_attention(
-        q, k, v, causal=True, sm_scale=float(dn + dr) ** -0.5
+        q_nope, k_nope, v, causal=True, q_rope=q_rope, k_rope=k_r,
+        sm_scale=float(dn + dr) ** -0.5
         * yarn_softmax_factor(cfg.rope_scaling))
     with name_scope("mla_proj"):
         ctx = layers.reshape(layers.transpose(ctx, perm=[0, 2, 1, 3]),

@@ -135,7 +135,10 @@ class _Metric:
         self.name = name
         self.help = help
         self.labelnames = tuple(labelnames)
-        self._mu = threading.Lock()
+        # re-entrant: a collection that starts while this thread holds the
+        # lock (between two bytecodes of ``series``) may run an executor's
+        # finalizer, whose ``retire`` folds series of this very family
+        self._mu = threading.RLock()
         self._series: Dict[Tuple[str, ...], Any] = {}  # guarded-by: _mu
 
     def _new_cell(self):

@@ -28,8 +28,10 @@ FLASH_LOWERINGS_CTR = _monitor.REGISTRY.counter(
     "flash_attention forward lowerings by the window (none = the whole "
     "causal half or no mask), the query heads to a KV head, the "
     "implementation (the Pallas kernels or the blockwise jax fallback) and "
-    "the two widths (widths = d_qk/d_v, e.g. 128/128 or latent attention's "
-    "192/128) and the form the forward kernel writes lse in at these shapes "
+    "the two widths (widths = d_qk/d_v, e.g. 128/128, or d_qk+d_r/d_v where "
+    "the score is two products, the op's QRope and KRope slots: latent "
+    "attention's 128+64/128) and the form the forward kernel writes lse in at "
+    "these shapes "
     "(lse = row: [bh, 1, Tq], what the backward reads, wherever a query "
     "block fills lanes; lanes: the [bh, Tq, 128] broadcast of which one "
     "lane is kept, at ragged toy blocks) — counted while tracing, once per "
@@ -54,12 +56,16 @@ FLASH_BWD_KERNEL_CTR = _monitor.REGISTRY.counter(
     "step", ("kernel", "window", "widths"))
 
 
-def _flash_call(ctx, attrs, q, k, v, counter, pallas, more_labels=None):
+def _flash_call(ctx, attrs, q, k, v, counter, pallas, more_labels=None,
+                q_rope=None, k_rope=None):
     """What the op and its grad op share: ``(window or None, the attributes
-    as keyword arguments of the kernel's entry points)``, and one count of
-    the lowering in ``counter`` (``pallas``: whether a TPU would run the
-    kernels here; ``more_labels``: a function of those keyword arguments
-    that gives the counter's further labels)."""
+    as keyword arguments of the kernel's entry points, the widths label)``,
+    and one count of the lowering in ``counter`` (``pallas``: whether a TPU
+    would run the kernels here; ``more_labels``: a function of those keyword
+    arguments that gives the counter's further labels).  ``q_rope`` /
+    ``k_rope``: the op's optional slots, handed on among the keyword
+    arguments only where they are given, so that a call without them is the
+    call it always was."""
     from ..device import on_tpu
     bq, bk = attrs.get("block_q"), attrs.get("block_k")
     window = int(attrs.get("window") or 0) or None
@@ -71,13 +77,17 @@ def _flash_call(ctx, attrs, q, k, v, counter, pallas, more_labels=None):
         block_q=int(bq) if bq else None,  # None → kernel's tuned default
         block_k=int(bk) if bk else None,
         bwd_impl=attrs.get("bwd_impl") or None, window=window)
+    widths = f"{q.shape[3]}/{v.shape[3]}"
+    if q_rope is not None:
+        kw.update(q_rope=q_rope, k_rope=k_rope)
+        widths = f"{q.shape[3]}+{q_rope.shape[3]}/{v.shape[3]}"
     if not getattr(ctx, "is_abstract", False):
         counter.inc(window="none" if window is None else str(window),
                     kv_groups=str(q.shape[1] // k.shape[1]),
                     impl="pallas" if pallas and on_tpu() else "jax",
-                    widths=f"{q.shape[3]}/{v.shape[3]}",
+                    widths=widths,
                     **(more_labels(kw) if more_labels else {}))
-    return window, kw
+    return window, kw, widths
 
 
 def _window_scope(window):
@@ -98,13 +108,23 @@ def _flash_attention(ctx, ins, attrs):
     the grad op's shapes follow V's) and Lse [b, h, Tq] float32, each
     query's log-sum-exp over its visible keys, which ``flash_attention_grad``
     rebuilds the probabilities from (an op without that slot still runs:
-    the executor binds the slots an op names)."""
+    the executor binds the slots an op names).
+
+    Optional QRope [b, h, Tq, d_r] and KRope [b, h_r, Tk, d_r] with ``h %
+    h_r == 0`` (latent attention: ``h_r`` 1, ``d_r`` 64 beside 128-wide Q
+    and K): the score of a pair is ``(q·k + q_rope·k_rope) · sm_scale``, the
+    kernels' second product into the same tile, query head ``i`` reading
+    rotary head ``i // (h // h_r)``; ``sm_scale`` stays the caller's.  The
+    code observes its input: the second product exists where the two slots
+    are given, and an op without them lowers as before they existed.  The
+    counters then label ``widths`` ``d_qk+d_r/d_v``."""
     from ..pallas.flash_attention import (flash_attention_fwd,
                                           flash_lse_layout)
     q, k, v = X(ins, "Q"), X(ins, "K"), X(ins, "V")
-    window, kw = _flash_call(
+    window, kw, _ = _flash_call(
         ctx, attrs, q, k, v, FLASH_LOWERINGS_CTR, pallas=True,
-        more_labels=lambda kw: {"lse": flash_lse_layout(q, k, v, **kw)})
+        more_labels=lambda kw: {"lse": flash_lse_layout(q, k, v, **kw)},
+        q_rope=X(ins, "QRope"), k_rope=X(ins, "KRope"))
     with _window_scope(window):
         out, lse = flash_attention_fwd(q, k, v, X(ins, "Bias"), **kw)
         # Lse leaves with Out: where the kernel still writes the lane-
@@ -121,7 +141,8 @@ def _flash_attention_grad_maker(op, block, no_grad_set):
             "flash_attention op without an Lse output (a program built "
             "before the op saved its log-sum-exp): build it again with "
             "layers.flash_attention to train it")
-    slots = [s for s in ("Q", "K", "V", "Bias") if op.input(s)]
+    slots = [s for s in ("Q", "K", "V", "Bias", "QRope", "KRope")
+             if op.input(s)]
     g_inputs = {"X$" + s: op.input(s) for s in slots}
     g_inputs["Out"], g_inputs["Lse"] = op.output("Out"), op.output("Lse")
     g_inputs["OG$Out"] = [grad_var_name(n) for n in op.output("Out")]
@@ -143,24 +164,30 @@ def _flash_attention_grad(ctx, ins, attrs):
     backward kernels on (Q, K, V, Out, Lse, dOut) and no forward kernel (the
     generic vjp ran it a second time for these two residuals; XLA does not
     merge two Mosaic calls).  With a bias the blockwise jax backward, on
-    every backend, and dBias only where the grad maker found a reader."""
+    every backend, and dBias only where the grad maker found a reader.
+    Where the forward had QRope and KRope the same kernels take them and
+    return their gradients too, KRope's summed over the query heads that
+    read each of its heads."""
     from ..pallas.flash_attention import (flash_attention_bwd,
                                           flash_bwd_kernel)
     q, k, v = X(ins, "X$Q"), X(ins, "X$K"), X(ins, "X$V")
     bias, out, d_out = X(ins, "X$Bias"), X(ins, "Out"), X(ins, "OG$Out")
-    window, kw = _flash_call(ctx, attrs, q, k, v, FLASH_GRAD_LOWERINGS_CTR,
-                             pallas=bias is None)
+    window, kw, widths = _flash_call(
+        ctx, attrs, q, k, v, FLASH_GRAD_LOWERINGS_CTR, pallas=bias is None,
+        q_rope=X(ins, "X$QRope"), k_rope=X(ins, "X$KRope"))
     if not getattr(ctx, "is_abstract", False):
         FLASH_BWD_KERNEL_CTR.inc(
             kernel=flash_bwd_kernel(q, k, v, bias, **kw),
-            window="none" if window is None else str(window),
-            widths=f"{q.shape[3]}/{v.shape[3]}")
+            window="none" if window is None else str(window), widths=widths)
     d_out = jnp.zeros_like(out) if d_out is None else d_out.astype(out.dtype)
     with _window_scope(window):
-        dq, dk, dv, db = flash_attention_bwd(
+        dq, dk, dv, db, *d_rope = flash_attention_bwd(
             q, k, v, bias, out, X(ins, "Lse"), d_out,
             need_dbias=bool(attrs.get("need_dbias", True)), **kw)
-    return {"IG$Q": [dq], "IG$K": [dk], "IG$V": [dv], "IG$Bias": [db]}
+    grads = {"IG$Q": [dq], "IG$K": [dk], "IG$V": [dv], "IG$Bias": [db]}
+    if d_rope:
+        grads["IG$QRope"], grads["IG$KRope"] = [d_rope[0]], [d_rope[1]]
+    return grads
 
 
 @register_op("ring_attention")

@@ -7,6 +7,17 @@ the second width existed; nothing is padded to the wider of the two in HBM:
 V's, O's and dO's blocks, the accumulators and dV are ``d_v`` wide, Q's, K's,
 dQ and dK ``d_qk``.
 
+The score as two products (PR 48): with ``q_rope`` ``[b, h, Tq, d_r]`` and
+``k_rope`` ``[b, h_r, Tk, d_r]`` a pair's score is ``(q·k + q_rope·k_rope) ·
+sm_scale``: every kernel adds a second product into the same float32 score
+tile, the rotary key's block comes through an index map of its own (``h_r``
+heads for ``h`` query heads; latent attention has one), and the backward
+feeds ``ds`` into two more small products for ``dq_rope`` and ``dk_rope``.
+Nothing ``d_qk + d_r`` wide and no per-head copy of the rotary key exists in
+HBM.  The second product is a trace-time ``if`` on the two operands, like the
+bias's: a call without them lowers to the kernels, grids, block tables and
+VMEM limits it had before they existed.
+
 Forward on TPU runs a Pallas kernel tiled for the MXU (grid over
 (batch*heads, q-blocks, k-blocks), f32 accumulators in VMEM scratch);
 elsewhere (CPU tests, interpret debugging) a blockwise ``lax.scan``
@@ -46,16 +57,26 @@ _LANE = 128      # TPU lane width: min last-dim tile
 
 
 def mha_reference(q, k, v, bias=None, causal=False, sm_scale=None,
-                  window=None):
+                  window=None, q_rope=None, k_rope=None):
     """O(T^2) reference attention (the math the kernel must reproduce): Q
     and K ``[b, h, T, d_qk]``, V ``[b, h, T, d_v]``, the result ``d_v`` wide.
     ``window``: with ``causal``, key ``j`` is visible to query ``i`` iff
     ``0 <= i - j < window``.  K and V may have fewer heads than Q: query
-    head ``h`` reads KV head ``h // (n_q_heads // n_kv_heads)``."""
+    head ``h`` reads KV head ``h // (n_q_heads // n_kv_heads)``.  With
+    ``q_rope`` ``[b, h, T, d_r]`` and ``k_rope`` ``[b, h_r, T, d_r]`` the
+    score is over ``[q | q_rope]`` and ``[k | k_rope]``, the rotary key's
+    heads repeated like K's, and the default scale from the whole width."""
+    if q_rope is not None:
+        q = jnp.concatenate([q, q_rope], axis=-1)
+        k = jnp.concatenate([
+            jnp.repeat(k, q.shape[1] // k.shape[1], axis=1),
+            jnp.repeat(k_rope, q.shape[1] // k_rope.shape[1], axis=1)],
+            axis=-1)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if k.shape[1] != q.shape[1]:
         k = jnp.repeat(k, q.shape[1] // k.shape[1], axis=1)
+    if v.shape[1] != q.shape[1]:
         v = jnp.repeat(v, q.shape[1] // v.shape[1], axis=1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * sm_scale
@@ -206,8 +227,17 @@ def _lse_rows(block_q, tqp):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
                 acc_sc, m_sc, l_sc, q_sc, *, sm_scale, causal, block_q,
-                block_k, tk_real, offset, pads, window=None, lse_rows=False):
+                block_k, tk_real, offset, pads, window=None, lse_rows=False,
+                rope=None):
     """One (bh, iq, ik) grid step of online-softmax attention.
+
+    ``rope`` (trace-time, like ``b_ref``): ``(qr_ref, kr_ref, qr_sc)``, the
+    score's second product.  The query block's rotary part is scaled once
+    into ``qr_sc`` beside ``q_sc`` and every key step adds ``qr_sc · krᵀ``
+    into the same float32 score tile: the sum of two float32 products of the
+    same values is the one wide contraction in another order of addition.
+    The rotary key's block comes through its own index map (one head for a
+    group of query heads), so nothing is broadcast or concatenated in HBM.
 
     The grid walks ik innermost (sequentially on TPU), so the VMEM scratch
     carries a query block's state across its key blocks: the float32 output
@@ -233,6 +263,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
 
     iq, ik = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
+    qr_ref, kr_ref, qr_sc = rope or (None,) * 3
 
     @pl.when(ik == 0)
     def _init():
@@ -240,12 +271,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
         q_sc[...] = q_ref[0].astype(jnp.float32) * sm_scale
+        if rope is not None:
+            qr_sc[...] = qr_ref[0].astype(jnp.float32) * sm_scale
 
     def _compute(masked):
         k = k_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q_sc[...], k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if rope is not None:
+            s = s + jax.lax.dot_general(
+                qr_sc[...], kr_ref[0].astype(jnp.float32),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
         if b_ref is not None:
             s = s + b_ref[0].astype(jnp.float32)
         if masked:
@@ -280,7 +318,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
             lse_ref[0] = jnp.broadcast_to(lse, lse_ref[0].shape)
 
 
-def _fwd_vmem_bytes(d, d_v, block_q, block_k, itemsize, bias_itemsize=0):
+def _lanes(w):
+    """A minor dimension as VMEM holds it: whole 128-lane tiles."""
+    return -(-w // _LANE) * _LANE
+
+
+def _fwd_vmem_bytes(d, d_v, block_q, block_k, itemsize, bias_itemsize=0,
+                    d_r=0):
     """The VMEM the forward asks for, from its shapes, as
     :func:`_fused_vmem_bytes` reckons the fused backward's: the operand and
     output blocks (double-buffered; ``lse``'s at the lane-broadcast form's
@@ -293,8 +337,10 @@ def _fwd_vmem_bytes(d, d_v, block_q, block_k, itemsize, bias_itemsize=0):
     and never over the share of a core's VMEM a kernel may ask for.  [32,
     8192, 192 | 128] bf16 asks 28.8 MiB at (1024, 1024), 51.9 at (1024,
     2048) and 54.4 at (2048, 1024), where 12, 20 and 24 are the least that
-    compile alone (tools/joyai_kernel_probe.py --aot --forward)."""
-    wide = d + d_v
+    compile alone (tools/joyai_kernel_probe.py --aot --forward).  ``d_r``:
+    the rotary parts' width under the two-product score, whose blocks, scratch
+    and copies lie beside Q's and K's at whole lanes (64 takes 128)."""
+    wide = d + d_v + _lanes(d_r)
     blocks = 2 * (itemsize * wide * (block_q + block_k)
                   + bias_itemsize * block_q * block_k + block_q * _LANE * 4)
     scratch = block_q * (wide + 2 * _LANE) * 4
@@ -304,9 +350,12 @@ def _fwd_vmem_bytes(d, d_v, block_q, block_k, itemsize, bias_itemsize=0):
 
 
 def _flash_fwd_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
-                      offset, interpret, window=None, group=1):
+                      offset, interpret, window=None, group=1, q_rope=None,
+                      k_rope=None):
     """Returns (o [bh,Tq,dv], lse [bh,Tq]) on padded collapsed inputs; K is
-    [bh // group, Tk, d] and V [bh // group, Tk, dv]."""
+    [bh // group, Tk, d] and V [bh // group, Tk, dv]; ``q_rope`` [bh, Tq,
+    d_r] and ``k_rope`` [bh // its own group, Tk, d_r] where the score is two
+    products."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -334,6 +383,17 @@ def _flash_fwd_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
         _k_spec(block_q, block_k, d_v, window, group, offset, nk),
     ]
     args = [q, k, v]
+    d_r = 0 if q_rope is None else q_rope.shape[2]
+    if d_r:
+        if pad_q:
+            q_rope = jnp.pad(q_rope, ((0, 0), (0, pad_q), (0, 0)))
+        if pad_k:
+            k_rope = jnp.pad(k_rope, ((0, 0), (0, pad_k), (0, 0)))
+        in_specs += [
+            pl.BlockSpec((1, block_q, d_r), lambda b, i, j: (b, i, 0)),
+            _k_spec(block_q, block_k, d_r, window, bh // k_rope.shape[0],
+                    offset, nk)]
+        args += [q_rope, k_rope]
     if bias is not None:
         nb = bias.shape[0]
         in_specs.append(pl.BlockSpec(
@@ -345,13 +405,18 @@ def _flash_fwd_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
     lse_rows = _lse_rows(block_q, tqp)
 
     def kernel(q_ref, k_ref, v_ref, *rest):
-        # rest = ([b_ref,] o_ref, lse_ref, acc, m, l, q32) depending on bias
+        # rest = ([qr_ref, kr_ref,] [b_ref,] o_ref, lse_ref, acc, m, l, q32
+        # [, qr32]) depending on the rotary parts and the bias
+        rope = None
+        if d_r:
+            rope, rest = (rest[0], rest[1], rest[-1]), rest[2:-1]
         b_ref = rest[0] if bias is not None else None
         _fwd_kernel(q_ref, k_ref, v_ref, b_ref, *rest[-6:],
                     sm_scale=sm_scale, causal=causal,
                     block_q=block_q, block_k=block_k,
                     tk_real=tk_real, offset=offset,
-                    pads=tkp != tk_real, window=window, lse_rows=lse_rows)
+                    pads=tkp != tk_real, window=window, lse_rows=lse_rows,
+                    rope=rope)
 
     if lse_rows:
         lse_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
@@ -377,11 +442,11 @@ def _flash_fwd_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
-        ],
+        ] + ([pltpu.VMEM((block_q, d_r), jnp.float32)] if d_r else []),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_fwd_vmem_bytes(
                 d, d_v, block_q, block_k, q.dtype.itemsize,
-                0 if bias is None else bias.dtype.itemsize)),
+                0 if bias is None else bias.dtype.itemsize, d_r)),
         interpret=interpret,
         name="flash_fwd",
     )(*args)
@@ -396,18 +461,23 @@ def _flash_fwd_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    dq_sc, *, sm_scale, causal, block_q, block_k,
-                   tq_real, tk_real, offset, pads, window=None):
+                   tq_real, tk_real, offset, pads, window=None, rope=None):
     """Grid (bh, iq, ik): accumulate dq over k-blocks in VMEM scratch.
-    Mask/scale elision as in _fwd_kernel (r5 skeleton microbench)."""
+    Mask/scale elision as in _fwd_kernel (r5 skeleton microbench).  ``rope``:
+    ``(qr_ref, kr_ref, dqr_ref, dqr_sc)``, the score's second product and
+    the rotary query's gradient, ``ds · kr``, accumulated like dq."""
     import jax.lax as lax
     from jax.experimental import pallas as pl
 
     iq, ik = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
+    qr_ref, kr_ref, dqr_ref, dqr_sc = rope or (None,) * 4
 
     @pl.when(ik == 0)
     def _init():
         dq_sc[...] = jnp.zeros_like(dq_sc)
+        if rope is not None:
+            dqr_sc[...] = jnp.zeros_like(dqr_sc)
 
     def _compute(masked):
         q = q_ref[0].astype(jnp.float32) * sm_scale
@@ -418,6 +488,11 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         delta = delta_ref[0]
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
+        if rope is not None:
+            kr = kr_ref[0].astype(jnp.float32)
+            s = s + lax.dot_general(
+                qr_ref[0].astype(jnp.float32) * sm_scale, kr,
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
         if masked:
             s = jnp.where(_pos_mask(iq, ik, block_q, block_k, causal,
                                     offset, tq_real, tk_real,
@@ -431,6 +506,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dq_sc[...] = dq_sc[...] + lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if rope is not None:
+            dqr_sc[...] = dqr_sc[...] + lax.dot_general(
+                ds, kr, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     _block_dispatch(causal, pads, iq, ik, block_q, block_k, offset,
                     _compute, window=window)
@@ -438,11 +517,14 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     @pl.when(ik == nk - 1)
     def _finalize():
         dq_ref[0] = (dq_sc[...] * sm_scale).astype(dq_ref.dtype)
+        if rope is not None:
+            dqr_ref[0] = (dqr_sc[...] * sm_scale).astype(dqr_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, *more, sm_scale, causal, block_q,
-                    block_k, tq_real, tk_real, offset, pads, window=None):
+                    block_k, tq_real, tk_real, offset, pads, window=None,
+                    rope=None):
     """Grid (bh, ik, iq): accumulate dk/dv over q-blocks in VMEM scratch
     (transposed tiles: everything is (bk, ·) so the MXU contractions stay
     tall).  Mask/scale elision as in _fwd_kernel (r5 microbench).  ``more``
@@ -457,7 +539,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     precision and the order of both accumulations (dq over key blocks
     ascending, dk/dv over query blocks ascending) are the split kernels'
     own.  Fused, it needs more than Mosaic's default scoped VMEM:
-    _fused_vmem_bytes."""
+    _fused_vmem_bytes.  ``rope``: ``(qr_ref, kr_ref, dkr_ref, dkr_sc,
+    dqr_ref, dqr_sc)`` (the last two ``None`` in the split pass), the
+    score's second product, ``kr · qrᵀ`` into the same tile, and the two
+    small products ``ds_t`` feeds beside its others: ``dkr += ds_t · qr``,
+    one partial a QUERY head, summed over the rotary key's group outside
+    like a grouped K's, and ``dqr += ds_tᵀ · kr`` beside dq."""
     import jax.lax as lax
     from jax.experimental import pallas as pl
 
@@ -466,15 +553,21 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     ik, iq = pl.program_id(1), pl.program_id(2)
     nk, nq = pl.num_programs(1), pl.num_programs(2)
 
+    qr_ref, kr_ref, dkr_ref, dkr_sc, dqr_ref, dqr_sc = rope or (None,) * 6
+
     if dq_sc is not None:
         @pl.when((ik == 0) & (iq == 0))
         def _init_dq():
             dq_sc[...] = jnp.zeros_like(dq_sc)
+            if rope is not None:
+                dqr_sc[...] = jnp.zeros_like(dqr_sc)
 
     @pl.when(iq == 0)
     def _init():
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
+        if rope is not None:
+            dkr_sc[...] = jnp.zeros_like(dkr_sc)
 
     def _compute(masked):
         # sm_scale folds into q: s_t = k @ (q·scale) and
@@ -488,6 +581,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         delta = delta_ref[0]
         s_t = lax.dot_general(k, q, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)
+        if rope is not None:
+            qr = qr_ref[0].astype(jnp.float32) * sm_scale
+            kr = kr_ref[0].astype(jnp.float32)
+            s_t = s_t + lax.dot_general(
+                kr, qr, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
         if masked:
             s_t = jnp.where(_pos_mask(iq, ik, block_q, block_k, causal,
                                       offset, tq_real, tk_real,
@@ -510,6 +609,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dq_sc[rows, :] = dq_sc[rows, :] + lax.dot_general(
                 ds_t, k, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
+        if rope is not None:
+            dkr_sc[...] = dkr_sc[...] + lax.dot_general(
+                ds_t, qr, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if dq_sc is not None:
+                dqr_sc[rows, :] = dqr_sc[rows, :] + lax.dot_general(
+                    ds_t, kr, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
 
     _block_dispatch(causal, pads, iq, ik, block_q, block_k, offset,
                     _compute, window=window)
@@ -518,11 +625,15 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _finalize():
         dk_ref[0] = dk_sc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
+        if rope is not None:
+            dkr_ref[0] = dkr_sc[...].astype(dkr_ref.dtype)
 
     if dq_sc is not None:
         @pl.when((ik == nk - 1) & (iq == nq - 1))
         def _finalize_dq():
             dq_ref[0] = (dq_sc[...] * sm_scale).astype(dq_ref.dtype)
+            if rope is not None:
+                dqr_ref[0] = (dqr_sc[...] * sm_scale).astype(dqr_ref.dtype)
 
 
 def _bwd_prologue(q, k, v, o, lse, do, block_q, block_k):
@@ -547,7 +658,7 @@ def _bwd_prologue(q, k, v, o, lse, do, block_q, block_k):
             tq + pad_q, tk + pad_k)
 
 
-def _fused_vmem_bytes(tq, d, d_v, block_q, block_k, itemsize):
+def _fused_vmem_bytes(tq, d, d_v, block_q, block_k, itemsize, d_r=0):
     """The VMEM the fused backward asks for, from its shapes: the head's dQ
     accumulator and its output block, the operand and dK/dV blocks (every
     block double-buffered), the (1, block_q) lse/delta rows at a sublane
@@ -557,10 +668,12 @@ def _fused_vmem_bytes(tq, d, d_v, block_q, block_k, itemsize):
     a step.  [32, 8192, 192 | 128] bf16 asks 34 MiB at (1024, 512) and 49.5
     at (1024, 1024), where 28 and 40 are the least that compile alone (20
     for the 43 asked at [32, 8192, 128 | 128] (1024, 1024): the compiler
-    shares tiles this sum counts apart)."""
+    shares tiles this sum counts apart).  ``d_r``: the rotary parts' width
+    under the two-product score: a second accumulator (dQRope's) and blocks,
+    scratch and copies beside Q's and K's, each at whole lanes."""
     tq = -(-tq // block_q) * block_q
-    wide = d + d_v
-    acc = tq * d * (4 + 2 * itemsize)
+    wide = d + d_v + _lanes(d_r)
+    acc = tq * (d + _lanes(d_r)) * (4 + 2 * itemsize)
     blocks = 2 * itemsize * wide * (block_q + 2 * block_k)
     rows = 2 * 2 * 8 * block_q * 4
     scratch = block_k * wide * 4
@@ -571,39 +684,46 @@ def _fused_vmem_bytes(tq, d, d_v, block_q, block_k, itemsize):
 _BWD_IMPLS = ("fused", "split")
 
 
-def _bwd_kernel_name(q, k, v, block_q, block_k, impl=None):
+def _bwd_kernel_name(q, k, v, block_q, block_k, impl=None, d_r=0):
     """Which of the two Pallas backwards runs for collapsed ``q``, ``k``,
     ``v`` (anything with a shape and a dtype) at these blocks: the fused
     kernel where a head's dQ accumulator and the blocks fit
     ``_FUSED_VMEM_SHARE`` of a core's VMEM, else the split kernels, which
-    fit everywhere.  ``impl="split"`` asks for the split kernels outright."""
+    fit everywhere.  ``impl="split"`` asks for the split kernels outright.
+    ``d_r``: the rotary parts' width where the score is two products."""
     if impl == "split":
         return "split"
     tq, d = q.shape[1:]
     tk, d_v = k.shape[1], v.shape[2]
     block_q, block_k = min(block_q, tq), min(block_k, tk)
     need = _fused_vmem_bytes(tq, d, d_v, block_q, block_k,
-                             jnp.dtype(q.dtype).itemsize)
+                             jnp.dtype(q.dtype).itemsize, d_r)
     return "fused" if need <= _FUSED_VMEM_SHARE * _VMEM_BYTES else "split"
 
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale, block_q,
                       block_k, offset, interpret, impl=None, window=None,
-                      group=1):
-    name = _bwd_kernel_name(q, k, v, block_q, block_k, impl)
+                      group=1, q_rope=None, k_rope=None):
+    name = _bwd_kernel_name(q, k, v, block_q, block_k, impl,
+                            0 if q_rope is None else q_rope.shape[2])
     return _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale,
                                    block_q, block_k, offset, interpret,
-                                   window, group, fused=name == "fused")
+                                   window, group, fused=name == "fused",
+                                   q_rope=q_rope, k_rope=k_rope)
 
 
 def _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale, block_q,
                             block_k, offset, interpret, window=None,
-                            group=1, fused=False):
+                            group=1, fused=False, q_rope=None, k_rope=None):
     """(dq, dk, dv) via the dq pass and the dk/dv pass (no-bias path), or,
     ``fused``, via the dk/dv pass alone with the head's dq accumulated
     beside them: no HBM temporaries but delta and the lse view either way.
     The dk/dv pass writes one result per query head, summed over each KV
-    head's ``group`` outside."""
+    head's ``group`` outside.  With ``q_rope`` / ``k_rope`` (the score as
+    two products) the same passes take the two more operands and return
+    ``(dq, dk, dv, dq_rope, dk_rope)``, the rotary key's per-head partials
+    summed over ITS group (one head for all: every query head) in the same
+    way."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -616,6 +736,13 @@ def _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale, block_q,
     statics = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
                    block_k=block_k, tq_real=tq, tk_real=tk, offset=offset,
                    pads=tqp != tq or tkp != tk, window=window)
+    d_r = 0 if q_rope is None else q_rope.shape[2]
+    if d_r:
+        rope_group = bh // k_rope.shape[0]
+        if tqp != tq:
+            q_rope = jnp.pad(q_rope, ((0, 0), (0, tqp - tq), (0, 0)))
+        if tkp != tk:
+            k_rope = jnp.pad(k_rope, ((0, 0), (0, tkp - tk), (0, 0)))
 
     if not fused:
         # lse/delta ride as [bh, tq, 1]: block (1, block_q, 1) keeps the
@@ -627,17 +754,38 @@ def _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale, block_q,
             return _k_spec(block_q, block_k, w, window, group, offset, nk)
         row_spec_q = pl.BlockSpec((1, block_q, 1),
                                   lambda b, i, j: (b, i, 0))
+        dq_in = [q_spec_q(d), k_spec_q(d), k_spec_q(d_v), q_spec_q(d_v),
+                 row_spec_q, row_spec_q]
+        dq_args = [q, k, v, do, lse[..., None], delta[..., None]]
+        dq_kernel = functools.partial(_bwd_dq_kernel, **statics)
+        dq_out = q_spec_q(d)
+        dq_shape = jax.ShapeDtypeStruct((bh, tqp, d), q.dtype)
+        dq_scratch = [pltpu.VMEM((block_q, d), jnp.float32)]
+        if d_r:
+            def dq_kernel(*refs):
+                # six operands, then (qr, kr | dq, dqr | dq_sc, dqr_sc)
+                qr, kr, dq_ref, dqr_ref, dq_sc, dqr_sc = refs[6:]
+                _bwd_dq_kernel(*refs[:6], dq_ref, dq_sc, **statics,
+                               rope=(qr, kr, dqr_ref, dqr_sc))
+            dq_in += [q_spec_q(d_r), _k_spec(block_q, block_k, d_r, window,
+                                             rope_group, offset, nk)]
+            dq_args += [q_rope, k_rope]
+            dq_out = [dq_out, q_spec_q(d_r)]
+            dq_shape = [dq_shape, jax.ShapeDtypeStruct((bh, tqp, d_r),
+                                                       q_rope.dtype)]
+            dq_scratch.append(pltpu.VMEM((block_q, d_r), jnp.float32))
         dq = pl.pallas_call(
-            functools.partial(_bwd_dq_kernel, **statics),
+            dq_kernel,
             grid=(bh, nq, nk),
-            in_specs=[q_spec_q(d), k_spec_q(d), k_spec_q(d_v),
-                      q_spec_q(d_v), row_spec_q, row_spec_q],
-            out_specs=q_spec_q(d),
-            out_shape=jax.ShapeDtypeStruct((bh, tqp, d), q.dtype),
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            in_specs=dq_in,
+            out_specs=dq_out,
+            out_shape=dq_shape,
+            scratch_shapes=dq_scratch,
             interpret=interpret,
             name="flash_bwd_dq",
-        )(q, k, v, do, lse[..., None], delta[..., None])
+        )(*dq_args)
+        if d_r:
+            dq, dq_rope = dq
 
     # dk/dv pass: grid iterates q innermost per k-block; lse/delta ride
     # TRANSPOSED [bh, 1, tq] so the kernel reads (1, bq) rows directly (a
@@ -663,6 +811,9 @@ def _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale, block_q,
         return pl.BlockSpec((1, block_k, w), lambda b, j, i: (b, j, 0))
     row_spec = pl.BlockSpec((1, 1, block_q),
                             lambda b, j, i: (b, 0, iq_of(i, j)))
+    in_specs = [q_spec(d), k_spec(d), k_spec(d_v), q_spec(d_v), row_spec,
+                row_spec]
+    args = [q, k, v, do, lse[:, None, :], delta[:, None, :]]
     out_specs = [out_spec(d), out_spec(d_v)]
     out_shape = [jax.ShapeDtypeStruct((bh, tkp, d), k.dtype),
                  jax.ShapeDtypeStruct((bh, tkp, d_v), v.dtype)]
@@ -671,43 +822,92 @@ def _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale, block_q,
     extra = {}
     if fused:
         # the head's whole dQ: one block for every (ik, iq) of a head
-        out_specs.append(pl.BlockSpec((1, tqp, d), lambda b, j, i: (b, 0, 0)))
+        def head_spec(w):
+            return pl.BlockSpec((1, tqp, w), lambda b, j, i: (b, 0, 0))
+        out_specs.append(head_spec(d))
         out_shape.append(jax.ShapeDtypeStruct((bh, tqp, d), q.dtype))
         scratch.append(pltpu.VMEM((tqp, d), jnp.float32))
         extra["compiler_params"] = pltpu.CompilerParams(
             vmem_limit_bytes=_fused_vmem_bytes(
-                tqp, d, d_v, block_q, block_k, q.dtype.itemsize))
-    dk, dv, *dqs = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **statics),
+                tqp, d, d_v, block_q, block_k, q.dtype.itemsize, d_r))
+    kernel = functools.partial(_bwd_dkv_kernel, **statics)
+    if d_r:
+        # the rotary operands, results and scratch behind the others of
+        # their kind: dKRope a partial per QUERY head, dQRope the head's
+        in_specs += [q_spec(d_r), pl.BlockSpec(
+            (1, block_k, d_r),
+            lambda b, j, i: (_kv_head(b, rope_group), j, 0))]
+        args += [q_rope, k_rope]
+        out_specs.append(out_spec(d_r))
+        out_shape.append(jax.ShapeDtypeStruct((bh, tkp, d_r), k_rope.dtype))
+        scratch.append(pltpu.VMEM((block_k, d_r), jnp.float32))
+        if fused:
+            out_specs.append(head_spec(d_r))
+            out_shape.append(
+                jax.ShapeDtypeStruct((bh, tqp, d_r), q_rope.dtype))
+            scratch.append(pltpu.VMEM((tqp, d_r), jnp.float32))
+
+        def kernel(*refs):
+            qr, kr = refs[6:8]
+            if fused:
+                (dk, dv, dq, dkr, dqr, dk_sc, dv_sc, dq_sc, dkr_sc,
+                 dqr_sc) = refs[8:]
+                more = (dq, dk_sc, dv_sc, dq_sc)
+            else:
+                dk, dv, dkr, dk_sc, dv_sc, dkr_sc = refs[8:]
+                more, dqr, dqr_sc = (dk_sc, dv_sc), None, None
+            _bwd_dkv_kernel(*refs[:6], dk, dv, *more, **statics,
+                            rope=(qr, kr, dkr, dkr_sc, dqr, dqr_sc))
+    dk, dv, *more = pl.pallas_call(
+        kernel,
         grid=(bh, nk, nq),
-        in_specs=[q_spec(d), k_spec(d), k_spec(d_v), q_spec(d_v), row_spec,
-                  row_spec],
+        in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
         name="flash_bwd_fused" if fused else "flash_bwd_dkv",
         **extra,
-    )(q, k, v, do, lse[:, None, :], delta[:, None, :])
+    )(*args)
     if fused:
-        dq, = dqs
+        dq, *more = more
     if group > 1:
         dk = dk.reshape(bh // group, group, tkp, d).astype(
             jnp.float32).sum(axis=1).astype(k.dtype)
         dv = dv.reshape(bh // group, group, tkp, d_v).astype(
             jnp.float32).sum(axis=1).astype(v.dtype)
-    return dq[:, :tq], dk[:, :tk], dv[:, :tk]
+    if not d_r:
+        return dq[:, :tq], dk[:, :tk], dv[:, :tk]
+    dk_rope = more[0]
+    if fused:
+        dq_rope = more[1]
+    if rope_group > 1:
+        dk_rope = dk_rope.reshape(bh // rope_group, rope_group, tkp, d_r
+                                  ).astype(jnp.float32).sum(axis=1).astype(
+                                      k_rope.dtype)
+    return (dq[:, :tq], dk[:, :tk], dv[:, :tk], dq_rope[:, :tq],
+            dk_rope[:, :tk])
 
 
 # ---------------------------------------------------------------------------
 # Blockwise JAX fallback (same math, lax.scan over k-blocks)
 # ---------------------------------------------------------------------------
 
+def _rope_chunks(q_rope, k_rope, bh, pad_k, nk, block_k):
+    """The jax fallbacks' rotary operands: the query part in float32 and the
+    rotary key repeated over its group, padded and cut into K's chunks."""
+    kr = jnp.repeat(k_rope, bh // k_rope.shape[0], axis=0)
+    if pad_k:
+        kr = jnp.pad(kr, ((0, 0), (0, pad_k), (0, 0)))
+    return q_rope.astype(jnp.float32), kr.reshape(
+        bh, nk, block_k, kr.shape[2]).transpose(1, 0, 2, 3)
+
+
 def _flash_fwd_jax(q, k, v, bias, causal, sm_scale, block_k, offset,
-                   window=None, group=1):
+                   window=None, group=1, q_rope=None, k_rope=None):
     """(o, lse) via scan over k chunks — O(T*block_k) memory on any backend.
     No block is skipped here (this path runs where no TPU is): a window is
-    a mask, and grouped K/V heads are repeated."""
+    a mask, and grouped K/V heads (and a rotary key's) are repeated."""
     if group > 1:
         k, v = jnp.repeat(k, group, axis=0), jnp.repeat(v, group, axis=0)
     bh, tq, d = q.shape
@@ -729,15 +929,22 @@ def _flash_fwd_jax(q, k, v, bias, causal, sm_scale, block_k, offset,
                           ).transpose(2, 0, 1, 3)
     q32 = q.astype(jnp.float32)
     q_pos = offset + jnp.arange(tq)[:, None]
+    if q_rope is not None:
+        qr32, krc = _rope_chunks(q_rope, k_rope, bh, pad_k, nk, block_k)
 
     def step(carry, xs):
         m_prev, l_prev, acc = carry
+        if q_rope is not None:
+            *xs, krj = xs
         if bias is not None:
             kj, vj, bj, j = xs
         else:
             kj, vj, j = xs
-        s = jnp.einsum("bqd,bkd->bqk", q32, kj.astype(jnp.float32)
-                       ) * sm_scale
+        s = jnp.einsum("bqd,bkd->bqk", q32, kj.astype(jnp.float32))
+        if q_rope is not None:
+            s = s + jnp.einsum("bqd,bkd->bqk", qr32,
+                               krj.astype(jnp.float32))
+        s = s * sm_scale
         if bias is not None:
             s = s + bj.astype(jnp.float32)
         k_pos = j * block_k + jnp.arange(block_k)[None, :]
@@ -764,6 +971,8 @@ def _flash_fwd_jax(q, k, v, bias, causal, sm_scale, block_k, offset,
             jnp.zeros((bh, tq, d_v), jnp.float32) + zero)
     xs = (kc, vc, bc, jnp.arange(nk)) if bias is not None else \
          (kc, vc, jnp.arange(nk))
+    if q_rope is not None:
+        xs = xs + (krc,)
     (m, l, acc), _ = jax.lax.scan(step, init, xs)
     l_safe = jnp.where(l == 0.0, 1.0, l)
     o = (acc / l_safe).astype(q.dtype)
@@ -773,23 +982,24 @@ def _flash_fwd_jax(q, k, v, bias, causal, sm_scale, block_k, offset,
 
 def _flash_bwd_jax(q, k, v, bias, o, lse, do, causal, sm_scale, block_k,
                    offset, delta=None, need_dbias=True, window=None,
-                   group=1):
+                   group=1, q_rope=None, k_rope=None):
     """Flash backward: scan over k chunks rebuilding P from saved lse.
 
     dq accumulates across chunks; dk/dv are emitted per chunk (stacked by
     scan) — memory stays O(T*block_k).  ``window``/``group`` as in
     :func:`_flash_fwd_jax`; dk and dv come back summed over each group.
+    With ``q_rope`` / ``k_rope`` the result is ``(dq, dk, dv, db, dq_rope,
+    dk_rope)``, the rotary key's gradient summed over its own group.
     """
+    def fold(g, group):
+        return g.astype(jnp.float32).reshape(
+            (-1, group) + g.shape[1:]).sum(axis=1).astype(g.dtype)
     if group > 1:
-        dq, dk, dv, db = _flash_bwd_jax(
+        dq, dk, dv, *more = _flash_bwd_jax(
             q, jnp.repeat(k, group, axis=0), jnp.repeat(v, group, axis=0),
             bias, o, lse, do, causal, sm_scale, block_k, offset, delta,
-            need_dbias, window)
-
-        def fold(g):
-            return g.astype(jnp.float32).reshape(
-                (-1, group) + g.shape[1:]).sum(axis=1).astype(g.dtype)
-        return dq, fold(dk), fold(dv), db
+            need_dbias, window, 1, q_rope, k_rope)
+        return (dq, fold(dk, group), fold(dv, group), *more)
     bh, tq, d = q.shape
     d_v = v.shape[2]
     tk = k.shape[1]
@@ -812,14 +1022,23 @@ def _flash_bwd_jax(q, k, v, bias, o, lse, do, causal, sm_scale, block_k,
     if delta is None:
         delta = jnp.sum(do32 * o.astype(jnp.float32), axis=-1)  # [bh, tq]
     q_pos = offset + jnp.arange(tq)[:, None]
+    if q_rope is not None:
+        qr32, krc = _rope_chunks(q_rope, k_rope, bh, pad_k, nk, block_k)
 
     def step(dq_acc, xs):
+        if q_rope is not None:
+            dq_acc, dqr_acc = dq_acc
+            *xs, krj = xs
+            krj32 = krj.astype(jnp.float32)
         if bias is not None:
             kj, vj, bj, j = xs
         else:
             kj, vj, j = xs
         kj32, vj32 = kj.astype(jnp.float32), vj.astype(jnp.float32)
-        s = jnp.einsum("bqd,bkd->bqk", q32, kj32) * sm_scale
+        s = jnp.einsum("bqd,bkd->bqk", q32, kj32)
+        if q_rope is not None:
+            s = s + jnp.einsum("bqd,bkd->bqk", qr32, krj32)
+        s = s * sm_scale
         if bias is not None:
             s = s + bj.astype(jnp.float32)
         k_pos = j * block_k + jnp.arange(block_k)[None, :]
@@ -836,21 +1055,28 @@ def _flash_bwd_jax(q, k, v, bias, o, lse, do, causal, sm_scale, block_k,
         ds = p * (dp - delta[..., None])                   # dL/ds_ij
         dq_acc = dq_acc + sm_scale * jnp.einsum("bqk,bkd->bqd", ds, kj32)
         dk_j = sm_scale * jnp.einsum("bqk,bqd->bkd", ds, q32)
-        if bias is not None and not need_dbias:
-            return dq_acc, (dk_j, dv_j)
-        if bias is not None:
+        outs = (dk_j, dv_j)
+        if bias is not None and need_dbias:
             nb = bias.shape[0]
-            dbias_j = ds if nb == q.shape[0] else \
-                jnp.sum(ds, axis=0, keepdims=True)
-            return dq_acc, (dk_j, dv_j, dbias_j)
-        return dq_acc, (dk_j, dv_j)
+            outs += (ds if nb == q.shape[0] else
+                     jnp.sum(ds, axis=0, keepdims=True),)
+        if q_rope is not None:
+            dq_acc = (dq_acc, dqr_acc + sm_scale * jnp.einsum(
+                "bqk,bkd->bqd", ds, krj32))
+            outs += (sm_scale * jnp.einsum("bqk,bqd->bkd", ds, qr32),)
+        return dq_acc, outs
 
     xs = (kc, vc, bc, jnp.arange(nk)) if bias is not None else \
          (kc, vc, jnp.arange(nk))
     zero = (q32[0, 0, 0] + k[0, 0, 0].astype(jnp.float32)
             + do32[0, 0, 0]) * 0.0
-    dq, outs = jax.lax.scan(
-        step, jnp.zeros((bh, tq, d), jnp.float32) + zero, xs)
+    init = jnp.zeros((bh, tq, d), jnp.float32) + zero
+    if q_rope is not None:
+        xs = xs + (krc,)
+        init = (init, jnp.zeros(qr32.shape, jnp.float32) + zero)
+    dq, outs = jax.lax.scan(step, init, xs)
+    if q_rope is not None:
+        (dq, dqr), (*outs, dkrc) = dq, outs
     if bias is not None and need_dbias:
         dkc, dvc, dbc = outs
     else:
@@ -862,7 +1088,13 @@ def _flash_bwd_jax(q, k, v, bias, o, lse, do, causal, sm_scale, block_k,
     if dbc is not None:
         db = dbc.transpose(1, 2, 0, 3).reshape(
             bias.shape[0], tq, tk + pad_k)[:, :, :tk]
-    return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype), db)
+    if q_rope is None:
+        return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
+                db)
+    dkr = dkrc.transpose(1, 0, 2, 3).reshape(bh, tk + pad_k, -1)[:, :tk]
+    return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype), db,
+            dqr.astype(q_rope.dtype),
+            fold(dkr.astype(k_rope.dtype), bh // k_rope.shape[0]))
 
 
 # ---------------------------------------------------------------------------
@@ -872,49 +1104,56 @@ def _flash_bwd_jax(q, k, v, bias, o, lse, do, causal, sm_scale, block_k,
 @functools.partial(jax.custom_vjp,
                    nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12))
 def _flash(q, k, v, bias, causal, sm_scale, block_q, block_k, bwd_blocks,
-           bwd_impl, interpret, window=None, group=1):
+           bwd_impl, interpret, window=None, group=1, q_rope=None,
+           k_rope=None):
     o, _ = _flash_fwd(q, k, v, bias, causal, sm_scale, block_q, block_k,
-                      interpret, window, group)
+                      interpret, window, group, q_rope, k_rope)
     return o
 
 
 def _flash_fwd(q, k, v, bias, causal, sm_scale, block_q, block_k, interpret,
-               window=None, group=1):
+               window=None, group=1, q_rope=None, k_rope=None):
     # end-aligned causal mask (matches jnp.tril(k=tk-tq)): the last query
     # attends to every key — the KV-cache decode convention
     offset = k.shape[1] - q.shape[1]
+    rope = {} if q_rope is None else dict(q_rope=q_rope, k_rope=k_rope)
     if on_tpu() or interpret:
         return _flash_fwd_pallas(q, k, v, bias, causal, sm_scale,
                                  block_q, block_k, offset, interpret,
-                                 window, group)
+                                 window, group, **rope)
     return _flash_fwd_jax(q, k, v, bias, causal, sm_scale, block_k, offset,
-                          window, group)
+                          window, group, **rope)
 
 
 def _flash_vjp_fwd(q, k, v, bias, causal, sm_scale, block_q, block_k,
-                   bwd_blocks, bwd_impl, interpret, window=None, group=1):
+                   bwd_blocks, bwd_impl, interpret, window=None, group=1,
+                   q_rope=None, k_rope=None):
     o, lse = _flash_fwd(q, k, v, bias, causal, sm_scale, block_q, block_k,
-                        interpret, window, group)
-    return o, (q, k, v, bias, o, lse)
+                        interpret, window, group, q_rope, k_rope)
+    return o, (q, k, v, bias, o, lse, q_rope, k_rope)
 
 
 def _flash_vjp_bwd(causal, sm_scale, block_q, block_k, bwd_blocks,
                    bwd_impl, interpret, window, group, res, do,
                    need_dbias=True):
-    q, k, v, bias, o, lse = res
+    """``(dq, dk, dv, dbias, dq_rope, dk_rope)``: one cotangent an operand of
+    :func:`_flash`, the last two ``None`` where the score is one product."""
+    q, k, v, bias, o, lse, q_rope, k_rope = res
     offset = k.shape[1] - q.shape[1]
     bq_b, bk_b = bwd_blocks if bwd_blocks is not None else (block_q, block_k)
+    rope = {} if q_rope is None else dict(q_rope=q_rope, k_rope=k_rope)
     if bias is None and (on_tpu() or interpret):
-        dq, dk, dv = _flash_bwd_pallas(q, k, v, o, lse, do, causal,
-                                       sm_scale, bq_b, bk_b, offset,
-                                       interpret, impl=bwd_impl,
-                                       window=window, group=group)
-        return dq, dk, dv, None
-    dq, dk, dv, db = _flash_bwd_jax(q, k, v, bias, o, lse, do, causal,
-                                    sm_scale, bk_b, offset,
-                                    need_dbias=need_dbias, window=window,
-                                    group=group)
-    return dq, dk, dv, db
+        dq, dk, dv, *dr = _flash_bwd_pallas(q, k, v, o, lse, do, causal,
+                                            sm_scale, bq_b, bk_b, offset,
+                                            interpret, impl=bwd_impl,
+                                            window=window, group=group,
+                                            **rope)
+        return (dq, dk, dv, None, *(dr or (None, None)))
+    dq, dk, dv, db, *dr = _flash_bwd_jax(q, k, v, bias, o, lse, do, causal,
+                                         sm_scale, bk_b, offset,
+                                         need_dbias=need_dbias,
+                                         window=window, group=group, **rope)
+    return (dq, dk, dv, db, *(dr or (None, None)))
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -1052,13 +1291,19 @@ def _collapse_bias(bias, b, h, tq, tk):
 
 
 def _statics(q, k, v, causal, sm_scale, block_q, block_k, block_q_bwd,
-             block_k_bwd, bwd_impl, interpret, window):
+             block_k_bwd, bwd_impl, interpret, window, q_rope=None,
+             k_rope=None):
     """``_flash``'s non-differentiated arguments, in its order, from the
     shapes and types of ``q``, ``k``, ``v`` alone: the checks, the block
     choice from the tables and the window folded away where it is the whole
-    causal half."""
+    causal half.  With ``q_rope`` / ``k_rope`` the default scale and the
+    tables' rows are those of the whole score width, Q's plus the rotary
+    part's: the blocks a concatenated Q and K would get."""
     b, h, tq, d = q.shape
     hk, tk = k.shape[1], k.shape[2]
+    if (q_rope is None) != (k_rope is None):
+        raise ValueError("q_rope and k_rope come together: the score's "
+                         "second product needs both")
     if bwd_impl is not None and bwd_impl not in _BWD_IMPLS:
         raise ValueError(f"bwd_impl {bwd_impl!r}: the Pallas backwards are "
                          f"{_BWD_IMPLS} (None: fused where it fits VMEM)")
@@ -1068,6 +1313,15 @@ def _statics(q, k, v, causal, sm_scale, block_q, block_k, block_q_bwd,
         raise ValueError(f"Q is {d} wide and K {k.shape[3]}: the scores "
                          "contract over one width (V's may differ)")
     group = h // hk
+    if q_rope is not None:
+        hr, d_r = k_rope.shape[1], k_rope.shape[3]
+        if q_rope.shape != (b, h, tq, d_r) or h % hr or \
+                k_rope.shape != (b, hr, tk, d_r):
+            raise ValueError(
+                f"q_rope {q_rope.shape} and k_rope {k_rope.shape} beside Q "
+                f"{q.shape} and K {k.shape}: [b, h, Tq, d_r] and [b, h_r, "
+                "Tk, d_r] with h % h_r == 0")
+        d = d + d_r                # the score's width: scale and tables
     if window is not None:
         if not causal:
             raise ValueError("window= needs causal=True")
@@ -1117,19 +1371,26 @@ def _statics(q, k, v, causal, sm_scale, block_q, block_k, block_q_bwd,
 
 
 def _plan(q, k, v, bias, causal, sm_scale, block_q, block_k, block_q_bwd,
-          block_k_bwd, bwd_impl, interpret, window):
+          block_k_bwd, bwd_impl, interpret, window, q_rope=None,
+          k_rope=None):
     """What :func:`flash_attention` and its two halves share: :func:`_statics`,
     the heads collapsed into the batch and the bias broadcast.  Returns
-    ``((q, k, v, bias) collapsed, statics)``."""
+    ``((q, k, v, bias) collapsed, rest)``, ``rest`` being :func:`_flash`'s
+    other arguments in its order: the nine statics, then ``q_rope`` and
+    ``k_rope`` collapsed (``None`` twice where the score is one product)."""
     statics = _statics(q, k, v, causal, sm_scale, block_q, block_k,
-                       block_q_bwd, block_k_bwd, bwd_impl, interpret, window)
+                       block_q_bwd, block_k_bwd, bwd_impl, interpret, window,
+                       q_rope, k_rope)
     b, h, tq, d = q.shape
     hk, tk = k.shape[1], k.shape[2]
     qc = q.reshape(b * h, tq, d)
     kc = k.reshape(b * hk, tk, d)
     vc = v.reshape(b * hk, tk, v.shape[3])   # V's own width, the output's
     bc = None if bias is None else _collapse_bias(bias, b, h, tq, tk)
-    return (qc, kc, vc, bc), statics
+    rope = (None, None) if q_rope is None else (
+        q_rope.reshape(b * h, tq, -1),
+        k_rope.reshape(b * k_rope.shape[1], tk, -1))
+    return (qc, kc, vc, bc), statics + rope
 
 
 def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
@@ -1140,7 +1401,9 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
                     block_k_bwd: Optional[int] = None,
                     bwd_impl: Optional[str] = None,
                     interpret: bool = False,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None,
+                    q_rope: Optional[jax.Array] = None,
+                    k_rope: Optional[jax.Array] = None):
     """Fused attention over [batch, heads, T, head_dim] tensors: Q and K
     ``[.., d_qk]``, V ``[.., d_v]``, the result ``[batch, heads, Tq, d_v]``
     (``d_v`` read from V; the two are one width for every model but latent
@@ -1177,18 +1440,29 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
     and the blocks pass ``_FUSED_VMEM_SHARE`` of a core's VMEM: ``Tq · d_qk``
     some 4 to 8 times the cells') or "split" (two-pass, outright); anything
     else raises.  :func:`flash_bwd_kernel` says which one a call gets.
+
+    ``q_rope`` ``[batch, heads, Tq, d_r]`` and ``k_rope`` ``[batch, h_r, Tk,
+    d_r]`` (``heads % h_r == 0``; latent attention: one rotary key head for
+    all, ``d_r`` 64 beside 128): the score of a pair is ``(q·k + q_rope·
+    k_rope) · sm_scale``, a second product into the same float32 tile in
+    every kernel, so that no ``[q | q_rope]`` and no per-head copy of the
+    rotary key is ever built in HBM: query head ``h`` reads rotary head ``h
+    // (heads // h_r)`` through an index map, and ``k_rope``'s gradient is
+    summed over that group.  ``sm_scale`` defaults to ``(d_qk + d_r) **
+    -0.5`` and the blocks are the tables' at that whole width.  Without the
+    two the lowering is what it was before they existed.
     """
-    (qc, kc, vc, bc), statics = _plan(
+    (qc, kc, vc, bc), rest = _plan(
         q, k, v, bias, causal, sm_scale, block_q, block_k, block_q_bwd,
-        block_k_bwd, bwd_impl, interpret, window)
-    return _flash(qc, kc, vc, bc, *statics).reshape(
+        block_k_bwd, bwd_impl, interpret, window, q_rope, k_rope)
+    return _flash(qc, kc, vc, bc, *rest).reshape(
         q.shape[:3] + v.shape[3:])
 
 
 def flash_attention_fwd(q, k, v, bias=None, causal=False, sm_scale=None,
                         block_q=None, block_k=None, block_q_bwd=None,
                         block_k_bwd=None, bwd_impl=None, interpret=False,
-                        window=None):
+                        window=None, q_rope=None, k_rope=None):
     """The forward half of :func:`flash_attention` as a plain function, for
     a caller that keeps the residuals itself (the ``flash_attention`` op of a
     ``Program``): ``(o [b, h, Tq, d], lse [b, h, Tq] float32)``, the
@@ -1196,50 +1470,56 @@ def flash_attention_fwd(q, k, v, bias=None, causal=False, sm_scale=None,
     arguments and block choice; the ``custom_vjp``'s forward rule, called
     plainly."""
     b, h, tq, _ = q.shape
-    (qc, kc, vc, bc), statics = _plan(
+    (qc, kc, vc, bc), rest = _plan(
         q, k, v, bias, causal, sm_scale, block_q, block_k, block_q_bwd,
-        block_k_bwd, bwd_impl, interpret, window)
-    o, (*_, lse) = _flash_vjp_fwd(qc, kc, vc, bc, *statics)
-    return o.reshape(b, h, tq, v.shape[3]), lse.reshape(b, h, tq)
+        block_k_bwd, bwd_impl, interpret, window, q_rope, k_rope)
+    o, res = _flash_vjp_fwd(qc, kc, vc, bc, *rest)
+    return o.reshape(b, h, tq, v.shape[3]), res[5].reshape(b, h, tq)
 
 
 def flash_attention_bwd(q, k, v, bias, o, lse, do, causal=False,
                         sm_scale=None, block_q=None, block_k=None,
                         block_q_bwd=None, block_k_bwd=None, bwd_impl=None,
-                        interpret=False, window=None, need_dbias=True):
+                        interpret=False, window=None, need_dbias=True,
+                        q_rope=None, k_rope=None):
     """The backward half: ``(dq, dk, dv, dbias)`` from the inputs, what
     :func:`flash_attention_fwd` returned for them and the output's gradient;
     ``dbias`` has the bias's shape, and is ``None`` without a bias or where
     ``need_dbias`` is false (nobody keeps its [Tq, Tk] tiles then).
     The kernels and blocks are the ones ``jax.grad`` of
     :func:`flash_attention` runs (``_flash_vjp_bwd``); nothing of the forward
-    is computed again but the scores, tile by tile, from ``lse``."""
+    is computed again but the scores, tile by tile, from ``lse``.  With
+    ``q_rope`` / ``k_rope`` the result is ``(dq, dk, dv, dbias, dq_rope,
+    dk_rope)``."""
     b, h, tq, _ = q.shape
-    (qc, kc, vc, bc), statics = _plan(
+    (qc, kc, vc, bc), rest = _plan(
         q, k, v, bias, causal, sm_scale, block_q, block_k, block_q_bwd,
-        block_k_bwd, bwd_impl, interpret, window)
-    dq, dk, dv, db = _flash_vjp_bwd(
-        *statics, (qc, kc, vc, bc, o.reshape(b * h, tq, v.shape[3]),
-                   lse.reshape(b * h, tq)),
+        block_k_bwd, bwd_impl, interpret, window, q_rope, k_rope)
+    dq, dk, dv, db, dqr, dkr = _flash_vjp_bwd(
+        *rest[:-2], (qc, kc, vc, bc, o.reshape(b * h, tq, v.shape[3]),
+                     lse.reshape(b * h, tq), *rest[-2:]),
         do.reshape(b * h, tq, v.shape[3]), need_dbias)
     if db is not None:
         # the transpose of _collapse_bias: summed over what it broadcast
         db, = jax.vjp(lambda x: _collapse_bias(x, b, h, tq, k.shape[2]),
                       bias)[1](db.astype(bias.dtype))
-    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape), db
+    grads = dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape), db
+    if q_rope is None:
+        return grads
+    return grads + (dqr.reshape(q_rope.shape), dkr.reshape(k_rope.shape))
 
 
 def flash_bwd_kernel(q, k, v, bias=None, causal=False, sm_scale=None,
                      block_q=None, block_k=None, block_q_bwd=None,
                      block_k_bwd=None, bwd_impl=None, interpret=False,
-                     window=None):
+                     window=None, q_rope=None, k_rope=None):
     """Which backward :func:`flash_attention_bwd` (and ``jax.grad`` of
     :func:`flash_attention`) runs for these arguments, from their shapes
     alone: ``"fused"`` or ``"split"`` of the Pallas kernels,
     or ``"jax"``, the blockwise fallback (a bias, or no TPU)."""
     *_, block_q, block_k, bwd_blocks, bwd_impl, interpret, _, _ = _statics(
         q, k, v, causal, sm_scale, block_q, block_k, block_q_bwd,
-        block_k_bwd, bwd_impl, interpret, window)
+        block_k_bwd, bwd_impl, interpret, window, q_rope, k_rope)
     if bias is not None or not (on_tpu() or interpret):
         return "jax"
 
@@ -1247,16 +1527,18 @@ def flash_bwd_kernel(q, k, v, bias=None, causal=False, sm_scale=None,
         return jax.ShapeDtypeStruct(
             (x.shape[0] * x.shape[1],) + tuple(x.shape[2:]), x.dtype)
     return _bwd_kernel_name(collapsed(q), collapsed(k), collapsed(v),
-                            *(bwd_blocks or (block_q, block_k)), bwd_impl)
+                            *(bwd_blocks or (block_q, block_k)), bwd_impl,
+                            0 if q_rope is None else q_rope.shape[3])
 
 
 def flash_lse_layout(q, k, v, causal=False, sm_scale=None, block_q=None,
-                     block_k=None, interpret=False, window=None, **_):
+                     block_k=None, interpret=False, window=None, q_rope=None,
+                     k_rope=None, **_):
     """How the forward kernel writes ``lse`` for these arguments, from their
     shapes alone (:func:`_lse_rows` at the blocks the tables give):
     ``"row"``, ``[bh, 1, Tq]`` as the backward reads it, or ``"lanes"``, the
     lane-broadcast ``[bh, Tq, 128]`` columns of which one lane is kept."""
     block_q = _statics(q, k, v, causal, sm_scale, block_q, block_k, None,
-                       None, None, interpret, window)[2]
+                       None, None, interpret, window, q_rope, k_rope)[2]
     tq = q.shape[2]
     return "row" if _lse_rows(block_q, tq + (-tq) % block_q) else "lanes"

@@ -433,3 +433,64 @@ def test_tpu_compiler_takes_the_rope_kernel(topo, case, transpose):
     if head_dim % 128 == 0:
         assert not re.search(r" = \S+ (copy|convert)\(", entry)
         assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+#: name -> T: the two latent-attention cells' flash calls since PR 48, 32
+#: heads of 128 + 64 over 128-wide values, ONE rotary key head, bf16
+TWO_PRODUCT_CASES = {"joyai": 8192, "xing4": 4096}
+
+
+@pytest.mark.parametrize("half", ["forward", "backward"])
+@pytest.mark.parametrize("case", sorted(TWO_PRODUCT_CASES))
+def test_tpu_compiler_takes_the_two_product_kernels(topo, monkeypatch, case,
+                                                    half):
+    """The flash kernels with the score as two products (``q_rope``,
+    ``k_rope``) at the cells' real shapes, compiled for one described chip:
+    Mosaic takes the 64-wide blocks, scratch and the ``[Tq, 64]`` dQRope
+    accumulator; the forward is one custom call that reads the ``[1, T,
+    64]`` rotary key as it is, and the module holds nothing 192 wide and no
+    32-head copy of that key; the backward is the fused kernel, asks for the
+    VMEM its shapes need, and the only ``[32, T, 64]`` it writes beside
+    dQRope is the per-head dKRope partial that the sum outside folds to one
+    head."""
+    import importlib
+    import re
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    F = importlib.import_module("paddle_tpu.pallas.flash_attention")
+    monkeypatch.setattr(F, "on_tpu", lambda: True)
+    t, h = TWO_PRODUCT_CASES[case], 32
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def s(n, w, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((1, n, t, w), dtype, sharding=one)
+    q, k, v, qr, kr = s(h, 128), s(h, 128), s(h, 128), s(h, 64), s(1, 64)
+    kw = dict(causal=True, sm_scale=192 ** -0.5)
+    assert F.flash_lse_layout(q, k, v, q_rope=qr, k_rope=kr, **kw) == "row"
+    assert F.flash_bwd_kernel(q, k, v, interpret=True, q_rope=qr, k_rope=kr,
+                              **kw) == "fused"
+    with _no_compile_cache():
+        if half == "forward":
+            compiled = jax.jit(lambda q, k, v, qr, kr: F.flash_attention_fwd(
+                q, k, v, q_rope=qr, k_rope=kr, **kw)).lower(
+                    q, k, v, qr, kr).compile()
+        else:
+            lse = jax.ShapeDtypeStruct((1, h, t), jnp.float32, sharding=one)
+            compiled = jax.jit(
+                lambda q, k, v, o, lse, do, qr, kr: F.flash_attention_bwd(
+                    q, k, v, None, o, lse, do, q_rope=qr, k_rope=kr, **kw)
+            ).lower(q, k, v, v, lse, v, qr, kr).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.split("\n")
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 1, calls
+    assert not re.search(r"\[(1,)?32,\d+,(192|256)\]", text)
+    if half == "forward":
+        assert f"bf16[1,{t},64]" in calls[0]        # the key, one head
+        assert not re.search(rf"bf16\[(1,)?32,{t},64\]\S* broadcast", text)
+    else:
+        assert "flash_bwd_fused" in calls[0]
+        # dQ, dK, dV, dKRope a head, dQRope: five results of one call
+        results = calls[0].split(" custom-call(")[0]
+        assert len(re.findall(rf"bf16\[32,{t},128\]", results)) == 3
+        assert len(re.findall(rf"bf16\[32,{t},64\]", results)) == 2

@@ -196,6 +196,15 @@ def _configs(op):
              "V": [f(1, 2, 8, 4)]},
             {"sm_scale": 0.5, "causal": False}, loss_outputs=["Out"],
             rtol=8e-2, atol=2e-2),
+        # the score as two products: the op's optional QRope and KRope
+        # slots, one rotary key head for both query heads, so IG$KRope is
+        # a sum over them
+        "flash_attention+rope": lambda: _Cfg(
+            {"Q": [f(1, 2, 8, 4)], "K": [f(1, 2, 8, 4)],
+             "V": [f(1, 2, 8, 4)], "QRope": [f(1, 2, 8, 2)],
+             "KRope": [f(1, 1, 8, 2)]},
+            {"sm_scale": 0.5, "causal": True}, loss_outputs=["Out"],
+            rtol=8e-2, atol=2e-2),
         "fsp": lambda: _Cfg({"X": [f(1, 2, 3, 3)], "Y": [f(1, 4, 3, 3)]}),
         # analysis.fusion rewrite target: exact composition of
         # mul+bias+gelu+tagged dropout (mask is a pure function of the
@@ -664,6 +673,9 @@ def _resolve(op_type):
 
 
 AUDIT_OPS = sorted(t for t in _diffable_ops() if t not in EXCLUDE)
+#: an op's second recipe, "<op>+<what>": optional slots that change what
+#: its grad op computes
+AUDIT_VARIANTS = ["flash_attention+rope"]
 
 
 def test_audit_accounts_for_every_op():
@@ -676,9 +688,10 @@ def test_audit_accounts_for_every_op():
     assert sorted(diffable - set(EXCLUDE)) == AUDIT_OPS
 
 
-@pytest.mark.parametrize("op_type", AUDIT_OPS)
+@pytest.mark.parametrize("op_type", AUDIT_OPS + AUDIT_VARIANTS)
 def test_check_grad(op_type):
     cfg = _resolve(op_type)
+    op_type = op_type.partition("+")[0]
     assert cfg is not None, (
         f"{op_type}: no input config — add one to _configs() or document "
         f"an exclusion in EXCLUDE")

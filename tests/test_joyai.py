@@ -370,10 +370,11 @@ def test_the_tags_ride_the_ops_and_their_grads_into_the_step():
     """``mla_proj`` over everything of latent attention but the flash op,
     ``mtp`` over the whole module (``mtp.mla_proj``, ``mtp.shared_expert``
     inside it), ``dense_ffn`` and ``shared_expert`` as Trinity tags them;
-    grad ops inherit; the flash counters label 24/12."""
+    grad ops inherit; the flash counters label the two-product score's
+    widths, 16+8/12, and no concat or expand is left under ``mla_proj``."""
     from paddle_tpu.framework import executor as E
     from paddle_tpu.ops.attention_ops import FLASH_LOWERINGS_CTR as ctr
-    labels = dict(window="none", kv_groups="1", impl="jax", widths="24/12")
+    labels = dict(window="none", kv_groups="1", impl="jax", widths="16+8/12")
     before = ctr.value(**labels)
     cfg = toy_cfg(n_layer=2)
     scope, main, exe, parts, loss = _model(cfg)
@@ -385,12 +386,15 @@ def test_the_tags_ride_the_ops_and_their_grads_into_the_step():
         "mtp.shared_expert") for g in (False, True)}
     scoped = {E.op_scope(op) for op in ops}
     for s in ("pt.fwd/mul/mla_proj", "pt.bwd/mul_grad/mtp.mla_proj",
-              "pt.fwd/rope/mla_proj", "pt.fwd/concat/mla_proj",
+              "pt.fwd/rope/mla_proj", "pt.bwd/rope_grad/mla_proj",
               "pt.fwd/flash_attention", "pt.fwd/flash_attention/mtp",
               "pt.bwd/flash_attention_grad/mtp", "pt.fwd/moe_ffn/mtp",
               "pt.fwd/lookup_table/mtp", "pt.fwd/mul/mtp",
               "pt.bwd/mul_grad/mtp.shared_expert", "pt.fwd/mul/dense_ffn"):
         assert s in scoped, (s, sorted(scoped))
+    built = [s for s in scoped if "mla_proj" in s and s.split("/")[1] in (
+        "concat", "expand", "concat_grad", "expand_grad")]
+    assert not built, built
     feed = adapter.make_batch(np.random.RandomState(0), cfg, 1, SEQ)
     exe.run(main, feed=feed, scope=scope, fetch_list=[loss.name])
     assert ctr.value(**labels) == before + 3    # dense, expert, MTP blocks

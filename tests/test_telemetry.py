@@ -211,6 +211,34 @@ def test_aggregate_dispatch_stats_multi_executor_and_reset():
                     '{executor="retired"}'] >= s1["steps_dispatched"]
 
 
+def test_a_finalizer_that_folds_a_family_under_its_own_lock_does_not_hang():
+    """A collection may start between two bytecodes of ``series`` (or any
+    holder of a family's lock) and run a dead executor's finalizer, whose
+    ``retire`` takes the same family's lock in the same thread: tier-1 hung
+    there once (PR 48: ``test_coordinator`` inside ``counter_totals``).  The
+    lock is re-entrant, so the fold goes through and the totals stay exact."""
+    fam = monitor.Counter("pr48_reentrant_total", "t", ("executor",))
+    fam.labels(executor="7").inc(3)
+    done = []
+
+    def finalizer():                    # what _ExecStats.retire does
+        fam.labels(executor="retired")
+        fam.fold({"executor": "7"}, {"executor": "retired"})
+        done.append(True)
+    worker = threading.Thread(target=lambda: _under(fam, finalizer),
+                              daemon=True)
+    worker.start()
+    worker.join(10)
+    assert done and not worker.is_alive()
+    assert {tuple(k.items()): c.get() for k, c in fam.series()} == \
+        {(("executor", "retired"),): 3}
+
+
+def _under(fam, fn):
+    with fam._mu:
+        fn()
+
+
 def test_dispatch_stats_concurrent_run_threads_exact():
     """Registry-backed counters under concurrent run() threads: the final
     counts must be exact (lost updates would silently undercount)."""

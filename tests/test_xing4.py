@@ -328,6 +328,40 @@ def test_rope_by_a_frequency_table_forward_and_gradient(form, request):
     assert _rel(plain, got[0]) > 0.1
 
 
+
+
+def test_latent_attention_hands_the_flash_op_its_pieces(toy_run):
+    """Since PR 48: no ``concat`` and no ``expand`` (or their grads) under
+    ``mla_proj``, every flash op with its QRope and KRope slots, the grad op
+    returning both gradients, and the lowerings counted at the two-product
+    widths ``d_nope+d_rope/d_v``."""
+    from paddle_tpu.framework import executor as E
+    from paddle_tpu.ops import attention_ops as A
+    cfg, ops = toy_run["cfg"], toy_run["main"].global_block().ops
+    built = [E.op_scope(op) for op in ops if op.type.partition("_grad")[0]
+             in ("concat", "expand") and "mla_proj" in E.op_scope(op)]
+    assert not built, built
+    flash = [op for op in ops if op.type == "flash_attention"]
+    assert len(flash) == cfg.n_layer
+    for op in flash:
+        assert op.input("QRope") and op.input("KRope")
+    grads = [op for op in ops if op.type == "flash_attention_grad"]
+    assert len(grads) == cfg.n_layer
+    for op in grads:
+        assert all(op.output("IG$" + s)[0] for s in
+                   ("Q", "K", "V", "QRope", "KRope")), op.outputs
+    widths = f"{cfg.d_nope}+{cfg.d_rope}/{cfg.d_v}"
+    assert A.FLASH_LOWERINGS_CTR.value(
+        window="none", kv_groups="1", impl="jax", widths=widths,
+        lse="row") + A.FLASH_LOWERINGS_CTR.value(
+        window="none", kv_groups="1", impl="jax", widths=widths,
+        lse="lanes") >= cfg.n_layer
+    assert A.FLASH_BWD_KERNEL_CTR.value(
+        kernel="jax", window="none", widths=widths) >= cfg.n_layer
+    assert not A.FLASH_LOWERINGS_CTR.value(
+        window="none", kv_groups="1", impl="jax", lse="row",
+        widths=f"{cfg.d_nope + cfg.d_rope}/{cfg.d_v}")
+
 # -- the whole model ---------------------------------------------------------------
 
 def _model(cfg, seq=SEQ, seed=3, recompute=False, amp=False):
@@ -505,9 +539,11 @@ def _ref_params_of_block(values, cfg):
 #: (``tools/joyai_step_aot.py --lowered``) was compared at both commits too
 #: and is the same outside the Mosaic kernels' serialized bodies, which carry
 #: source lines (PERF.md section 6, PR 45).  A PR that means to change JoyAI's
-#: lowering replaces the hash and says so.
+#: lowering replaces the hash and says so.  PR 48 did (it read 0f228436…
+#: until then): ``latent_attention`` hands the flash op its pieces (QRope,
+#: KRope) and the two ``concat``s and the ``expand`` are gone from the step.
 JOYAI_TOY_STEP_SHA256 = (
-    "0f228436611a000caddbc98fdca0e5cd9514b50953454730e9dc34c3d0b1028d")
+    "28f4c15b81468b08df4f320a905ff11ce51aec3d88a21f7eef051b8e8c560876")
 
 
 def _joyai_step_text():

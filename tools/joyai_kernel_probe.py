@@ -7,7 +7,13 @@ the 64-wide rotary key is one head
 for all 32, broadcast and concatenated behind each head's 128-wide content
 part outside the kernel (``build``: Q's and K's concatenation, forward, and
 their transposes, backward, as XLA fuses them alone), which is the most a
-kernel that took the score as two products could save.  ``128/128`` rows are
+kernel that took the score as two products could save.  Since PR 48 the
+kernels take it so (``q_rope=``, ``k_rope=``), and the ``pieces`` rows time
+both forms from the same four pieces at ``--piece_seqs`` (JoyAI's 8192 and
+Xing4.0's 4096): ``build + one product`` (the concatenation and broadcast,
+then the kernels at 192 | 128, differentiated back to the pieces) against
+``two products`` (the kernels on the pieces), forward and forward + backward,
+each with its distances from ``mha_reference``.  ``128/128`` rows are
 the same kernels at Trinity's width, for the rate.  Prints one JSON line per
 case: forward ms, forward + backward ms, the backward's temporaries and, for
 the block tables' own choice, how far the output and the gradients are from
@@ -18,7 +24,8 @@ heads at a time).
     JAX_PLATFORMS=cpu python3 tools/joyai_kernel_probe.py --aot   # compiles
         each block choice for a described v5e, runs nothing: which fit VMEM,
         which backward the entry point runs, the VMEM limit each call asks
-        for and, for the forward, the least limit that compiles the row
+        for and, for the forward, the least limit that compiles the row;
+        then the same rows for the two-product kernels (``"rope": 64``)
 
 ``--forward`` (PR 39) times the forward half alone (``flash_attention_fwd``:
 the kernel and what hands ``lse`` on) over ``--fwd_blocks``, the 2048-wide
@@ -162,32 +169,43 @@ def aot(args, F):
     def s(w, dtype=jnp.dtype(args.dtype)):
         return jax.ShapeDtypeStruct((bh, t, w), dtype, sharding=one)
     sm = args.d_qk ** -0.5
-    aot_forward(F, _blocks(args.fwd_blocks), lambda bq, bk: jax.jit(
-        lambda q, k, v: F._flash_fwd_pallas(
-            q, k, v, None, True, sm, bq, bk, 0, False)
-    ).lower(s(args.d_qk), s(args.d_qk), s(args.d_v)))
-    if args.forward:
-        return
     lse = jax.ShapeDtypeStruct((bh, t), jnp.float32, sharding=one)
-    for impl, bq, bk in _blocks(args.bwd_blocks):
-        fn = jax.jit(lambda q, k, v, o, lse, do: F._flash_bwd_pallas(
-            q, k, v, o, lse, do, True, sm, bq, bk, 0, False, impl=impl))
-        try:
-            c = fn.lower(s(args.d_qk), s(args.d_qk), s(args.d_v),
-                         s(args.d_v), lse, s(args.d_v)).compile()
-            row = {"bwd": [impl, bq, bk], "compiles": True, "temp_gb":
-                   c.memory_analysis().temp_size_in_bytes / 1e9}
-        except Exception as e:
-            row = {"bwd": [impl, bq, bk], "compiles": False,
-                   "error": str(e).strip().splitlines()[-1][:160]}
-        # what the entry point runs for this request, and the VMEM it asks
-        # for (the split kernels: Mosaic's default 16 MiB)
-        row["runs"] = F._bwd_kernel_name(s(args.d_qk), s(args.d_qk),
-                                         s(args.d_v), bq, bk, impl)
-        row["vmem_limit_mib"] = F._fused_vmem_bytes(
-            t, args.d_qk, args.d_v, bq, bk, jnp.dtype(args.dtype).itemsize
-        ) / 2 ** 20 if row["runs"] == "fused" else 16
-        print(json.dumps(row), flush=True)
+    # the score as one product at d_qk, then as two at d_qk - d_rope + d_rope
+    # with ONE rotary key head for all (the collapsed [1, t, d_rope])
+    for d_r in (0, args.d_rope):
+        d = args.d_qk - d_r
+        rope = {}
+        if d_r:
+            print(json.dumps({"rope": d_r, "widths": f"{d}+{d_r}/{args.d_v}"}),
+                  flush=True)
+            rope = dict(q_rope=s(d_r), k_rope=jax.ShapeDtypeStruct(
+                (1, t, d_r), jnp.dtype(args.dtype), sharding=one))
+        aot_forward(F, _blocks(args.fwd_blocks), lambda bq, bk: jax.jit(
+            lambda q, k, v, **r: F._flash_fwd_pallas(
+                q, k, v, None, True, sm, bq, bk, 0, False, **r)
+        ).lower(s(d), s(d), s(args.d_v), **rope))
+        if args.forward:
+            continue
+        for impl, bq, bk in _blocks(args.bwd_blocks):
+            fn = jax.jit(lambda q, k, v, o, lse, do, **r: F._flash_bwd_pallas(
+                q, k, v, o, lse, do, True, sm, bq, bk, 0, False, impl=impl,
+                **r))
+            try:
+                c = fn.lower(s(d), s(d), s(args.d_v), s(args.d_v), lse,
+                             s(args.d_v), **rope).compile()
+                row = {"bwd": [impl, bq, bk], "compiles": True, "temp_gb":
+                       c.memory_analysis().temp_size_in_bytes / 1e9}
+            except Exception as e:
+                row = {"bwd": [impl, bq, bk], "compiles": False,
+                       "error": str(e).strip().splitlines()[-1][:160]}
+            # what the entry point runs for this request, and the VMEM it
+            # asks for (the split kernels: Mosaic's default 16 MiB)
+            row["runs"] = F._bwd_kernel_name(s(d), s(d), s(args.d_v), bq, bk,
+                                             impl, d_r)
+            row["vmem_limit_mib"] = F._fused_vmem_bytes(
+                t, d, args.d_v, bq, bk, jnp.dtype(args.dtype).itemsize, d_r
+            ) / 2 ** 20 if row["runs"] == "fused" else 16
+            print(json.dumps(row), flush=True)
 
 
 def main():
@@ -200,6 +218,11 @@ def main():
     ap.add_argument("--fwd_blocks", default=FWD_BLOCKS)
     ap.add_argument("--bwd_blocks", default=BWD_BLOCKS)
     ap.add_argument("--iters", type=int, default=10)
+    ap.add_argument("--piece_seqs", default="8192,4096",
+                    help="lengths of the pieces rows (build + one product "
+                    "against two products); empty: none")
+    ap.add_argument("--pieces_only", action="store_true",
+                    help="the pieces rows and nothing else")
     ap.add_argument("--aot", action="store_true")
     ap.add_argument("--forward", action="store_true",
                     help="the forward half alone over --fwd_blocks")
@@ -221,6 +244,7 @@ def main():
         args.seq, args.heads, args.iters = 64, 4, 1
         args.d_qk, args.d_v, args.d_rope = 24, 16, 8
         args.fwd_blocks, args.bwd_blocks = "16,16", "fused,16,16"
+        args.piece_seqs = "64,32"
     h, t = args.heads, args.seq
     d_nope = args.d_qk - args.d_rope
     key = jax.random.PRNGKey(0)
@@ -258,6 +282,63 @@ def main():
             row["error"] = str(e).strip().splitlines()[-1][:200]
         print(json.dumps(row), flush=True)
 
+    def pieces_rows(t):
+        """Both forms from the same four pieces at length ``t``: forward and
+        forward + backward (to the pieces' gradients), and how far each is
+        from ``mha_reference`` (float32 at ``highest``, eight heads at a time,
+        the rotary key's gradient summed over all)."""
+        p = [a[:, :, :t] for a in (q_nope, q_rope, k_nope, k_rope, v)]
+        dot = do[:, :, :t]
+        kw = dict(causal=True, sm_scale=sm, interpret=interpret)
+        if interpret:
+            kw.update(block_q=16, block_k=16)
+
+        def one(qn, qr, kn, kr, v):
+            return F.flash_attention(*build(qn, qr, kn, kr), v, **kw)
+
+        def two(qn, qr, kn, kr, v):
+            return F.flash_attention(qn, kn, v, q_rope=qr, k_rope=kr, **kw)
+
+        @jax.jit
+        def oracle_part(qn, qr, kn, kr, v, dog):
+            f32 = [a.astype(jnp.float32) for a in (qn, qr, kn, kr, v)]
+            with jax.default_matmul_precision("highest"):
+                o, back = jax.vjp(
+                    lambda qn, qr, kn, kr, v: F.mha_reference(
+                        qn, kn, v, causal=True, sm_scale=sm, q_rope=qr,
+                        k_rope=kr), *f32)
+                return (o,) + back(dog.astype(jnp.float32))
+        g = min(8, h)
+        parts = [oracle_part(p[0][:, i:i + g], p[1][:, i:i + g],
+                             p[2][:, i:i + g], p[3], p[4][:, i:i + g],
+                             dot[:, i:i + g]) for i in range(0, h, g)]
+        want = [sum(x) if n == 4 else jnp.concatenate(x, axis=1)
+                for n, x in enumerate(zip(*parts))]   # 4: dk_rope, summed
+        names = ("o", "dq_nope", "dq_rope", "dk_nope", "dk_rope", "dv")
+        for case, f in (("build + one product", one), ("two products", two)):
+            def row_of(row, f=f):
+                fwd = jax.jit(f)
+                both = jax.jit(lambda *a: jax.vjp(f, *a[:5])[1](a[5]))
+                row["fwd_ms"] = timed(fwd, *p)
+                row["fwd_bwd_ms"] = timed(both, *p, dot)
+                row["temp_gb"] = both.lower(*p, dot).compile(
+                ).memory_analysis().temp_size_in_bytes / 1e9
+                got = (fwd(*p),) + tuple(both(*p, dot))
+                row.update({f"{n}_rel": off(a, w)
+                            for n, a, w in zip(names, got, want)})
+            say({"case": case, "pieces": [h, t, f"{d_nope}+{args.d_rope}",
+                                          args.d_v]}, row_of)
+
+    def off(got, want):
+        got = got.astype(jnp.float32)
+        return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+
+    if not args.forward:
+        for t_piece in (int(x) for x in args.piece_seqs.split(",") if x):
+            pieces_rows(t_piece)
+    if args.pieces_only:
+        return
+
     # what building Q and K costs alone, forward and with its transpose
     def build_row(row):
         dq, dk = jnp.ones_like(q), jnp.ones_like(k)
@@ -287,10 +368,6 @@ def main():
         parts = [one(*(a[:, i:i + g] for a in (q, k, v, do)))
                  for i in range(0, h, g)]
         return [jnp.concatenate(x, axis=1) for x in zip(*parts)]
-
-    def off(got, want):
-        got = got.astype(jnp.float32)
-        return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
 
     if args.forward:
         rows = _blocks(args.fwd_blocks)
