@@ -52,6 +52,10 @@ BF16_IF_BIG = {
     # the streams in bf16; Phi, Alpha, Bias and the maps float32 (the
     # coefficients and Sinkhorn-Knopp are float32 inside: ops/hc_ops.py)
     "hc_pre", "hc_post",
+    # Q, K and V in bf16; the log-decay and beta stay float32, as does
+    # everything inside the scan (ops/kda_ops.py); kda_gate is in no list:
+    # float32 inside, and its outputs are float32
+    "kda_scan",
 }
 
 _COMPUTE = jnp.bfloat16
@@ -62,7 +66,7 @@ _FLOATS = (jnp.float32, jnp.bfloat16, jnp.float16)
 _SLOT_RESTRICT = {"batch_norm": {"X"}, "layer_norm": {"X"},
                   "group_norm": {"X"}, "rms_norm": {"X"},
                   "short_conv": {"X"}, "hc_pre": {"X"},
-                  "hc_post": {"X", "Y"}}
+                  "hc_post": {"X", "Y"}, "kda_scan": {"Q", "K", "V"}}
 
 # NOTE: the analysis.fusion targets (fused_dense_act,
 # fused_embedding_layer_norm) appear in NO list above on purpose: one

@@ -64,10 +64,22 @@ def _is_rng_op(op: Operator) -> bool:
     return bool(info and info.stateful_rng)
 
 
-def apply_recompute(program: Program,
-                    checkpoints: Sequence[str]) -> Program:
+def apply_recompute(program: Program, checkpoints: Sequence[str],
+                    after_gradient: bool = False) -> Program:
     """Rewrite IN PLACE; returns the program.  ``checkpoints`` are forward
-    var names (segment boundaries) that stay stored."""
+    var names (segment boundaries) that stay stored.
+
+    ``after_gradient``: a segment between two checkpoints is emitted where
+    the backward has made the gradient of the checkpoint that ENDS it, and
+    its input passes the barrier together with that gradient (one
+    ``optimization_barrier`` over the pair, whose second result the backward
+    goes on from), so that the compiler cannot run a segment again before
+    the backward has reached it.  Without it nothing but the scheduler's own
+    choice keeps the recomputed segments from running side by side at the
+    start of the backward (Solar-Open2's step held four blocks' recomputed
+    expert buffers at once: PERF.md section 6, PR 49).  A segment may then
+    read stored values and its own alone: a program in which a later segment
+    reads what an earlier one makes again is refused (``ValueError``)."""
     block = program.global_block()
     ckpt = set(checkpoints)
     loss_seed = None
@@ -106,8 +118,12 @@ def apply_recompute(program: Program,
         return barriered[v]
 
     seen_ckpt = False
+    reached: List[str] = []          # the checkpoints in forward order
+    segment_of: List[int] = []       # per clone: checkpoints before it
     for op in fwd_ops:
         outs = op.output_arg_names()
+        before = len(reached)
+        reached.extend(o for o in outs if o in ckpt)
         if not seen_ckpt:
             if ckpt & set(outs):
                 seen_ckpt = True
@@ -142,6 +158,7 @@ def apply_recompute(program: Program,
                     new.append(rename[n])
             clone.outputs[slot] = new
         recompute_ops.append(clone)
+        segment_of.append(before)
 
     if not recompute_ops:
         return program
@@ -172,12 +189,78 @@ def apply_recompute(program: Program,
         for slot, names in op.inputs.items():
             op.inputs[slot] = [rename.get(n, n) for n in names]
 
-    # op-list position is cosmetic — XLA schedules by dataflow and sinks
-    # each recomputed chain next to the grads consuming it
-    block.ops = fwd_ops + [bwd_ops[0]] + barrier_ops + \
-        recompute_ops + bwd_ops[1:]
+    if after_gradient:
+        block.ops = fwd_ops + _after_gradients(
+            block, bwd_ops, barrier_ops, recompute_ops, segment_of, reached)
+    else:
+        # XLA schedules by dataflow, not by this position: nothing holds a
+        # recomputed chain back to the grads that consume it (JoyAI's step
+        # fits so; Solar-Open2's ran four blocks again side by side and was
+        # refused at 19.19 GiB, which is what after_gradient is for)
+        block.ops = fwd_ops + [bwd_ops[0]] + barrier_ops + \
+            recompute_ops + bwd_ops[1:]
     program._bump_version()
     for typ, n in collections.Counter(
             c.type for c in recompute_ops).items():
         RECOMPUTE_OPS_CTR.inc(n, op=typ)
     return program
+
+
+def _after_gradients(block, bwd_ops, barrier_ops, clones, segment_of,
+                     reached):
+    """The backward's op list with each segment behind the gradient of the
+    checkpoint that ends it: the segment that starts at ``reached[i - 1]``
+    and ends at ``reached[i]`` (``segment_of`` == i) goes behind the last op
+    that writes ``reached[i]@GRAD``; the barrier of its input takes that
+    gradient as a second operand, and the ops behind read the barrier's
+    copy of it.  What has no such gradient (the ops behind the last
+    checkpoint, a stored value that is no checkpoint) stays at the front."""
+    from .core import grad_var_name
+    by_src = {b.inputs["X"][0]: b for b in barrier_ops}
+    last_write = {}
+    for i, op in enumerate(bwd_ops):
+        for n in op.output_arg_names():
+            last_write[n] = i
+    behind = collections.defaultdict(list)        # bwd index -> ops
+    front = []
+    tied = set()
+    for seg in sorted(set(segment_of)):
+        ops = [c for c, s in zip(clones, segment_of) if s == seg]
+        grad = grad_var_name(reached[seg]) if seg < len(reached) else None
+        src = reached[seg - 1] if seg >= 1 else None
+        if grad not in last_write or src not in by_src:
+            front.extend(ops)
+            continue
+        at = last_write[grad]
+        fenced = grad + BARRIER_SUFFIX
+        v = block.var(grad) if block.has_var(grad) else None
+        block.create_var(name=fenced, shape=v.shape if v else None,
+                         dtype=v.dtype if v else "float32")
+        barrier = by_src[src]
+        barrier.inputs["X"].append(grad)
+        barrier.outputs["Out"].append(fenced)
+        tied.add(src)
+        for op in bwd_ops[at + 1:]:
+            for slot, names in op.inputs.items():
+                op.inputs[slot] = [fenced if n == grad else n for n in names]
+        behind[at].extend([barrier] + ops)
+    out = [bwd_ops[0]] + [b for b in barrier_ops
+                          if b.inputs["X"][0] not in tied] + front
+    for i, op in enumerate(bwd_ops[1:], 1):
+        out.append(op)
+        out.extend(behind.get(i, ()))
+    out += behind.get(0, [])
+    # the segments now stand in the backward's order: one that reads what
+    # an EARLIER segment makes again (a term summed over the blocks, as
+    # JoyAI's step has) would read it before it is made
+    made = set()
+    for op in out:
+        late = [n for n in op.input_arg_names()
+                if n.endswith(RECOMPUTE_SUFFIX) and n not in made]
+        if late:
+            raise ValueError(
+                f"after_gradient: {op.type} reads {late[0]}, which an "
+                f"earlier segment makes again and the backward reaches "
+                f"later; make it a checkpoint or leave after_gradient out")
+        made.update(op.output_arg_names())
+    return out

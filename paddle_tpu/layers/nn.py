@@ -1619,23 +1619,86 @@ def fused_lm_head_ce(x, size, label, param_attr=None, bias_attr=None,
     return loss
 
 
-def short_conv(x, filter_size=3, param_attr=None, name=None):
+def short_conv(x, filter_size=3, param_attr=None, name=None, gated=True):
     """The core of a gated short-convolution operator over ``x`` [b, t, 3 d],
     an input projection split in three ``B | C | u``: ``C * conv(B * u)``
     with ``conv`` a causal depthwise convolution of ``filter_size`` taps over
     the ``d`` channels (``c[t] = sum_j w[:, j] * g[t - (filter_size - 1) +
     j]``, zeros before the sequence starts), no bias, no activation
     (``short_conv`` op; its filter ``w`` is [d, filter_size]).  Returns
-    [b, t, d]; the projections before and after are the caller's ``fc``."""
+    [b, t, d]; the projections before and after are the caller's ``fc``.
+
+    ``gated=False``: ``silu(conv(x))`` over ``x`` [b, t, d] as it is (the
+    convolution in front of a linear-attention layer's Q, K and V); the
+    filter is [d, filter_size] again."""
     helper = LayerHelper("short_conv", name=name)
-    d3 = int(x.shape[-1])
-    if d3 % 3:
-        raise ValueError(f"the last axis ({d3}) is not three equal parts")
-    w = helper.create_parameter(param_attr, shape=[d3 // 3, int(filter_size)],
+    d = int(x.shape[-1])
+    attrs = {}
+    if gated:
+        if d % 3:
+            raise ValueError(f"the last axis ({d}) is not three equal parts")
+        d //= 3
+    else:
+        attrs = {"gated": False}
+    w = helper.create_parameter(param_attr, shape=[d, int(filter_size)],
                                 dtype=x.dtype)
     out = helper.create_variable_for_type_inference(x.dtype)
     helper.append_op("short_conv", inputs={"X": [x], "Filter": [w]},
-                     outputs={"Out": [out]})
+                     outputs={"Out": [out]}, attrs=attrs)
+    return out
+
+
+def kda_gate(x, beta_logits, n_head, param_prefix="kda", name=None):
+    """The two gates of a gated delta-rule layer with a per-channel decay
+    (KDA, arXiv:2510.26692), float32 whatever AMP says: ``g = -exp(A_log_h)
+    softplus(x + dt_bias)`` [b, t, n_head, d] from ``x`` [b, t, n_head * d]
+    (the log of the decay, ``<= 0``), and ``beta = sigmoid(beta_logits)``
+    [b, t, n_head].  Parameters, float32: ``<prefix>.A_log`` [n_head], ``log
+    U(1, 16)``, and ``<prefix>.dt_bias`` [n_head * d], the inverse softplus
+    of ``exp U(log 1e-3, log 1e-1)`` (the published layer's initial values);
+    both are drawn here, from the parameter's name, and not by the startup
+    program's seed.  Returns ``(g, beta)`` for :func:`kda_scan`."""
+    import zlib
+    from ..initializer import NumpyArrayInitializer
+    from ..param_attr import ParamAttr
+    helper = LayerHelper("kda_gate", name=name)
+    width = int(x.shape[-1])
+    rng = np.random.RandomState(zlib.crc32(param_prefix.encode()))
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), width))
+    values = {"A_log": np.log(rng.uniform(1.0, 16.0, int(n_head))),
+              "dt_bias": dt + np.log(-np.expm1(-dt))}
+    a_log, dt_bias = (helper.create_parameter(
+        ParamAttr(name=f"{param_prefix}.{what}",
+                  initializer=NumpyArrayInitializer(
+                      values[what].astype(np.float32))),
+        shape=list(values[what].shape), dtype="float32")
+        for what in ("A_log", "dt_bias"))
+    g = helper.create_variable_for_type_inference("float32")
+    beta = helper.create_variable_for_type_inference("float32")
+    helper.append_op(
+        "kda_gate", inputs={"X": [x], "B": [beta_logits], "ALog": [a_log],
+                            "DtBias": [dt_bias]},
+        outputs={"G": [g], "Beta": [beta]})
+    return g, beta
+
+
+def kda_scan(q, k, v, g, beta, chunk=64, neg_eigval=False, name=None):
+    """Gated delta-rule linear attention with a per-channel decay (``kda_scan``
+    op; ``ops/kda_ops.py`` has the equations): per head a ``d_k x d_v`` state,
+    ``S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t
+    v_t^T``, ``o_t = S_t^T q_t``, from zero, run in chunks of ``chunk``
+    positions (one ``lax.scan`` over the chunk states; a ``t`` that is no
+    multiple is padded inside).  ``q``, ``k`` [b, t, h, d_k], ``v`` [b, t, h,
+    d_v], ``g`` [b, t, h, d_k] the LOG of the decay, ``beta`` [b, t, h] in
+    (0, 1); ``neg_eigval`` doubles beta inside; q and k are divided by their
+    norms over d_k inside and q scaled by ``d_k^-0.5``.  Float32 inside;
+    returns [b, t, h, d_v] in q's dtype."""
+    helper = LayerHelper("kda_scan", name=name)
+    out = helper.create_variable_for_type_inference(q.dtype)
+    helper.append_op(
+        "kda_scan", inputs={"Q": [q], "K": [k], "V": [v], "G": [g],
+                            "Beta": [beta]}, outputs={"Out": [out]},
+        attrs={"chunk": int(chunk), "neg_eigval": bool(neg_eigval)})
     return out
 
 

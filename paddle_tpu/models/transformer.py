@@ -1198,6 +1198,191 @@ def build_lfm2_pretrain(cfg: Lfm2Config, seq_len, is_test=False,
     return (src_ids, lm_label), {"expert_load": loads, "hidden": x}, loss
 
 
+# -- Solar Open 2 (solar_open2; Kimi Linear's layer): gated delta-rule linear --
+# -- attention 3:1 with gated position-free grouped-query attention, sigmoid ----
+# -- routing beside a shared expert; a chip may hold a share of a layer's heads -
+
+class SolarOpen2Config:
+    """Solar-Open2-250B defaults (``upstage/Solar-Open2-250B`` config.json,
+    ``model_type`` ``solar_open2``; the linear-attention layer is Kimi
+    Linear's KDA, arXiv:2510.26692).  Layers in ``gqa_layers`` are softmax
+    grouped-query attention with no positional term and a gated output, the
+    others KDA (:func:`kda_attention`); every layer has ``n_experts`` routed
+    experts of width ``d_expert`` (``top_k`` a token, sigmoid scores, a
+    selection bias, the kept scores renormalised and scaled) beside one
+    shared expert of width ``d_expert * n_shared``.
+
+    ``n_head``, ``n_kv_head`` and ``n_kda_head`` are the heads this program
+    HOLDS, default all 64, 8 and 64: under tensor parallelism a chip holds a
+    share of a layer's heads, every head at its published ``d_head``, and
+    the output projections give the partial sum over the held heads (the
+    all-reduce is the deployment's).  ``n_held``/``expert_offset``: the
+    experts held, as :class:`TrinityConfig`."""
+
+    def __init__(self, vocab_size=196608, d_model=4096, n_layer=48,
+                 n_head=64, n_kv_head=8, n_kda_head=64, d_head=128,
+                 d_expert=1280, n_experts=320, top_k=8, n_shared=1,
+                 gqa_layers=None, conv_taps=4, kda_gate_rank=128,
+                 kda_neg_eigval=True, kda_chunk=64, route_scale=1.0,
+                 rms_eps=1e-5, n_held=None, expert_offset=0, init_std=0.02):
+        self.vocab_size = vocab_size
+        self.d_model = d_model
+        self.n_layer = n_layer
+        self.n_head = n_head
+        self.n_kv_head = n_kv_head
+        self.n_kda_head = n_kda_head
+        self.d_head = d_head
+        self.d_expert = d_expert
+        self.n_experts = n_experts
+        self.top_k = top_k
+        self.n_shared = n_shared
+        # published: a GQA layer first of every four
+        self.gqa_layers = sorted(gqa_layers) if gqa_layers is not None \
+            else list(range(0, n_layer, 4))
+        self.conv_taps = conv_taps
+        self.kda_gate_rank = kda_gate_rank
+        self.kda_neg_eigval = kda_neg_eigval
+        self.kda_chunk = kda_chunk
+        self.route_scale = route_scale
+        self.rms_eps = rms_eps
+        self.n_held = n_experts if n_held is None else n_held
+        self.expert_offset = expert_offset
+        self.init_std = init_std
+
+
+def kda_attention(x, cfg: SolarOpen2Config, param_prefix="kda"):
+    """One KDA sublayer over ``x`` [b, t, d_model] (the block's normed
+    input), every op under the ``kda`` tag; ``h = cfg.n_kda_head`` heads of
+    ``d = cfg.d_head``, no bias anywhere::
+
+        [q | k | v | f | g | b] = x W_in      W_in [d_model, 3 h d + 2 r + h]
+        q, k, v = silu(conv(q | k | v))       depthwise, causal, conv_taps
+        decay g_t = -exp(A_log) softplus(f W_f + dt_bias);  beta = sigmoid(b)
+        o = kda_scan(q, k, v, g_t, beta)      q, k normalised and q scaled
+                                              inside; beta doubled where
+                                              cfg.kda_neg_eigval
+        y = W_o [rmsnorm_head(o) * sigmoid(g W_g)]
+
+    ``<prefix>.in_proj.w`` holds the six input projections side by side (the
+    two low-rank gates' down-projections, rank ``r = cfg.kda_gate_rank``, and
+    beta's among them); ``<prefix>.conv.filter`` [3 h d, taps] the three
+    filters (uniform in ``+-taps^-0.5``); ``<prefix>.f_up.w`` and
+    ``<prefix>.g_up.w`` [r, h d]; ``<prefix>.A_log`` [h], ``<prefix>.dt_bias``
+    [h d] (``layers.kda_gate``); ``<prefix>.o_norm.w`` [d] the norm over each
+    head's output; ``<prefix>.out.w`` [h d, d_model], whose result is the
+    partial sum over the heads held here."""
+    from ..initializer import UniformInitializer
+    h, d, r = cfg.n_kda_head, cfg.d_head, cfg.kda_gate_rank
+    dq = h * d
+
+    def proj(v, size, name):
+        return layers.fc(v, size=size, num_flatten_dims=2, bias_attr=False,
+                         param_attr=ParamAttr(name=f"{param_prefix}.{name}.w"))
+
+    with name_scope("kda"):
+        qkv, f, g, b = layers.split(proj(x, 3 * dq + 2 * r + h, "in_proj"),
+                                    [3 * dq, r, r, h], dim=2)
+        bound = float(cfg.conv_taps) ** -0.5
+        qkv = layers.short_conv(
+            qkv, cfg.conv_taps, gated=False,
+            param_attr=ParamAttr(name=f"{param_prefix}.conv.filter",
+                                 initializer=UniformInitializer(-bound,
+                                                                bound)))
+        q, k, v = (layers.reshape(t, shape=[0, 0, h, d])
+                   for t in layers.split(qkv, 3, dim=2))
+        decay, beta = layers.kda_gate(proj(f, dq, "f_up"), b, h, param_prefix)
+        o = layers.kda_scan(q, k, v, decay, beta, chunk=cfg.kda_chunk,
+                            neg_eigval=cfg.kda_neg_eigval)
+        o = layers.rms_norm(o, begin_norm_axis=3, epsilon=cfg.rms_eps,
+                            param_attr=ParamAttr(
+                                name=f"{param_prefix}.o_norm.w"))
+        y = layers.reshape(o, shape=[0, 0, dq]) \
+            * layers.sigmoid(proj(g, dq, "g_up"))
+        return proj(y, cfg.d_model, "out")
+
+
+def solar_open2_decoder_layer(x, cfg: SolarOpen2Config, idx=0,
+                              attn_impl="flash", is_test=False):
+    """One solar_open2 block, pre-norm, two norms, no bias anywhere: ``h = x
+    + Mixer(RMS1(x))``, ``out = h + Shared(RMS2(h)) + MoE(RMS2(h))``.
+    ``Mixer``: in ``cfg.gqa_layers`` grouped-query softmax attention over
+    the whole causal half with NO positional term (``use_rope`` false) and
+    the output gated by ``sigmoid`` of a fourth slice of the fused
+    projection (``use_gqa_gate``), under the ``attn`` tag, at the
+    ``n_head`` over ``n_kv_head`` heads held here (the fused projection is
+    ``[d_model, (2 n_head + 2 n_kv_head) d_head]`` and the output projection
+    ``[n_head d_head, d_model]``: narrower than ``d_model`` where a chip
+    holds a share); else :func:`kda_attention`.  ``MoE``: ``moe_ffn`` with
+    sigmoid scores, a selection bias held at zero, the kept scores
+    renormalised (``+ 1e-20``) and scaled; ``Shared``: :func:`gated_ffn`.
+    Returns ``(out, expert_load)``."""
+    from ..initializer import NormalInitializer
+    p = f"dec_{idx}"
+
+    def norm(v, name):
+        return layers.rms_norm(v, begin_norm_axis=2, epsilon=cfg.rms_eps,
+                               param_attr=ParamAttr(name=f"{p}.{name}.w"))
+
+    n = norm(x, "ln1")
+    if idx in cfg.gqa_layers:
+        with name_scope("attn"):
+            mix = multi_head_attention(
+                n, n, n, cfg.d_model, cfg.n_head, is_test=is_test,
+                param_prefix=f"{p}.attn", attn_impl=attn_impl, causal=True,
+                bias=False, n_kv_head=cfg.n_kv_head, d_head=cfg.d_head,
+                out_gate=True)
+    else:
+        mix = kda_attention(n, cfg, f"{p}.kda")
+    h = x + mix
+    m = norm(h, "ln2")
+    with name_scope("shared_expert"):
+        f = gated_ffn(m, cfg.d_expert * cfg.n_shared, cfg.d_model,
+                      f"{p}.shared")
+    moe, _, _, load = layers.moe_ffn(
+        m, cfg.n_experts, cfg.top_k, cfg.d_expert, norm_topk_prob=True,
+        param_prefix=f"{p}.moe",
+        initializer=NormalInitializer(0.0, cfg.init_std),
+        score_func="sigmoid", select_bias=True, norm_eps=1e-20,
+        route_scale=cfg.route_scale, num_held=cfg.n_held,
+        expert_offset=cfg.expert_offset)
+    return h + f + moe, load
+
+
+def build_solar_open2_pretrain(cfg: SolarOpen2Config, seq_len, is_test=False,
+                               attn_impl="flash", fused_head=True,
+                               checkpoints=None):
+    """Causal LM over :func:`solar_open2_decoder_layer` blocks: ids ->
+    embedding -> ``n_layer`` blocks -> final RMSNorm -> untied bias-free
+    head; loss = mean next-token CE (label 0 excluded, as in the other
+    builders) and nothing else (the selection bias is held at zero, as in
+    :func:`build_trinity_pretrain`).  ``checkpoints=[]`` collects the block
+    boundaries for ``RecomputeOptimizer``: the embedding's output and every
+    block's, so that every block is computed again, the first too.  Returns
+    ``(feeds, parts, loss)`` with ``parts`` = {"expert_load": [per layer],
+    "hidden": the final norm's output}."""
+    src_ids = layers.data("src_ids", shape=[seq_len], dtype="int64")
+    lm_label = layers.data("lm_label", shape=[seq_len], dtype="int64")
+    x = layers.embedding(src_ids, size=[cfg.vocab_size, cfg.d_model],
+                         param_attr=ParamAttr(name="word_embedding"))
+    loads = []
+    if checkpoints is not None:
+        # the first block's input too: what lies before the first checkpoint
+        # is no segment and would be kept whole (0.95 GB and, XLA then
+        # rematerialising on its own, 17 ms a step at the published widths:
+        # benchmark/traffic/lm_s8192_r64.json, recompute_why)
+        checkpoints.append(x)
+    for i in range(cfg.n_layer):
+        x, load = solar_open2_decoder_layer(x, cfg, i, attn_impl, is_test)
+        loads.append(load)
+        if checkpoints is not None:
+            checkpoints.append(x)
+    x = layers.rms_norm(x, begin_norm_axis=2, epsilon=cfg.rms_eps,
+                        param_attr=ParamAttr(name="final_norm.w"))
+    _, loss = _lm_head_loss(x, cfg, lm_label, fused_head, "lm_out",
+                            bias=False)
+    return (src_ids, lm_label), {"expert_load": loads, "hidden": x}, loss
+
+
 def annotate_tensor_parallel(program=None):
     """Megatron-style TP layout via dist_spec (SURVEY §2.5: TP is a
     capability the reference LACKS — first-class here)."""

@@ -1,0 +1,252 @@
+"""Gated delta-rule linear attention with a per-channel decay (KDA, Kimi
+Delta Attention: arXiv:2510.26692 §3; the delta rule of arXiv:2406.06484 with
+the diagonal gate of arXiv:2412.06464 made a vector a head): the first ops
+here whose forward carries state along the sequence.  Per head, ``S`` a
+``d_k x d_v`` state that starts at zero::
+
+    S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t
+
+``g_t`` in R^{d_k} is the LOG of the decay (``<= 0``), ``beta_t`` a scalar.
+Two ops::
+
+    G, Beta = kda_gate(X, B; ALog, DtBias)   g = -exp(A_log_h) softplus(x + dt_bias)
+                                             beta = sigmoid(b)
+    Out     = kda_scan(Q, K, V, G, Beta)
+
+``kda_scan`` runs the recurrence in its chunked (WY / UT-transform) form.  A
+chunk of ``C`` positions with the state ``S_0`` before it, ``Gam_i`` the log
+decays cumulated inside the chunk up to and including ``i``::
+
+    u_i   = beta_i (v_i - (Diag(exp(g_i)) S_{i-1})^T k_i)      so that
+    S_i   = Diag(exp(g_i)) S_{i-1} + k_i u_i^T
+    A_ij  = beta_i sum_c k_ic exp(Gam_ic - Gam_jc) k_jc          j <  i
+    P_ij  =        sum_c q_ic exp(Gam_ic - Gam_jc) k_jc          j <= i
+    (I + A) [W_v | W_k] = Diag(beta) [V | K * exp(Gam)]          unit lower
+    U     = W_v - W_k S_0
+    O     = (Q * exp(Gam)) S_0 + P U
+    S_C   = Diag(exp(Gam_C)) S_0 + (K * exp(Gam_C - Gam))^T U
+
+``A``, ``P``, the triangular solve and every product that has no ``S_0`` in
+it are batched matmuls over all chunks at once; what is left is ONE
+``lax.scan`` over the ``t / C`` chunk states (three small products a step).
+
+**Decays enter as differences of cumulated log-decays and never as a
+quotient of cumulated products**: ``exp(Gam_i) / exp(Gam_j)`` is ``0 / 0`` or
+``x / 0`` once a strong decay has run for a few positions (``exp(-20 * 16)``
+is 0 in float32).  Inside a sub-block of ``sub`` (16) positions the
+differences are taken exactly, ``exp(Gam_i - Gam_j)`` over ``[sub, sub,
+d_k]``; between sub-blocks through the first position ``r`` of the later one,
+``exp(Gam_i - Gam_r) exp(Gam_r - Gam_j)`` with ``j < r <= i``: both exponents
+are ``<= 0``, so nothing overflows, and a factor that underflows bounds a
+product that is as small.  Everything inside is float32 whatever AMP says,
+its products at ``highest`` precision (they are small: some 12 MFLOP a chunk
+and head); Out comes back in Q's dtype.
+
+``paddle_tpu_kda_lowerings_total`` counts the lowerings."""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from .. import monitor as _monitor
+from ..framework.core import grad_var_name
+from ..framework.registry import register_op
+from .common import X
+
+KDA_LOWERINGS_CTR = _monitor.REGISTRY.counter(
+    "paddle_tpu_kda_lowerings_total",
+    "kda_scan and kda_scan_grad lowerings by the heads held, their width, "
+    "the chunk, what implements the op (xla: jnp that XLA fuses, one "
+    "lax.scan over the chunk states) and whether beta is doubled (negative "
+    "eigenvalues) — counted while tracing, once per compile of a program "
+    "that holds the op",
+    ("heads", "head_dim", "chunk", "impl", "neg_eigval"))
+
+#: positions whose decays are differenced exactly, [sub, sub, d_k] a block
+SUB = 16
+L2_EPS = 1e-6
+
+
+def l2norm(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
+
+
+def _block_diag(blocks):
+    """[.., m, s, s] -> [.., m s, m s] with the blocks on the diagonal."""
+    *lead, m, s, _ = blocks.shape
+    eye = jnp.eye(m, dtype=blocks.dtype)[:, None, :, None]
+    return (blocks[..., :, :, None, :] * eye).reshape(*lead, m * s, m * s)
+
+
+def _within_chunks(q, k, gc, sub):
+    """``(P, A)`` [.., C, C] of the module's docstring without ``A``'s beta
+    and before the triangles are cut, from q, k, gc [.., C, d] (gc the log
+    decays cumulated in the chunk)."""
+    *lead, c, d = q.shape
+    m = c // sub
+    qb, kb, gb = (x.reshape(*lead, m, sub, d) for x in (q, k, gc))
+    # inside a sub-block: the differences themselves
+    i = jnp.arange(sub)
+    upto = (i[:, None] >= i[None, :])[:, :, None]
+    decay = jnp.exp(jnp.where(
+        upto, gb[..., :, None, :] - gb[..., None, :, :], -jnp.inf))
+    kd = decay * kb[..., None, :, :]
+    p_diag = jnp.sum(qb[..., :, None, :] * kd, axis=-1)
+    a_diag = jnp.sum(kb[..., :, None, :] * kd, axis=-1)
+    # between sub-blocks: through the later block's first position r
+    ref = gb[..., :, :1, :]
+    down = jnp.exp(gb - ref)                        # exp(Gam_i - Gam_r)
+    earlier = (jnp.arange(c)[None, :] < (jnp.arange(m) * sub)[:, None])
+    up = jnp.exp(jnp.where(earlier[:, :, None],     # exp(Gam_r - Gam_j)
+                           ref - gc[..., None, :, :], -jnp.inf))
+    k_bar = k[..., None, :, :] * up                 # [.., m, C, d]
+    p_off = jnp.einsum("...id,...jd->...ij", qb * down, k_bar)
+    a_off = jnp.einsum("...id,...jd->...ij", kb * down, k_bar)
+    return (p_off.reshape(*lead, c, c) + _block_diag(p_diag),
+            a_off.reshape(*lead, c, c) + _block_diag(a_diag))
+
+
+def kda_chunked(q, k, v, g, beta, *, chunk=64, neg_eigval=False):
+    """q, k [b, t, h, d_k], v [b, t, h, d_v], g [b, t, h, d_k] (log decay),
+    beta [b, t, h] -> o [b, t, h, d_v], float32 (the module's docstring).
+    q and k are divided by their norms over d_k first and q scaled by
+    ``d_k^-0.5``; ``neg_eigval``: beta doubled, so that ``I - beta k k^T``
+    has eigenvalues in (-1, 1).  Everything that has no state in it for all
+    ``t / chunk`` chunks at once, then the scan over them."""
+    f32 = jnp.float32
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+    q, k, v, g, beta = (x.astype(f32) for x in (q, k, v, g, beta))
+    q, k = l2norm(q) * float(dk) ** -0.5, l2norm(k)
+    if neg_eigval:
+        beta = beta * 2.0
+    sub = min(SUB, chunk)
+    assert chunk % sub == 0, f"chunk {chunk} is not a multiple of {sub}"
+    pad = -t % chunk
+    if pad:     # zeros after the end: no key, no write, no decay
+        q, k, v, g = (jnp.pad(x, [(0, 0), (0, pad), (0, 0), (0, 0)])
+                      for x in (q, k, v, g))
+        beta = jnp.pad(beta, [(0, 0), (0, pad), (0, 0)])
+    n = (t + pad) // chunk
+
+    def chunks(x):      # [b, T, h, ...] -> [b, h, n, C, ...]
+        return jnp.moveaxis(x.reshape(b, n, chunk, *x.shape[2:]), 3, 1)
+
+    q, k, v, g, beta = (chunks(x) for x in (q, k, v, g, beta))
+    with jax.default_matmul_precision("highest"):
+        gc = jnp.cumsum(g, axis=3)
+        p, a = _within_chunks(q, k, gc, sub)
+        i = jnp.arange(chunk)
+        p = jnp.where(i[:, None] >= i[None, :], p, 0.0)
+        a = jnp.where(i[:, None] > i[None, :], a, 0.0) * beta[..., None]
+        w = jax.scipy.linalg.solve_triangular(
+            a + jnp.eye(chunk, dtype=a.dtype),
+            beta[..., None] * jnp.concatenate([v, k * jnp.exp(gc)], axis=-1),
+            lower=True, unit_diagonal=True)
+        last = gc[..., -1:, :]
+        per_chunk = (w[..., :dv], w[..., dv:], q * jnp.exp(gc), p,
+                     k * jnp.exp(last - gc), jnp.exp(last[..., 0, :]))
+
+        def step(s, x):
+            w_v, w_k, q_g, p_n, k_hat, d_last = x
+            u = w_v - w_k @ s
+            o = q_g @ s + p_n @ u
+            s = d_last[..., None] * s + jnp.swapaxes(k_hat, -1, -2) @ u
+            return s, o
+
+        _, o = jax.lax.scan(step, jnp.zeros((b, h, dk, dv), f32),
+                            tuple(jnp.moveaxis(x, 2, 0) for x in per_chunk))
+    # [n, b, h, C, d_v] -> [b, T, h, d_v]
+    return o.transpose(1, 0, 3, 2, 4).reshape(b, n * chunk, h, dv)[:, :t]
+
+
+def _scan_fn(attrs):
+    return functools.partial(
+        kda_chunked, chunk=int(attrs.get("chunk", 64)),
+        neg_eigval=bool(attrs.get("neg_eigval", False)))
+
+
+def _count(ctx, q, attrs):
+    # shape inference runs the lowering abstractly: uncounted
+    if not getattr(ctx, "is_abstract", False):
+        KDA_LOWERINGS_CTR.labels(
+            heads=str(q.shape[2]), head_dim=str(q.shape[3]),
+            chunk=str(int(attrs.get("chunk", 64))), impl="xla",
+            neg_eigval=str(bool(attrs.get("neg_eigval", False))).lower()
+        ).inc()
+
+
+_SCAN_IN = ("Q", "K", "V", "G", "Beta")
+
+
+def _kda_scan(ctx, ins, attrs):
+    """Q, K [b, t, h, d_k], V [b, t, h, d_v], G [b, t, h, d_k] (the log of
+    the per-channel decay), Beta [b, t, h] -> Out [b, t, h, d_v] in Q's
+    dtype: the gated delta rule in chunks of ``chunk`` positions (the
+    module's docstring); Q and K are normalised over d_k inside and Q scaled
+    by ``d_k^-0.5``.  Attributes: ``chunk`` (64; ``t`` is padded to a
+    multiple inside), ``neg_eigval`` (Beta doubled)."""
+    q = X(ins, "Q")
+    _count(ctx, q, attrs)
+    out = _scan_fn(attrs)(*(X(ins, s) for s in _SCAN_IN))
+    return {"Out": [out.astype(q.dtype)]}
+
+
+def _kda_scan_grad_maker(op, block, no_grad_set):
+    def wanted(n):
+        v = block.var(n) if block.has_var(n) else None
+        return n not in no_grad_set and not (v is not None
+                                             and v.stop_gradient)
+    inputs = {"X$" + s: op.input(s) for s in _SCAN_IN}
+    inputs["OG$Out"] = [grad_var_name(n) for n in op.output("Out")]
+    outputs = {"IG$" + s: [grad_var_name(n) if wanted(n) else ""
+                           for n in op.input(s)] for s in _SCAN_IN}
+    return [{"type": "kda_scan_grad", "inputs": inputs, "outputs": outputs,
+             "attrs": dict(op.attrs)}]
+
+
+register_op("kda_scan", _kda_scan, grad_maker=_kda_scan_grad_maker)
+
+
+@register_op("kda_scan_grad")
+def _kda_scan_grad(ctx, ins, attrs):
+    """``kda_scan``'s backward from its five inputs and Out's gradient:
+    NOTHING of the forward is saved between the two ops, the grad op runs
+    the chunked forward again and back (``jax.vjp``).  At [1, 8192, 8, 128]
+    it reads Q, K, V and dOut (16.8 MB each in bf16), G (33.6 MB float32)
+    and Beta (0.26 MB) and writes their five gradients in their dtypes; what
+    the forward again makes and the way back reads are the op's own
+    temporaries: the 128 chunk states (8 x 64 KB each: 67 MB), ``U``,
+    ``W_v`` and ``W_k`` (34 MB each), ``P`` and ``A`` (17 MB each) and the
+    [16, 16, 128] decay differences (537 MB).
+    Keeping the chunk states from the forward op would spare the forward's
+    scan here and cost 67 MB a layer between forward and backward; a kernel
+    that does so is ROADMAP.md Queue 2b item 7."""
+    prim = [X(ins, "X$" + s) for s in _SCAN_IN]
+    _count(ctx, prim[0], attrs)
+    d_out = X(ins, "OG$Out")
+    out, back = jax.vjp(_scan_fn(attrs), *prim)
+    cot = jnp.zeros_like(out) if d_out is None else d_out.astype(out.dtype)
+    return {"IG$" + s: [g.astype(p.dtype)]
+            for s, g, p in zip(_SCAN_IN, back(cot), prim)}
+
+
+@register_op("kda_gate")
+def _kda_gate(ctx, ins, attrs):
+    """KDA's two gates in float32 whatever AMP says: X [b, t, h d] (the
+    decay's low-rank projection), B [b, t, h] (beta's logits), ALog [h],
+    DtBias [h d] -> G [b, t, h, d] = ``-exp(ALog_h) softplus(X + DtBias)``,
+    the log of the per-channel decay, and Beta [b, t, h] = ``sigmoid(B)``
+    (``kda_scan`` doubles it where the model allows negative eigenvalues).
+    The backward is the registry's ``jax.vjp`` of this lowering."""
+    f32 = jnp.float32
+    x, a_log, dt_bias = X(ins, "X"), X(ins, "ALog"), X(ins, "DtBias")
+    h = a_log.shape[0]
+    gate = jax.nn.softplus(x.astype(f32) + dt_bias.astype(f32))
+    gate = gate.reshape(*x.shape[:-1], h, x.shape[-1] // h)
+    return {"G": [-jnp.exp(a_log.astype(f32))[:, None] * gate],
+            "Beta": [jax.nn.sigmoid(X(ins, "B").astype(f32))]}

@@ -216,4 +216,6 @@ def _optimization_barrier(ctx, ins, attrs):
     compiler cannot merge the recomputation with the original forward
     values (jax.checkpoint uses the same primitive for the same reason).
     No reference counterpart — remat support is TPU-native."""
+    if len(ins["X"]) > 1:      # values that may only go on together
+        return {"Out": list(jax.lax.optimization_barrier(tuple(ins["X"])))}
     return {"Out": [jax.lax.optimization_barrier(X(ins, "X"))]}

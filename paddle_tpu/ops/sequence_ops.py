@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import monitor as _monitor
 from ..framework.core import grad_var_name
 from ..framework.registry import register_op
 from .common import X, XS, ids_dtype, canon_dtype
@@ -192,6 +193,23 @@ def _causal_depthwise(g, filt):
     return sum(pads[:, j:j + t] * filt[:, j] for j in range(taps))
 
 
+SHORT_CONV_LOWERINGS_CTR = _monitor.REGISTRY.counter(
+    "paddle_tpu_short_conv_lowerings_total",
+    "short_conv and short_conv_grad lowerings by the filter's taps, whether "
+    "the convolution is gated (LFM2: C * conv(B * u), act none) or not "
+    "(KDA: silu(conv(x)), act silu) — counted while tracing, once per "
+    "compile of a program that holds the op", ("taps", "gated", "act"))
+
+
+def _count_short_conv(ctx, filt, attrs):
+    # shape inference runs the lowering abstractly: uncounted
+    if not getattr(ctx, "is_abstract", False):
+        gated = bool(attrs.get("gated", True))
+        SHORT_CONV_LOWERINGS_CTR.labels(
+            taps=str(filt.shape[1]), gated=str(gated).lower(),
+            act="none" if gated else "silu").inc()
+
+
 def _short_conv(ctx, ins, attrs):
     """The core of a gated short-convolution operator (LFM2's ``conv``
     layers): X [b, t, 3 d] is the input projection, split in three ``B | C |
@@ -201,12 +219,34 @@ def _short_conv(ctx, ins, attrs):
     No activation, no positional term; the two projections round it are the
     program's own ``mul`` ops.  Bandwidth-bound: three [t, d] streams in, one
     out.  Float32 inside (a v5e has no bf16 vector unit: the products would
-    be widened anyway), the output in X's dtype."""
+    be widened anyway), the output in X's dtype.
+
+    ``gated=False`` (KDA's convolution in front of Q, K and V): X [b, t, d]
+    is convolved as it is and SiLU follows, ``Out = silu(conv(X))``; Filter
+    [d, L] as above."""
     x, filt = X(ins, "X"), X(ins, "Filter")
     f32 = jnp.float32
+    _count_short_conv(ctx, filt, attrs)
+    if not attrs.get("gated", True):
+        out = jax.nn.silu(_causal_depthwise(x.astype(f32), filt.astype(f32)))
+        return {"Out": [out.astype(x.dtype)]}
     b_, c_, u = jnp.split(x.astype(f32), 3, axis=-1)
     out = c_ * _causal_depthwise(b_ * u, filt.astype(f32))
     return {"Out": [out.astype(x.dtype)]}
+
+
+def _depthwise_back(g, dc, w):
+    """``(dg, dFilter)`` of ``c = _causal_depthwise(g, w)`` from ``dc``: the
+    same taps run towards the past, and per tap the sum over batch and time
+    of ``dc`` times the shifted input."""
+    taps, t = w.shape[1], g.shape[1]
+    ahead = jnp.pad(dc, [(0, 0), (0, taps - 1), (0, 0)])
+    dg = sum(ahead[:, taps - 1 - j:taps - 1 - j + t] * w[:, j]
+             for j in range(taps))
+    behind = jnp.pad(g, [(0, 0), (taps - 1, 0), (0, 0)])
+    d_filt = jnp.stack([jnp.sum(behind[:, j:j + t] * dc, axis=(0, 1))
+                        for j in range(taps)], axis=1)
+    return dg, d_filt
 
 
 def _short_conv_grad_maker(op, block, no_grad_set):
@@ -236,18 +276,21 @@ def _short_conv_grad(ctx, ins, attrs):
     X and dOut, writes dX: seven [t, d] streams."""
     x, filt, d_out = X(ins, "X$X"), X(ins, "X$Filter"), X(ins, "OG$Out")
     f32 = jnp.float32
-    taps, t = filt.shape[1], x.shape[1]
-    b_, c_, u = jnp.split(x.astype(f32), 3, axis=-1)
+    _count_short_conv(ctx, filt, attrs)
     w = filt.astype(f32)
+    if not attrs.get("gated", True):
+        # the convolution again, SiLU's slope at it, then as below
+        g = x.astype(f32)
+        _, slope = jax.vjp(jax.nn.silu, _causal_depthwise(g, w))
+        dc, = slope(jnp.zeros_like(g) if d_out is None else d_out.astype(f32))
+        dg, d_filt = _depthwise_back(g, dc, w)
+        return {"IG$X": [dg.astype(x.dtype)],
+                "IG$Filter": [d_filt.astype(filt.dtype)]}
+    b_, c_, u = jnp.split(x.astype(f32), 3, axis=-1)
     g = b_ * u
     dy = jnp.zeros_like(g) if d_out is None else d_out.astype(f32)
     dc = dy * c_
-    ahead = jnp.pad(dc, [(0, 0), (0, taps - 1), (0, 0)])
-    dg = sum(ahead[:, taps - 1 - j:taps - 1 - j + t] * w[:, j]
-             for j in range(taps))
-    behind = jnp.pad(g, [(0, 0), (taps - 1, 0), (0, 0)])
-    d_filt = jnp.stack([jnp.sum(behind[:, j:j + t] * dc, axis=(0, 1))
-                        for j in range(taps)], axis=1)
+    dg, d_filt = _depthwise_back(g, dc, w)
     dx = jnp.concatenate([dg * u, dy * _causal_depthwise(g, w), dg * b_],
                          axis=-1)
     return {"IG$X": [dx.astype(x.dtype)],

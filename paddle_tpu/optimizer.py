@@ -725,10 +725,16 @@ class RecomputeOptimizer:
     def __init__(self, optimizer):
         self._optimizer = optimizer
         self._checkpoints = []
+        self._after_gradient = False
 
-    def _set_checkpoints(self, checkpoints):
+    def _set_checkpoints(self, checkpoints, after_gradient=False):
+        """``after_gradient``: each segment is emitted behind the gradient
+        of the checkpoint that ends it and fenced together with it
+        (``framework.recompute.apply_recompute``): the compiler then cannot
+        run the segments again side by side."""
         self._checkpoints = [
             c.name if hasattr(c, "name") else c for c in checkpoints]
+        self._after_gradient = bool(after_gradient)
 
     def __getattr__(self, name):
         return getattr(self._optimizer, name)
@@ -738,7 +744,9 @@ class RecomputeOptimizer:
         # backward() may already have routed through this wrapper
         if self._checkpoints and not program._attrs.get("__recompute__"):
             from .framework.recompute import apply_recompute
-            apply_recompute(program, self._checkpoints)
+            apply_recompute(program, self._checkpoints,
+                            **({"after_gradient": True}
+                               if self._after_gradient else {}))
             program._attrs["__recompute__"] = True
 
     def backward(self, loss, **kw):

@@ -54,6 +54,63 @@ def _train(recompute, steps=10):
         return out, prog
 
 
+def _train_after_gradient(summed, steps=3):
+    """``_train`` under ``after_gradient``; ``summed``: the loss also takes
+    the product of a mean of each block's inside, so that a later segment
+    reads what an earlier one makes again."""
+    with program_guard(Program(), Program()), scope_guard(Scope()):
+        x = layers.data("x", shape=[16], dtype="float32")
+        y = layers.data("y", shape=[1], dtype="float32")
+        h, ckpts, term = x, [x], None
+        x.stop_gradient = False
+        for _ in range(3):
+            inside = layers.fc(h, size=16, act="tanh")
+            if summed:
+                m = layers.mean(inside)
+                term = m if term is None else term * m
+            h = layers.fc(inside, size=16)
+            ckpts.append(h)
+        loss = layers.mean(layers.square_error_cost(layers.fc(h, size=1), y))
+        if summed:
+            loss = loss + term
+        opt = fluid.optimizer.RecomputeOptimizer(fluid.optimizer.Adam(0.01))
+        opt._set_checkpoints(ckpts, after_gradient=True)
+        opt.minimize(loss)
+        exe = Executor()
+        exe.run(fluid.default_startup_program(), seed=11)
+        rng = np.random.RandomState(0)
+        out = []
+        for _ in range(steps):
+            xv = rng.rand(8, 16).astype(np.float32)
+            lv, = exe.run(feed={"x": xv, "y": xv.sum(1, keepdims=True)},
+                          fetch_list=[loss])
+            out.append(float(lv))
+        return out, fluid.default_main_program()
+
+
+def test_after_gradient_puts_each_segment_behind_its_gradient():
+    out, prog = _train_after_gradient(False)
+    assert np.all(np.isfinite(out)) and out[-1] < out[0]
+    ops = prog.global_block().ops
+    clones = [i for i, op in enumerate(ops)
+              if op.attrs.get(RECOMPUTED_ATTR)
+              and op.type != "optimization_barrier"]
+    first_bwd = next(i for i, op in enumerate(ops)
+                     if op.attrs.get("op_role") == "backward")
+    # no longer one run of clones at the head of the backward
+    assert clones and min(clones) > first_bwd + 1
+    assert clones != list(range(clones[0], clones[0] + len(clones)))
+    fences = [op for op in ops if op.type == "optimization_barrier"
+              and len(op.inputs["X"]) == 2]
+    assert fences and all(n.endswith("@GRAD")
+                          for op in fences for n in op.inputs["X"][1:])
+
+
+def test_after_gradient_refuses_a_segment_that_reads_an_earlier_ones():
+    with pytest.raises(ValueError, match="earlier segment makes again"):
+        _train_after_gradient(True)
+
+
 def test_recompute_exact_parity():
     """Recompute must not change a single gradient: loss trajectories are
     bit-identical to the stored-activation run."""

@@ -12,7 +12,9 @@ each ``flash_attention_grad`` of the step got
 writes ``lse`` in (``paddle_tpu_flash_lowerings_total{lse}``) and the form each
 ``rope`` and ``rope_grad`` got (``paddle_tpu_rope_lowerings_total``, the
 frequency-table form told from ``theta``'s) and, for ``--cell xing4``, the
-hyper-connection ops' lowerings (``paddle_tpu_hc_lowerings_total``), with
+hyper-connection ops' lowerings (``paddle_tpu_hc_lowerings_total``) and,
+for ``--cell solar``, the chunked scan's
+(``paddle_tpu_kda_lowerings_total``), with
 and without ``--recompute``: whether the step fits beside its state, and what fitting
 costs (PERF.md section 7, row 31).  Nothing runs: no time comes from this.  The adapter has the
 recomputing step only (the traffic file's); without ``--recompute`` this
@@ -54,7 +56,7 @@ class _NoRecompute:
     def __init__(self, inner):
         self._inner = inner
 
-    def _set_checkpoints(self, checkpoints):
+    def _set_checkpoints(self, checkpoints, **kw):
         pass
 
     def __getattr__(self, name):
@@ -67,7 +69,8 @@ CELLS = {"joyai": ("joyai_llm_flash", "lm_mtp_s8192"),
          "olmoe": ("olmoe_1b_7b", "lm_s4096"),
          "smallthinker": ("smallthinker_21b_a3b", "lm_s16384"),
          "lfm2": ("lfm2_8b_a1b", "lm_s16384_r64"),
-         "xing4": ("xing4_29b_a4b", "lm_s4096_r64")}
+         "xing4": ("xing4_29b_a4b", "lm_s4096_r64"),
+         "solar": ("solar_open2_250b", "lm_s8192_r64")}
 
 
 def reads_after_update(text):
@@ -148,6 +151,9 @@ def main():
     ap.add_argument("--layers", type=int, default=0)
     ap.add_argument("--seq", type=int, default=0)
     ap.add_argument("--dump", default="")
+    ap.add_argument("--error_chars", type=int, default=700, help="how much "
+                    "of the compiler's refusal to print (its list of the "
+                    "largest allocations at the peak is some 60000 long)")
     ap.add_argument("--lowered", default="", help="write the step's lowered "
                     "StableHLO text there, locations stripped, and compile "
                     "nothing: what two commits' steps are diffed by")
@@ -164,7 +170,9 @@ def main():
                     "--recompute out, as for smallthinker and lfm2; lfm2's "
                     "adapter builds ISSUE 40's fallback where the traffic "
                     "says recompute, which --recompute sets, and so does "
-                    "xing4's, whose timed step is the plain one)")
+                    "xing4's, whose timed step is the plain one; solar's "
+                    "timed step recomputes: pass --recompute for it, and "
+                    "leave it out to see the plain step refused)")
     args = ap.parse_args()
     if args.run:
         return run_on_chip(args)
@@ -196,7 +204,7 @@ def main():
         config["num_hidden_layers"] = args.layers
     if args.seq:
         traffic["seq_len"] = args.seq
-    if args.recompute and args.cell in ("lfm2", "xing4"):
+    if args.recompute and args.cell in ("lfm2", "xing4", "solar"):
         traffic["recompute"] = True      # the adapter builds the fallback
     m = adapter.build_train(config, traffic, 7, 1, False)
     cb, step_args = dp_arith_check.caught_step(lambda: m["exe"].run(
@@ -234,6 +242,13 @@ def main():
         from paddle_tpu.ops import hc_ops
         return counted(hc_ops.HC_LOWERINGS_CTR, "op", "n", "sinkhorn_iters",
                        "impl")
+
+    def kda_lowerings():
+        """The step's kda_scan and kda_scan_grad lowerings (a recomputed
+        clone counts) by heads, width, chunk, form and beta's doubling."""
+        from paddle_tpu.ops import kda_ops
+        return counted(kda_ops.KDA_LOWERINGS_CTR, "heads", "head_dim",
+                       "chunk", "impl", "neg_eigval")
     if args.fingerprint:
         text = cb.jitted.lower(*shapes).as_text(debug_info=True)
         print(json.dumps({
@@ -261,7 +276,8 @@ def main():
         compiled = cb.jitted.lower(*shapes).compile()
     except Exception as e:                       # noqa: BLE001
         print(json.dumps({"recompute": args.recompute, "compiles": False,
-                          "error": re.sub(r"\s+", " ", str(e))[:700]}))
+                          "error": re.sub(r"\s+", " ",
+                                          str(e))[:args.error_chars]}))
         return 1
     mem = compiled.memory_analysis()
     text = compiled.as_text()
@@ -284,6 +300,7 @@ def main():
         "flash_fwd_lse": flash_fwd_lse(),
         "rope_lowerings": rope_lowerings(),
         "hc_lowerings": hc_lowerings(),
+        "kda_lowerings": kda_lowerings(),
         "parameters_m": sum(int(np.prod(p.shape))
                             for p in m["parameters"]) / 1e6}))
     return 0

@@ -25,6 +25,10 @@ adapter, reference, configuration and traffic in SmallThinker's place (4 of
 the hyper-connections' ``maps``), and one reading more: the first
 hyper-connection's ``H_res`` by the reference in float32 and in bf16, row and
 column sums against 1 (``h_res_sums``: Sinkhorn-Knopp run in bf16).
+``--cell solar`` (PR 49): the Solar-Open2 cell's (8 of 320 experts; a fourth
+kind of gradient leaf, the KDA layers' own parameters ``kda``; the
+reference's token-by-token recurrence run in bf16 is the control of the
+scan).
 """
 
 import argparse
@@ -49,7 +53,10 @@ CELLS = {"smallthinker": ("smallthinker_21b_a3b", "lm_s16384",
                   {"is_test": True}),
          "xing4": ("xing4_29b_a4b", "lm_s4096_r64", "xing_config",
                    "build_joyai_pretrain", "test_xing4_cell", "toy_xing",
-                   {})}
+                   {}),
+         "solar": ("solar_open2_250b", "lm_s8192_r64", "solar_config",
+                   "build_solar_open2_pretrain", "test_solar_open2_cell",
+                   "toy_solar", {"is_test": True})}
 
 
 def main():
