@@ -1687,17 +1687,22 @@ def kda_scan(q, k, v, g, beta, chunk=64, neg_eigval=False, name=None):
     op; ``ops/kda_ops.py`` has the equations): per head a ``d_k x d_v`` state,
     ``S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t
     v_t^T``, ``o_t = S_t^T q_t``, from zero, run in chunks of ``chunk``
-    positions (one ``lax.scan`` over the chunk states; a ``t`` that is no
-    multiple is padded inside).  ``q``, ``k`` [b, t, h, d_k], ``v`` [b, t, h,
-    d_v], ``g`` [b, t, h, d_k] the LOG of the decay, ``beta`` [b, t, h] in
+    positions (on a TPU, at heads of whole lane tiles, by the kernels of
+    ``pallas/kda.py``; else one ``lax.scan`` over the chunk states; a ``t``
+    that is no multiple is padded inside).  ``q``, ``k`` [b, t, h, d_k],
+    ``v`` [b, t, h, d_v], ``g`` [b, t, h, d_k] the LOG of the decay, ``beta`` [b, t, h] in
     (0, 1); ``neg_eigval`` doubles beta inside; q and k are divided by their
     norms over d_k inside and q scaled by ``d_k^-0.5``.  Float32 inside;
-    returns [b, t, h, d_v] in q's dtype."""
+    returns [b, t, h, d_v] in q's dtype.  States, the op's second output
+    (the float32 state before every chunk), is what ``kda_scan_grad`` reads
+    where the kernels run; it carries no gradient."""
     helper = LayerHelper("kda_scan", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
+    states = helper.create_variable_for_type_inference("float32", True)
     helper.append_op(
         "kda_scan", inputs={"Q": [q], "K": [k], "V": [v], "G": [g],
-                            "Beta": [beta]}, outputs={"Out": [out]},
+                            "Beta": [beta]},
+        outputs={"Out": [out], "States": [states]},
         attrs={"chunk": int(chunk), "neg_eigval": bool(neg_eigval)})
     return out
 

@@ -1270,7 +1270,11 @@ def kda_attention(x, cfg: SolarOpen2Config, param_prefix="kda"):
     ``<prefix>.g_up.w`` [r, h d]; ``<prefix>.A_log`` [h], ``<prefix>.dt_bias``
     [h d] (``layers.kda_gate``); ``<prefix>.o_norm.w`` [d] the norm over each
     head's output; ``<prefix>.out.w`` [h d, d_model], whose result is the
-    partial sum over the heads held here."""
+    partial sum over the heads held here.  The scan decides its lowering
+    from what it is given: at heads of whole lane tiles (the published 128)
+    on a TPU the kernel pair of ``pallas/kda.py``, whose backward starts
+    from the chunk states the forward kept; ``kda_chunked``'s plain
+    ``jax.numpy`` elsewhere (``paddle_tpu_kda_lowerings_total{impl}``)."""
     from ..initializer import UniformInitializer
     h, d, r = cfg.n_kda_head, cfg.d_head, cfg.kda_gate_rank
     dq = h * d

@@ -12,7 +12,7 @@ Two ops::
 
     G, Beta = kda_gate(X, B; ALog, DtBias)   g = -exp(A_log_h) softplus(x + dt_bias)
                                              beta = sigmoid(b)
-    Out     = kda_scan(Q, K, V, G, Beta)
+    Out, States = kda_scan(Q, K, V, G, Beta)     States: S before each chunk
 
 ``kda_scan`` runs the recurrence in its chunked (WY / UT-transform) form.  A
 chunk of ``C`` positions with the state ``S_0`` before it, ``Gam_i`` the log
@@ -27,9 +27,22 @@ decays cumulated inside the chunk up to and including ``i``::
     O     = (Q * exp(Gam)) S_0 + P U
     S_C   = Diag(exp(Gam_C)) S_0 + (K * exp(Gam_C - Gam))^T U
 
-``A``, ``P``, the triangular solve and every product that has no ``S_0`` in
-it are batched matmuls over all chunks at once; what is left is ONE
-``lax.scan`` over the ``t / C`` chunk states (three small products a step).
+Two lowerings, chosen from the input (``pallas/kda.py:fits`` beside
+``device.on_tpu()``; no attribute, flag or environment variable chooses):
+
+- ``pallas``, on a TPU where ``d_k`` and ``d_v`` are multiples of 128 and the
+  chunk one of 16: one kernel forward (``kda_fwd``) and one backward
+  (``kda_bwd``) that walk the chunks with the states in VMEM and make ``A``,
+  ``P``, the decay differences and the solve there.  The forward writes
+  ``States`` (the state before every chunk, float32), ``kda_scan_grad`` reads
+  it and runs no forward scan.
+- ``xla`` everywhere else (the CPU, narrow toy heads): :func:`kda_chunked`,
+  plain ``jax.numpy``.  ``A``, ``P``, the triangular solve and every product
+  that has no ``S_0`` in it are batched matmuls over all chunks at once
+  through HBM; what is left is ONE ``lax.scan`` over the ``t / C`` chunk
+  states (three small products a step), whose carry fills ``States``;
+  ``kda_scan_grad`` is ``jax.vjp`` of the same function, forward again and
+  back.  It is the form the tests hold the kernels to.
 
 **Decays enter as differences of cumulated log-decays and never as a
 quotient of cumulated products**: ``exp(Gam_i) / exp(Gam_j)`` is ``0 / 0`` or
@@ -43,7 +56,7 @@ product that is as small.  Everything inside is float32 whatever AMP says,
 its products at ``highest`` precision (they are small: some 12 MFLOP a chunk
 and head); Out comes back in Q's dtype.
 
-``paddle_tpu_kda_lowerings_total`` counts the lowerings."""
+``paddle_tpu_kda_lowerings_total{impl}`` counts the lowerings of each."""
 
 from __future__ import annotations
 
@@ -60,10 +73,12 @@ from .common import X
 KDA_LOWERINGS_CTR = _monitor.REGISTRY.counter(
     "paddle_tpu_kda_lowerings_total",
     "kda_scan and kda_scan_grad lowerings by the heads held, their width, "
-    "the chunk, what implements the op (xla: jnp that XLA fuses, one "
-    "lax.scan over the chunk states) and whether beta is doubled (negative "
-    "eigenvalues) — counted while tracing, once per compile of a program "
-    "that holds the op",
+    "the chunk, what implements the op (pallas: the kernels kda_fwd and "
+    "kda_bwd, the chunk's tensors in VMEM and the chunk states kept from "
+    "forward to backward; xla: jnp that XLA fuses, one lax.scan over the "
+    "chunk states, forward again inside the grad op) and whether beta is "
+    "doubled (negative eigenvalues) — counted while tracing, once per "
+    "compile of a program that holds the op",
     ("heads", "head_dim", "chunk", "impl", "neg_eigval"))
 
 #: positions whose decays are differenced exactly, [sub, sub, d_k] a block
@@ -110,13 +125,16 @@ def _within_chunks(q, k, gc, sub):
             a_off.reshape(*lead, c, c) + _block_diag(a_diag))
 
 
-def kda_chunked(q, k, v, g, beta, *, chunk=64, neg_eigval=False):
+def kda_chunked(q, k, v, g, beta, *, chunk=64, neg_eigval=False,
+                with_states=False):
     """q, k [b, t, h, d_k], v [b, t, h, d_v], g [b, t, h, d_k] (log decay),
     beta [b, t, h] -> o [b, t, h, d_v], float32 (the module's docstring).
     q and k are divided by their norms over d_k first and q scaled by
     ``d_k^-0.5``; ``neg_eigval``: beta doubled, so that ``I - beta k k^T``
     has eigenvalues in (-1, 1).  Everything that has no state in it for all
-    ``t / chunk`` chunks at once, then the scan over them."""
+    ``t / chunk`` chunks at once, then the scan over them.  ``with_states``:
+    ``(o, states [b, h, ceil(t / chunk), d_k, d_v])``, the scan's carry
+    before every chunk."""
     f32 = jnp.float32
     b, t, h, dk = q.shape
     dv = v.shape[-1]
@@ -155,32 +173,39 @@ def kda_chunked(q, k, v, g, beta, *, chunk=64, neg_eigval=False):
             w_v, w_k, q_g, p_n, k_hat, d_last = x
             u = w_v - w_k @ s
             o = q_g @ s + p_n @ u
-            s = d_last[..., None] * s + jnp.swapaxes(k_hat, -1, -2) @ u
-            return s, o
+            s1 = d_last[..., None] * s + jnp.swapaxes(k_hat, -1, -2) @ u
+            return s1, ((o, s) if with_states else o)
 
         _, o = jax.lax.scan(step, jnp.zeros((b, h, dk, dv), f32),
                             tuple(jnp.moveaxis(x, 2, 0) for x in per_chunk))
+    if with_states:
+        o, states = o
     # [n, b, h, C, d_v] -> [b, T, h, d_v]
-    return o.transpose(1, 0, 3, 2, 4).reshape(b, n * chunk, h, dv)[:, :t]
+    o = o.transpose(1, 0, 3, 2, 4).reshape(b, n * chunk, h, dv)[:, :t]
+    return (o, jnp.moveaxis(states, 0, 2)) if with_states else o
 
 
-def _scan_fn(attrs):
-    return functools.partial(
-        kda_chunked, chunk=int(attrs.get("chunk", 64)),
-        neg_eigval=bool(attrs.get("neg_eigval", False)))
+_SCAN_IN = ("Q", "K", "V", "G", "Beta")
 
 
-def _count(ctx, q, attrs):
+def _lowering(ctx, prim, attrs):
+    """``(impl, chunk and neg_eigval)`` for the op and its grad op alike,
+    decided from the five inputs and the backend, and one count of it."""
+    from ..device import on_tpu
+    from ..pallas import kda
+    q, _, v, *_ = prim
+    kw = dict(chunk=int(attrs.get("chunk", 64)),
+              neg_eigval=bool(attrs.get("neg_eigval", False)))
+    impl = "pallas" if kda.fits(q.shape[3], v.shape[3], kw["chunk"],
+                                [x.dtype for x in prim]) and on_tpu() \
+        else "xla"
     # shape inference runs the lowering abstractly: uncounted
     if not getattr(ctx, "is_abstract", False):
         KDA_LOWERINGS_CTR.labels(
             heads=str(q.shape[2]), head_dim=str(q.shape[3]),
-            chunk=str(int(attrs.get("chunk", 64))), impl="xla",
-            neg_eigval=str(bool(attrs.get("neg_eigval", False))).lower()
-        ).inc()
-
-
-_SCAN_IN = ("Q", "K", "V", "G", "Beta")
+            chunk=str(kw["chunk"]), impl=impl,
+            neg_eigval=str(kw["neg_eigval"]).lower()).inc()
+    return impl, kw
 
 
 def _kda_scan(ctx, ins, attrs):
@@ -188,12 +213,20 @@ def _kda_scan(ctx, ins, attrs):
     the per-channel decay), Beta [b, t, h] -> Out [b, t, h, d_v] in Q's
     dtype: the gated delta rule in chunks of ``chunk`` positions (the
     module's docstring); Q and K are normalised over d_k inside and Q scaled
-    by ``d_k^-0.5``.  Attributes: ``chunk`` (64; ``t`` is padded to a
-    multiple inside), ``neg_eigval`` (Beta doubled)."""
-    q = X(ins, "Q")
-    _count(ctx, q, attrs)
-    out = _scan_fn(attrs)(*(X(ins, s) for s in _SCAN_IN))
-    return {"Out": [out.astype(q.dtype)]}
+    by ``d_k^-0.5``.  States [b, h, ceil(t / chunk), d_k, d_v] float32: the
+    state before every chunk, which the kernels' backward starts each chunk
+    from; it carries no gradient, and where nothing reads it (the forward
+    role's copy of a recomputed segment, the ``xla`` path, whose grad op
+    runs the scan again) XLA drops it.  Attributes: ``chunk`` (64; ``t`` is
+    padded to a multiple inside), ``neg_eigval`` (Beta doubled)."""
+    from ..pallas import kda
+    prim = [X(ins, s) for s in _SCAN_IN]
+    impl, kw = _lowering(ctx, prim, attrs)
+    if impl == "pallas":
+        out, states = kda.kda_fwd(*prim, **kw)
+    else:
+        out, states = kda_chunked(*prim, with_states=True, **kw)
+    return {"Out": [out.astype(prim[0].dtype)], "States": [states]}
 
 
 def _kda_scan_grad_maker(op, block, no_grad_set):
@@ -201,7 +234,13 @@ def _kda_scan_grad_maker(op, block, no_grad_set):
         v = block.var(n) if block.has_var(n) else None
         return n not in no_grad_set and not (v is not None
                                              and v.stop_gradient)
+    if not op.output("States"):
+        raise ValueError(
+            "kda_scan op without a States output (a program built before "
+            "the op kept its chunk states): build it again with "
+            "layers.kda_scan to train it")
     inputs = {"X$" + s: op.input(s) for s in _SCAN_IN}
+    inputs["States"] = op.output("States")
     inputs["OG$Out"] = [grad_var_name(n) for n in op.output("Out")]
     outputs = {"IG$" + s: [grad_var_name(n) if wanted(n) else ""
                            for n in op.input(s)] for s in _SCAN_IN}
@@ -214,25 +253,32 @@ register_op("kda_scan", _kda_scan, grad_maker=_kda_scan_grad_maker)
 
 @register_op("kda_scan_grad")
 def _kda_scan_grad(ctx, ins, attrs):
-    """``kda_scan``'s backward from its five inputs and Out's gradient:
-    NOTHING of the forward is saved between the two ops, the grad op runs
-    the chunked forward again and back (``jax.vjp``).  At [1, 8192, 8, 128]
-    it reads Q, K, V and dOut (16.8 MB each in bf16), G (33.6 MB float32)
-    and Beta (0.26 MB) and writes their five gradients in their dtypes; what
-    the forward again makes and the way back reads are the op's own
-    temporaries: the 128 chunk states (8 x 64 KB each: 67 MB), ``U``,
-    ``W_v`` and ``W_k`` (34 MB each), ``P`` and ``A`` (17 MB each) and the
-    [16, 16, 128] decay differences (537 MB).
-    Keeping the chunk states from the forward op would spare the forward's
-    scan here and cost 67 MB a layer between forward and backward; a kernel
-    that does so is ROADMAP.md Queue 2b item 7."""
+    """``kda_scan``'s backward from its five inputs, Out's gradient and the
+    forward op's States.  ``pallas``: the backward kernel alone, which walks
+    the chunks from the last, starts each from ``States[n]`` and makes the
+    chunk's tensors again in VMEM; no forward scan runs here.  At [1, 8192,
+    8, 128] it reads Q, K, V and dOut (16.8 MB each in bf16), G (33.6 MB
+    float32), Beta (0.26 MB) and States (67 MB) and writes the five
+    gradients in their dtypes; it has no temporary in HBM.  ``xla``: nothing
+    of the forward is used, the grad op runs ``kda_chunked`` again and back
+    (``jax.vjp``), with ``U``, ``W_v``, ``W_k`` (34 MB each at that shape),
+    ``P``, ``A`` (17 MB each) and the [16, 16, 128] decay differences
+    (537 MB) as its own temporaries."""
+    from ..pallas import kda
     prim = [X(ins, "X$" + s) for s in _SCAN_IN]
-    _count(ctx, prim[0], attrs)
+    impl, kw = _lowering(ctx, prim, attrs)
     d_out = X(ins, "OG$Out")
-    out, back = jax.vjp(_scan_fn(attrs), *prim)
-    cot = jnp.zeros_like(out) if d_out is None else d_out.astype(out.dtype)
+    if impl == "pallas":
+        if d_out is None:
+            d_out = jnp.zeros(prim[2].shape, prim[0].dtype)
+        grads = kda.kda_bwd(*prim, X(ins, "States"), d_out, **kw)
+    else:
+        out, back = jax.vjp(functools.partial(kda_chunked, **kw), *prim)
+        cot = jnp.zeros_like(out) if d_out is None \
+            else d_out.astype(out.dtype)
+        grads = back(cot)
     return {"IG$" + s: [g.astype(p.dtype)]
-            for s, g, p in zip(_SCAN_IN, back(cot), prim)}
+            for s, g, p in zip(_SCAN_IN, grads, prim)}
 
 
 @register_op("kda_gate")

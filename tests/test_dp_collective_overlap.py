@@ -494,3 +494,59 @@ def test_tpu_compiler_takes_the_two_product_kernels(topo, monkeypatch, case,
         results = calls[0].split(" custom-call(")[0]
         assert len(re.findall(rf"bf16\[32,{t},128\]", results)) == 3
         assert len(re.findall(rf"bf16\[32,{t},64\]", results)) == 2
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_tpu_compiler_takes_the_kda_kernels_at_the_cells_shapes(
+        topo, monkeypatch, dtype):
+    """``kda_scan`` + ``kda_scan_grad`` at Solar-Open2's [1, 8192, 8, 128]
+    in chunks of 64, beta doubled, lowered as on a TPU and compiled for one
+    described chip: Mosaic takes the forward kernel and the ``jax.vjp``
+    inside the backward one (interpret mode, ``tests/test_kda_kernel.py``,
+    says nothing about that), the grad op holds no forward kernel and no
+    loop, and beyond ``States`` (67 MB) the bf16 pair has no temporary in HBM
+    but the padded ``[t, 8]`` blocks of Beta and dBeta."""
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu import device
+    from paddle_tpu.ops import kda_ops
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    ctx = types.SimpleNamespace(amp=False, is_abstract=True)
+    attrs = {"chunk": 64, "neg_eigval": True}
+    slots = ("Q", "K", "V", "G", "Beta")
+
+    def forward(*prim):
+        return kda_ops._kda_scan(ctx, {s: [x] for s, x in zip(slots, prim)},
+                                 attrs)
+
+    def step(d_out, *prim):
+        fwd = forward(*prim)
+        ins = {"X$" + s: [x] for s, x in zip(slots, prim)}
+        ins.update({"States": fwd["States"], "OG$Out": [d_out]})
+        return fwd["Out"][0], [v[0] for v in kda_ops._kda_scan_grad(
+            ctx, ins, attrs).values()]
+
+    def backward(d_out, states, *prim):
+        ins = {"X$" + s: [x] for s, x in zip(slots, prim)}
+        ins.update({"States": [states], "OG$Out": [d_out]})
+        return [v[0] for v in kda_ops._kda_scan_grad(ctx, ins,
+                                                     attrs).values()]
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one)
+    shape = (1, 8192, 8, 128)
+    x, g, beta = s(shape, dtype), s(shape, "float32"), s(shape[:3], "float32")
+    states = s((1, 8, 128, 128, 128), "float32")
+    with _no_compile_cache():
+        both = jax.jit(step).lower(x, x, x, x, g, beta).compile()
+        back = jax.jit(backward).lower(x, states, x, x, x, g, beta).compile()
+    text = both.as_text()
+    assert text.count('"kda_fwd"') == 1 and text.count('"kda_bwd"') == 1, \
+        [line for line in text.splitlines() if "custom_call_target" in line]
+    assert "kda_fwd" not in back.as_text() and " while(" not in back.as_text()
+    if dtype == "bfloat16":     # float32 streams are copied into the
+        # [t, h d] tiling first; the cell's are bf16
+        assert both.memory_analysis().temp_size_in_bytes < (67 + 32) << 20
+        assert back.memory_analysis().temp_size_in_bytes < 16 << 20

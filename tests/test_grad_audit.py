@@ -411,12 +411,13 @@ def _configs(op):
             {"X": [f(2, 3, 4), f(2, 3, 4)], "Y": [f(2, 3, 4)],
              "HPost": [f(2, 3, 2)], "HRes": [f(2, 3, 4)]},
             {"n": 2, "sinkhorn_iters": 3}),
-        # two heads of width 4 over 6 positions, chunks of 4 (padded inside)
+        # two heads of width 4 over 6 positions, chunks of 4 (padded inside);
+        # States, the chunk states kept for the grad op, carries no gradient
         "kda_scan": lambda: _Cfg(
             {"Q": [f(1, 6, 2, 4)], "K": [f(1, 6, 2, 4)], "V": [f(1, 6, 2, 4)],
              "G": [f(1, 6, 2, 4, lo=-0.9, hi=-0.1)],
              "Beta": [f(1, 6, 2, lo=0.2, hi=0.8)]},
-            {"chunk": 4, "neg_eigval": True}),
+            {"chunk": 4, "neg_eigval": True}, loss_outputs=["Out"]),
         "kda_gate": lambda: _Cfg(
             {"X": [f(1, 3, 8)], "B": [f(1, 3, 2)],
              "ALog": [f(2, lo=0.1, hi=1.0)], "DtBias": [f(8)]},
