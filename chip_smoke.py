@@ -390,7 +390,7 @@ def main():
             "dir": jax.config.jax_compilation_cache_dir,
             "hits": meter.hits, "writes": meter.writes,
             "executor_compiles": {k: int(persist.value(persist=k))
-                                  for k in ("hit", "write", "off")}},
+                                  for k in ("hit", "miss", "off")}},
         "memory_stats": {k: int(v) for k, v in
                          (jax.devices()[0].memory_stats() or {}).items()
                          if k in ("bytes_in_use", "peak_bytes_in_use",

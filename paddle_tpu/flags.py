@@ -394,11 +394,6 @@ _DEFAULTS: Dict[str, Any] = {
     # peak-watermark window: paddle_tpu_hbm_peak_bytes is the max of the
     # last N live-bytes samples
     "FLAGS_hbm_window": 16,
-    # record each compiled executable's XLA buffer-assignment plan
-    # (memory_analysis) through hbm.record_xla_plan on its first call —
-    # the AOT object is reused for execution, so recording costs no
-    # extra compile.  PADDLE_TPU_RECORD_HBM=1 is the legacy env alias.
-    "FLAGS_hbm_record_plans": False,
     # headroom-regression capture trigger (the memory twin of
     # FLAGS_profile_sample_regress_frac): when > 0 and a budget is
     # known, a profiler capture window (trigger:"hbm_regress") opens

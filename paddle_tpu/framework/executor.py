@@ -51,11 +51,11 @@ _COMPILE_HIST = _monitor.REGISTRY.histogram(
              2500.0, 5000.0, 10000.0, 30000.0, 60000.0, 120000.0))
 _COMPILE_CTR = _monitor.REGISTRY.counter(
     "paddle_tpu_compile_total",
-    "fresh compiled blocks by persistent-cache outcome: 'write' = new "
-    "disk-cache entry persisted, 'hit' = cache dir set and no write "
-    "(disk hit, or compile under the persist threshold), 'off' = "
-    "jax_compilation_cache_dir cleared behind the package's back",
-    ("persist",))
+    "backend compiles of this executor's blocks by what jax.monitoring "
+    "says the persistent cache did: 'hit' = it served the executable, "
+    "'miss' = XLA compiled, 'off' = the compile never asked the cache; "
+    "block = 'train' | 'other' as paddle_tpu_compile_phase_seconds has it",
+    ("persist", "block"))
 _COMPILE_PHASE_HIST = _monitor.REGISTRY.histogram(
     "paddle_tpu_compile_phase_seconds",
     "first call of a compiled block, split by phase (seconds): 'prepare' "
@@ -231,11 +231,21 @@ _COLL_H2G = _COLLECTIVE_CTR.labels(kind="host_to_global")
 _COLL_BARRIER = _COLLECTIVE_CTR.labels(kind="step_barrier")
 
 
-#: jax.monitoring's own compile events -> the phase each one ENDS
+#: jax.monitoring's own compile events -> the phase each one ENDS, and
+#: the persistent cache's plain events beside them (no duration: JAX
+#: fires 'compile_requests_use_cache' for every compile that asks the
+#: cache, 'cache_hits' where it served the executable, 'cache_misses'
+#: where XLA compiled and wrote the entry; a compile under the persist
+#: threshold fires the first alone).  ``_cache_outcome`` (end of this
+#: file) reads a dispatch's outcome from them: the cache directory's
+#: listing says nothing where an entry leaves as one arrives (PR 47)
 _JAX_PHASE_EVENTS = {
     "/jax/core/compile/jaxpr_trace_duration": "trace",
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
     "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/compile_requests_use_cache": "cache_request",
+    "/jax/compilation_cache/cache_hits": "cache_hit",
+    "/jax/compilation_cache/cache_misses": "cache_miss",
 }
 #: per-thread sink of (phase, t_start, t_end) on the perf_counter clock,
 #: armed around the jitted call of every dispatch: JAX fires its events
@@ -245,7 +255,7 @@ _phase_listener_lock = threading.Lock()
 _phase_listener_on = False
 
 
-def _on_jax_duration(event, secs, **_):
+def _on_jax_duration(event, secs=0.0, **_):
     phase = _JAX_PHASE_EVENTS.get(event)
     sink = getattr(_phase_sink, "events", None)
     if phase is not None and sink is not None:
@@ -262,6 +272,7 @@ def _install_phase_listener() -> None:
             import jax.monitoring
             jax.monitoring.register_event_duration_secs_listener(
                 _on_jax_duration)
+            jax.monitoring.register_event_listener(_on_jax_duration)
             _phase_listener_on = True
 
 
@@ -287,17 +298,6 @@ def _compile_phase_bounds(events, t_call, t_end):
         t = cut
     out.append(("first_run", t, t_end))
     return out
-
-
-def _compile_cache_entries(cache_dir: str) -> int:
-    """File count under the persistent XLA compile cache dir (hit/miss
-    heuristic for compile telemetry; '' → cache off → -1)."""
-    if not cache_dir:
-        return -1
-    try:
-        return sum(len(files) for _, _, files in os.walk(cache_dir))
-    except OSError:
-        return -1
 
 
 #: live executors, for profiler-level aggregation (weak: an executor's
@@ -1270,38 +1270,38 @@ class _CompiledBlock:
                 else (None, list(in_shardings[2]), None))
         self.jitted = _jit_step(step, mesh, **kwargs)
 
-    _hbm_recorded = False
-    _compiled_aot = None
+    def record_plan(self, args, block: str, compiled_at: float) -> dict:
+        """Record the memory plan of the executable that the compiling
+        call ``self(*args)`` has just built and run (ref allocator_facade
+        stats): its ``memory_analysis()`` IS the on-chip buffer assignment
+        — arguments + temporaries + outputs - aliased is what the runtime
+        holds for a step, per executable, which device.memory_stats()
+        cannot split and no live array shows.  Always, where the block
+        compiles, and at no compile's cost: lowering and compiling again
+        with that call's own arguments is served by JAX's caches
+        (hbm.record_compiled_plan, which never raises).  The plan's
+        temporaries join the block's ``hbm_info``, the record every
+        accountant sample of this block carries.
+
+        One kind of block is read later: a jit that carries compiler
+        options (``_jit_step``: data parallel on a TPU) gets its
+        executable's wrapper built anew by every ``.compile()`` (no XLA
+        compile; 3.3 s of PJRT queries for BERT-base on four chips), so
+        its plan waits for whoever asks (``memory.hbm_plans()``), the
+        newest compile of the block standing for the older."""
+        info = getattr(self, "hbm_info", None)
+        if not isinstance(info, dict):
+            info = self.hbm_info = {}
+        return _hbm().record_compiled_plan(
+            self.jitted, args,
+            (self.feed_names, self.persist_ro, self.persist_rw), info,
+            ",".join(self.fetch_names) or "<block>", block, compiled_at,
+            defer=id(self.jitted) in _OPTION_JITS)
 
     def __call__(self, feeds, ro, rw, seed):
-        if not self._hbm_recorded and _hbm().plans_enabled():
-            # capture the executable's HBM allocation plan (ref
-            # allocator_facade stats): the AOT-compiled executable's
-            # memory_analysis IS the on-chip buffer assignment — arguments
-            # + temps + outputs is what the runtime allocates for a step,
-            # per executable, which device.memory_stats() cannot split.
-            # The AOT object is then used for execution, so recording costs
-            # no extra compile — and a failed compile here IS the step's
-            # compile error, so it raises.  Routed through
-            # hbm.record_xla_plan (the one ingestion point for measured
-            # bytes; FLAGS_hbm_record_plans with PADDLE_TPU_RECORD_HBM kept
-            # as the legacy env alias).
-            self._hbm_recorded = True
-            compiled = self.jitted.lower(feeds, ro, rw, seed).compile()
-            _hbm().record_xla_plan(
-                ",".join(self.fetch_names) or "<block>",
-                compiled.memory_analysis())
-            self._compiled_aot = compiled
-        if self._compiled_aot is not None:
-            if self._donating:
-                # rw buffers are donated: a mid-execution failure leaves
-                # them deleted, so a fallback retry would mask the real
-                # error with 'Array has been deleted' — just run it
-                return self._compiled_aot(feeds, ro, rw, seed)
-            try:
-                return self._compiled_aot(feeds, ro, rw, seed)
-            except Exception:
-                self._compiled_aot = None
+        # one way to run a block: the jitted step.  ROADMAP D13: the number
+        # of the line below is in every step's compile-cache key, so an
+        # edit above it in this file gives back the lines it takes
         return self.jitted(feeds, ro, rw, seed)
 
 
@@ -1745,13 +1745,16 @@ class Executor:
         # compile telemetry: a freshly-lowered block pays trace + lower +
         # XLA compile inside its first call (the jit call blocks until the
         # executable exists; only the execution is async).  Record the
-        # wall time and whether the persistent disk cache absorbed it —
-        # heuristically, by whether the cache dir gained an entry ('hit'
-        # also covers compiles under jax's persist threshold).  JAX's own
-        # compile events of this dispatch land in ``jax_events`` (a first
+        # wall time and whether the persistent disk cache served it, as
+        # JAX's own cache events say (``_cache_outcome``).  JAX's compile
+        # and cache events of this dispatch land in ``jax_events`` (a first
         # call fires them, the cost cross-check's AOT compile below
         # included; a steady-state step fires none): they cut the first
-        # call into phases and give a later re-trace away.
+        # call into phases and give a later re-trace away.  Once the call
+        # has returned, ``_record_block_plan`` (end of this file) records
+        # the new executable's memory plan, for every block and with no
+        # flag: its second lowering is served by JAX's caches and fires no
+        # lower or backend event (the sink is off by then in any case).
         _phase_sink.events = jax_events = []
         pending_compile = getattr(cb, "pending_compile", False)
         if pending_compile:
@@ -1764,20 +1767,17 @@ class Executor:
                 cb.pending_compile = False
         if pending_compile:
             from ..flags import get_flags as _gf
-            # the directory JAX actually persists to (device.
-            # place_compile_cache: env var, else flag, else the checkout)
-            cache_dir = jax.config.jax_compilation_cache_dir or ""
-            n_before = _compile_cache_entries(cache_dir)
             tc0 = time.perf_counter()
             if _gf("FLAGS_cost_crosscheck")["FLAGS_cost_crosscheck"]:
                 # AOT-compile so XLA's own cost_analysis() is available
-                # to cross-check the analytic model; the compiled object
-                # is then USED for execution (same pattern as the
-                # RECORD_HBM path), so the check costs no extra compile.
-                # A failed compile is the step's compile error: it raises.
+                # to cross-check the analytic model.  The check costs no
+                # extra compile: the jitted call below finds this
+                # lowering and its executable in JAX's caches (the same
+                # arguments), as the plan's hook does the other way
+                # round.  A failed compile is the step's compile error:
+                # it raises.
                 compiled = cb.jitted.lower(
                     feeds, ro_vals, rw_vals, seed_arr).compile()
-                cb._compiled_aot = compiled
                 try:
                     from ..analysis.cost import (xla_cost_breakdown,
                                                  xla_cost_totals)
@@ -1874,10 +1874,9 @@ class Executor:
             raise
         tdisp = time.perf_counter()
         if pending_compile:
-            outcome = ("off" if not cache_dir else
-                       "write" if _compile_cache_entries(cache_dir)
-                       > n_before else "hit")
-            _COMPILE_CTR.inc(1, persist=outcome)
+            outcome = _cache_outcome(jax_events)
+            kind = _block_kind(program)
+            _COMPILE_CTR.inc(1, persist=outcome, block=kind)
             _COMPILE_HIST.observe((tdisp - tc0) * 1e3)
             if _monitor.TRACER.enabled:
                 _monitor.TRACER.add_complete(
@@ -1887,18 +1886,26 @@ class Executor:
             # the same first call by phase: spans for a reader of the ring,
             # the histogram for one who comes after the ring was cleared
             self._note_compile_phases(
-                program, [("prepare", t0, tc0)]
+                kind, [("prepare", t0, tc0)]
                 + _compile_phase_bounds(jax_events, tc0, tdisp), outcome)
+            _record_block_plan(cb, program, feeds, ro_vals, rw_vals,
+                               seed_arr, kind, tdisp)
         elif jax_events:
             # JAX traced and compiled again inside a block this executor
             # had compiled already (an argument's layout or sharding
             # changed under the same shapes): a re-trace nobody asked for.
             # Not a ``traces`` bump: that counts the executor's own
-            # lowerings; the histogram's count of 'retrace' counts these
+            # lowerings; the histogram's count of 'retrace' counts these.
+            # It is a backend compile like the first, and from here on the
+            # block runs THIS executable: counted, and its plan recorded
+            outcome = _cache_outcome(jax_events)
+            kind = _block_kind(program)
+            _COMPILE_CTR.inc(1, persist=outcome, block=kind)
             self._note_compile_phases(
-                program,
-                [("retrace", min(a for _, a, _ in jax_events), tdisp)],
-                None)
+                kind, [("retrace", min(a for _, a, _ in jax_events), tdisp)],
+                outcome)
+            _record_block_plan(cb, program, feeds, ro_vals, rw_vals,
+                               seed_arr, kind, tdisp)
         if cb.collective_nranks or getattr(cb, "partitioned", False):
             if cb.collective_nranks:
                 _COLL_STEP.inc()
@@ -2114,21 +2121,19 @@ class Executor:
         return [FetchHandle(f, stats) for f in fetches]
 
     @staticmethod
-    def _note_compile_phases(program, phases, outcome) -> None:
-        """Record ``(phase, t0, t1)`` intervals of a compiling dispatch as
-        ``compile.<phase>`` spans and in ``paddle_tpu_compile_phase_
-        seconds``; ``compile.backend`` carries the persistent cache's
-        ``outcome`` like ``xla.compile`` does."""
-        kind = "train" if any(
-            op.attrs.get("op_role") in ("backward", "optimize")
-            for op in program.global_block().ops) else "other"
+    def _note_compile_phases(kind, phases, outcome) -> None:
+        """Record ``(phase, t0, t1)`` intervals of a compiling dispatch of
+        a block of ``kind`` (``_block_kind``) as ``compile.<phase>`` spans
+        and in ``paddle_tpu_compile_phase_seconds``; ``compile.backend``
+        and ``compile.retrace`` carry the persistent cache's ``outcome``
+        like ``xla.compile`` does."""
         for phase, a, b in phases:
             _COMPILE_PHASE_HIST.observe(b - a, phase=phase, block=kind)
             if _monitor.TRACER.enabled:
                 _monitor.TRACER.add_complete(
                     "compile." + phase, "compile", a, b,
-                    {"persist_cache": outcome} if phase == "backend"
-                    else None)
+                    {"persist_cache": outcome}
+                    if phase in ("backend", "retrace") else None)
 
     def _maybe_step_barrier(self, cb, program):
         """Automatic per-step gang barrier for collective shard_map
@@ -2681,7 +2686,19 @@ def _jit_step(fn, mesh, **kwargs):
     DP_OVERLAP_CTR.inc(asked=str(int(options is not None)), reason=reason)
     if options is not None:
         kwargs["compiler_options"] = options
-    return jax.jit(fn, **kwargs)
+    jitted = jax.jit(fn, **kwargs)
+    if options is not None:
+        _OPTION_JITS.add(id(jitted))
+    return jitted
+
+
+#: ``id`` of every jit that ``_jit_step`` gave compiler options: JAX never
+#: keeps an executable compiled under options, so each ``.compile()`` of
+#: such a lowering builds its wrapper again (``_CompiledBlock.record_plan``
+#: defers these).  Ids are not taken back: a block keeps its jit alive, and
+#: a dead jit's id on a new one only defers a plan that could have been
+#: read at once
+_OPTION_JITS: set = set()
 
 
 def _name_scope_of(op: Operator) -> str:
@@ -2704,3 +2721,41 @@ def _scope_role(op: Operator) -> str:
     if op.attrs.get(RECOMPUTED_ATTR):
         return "rc"
     return _SCOPE_ROLES.get(op.attrs.get("op_role"), "fwd")
+
+
+# -- what a compiling dispatch records once its call has returned (PR 51; at
+# the end of the file for ROADMAP D13: nothing above moves a line) ----------
+
+def _block_kind(program) -> str:
+    """'train' where the block holds backward or optimizer ops, else
+    'other': the ``block`` label of the compile and plan families."""
+    return "train" if any(
+        op.attrs.get("op_role") in ("backward", "optimize")
+        for op in program.global_block().ops) else "other"
+
+
+def _cache_outcome(events) -> str:
+    """What the persistent compile cache did for the compiles whose
+    jax.monitoring events a dispatch's sink caught: 'off' where none asked
+    it, 'hit' where it served every one that did, else 'miss' (XLA
+    compiled: an entry written, or a compile under the persist threshold,
+    whatever the cache directory holds)."""
+    asked, hit, miss = (sum(1 for p, _, _ in events if p == k) for k in
+                        ("cache_request", "cache_hit", "cache_miss"))
+    if miss or asked > hit:
+        return "miss"
+    return "hit" if hit else "off"
+
+
+def _record_block_plan(cb, program, feeds, ro_vals, rw_vals, seed_arr,
+                       kind, compiled_at) -> None:
+    """Hand the block that the dispatch just compiled to the HBM plane,
+    with the call's own arguments (donated ones are deleted by now: only
+    avals and shardings are read).  Can never fail a step."""
+    try:
+        if getattr(cb, "hbm_info", _UNSET) is _UNSET:
+            cb.hbm_info = _resolve_hbm_info(cb, program, feeds)
+        cb.record_plan((feeds, ro_vals, rw_vals, seed_arr), kind,
+                       compiled_at)
+    except Exception:
+        pass

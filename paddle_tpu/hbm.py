@@ -31,10 +31,19 @@ closes that gap with three pieces:
   window (``trigger:"oom"``).
 
 - **One reader** — :func:`measure_live_bytes` is the canonical measured-
-  bytes source: the executor's ``PADDLE_TPU_RECORD_HBM`` one-shot (env
-  var kept as an alias of ``FLAGS_hbm_record_plans``) routes through
-  :func:`record_xla_plan`, and ``tools/hbm_smoke.py`` and the HBM tests
-  read this module instead of a private measurement.
+  bytes source; ``tools/hbm_smoke.py`` and the HBM tests read this module
+  instead of a private measurement.
+
+- **The compiled step's own plan** — live arrays cannot see a compiled
+  step's temporaries, so the executor hands every block it compiles to
+  :func:`record_compiled_plan` (always, where it compiles, at no
+  compile's cost: JAX's caches serve the second lowering): the
+  executable's ``memory_analysis()`` goes through :func:`record_xla_plan`
+  into ``memory.hbm_plans()`` and the gauges
+  ``paddle_tpu_step_hbm_plan_bytes{block, tag, part}`` /
+  ``paddle_tpu_step_hbm_argument_bytes{block, tag, cls}``, and the
+  accountant counts the plan's temporaries against the budget
+  (``cls="step_temporaries"``; headroom = budget - live - temporaries).
 
 Fleet-wide, the heartbeat digest carries ``hbm``/``hdrm`` keys folded
 into ``paddle_tpu_gang_rank_hbm_*`` gauges, gangtop renders HBM/HDRM%
@@ -53,12 +62,15 @@ import time
 import weakref
 from typing import Any, Dict, List, Optional
 
+import numpy as np
+
 from . import memory as _memory
 from . import monitor as _monitor
 
 __all__ = [
     "HBMAccountant", "ACCOUNTANT", "measure_live_bytes", "budget_bytes",
-    "oom_forensics", "record_xla_plan", "plans_enabled",
+    "oom_forensics", "record_xla_plan", "record_compiled_plan",
+    "compiled_plan", "argument_classes", "PLAN_PARTS", "ARGUMENT_CLASSES",
     "set_ckpt_capture_bytes", "register_kv_pool", "register_census",
     "serving_census", "OOM_RISK_HEADROOM_FRAC",
 ]
@@ -84,9 +96,11 @@ HBM_BUDGET_GAUGE = _monitor.REGISTRY.gauge(
     "(0 = no budget known; headroom is then unpublished)")
 HBM_HEADROOM_GAUGE = _monitor.REGISTRY.gauge(
     "paddle_tpu_hbm_headroom_bytes",
-    "budget - live at the most recent sample (published only while a "
-    "budget is known) — the measured admission signal the GSPMD "
-    "sharding chooser and the serving width admission consume")
+    "budget - live - step_temporaries at the most recent sample "
+    "(published only while a budget is known): what is free once the "
+    "live arrays and the dispatched block's compiled temporaries region "
+    "are counted — the measured admission signal the GSPMD sharding "
+    "chooser and the serving width admission consume")
 HBM_DRIFT_GAUGE = _monitor.REGISTRY.gauge(
     "paddle_tpu_hbm_plan_drift",
     "measured live bytes over the static plan's steady_bytes for the "
@@ -97,10 +111,15 @@ HBM_CLASS_GAUGE = _monitor.REGISTRY.gauge(
     "paddle_tpu_hbm_class_bytes",
     "live-byte attribution by class at the most recent sample: "
     "params / opt_state (non-parameter persistables: moments, BN "
-    "stats) / activations (unattributed remainder: temps, fetch "
-    "buffers, XLA scratch) / lazy_fetch (in-flight throttle probes) / "
-    "ckpt_capture (checkpoint snapshot copies in flight) / kv_pages "
-    "(serving paged-KV pools)", ("cls",))
+    "stats) / activations (live arrays outside the named classes: "
+    "resident batches, fetch buffers, other programs' state — NOT a "
+    "compiled step's temporaries, which are in no live array) / "
+    "step_temporaries (the temporaries region of the dispatched "
+    "block's compiled plan: what the forward keeps for the backward, "
+    "gradients, casts, scratch; 0 until the block's plan is recorded) "
+    "/ lazy_fetch (in-flight throttle probes) / ckpt_capture "
+    "(checkpoint snapshot copies in flight) / kv_pages (serving "
+    "paged-KV pools)", ("cls",))
 OOM_CTR = _monitor.REGISTRY.counter(
     "paddle_tpu_oom_total",
     "RESOURCE_EXHAUSTED events that went through OOM forensics, by "
@@ -120,8 +139,8 @@ _SAMPLE_ERROR = HBM_SAMPLES_CTR.labels(outcome="error")
 #: must not import paddle_tpu)
 OOM_RISK_HEADROOM_FRAC = 0.10
 
-_CLASSES = ("params", "opt_state", "activations", "lazy_fetch",
-            "ckpt_capture", "kv_pages")
+_CLASSES = ("params", "opt_state", "activations", "step_temporaries",
+            "lazy_fetch", "ckpt_capture", "kv_pages")
 _CLASS_CELLS = {c: HBM_CLASS_GAUGE.labels(cls=c) for c in _CLASSES}
 
 
@@ -298,7 +317,9 @@ class HBMAccountant:
         """Queue one step boundary for off-thread sampling.  ``info`` is
         the executor's per-compiled-block resolution ({params,
         opt_state} name sets + the static plan's steady/peak bytes at
-        the real batch), or None for foreign/unplanned programs."""
+        the real batch + ``step_temporaries``, the temporaries of the
+        block's compiled plan once :func:`record_compiled_plan` has it),
+        or None for foreign/unplanned programs."""
         with self._cv:
             self._ensure_thread_locked()
             if len(self._pending) >= self.MAX_PENDING:
@@ -357,9 +378,13 @@ class HBMAccountant:
         kv = _kv_pool_bytes()
         ckpt = int(_ckpt_capture_bytes)
         acts = max(live - params - opt - kv - ckpt - inflight_bytes, 0)
+        # in no live array: the region the dispatched block's executable
+        # reserves while it runs, from its compiled plan
+        temps = int((info or {}).get("step_temporaries", 0) or 0)
         _CLASS_CELLS["params"].set(float(params))
         _CLASS_CELLS["opt_state"].set(float(opt))
         _CLASS_CELLS["activations"].set(float(acts))
+        _CLASS_CELLS["step_temporaries"].set(float(temps))
         _CLASS_CELLS["lazy_fetch"].set(float(inflight_bytes))
         _CLASS_CELLS["kv_pages"].set(float(kv))
         # ckpt_capture is set by its reporter (set_ckpt_capture_bytes)
@@ -367,7 +392,7 @@ class HBMAccountant:
         budget = budget_bytes()
         headroom = None
         if budget > 0:
-            headroom = float(budget - live)
+            headroom = float(budget - live - temps)
             HBM_BUDGET_GAUGE.set(float(budget))
             HBM_HEADROOM_GAUGE.set(headroom)
         else:
@@ -387,7 +412,9 @@ class HBMAccountant:
             peak = max(self._live_win)
             trigger = self._observe_headroom_locked(headroom)
         HBM_PEAK_GAUGE.set(peak)
-        self.last_sample = (int(live), headroom)
+        # the digest's hbm key is what the budget is spent on, so that
+        # hbm + hdrm stays the budget (gangtop's HDRM% divides by it)
+        self.last_sample = (int(live + temps), headroom)
         self.last_publish_wall = time.time()
         tracer = _monitor.TRACER
         if tracer.enabled:
@@ -395,6 +422,7 @@ class HBMAccountant:
             args = {"step": int(step_id), "live": int(live),
                     "peak": int(peak), "params": int(params),
                     "opt_state": int(opt), "activations": int(acts),
+                    "step_temporaries": temps,
                     "lazy_fetch": int(inflight_bytes),
                     "ckpt_capture": ckpt, "kv_pages": int(kv)}
             if headroom is not None:
@@ -443,16 +471,23 @@ def _scope_nbytes(scope, name: str) -> int:
         return 0
 
 
-def per_device_nbytes(v) -> int:
+def per_device_nbytes(v, sharding=None) -> int:
     """Bytes ONE device holds for an array: sharded jax Arrays (GSPMD
     params under a rule table, ZeRO-1 optimizer state) cost their shard,
     not the global shape — ``sharding.shard_shape`` is the same
     arithmetic XLA's buffer assignment uses, so a dp-sharded Adam moment
     reports 1/dp of its global bytes.  Replicated (or host/numpy) values
-    keep their full nbytes."""
-    nbytes = int(getattr(v, "nbytes", 0) or 0)
-    sharding = getattr(v, "sharding", None)
+    keep their full nbytes.  ``sharding`` stands in for the value's own
+    (an executable's input sharding for a host batch it shards on the way
+    in); a ``ShapeDtypeStruct`` serves as well as an array."""
     shape = getattr(v, "shape", None)
+    nbytes = getattr(v, "nbytes", None)
+    if nbytes is None and shape is not None and hasattr(v, "dtype"):
+        nbytes = int(np.prod(shape, dtype=np.int64)) * \
+            np.dtype(v.dtype).itemsize
+    nbytes = int(nbytes or 0)
+    if sharding is None:
+        sharding = getattr(v, "sharding", None)
     if sharding is None or not shape or not nbytes:
         return nbytes
     try:
@@ -622,43 +657,165 @@ def oom_forensics(error: BaseException, scope=None, program=None,
 
 
 # ---------------------------------------------------------------------------
-# XLA executable plans (the RECORD_HBM one-shot, rerouted here)
+# compiled plans: every block's memory_analysis(), where it compiles
 # ---------------------------------------------------------------------------
 
-XLA_PLAN_GAUGE = _monitor.REGISTRY.gauge(
-    "paddle_tpu_hbm_xla_plan_peak_bytes",
-    "XLA buffer-assignment peak (arguments + temps + outputs - aliased) "
-    "of the most recently recorded compiled step "
-    "(FLAGS_hbm_record_plans / PADDLE_TPU_RECORD_HBM)")
+#: gauge label ``part`` -> key of a ``memory.hbm_plans()`` entry
+PLAN_PARTS = {"arguments": "argument_bytes", "outputs": "output_bytes",
+              "aliased": "alias_bytes", "temporaries": "temp_bytes",
+              "code": "generated_code_bytes"}
+ARGUMENT_CLASSES = ("params", "opt_state", "feeds", "other")
+
+STEP_PLAN_GAUGE = _monitor.REGISTRY.gauge(
+    "paddle_tpu_step_hbm_plan_bytes",
+    "XLA buffer assignment (memory_analysis(), bytes on one device) of "
+    "each compiled block, set when it compiles: part = arguments (the "
+    "state the step takes, and one batch) / outputs / aliased (outputs "
+    "that donation put in their argument's buffer) / temporaries (what "
+    "the forward keeps for the backward, gradients, casts, scratch: "
+    "those live at the executable's peak where the backend reports "
+    "one, memory.plan_parts) / code.  block = 'train' | 'other' as "
+    "paddle_tpu_compile_phase_seconds decides it; tag = the block's "
+    "fetch list, cut to 64 characters, '#n' on a later compile under "
+    "the same list (a re-trace compiles its block again)",
+    ("block", "tag", "part"))
+STEP_PLAN_AT_GAUGE = _monitor.REGISTRY.gauge(
+    "paddle_tpu_step_hbm_plan_compiled_at_seconds",
+    "time.perf_counter() when the compiling call of that block returned "
+    "(which plan ran a window: the newest before it opened)",
+    ("block", "tag"))
+STEP_ARGUMENT_GAUGE = _monitor.REGISTRY.gauge(
+    "paddle_tpu_step_hbm_argument_bytes",
+    "the compiled block's arguments by the program's own classes, from "
+    "the compiling call's avals under the executable's input shardings "
+    "(bytes on one device): params / opt_state (non-parameter "
+    "persistables) / feeds / other (the seed, names the block does not "
+    "declare); they add up to part='arguments' within the compiler's "
+    "padding", ("block", "tag", "cls"))
+PLAN_HOOK_CTR = _monitor.REGISTRY.counter(
+    "paddle_tpu_step_hbm_plan_records_total",
+    "compiled-block plans by outcome ('ok' recorded, 'error' the hook "
+    "failed: the step ran, its plan is missing)", ("outcome",))
 
 
-def plans_enabled() -> bool:
-    """True when compiled-executable HBM plans should be recorded:
-    ``FLAGS_hbm_record_plans`` or the legacy ``PADDLE_TPU_RECORD_HBM``
-    env var (kept as an alias — tools/record_hbm.py sets it)."""
-    if os.environ.get("PADDLE_TPU_RECORD_HBM"):
-        return True
-    from .flags import get_flags
-    return bool(get_flags("FLAGS_hbm_record_plans")
-                ["FLAGS_hbm_record_plans"])
+def argument_classes(args, names, info=None, input_shardings=None) -> dict:
+    """The bytes of a block's call arguments ``(feeds, ro, rw, seed)`` by
+    class.  ``names`` is ``(feed_names, persist_ro, persist_rw)``;
+    ``info`` the executor's ``{params, opt_state}`` name sets (None: every
+    persistable is ``other``); ``input_shardings`` the executable's, laid
+    out like ``args``, so that a feed the step shards on its way in counts
+    its shard.  Only avals and shardings are read: donated, deleted
+    arrays serve."""
+    feeds, ro, rw, seed = args
+    sh = input_shardings if input_shardings is not None \
+        else ([None] * len(feeds), [None] * len(ro), [None] * len(rw), None)
+    params = (info or {}).get("params", ())
+    opt = (info or {}).get("opt_state", ())
+    out = dict.fromkeys(ARGUMENT_CLASSES, 0)
+    for v, s in zip(feeds, sh[0]):
+        out["feeds"] += per_device_nbytes(v, s)
+    for group, shs, ns in ((ro, sh[1], names[1]), (rw, sh[2], names[2])):
+        for v, s, n in zip(group, shs, ns):
+            cls = ("params" if n in params else
+                   "opt_state" if n in opt else "other")
+            out[cls] += per_device_nbytes(v, s)
+    out["other"] += per_device_nbytes(seed, sh[3])
+    return out
 
 
-def record_xla_plan(tag: str, ma) -> dict:
+def compiled_plan(compiled, args, names, info=None):
+    """``(memory_analysis, argument classes)`` of one compiled block: the
+    one function the executor's hook, ``tools/joyai_step_aot.py`` and
+    ``tools/record_hbm.py`` read an executable through."""
+    try:
+        shardings = compiled.input_shardings[0]
+    except Exception:
+        shardings = None
+    return compiled.memory_analysis(), argument_classes(
+        args, names, info, shardings)
+
+
+def record_compiled_plan(jitted, args, names, info, tag: str, block: str,
+                         compiled_at: float, defer: bool = False) -> dict:
+    """The executor's hook, called after a compiling call has returned:
+    lower and compile the block again with that call's OWN arguments —
+    JAX's caches serve both (no lowering, no backend compile; a
+    ShapeDtypeStruct in an array's place would miss) — and record the
+    executable's plan; its temporaries go into ``info``, the record the
+    accountant's samples of this block carry.  Times itself
+    (``hook_ms``).  Never raises: a failure costs the plan, not the step.
+
+    ``defer``: nothing now; the same call is left with
+    ``memory.defer_hbm_plan`` for the first reader of
+    ``memory.hbm_plans()`` (for a jit whose ``.compile()`` is never
+    cached: ``_CompiledBlock.record_plan``).  Until then the block's
+    samples carry no temporaries, and the deferred call keeps that
+    compile's arguments referenced."""
+    if defer:
+        # the seed is the one argument nothing else keeps alive (the state
+        # is the scope's or donated and gone, the batch its reader's): as
+        # a host scalar it has the same aval and pins no device buffer
+        args = (*args[:3], np.asarray(args[3]))
+        _memory.defer_hbm_plan(id(jitted), lambda: record_compiled_plan(
+            jitted, args, names, info, tag, block, compiled_at))
+        return {}
+    t0 = time.perf_counter()
+    try:
+        ma, classes = compiled_plan(jitted.lower(*args).compile(), args,
+                                    names, info)
+        entry = record_xla_plan(
+            tag, ma, block=block, compiled_at=float(compiled_at),
+            classes=classes, hook_ms=(time.perf_counter() - t0) * 1e3)
+        if isinstance(info, dict):
+            info["step_temporaries"] = entry["temp_bytes"]
+        PLAN_HOOK_CTR.inc(1, outcome="ok")
+        return entry
+    except Exception:
+        PLAN_HOOK_CTR.inc(1, outcome="error")
+        return {}
+
+
+def _drop_plan_series(tag: str, entry: dict) -> None:
+    block = entry.get("block", "other")
+    for part in PLAN_PARTS:
+        STEP_PLAN_GAUGE.fold({"block": block, "tag": tag, "part": part},
+                             None)
+    for cls in ARGUMENT_CLASSES:
+        STEP_ARGUMENT_GAUGE.fold({"block": block, "tag": tag, "cls": cls},
+                                 None)
+    STEP_PLAN_AT_GAUGE.fold({"block": block, "tag": tag}, None)
+
+
+def record_xla_plan(tag: str, ma, block: str = "other",
+                    compiled_at: Optional[float] = None,
+                    classes: Optional[dict] = None,
+                    hook_ms: Optional[float] = None) -> dict:
     """Record one compiled executable's ``memory_analysis()`` — the
     on-chip buffer assignment — into the shared plan store
     (``memory.hbm_plans()``, which the residency summary and
-    tools/record_hbm.py read) and publish its peak as a gauge.  The ONE
-    ingestion point for XLA-side measured bytes."""
+    tools/record_hbm.py read) and publish it as gauges.  The ONE
+    ingestion point for XLA-side measured bytes.  Returns the entry,
+    with the ``tag`` it was stored under."""
+    facts = {"block": block,
+             "compiled_at": time.perf_counter() if compiled_at is None
+             else compiled_at}
+    if classes is not None:
+        facts["argument_classes"] = dict(classes)
+    if hook_ms is not None:
+        facts["hook_ms"] = float(hook_ms)
     # record_hbm_plan suffixes colliding tags (startup programs all tag
     # '<block>') and returns the FINAL tag — reading back by the passed
     # tag would hand a collision the previous executable's plan
-    tag = _memory.record_hbm_plan(tag, ma)
-    entry = _memory.hbm_plans().get(tag)
-    if entry:
-        XLA_PLAN_GAUGE.set(float(entry["peak_bytes"]))
+    tag, entry, evicted = _memory.record_hbm_plan(tag, ma, **facts)
+    for old_tag, old in evicted:
+        _drop_plan_series(old_tag, old)
+    entry = dict(entry, tag=tag)
+    for part, key in PLAN_PARTS.items():
+        STEP_PLAN_GAUGE.set(float(entry[key]), block=block, tag=tag,
+                            part=part)
+    STEP_PLAN_AT_GAUGE.set(entry["compiled_at"], block=block, tag=tag)
+    for cls, n in (classes or {}).items():
+        STEP_ARGUMENT_GAUGE.set(float(n), block=block, tag=tag, cls=cls)
     if _monitor.TRACER.enabled:
-        _monitor.TRACER.instant(
-            "hbm.xla_plan", "memory",
-            {"tag": tag[:64], **({k: entry[k] for k in entry}
-                                 if entry else {})})
-    return entry or {}
+        _monitor.TRACER.instant("hbm.xla_plan", "memory", dict(entry))
+    return entry

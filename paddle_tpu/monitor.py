@@ -484,13 +484,15 @@ GANG_RANK_COMM_BW = REGISTRY.gauge(
     "column gangtop renders as BW%", ("rank",))
 GANG_RANK_HBM = REGISTRY.gauge(
     "paddle_tpu_gang_rank_hbm_bytes",
-    "per-rank measured live HBM bytes from the heartbeat digest (hbm "
-    "plane 'hbm' key) — the fleet-wide residency view gangtop renders "
-    "as the HBM column", ("rank",))
+    "per-rank measured live HBM bytes plus the dispatched block's "
+    "compiled temporaries from the heartbeat digest (hbm plane 'hbm' "
+    "key) — the fleet-wide residency view gangtop renders as the HBM "
+    "column", ("rank",))
 GANG_RANK_HDRM = REGISTRY.gauge(
     "paddle_tpu_gang_rank_hbm_headroom_bytes",
-    "per-rank measured HBM headroom (budget - live) from the heartbeat "
-    "digest ('hdrm'; present only while the rank knows a budget) — the "
+    "per-rank measured HBM headroom (budget - live - the dispatched "
+    "block's compiled temporaries) from the heartbeat digest ('hdrm'; "
+    "present only while the rank knows a budget) — the "
     "admission signal the GSPMD sharding chooser and an autoscaler "
     "read, and the gangtop HDRM%/OOM-RISK input", ("rank",))
 GANG_DIGEST_CTR = REGISTRY.counter(
@@ -702,7 +704,8 @@ def metrics_digest() -> Dict[str, Any]:
     # net-of-wait straggler math with frozen medians (a stale comm_wait
     # would excuse a genuinely slow rank forever).  comm_wait rides
     # whenever comm_ms does (a measured 0 is the signal's baseline).
-    # hbm plane (this PR): measured live bytes + headroom — presence-
+    # hbm plane: measured live bytes (with the dispatched block's compiled
+    # temporaries: hbm + hdrm is the budget) + headroom — presence-
     # gated on the accountant having published RECENTLY (same frozen-
     # value discipline as the comms keys: a rank that stopped sampling
     # must not read as holding its last-known residency forever).

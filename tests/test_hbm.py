@@ -57,6 +57,12 @@ def test_accountant_publishes_gauges_and_class_attribution():
         # Adam state (moments) is non-parameter persistable state
         assert cls.get("params", 0) > 0
         assert cls.get("opt_state", 0) > 0
+        # in no live array: the temporaries region of the train step's
+        # compiled plan, which the executor recorded where it compiled
+        temps = cls.pop("step_temporaries")
+        plan = [p for p in pt.memory.hbm_plans().values()
+                if p["block"] == "train"][-1]
+        assert temps == plan["temp_bytes"] > 0
         # attribution partitions the live set: classes never exceed it
         assert sum(cls.values()) <= live * 1.01
         tot = monitor.counter_totals()
@@ -376,24 +382,28 @@ def test_record_xla_plan_routes_through_shared_store():
         temp_size_in_bytes = 20
         alias_size_in_bytes = 30
         generated_code_size_in_bytes = 1
-    entry = hbm.record_xla_plan("test_hbm_plan_tag", _MA())
+    entry = hbm.record_xla_plan("test_hbm_plan_tag", _MA(), block="train",
+                                compiled_at=12.5,
+                                classes={"params": 60, "feeds": 40})
     assert entry["peak_bytes"] == 100 + 40 + 20 + 1 - 30
-    assert "test_hbm_plan_tag" in mem.hbm_plans()
+    stored = mem.hbm_plans()["test_hbm_plan_tag"]
+    assert (stored["block"], stored["compiled_at"]) == ("train", 12.5)
+    assert stored["argument_classes"] == {"params": 60, "feeds": 40}
+    lbl = {"block": "train", "tag": "test_hbm_plan_tag"}
+    parts = monitor.REGISTRY.get("paddle_tpu_step_hbm_plan_bytes")
+    assert {p: parts.value(part=p, **lbl) for p in hbm.PLAN_PARTS} == {
+        "arguments": 100, "outputs": 40, "aliased": 30, "temporaries": 20,
+        "code": 1}
     assert monitor.REGISTRY.get(
-        "paddle_tpu_hbm_xla_plan_peak_bytes").value() == \
-        entry["peak_bytes"]
-
-
-def test_plans_enabled_env_alias(monkeypatch):
-    monkeypatch.delenv("PADDLE_TPU_RECORD_HBM", raising=False)
-    pt.set_flags({"FLAGS_hbm_record_plans": False})
-    assert not hbm.plans_enabled()
-    monkeypatch.setenv("PADDLE_TPU_RECORD_HBM", "1")
-    assert hbm.plans_enabled()          # legacy env var stays an alias
-    monkeypatch.delenv("PADDLE_TPU_RECORD_HBM")
-    pt.set_flags({"FLAGS_hbm_record_plans": True})
-    assert hbm.plans_enabled()
-    pt.set_flags({"FLAGS_hbm_record_plans": False})
+        "paddle_tpu_step_hbm_plan_compiled_at_seconds").value(**lbl) == 12.5
+    assert monitor.REGISTRY.get(
+        "paddle_tpu_step_hbm_argument_bytes").value(cls="params", **lbl) == 60
+    # a second executable under the same fetch list keeps the first
+    again = hbm.record_xla_plan("test_hbm_plan_tag", _MA())
+    assert again["tag"] == "test_hbm_plan_tag#2" and again["block"] == "other"
+    # the residency summary prints both through the one formatter
+    assert "unaliased outputs" in mem.format_plan(stored)
+    assert "params 60" in mem.format_plan(stored)
 
 
 def test_headroom_regress_trigger_opens_window(tmp_path):

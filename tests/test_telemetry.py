@@ -325,7 +325,7 @@ def test_end_to_end_four_layer_trace_and_matching_export(tmp_path):
     evs = json.load(open(paths["trace"]))["traceEvents"]
     compile_evs = [e for e in evs if e["name"] == "xla.compile"]
     assert compile_evs and all(
-        e["args"]["persist_cache"] in ("off", "hit", "write")
+        e["args"]["persist_cache"] in ("off", "hit", "miss")
         for e in compile_evs)
 
     # exported dispatch counters == dispatch_stats(), exactly
@@ -503,8 +503,8 @@ def test_local_numpy_matches_numpy_single_process():
 
 def test_compile_telemetry_counts_and_persist_label(tmp_path):
     """Every fresh lowering records one compile event, labelled by what
-    the persistent cache did: it is always placed
-    (device.place_compile_cache), so the label is hit/write, never 'off'."""
+    the persistent cache did, as jax.monitoring says: it is always placed
+    (device.place_compile_cache), so the label is hit/miss, never 'off'."""
     ctr = monitor.REGISTRY.get("paddle_tpu_compile_total")
 
     def total():

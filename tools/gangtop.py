@@ -73,7 +73,8 @@ OOM_RISK_FRAC = 0.10
 
 def hdrm_frac(digest: dict):
     """Headroom fraction of budget from the digest's hbm/hdrm keys
-    (budget = live + headroom by construction); None when the rank
+    (budget = hbm + hdrm by construction: the hbm key counts the live
+    bytes and the running step's compiled temporaries); None when the rank
     carries no headroom signal (no budget known, or keys shed)."""
     hbm = digest.get("hbm")
     hdrm = digest.get("hdrm")

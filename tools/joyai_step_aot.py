@@ -279,7 +279,16 @@ def main():
                           "error": re.sub(r"\s+", " ",
                                           str(e))[:args.error_chars]}))
         return 1
-    mem = compiled.memory_analysis()
+    # the plan through the function the executor's hook calls where a block
+    # compiles (hbm.compiled_plan; PR 51): what this prints with no chip is
+    # what the chip's hbm_step_* per-layer metrics read
+    from paddle_tpu import hbm, memory
+    from paddle_tpu.framework.executor import _resolve_hbm_info
+    mem, classes = hbm.compiled_plan(
+        compiled, shapes, (cb.feed_names, cb.persist_ro, cb.persist_rw),
+        _resolve_hbm_info(cb, m["program"], step_args[0]))
+    plan = dict(memory.plan_parts(mem), argument_classes=classes)
+    print("plan: " + memory.format_plan(plan), file=sys.stderr)
     text = compiled.as_text()
     if args.dump:
         with open(args.dump, "w") as f:
@@ -292,6 +301,13 @@ def main():
         "argument_gb": mem.argument_size_in_bytes / 1e9,
         "output_gb": mem.output_size_in_bytes / 1e9,
         "alias_gb": mem.alias_size_in_bytes / 1e9,
+        # the four per-layer readings' parts, as the chip's line names them
+        "hbm_step_arguments_gb": plan["argument_bytes"] / 1e9,
+        "hbm_step_temporaries_gb": plan["temp_bytes"] / 1e9,
+        "hbm_step_unaliased_outputs_gb":
+            (plan["output_bytes"] - plan["alias_bytes"]) / 1e9,
+        "code_gb": plan["generated_code_bytes"] / 1e9,
+        "arguments_by_class_gb": {c: n / 1e9 for c, n in classes.items()},
         "remat_instructions": len(re.findall(r"\.remat\d* = ", text)),
         "reads_after_update": reads_after_update(text),
         # which backward each flash_attention_grad lowering of the step got

@@ -3,15 +3,16 @@ bench steps (VERDICT r3 missing #3 / ask #5).
 
 Two measurements, one process (this process owns the chip while it runs):
 the compiled executable's XLA buffer assignment (memory_analysis):
-arguments + temps + outputs - aliased(donated) — the bytes the runtime
-reserves for ONE training step, which the executor records when
-PADDLE_TPU_RECORD_HBM=1 (see memory.record_hbm_plan) — and the allocator's
-own ``device.memory_stats()`` counters after both steps.
+arguments + temporaries + outputs - aliased(donated) — the bytes the runtime
+reserves for ONE training step, which the executor records for every block
+where it compiles (hbm.record_compiled_plan; the arguments by class too) —
+and the allocator's own ``device.memory_stats()`` counters after both steps.
 
 Run on a chip:
     python tools/record_hbm.py
-Prints one JSON object {"device", "memory_stats", "plans"} on the last
-line.
+Prints each step's plan as the residency summary does (memory.format_plan:
+what tools/joyai_step_aot.py prints with no chip) and one JSON object
+{"device", "memory_stats", "plans"} on the last line.
 """
 import json
 import os
@@ -19,7 +20,6 @@ import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
-os.environ["PADDLE_TPU_RECORD_HBM"] = "1"
 
 import numpy as np  # noqa: E402
 
@@ -105,14 +105,14 @@ def main():
             plans[name] = {"error": str(e)[:300]}
             failed.append(name)
             continue
+        # the step's plan is the newest train block's (the startup
+        # program's block is recorded too, as 'other')
         new = {k: v for k, v in memory.hbm_plans().items()
-               if k not in before}
+               if k not in before and v["block"] == "train"}
         if new:
-            # the training-step plan is the largest new one (startup
-            # programs record tiny plans too)
-            tag, plan = max(new.items(),
-                            key=lambda kv: kv[1]["peak_bytes"])
+            tag, plan = list(new.items())[-1]
             plans[name] = dict(plan, fetch=tag[:80])
+            print(f"{name}: {memory.format_plan(plan)}")
     dev = jax.devices()[0]
     print(json.dumps({
         "device": {"platform": dev.platform, "kind": dev.device_kind,
