@@ -54,7 +54,11 @@ BF16_IF_BIG = {
     "hc_pre", "hc_post",
     # Q, K and V in bf16; the log-decay and beta stay float32, as does
     # everything inside the scan (ops/kda_ops.py); kda_gate is in no list:
-    # float32 inside, and its outputs are float32
+    # float32 inside in either form of the decay's gate (softplus, or the
+    # bounded lower_bound * sigmoid), and its outputs are float32; moe_ffn
+    # is in no list either: its router (scores, the group scores of a
+    # group-limited selection, the mask and the top-k) is float32 at full
+    # precision inside whatever the rows' dtype (ops/moe_ops.py)
     "kda_scan",
 }
 

@@ -1648,12 +1648,17 @@ def short_conv(x, filter_size=3, param_attr=None, name=None, gated=True):
     return out
 
 
-def kda_gate(x, beta_logits, n_head, param_prefix="kda", name=None):
+def kda_gate(x, beta_logits, n_head, param_prefix="kda", name=None,
+             lower_bound=None, rank=None):
     """The two gates of a gated delta-rule layer with a per-channel decay
     (KDA, arXiv:2510.26692), float32 whatever AMP says: ``g = -exp(A_log_h)
     softplus(x + dt_bias)`` [b, t, n_head, d] from ``x`` [b, t, n_head * d]
     (the log of the decay, ``<= 0``), and ``beta = sigmoid(beta_logits)``
-    [b, t, n_head].  Parameters, float32: ``<prefix>.A_log`` [n_head], ``log
+    [b, t, n_head].  With ``lower_bound`` (negative) the decay's gate is the
+    bounded form, ``g = lower_bound * sigmoid(exp(A_log_h) (x + dt_bias))`` in
+    ``(lower_bound, 0)``.  ``rank`` (an int or ``"full"``) says what made
+    ``x`` and only labels ``paddle_tpu_kda_gate_lowerings_total``.
+    Parameters, float32: ``<prefix>.A_log`` [n_head], ``log
     U(1, 16)``, and ``<prefix>.dt_bias`` [n_head * d], the inverse softplus
     of ``exp U(log 1e-3, log 1e-1)`` (the published layer's initial values);
     both are drawn here, from the parameter's name, and not by the startup
@@ -1675,10 +1680,18 @@ def kda_gate(x, beta_logits, n_head, param_prefix="kda", name=None):
         for what in ("A_log", "dt_bias"))
     g = helper.create_variable_for_type_inference("float32")
     beta = helper.create_variable_for_type_inference("float32")
+    attrs = {}
+    if lower_bound is not None:
+        if not lower_bound < 0:
+            raise ValueError(f"kda_gate lower_bound {lower_bound!r}: the "
+                             "log of a decay is negative")
+        attrs["lower_bound"] = float(lower_bound)
+    if rank is not None:
+        attrs["rank"] = str(rank)
     helper.append_op(
         "kda_gate", inputs={"X": [x], "B": [beta_logits], "ALog": [a_log],
                             "DtBias": [dt_bias]},
-        outputs={"G": [g], "Beta": [beta]})
+        outputs={"G": [g], "Beta": [beta]}, attrs=attrs)
     return g, beta
 
 
@@ -1814,7 +1827,7 @@ def moe_ffn(x, num_experts, top_k, d_expert, norm_topk_prob=False,
             param_prefix="moe", initializer=None, name=None,
             score_func="softmax", select_bias=False, norm_eps=0.0,
             route_scale=1.0, num_held=None, expert_offset=0, act="silu",
-            router_x=None):
+            router_x=None, n_group=1, topk_group=1):
     """Dropless top-k mixture of gated experts (SiLU on the gate branch, or
     ReLU with ``act="relu"``) over [b, t, d] input
     (``moe_ffn`` op: sorted rows + grouped matmuls, no capacity, no dropped
@@ -1831,7 +1844,11 @@ def moe_ffn(x, num_experts, top_k, d_expert, norm_topk_prob=False,
     ``sigmoid``; ``select_bias=True`` creates ``<prefix>.select_bias``
     [num_experts], zero, not trainable, added to the scores for the choice
     of experts only; ``norm_eps`` joins the renormalising sum;
-    ``route_scale`` multiplies the weights.  ``num_held`` (default all):
+    ``route_scale`` multiplies the weights; ``n_group`` > 1: group-limited
+    selection (DeepSeek-V3's, Ling 2.0's): the experts in ``n_group`` groups of
+    consecutive experts, a group's score the sum of its two largest (biased)
+    scores, the ``topk_group`` best groups kept and the ``top_k`` chosen among
+    their experts alone.  ``num_held`` (default all):
     the expert weights are [num_held, ...] and hold experts
     ``expert_offset .. expert_offset + num_held - 1`` of the ``num_experts``
     the router scores; the output is their part of the layer's.
@@ -1885,6 +1902,15 @@ def moe_ffn(x, num_experts, top_k, d_expert, norm_topk_prob=False,
         attrs["expert_offset"] = int(expert_offset)
     if act != "silu":
         attrs["act"] = str(act)
+    if int(n_group) > 1:
+        if E % int(n_group) or not 1 <= int(topk_group) <= int(n_group) \
+                or int(top_k) > int(topk_group) * (E // int(n_group)):
+            raise ValueError(
+                f"moe_ffn: {E} experts in {n_group} groups, {topk_group} "
+                f"kept, {top_k} a token")
+        attrs["n_group"], attrs["topk_group"] = int(n_group), int(topk_group)
+    elif int(topk_group) != 1:
+        raise ValueError(f"moe_ffn topk_group {topk_group} of one group")
     helper.append_op(
         "moe_ffn", inputs=inputs,
         outputs={"Out": [out], "LbLoss": [lb], "ZLoss": [z],

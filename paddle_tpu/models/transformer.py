@@ -749,8 +749,23 @@ def latent_attention(x, cfg: JoyaiConfig, param_prefix="attn"):
     softmax scale is multiplied by :func:`yarn_softmax_factor`.  Everything
     but the flash op lies under the ``mla_proj`` tag.  Parameters:
     ``<prefix>.a.w``,
-    ``.q_norm.w``, ``.kv_norm.w``, ``.q_b.w``, ``.kv_b.w``, ``.out.w``."""
+    ``.q_norm.w``, ``.kv_norm.w``, ``.q_b.w``, ``.kv_b.w``, ``.out.w``.
+
+    What a configuration may switch (each absent from :class:`JoyaiConfig`,
+    whose program is then the one above): ``cfg.q_lora_rank`` None, Q at full
+    rank, ``[q_nope | q_rope] = x W_q`` (``.q.w`` [d, h (d_nope + d_rope)];
+    no Q latent, no ``q_norm``; ``W_a`` is [d, r_kv + d_rope]);
+    ``cfg.qk_norm``: ``q_nope`` and ``k_nope`` RMS-normed over ``d_nope``,
+    one learned [d_nope] scale for all query heads (``.q_nope_norm.w``) and
+    one for all key heads (``.k_nope_norm.w``); the rotary slices are not
+    normed (the rotary key is one head for all); ``cfg.head_gate``: the
+    context of head ``i`` times ``sigmoid((x W_gate)_i)``, ``.gate.w`` [d,
+    h], one gate a head and token, between the flash op and ``W_o``.
+    ``cfg.n_head`` may be a SHARE of the layer's heads (tensor parallelism):
+    ``W_o`` is [n_head d_v, d_model] and its result the partial sum over
+    the heads held; the K/V latent and the rotary key are whole."""
     h, dn, dr, dv = cfg.n_head, cfg.d_nope, cfg.d_rope, cfg.d_v
+    qk_norm = getattr(cfg, "qk_norm", False)
 
     def proj(v, size, name):
         return layers.fc(v, size=size, num_flatten_dims=2, bias_attr=False,
@@ -770,15 +785,26 @@ def latent_attention(x, cfg: JoyaiConfig, param_prefix="attn"):
                            rope_scaling=cfg.rope_scaling)
 
     with name_scope("mla_proj"):
-        c_q, c_kv, k_r = layers.split(
-            proj(x, cfg.q_lora_rank + cfg.kv_lora_rank + dr, "a"),
-            [cfg.q_lora_rank, cfg.kv_lora_rank, dr], dim=2)
-        q_nope, q_rope = layers.split(
-            heads(proj(norm(c_q, "q_norm"), h * (dn + dr), "q_b"), dn + dr),
-            [dn, dr], dim=3)
+        if cfg.q_lora_rank is None:
+            q = proj(x, h * (dn + dr), "q")
+            c_kv, k_r = layers.split(proj(x, cfg.kv_lora_rank + dr, "a"),
+                                     [cfg.kv_lora_rank, dr], dim=2)
+        else:
+            c_q, c_kv, k_r = layers.split(
+                proj(x, cfg.q_lora_rank + cfg.kv_lora_rank + dr, "a"),
+                [cfg.q_lora_rank, cfg.kv_lora_rank, dr], dim=2)
+            q = proj(norm(c_q, "q_norm"), h * (dn + dr), "q_b")
+        q_nope, q_rope = layers.split(heads(q, dn + dr), [dn, dr], dim=3)
         k_nope, v = layers.split(
             heads(proj(norm(c_kv, "kv_norm"), h * (dn + dv), "kv_b"),
                   dn + dv), [dn, dv], dim=3)
+        if qk_norm:
+            q_nope, k_nope = (
+                layers.rms_norm(t, begin_norm_axis=3, epsilon=cfg.rms_eps,
+                                param_attr=ParamAttr(
+                                    name=f"{param_prefix}.{n}.w"))
+                for t, n in ((q_nope, "q_nope_norm"),
+                             (k_nope, "k_nope_norm")))
         k_r = rotate(layers.unsqueeze(k_r, [1]))            # [b, 1, t, dr]
         q_rope = rotate(q_rope)
     ctx = layers.flash_attention(
@@ -786,8 +812,11 @@ def latent_attention(x, cfg: JoyaiConfig, param_prefix="attn"):
         sm_scale=float(dn + dr) ** -0.5
         * yarn_softmax_factor(cfg.rope_scaling))
     with name_scope("mla_proj"):
-        ctx = layers.reshape(layers.transpose(ctx, perm=[0, 2, 1, 3]),
-                             shape=[0, 0, h * dv])
+        ctx = layers.transpose(ctx, perm=[0, 2, 1, 3])      # [b, t, h, dv]
+        if getattr(cfg, "head_gate", False):
+            ctx = ctx * layers.unsqueeze(
+                layers.sigmoid(proj(x, h, "gate")), [3])
+        ctx = layers.reshape(ctx, shape=[0, 0, h * dv])
         return proj(ctx, cfg.d_model, "out")
 
 
@@ -1274,10 +1303,19 @@ def kda_attention(x, cfg: SolarOpen2Config, param_prefix="kda"):
     from what it is given: at heads of whole lane tiles (the published 128)
     on a TPU the kernel pair of ``pallas/kda.py``, whose backward starts
     from the chunk states the forward kept; ``kda_chunked``'s plain
-    ``jax.numpy`` elsewhere (``paddle_tpu_kda_lowerings_total{impl}``)."""
+    ``jax.numpy`` elsewhere (``paddle_tpu_kda_lowerings_total{impl}``).
+
+    ``cfg.kda_gate_rank`` None: both gates at FULL rank (no ``f_up`` /
+    ``g_up``; ``W_in`` is ``[d_model, 3 h d + 2 h d + h]`` and its ``f`` and
+    ``g`` slices are the gates' inputs themselves).  ``cfg.kda_lower_bound``
+    (absent or None: the form above): the decay's gate in its bounded form,
+    ``g_t = lower_bound sigmoid(exp(A_log) (f + dt_bias))``
+    (``layers.kda_gate(lower_bound=)``)."""
     from ..initializer import UniformInitializer
     h, d, r = cfg.n_kda_head, cfg.d_head, cfg.kda_gate_rank
     dq = h * d
+    full = r is None
+    r = dq if full else r
 
     def proj(v, size, name):
         return layers.fc(v, size=size, num_flatten_dims=2, bias_attr=False,
@@ -1294,14 +1332,17 @@ def kda_attention(x, cfg: SolarOpen2Config, param_prefix="kda"):
                                                                 bound)))
         q, k, v = (layers.reshape(t, shape=[0, 0, h, d])
                    for t in layers.split(qkv, 3, dim=2))
-        decay, beta = layers.kda_gate(proj(f, dq, "f_up"), b, h, param_prefix)
+        decay, beta = layers.kda_gate(
+            f if full else proj(f, dq, "f_up"), b, h, param_prefix,
+            lower_bound=getattr(cfg, "kda_lower_bound", None),
+            rank="full" if full else r)
         o = layers.kda_scan(q, k, v, decay, beta, chunk=cfg.kda_chunk,
                             neg_eigval=cfg.kda_neg_eigval)
         o = layers.rms_norm(o, begin_norm_axis=3, epsilon=cfg.rms_eps,
                             param_attr=ParamAttr(
                                 name=f"{param_prefix}.o_norm.w"))
         y = layers.reshape(o, shape=[0, 0, dq]) \
-            * layers.sigmoid(proj(g, dq, "g_up"))
+            * layers.sigmoid(g if full else proj(g, dq, "g_up"))
         return proj(y, cfg.d_model, "out")
 
 
@@ -1378,6 +1419,153 @@ def build_solar_open2_pretrain(cfg: SolarOpen2Config, seq_len, is_test=False,
     for i in range(cfg.n_layer):
         x, load = solar_open2_decoder_layer(x, cfg, i, attn_impl, is_test)
         loads.append(load)
+        if checkpoints is not None:
+            checkpoints.append(x)
+    x = layers.rms_norm(x, begin_norm_axis=2, epsilon=cfg.rms_eps,
+                        param_attr=ParamAttr(name="final_norm.w"))
+    _, loss = _lm_head_loss(x, cfg, lm_label, fused_head, "lm_out",
+                            bias=False)
+    return (src_ids, lm_label), {"expert_load": loads, "hidden": x}, loss
+
+
+# -- Ling 3.0: KDA with a bounded gate beside latent attention, --------------
+# -- group-limited routing, a leading dense layer ----------------------------
+
+class LingConfig:
+    """Ling-3.0-flash defaults (``inclusionAI/Ling-3.0-flash-VL``
+    config.json, the language model; the vision tower is not built).  Layer
+    ``i`` (published number) is latent attention where ``(i + 1) %
+    layer_group_size == 0`` and KDA otherwise: five KDA layers, then one MLA
+    layer.  KDA (:func:`kda_attention`): both gates at full rank
+    (``no_kda_lora``), the decay's gate bounded below by ``kda_lower_bound``
+    (``kda_safe_gate``), beta not doubled.  MLA (:func:`latent_attention`):
+    Q at full rank (``q_lora_rank`` None), QK-norm on the content parts, a
+    head-wise output gate.  The first ``n_dense_layer`` layers have a dense
+    gated FFN of width ``d_inner``, the others ``n_experts`` routed experts
+    of width ``d_expert`` (``top_k`` a token among the ``topk_group`` best of
+    ``n_group`` groups, sigmoid scores, a selection bias, renormalised and
+    scaled) beside one shared expert of width ``d_shared``.
+
+    ``first_layer``: the published number of this program's layer 0, where
+    it holds a run of the layers (a pipeline stage); the dense layers and
+    the MLA layers follow the published numbers.  ``n_head`` and
+    ``n_kda_head`` are the heads HELD (default all 32), as
+    :class:`SolarOpen2Config`; ``n_held``/``expert_offset`` the experts
+    held, as :class:`TrinityConfig`."""
+
+    rope_scaling = None
+    kda_neg_eigval = False
+    qk_norm = True
+    head_gate = True
+
+    def __init__(self, vocab_size=157184, d_model=2560, n_layer=42,
+                 n_head=32, n_kda_head=32, d_head=128, kv_lora_rank=512,
+                 d_nope=128, d_rope=64, d_v=128, d_inner=6144, d_expert=768,
+                 d_shared=768, n_experts=512, top_k=8, n_group=8,
+                 topk_group=4, n_dense_layer=2, layer_group_size=6,
+                 first_layer=0, conv_taps=4, kda_lower_bound=-5.0,
+                 kda_chunk=64, route_scale=2.5, rms_eps=1e-6,
+                 rope_theta=6000000.0, n_held=None, expert_offset=0,
+                 init_std=0.02):
+        self.vocab_size = vocab_size
+        self.d_model = d_model
+        self.n_layer = n_layer
+        self.n_head = n_head
+        self.n_kda_head = n_kda_head
+        self.d_head = d_head
+        self.q_lora_rank = None
+        self.kv_lora_rank = kv_lora_rank
+        self.d_nope = d_nope
+        self.d_rope = d_rope
+        self.d_v = d_v
+        self.d_inner = d_inner
+        self.d_expert = d_expert
+        self.d_shared = d_shared
+        self.n_experts = n_experts
+        self.top_k = top_k
+        self.n_group = n_group
+        self.topk_group = topk_group
+        self.layer_group_size = layer_group_size
+        self.first_layer = first_layer
+        numbers = range(first_layer, first_layer + n_layer)
+        self.dense_layers = [j for j, i in enumerate(numbers)
+                             if i < n_dense_layer]
+        self.mla_layers = [j for j, i in enumerate(numbers)
+                           if (i + 1) % layer_group_size == 0]
+        self.conv_taps = conv_taps
+        self.kda_gate_rank = None
+        self.kda_lower_bound = kda_lower_bound
+        self.kda_chunk = kda_chunk
+        self.route_scale = route_scale
+        self.rms_eps = rms_eps
+        self.rope_theta = rope_theta
+        self.n_held = n_experts if n_held is None else n_held
+        self.expert_offset = expert_offset
+        self.init_std = init_std
+
+
+def ling_decoder_layer(x, cfg: LingConfig, idx=0):
+    """One Ling block, pre-norm, two norms, no bias anywhere: ``u = x +
+    Mixer(RMS1(x))``, ``out = u + FFN(RMS2(u))``.  ``Mixer``:
+    :func:`latent_attention` in ``cfg.mla_layers``, else
+    :func:`kda_attention`.  ``FFN``: :func:`gated_ffn` of width ``d_inner``
+    in ``cfg.dense_layers`` (the ``dense_ffn`` tag); else the shared expert
+    (the same builder at ``d_shared``) plus ``moe_ffn`` with sigmoid scores,
+    a selection bias held at zero, group-limited selection, the kept scores
+    renormalised (``+ 1e-20``) and scaled.  Returns ``(out, expert_load or
+    None)``."""
+    from ..initializer import NormalInitializer
+    p = f"dec_{idx}"
+
+    def norm(v, name):
+        return layers.rms_norm(v, begin_norm_axis=2, epsilon=cfg.rms_eps,
+                               param_attr=ParamAttr(name=f"{p}.{name}.w"))
+
+    n = norm(x, "ln1")
+    if idx in cfg.mla_layers:
+        u = x + latent_attention(n, cfg, f"{p}.attn")
+    else:
+        u = x + kda_attention(n, cfg, f"{p}.kda")
+    m = norm(u, "ln2")
+    if idx in cfg.dense_layers:
+        with name_scope("dense_ffn"):
+            return u + gated_ffn(m, cfg.d_inner, cfg.d_model, f"{p}.ffn"), \
+                None
+    with name_scope("shared_expert"):
+        f = gated_ffn(m, cfg.d_shared, cfg.d_model, f"{p}.shared")
+    moe, _, _, load = layers.moe_ffn(
+        m, cfg.n_experts, cfg.top_k, cfg.d_expert, norm_topk_prob=True,
+        param_prefix=f"{p}.moe",
+        initializer=NormalInitializer(0.0, cfg.init_std),
+        score_func="sigmoid", select_bias=True, norm_eps=1e-20,
+        route_scale=cfg.route_scale, num_held=cfg.n_held,
+        expert_offset=cfg.expert_offset, n_group=cfg.n_group,
+        topk_group=cfg.topk_group)
+    return u + f + moe, load
+
+
+def build_ling_pretrain(cfg: LingConfig, seq_len, fused_head=True,
+                        checkpoints=None):
+    """Causal LM over :func:`ling_decoder_layer` blocks: ids -> embedding ->
+    ``n_layer`` blocks -> final RMSNorm -> untied bias-free head; loss =
+    mean next-token CE and nothing else (the selection bias is held at zero
+    and there is no auxiliary term).  ``checkpoints=[]`` collects the block
+    boundaries for ``RecomputeOptimizer``: the embedding's output and every
+    block's, KDA, MLA, dense or expert alike, as
+    :func:`build_solar_open2_pretrain`.  Returns ``(feeds, parts, loss)``
+    with ``parts`` = {"expert_load": [per expert layer], "hidden": the final
+    norm's output}."""
+    src_ids = layers.data("src_ids", shape=[seq_len], dtype="int64")
+    lm_label = layers.data("lm_label", shape=[seq_len], dtype="int64")
+    x = layers.embedding(src_ids, size=[cfg.vocab_size, cfg.d_model],
+                         param_attr=ParamAttr(name="word_embedding"))
+    loads = []
+    if checkpoints is not None:
+        checkpoints.append(x)
+    for i in range(cfg.n_layer):
+        x, load = ling_decoder_layer(x, cfg, i)
+        if load is not None:
+            loads.append(load)
         if checkpoints is not None:
             checkpoints.append(x)
     x = layers.rms_norm(x, begin_norm_axis=2, epsilon=cfg.rms_eps,

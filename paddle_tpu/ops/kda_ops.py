@@ -11,6 +11,8 @@ here whose forward carries state along the sequence.  Per head, ``S`` a
 Two ops::
 
     G, Beta = kda_gate(X, B; ALog, DtBias)   g = -exp(A_log_h) softplus(x + dt_bias)
+                                             or, bounded: g = lower_bound
+                                             sigmoid(exp(A_log_h) (x + dt_bias))
                                              beta = sigmoid(b)
     Out, States = kda_scan(Q, K, V, G, Beta)     States: S before each chunk
 
@@ -281,18 +283,44 @@ def _kda_scan_grad(ctx, ins, attrs):
             for s, g, p in zip(_SCAN_IN, grads, prim)}
 
 
+KDA_GATE_LOWERINGS_CTR = _monitor.REGISTRY.counter(
+    "paddle_tpu_kda_gate_lowerings_total",
+    "kda_gate lowerings by the form of the decay's gate (softplus: g = "
+    "-exp(A_log) softplus(x + dt_bias), unbounded below; bounded: g = "
+    "lower_bound sigmoid(exp(A_log) (x + dt_bias)), in (lower_bound, 0)) "
+    "and the rank of the projection that made x ('full' where the layer "
+    "projects at full rank, '' where the builder did not say) — counted "
+    "while tracing, once per compile of a program that holds the op",
+    ("form", "rank"))
+
+
 @register_op("kda_gate")
 def _kda_gate(ctx, ins, attrs):
     """KDA's two gates in float32 whatever AMP says: X [b, t, h d] (the
-    decay's low-rank projection), B [b, t, h] (beta's logits), ALog [h],
-    DtBias [h d] -> G [b, t, h, d] = ``-exp(ALog_h) softplus(X + DtBias)``,
-    the log of the per-channel decay, and Beta [b, t, h] = ``sigmoid(B)``
-    (``kda_scan`` doubles it where the model allows negative eigenvalues).
-    The backward is the registry's ``jax.vjp`` of this lowering."""
+    decay's projection), B [b, t, h] (beta's logits), ALog [h], DtBias [h d]
+    -> G [b, t, h, d], the log of the per-channel decay, and Beta [b, t, h] =
+    ``sigmoid(B)`` (``kda_scan`` doubles it where the model allows negative
+    eigenvalues).  Two forms of G: ``-exp(ALog_h) softplus(X + DtBias)``
+    (``<= 0``, unbounded), and with the attribute ``lower_bound`` (a negative
+    number; the fla layer's ``lower_bound`` / "safe" gate) ``lower_bound
+    sigmoid(exp(ALog_h) (X + DtBias))``, in ``(lower_bound, 0)``: no channel
+    decays faster than ``exp(lower_bound)`` a position.  The attribute
+    ``rank`` only labels the count.  The backward is the registry's
+    ``jax.vjp`` of this lowering."""
     f32 = jnp.float32
     x, a_log, dt_bias = X(ins, "X"), X(ins, "ALog"), X(ins, "DtBias")
     h = a_log.shape[0]
-    gate = jax.nn.softplus(x.astype(f32) + dt_bias.astype(f32))
-    gate = gate.reshape(*x.shape[:-1], h, x.shape[-1] // h)
-    return {"G": [-jnp.exp(a_log.astype(f32))[:, None] * gate],
-            "Beta": [jax.nn.sigmoid(X(ins, "B").astype(f32))]}
+    bound = attrs.get("lower_bound")
+    if not getattr(ctx, "is_abstract", False):
+        KDA_GATE_LOWERINGS_CTR.inc(
+            form="softplus" if bound is None else "bounded",
+            rank=str(attrs.get("rank", "") or ""))
+    pre = x.astype(f32) + dt_bias.astype(f32)
+    heads = (*x.shape[:-1], h, x.shape[-1] // h)
+    if bound is None:
+        gate = jax.nn.softplus(pre).reshape(heads)
+        g = -jnp.exp(a_log.astype(f32))[:, None] * gate
+    else:
+        g = float(bound) * jax.nn.sigmoid(
+            jnp.exp(a_log.astype(f32))[:, None] * pre.reshape(heads))
+    return {"G": [g], "Beta": [jax.nn.sigmoid(X(ins, "B").astype(f32))]}

@@ -28,6 +28,15 @@ was before they existed, bit for bit):
 - ``norm_topk_prob`` with ``norm_eps``: the kept scores divided by their sum
   plus ``norm_eps`` (published 1e-20 for sigmoid routers); ``route_scale``
   multiplies the weights after that.
+- ``n_group``, ``topk_group`` (group-limited selection, DeepSeek-V3's
+  ``noaux_tc`` / Ling 2.0's gate): the ``E_total`` experts in ``n_group``
+  groups of consecutive experts, a group's score the sum of its two largest
+  biased scores, the ``topk_group`` best groups kept, the others' entries
+  masked out of the choice of the ``k`` (``_group_mask``: ``lax.top_k`` over
+  ``[S, n_group, E / n_group]`` and over the group scores, no sort of
+  ``E_total``).  The weights stay the unbiased scores of the chosen; the
+  backward passes nothing through the mask.  1 / 1 is no attribute and the
+  lowering as it was.
 - ``expert_offset``: the router stays ``[d, E_total]``, the expert weights
   are ``[E_here, d, f]`` and hold experts ``offset .. offset + E_here - 1``.
   The op computes ``sum_{e in top-k, offset <= e < offset + E_here} w_e *
@@ -175,9 +184,11 @@ MOE_LOWERINGS_CTR = _monitor.REGISTRY.counter(
     "reads the experts' rows and own where it has an input of its own, "
     "unsort = what the two un-sorts (the forward's weighted sum, the "
     "backward's gather back to tokens) cost by: rows, a share's held rows "
-    "alone (pallas/held_rows.py), or slots, XLA's gather over every slot",
+    "alone (pallas/held_rows.py), or slots, XLA's gather over every slot; "
+    "groups = n_group/topk_group of the router's group-limited selection, "
+    "1/1 where every expert competes with every other",
     ("impl", "experts", "top_k", "held", "score_func", "ladder", "act",
-     "router_input", "unsort"))
+     "router_input", "unsort", "groups"))
 
 
 MOE_ROUTED_ROWS_CTR = _monitor.REGISTRY.counter(
@@ -296,14 +307,32 @@ def gated_experts(xs, wg, wu, wd, load, dt, impl=None, tiling=None,
     return mm(gate(g, u, dt), wd, load), g, u
 
 
+def _group_mask(sel, n_group, topk_group):
+    """[S, E] bool: the experts of each token's ``topk_group`` best of
+    ``n_group`` groups of ``E / n_group`` consecutive experts, a group's
+    score the sum of its two largest entries of ``sel`` (DeepSeek-V3's
+    ``noaux_tc``); a ``top_k`` over [S, n_group, E / n_group] and one over
+    the group scores, no sort of ``E``."""
+    S, E = sel.shape
+    top2, _ = jax.lax.top_k(sel.reshape(S, n_group, E // n_group), 2)
+    _, best = jax.lax.top_k(jnp.sum(top2, axis=-1), topk_group)
+    kept = jnp.any(best[:, :, None] == jnp.arange(n_group)[None, None, :],
+                   axis=1)                                  # [S, n_group]
+    return jnp.repeat(kept, E // n_group, axis=1)
+
+
 def _router(xt, wr, k, renorm, score_func="softmax", bias=None,
-            norm_eps=0.0, scale=1.0):
+            norm_eps=0.0, scale=1.0, n_group=1, topk_group=1):
     """Float32 at full precision whatever AMP says, softmax or sigmoid:
     ``(top_p [S, k], lb [], z [])`` and, not differentiated, ``(top_e
     [S, k], load [E])``.  ``bias`` [E] moves the choice of the ``k`` only;
     ``top_p`` are the unbiased scores of the chosen, renormalised (``/ (sum
     + norm_eps)``) and scaled if asked.  Under ``sigmoid`` the
-    load-balancing loss reads the scores normalised over the experts."""
+    load-balancing loss reads the scores normalised over the experts.
+    ``n_group`` > 1: the ``k`` are chosen among the experts of the
+    ``topk_group`` best groups (:func:`_group_mask` of the biased scores);
+    nothing is differentiated through the mask or the choice, the weights'
+    gradient flows through the chosen scores alone."""
     f32 = jnp.float32
     S, E = xt.shape[0], wr.shape[-1]
     logits = jnp.dot(xt.astype(f32), wr.astype(f32),
@@ -314,7 +343,13 @@ def _router(xt, wr, k, renorm, score_func="softmax", bias=None,
         p = jax.nn.softmax(logits, axis=-1)
     else:
         raise ValueError(f"moe_ffn score_func {score_func!r}")
-    if bias is None:
+    if n_group > 1:
+        sel = jax.lax.stop_gradient(
+            p if bias is None else p + bias.astype(f32)[None, :])
+        sel = jnp.where(_group_mask(sel, n_group, topk_group), sel, -jnp.inf)
+        _, top_e = jax.lax.top_k(sel, k)
+        top_p = jnp.take_along_axis(p, top_e, axis=-1)
+    elif bias is None:
         top_p, top_e = jax.lax.top_k(p, k)
     else:
         _, top_e = jax.lax.top_k(
@@ -338,7 +373,9 @@ def _router_of(attrs, k, bias):
     kw = dict(renorm=bool(attrs.get("norm_topk_prob", False)),
               score_func=attrs.get("score_func", "softmax") or "softmax",
               bias=bias, norm_eps=float(attrs.get("norm_eps", 0.0) or 0.0),
-              scale=float(attrs.get("route_scale", 1.0) or 1.0))
+              scale=float(attrs.get("route_scale", 1.0) or 1.0),
+              n_group=int(attrs.get("n_group", 1) or 1),
+              topk_group=int(attrs.get("topk_group", 1) or 1))
     return lambda xt, wr: _router(xt, wr, k, **kw)
 
 
@@ -556,7 +593,9 @@ def _moe_ffn(ctx, ins, attrs):
             score_func=attrs.get("score_func", "softmax") or "softmax",
             ladder=".".join(map(str, ladder)), act=act,
             router_input="x" if router_x is None else "own",
-            unsort="slots" if unsort is None else "rows")
+            unsort="slots" if unsort is None else "rows",
+            groups=f"{int(attrs.get('n_group', 1) or 1)}/"
+                   f"{int(attrs.get('topk_group', 1) or 1)}")
         if ladder:
             _TRACED_LADDERS[S * k, E, n_held] = ladder
     xt = x.reshape(S, d)

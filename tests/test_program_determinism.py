@@ -57,6 +57,8 @@ BUILDERS = {
     "lfm2": ("lfm2_8b_a1b", lambda: _toy("test_lfm2_cell", "toy_lfm2")),
     "solar": ("solar_open2_250b",
               lambda: _toy("test_solar_open2_cell", "toy_solar")),
+    "ling": ("ling3_flash_vl",
+             lambda: _toy("test_ling3_cell", "toy_ling")),
     "olmoe": ("olmoe_1b_7b", lambda: _toy("test_olmoe_cell", "toy_olmoe")),
     "bert_fused": ("bert_base",
                    lambda: _toy("test_benchmark_rehearsal", "toy_bert")),

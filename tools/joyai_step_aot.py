@@ -13,8 +13,10 @@ writes ``lse`` in (``paddle_tpu_flash_lowerings_total{lse}``) and the form each
 ``rope`` and ``rope_grad`` got (``paddle_tpu_rope_lowerings_total``, the
 frequency-table form told from ``theta``'s) and, for ``--cell xing4``, the
 hyper-connection ops' lowerings (``paddle_tpu_hc_lowerings_total``) and,
-for ``--cell solar``, the chunked scan's
-(``paddle_tpu_kda_lowerings_total``), with
+for ``--cell solar`` and ``--cell ling``, the chunked scan's
+(``paddle_tpu_kda_lowerings_total``) and its gate's
+(``paddle_tpu_kda_gate_lowerings_total``), and the routing groups of every
+``moe_ffn`` lowering (``paddle_tpu_moe_lowerings_total{groups}``), with
 and without ``--recompute``: whether the step fits beside its state, and what fitting
 costs (PERF.md section 7, row 31).  Nothing runs: no time comes from this.  The adapter has the
 recomputing step only (the traffic file's); without ``--recompute`` this
@@ -70,7 +72,8 @@ CELLS = {"joyai": ("joyai_llm_flash", "lm_mtp_s8192"),
          "smallthinker": ("smallthinker_21b_a3b", "lm_s16384"),
          "lfm2": ("lfm2_8b_a1b", "lm_s16384_r64"),
          "xing4": ("xing4_29b_a4b", "lm_s4096_r64"),
-         "solar": ("solar_open2_250b", "lm_s8192_r64")}
+         "solar": ("solar_open2_250b", "lm_s8192_r64"),
+         "ling": ("ling3_flash_vl", "lm_s8192_r64")}
 
 
 def reads_after_update(text):
@@ -172,7 +175,8 @@ def main():
                     "says recompute, which --recompute sets, and so does "
                     "xing4's, whose timed step is the plain one; solar's "
                     "timed step recomputes: pass --recompute for it, and "
-                    "leave it out to see the plain step refused)")
+                    "leave it out to see the plain step refused; ling's "
+                    "likewise)")
     args = ap.parse_args()
     if args.run:
         return run_on_chip(args)
@@ -204,7 +208,7 @@ def main():
         config["num_hidden_layers"] = args.layers
     if args.seq:
         traffic["seq_len"] = args.seq
-    if args.recompute and args.cell in ("lfm2", "xing4", "solar"):
+    if args.recompute and args.cell in ("lfm2", "xing4", "solar", "ling"):
         traffic["recompute"] = True      # the adapter builds the fallback
     m = adapter.build_train(config, traffic, 7, 1, False)
     cb, step_args = dp_arith_check.caught_step(lambda: m["exe"].run(
@@ -249,6 +253,19 @@ def main():
         from paddle_tpu.ops import kda_ops
         return counted(kda_ops.KDA_LOWERINGS_CTR, "heads", "head_dim",
                        "chunk", "impl", "neg_eigval")
+
+    def kda_gate_lowerings():
+        """The step's kda_gate lowerings (its grad op's vjp and a recomputed
+        clone count) by the gate's form and the rank of what feeds it."""
+        from paddle_tpu.ops import kda_ops
+        return counted(kda_ops.KDA_GATE_LOWERINGS_CTR, "form", "rank")
+
+    def moe_groups():
+        """The step's moe_ffn forward lowerings (a recomputed clone counts)
+        by the experts routed over, those held and the routing groups."""
+        from paddle_tpu.ops import moe_ops
+        return counted(moe_ops.MOE_LOWERINGS_CTR, "experts", "held",
+                       "groups")
     if args.fingerprint:
         text = cb.jitted.lower(*shapes).as_text(debug_info=True)
         print(json.dumps({
@@ -317,6 +334,8 @@ def main():
         "rope_lowerings": rope_lowerings(),
         "hc_lowerings": hc_lowerings(),
         "kda_lowerings": kda_lowerings(),
+        "kda_gate_lowerings": kda_gate_lowerings(),
+        "moe_groups": moe_groups(),
         "parameters_m": sum(int(np.prod(p.shape))
                             for p in m["parameters"]) / 1e6}))
     return 0

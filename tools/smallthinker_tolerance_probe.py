@@ -28,7 +28,9 @@ column sums against 1 (``h_res_sums``: Sinkhorn-Knopp run in bf16).
 ``--cell solar`` (PR 49): the Solar-Open2 cell's (8 of 320 experts; a fourth
 kind of gradient leaf, the KDA layers' own parameters ``kda``; the
 reference's token-by-token recurrence run in bf16 is the control of the
-scan).
+scan).  ``--cell ling`` (PR 55): the Ling-3.0-flash cell's (8 of 512 experts
+under group-limited selection; a fifth kind, ``mla``: the latent-attention
+layer's norms and head gate).
 """
 
 import argparse
@@ -56,7 +58,9 @@ CELLS = {"smallthinker": ("smallthinker_21b_a3b", "lm_s16384",
                    {}),
          "solar": ("solar_open2_250b", "lm_s8192_r64", "solar_config",
                    "build_solar_open2_pretrain", "test_solar_open2_cell",
-                   "toy_solar", {"is_test": True})}
+                   "toy_solar", {"is_test": True}),
+         "ling": ("ling3_flash_vl", "lm_s8192_r64", "ling_config",
+                  "build_ling_pretrain", "test_ling3_cell", "toy_ling", {})}
 
 
 def main():
