@@ -37,7 +37,9 @@ Two lowerings, chosen from the input (``pallas/kda.py:fits`` beside
   (``kda_bwd``) that walk the chunks with the states in VMEM and make ``A``,
   ``P``, the decay differences and the solve there.  The forward writes
   ``States`` (the state before every chunk, float32), ``kda_scan_grad`` reads
-  it and runs no forward scan.
+  it and runs no forward scan: the backward kernel makes a chunk's tensors
+  again and runs the chunk's backward as derived by hand
+  (``pallas/kda.py:_chunk_back``), no ``jax.vjp`` inside.
 - ``xla`` everywhere else (the CPU, narrow toy heads): :func:`kda_chunked`,
   plain ``jax.numpy``.  ``A``, ``P``, the triangular solve and every product
   that has no ``S_0`` in it are batched matmuls over all chunks at once
@@ -257,8 +259,11 @@ register_op("kda_scan", _kda_scan, grad_maker=_kda_scan_grad_maker)
 def _kda_scan_grad(ctx, ins, attrs):
     """``kda_scan``'s backward from its five inputs, Out's gradient and the
     forward op's States.  ``pallas``: the backward kernel alone, which walks
-    the chunks from the last, starts each from ``States[n]`` and makes the
-    chunk's tensors again in VMEM; no forward scan runs here.  At [1, 8192,
+    the chunks from the last, starts each from ``States[n]``, makes the
+    chunk's tensors again in VMEM and goes back through the chunk by the
+    hand-derived equations of ``pallas/kda.py:_chunk_back`` (the transposed
+    solve by substitution, stacked products, no product for the decays'
+    cotangent); no forward scan runs here and no ``jax.vjp``.  At [1, 8192,
     8, 128] it reads Q, K, V and dOut (16.8 MB each in bf16), G (33.6 MB
     float32), Beta (0.26 MB) and States (67 MB) and writes the five
     gradients in their dtypes; it has no temporary in HBM.  ``xla``: nothing

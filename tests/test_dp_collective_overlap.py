@@ -497,22 +497,25 @@ def test_tpu_compiler_takes_the_two_product_kernels(topo, monkeypatch, case,
 
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("heads,neg_eigval", [(8, True), (16, False)],
+                         ids=["solar_open2", "ling3"])
 def test_tpu_compiler_takes_the_kda_kernels_at_the_cells_shapes(
-        topo, monkeypatch, dtype):
+        topo, monkeypatch, heads, neg_eigval, dtype):
     """``kda_scan`` + ``kda_scan_grad`` at Solar-Open2's [1, 8192, 8, 128]
-    in chunks of 64, beta doubled, lowered as on a TPU and compiled for one
-    described chip: Mosaic takes the forward kernel and the ``jax.vjp``
-    inside the backward one (interpret mode, ``tests/test_kda_kernel.py``,
-    says nothing about that), the grad op holds no forward kernel and no
-    loop, and beyond ``States`` (67 MB) the bf16 pair has no temporary in HBM
-    but the padded ``[t, 8]`` blocks of Beta and dBeta."""
+    with beta doubled and at Ling's [1, 8192, 16, 128] with beta as it
+    comes, in chunks of 64, lowered as on a TPU and compiled for one
+    described chip: Mosaic takes the forward kernel and the hand-written
+    backward one (interpret mode, ``tests/test_kda_kernel.py``, says nothing
+    about that), the grad op holds no forward kernel and no loop, and beyond
+    ``States`` (67 MB at 8 heads) the bf16 pair has no temporary in HBM but
+    the padded ``[t, heads]`` blocks of Beta and dBeta."""
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
     from paddle_tpu import device
     from paddle_tpu.ops import kda_ops
     monkeypatch.setattr(device, "on_tpu", lambda: True)
     ctx = types.SimpleNamespace(amp=False, is_abstract=True)
-    attrs = {"chunk": 64, "neg_eigval": True}
+    attrs = {"chunk": 64, "neg_eigval": neg_eigval}
     slots = ("Q", "K", "V", "G", "Beta")
 
     def forward(*prim):
@@ -536,9 +539,9 @@ def test_tpu_compiler_takes_the_kda_kernels_at_the_cells_shapes(
 
     def s(shape, dt):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one)
-    shape = (1, 8192, 8, 128)
+    shape = (1, 8192, heads, 128)
     x, g, beta = s(shape, dtype), s(shape, "float32"), s(shape[:3], "float32")
-    states = s((1, 8, 128, 128, 128), "float32")
+    states = s((1, heads, 128, 128, 128), "float32")
     with _no_compile_cache():
         both = jax.jit(step).lower(x, x, x, x, g, beta).compile()
         back = jax.jit(backward).lower(x, states, x, x, x, g, beta).compile()
@@ -548,5 +551,10 @@ def test_tpu_compiler_takes_the_kda_kernels_at_the_cells_shapes(
     assert "kda_fwd" not in back.as_text() and " while(" not in back.as_text()
     if dtype == "bfloat16":     # float32 streams are copied into the
         # [t, h d] tiling first; the cell's are bf16
-        assert both.memory_analysis().temp_size_in_bytes < (67 + 32) << 20
-        assert back.memory_analysis().temp_size_in_bytes < 16 << 20
+        # at 16 heads a call that stands alone also copies dQ, dK and dV
+        # (34 MB each) out of the kernel's [t, h d] tiling into the entry
+        # result's [t, h, d] one; at 8 heads that is a bitcast
+        copies = (3 * 34 << 20) * (heads == 16)
+        assert both.memory_analysis().temp_size_in_bytes < copies + (
+            67 * heads // 8 + 32 << 20)
+        assert back.memory_analysis().temp_size_in_bytes < copies + (16 << 20)

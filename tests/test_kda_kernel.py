@@ -2,7 +2,9 @@
 128-wide heads, against ``kda_chunked`` (the jnp form the op lowers to
 elsewhere) and against the recurrence token by token: Out, the chunk states
 and the five gradients; beta doubled and not, a ragged end, a strong decay,
-near-parallel keys, bf16 streams.  Then the op: who runs what (``fits``, the
+near-parallel keys, bf16 streams, four chunks (``dS`` over three boundaries),
+three heads of two sequences, Ling's bounded decays with beta as it comes,
+rows whose beta is 0.  Then the op: who runs what (``fits``, the
 counter's ``impl``), and a recomputed program whose grad op reads the states
 of the segment's own copy.  (The kernels compiled for a described v5e at the
 cell's shapes: ``tests/test_dp_collective_overlap.py``, the one file that
@@ -30,7 +32,7 @@ D = 128
 
 
 def _values(t, b=1, h=2, decay=0.3, parallel=False, strong=False, seed=0,
-            dtype=np.float32):
+            dtype=np.float32, bounded=False, beta_zero=False):
     r = np.random.RandomState(seed)
     shape = (b, t, h, D)
     v = {s: r.randn(*shape).astype(np.float32) for s in ("Q", "K", "V")}
@@ -41,8 +43,12 @@ def _values(t, b=1, h=2, decay=0.3, parallel=False, strong=False, seed=0,
     if strong:          # exp(-20 * 32) is 0 in float32 many times over
         v["G"][:, 40:72] = -20.0 - np.abs(r.randn(b, 32, h, D)).astype(
             np.float32)
+    if bounded:         # Ling's gate: -5 sigmoid(.), in (-5, 0)
+        v["G"] = (-5.0 / (1 + np.exp(-r.randn(*shape)))).astype(np.float32)
     logits = r.randn(b, t, h) + (2.0 if parallel else 0.0)
     v["Beta"] = (1 / (1 + np.exp(-logits))).astype(np.float32)
+    if beta_zero:       # a third of the positions write nothing
+        v["Beta"][r.rand(b, t, h) < 1 / 3] = 0.0
     v["W"] = r.randn(*shape).astype(np.float32)         # Out's cotangent
     for s in ("Q", "K", "V", "W"):
         v[s] = jnp.asarray(v[s], dtype)
@@ -84,6 +90,10 @@ CASES = {
     "strong": (128, {"strong": True, "seed": 3}, True),
     "parallel": (128, {"parallel": True, "decay": 0.02, "seed": 4}, True),
     "bf16": (128, {"dtype": jnp.bfloat16, "seed": 5}, True),
+    "four_chunks": (256, {"seed": 6}, True),
+    "two_by_three": (128, {"b": 2, "h": 3, "seed": 7}, True),
+    "bounded_once": (128, {"bounded": True, "seed": 8}, False),
+    "beta_zero": (128, {"beta_zero": True, "seed": 9}, True),
 }
 
 
@@ -138,6 +148,33 @@ def test_the_kernels_give_the_jnp_forms_and_the_recurrences_numbers(
     assert _rel(got, exact) <= tol, (case, what, "recurrence")
 
 
+@pytest.fixture(scope="module")
+def written_out():
+    """The ``ragged`` case (100 positions) with its 28 padded positions
+    written out as zeros: the five gradients of the 128."""
+    t, kw, neg = CASES["ragged"]
+    v = {s: jnp.pad(x, [(0, 0), (0, 128 - t)] + [(0, 0)] * (x.ndim - 2))
+         for s, x in _values(t, **kw).items()}
+    assert (np.asarray(v["Beta"])[:, t:] == 0).all()
+    ins = [v[s] for s in SLOTS]
+    opts = dict(chunk=CHUNK, neg_eigval=neg, interpret=True)
+    _, states = kda.kda_fwd(*ins, **opts)
+    return t, kda.kda_bwd(*ins, states, v["W"], **opts)
+
+
+@pytest.mark.parametrize("what", SLOTS)
+def test_zeros_behind_a_ragged_end_get_zero_gradients(what, written_out,
+                                                      runs):
+    """Behind the end every gradient is 0 to the bit (no key, no write, no
+    decay, no cotangent; and beta = 0 is divided by nowhere), and before it
+    the numbers are the ragged call's."""
+    t, grads = written_out
+    got = np.asarray(grads[SLOTS.index(what)])
+    assert (got[:, t:] == 0).all()
+    np.testing.assert_array_equal(
+        got[:, :t], np.asarray(runs["ragged"][0][2 + SLOTS.index(what)]))
+
+
 def test_the_strong_decay_is_strong_and_the_parallel_keys_parallel(runs):
     """What the two hard cases are made of: a quotient of cumulated decays
     would be 0 / 0 in the first, and in the second ``A``'s entries stand near
@@ -153,6 +190,29 @@ def test_the_strong_decay_is_strong_and_the_parallel_keys_parallel(runs):
     assert np.median(k @ k.T) > 0.99 and beta.max() > 1.8
     assert np.abs(np.linalg.matrix_power(a, 16)).max() > 1e12
     assert np.abs(np.linalg.inv(np.eye(CHUNK) + a)).max() < 4
+
+
+@pytest.mark.parametrize("fn,cotangents,products", [
+    ("_chunk", 0, 11), ("_chunk_back", 2, 27)])
+def test_every_product_of_a_chunk_is_at_highest_and_counted(fn, cotangents,
+                                                            products):
+    """What the kernels cost by: the chunk's products, forward eleven and
+    the hand-written backward 27 (nine to make the chunk's tensors again),
+    each float32 at ``highest``, and nothing differentiated by jax inside
+    either (no custom rule, no loop)."""
+    tile = jnp.zeros((CHUNK, D), jnp.float32)
+    args = [tile] * 4 + [jnp.zeros((CHUNK, 1)), jnp.zeros((D, D))] + [
+        tile, jnp.zeros((D, D))][:cotangents]
+    jaxpr = jax.make_jaxpr(functools.partial(getattr(kda, fn),
+                                             neg_eigval=True))(*args)
+    dots = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "dot_general"]
+    assert len(dots) == products
+    for e in dots:
+        assert e.params["preferred_element_type"] == jnp.float32
+        assert set(e.params["precision"]) == {jax.lax.Precision.HIGHEST}
+    names = {e.primitive.name for e in jaxpr.jaxpr.eqns}
+    assert not names & {"custom_vjp_call", "custom_vjp_call_jaxpr",
+                        "custom_jvp_call", "while", "scan"}
 
 
 @pytest.mark.parametrize("d_k,d_v,chunk,dtypes,ok", [

@@ -7,7 +7,13 @@ and five gradients are from the jnp form's on the same inputs, ``|x - x_ref|
 / |x_ref|``.  One JSON line a row.
 
     chiprun -- python3 tools/kda_kernel_probe.py
+    chiprun -- python3 tools/kda_kernel_probe.py --heads 16 --beta_once --bounded
     JAX_PLATFORMS=cpu python3 tools/kda_kernel_probe.py --aot
+
+The second is Ling's cell: 16 heads, beta not doubled, log-decays in (-5, 0).
+``kda_bwd_ms`` is a layer's backward; ``parent_kda_bwd_ms`` beside it is what
+the backward kernel read here while it took ``jax.vjp`` of the chunk (the
+commit before the hand-written one, same tool, same inputs).
 
 ``--aot``, no chip: both kernels compiled for a described v5e (what Mosaic
 refuses, it refuses here) and nothing run.  ``--decay 20``: log-decays near
@@ -25,6 +31,15 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 
+#: ``kda_bwd`` as ``jax.vjp`` of the chunk, ms a layer by this tool on a v5e
+#: (commit b023604; my chip runs, PR 56: the hand-written one read 3.819,
+#: 7.501 and 4.124 as handed in), by (seq, heads, head_dim, chunk,
+#: streams, beta doubled); the decays (0.05, bounded, 20) move neither side
+PARENT_BWD_MS = {(8192, 8, 128, 64, "bfloat16", True): 6.255,
+                 (8192, 16, 128, 64, "bfloat16", False): 12.372,
+                 (8192, 8, 128, 64, "float32", True): 6.548}
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--seq", type=int, default=8192)
@@ -34,6 +49,10 @@ def main():
     ap.add_argument("--dtype", default="bfloat16")
     ap.add_argument("--decay", type=float, default=0.05)
     ap.add_argument("--reps", type=int, default=10)
+    ap.add_argument("--beta_once", action="store_true",
+                    help="beta as it comes, not doubled (Ling)")
+    ap.add_argument("--bounded", action="store_true",
+                    help="log-decays -5 sigmoid(N(0, 1)), Ling's bounded gate")
     ap.add_argument("--aot", action="store_true")
     args = ap.parse_args()
     if args.aot:
@@ -43,7 +62,7 @@ def main():
     import numpy as np
     from paddle_tpu.ops import kda_ops
     from paddle_tpu.pallas import kda
-    kw = dict(chunk=args.chunk, neg_eigval=True)
+    kw = dict(chunk=args.chunk, neg_eigval=not args.beta_once)
     shape = (1, args.seq, args.heads, args.head_dim)
     dt = jnp.dtype(args.dtype)
     fwd = jax.jit(lambda *a: kda.kda_fwd(*a, **kw))
@@ -74,7 +93,8 @@ def main():
     assert on_tpu(), "no TPU: --aot compiles without one"
     r = np.random.RandomState(0)
     q, k, v, w = (jnp.asarray(r.randn(*shape), dt) for _ in range(4))
-    g = jnp.asarray(-np.abs(r.randn(*shape)) * args.decay, jnp.float32)
+    g = jnp.asarray(-5.0 / (1 + np.exp(-r.randn(*shape))) if args.bounded
+                    else -np.abs(r.randn(*shape)) * args.decay, jnp.float32)
     beta = jnp.asarray(1 / (1 + np.exp(-r.randn(*shape[:3]))), jnp.float32)
     ins = (q, k, v, g, beta)
     ref = functools.partial(kda_ops.kda_chunked, **kw)
@@ -104,8 +124,12 @@ def main():
     ms_rb, want_g = timed(ref_bwd, *ins)
     chunks = args.heads * -(-args.seq // args.chunk)
     print(json.dumps({
-        "shape": shape, "dtype": args.dtype, "decay": args.decay,
+        "shape": shape, "dtype": args.dtype, "neg_eigval": kw["neg_eigval"],
+        "decay": "bounded" if args.bounded else args.decay,
         "kda_fwd_ms": ms_f, "kda_bwd_ms": ms_b,
+        "parent_kda_bwd_ms": PARENT_BWD_MS.get(
+            (args.seq, args.heads, args.head_dim, args.chunk, args.dtype,
+             kw["neg_eigval"])),
         "us_a_chunk_and_head": [round(ms_f * 1e3 / chunks, 3),
                                 round(ms_b * 1e3 / chunks, 3)],
         "kda_chunked_ms": ms_rf, "kda_chunked_vjp_ms": ms_rb,
