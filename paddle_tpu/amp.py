@@ -60,6 +60,12 @@ BF16_IF_BIG = {
     # group-limited selection, the mask and the top-k) is float32 at full
     # precision inside whatever the rows' dtype (ops/moe_ops.py)
     "kda_scan",
+    # x, B and C in bf16; dt, A_log, D and dt_bias stay float32 and
+    # softplus(dt + dt_bias), the decay and everything inside the scan are
+    # float32 (ops/ssd_ops.py); gated_rms_norm is in no list: the gate's
+    # product and the groups' statistics are float32 inside whatever its
+    # inputs' dtype
+    "ssd_scan",
 }
 
 _COMPUTE = jnp.bfloat16
@@ -70,7 +76,8 @@ _FLOATS = (jnp.float32, jnp.bfloat16, jnp.float16)
 _SLOT_RESTRICT = {"batch_norm": {"X"}, "layer_norm": {"X"},
                   "group_norm": {"X"}, "rms_norm": {"X"},
                   "short_conv": {"X"}, "hc_pre": {"X"},
-                  "hc_post": {"X", "Y"}, "kda_scan": {"Q", "K", "V"}}
+                  "hc_post": {"X", "Y"}, "kda_scan": {"Q", "K", "V"},
+                  "ssd_scan": {"X", "B", "C"}}
 
 # NOTE: the analysis.fusion targets (fused_dense_act,
 # fused_embedding_layer_norm) appear in NO list above on purpose: one

@@ -1619,7 +1619,8 @@ def fused_lm_head_ce(x, size, label, param_attr=None, bias_attr=None,
     return loss
 
 
-def short_conv(x, filter_size=3, param_attr=None, name=None, gated=True):
+def short_conv(x, filter_size=3, param_attr=None, name=None, gated=True,
+               bias_attr=None):
     """The core of a gated short-convolution operator over ``x`` [b, t, 3 d],
     an input projection split in three ``B | C | u``: ``C * conv(B * u)``
     with ``conv`` a causal depthwise convolution of ``filter_size`` taps over
@@ -1630,7 +1631,10 @@ def short_conv(x, filter_size=3, param_attr=None, name=None, gated=True):
 
     ``gated=False``: ``silu(conv(x))`` over ``x`` [b, t, d] as it is (the
     convolution in front of a linear-attention layer's Q, K and V); the
-    filter is [d, filter_size] again."""
+    filter is [d, filter_size] again.  ``bias_attr`` (ungated only; None: no
+    bias, the op as it was): a [d] bias, zero at first, added to the
+    convolution before the SiLU, ``silu(conv(x) + b)`` (a state-space
+    mixer's ``use_conv_bias``)."""
     helper = LayerHelper("short_conv", name=name)
     d = int(x.shape[-1])
     attrs = {}
@@ -1642,9 +1646,16 @@ def short_conv(x, filter_size=3, param_attr=None, name=None, gated=True):
         attrs = {"gated": False}
     w = helper.create_parameter(param_attr, shape=[d, int(filter_size)],
                                 dtype=x.dtype)
+    inputs = {"X": [x], "Filter": [w]}
+    if bias_attr is not None:
+        if gated:
+            raise ValueError("short_conv: a bias belongs to the ungated form")
+        inputs["Bias"] = [helper.create_parameter(
+            bias_attr, shape=[d], dtype=x.dtype,
+            default_initializer=ConstantInitializer(0.0))]
     out = helper.create_variable_for_type_inference(x.dtype)
-    helper.append_op("short_conv", inputs={"X": [x], "Filter": [w]},
-                     outputs={"Out": [out]}, attrs=attrs)
+    helper.append_op("short_conv", inputs=inputs, outputs={"Out": [out]},
+                     attrs=attrs)
     return out
 
 
@@ -1717,6 +1728,52 @@ def kda_scan(q, k, v, g, beta, chunk=64, neg_eigval=False, name=None):
                             "Beta": [beta]},
         outputs={"Out": [out], "States": [states]},
         attrs={"chunk": int(chunk), "neg_eigval": bool(neg_eigval)})
+    return out
+
+
+def ssd_scan(x, dt, a_log, b, c, d, dt_bias=None, chunk=128, name=None):
+    """Mamba-2's state-space recurrence in its chunked (SSD) form
+    (``ssd_scan`` op; ``ops/ssd_ops.py`` has the equations): per head a ``P
+    x N`` state, ``S_t = exp(Delta_t A) S_{t-1} + Delta_t x_t B_t^T``, ``y_t
+    = S_t C_t + D x_t``, from zero, ``A = -exp(a_log)`` a scalar a head.
+    ``x`` [b, t, H, P]; ``dt`` [b, t, H]; ``a_log``, ``d`` and ``dt_bias``
+    [H] (variables: the model's parameters); ``b``, ``c`` [b, t, G, N], head
+    ``h`` reading group ``h // (H / G)``.  With ``dt_bias`` the step is
+    ``Delta = softplus(dt + dt_bias)``, computed inside in float32; without
+    it ``dt`` is the step itself.  Run in chunks of ``chunk`` positions (a
+    ``t`` that is no multiple is padded inside), float32 inside whatever AMP
+    says; returns [b, t, H, P] in x's dtype.  States, the op's second output
+    (the float32 state before every chunk), is what ``ssd_scan_grad`` starts
+    each chunk from; it carries no gradient."""
+    helper = LayerHelper("ssd_scan", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    states = helper.create_variable_for_type_inference("float32", True)
+    inputs = {"X": [x], "Dt": [dt], "ALog": [a_log], "B": [b], "C": [c],
+              "D": [d]}
+    if dt_bias is not None:
+        inputs["DtBias"] = [dt_bias]
+    helper.append_op("ssd_scan", inputs=inputs,
+                     outputs={"Out": [out], "States": [states]},
+                     attrs={"chunk": int(chunk)})
+    return out
+
+
+def gated_rms_norm(x, z, groups=1, epsilon=1e-5, param_attr=None, name=None):
+    """``w * rms_g(x * silu(z))`` over [.., d]: the gate first, then the RMS
+    over each of ``groups`` consecutive groups of ``d / groups`` channels,
+    then one learned scale a channel (``w`` [d], ones at first).  Float32
+    inside (``gated_rms_norm`` op, ``ops/ssd_ops.py``); returns x's dtype."""
+    helper = LayerHelper("gated_rms_norm", name=name)
+    d = int(x.shape[-1])
+    if d % int(groups):
+        raise ValueError(f"{d} channels in {groups} groups")
+    w = helper.create_parameter(param_attr, shape=[d], dtype=x.dtype,
+                                default_initializer=ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op("gated_rms_norm",
+                     inputs={"X": [x], "Z": [z], "Scale": [w]},
+                     outputs={"Y": [out]},
+                     attrs={"groups": int(groups), "epsilon": float(epsilon)})
     return out
 
 
@@ -1827,7 +1884,7 @@ def moe_ffn(x, num_experts, top_k, d_expert, norm_topk_prob=False,
             param_prefix="moe", initializer=None, name=None,
             score_func="softmax", select_bias=False, norm_eps=0.0,
             route_scale=1.0, num_held=None, expert_offset=0, act="silu",
-            router_x=None, n_group=1, topk_group=1):
+            router_x=None, n_group=1, topk_group=1, gated=True):
     """Dropless top-k mixture of gated experts (SiLU on the gate branch, or
     ReLU with ``act="relu"``) over [b, t, d] input
     (``moe_ffn`` op: sorted rows + grouped matmuls, no capacity, no dropped
@@ -1854,10 +1911,15 @@ def moe_ffn(x, num_experts, top_k, d_expert, norm_topk_prob=False,
     the router scores; the output is their part of the layer's.
     ``router_x`` [b, t, d]: what the router reads where that is not ``x`` (a
     router placed before attention); the experts read ``x`` either way, and
-    the router's gradient goes to ``router_x`` alone."""
+    the router's gradient goes to ``router_x`` alone.  ``gated=False`` with
+    ``act="relu2"``: un-gated experts, ``Wd relu(Wu x)^2``; the op then holds
+    ``up.w`` and ``down.w`` and no third weight, and runs two grouped matmuls
+    where a gated expert has three."""
     from ..param_attr import ParamAttr
-    if act not in ("silu", "relu"):     # at build, not at the first run
-        raise ValueError(f"moe_ffn act {act!r}")
+    # at build, not at the first run
+    if act not in (("silu", "relu") if gated else ("relu2",)):
+        raise ValueError(f"moe_ffn act {act!r}"
+                         + ("" if gated else " of un-gated experts"))
     helper = LayerHelper("moe_ffn", name=name)
     d = int(x.shape[-1])
     E, F = int(num_experts), int(d_expert)
@@ -1871,10 +1933,11 @@ def moe_ffn(x, num_experts, top_k, d_expert, norm_topk_prob=False,
         return v
 
     ep = ("ep", None, None)
-    inputs = {"X": [x], "RouterW": [_p("router.w", [d, E], None)],
-              "GateW": [_p("gate.w", [H, d, F], ep)],
-              "UpW": [_p("up.w", [H, d, F], ep)],
-              "DownW": [_p("down.w", [H, F, d], ep)]}
+    inputs = {"X": [x], "RouterW": [_p("router.w", [d, E], None)]}
+    if gated:
+        inputs["GateW"] = [_p("gate.w", [H, d, F], ep)]
+    inputs.update(UpW=[_p("up.w", [H, d, F], ep)],
+                  DownW=[_p("down.w", [H, F, d], ep)])
     if router_x is not None:
         inputs["RouterX"] = [router_x]
     if select_bias:
@@ -1890,7 +1953,7 @@ def moe_ffn(x, num_experts, top_k, d_expert, norm_topk_prob=False,
     # what moe_ffn_grad reuses: sort order, sorted rows, gate and up
     # projections, the experts' output
     saved = [helper.create_variable_for_type_inference(t, True)
-             for t in ("int32", x.dtype, x.dtype, x.dtype, x.dtype)]
+             for t in ("int32",) + (x.dtype,) * (4 if gated else 3)]
     attrs = {"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob)}
     if score_func != "softmax":
         attrs["score_func"] = str(score_func)

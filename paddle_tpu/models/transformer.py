@@ -1575,6 +1575,201 @@ def build_ling_pretrain(cfg: LingConfig, seq_len, fused_head=True,
     return (src_ids, lm_label), {"expert_load": loads, "hidden": x}, loss
 
 
+# -- Nemotron-H: Mamba-2 (SSD) mixers, position-free grouped-query attention --
+# -- and un-gated ReLU^2 experts, ONE sublayer a block ------------------------
+
+class NemotronHConfig:
+    """NVIDIA-Nemotron-3-Nano-30B-A3B defaults (``nvidia/NVIDIA-Nemotron-3-
+    Nano-30B-A3B-BF16`` config.json, ``model_type`` ``nemotron_h``; Mamba-2:
+    arXiv:2405.21060, Nemotron-H: arXiv:2504.03624).  ``pattern`` (the
+    row's ``hybrid_override_pattern``) gives each block's ONE sublayer by
+    its letter: ``M`` a Mamba-2 mixer (:func:`mamba2_mixer`: ``n_mamba_head``
+    heads of ``d_mamba_head``, ``n_group`` groups sharing ``B`` / ``C`` of
+    ``d_state``, ``conv_taps`` taps with a bias, chunks of ``chunk``), ``*``
+    causal grouped-query softmax attention with NO positional term, no gate
+    and no QK-norm (``n_head`` over ``n_kv_head`` heads of ``d_head``), ``E``
+    ``n_experts`` routed un-gated ReLU^2 experts of width ``d_expert``
+    (``top_k`` a token, sigmoid scores, a selection bias, the kept scores
+    renormalised and scaled) beside one shared expert of the same form at
+    ``d_shared``.  ``n_held``/``expert_offset``: the experts held, as
+    :class:`TrinityConfig`."""
+
+    def __init__(self, vocab_size=131072, d_model=2688,
+                 pattern="MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*"
+                         "EMEMEMEME",
+                 n_mamba_head=64, d_mamba_head=64, n_group=8, d_state=128,
+                 conv_taps=4, chunk=128, n_head=32, n_kv_head=2, d_head=128,
+                 d_expert=1856, d_shared=3712, n_experts=128, top_k=6,
+                 route_scale=2.5, rms_eps=1e-5, n_held=None, expert_offset=0,
+                 init_std=0.02):
+        if set(pattern) - set("ME*"):
+            raise ValueError(f"pattern {pattern!r}: M, E and * are built")
+        self.vocab_size = vocab_size
+        self.d_model = d_model
+        self.pattern = pattern
+        self.n_layer = len(pattern)
+        self.n_mamba_head = n_mamba_head
+        self.d_mamba_head = d_mamba_head
+        self.n_group = n_group
+        self.d_state = d_state
+        self.conv_taps = conv_taps
+        self.chunk = chunk
+        self.n_head = n_head
+        self.n_kv_head = n_kv_head
+        self.d_head = d_head
+        self.d_expert = d_expert
+        self.d_shared = d_shared
+        self.n_experts = n_experts
+        self.top_k = top_k
+        self.route_scale = route_scale
+        self.rms_eps = rms_eps
+        self.n_held = n_experts if n_held is None else n_held
+        self.expert_offset = expert_offset
+        self.init_std = init_std
+
+
+def mamba2_mixer(x, cfg: NemotronHConfig, param_prefix="mamba"):
+    """One Mamba-2 sublayer over ``x`` [b, t, d_model] (the block's normed
+    input), every op under the ``mamba`` tag; ``H = cfg.n_mamba_head`` heads
+    of ``P = cfg.d_mamba_head`` (``d_inner = H P``), ``G = cfg.n_group``
+    groups, ``N = cfg.d_state``; no bias on a projection::
+
+        [z | xBC | dt] = x W_in           W_in [d_model, 2 H P + 2 G N + H]
+        xBC = silu(conv(xBC) + b)         depthwise, causal, conv_taps
+        [x | B | C] = xBC                 H P | G N | G N
+        y = ssd_scan(x, dt, A_log, B, C, D, dt_bias)
+                                          Delta = softplus(dt + dt_bias),
+                                          decay exp(-Delta exp(A_log))
+        out = W_out [w * rms_G(y * silu(z))]   the gate first, the RMS over
+                                          each of the G groups of H P / G
+
+    ``<prefix>.in_proj.w``; ``<prefix>.conv.filter`` [H P + 2 G N, taps]
+    (uniform in ``+-taps^-0.5``) and ``<prefix>.conv.bias`` (zero);
+    ``<prefix>.A_log`` = ``log(1 .. H)`` (``modeling_nemotron_h``'s
+    ``arange``), ``<prefix>.D`` ones, ``<prefix>.dt_bias`` the inverse
+    softplus of ``exp U(log 1e-3, log 1e-1)`` floored at 1e-4, drawn here
+    from the parameter's name and not by the startup program's seed;
+    ``<prefix>.norm.w`` [H P]; ``<prefix>.out.w`` [H P, d_model]."""
+    import zlib
+    from ..initializer import NumpyArrayInitializer, UniformInitializer
+    h, p, g, n = (cfg.n_mamba_head, cfg.d_mamba_head, cfg.n_group,
+                  cfg.d_state)
+    d_in = h * p
+
+    def proj(v, size, name):
+        return layers.fc(v, size=size, num_flatten_dims=2, bias_attr=False,
+                         param_attr=ParamAttr(name=f"{param_prefix}.{name}.w"))
+
+    def per_head(name, values):
+        return layers.create_parameter(
+            [h], "float32", attr=ParamAttr(
+                name=f"{param_prefix}.{name}",
+                initializer=NumpyArrayInitializer(
+                    np.asarray(values, np.float32))))
+
+    rng = np.random.RandomState(zlib.crc32(param_prefix.encode()))
+    dt0 = np.maximum(np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), h)), 1e-4)
+    with name_scope("mamba"):
+        z, xbc, dt = layers.split(proj(x, 2 * d_in + 2 * g * n + h, "in_proj"),
+                                  [d_in, d_in + 2 * g * n, h], dim=2)
+        bound = float(cfg.conv_taps) ** -0.5
+        xbc = layers.short_conv(
+            xbc, cfg.conv_taps, gated=False,
+            param_attr=ParamAttr(name=f"{param_prefix}.conv.filter",
+                                 initializer=UniformInitializer(-bound,
+                                                                bound)),
+            bias_attr=ParamAttr(name=f"{param_prefix}.conv.bias"))
+        xs, b, c = layers.split(xbc, [d_in, g * n, g * n], dim=2)
+        y = layers.ssd_scan(
+            layers.reshape(xs, shape=[0, 0, h, p]), dt,
+            per_head("A_log", np.log(np.arange(1, h + 1))),
+            layers.reshape(b, shape=[0, 0, g, n]),
+            layers.reshape(c, shape=[0, 0, g, n]),
+            per_head("D", np.ones(h)),
+            per_head("dt_bias", dt0 + np.log(-np.expm1(-dt0))),
+            chunk=cfg.chunk)
+        y = layers.gated_rms_norm(
+            layers.reshape(y, shape=[0, 0, d_in]), z, groups=g,
+            epsilon=cfg.rms_eps,
+            param_attr=ParamAttr(name=f"{param_prefix}.norm.w"))
+        return proj(y, cfg.d_model, "out")
+
+
+def relu2_ffn(x, d_inner, d_model, param_prefix="ffn"):
+    """``down(relu(up(x))^2)`` out of the dense ops, no gate branch, no bias
+    (``<prefix>.up.w``, ``<prefix>.down.w``)."""
+    u = layers.fc(x, size=d_inner, num_flatten_dims=2, bias_attr=False,
+                  param_attr=ParamAttr(name=f"{param_prefix}.up.w"))
+    return layers.fc(layers.square(layers.relu(u)), size=d_model,
+                     num_flatten_dims=2, bias_attr=False,
+                     param_attr=ParamAttr(name=f"{param_prefix}.down.w"))
+
+
+def nemotron_h_block(x, cfg: NemotronHConfig, idx=0, attn_impl="flash"):
+    """One nemotron_h block: ONE sublayer, pre-norm, no bias: ``out = x +
+    Mixer(RMS(x))`` and no second half, ``Mixer`` by ``cfg.pattern[idx]``:
+    ``M`` :func:`mamba2_mixer`; ``*`` :func:`multi_head_attention` at
+    ``n_head`` over ``n_kv_head`` heads with no position, gate or QK-norm
+    (the ``attn`` tag); ``E`` the shared expert (:func:`relu2_ffn` at
+    ``d_shared``, the ``shared_expert`` tag) plus ``moe_ffn`` with un-gated
+    ReLU^2 experts, sigmoid scores, a selection bias held at zero, the kept
+    scores renormalised (``+ 1e-20``) and scaled.  Returns ``(out,
+    expert_load or None)``."""
+    from ..initializer import NormalInitializer
+    p, kind = f"dec_{idx}", cfg.pattern[idx]
+    n = layers.rms_norm(x, begin_norm_axis=2, epsilon=cfg.rms_eps,
+                        param_attr=ParamAttr(name=f"{p}.norm.w"))
+    if kind == "M":
+        return x + mamba2_mixer(n, cfg, f"{p}.mamba"), None
+    if kind == "*":
+        with name_scope("attn"):
+            return x + multi_head_attention(
+                n, n, n, cfg.d_model, cfg.n_head, param_prefix=f"{p}.attn",
+                attn_impl=attn_impl, causal=True, bias=False,
+                n_kv_head=cfg.n_kv_head, d_head=cfg.d_head), None
+    with name_scope("shared_expert"):
+        f = relu2_ffn(n, cfg.d_shared, cfg.d_model, f"{p}.shared")
+    moe, _, _, load = layers.moe_ffn(
+        n, cfg.n_experts, cfg.top_k, cfg.d_expert, norm_topk_prob=True,
+        param_prefix=f"{p}.moe",
+        initializer=NormalInitializer(0.0, cfg.init_std),
+        score_func="sigmoid", select_bias=True, norm_eps=1e-20,
+        route_scale=cfg.route_scale, num_held=cfg.n_held,
+        expert_offset=cfg.expert_offset, act="relu2", gated=False)
+    return x + f + moe, load
+
+
+def build_nemotron_h_pretrain(cfg: NemotronHConfig, seq_len, fused_head=True,
+                              checkpoints=None, attn_impl="flash"):
+    """Causal LM over :func:`nemotron_h_block` blocks: ids -> embedding ->
+    ``len(cfg.pattern)`` one-sublayer blocks -> final RMSNorm -> untied
+    bias-free head; loss = mean next-token CE and nothing else (the
+    selection bias is held at zero and there is no auxiliary term).
+    ``checkpoints=[]`` collects the block boundaries for
+    ``RecomputeOptimizer``: the embedding's output and every block's, Mamba,
+    attention or expert alike, as :func:`build_solar_open2_pretrain`.
+    Returns ``(feeds, parts, loss)`` with ``parts`` = {"expert_load": [per
+    expert block], "hidden": the final norm's output}."""
+    src_ids = layers.data("src_ids", shape=[seq_len], dtype="int64")
+    lm_label = layers.data("lm_label", shape=[seq_len], dtype="int64")
+    x = layers.embedding(src_ids, size=[cfg.vocab_size, cfg.d_model],
+                         param_attr=ParamAttr(name="word_embedding"))
+    loads = []
+    if checkpoints is not None:
+        checkpoints.append(x)
+    for i in range(cfg.n_layer):
+        x, load = nemotron_h_block(x, cfg, i, attn_impl)
+        if load is not None:
+            loads.append(load)
+        if checkpoints is not None:
+            checkpoints.append(x)
+    x = layers.rms_norm(x, begin_norm_axis=2, epsilon=cfg.rms_eps,
+                        param_attr=ParamAttr(name="final_norm.w"))
+    _, loss = _lm_head_loss(x, cfg, lm_label, fused_head, "lm_out",
+                            bias=False)
+    return (src_ids, lm_label), {"expert_load": loads, "hidden": x}, loss
+
+
 def annotate_tensor_parallel(program=None):
     """Megatron-style TP layout via dist_spec (SURVEY §2.5: TP is a
     capability the reference LACKS — first-class here)."""

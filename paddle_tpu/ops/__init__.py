@@ -10,5 +10,6 @@ from . import fused_ops  # noqa  (analysis.fusion rewrite targets)
 from . import moe_ops  # noqa
 from . import hc_ops  # noqa
 from . import kda_ops  # noqa
+from . import ssd_ops  # noqa
 from . import compat_ops  # noqa  (must come last: aliases existing ops)
 from ..framework.registry import registered_ops  # noqa

@@ -65,6 +65,11 @@ was before they existed, bit for bit):
 - ``act``: the gate branch's activation, ``silu`` (default) or ``relu``
   (``relu(Wg x) * Wu x``, "ReGLU"), forward and backward, with every expert
   held and on every rung of a share's ladder.
+- no ``GateW`` input, with ``act="relu2"`` (``layers.moe_ffn(gated=False)``):
+  un-gated experts, ``Wd_e relu(Wu_e x)^2`` (Nemotron-H's): TWO grouped
+  matmuls a pass where a gated expert has three, ``Saved`` holds one
+  projection, and the backward passes ``2 relu(u)`` through the square;
+  sorted and held paths, every rung.  With GateW the op is as it was.
 - input ``RouterX`` [B, T, d]: what the router reads where that is not what
   the experts read (a router placed before attention scores the layer's
   input; the experts get the post-attention rows).  Only the ``router``
@@ -186,9 +191,11 @@ MOE_LOWERINGS_CTR = _monitor.REGISTRY.counter(
     "backward's gather back to tokens) cost by: rows, a share's held rows "
     "alone (pallas/held_rows.py), or slots, XLA's gather over every slot; "
     "groups = n_group/topk_group of the router's group-limited selection, "
-    "1/1 where every expert competes with every other",
+    "1/1 where every expert competes with every other; gated = 1 where an "
+    "expert is Wd (act(Wg x) * Wu x), three grouped matmuls, 0 where it is "
+    "Wd act(Wu x), two",
     ("impl", "experts", "top_k", "held", "score_func", "ladder", "act",
-     "router_input", "unsort", "groups"))
+     "router_input", "unsort", "groups", "gated"))
 
 
 MOE_ROUTED_ROWS_CTR = _monitor.REGISTRY.counter(
@@ -280,17 +287,29 @@ def _grouped_matmul(dt, impl=None, tiling=None):
 _ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
-def _act_of(attrs):
+def _act_of(attrs, gated=True):
+    """The op's activation: gated experts (the op has a GateW) have the two
+    forms of ``_ACTS``, un-gated experts the one form ``relu2``."""
     act = attrs.get("act", "silu") or "silu"
-    if act not in _ACTS:
-        raise ValueError(f"moe_ffn act {act!r}")
+    if act not in (_ACTS if gated else ("relu2",)):
+        raise ValueError(f"moe_ffn act {act!r} of "
+                         f"{'gated' if gated else 'un-gated'} experts")
     return act
 
 
 def _gate(g, u, dt, act="silu"):
-    """``act(g) * u`` in float32, stored in ``dt``."""
+    """``act(g) * u`` in float32, stored in ``dt``; un-gated experts (``g``
+    None): ``relu(u)^2``."""
+    if g is None:
+        return jnp.square(jax.nn.relu(u.astype(jnp.float32))).astype(dt)
     return (_ACTS[act](g.astype(jnp.float32)) * u.astype(jnp.float32)
             ).astype(dt)
+
+
+def _head(a, rows):
+    """The first ``rows`` rows of ``a``; None stays None (un-gated experts
+    have no gate branch)."""
+    return None if a is None else a[:rows]
 
 
 def gated_experts(xs, wg, wu, wd, load, dt, impl=None, tiling=None,
@@ -300,9 +319,11 @@ def gated_experts(xs, wg, wu, wd, load, dt, impl=None, tiling=None,
     accumulation and the gate's arithmetic in float32.  Returns ``(y, g,
     u)``: the result and the two projections a backward needs.  ``gate(g,
     u, dt)``: :func:`_gate` (SiLU) unless the caller binds another ``act``, or
-    the held path's, which passes over a rung's rows only."""
+    the held path's, which passes over a rung's rows only.  ``wg`` None:
+    un-gated experts, ``Wd_e gate(None, Wu_e x)``, two products and ``g``
+    None."""
     mm = _grouped_matmul(dt, impl, tiling)
-    g = mm(xs, wg, load)
+    g = None if wg is None else mm(xs, wg, load)
     u = mm(xs, wu, load)
     return mm(gate(g, u, dt), wd, load), g, u
 
@@ -481,13 +502,18 @@ def _gate_front(ladder, held_rows, g, u, dt, act="silu"):
     """``_gate`` over the rows of the rung that holds ``held_rows``, at the
     front of a buffer of the longest rung's length."""
     return _over_rungs(ladder, held_rows, lambda rows, g, u: _front(
-        _gate(g[:rows], u[:rows], dt, act), ladder[-1]), g, u)
+        _gate(_head(g, rows), u[:rows], dt, act), ladder[-1]), g, u)
 
 
 def _gate_backward(g, u, dh, dt, act="silu"):
     """``(dg, du)`` of ``_gate(g, u)`` given ``dh``: float32 arithmetic,
-    stored in ``dt``.  ReLU: ``dg = dh u [g > 0]``, ``du = dh relu(g)``."""
+    stored in ``dt``.  ReLU: ``dg = dh u [g > 0]``, ``du = dh relu(g)``.
+    Un-gated experts (``g`` None): ``(None, dh 2 relu(u))``, the square's
+    slope."""
     f32 = jnp.float32
+    if g is None:
+        return None, (dh.astype(f32) * 2.0 * jax.nn.relu(u.astype(f32))
+                      ).astype(dt)
     gf, uf, dhf = g.astype(f32), u.astype(f32), dh.astype(f32)
     if act == "relu":
         return (jnp.where(gf > 0, dhf * uf, 0.0).astype(dt),
@@ -570,19 +596,23 @@ def _moe_ffn(ctx, ins, attrs):
     source on a TPU: ``pallas/held_rows.py`` reads the held rows alone
     (``_rows_unsort``; elsewhere it is a gather over every slot, a copy a
     rung in a switch of its own).  Under AMP the rows and the expert weights
-    are bf16 with float32 accumulation."""
+    are bf16 with float32 accumulation.
+
+    Without GateW (``act`` ``relu2``): un-gated experts, ``Out = sum p_e
+    Wd_e relu(Wu_e x)^2``; two grouped matmuls under ``experts``, and Saved
+    holds the one projection."""
     x, wr = X(ins, "X"), X(ins, "RouterW")
     wg, wu, wd = X(ins, "GateW"), X(ins, "UpW"), X(ins, "DownW")
     k = int(attrs["top_k"])
     B, T, d = x.shape
-    E, n_held = wr.shape[-1], wg.shape[0]
+    E, n_held = wr.shape[-1], wu.shape[0]
     offset = int(attrs.get("expert_offset", 0) or 0)
     if offset < 0 or offset + n_held > E:
         raise ValueError(f"moe_ffn holds experts {offset}..{offset + n_held}"
                          f" of a router over {E}")
     S = B * T
     dt = _moe_dtype(ctx, x)
-    act = _act_of(attrs)
+    act = _act_of(attrs, wg is not None)
     router_x = X(ins, "RouterX")
     ladder = () if n_held == E else held_ladder(S, k, n_held, E)
     unsort = _rows_unsort(S, k, d, ladder, dt) if ladder else None
@@ -595,7 +625,8 @@ def _moe_ffn(ctx, ins, attrs):
             router_input="x" if router_x is None else "own",
             unsort="slots" if unsort is None else "rows",
             groups=f"{int(attrs.get('n_group', 1) or 1)}/"
-                   f"{int(attrs.get('topk_group', 1) or 1)}")
+                   f"{int(attrs.get('topk_group', 1) or 1)}",
+            gated="0" if wg is None else "1")
         if ladder:
             _TRACED_LADDERS[S * k, E, n_held] = ladder
     xt = x.reshape(S, d)
@@ -654,13 +685,14 @@ def _moe_ffn(ctx, ins, attrs):
     return {"Out": [out.astype(x.dtype).reshape(B, T, d)], "LbLoss": [lb],
             "ZLoss": [z], "ExpertLoad": [load],
             "TopExperts": [top_e.astype(jnp.int32).reshape(B, T, k)],
-            "Saved": [order, xs, g, u, y]}
+            "Saved": [a for a in (order, xs, g, u, y) if a is not None]}
 
 
 def _moe_ffn_grad_maker(op, block, no_grad_set):
     def grads(names):
         return [grad_var_name(n) for n in names]
-    slots = ("X", "RouterW", "GateW", "UpW", "DownW")
+    slots = tuple(s for s in ("X", "RouterW", "GateW", "UpW", "DownW")
+                  if op.input(s))
     g_inputs = {"X$" + s: op.input(s) for s in slots}
     if op.input("SelectBias"):
         g_inputs["X$SelectBias"] = op.input("SelectBias")
@@ -697,7 +729,10 @@ def _moe_ffn_grad(ctx, ins, attrs):
     zero."""
     x, wr = X(ins, "X$X"), X(ins, "X$RouterW")
     weights = [X(ins, "X$" + s) for s in ("GateW", "UpW", "DownW")]
-    order, xs, g, u, y = ins["Saved"]
+    if weights[0] is None:      # un-gated experts: no gate branch was saved
+        (order, xs, u, y), g = ins["Saved"], None
+    else:
+        order, xs, g, u, y = ins["Saved"]
     d_out, d_lb, d_z = (X(ins, "OG$" + s) for s in ("Out", "LbLoss", "ZLoss"))
     k = int(attrs["top_k"])
     B, T, d = x.shape
@@ -705,8 +740,8 @@ def _moe_ffn_grad(ctx, ins, attrs):
     f32, dt = jnp.float32, xs.dtype
     xt = x.reshape(S, d)
     router_x = X(ins, "X$RouterX")
-    act = _act_of(attrs)
-    E, n_held = wr.shape[-1], weights[0].shape[0]
+    act = _act_of(attrs, weights[0] is not None)
+    E, n_held = wr.shape[-1], weights[1].shape[0]
     mm = _grouped_matmul(dt, tiling=None if n_held == E else _GMM_TILING_HELD)
     offset = int(attrs.get("expert_offset", 0) or 0)
 
@@ -744,7 +779,8 @@ def _moe_ffn_grad(ctx, ins, attrs):
                 dxs_u, d_wu = transposed(xs, wu, (dhf * gf * sig).astype(dt))
             else:
                 dg, du = _gate_backward(g, u, dh, dt, act)
-                dxs_g, d_wg = transposed(xs, wg, dg)
+                dxs_g, d_wg = (0, None) if g is None else \
+                    transposed(xs, wg, dg)
                 dxs_u, d_wu = transposed(xs, wu, du)
 
         with jax.named_scope("dispatch"):
@@ -835,17 +871,22 @@ def _moe_ffn_grad(ctx, ins, attrs):
                 dh, d_wd = jax.lax.optimization_barrier((dh, d_wd))
             dg, du = _over_rungs(
                 ladder, held_rows, lambda rows, g, u, dh: tuple(
-                    _front(a, full) for a in _gate_backward(
-                        g[:rows], u[:rows], dh[:rows], dt, act)), g, u, dh)
-            if unsort is None:
+                    None if a is None else _front(a, full)
+                    for a in _gate_backward(_head(g, rows), u[:rows],
+                                            dh[:rows], dt, act)), g, u, dh)
+            if g is None:           # un-gated: the one projection's transpose
+                dxs_u, d_wu = transposed(xs, wu, du)
+                dxs, d_wg = (dxs_u,), None
+            elif unsort is None:
                 dxs_g, d_wg = transposed(xs, wg, dg)
                 dxs_u, d_wu = transposed(xs, wu, du)
+                dxs = (dxs_g, dxs_u)
             else:
                 d_wg, d_wu, dg, du = jax.lax.optimization_barrier(
                     (transposed(xs, wg, dg)[1], transposed(xs, wu, du)[1],
                      dg, du))
-                dxs_g, dxs_u = (transposed(xs, w, c)[0]
-                                for w, c in ((wg, dg), (wu, du)))
+                dxs = tuple(transposed(xs, w, c)[0]
+                            for w, c in ((wg, dg), (wu, du)))
 
         def back_to_tokens(rows, dxs, place, slot_held):
             # gathered as stored, widened after: the same numbers as
@@ -864,18 +905,21 @@ def _moe_ffn_grad(ctx, ins, attrs):
                 # tools/joyai_step_aot.py's reads_after_update, PERF.md
                 # section 6, PR 35)
                 dx = _over_rungs(ladder, held_rows, back_to_tokens,
-                                 dxs_g + dxs_u, place, slot_held)
+                                 functools.reduce(jnp.add, dxs), place,
+                                 slot_held)
             else:
                 # each held row's two parts added as it is read: no pass
                 # over the whole buffer, and no switch
-                dx = unsort((dxs_g, dxs_u), place, slot_held, k)
+                dx = unsort(dxs, place, slot_held, k)
 
     with jax.named_scope("router"):
         zero = jnp.zeros((), f32)
         dx_r, d_wr = router_vjp((d_top_p, zero if d_lb is None else d_lb,
                                  zero if d_z is None else d_z))
-    out = {"IG$RouterW": [d_wr.astype(wr.dtype)], "IG$GateW": [d_wg],
-           "IG$UpW": [d_wu], "IG$DownW": [d_wd]}
+    out = {"IG$RouterW": [d_wr.astype(wr.dtype)], "IG$UpW": [d_wu],
+           "IG$DownW": [d_wd]}
+    if d_wg is not None:
+        out["IG$GateW"] = [d_wg]
     if router_x is None:
         out["IG$X"] = [(dx + dx_r).astype(x.dtype).reshape(B, T, d)]
     else:

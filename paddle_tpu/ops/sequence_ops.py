@@ -197,17 +197,20 @@ SHORT_CONV_LOWERINGS_CTR = _monitor.REGISTRY.counter(
     "paddle_tpu_short_conv_lowerings_total",
     "short_conv and short_conv_grad lowerings by the filter's taps, whether "
     "the convolution is gated (LFM2: C * conv(B * u), act none) or not "
-    "(KDA: silu(conv(x)), act silu) — counted while tracing, once per "
-    "compile of a program that holds the op", ("taps", "gated", "act"))
+    "(KDA: silu(conv(x)), act silu) and whether the ungated form adds a "
+    "bias before its SiLU (a state-space mixer's) — counted while tracing, "
+    "once per compile of a program that holds the op",
+    ("taps", "gated", "act", "bias"))
 
 
-def _count_short_conv(ctx, filt, attrs):
+def _count_short_conv(ctx, filt, attrs, bias=None):
     # shape inference runs the lowering abstractly: uncounted
     if not getattr(ctx, "is_abstract", False):
         gated = bool(attrs.get("gated", True))
         SHORT_CONV_LOWERINGS_CTR.labels(
             taps=str(filt.shape[1]), gated=str(gated).lower(),
-            act="none" if gated else "silu").inc()
+            act="none" if gated else "silu",
+            bias=str(bias is not None).lower()).inc()
 
 
 def _short_conv(ctx, ins, attrs):
@@ -223,13 +226,16 @@ def _short_conv(ctx, ins, attrs):
 
     ``gated=False`` (KDA's convolution in front of Q, K and V): X [b, t, d]
     is convolved as it is and SiLU follows, ``Out = silu(conv(X))``; Filter
-    [d, L] as above."""
-    x, filt = X(ins, "X"), X(ins, "Filter")
+    [d, L] as above; with the optional input Bias [d], ``silu(conv(X) +
+    Bias)``."""
+    x, filt, bias = X(ins, "X"), X(ins, "Filter"), X(ins, "Bias")
     f32 = jnp.float32
-    _count_short_conv(ctx, filt, attrs)
+    _count_short_conv(ctx, filt, attrs, bias)
     if not attrs.get("gated", True):
-        out = jax.nn.silu(_causal_depthwise(x.astype(f32), filt.astype(f32)))
-        return {"Out": [out.astype(x.dtype)]}
+        conv = _causal_depthwise(x.astype(f32), filt.astype(f32))
+        if bias is not None:
+            conv = conv + bias.astype(f32)
+        return {"Out": [jax.nn.silu(conv).astype(x.dtype)]}
     b_, c_, u = jnp.split(x.astype(f32), 3, axis=-1)
     out = c_ * _causal_depthwise(b_ * u, filt.astype(f32))
     return {"Out": [out.astype(x.dtype)]}
@@ -254,10 +260,11 @@ def _short_conv_grad_maker(op, block, no_grad_set):
         v = block.var(n) if block.has_var(n) else None
         return n not in no_grad_set and not (v is not None
                                              and v.stop_gradient)
-    g_inputs = {"X$X": op.input("X"), "X$Filter": op.input("Filter"),
-                "OG$Out": [grad_var_name(n) for n in op.output("Out")]}
+    slots = ("X", "Filter") + (("Bias",) if op.input("Bias") else ())
+    g_inputs = {"X$" + s: op.input(s) for s in slots}
+    g_inputs["OG$Out"] = [grad_var_name(n) for n in op.output("Out")]
     g_outputs = {"IG$" + s: [grad_var_name(n) if wanted(n) else ""
-                             for n in op.input(s)] for s in ("X", "Filter")}
+                             for n in op.input(s)] for s in slots}
     return [{"type": "short_conv_grad", "inputs": g_inputs,
              "outputs": g_outputs, "attrs": dict(op.attrs)}]
 
@@ -273,19 +280,27 @@ def _short_conv_grad(ctx, ins, attrs):
     j] * dc[s + (L - 1) - j]`` (the same taps, run towards the past);
     ``dB = dg * u``, ``du = dg * B``; ``dFilter[:, j] = sum_{b, t} dc[t] *
     g[t - (L - 1) + j]`` in float32 (the filter is a master weight).  Reads
-    X and dOut, writes dX: seven [t, d] streams."""
+    X and dOut, writes dX: seven [t, d] streams.  The ungated form's Bias
+    gets ``sum_{b, t} dc``."""
     x, filt, d_out = X(ins, "X$X"), X(ins, "X$Filter"), X(ins, "OG$Out")
+    bias = X(ins, "X$Bias")
     f32 = jnp.float32
-    _count_short_conv(ctx, filt, attrs)
+    _count_short_conv(ctx, filt, attrs, bias)
     w = filt.astype(f32)
     if not attrs.get("gated", True):
         # the convolution again, SiLU's slope at it, then as below
         g = x.astype(f32)
-        _, slope = jax.vjp(jax.nn.silu, _causal_depthwise(g, w))
+        conv = _causal_depthwise(g, w)
+        if bias is not None:
+            conv = conv + bias.astype(f32)
+        _, slope = jax.vjp(jax.nn.silu, conv)
         dc, = slope(jnp.zeros_like(g) if d_out is None else d_out.astype(f32))
         dg, d_filt = _depthwise_back(g, dc, w)
-        return {"IG$X": [dg.astype(x.dtype)],
-                "IG$Filter": [d_filt.astype(filt.dtype)]}
+        out = {"IG$X": [dg.astype(x.dtype)],
+               "IG$Filter": [d_filt.astype(filt.dtype)]}
+        if bias is not None:
+            out["IG$Bias"] = [jnp.sum(dc, axis=(0, 1)).astype(bias.dtype)]
+        return out
     b_, c_, u = jnp.split(x.astype(f32), 3, axis=-1)
     g = b_ * u
     dy = jnp.zeros_like(g) if d_out is None else d_out.astype(f32)

@@ -422,6 +422,18 @@ def _configs(op):
             {"X": [f(1, 3, 8)], "B": [f(1, 3, 2)],
              "ALog": [f(2, lo=0.1, hi=1.0)], "DtBias": [f(8)]},
             loss_outputs=["G", "Beta"]),
+        # two groups of two heads of width 4, state 3, over 6 positions in
+        # chunks of 4 (padded inside); States, the chunk states kept for the
+        # grad op, carries no gradient
+        "ssd_scan": lambda: _Cfg(
+            {"X": [f(1, 6, 4, 4)], "Dt": [f(1, 6, 4, lo=-0.5, hi=0.5)],
+             "ALog": [f(4, lo=0.0, hi=1.0)], "B": [f(1, 6, 2, 3)],
+             "C": [f(1, 6, 2, 3)], "D": [f(4)],
+             "DtBias": [f(4, lo=-0.5, hi=0.5)]},
+            {"chunk": 4}, loss_outputs=["Out"]),
+        "gated_rms_norm": lambda: _Cfg(
+            {"X": [f(2, 3, 8)], "Z": [f(2, 3, 8, lo=-1.0, hi=1.0)],
+             "Scale": [f(8)]}, {"groups": 2, "epsilon": 1e-5}),
         "sample_logits": lambda: _Cfg(
             {"Logits": [f(3, 5)], "Labels": [i(3, 1, n=5)]},
             {"num_samples": 2, "seed": 3}, loss_outputs=["SampledLogits"]),

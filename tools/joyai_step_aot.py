@@ -16,7 +16,10 @@ hyper-connection ops' lowerings (``paddle_tpu_hc_lowerings_total``) and,
 for ``--cell solar`` and ``--cell ling``, the chunked scan's
 (``paddle_tpu_kda_lowerings_total``) and its gate's
 (``paddle_tpu_kda_gate_lowerings_total``), and the routing groups of every
-``moe_ffn`` lowering (``paddle_tpu_moe_lowerings_total{groups}``), with
+``moe_ffn`` lowering (``paddle_tpu_moe_lowerings_total{groups, gated}``),
+for ``--cell nemotron3`` the state-space scan's
+(``paddle_tpu_ssd_lowerings_total``) and every ``short_conv`` lowering's
+taps, form and bias (``paddle_tpu_short_conv_lowerings_total``), with
 and without ``--recompute``: whether the step fits beside its state, and what fitting
 costs (PERF.md section 7, row 31).  Nothing runs: no time comes from this.  The adapter has the
 recomputing step only (the traffic file's); without ``--recompute`` this
@@ -73,7 +76,8 @@ CELLS = {"joyai": ("joyai_llm_flash", "lm_mtp_s8192"),
          "lfm2": ("lfm2_8b_a1b", "lm_s16384_r64"),
          "xing4": ("xing4_29b_a4b", "lm_s4096_r64"),
          "solar": ("solar_open2_250b", "lm_s8192_r64"),
-         "ling": ("ling3_flash_vl", "lm_s8192_r64")}
+         "ling": ("ling3_flash_vl", "lm_s8192_r64"),
+         "nemotron3": ("nemotron3_nano_30b_a3b", "lm_s8192_r64")}
 
 
 def reads_after_update(text):
@@ -176,7 +180,7 @@ def main():
                     "xing4's, whose timed step is the plain one; solar's "
                     "timed step recomputes: pass --recompute for it, and "
                     "leave it out to see the plain step refused; ling's "
-                    "likewise)")
+                    "and nemotron3's likewise)")
     args = ap.parse_args()
     if args.run:
         return run_on_chip(args)
@@ -208,7 +212,8 @@ def main():
         config["num_hidden_layers"] = args.layers
     if args.seq:
         traffic["seq_len"] = args.seq
-    if args.recompute and args.cell in ("lfm2", "xing4", "solar", "ling"):
+    if args.recompute and args.cell in ("lfm2", "xing4", "solar", "ling",
+                                        "nemotron3"):
         traffic["recompute"] = True      # the adapter builds the fallback
     m = adapter.build_train(config, traffic, 7, 1, False)
     cb, step_args = dp_arith_check.caught_step(lambda: m["exe"].run(
@@ -262,10 +267,24 @@ def main():
 
     def moe_groups():
         """The step's moe_ffn forward lowerings (a recomputed clone counts)
-        by the experts routed over, those held and the routing groups."""
+        by the experts routed over, those held, the routing groups and
+        whether the experts are gated (three grouped matmuls) or not."""
         from paddle_tpu.ops import moe_ops
         return counted(moe_ops.MOE_LOWERINGS_CTR, "experts", "held",
-                       "groups")
+                       "groups", "gated")
+
+    def ssd_lowerings():
+        """The step's ssd_scan and ssd_scan_grad lowerings (a recomputed
+        clone counts) by form and chunk."""
+        from paddle_tpu.ops import ssd_ops
+        return counted(ssd_ops.SSD_LOWERINGS_CTR, "impl", "chunk")
+
+    def short_conv_lowerings():
+        """The step's short_conv and short_conv_grad lowerings (a recomputed
+        clone counts) by taps, gate, activation and bias."""
+        from paddle_tpu.ops import sequence_ops
+        return counted(sequence_ops.SHORT_CONV_LOWERINGS_CTR, "taps",
+                       "gated", "act", "bias")
     if args.fingerprint:
         text = cb.jitted.lower(*shapes).as_text(debug_info=True)
         print(json.dumps({
@@ -336,6 +355,8 @@ def main():
         "kda_lowerings": kda_lowerings(),
         "kda_gate_lowerings": kda_gate_lowerings(),
         "moe_groups": moe_groups(),
+        "ssd_lowerings": ssd_lowerings(),
+        "short_conv_lowerings": short_conv_lowerings(),
         "parameters_m": sum(int(np.prod(p.shape))
                             for p in m["parameters"]) / 1e6}))
     return 0

@@ -30,7 +30,10 @@ kind of gradient leaf, the KDA layers' own parameters ``kda``; the
 reference's token-by-token recurrence run in bf16 is the control of the
 scan).  ``--cell ling`` (PR 55): the Ling-3.0-flash cell's (8 of 512 experts
 under group-limited selection; a fifth kind, ``mla``: the latent-attention
-layer's norms and head gate).
+layer's norms and head gate).  ``--cell nemotron3`` (PR 57): the
+Nemotron-3-Nano cell's (8 of 128 un-gated experts; the kinds ``mamba``, the
+Mamba-2 blocks' own parameters, and ``attention``; the reference's
+token-by-token state-space recurrence run in bf16 is the control of the scan).
 """
 
 import argparse
@@ -60,7 +63,10 @@ CELLS = {"smallthinker": ("smallthinker_21b_a3b", "lm_s16384",
                    "build_solar_open2_pretrain", "test_solar_open2_cell",
                    "toy_solar", {"is_test": True}),
          "ling": ("ling3_flash_vl", "lm_s8192_r64", "ling_config",
-                  "build_ling_pretrain", "test_ling3_cell", "toy_ling", {})}
+                  "build_ling_pretrain", "test_ling3_cell", "toy_ling", {}),
+         "nemotron3": ("nemotron3_nano_30b_a3b", "lm_s8192_r64",
+                       "nemotron_config", "build_nemotron_h_pretrain",
+                       "test_nemotron3_cell", "toy_nemotron", {})}
 
 
 def main():
