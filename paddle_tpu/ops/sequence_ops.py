@@ -197,20 +197,28 @@ SHORT_CONV_LOWERINGS_CTR = _monitor.REGISTRY.counter(
     "paddle_tpu_short_conv_lowerings_total",
     "short_conv and short_conv_grad lowerings by the filter's taps, whether "
     "the convolution is gated (LFM2: C * conv(B * u), act none) or not "
-    "(KDA: silu(conv(x)), act silu) and whether the ungated form adds a "
-    "bias before its SiLU (a state-space mixer's) — counted while tracing, "
-    "once per compile of a program that holds the op",
-    ("taps", "gated", "act", "bias"))
+    "(KDA: silu(conv(x)), act silu), whether the ungated form adds a bias "
+    "(a state-space mixer's) and what implements it (pallas: the kernel pair "
+    "of pallas/short_conv.py; xla: jnp that XLA fuses) — counted while "
+    "tracing, once per compile of a program that holds the op",
+    ("taps", "gated", "act", "bias", "impl"))
 
 
-def _count_short_conv(ctx, filt, attrs, bias=None):
+def _short_conv_lowering(ctx, x, filt, attrs, bias=None):
+    """``pallas`` or ``xla`` for the op and its grad op alike, from what
+    ``pallas/short_conv.py:fits`` and ``device.on_tpu()`` say; counts it."""
+    from ..device import on_tpu
+    from ..pallas import short_conv
+    gated = bool(attrs.get("gated", True))
+    impl = "pallas" if short_conv.fits(
+        x.shape, filt.shape[1], x.dtype, gated) and on_tpu() else "xla"
     # shape inference runs the lowering abstractly: uncounted
     if not getattr(ctx, "is_abstract", False):
-        gated = bool(attrs.get("gated", True))
         SHORT_CONV_LOWERINGS_CTR.labels(
             taps=str(filt.shape[1]), gated=str(gated).lower(),
             act="none" if gated else "silu",
-            bias=str(bias is not None).lower()).inc()
+            bias=str(bias is not None).lower(), impl=impl).inc()
+    return impl
 
 
 def _short_conv(ctx, ins, attrs):
@@ -221,21 +229,13 @@ def _short_conv(ctx, ins, attrs):
     the sequence starts (Filter [d, L], no bias); ``Out = C * c`` [b, t, d].
     No activation, no positional term; the two projections round it are the
     program's own ``mul`` ops.  Bandwidth-bound: three [t, d] streams in, one
-    out.  Float32 inside (a v5e has no bf16 vector unit: the products would
-    be widened anyway), the output in X's dtype.
-
-    ``gated=False`` (KDA's convolution in front of Q, K and V): X [b, t, d]
-    is convolved as it is and SiLU follows, ``Out = silu(conv(X))``; Filter
-    [d, L] as above; with the optional input Bias [d], ``silu(conv(X) +
-    Bias)``."""
+    out.  Float32 inside (a v5e has no bf16 vector unit), the output in X's
+    dtype.  ``gated=False`` (KDA's, a state-space mixer's): :func:`_ungated`."""
     x, filt, bias = X(ins, "X"), X(ins, "Filter"), X(ins, "Bias")
     f32 = jnp.float32
-    _count_short_conv(ctx, filt, attrs, bias)
+    impl = _short_conv_lowering(ctx, x, filt, attrs, bias)
     if not attrs.get("gated", True):
-        conv = _causal_depthwise(x.astype(f32), filt.astype(f32))
-        if bias is not None:
-            conv = conv + bias.astype(f32)
-        return {"Out": [jax.nn.silu(conv).astype(x.dtype)]}
+        return {"Out": [_ungated(impl, x, filt, bias)]}
     b_, c_, u = jnp.split(x.astype(f32), 3, axis=-1)
     out = c_ * _causal_depthwise(b_ * u, filt.astype(f32))
     return {"Out": [out.astype(x.dtype)]}
@@ -280,27 +280,27 @@ def _short_conv_grad(ctx, ins, attrs):
     j] * dc[s + (L - 1) - j]`` (the same taps, run towards the past);
     ``dB = dg * u``, ``du = dg * B``; ``dFilter[:, j] = sum_{b, t} dc[t] *
     g[t - (L - 1) + j]`` in float32 (the filter is a master weight).  Reads
-    X and dOut, writes dX: seven [t, d] streams.  The ungated form's Bias
-    gets ``sum_{b, t} dc``."""
+    X and dOut, writes dX: seven [t, d] streams.  ``gated=False``:
+    :func:`_ungated_grad`.
+
+    The gated form below, ``_causal_depthwise`` and ``_depthwise_back``
+    above, and the LINES all three stand on are the parent commit's (PR 58
+    moved the ungated form to the end of this file and filled what it left
+    with words): the compile cache keys on the source line of every frame
+    under a lowering, and LFM2's step, which holds the gated form alone, is
+    to stay the executable it was.  After an edit up here,
+    ``tools/joyai_step_aot.py --cell lfm2 --fingerprint`` at both commits
+    from one directory says whether it still is; where it is not, nothing
+    is wrong but LFM2's first run compiles its step again."""
     x, filt, d_out = X(ins, "X$X"), X(ins, "X$Filter"), X(ins, "OG$Out")
     bias = X(ins, "X$Bias")
     f32 = jnp.float32
-    _count_short_conv(ctx, filt, attrs, bias)
+    impl = _short_conv_lowering(ctx, x, filt, attrs, bias)
     w = filt.astype(f32)
     if not attrs.get("gated", True):
-        # the convolution again, SiLU's slope at it, then as below
-        g = x.astype(f32)
-        conv = _causal_depthwise(g, w)
-        if bias is not None:
-            conv = conv + bias.astype(f32)
-        _, slope = jax.vjp(jax.nn.silu, conv)
-        dc, = slope(jnp.zeros_like(g) if d_out is None else d_out.astype(f32))
-        dg, d_filt = _depthwise_back(g, dc, w)
-        out = {"IG$X": [dg.astype(x.dtype)],
-               "IG$Filter": [d_filt.astype(filt.dtype)]}
-        if bias is not None:
-            out["IG$Bias"] = [jnp.sum(dc, axis=(0, 1)).astype(bias.dtype)]
-        return out
+        out = _ungated_grad(impl, x, filt, bias, d_out)
+        return {"IG$" + s: [g] for s, g in zip(("X", "Filter", "Bias"), out)
+                if g is not None}
     b_, c_, u = jnp.split(x.astype(f32), 3, axis=-1)
     g = b_ * u
     dy = jnp.zeros_like(g) if d_out is None else d_out.astype(f32)
@@ -310,3 +310,52 @@ def _short_conv_grad(ctx, ins, attrs):
                          axis=-1)
     return {"IG$X": [dx.astype(x.dtype)],
             "IG$Filter": [d_filt.astype(filt.dtype)]}
+
+
+# -- the ungated form: silu(conv(X) [+ Bias]) ---------------------------------
+
+def _ungated(impl, x, filt, bias):
+    """``gated=False`` (KDA's convolution in front of Q, K and V): X [b, t,
+    d] is convolved as it is and SiLU follows, ``Out = silu(conv(X))``;
+    Filter [d, L] as in the gated form; with the optional input Bias [d],
+    ``silu(conv(X) + Bias)`` (a state-space mixer's).  Float32 inside, Out
+    in X's dtype.  ``pallas``, on a TPU where ``pallas/short_conv.py:fits``
+    says so (channels in whole lane tiles, a length of whole tiles, float32
+    or bf16): the kernel ``short_conv_fwd``, one pass over X and Out;
+    ``xla`` everywhere else (the CPU, toy widths): the ``jax.numpy`` text,
+    which is also what the tests hold the kernel to."""
+    if impl == "pallas":
+        from ..pallas import short_conv
+        return short_conv.short_conv_fwd(x, filt, bias)
+    f32 = jnp.float32
+    conv = _causal_depthwise(x.astype(f32), filt.astype(f32))
+    if bias is not None:
+        conv = conv + bias.astype(f32)
+    return jax.nn.silu(conv).astype(x.dtype)
+
+
+def _ungated_grad(impl, x, filt, bias, d_out):
+    """``(dX, dFilter, dBias or None)`` of :func:`_ungated` from its inputs
+    and Out's gradient, each in its variable's dtype: the convolution again,
+    SiLU's slope at it, ``dc = dOut * slope``, then the gated form's way back
+    through the taps; Bias gets ``sum_{b, t} dc``.  ``pallas``: the kernel
+    ``short_conv_bwd``, one pass over X, dOut and dX with the two sums in
+    float32 beside it."""
+    f32 = jnp.float32
+    if impl == "pallas":
+        from ..pallas import short_conv
+        dx, d_filt, d_bias = short_conv.short_conv_bwd(
+            x, filt, bias, jnp.zeros_like(x) if d_out is None else d_out)
+    else:
+        g, w = x.astype(f32), filt.astype(f32)
+        conv = _causal_depthwise(g, w)
+        if bias is not None:
+            conv = conv + bias.astype(f32)
+        _, slope = jax.vjp(jax.nn.silu, conv)
+        dc, = slope(jnp.zeros_like(g) if d_out is None
+                    else d_out.astype(f32))
+        dg, d_filt = _depthwise_back(g, dc, w)
+        dx = dg.astype(x.dtype)
+        d_bias = None if bias is None else jnp.sum(dc, axis=(0, 1))
+    return (dx, d_filt.astype(filt.dtype),
+            None if bias is None else d_bias.astype(bias.dtype))

@@ -558,3 +558,49 @@ def test_tpu_compiler_takes_the_kda_kernels_at_the_cells_shapes(
         assert both.memory_analysis().temp_size_in_bytes < copies + (
             67 * heads // 8 + 32 << 20)
         assert back.memory_analysis().temp_size_in_bytes < copies + (16 << 20)
+
+
+@pytest.mark.parametrize("d,bias", [(6144, False), (6144, True),
+                                    (3072, False)],
+                         ids=["ling3", "nemotron3", "solar_open2"])
+def test_tpu_compiler_takes_the_short_conv_kernels_at_the_cells_shapes(
+        topo, monkeypatch, d, bias):
+    """The ungated ``short_conv`` + ``short_conv_grad`` at [1, 8192, 6144]
+    bf16 with Nemotron's bias and without (Ling's) and at Solar-Open2's
+    3072 channels, four taps, lowered as on a TPU and compiled for one
+    described chip: Mosaic takes both kernels (interpret mode,
+    ``tests/test_short_conv_kernel.py``, says nothing about that: the
+    sublane rotations, the halo blocks, the revisited sums), one of each and
+    nothing of the ``jax.numpy`` form beside them: no float32 copy of a
+    stream in HBM, only the filter's partial sums (1 MB)."""
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu import device
+    from paddle_tpu.ops import sequence_ops
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    ctx = types.SimpleNamespace(amp=False, is_abstract=True)
+    attrs = {"gated": False}
+
+    def step(x, w, b, d_out):
+        fwd = sequence_ops._short_conv(
+            ctx, {"X": [x], "Filter": [w], "Bias": [b]}, attrs)
+        back = sequence_ops._short_conv_grad(
+            ctx, {"X$X": [x], "X$Filter": [w], "X$Bias": [b],
+                  "OG$Out": [d_out]}, attrs)
+        return fwd["Out"][0], [v[0] for v in back.values()]
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one)
+    x = s((1, 8192, d), "bfloat16")
+    with _no_compile_cache():
+        both = jax.jit(step).lower(
+            x, s((d, 4), "float32"), s((d,), "float32") if bias else None,
+            x).compile()
+    text = both.as_text()
+    assert text.count('"short_conv_fwd"') == 1 and \
+        text.count('"short_conv_bwd"') == 1, \
+        [line for line in text.splitlines() if "custom_call_target" in line]
+    assert len(jax.tree_util.tree_leaves(both.out_info)) == 3 + bias
+    assert both.memory_analysis().temp_size_in_bytes < 4 << 20

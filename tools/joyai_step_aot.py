@@ -18,8 +18,9 @@ for ``--cell solar`` and ``--cell ling``, the chunked scan's
 (``paddle_tpu_kda_gate_lowerings_total``), and the routing groups of every
 ``moe_ffn`` lowering (``paddle_tpu_moe_lowerings_total{groups, gated}``),
 for ``--cell nemotron3`` the state-space scan's
-(``paddle_tpu_ssd_lowerings_total``) and every ``short_conv`` lowering's
-taps, form and bias (``paddle_tpu_short_conv_lowerings_total``), with
+(``paddle_tpu_ssd_lowerings_total``), and for every cell that holds one
+every ``short_conv`` lowering's taps, form, bias and ``impl``
+(``paddle_tpu_short_conv_lowerings_total``), with
 and without ``--recompute``: whether the step fits beside its state, and what fitting
 costs (PERF.md section 7, row 31).  Nothing runs: no time comes from this.  The adapter has the
 recomputing step only (the traffic file's); without ``--recompute`` this
@@ -281,10 +282,11 @@ def main():
 
     def short_conv_lowerings():
         """The step's short_conv and short_conv_grad lowerings (a recomputed
-        clone counts) by taps, gate, activation and bias."""
+        clone counts) by taps, gate, activation, bias and what implements
+        it (pallas: the kernel pair of ``pallas/short_conv.py``)."""
         from paddle_tpu.ops import sequence_ops
         return counted(sequence_ops.SHORT_CONV_LOWERINGS_CTR, "taps",
-                       "gated", "act", "bias")
+                       "gated", "act", "bias", "impl")
     if args.fingerprint:
         text = cb.jitted.lower(*shapes).as_text(debug_info=True)
         print(json.dumps({

@@ -1,7 +1,7 @@
 """Device time of the last traced benchmark run by program op.
 
     python3 benchmark/run.py --workload <cell> ... --trace 1
-    python3 tools/trace_by_op.py <cell>
+    python3 tools/trace_by_op.py <cell> [role/op ...]
 
 Reads the newest ``.xplane.pb`` under ``.cache/bench_trace/<cell>/`` with the
 benchmark's own reducers (``benchmark/op_scopes.py``, ``part_scopes.py``) over
@@ -14,7 +14,9 @@ have their rows: the program's own recomputation is the role ``rc/`` among
 the scopes (``framework/recompute.py``), XLA's rematerialised instructions
 are ``remat_pct`` by the program op they belong to (``benchmark/
 remat_scopes.py``: events named ``*.remat*``; "-" without a scope), and
-forward work a generic vjp lowered again is ``forward_again_pct``.
+forward work a generic vjp lowered again is ``forward_again_pct``.  Scopes
+named after the cell (``fwd/short_conv rc/short_conv bwd/short_conv_grad``)
+get every XLA operation under them listed, whatever their size.
 """
 
 import glob
@@ -27,7 +29,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmark import (harness, op_scopes, part_scopes,  # noqa: E402
-                       remat_scopes)
+                       remat_scopes, trace_reduce)
 
 
 #: scopes a model nests inside an op's own (``flash_attention``'s ``window``,
@@ -35,6 +37,18 @@ from benchmark import (harness, op_scopes, part_scopes,  # noqa: E402
 #: a dot, and the longer name comes first so that it is the one found)
 TAGS = ("window", "mtp.mla_proj", "mtp.shared_expert", "mtp", "mla_proj",
         "shared_expert", "dense_ffn")
+
+
+def _by_class(names, busy):
+    """``{XLA operation class: [% of busy, instances]}`` of one scope's
+    ``{operation name: seconds}``, largest first."""
+    out = {}
+    for name, sec in names.items():
+        row = out.setdefault(trace_reduce.op_class(name), [0.0, 0])
+        row[0] += 100 * sec / busy
+        row[1] += 1
+    return {k: [round(v[0], 3), v[1]] for k, v in sorted(
+        out.items(), key=lambda kv: -kv[1][0])}
 
 
 def main():
@@ -82,7 +96,9 @@ def main():
                                    paths[-1], (lo, hi)).items()}),
            "moe_parts_pct": share(moe), "tagged_pct": share(tagged),
            "xla_ops_pct": {k: dict(list(share(by[k]).items())[:6])
-                           for k in top + ["unscoped"] if k in by}}
+                           for k in top + ["unscoped"] if k in by},
+           "asked_xla_ops_pct": {k: _by_class(by[k], busy)
+                                 for k in sys.argv[2:] if k in by}}
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     with open(os.path.join(ROOT, "chiprun_out",
                            f"trace_by_op.{cell}.json"), "w") as f:
