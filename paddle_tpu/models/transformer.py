@@ -16,8 +16,8 @@ from __future__ import annotations
 
 import numpy as np
 
-from .. import layers
-from ..framework import name_scope
+from .. import initializer, layers
+from ..framework import default_main_program, name_scope
 from ..param_attr import ParamAttr
 
 
@@ -374,141 +374,115 @@ def build_gpt_serving(cfg: BertConfig, seq_len, attn_impl="auto"):
     return (src_ids,), logits
 
 
-# -- OLMoE: pre-norm decoder with QK-norm, rotary and a sparse-expert FFN ----
+# -- Decoders: one block, assembled from the parts a configuration names, ----
+# -- and one causal-LM loop over it ------------------------------------------
 
-class OlmoeConfig:
-    """OLMoE-1B-7B defaults (``allenai/OLMoE-1B-7B-0125-Instruct``
-    config.json); the loss coefficients are the training recipe's
-    (arXiv:2409.02060)."""
+# Down here because the lines above must stay where they are: where
+# ``_lm_head_loss`` calls ``fused_lm_head_ce`` is a source location in every
+# cell's lowered step, and the compile cache keys on that text (ROADMAP D13).
+import contextlib  # noqa: E402
 
-    def __init__(self, vocab_size=50304, d_model=2048, n_layer=16, n_head=16,
-                 n_kv_head=None, d_expert=1024, n_experts=64, top_k=8,
-                 norm_topk_prob=False, rms_eps=1e-5, rope_theta=10000.0,
-                 lb_coef=0.01, z_coef=0.001, init_std=0.02):
+
+class DecoderConfig:
+    """What :func:`decoder_block`, :func:`routed_ffn` and the causal-LM loop
+    read off a decoder's configuration, with the values most of the classes
+    below share.  A class sets what its model has otherwise: the family's
+    constants in its body, a row's numbers in its constructor.  A decoder
+    made of parts that exist is such a class and an entry point; a new
+    mixer is its function and one entry of ``MIXERS``.
+
+    ``mixer(idx)`` names the sequence mixer of layer ``idx``: ``"gqa"``
+    (:func:`grouped_query_attention`, by the keys below), a key of
+    ``MIXERS`` (``"mla"``, ``"kda"``, ``"conv"``, ``"mamba2"``) or None;
+    ``ffn(idx)`` its FFN: ``"dense"``, ``"routed"`` (:func:`routed_ffn`) or
+    None.  A block with both is the pre-norm pair ``h = x + Mixer(RMS(x))``,
+    ``out = h + FFN(RMS(h))``; a block with one is that half alone.
+
+    The stream: ``mup``, the embedding times ``sqrt(d_model)``;
+    ``tie_embeddings``, the head reads the embedding table and there is no
+    ``lm_out.w``.  The block: ``sandwich_norm``, a second norm on every
+    sublayer's output, its terms summed before it.  Grouped-query attention:
+    ``d_head`` (None: ``d_model // n_head``); ``qk_norm_over``, what Q and K
+    are RMS-normed over, ``"projection"`` (before the head split, one
+    weight for all heads), ``"head"`` (weight ``[d_head]``) or None;
+    ``rotary(idx)``, whether the layer turns Q and K (rotate-half, after
+    the norm; by default wherever there is a ``rope_theta``);
+    ``window_at(idx)``, the keys the layer sees back (None: the whole causal
+    half); ``out_gate``, the output times ``sigmoid`` of a fourth slice of
+    the fused projection; ``mixer_tags``, the ``name_scope`` of a mixer that
+    does not tag itself (the per-layer metrics read the tags).  The FFNs:
+    the first ``n_dense_layer`` layers ``"dense"`` (width ``d_inner``, the
+    ``dense_ffn`` tag), the others ``"routed"``; ``gated``, SiLU-gated
+    (:func:`gated_ffn`) or un-gated ReLU^2 (:func:`relu2_ffn`), the experts
+    alike; ``d_shared``, the shared expert's width (None: none);
+    ``score_func``, ``select_bias`` (a selection bias held at zero: the
+    published recipes balance load through it, not through a loss term),
+    ``route_norm`` and ``route_norm_eps`` (the kept scores renormalised, ``+
+    eps``), ``route_scale``, ``n_route_group`` and ``topk_group``
+    (group-limited selection), ``act`` (the gated experts' activation),
+    ``init_std``: ``layers.moe_ffn``'s; ``router_before_mixer``, the router
+    scores the block's normed input, before the mixer, and the experts read
+    the FFN's; ``n_held``/``expert_offset``, the experts whose weights this
+    program holds (default all): a chip's share under expert parallelism,
+    see ``ops/moe_ops.py``.
+
+    Two keys hold an order of independent ops and not a model's arithmetic;
+    each value is an accepted cell's lowered step, and one value for all is
+    a pair on the chip away (ROADMAP D19): ``qk_in_turn``, Q through norm
+    and rotary, then K (False: both normed, then both turned);
+    ``tag_covers_add``, the parts (a mixer's kind, ``"dense"``) whose tag
+    also covers the residual add behind them."""
+
+    mup = False
+    tie_embeddings = False
+    sandwich_norm = False
+    qk_norm_over = None
+    rope_theta = None
+    out_gate = False
+    mixer_tags = {"gqa": "attn", "conv": "conv_operator"}
+    n_dense_layer = 0
+    gated = True
+    d_shared = None
+    score_func = "sigmoid"
+    select_bias = True
+    route_norm = True
+    route_norm_eps = 1e-20
+    route_scale = 1.0
+    n_route_group = 1
+    topk_group = 1
+    act = "silu"
+    router_before_mixer = False
+    qk_in_turn = True
+    tag_covers_add = ()
+
+    def __init__(self, vocab_size, d_model, n_layer, n_head, d_expert,
+                 n_experts, top_k, rms_eps, n_held=None, expert_offset=0,
+                 init_std=0.02, n_kv_head=None, d_head=None):
         self.vocab_size = vocab_size
         self.d_model = d_model
         self.n_layer = n_layer
         self.n_head = n_head
         self.n_kv_head = n_kv_head or n_head
-        self.d_expert = d_expert
-        self.n_experts = n_experts
-        self.top_k = top_k
-        self.norm_topk_prob = norm_topk_prob
-        self.rms_eps = rms_eps
-        self.rope_theta = rope_theta
-        self.lb_coef = lb_coef
-        self.z_coef = z_coef
-        self.init_std = init_std
-
-
-def olmoe_decoder_layer(x, cfg: OlmoeConfig, idx=0, attn_impl="flash",
-                        is_test=False):
-    """Pre-norm block: ``h = x + Attn(RMSNorm(x))``, ``out = h +
-    MoE(RMSNorm(h))``; no bias anywhere.  Q and K are RMS-normed over the
-    whole projection before the head split, then rotated.  Returns
-    ``(out, lb_loss, z_loss, expert_load)``."""
-    from ..initializer import NormalInitializer
-    p = f"dec_{idx}"
-    d_head = cfg.d_model // cfg.n_head
-
-    def norm(v, name):
-        return layers.rms_norm(v, begin_norm_axis=2, epsilon=cfg.rms_eps,
-                               param_attr=ParamAttr(name=name))
-
-    def qk_hook(q, k):
-        return tuple(
-            layers.rope(norm(t, f"{p}.attn.{n}_norm.w"), d_head,
-                        cfg.rope_theta) for t, n in ((q, "q"), (k, "k")))
-
-    n = norm(x, f"{p}.ln1.w")
-    h = x + multi_head_attention(
-        n, n, n, cfg.d_model, cfg.n_head, is_test=is_test,
-        param_prefix=f"{p}.attn", attn_impl=attn_impl, causal=True,
-        bias=False, n_kv_head=cfg.n_kv_head, qk_hook=qk_hook)
-    moe, lb, z, load = layers.moe_ffn(
-        norm(h, f"{p}.ln2.w"), cfg.n_experts, cfg.top_k, cfg.d_expert,
-        norm_topk_prob=cfg.norm_topk_prob, param_prefix=f"{p}.moe",
-        initializer=NormalInitializer(0.0, cfg.init_std))
-    return h + moe, lb, z, load
-
-
-def build_olmoe_pretrain(cfg: OlmoeConfig, seq_len, is_test=False,
-                         attn_impl="flash", fused_head=True):
-    """Causal LM over OLMoE blocks: ids -> embedding (no position table) ->
-    ``n_layer`` pre-norm blocks -> final RMSNorm -> untied bias-free head.
-    Loss = mean next-token CE (``lm_label`` as the pipeline shifted it;
-    label 0 excluded, as in the other builders) + ``lb_coef`` * mean over
-    layers of the load-balancing loss + ``z_coef`` * mean over layers of the
-    router z-loss.  The model has no dropout, so ``is_test`` only reaches
-    the attention's choice of path.  Returns ``(feeds, parts, loss)`` with
-    ``parts`` = {"ce", "lb", "z", "expert_load": [per layer], "hidden": the
-    final norm's output}."""
-    src_ids = layers.data("src_ids", shape=[seq_len], dtype="int64")
-    lm_label = layers.data("lm_label", shape=[seq_len], dtype="int64")
-    x = layers.embedding(src_ids, size=[cfg.vocab_size, cfg.d_model],
-                         param_attr=ParamAttr(name="word_embedding"))
-    lbs, zs, loads = [], [], []
-    for i in range(cfg.n_layer):
-        x, lb, z, load = olmoe_decoder_layer(x, cfg, i, attn_impl, is_test)
-        lbs.append(lb)
-        zs.append(z)
-        loads.append(load)
-    x = layers.rms_norm(x, begin_norm_axis=2, epsilon=cfg.rms_eps,
-                        param_attr=ParamAttr(name="final_norm.w"))
-    _, ce = _lm_head_loss(x, cfg, lm_label, fused_head, "lm_out", bias=False)
-    lb = layers.sum(lbs) / float(cfg.n_layer)
-    z = layers.sum(zs) / float(cfg.n_layer)
-    loss = ce + cfg.lb_coef * lb + cfg.z_coef * z
-    return (src_ids, lm_label), {"ce": ce, "lb": lb, "z": z,
-                                 "expert_load": loads, "hidden": x}, loss
-
-
-# -- Trinity (afmoe): window and full attention mixed, gated, per-head -------
-# -- QK-norm, grouped-query; sigmoid routing beside a shared expert ----------
-
-class TrinityConfig:
-    """Trinity-Mini defaults (``arcee-ai/Trinity-Mini`` config.json,
-    ``model_type`` ``afmoe``).  ``layer_types[i]`` is ``sliding_attention``
-    or ``full_attention``; the first ``n_dense_layer`` layers have a dense
-    gated FFN of width ``d_inner``, the others ``n_experts`` routed experts
-    of width ``d_expert`` (``top_k`` a token) beside one shared expert of
-    width ``d_expert * n_shared``.  ``n_held``/``expert_offset``: the
-    experts whose weights this program holds (default all): a chip's share
-    under expert parallelism, see ``ops/moe_ops.py``."""
-
-    def __init__(self, vocab_size=200192, d_model=2048, n_layer=32,
-                 n_head=32, n_kv_head=4, d_head=128, d_inner=6144,
-                 d_expert=1024, n_experts=128, top_k=8, n_shared=1,
-                 n_dense_layer=2, layer_types=None, window=2048,
-                 score_func="sigmoid", route_norm=True, route_scale=2.826,
-                 rms_eps=1e-5, rope_theta=10000.0, mup=True, n_held=None,
-                 expert_offset=0, init_std=0.02):
-        self.vocab_size = vocab_size
-        self.d_model = d_model
-        self.n_layer = n_layer
-        self.n_head = n_head
-        self.n_kv_head = n_kv_head
         self.d_head = d_head
-        self.d_inner = d_inner
         self.d_expert = d_expert
         self.n_experts = n_experts
         self.top_k = top_k
-        self.n_shared = n_shared
-        self.n_dense_layer = n_dense_layer
-        self.layer_types = list(layer_types) if layer_types else [
-            "full_attention" if i % 4 == 3 else "sliding_attention"
-            for i in range(n_layer)]
-        assert len(self.layer_types) == n_layer
-        self.window = window
-        self.score_func = score_func
-        self.route_norm = route_norm
-        self.route_scale = route_scale
         self.rms_eps = rms_eps
-        self.rope_theta = rope_theta
-        self.mup = mup
         self.n_held = n_experts if n_held is None else n_held
         self.expert_offset = expert_offset
         self.init_std = init_std
+
+    def mixer(self, idx):
+        return "gqa"
+
+    def ffn(self, idx):
+        return "dense" if idx < self.n_dense_layer else "routed"
+
+    def rotary(self, idx):
+        return self.rope_theta is not None
+
+    def window_at(self, idx):
+        return None
 
 
 def gated_ffn(x, d_inner, d_model, param_prefix="ffn"):
@@ -523,115 +497,184 @@ def gated_ffn(x, d_inner, d_model, param_prefix="ffn"):
                      param_attr=ParamAttr(name=f"{param_prefix}.down.w"))
 
 
-def trinity_decoder_layer(x, cfg: TrinityConfig, idx=0, attn_impl="flash",
-                          is_test=False):
-    """One afmoe block, four norms: ``h = x + RMS2(Attn(RMS1(x)))``, ``out =
-    h + RMS4(FFN(RMS3(h)))``; no bias anywhere.  Attention: grouped-query,
-    Q and K RMS-normed per head (weights ``[d_head]``), rotary on
-    ``sliding_attention`` layers only (``full_attention`` layers carry no
-    positional term), a ``window`` on the sliding layers, and the output
-    gated by ``sigmoid`` of a fourth slice of the fused projection.  FFN:
-    :func:`gated_ffn` in the first ``n_dense_layer`` layers; else the
-    shared expert (the same builder) plus ``moe_ffn`` with sigmoid scores,
-    a selection bias held at zero, the kept scores renormalised (``+
-    1e-20``) and scaled.  Returns ``(out, expert_load or None)``."""
-    from ..initializer import NormalInitializer
-    p = f"dec_{idx}"
-    sliding = cfg.layer_types[idx] == "sliding_attention"
+def relu2_ffn(x, d_inner, d_model, param_prefix="ffn"):
+    """``down(relu(up(x))^2)`` out of the dense ops, no gate branch, no bias
+    (``<prefix>.up.w``, ``<prefix>.down.w``)."""
+    u = layers.fc(x, size=d_inner, num_flatten_dims=2, bias_attr=False,
+                  param_attr=ParamAttr(name=f"{param_prefix}.up.w"))
+    return layers.fc(layers.square(layers.relu(u)), size=d_model,
+                     num_flatten_dims=2, bias_attr=False,
+                     param_attr=ParamAttr(name=f"{param_prefix}.down.w"))
 
-    def norm(v, name, axis=2):
-        return layers.rms_norm(v, begin_norm_axis=axis, epsilon=cfg.rms_eps,
-                               param_attr=ParamAttr(name=name))
 
-    def head_hook(q, k):
-        q = norm(q, f"{p}.attn.q_norm.w", 3)
-        k = norm(k, f"{p}.attn.k_norm.w", 3)
-        if sliding:
-            q = layers.rope(q, cfg.d_head, cfg.rope_theta)
-            k = layers.rope(k, cfg.d_head, cfg.rope_theta)
-        return q, k
+def plain_residual(x, sublayer, name):
+    """The plain residual rule, ``x + F(norm(x))``: ``sublayer(x)`` norms its
+    input and returns ``F``'s terms, added to ``x`` one by one, in order."""
+    for term in sublayer(x):
+        x = x + term
+    return x
 
-    n = norm(x, f"{p}.ln1.w")
-    attn = multi_head_attention(
-        n, n, n, cfg.d_model, cfg.n_head, is_test=is_test,
-        param_prefix=f"{p}.attn", attn_impl=attn_impl, causal=True,
-        bias=False, n_kv_head=cfg.n_kv_head, d_head=cfg.d_head,
-        window=cfg.window if sliding else None, out_gate=True,
-        head_hook=head_hook)
-    h = x + norm(attn, f"{p}.ln2.w")
-    m = norm(h, f"{p}.ln3.w")
-    load = None
-    if idx < cfg.n_dense_layer:
-        with name_scope("dense_ffn"):
-            f = gated_ffn(m, cfg.d_inner, cfg.d_model, f"{p}.ffn")
-    else:
-        with name_scope("shared_expert"):
-            f = gated_ffn(m, cfg.d_expert * cfg.n_shared, cfg.d_model,
-                          f"{p}.shared")
-        moe, _, _, load = layers.moe_ffn(
-            m, cfg.n_experts, cfg.top_k, cfg.d_expert,
-            norm_topk_prob=cfg.route_norm, param_prefix=f"{p}.moe",
-            initializer=NormalInitializer(0.0, cfg.init_std),
-            score_func=cfg.score_func, select_bias=True, norm_eps=1e-20,
-            route_scale=cfg.route_scale, num_held=cfg.n_held,
-            expert_offset=cfg.expert_offset)
-        f = f + moe
-    return h + norm(f, f"{p}.ln4.w"), load
+
+def hyper_connection(cfg: XingConfig):
+    """The residual rule of a stream ``cfg.hc_mult`` wide, held as a list
+    of that many [b, t, d] variables (manifold-constrained
+    hyper-connections, arXiv:2512.24880 §4; ``layers.hc_pre`` /
+    ``layers.hc_post``): the sublayer reads ``u``, a learned token-dependent
+    mix of the streams, and its output is written back to every stream
+    beside a doubly stochastic mix of the streams themselves.  Parameters
+    ``<name>.phi``, ``.alpha``, ``.bias``, one set a sublayer."""
+    def rule(x, sublayer, name):
+        u, h_post, h_res = layers.hc_pre(
+            x, cfg.hc_sinkhorn_iters, cfg.hc_eps, cfg.rms_eps,
+            cfg.hc_res_clamp, param_prefix=name)
+        y = None
+        for term in sublayer(u):
+            y = term if y is None else y + term
+        return layers.hc_post(x, y, h_post, h_res, cfg.hc_sinkhorn_iters)
+    return rule
+
+
+# -- OLMoE: pre-norm decoder with QK-norm, rotary and a sparse-expert FFN ----
+
+class OlmoeConfig(DecoderConfig):
+    """OLMoE-1B-7B defaults (``allenai/OLMoE-1B-7B-0125-Instruct``
+    config.json); the loss coefficients are the training recipe's
+    (arXiv:2409.02060).
+
+    The block: ``h = x + Attn(RMSNorm(x))``, ``out = h +
+    MoE(RMSNorm(h))``; no bias anywhere.  Q and K are RMS-normed over the
+    whole projection before the head split, then rotated.  Every layer's
+    FFN is ``n_experts`` routed experts of width ``d_expert`` (``top_k`` a
+    token, softmax scores, no selection bias, no shared expert)."""
+
+    qk_norm_over = "projection"
+    mixer_tags = {}
+    score_func = "softmax"
+    select_bias = False
+    route_norm_eps = 0.0
+
+    def __init__(self, vocab_size=50304, d_model=2048, n_layer=16, n_head=16,
+                 n_kv_head=None, d_expert=1024, n_experts=64, top_k=8,
+                 norm_topk_prob=False, rms_eps=1e-5, rope_theta=10000.0,
+                 lb_coef=0.01, z_coef=0.001, init_std=0.02):
+        super().__init__(vocab_size, d_model, n_layer, n_head, d_expert,
+                         n_experts, top_k, rms_eps, init_std=init_std,
+                         n_kv_head=n_kv_head)
+        self.route_norm = norm_topk_prob
+        self.rope_theta = rope_theta
+        self.lb_coef = lb_coef
+        self.z_coef = z_coef
+
+
+def build_olmoe_pretrain(cfg: OlmoeConfig, seq_len, is_test=False,
+                         attn_impl="flash", fused_head=True):
+    """:func:`_causal_lm` over :class:`OlmoeConfig`'s blocks.  Loss = its
+    mean next-token CE + ``lb_coef`` * mean over layers of the
+    load-balancing loss + ``z_coef`` * mean over layers of the router
+    z-loss.  Returns ``(feeds, parts, loss)``, ``parts`` with "ce", "lb" and
+    "z" beside what the loop gives."""
+    feeds, parts, ce, aux = _causal_lm(cfg, seq_len, attn_impl, is_test,
+                                       fused_head)
+    lbs, zs = zip(*aux)
+    lb = layers.sum(list(lbs)) / float(cfg.n_layer)
+    z = layers.sum(list(zs)) / float(cfg.n_layer)
+    loss = ce + cfg.lb_coef * lb + cfg.z_coef * z
+    return feeds, dict(parts, ce=ce, lb=lb, z=z), loss
+
+
+# -- Trinity (afmoe): window and full attention mixed, gated, per-head -------
+# -- QK-norm, grouped-query; sigmoid routing beside a shared expert ----------
+
+class TrinityConfig(DecoderConfig):
+    """Trinity-Mini defaults (``arcee-ai/Trinity-Mini`` config.json,
+    ``model_type`` ``afmoe``).  One afmoe block, four norms: ``h = x +
+    RMS2(Attn(RMS1(x)))``, ``out = h + RMS4(FFN(RMS3(h)))``; no bias
+    anywhere.  ``layer_types[i]`` is ``sliding_attention`` or
+    ``full_attention``.  Attention: grouped-query, Q and K RMS-normed per
+    head (weights ``[d_head]``), rotary on ``sliding_attention`` layers only
+    (``full_attention`` layers carry no positional term), a ``window`` on
+    the sliding layers, and the output gated by ``sigmoid`` of a fourth
+    slice of the fused projection.  FFN: :func:`gated_ffn` of width
+    ``d_inner`` in the first ``n_dense_layer`` layers; else one shared
+    expert (the same builder, width ``d_expert * n_shared``) plus
+    ``moe_ffn`` over ``n_experts`` routed experts of width ``d_expert``
+    (``top_k`` a token) with sigmoid scores, a selection bias held at zero,
+    the kept scores renormalised (``+ 1e-20``) and scaled."""
+
+    sandwich_norm = True
+    qk_norm_over = "head"
+    out_gate = True
+    mixer_tags = {}
+    qk_in_turn = False
+
+    def __init__(self, vocab_size=200192, d_model=2048, n_layer=32,
+                 n_head=32, n_kv_head=4, d_head=128, d_inner=6144,
+                 d_expert=1024, n_experts=128, top_k=8, n_shared=1,
+                 n_dense_layer=2, layer_types=None, window=2048,
+                 score_func="sigmoid", route_norm=True, route_scale=2.826,
+                 rms_eps=1e-5, rope_theta=10000.0, mup=True, n_held=None,
+                 expert_offset=0, init_std=0.02):
+        super().__init__(vocab_size, d_model, n_layer, n_head, d_expert,
+                         n_experts, top_k, rms_eps, n_held, expert_offset,
+                         init_std, n_kv_head, d_head)
+        self.d_inner = d_inner
+        self.n_shared = n_shared
+        self.d_shared = d_expert * n_shared
+        self.n_dense_layer = n_dense_layer
+        self.layer_types = list(layer_types) if layer_types else [
+            "full_attention" if i % 4 == 3 else "sliding_attention"
+            for i in range(n_layer)]
+        assert len(self.layer_types) == n_layer
+        self.window = window
+        self.score_func = score_func
+        self.route_norm = route_norm
+        self.route_scale = route_scale
+        self.rope_theta = rope_theta
+        self.mup = mup
+
+    def rotary(self, idx):
+        return self.layer_types[idx] == "sliding_attention"
+
+    def window_at(self, idx):
+        return self.window if self.rotary(idx) else None     # the same layers
 
 
 def build_trinity_pretrain(cfg: TrinityConfig, seq_len, is_test=False,
                            attn_impl="flash", fused_head=True,
                            checkpoints=None):
-    """Causal LM over afmoe blocks: ids -> embedding scaled by
-    ``sqrt(d_model)`` (``mup``) -> ``n_layer`` :func:`trinity_decoder_layer`
-    -> final RMSNorm -> untied bias-free head; loss = mean next-token CE
-    (label 0 excluded, as in the other builders) and nothing else: the
-    published recipe balances load through the selection bias, which is a
-    persistent variable held at its initial zero here, not through a loss
-    term.  ``checkpoints=[]`` collects the block outputs for
-    ``RecomputeOptimizer``.  Returns ``(feeds, parts, loss)`` with ``parts``
-    = {"expert_load": [per expert layer], "hidden": the final norm's
-    output}."""
-    src_ids = layers.data("src_ids", shape=[seq_len], dtype="int64")
-    lm_label = layers.data("lm_label", shape=[seq_len], dtype="int64")
-    x = layers.embedding(src_ids, size=[cfg.vocab_size, cfg.d_model],
-                         param_attr=ParamAttr(name="word_embedding"))
-    if cfg.mup:
-        x = layers.scale(x, scale=float(cfg.d_model) ** 0.5)
-    loads = []
-    for i in range(cfg.n_layer):
-        x, load = trinity_decoder_layer(x, cfg, i, attn_impl, is_test)
-        if load is not None:
-            loads.append(load)
-        if checkpoints is not None:
-            checkpoints.append(x)
-    x = layers.rms_norm(x, begin_norm_axis=2, epsilon=cfg.rms_eps,
-                        param_attr=ParamAttr(name="final_norm.w"))
-    _, loss = _lm_head_loss(x, cfg, lm_label, fused_head, "lm_out",
-                            bias=False)
-    return (src_ids, lm_label), {"expert_load": loads, "hidden": x}, loss
+    """:func:`_causal_lm` over :class:`TrinityConfig`'s afmoe blocks, the
+    embedding scaled by ``sqrt(d_model)`` (``mup``); loss = its mean
+    next-token CE and nothing else: the published recipe balances load
+    through the selection bias, which is a persistent variable held at its
+    initial zero here, not through a loss term.  ``checkpoints=[]`` collects
+    the block outputs.  Returns its ``(feeds, parts, loss)``."""
+    return _causal_lm(cfg, seq_len, attn_impl, is_test, fused_head,
+                      checkpoints)[:3]
 
 
 # -- JoyAI-LLM-Flash (DeepSeek-V3 family): latent attention, one multi-token --
 # -- prediction module over the shared embedding and head ---------------------
 
-class JoyaiConfig:
+class JoyaiConfig(DecoderConfig):
     """JoyAI-LLM-Flash defaults (``jdopensource/JoyAI-LLM-Flash``
     config.json, ``model_type`` ``joyai_llm_flash``, the DeepSeek-V3 family's
-    keys).  Latent attention: Q through a ``q_lora_rank`` latent, K's content
-    part and V through a ``kv_lora_rank`` latent, ``d_nope + d_rope`` wide
-    scores over ``d_v`` wide values, the rotary slice on adjacent pairs and
-    its key one head for all.  The first ``n_dense_layer`` layers have a
-    dense gated FFN of width ``d_inner``, the others ``n_experts`` routed
-    experts of width ``d_expert`` (``top_k`` a token, sigmoid scores, a
-    selection bias, renormalised and scaled) beside one shared expert;
-    ``n_mtp`` multi-token-prediction modules (0 or 1) follow the last
-    layer.  ``n_held``/``expert_offset``: the experts whose weights this
-    program holds (default all), as :class:`TrinityConfig` has them.  What
-    the family's later members add is :class:`XingConfig`'s; here it is
-    absent: ``hc_mult`` 1 (the residual is the plain add) and
-    ``rope_scaling`` None (frequencies ``rope_theta^(-2i / d_rope)``, the
-    softmax scale ``(d_nope + d_rope)^-1/2``)."""
+    keys).  The block, two norms: ``h = x + MLA(RMS1(x))``, ``out = h +
+    FFN(RMS2(h))``; no bias anywhere.  Latent attention
+    (:func:`latent_attention`): Q through a ``q_lora_rank`` latent, K's
+    content part and V through a ``kv_lora_rank`` latent, ``d_nope +
+    d_rope`` wide scores over ``d_v`` wide values, the rotary slice on
+    adjacent pairs and its key one head for all.  FFN: :func:`gated_ffn` of
+    width ``d_inner`` in the first ``n_dense_layer`` layers; else the one
+    shared expert (the same builder, width ``d_expert``) plus ``moe_ffn``
+    over ``n_experts`` routed experts of width ``d_expert`` as Trinity's
+    block calls it (``noaux_tc`` with one group: ``top_k`` a token, sigmoid
+    scores, a selection bias held at zero, the kept scores renormalised
+    with ``1e-20`` and scaled).  ``n_mtp`` multi-token-prediction modules
+    (0 or 1) follow the last layer.  What the family's later members add
+    is :class:`XingConfig`'s; here it is absent: ``hc_mult`` 1 (the
+    residual is the plain add) and ``rope_scaling`` None (frequencies
+    ``rope_theta^(-2i / d_rope)``, the softmax scale ``(d_nope +
+    d_rope)^-1/2``)."""
 
     hc_mult = 1
     rope_scaling = None
@@ -643,26 +686,22 @@ class JoyaiConfig:
                  route_scale=2.5, rms_eps=1e-6, rope_theta=32000000.0,
                  n_held=None, expert_offset=0):
         assert n_mtp in (0, 1), "one multi-token-prediction depth at most"
-        self.vocab_size = vocab_size
-        self.d_model = d_model
-        self.n_layer = n_layer
-        self.n_head = n_head
+        super().__init__(vocab_size, d_model, n_layer, n_head, d_expert,
+                         n_experts, top_k, rms_eps, n_held, expert_offset)
         self.q_lora_rank = q_lora_rank
         self.kv_lora_rank = kv_lora_rank
         self.d_nope = d_nope
         self.d_rope = d_rope
         self.d_v = d_v
         self.d_inner = d_inner
-        self.d_expert = d_expert
-        self.n_experts = n_experts
-        self.top_k = top_k
+        self.d_shared = d_expert
         self.n_dense_layer = n_dense_layer
         self.n_mtp = n_mtp
         self.route_scale = route_scale
-        self.rms_eps = rms_eps
         self.rope_theta = rope_theta
-        self.n_held = n_experts if n_held is None else n_held
-        self.expert_offset = expert_offset
+
+    def mixer(self, idx):
+        return "mla"
 
 
 class XingConfig(JoyaiConfig):
@@ -709,6 +748,415 @@ class XingConfig(JoyaiConfig):
         self.rope_scaling = None if rope_scaling is None \
             else dict(rope_scaling)
 
+
+def build_joyai_pretrain(cfg: JoyaiConfig, seq_len, mtp_weight=0.3,
+                         checkpoints=None, fused_head=True):
+    """:func:`_causal_lm` over :class:`JoyaiConfig`'s blocks (``L_main`` its
+    mean CE against ``lm_label``, token ``i + 1``; ``z`` its final norm's
+    output) with one multi-token-prediction module (arXiv:2412.19437 §2.2,
+    depth 1).  With ``cfg.n_mtp``: ``u = [RMS_e(E[lm_label]) | RMS_h(z)]
+    W_eh``, one whole expert-layer block over ``u`` (positions ``0 .. T -
+    1``; the family numbers it ``n_layer``, past the dense layers), a norm,
+    and THE SAME head: ``L_mtp`` = mean CE against ``mtp_label`` (token ``i
+    + 2``).  ``E`` (``word_embedding``) and the head (``lm_out.w``) are the
+    main model's parameters, read a second time by name, so each one's
+    gradient is the sum of its two uses.  Loss = ``L_main + mtp_weight *
+    L_mtp`` and nothing else (the selection bias is held at zero, as in
+    :func:`build_trinity_pretrain`).  The module lies under the ``mtp``
+    tag.  With ``cfg.hc_mult`` > 1 (:class:`XingConfig`) the stream between
+    the blocks is ``hc_mult`` variables [b, t, d_model] and the blocks'
+    residual rule is :func:`hyper_connection`; its entry and exit are here
+    (arXiv:2409.19606 §3): the embedding copied to every stream, and the
+    streams' sum before the final norm.  ``checkpoints=[]`` collects the
+    block outputs, the module's among them.  Returns ``(feeds, parts,
+    loss)``, ``parts`` with the module's "expert_load" last and
+    "mtp_hidden" (the module's normed output), "main_loss" and "mtp_loss"
+    beside what the loop gives."""
+    wide = {}
+    if cfg.hc_mult > 1:
+        # a variable a stream: the same embedding read hc_mult times
+        wide = dict(
+            residual=hyper_connection(cfg), leave=layers.sums,
+            enter=lambda x: [layers.scale(x, scale=1.0)
+                             for _ in range(cfg.hc_mult)])
+    feeds, parts, main_loss, _ = _causal_lm(
+        cfg, seq_len, fused_head=fused_head, checkpoints=checkpoints, **wide)
+    z = parts["hidden"]
+    parts["main_loss"] = loss = main_loss
+    if cfg.n_mtp:
+        lm_label = feeds[1]
+        mtp_label = layers.data("mtp_label", shape=[seq_len], dtype="int64")
+        feeds += (mtp_label,)
+        with name_scope("mtp"):
+            e = layers.embedding(
+                lm_label, size=[cfg.vocab_size, cfg.d_model],
+                param_attr=ParamAttr(name="word_embedding"))
+            u = layers.fc(
+                layers.concat([_rms(e, cfg, "mtp_0.enorm"),
+                               _rms(z, cfg, "mtp_0.hnorm")], axis=2),
+                size=cfg.d_model, num_flatten_dims=2, bias_attr=False,
+                param_attr=ParamAttr(name="mtp_0.eh_proj.w"))
+            u, routed = decoder_block(u, cfg, cfg.n_layer,
+                                      param_prefix="mtp_0")
+            if checkpoints is not None:
+                checkpoints.append(u)
+            s = _rms(u, cfg, "mtp_0.shared_head_norm")
+            _, mtp_loss = _lm_head_loss(s, cfg, mtp_label, fused_head,
+                                        "lm_out", bias=False)
+        parts["expert_load"].append(routed[2])
+        parts.update(mtp_hidden=s, mtp_loss=mtp_loss)
+        loss = main_loss + float(mtp_weight) * mtp_loss
+    return feeds, parts, loss
+
+
+# -- SmallThinker: a router that reads the layer's input before attention, ----
+# -- ReLU-gated experts, window layers with rotary, full layers without -------
+
+class SmallThinkerConfig(DecoderConfig):
+    """SmallThinker-21BA3B-Instruct defaults
+    (``PowerInfer/SmallThinker-21BA3B-Instruct`` config.json).  Every layer
+    is an expert layer.  One block, two norms, no bias anywhere: ``n =
+    RMS1(x)``; the router scores ``n``, the layer's input, BEFORE attention
+    (six of 64, softmax over the six kept logits); ``h = x + Attn(n)``
+    (grouped-query, no QK-norm, no gate); ``out = h + sum_e p_e Wd_e
+    (relu(Wg_e m) * Wu_e m)`` over ``m = RMS2(h)``: the scores are taken
+    before attention and consumed after it, so ``moe_ffn`` gets
+    ``router_x=n`` beside its rows ``m``.  ``sliding_window_layout[i]`` 1:
+    layer ``i`` sees ``window`` keys back, 0: the whole causal half;
+    ``rope_layout[i]`` 1: rotate-half rotary on Q and K, 0: no positional
+    term (published: the two layouts are one, full layers first of every
+    four)."""
+
+    score_func = "softmax"
+    select_bias = False
+    route_norm_eps = 0.0
+    act = "relu"
+    router_before_mixer = True
+    tag_covers_add = ("gqa",)
+
+    def __init__(self, vocab_size=151936, d_model=2560, n_layer=52,
+                 n_head=28, n_kv_head=4, d_head=128, d_expert=768,
+                 n_experts=64, top_k=6, window=4096,
+                 sliding_window_layout=None, rope_layout=None,
+                 rms_eps=1e-6, rope_theta=1.5e6, n_held=None,
+                 expert_offset=0, init_std=0.02):
+        super().__init__(vocab_size, d_model, n_layer, n_head, d_expert,
+                         n_experts, top_k, rms_eps, n_held, expert_offset,
+                         init_std, n_kv_head, d_head)
+        self.window = window
+        self.sliding_window_layout = list(sliding_window_layout) \
+            if sliding_window_layout is not None else \
+            [int(i % 4 != 0) for i in range(n_layer)]
+        self.rope_layout = list(rope_layout) if rope_layout is not None \
+            else list(self.sliding_window_layout)
+        assert len(self.sliding_window_layout) == n_layer
+        assert len(self.rope_layout) == n_layer
+        self.rope_theta = rope_theta
+
+    def rotary(self, idx):
+        return bool(self.rope_layout[idx])
+
+    def window_at(self, idx):
+        return self.window if self.sliding_window_layout[idx] else None
+
+
+def build_smallthinker_pretrain(cfg: SmallThinkerConfig, seq_len,
+                                is_test=False, attn_impl="flash",
+                                fused_head=True, checkpoints=None):
+    """:func:`_causal_lm` over :class:`SmallThinkerConfig`'s blocks; loss =
+    its mean next-token CE and nothing else (``config.json`` names no
+    auxiliary loss).  ``checkpoints=[]`` collects the block outputs.
+    Returns its ``(feeds, parts, loss)``."""
+    return _causal_lm(cfg, seq_len, attn_impl, is_test, fused_head,
+                      checkpoints)[:3]
+
+
+# -- LFM2 (lfm2_moe): gated short-convolution operators beside grouped-query --
+# -- attention, sigmoid routing, one table for the embedding and the head -----
+
+class Lfm2Config(DecoderConfig):
+    """LFM2-8B-A1B defaults (``LiquidAI/LFM2-8B-A1B`` config.json,
+    ``model_type`` ``lfm2_moe``).  One lfm2_moe block, pre-norm, two norms,
+    no bias anywhere: ``h = x + Op(RMS1(x))``, ``out = h + FF(RMS2(h))``.
+    ``Op``: where ``layer_types[i]`` says ``full_attention``, grouped-query
+    attention with Q and K RMS-normed per head (weights ``[d_head]``) and
+    then rotated (rotate-half), under the ``attention_operator`` tag; where
+    it says ``conv``, :func:`short_conv_operator` (the gated short
+    convolution of ``conv_taps`` taps) under ``conv_operator``.  ``FF``:
+    :func:`gated_ffn` of width ``d_inner`` in the first ``n_dense_layer``
+    layers; else ``moe_ffn`` over ``n_experts`` routed experts of width
+    ``d_expert`` (``top_k`` a token) with sigmoid scores, a selection bias
+    held at zero, the kept scores renormalised (``+ 1e-6``) and scaled, no
+    shared expert.  The head reads the embedding table."""
+
+    tie_embeddings = True
+    qk_norm_over = "head"
+    mixer_tags = {"gqa": "attention_operator", "conv": "conv_operator"}
+    route_norm_eps = 1e-6
+    tag_covers_add = ("dense",)
+
+    def __init__(self, vocab_size=65536, d_model=2048, n_layer=24, n_head=32,
+                 n_kv_head=8, d_head=64, d_inner=7168, d_expert=1792,
+                 n_experts=32, top_k=4, n_dense_layer=2, layer_types=None,
+                 conv_taps=3, route_scale=1.0, rms_eps=1e-5, rope_theta=1e6,
+                 n_held=None, expert_offset=0, init_std=0.02):
+        super().__init__(vocab_size, d_model, n_layer, n_head, d_expert,
+                         n_experts, top_k, rms_eps, n_held, expert_offset,
+                         init_std, n_kv_head, d_head)
+        self.d_inner = d_inner
+        self.n_dense_layer = n_dense_layer
+        # published: attention at layers 2, 6, 10, 14, 18 and 21 of 24
+        self.layer_types = list(layer_types) if layer_types else [
+            "full_attention" if i in (2, 6, 10, 14, 18, 21) else "conv"
+            for i in range(n_layer)]
+        assert len(self.layer_types) == n_layer
+        self.conv_taps = conv_taps
+        self.route_scale = route_scale
+        self.rope_theta = rope_theta
+
+    def mixer(self, idx):
+        return "gqa" if self.layer_types[idx] == "full_attention" else "conv"
+
+
+def build_lfm2_pretrain(cfg: Lfm2Config, seq_len, is_test=False,
+                        attn_impl="flash", fused_head=True, checkpoints=None):
+    """:func:`_causal_lm` over :class:`Lfm2Config`'s blocks, the logits
+    over the embedding table itself (there is no ``lm_out.w``); loss = its
+    mean next-token CE and nothing else (the selection bias is held at
+    zero, as in :func:`build_trinity_pretrain`).  ``checkpoints=[]``
+    collects the block outputs.  Returns its ``(feeds, parts, loss)``."""
+    return _causal_lm(cfg, seq_len, attn_impl, is_test, fused_head,
+                      checkpoints)[:3]
+
+
+# -- Solar Open 2 (solar_open2; Kimi Linear's layer): gated delta-rule linear --
+# -- attention 3:1 with gated position-free grouped-query attention, sigmoid ----
+# -- routing beside a shared expert; a chip may hold a share of a layer's heads -
+
+class SolarOpen2Config(DecoderConfig):
+    """Solar-Open2-250B defaults (``upstage/Solar-Open2-250B`` config.json,
+    ``model_type`` ``solar_open2``; the linear-attention layer is Kimi
+    Linear's KDA, arXiv:2510.26692).  One solar_open2 block, pre-norm, two
+    norms, no bias anywhere: ``h = x + Mixer(RMS1(x))``, ``out = h +
+    Shared(RMS2(h)) + MoE(RMS2(h))``.  ``Mixer``: in ``gqa_layers``
+    grouped-query softmax attention over the whole causal half with NO
+    positional term (``use_rope`` false) and the output gated by
+    ``sigmoid`` of a fourth slice of the fused projection
+    (``use_gqa_gate``), under the ``attn`` tag, at the ``n_head`` over
+    ``n_kv_head`` heads held here (the fused projection is ``[d_model, (2
+    n_head + 2 n_kv_head) d_head]`` and the output projection ``[n_head
+    d_head, d_model]``: narrower than ``d_model`` where a chip holds a
+    share); else :func:`kda_attention`.  ``MoE``, in every layer:
+    ``moe_ffn`` over ``n_experts`` routed experts of width ``d_expert``
+    (``top_k`` a token) with sigmoid scores, a selection bias held at zero,
+    the kept scores renormalised (``+ 1e-20``) and scaled; ``Shared``:
+    :func:`gated_ffn` of width ``d_expert * n_shared``.
+
+    ``n_head``, ``n_kv_head`` and ``n_kda_head`` are the heads this program
+    HOLDS, default all 64, 8 and 64: under tensor parallelism a chip holds a
+    share of a layer's heads, every head at its published ``d_head``, and
+    the output projections give the partial sum over the held heads (the
+    all-reduce is the deployment's)."""
+
+    out_gate = True
+
+    def __init__(self, vocab_size=196608, d_model=4096, n_layer=48,
+                 n_head=64, n_kv_head=8, n_kda_head=64, d_head=128,
+                 d_expert=1280, n_experts=320, top_k=8, n_shared=1,
+                 gqa_layers=None, conv_taps=4, kda_gate_rank=128,
+                 kda_neg_eigval=True, kda_chunk=64, route_scale=1.0,
+                 rms_eps=1e-5, n_held=None, expert_offset=0, init_std=0.02):
+        super().__init__(vocab_size, d_model, n_layer, n_head, d_expert,
+                         n_experts, top_k, rms_eps, n_held, expert_offset,
+                         init_std, n_kv_head, d_head)
+        self.n_kda_head = n_kda_head
+        self.n_shared = n_shared
+        self.d_shared = d_expert * n_shared
+        # published: a GQA layer first of every four
+        self.gqa_layers = sorted(gqa_layers) if gqa_layers is not None \
+            else list(range(0, n_layer, 4))
+        self.conv_taps = conv_taps
+        self.kda_gate_rank = kda_gate_rank
+        self.kda_neg_eigval = kda_neg_eigval
+        self.kda_chunk = kda_chunk
+        self.route_scale = route_scale
+
+    def mixer(self, idx):
+        return "gqa" if idx in self.gqa_layers else "kda"
+
+
+def build_solar_open2_pretrain(cfg: SolarOpen2Config, seq_len, is_test=False,
+                               attn_impl="flash", fused_head=True,
+                               checkpoints=None):
+    """:func:`_causal_lm` over :class:`SolarOpen2Config`'s blocks; loss =
+    its mean next-token CE and nothing else (the selection bias is held at
+    zero, as in :func:`build_trinity_pretrain`).  ``checkpoints=[]``
+    collects the block boundaries: the embedding's output and every
+    block's, so that every block is computed again, the first too.  Returns
+    its ``(feeds, parts, loss)``."""
+    return _causal_lm(cfg, seq_len, attn_impl, is_test, fused_head,
+                      checkpoints, checkpoint_input=True)[:3]
+
+
+# -- Ling 3.0: KDA with a bounded gate beside latent attention, --------------
+# -- group-limited routing, a leading dense layer ----------------------------
+
+class LingConfig(DecoderConfig):
+    """Ling-3.0-flash defaults (``inclusionAI/Ling-3.0-flash-VL``
+    config.json, the language model; the vision tower is not built).  One
+    Ling block, pre-norm, two norms, no bias anywhere: ``u = x +
+    Mixer(RMS1(x))``, ``out = u + FFN(RMS2(u))``.  ``Mixer``: layer ``i``
+    (published number) is latent attention where ``(i + 1) %
+    layer_group_size == 0`` (``mla_layers``) and KDA otherwise: five KDA
+    layers, then one MLA layer.  KDA (:func:`kda_attention`): both gates at
+    full rank (``no_kda_lora``), the decay's gate bounded below by
+    ``kda_lower_bound`` (``kda_safe_gate``), beta not doubled.  MLA
+    (:func:`latent_attention`): Q at full rank (``q_lora_rank`` None),
+    QK-norm on the content parts, a head-wise output gate.  ``FFN``:
+    :func:`gated_ffn` of width ``d_inner`` in the first ``n_dense_layer``
+    layers (``dense_layers``; the ``dense_ffn`` tag); else the shared
+    expert (the same builder at ``d_shared``) plus ``moe_ffn`` over
+    ``n_experts`` routed experts of width ``d_expert`` (``top_k`` a token
+    among the ``topk_group`` best of ``n_group`` groups) with sigmoid
+    scores, a selection bias held at zero, the kept scores renormalised
+    (``+ 1e-20``) and scaled.
+
+    ``first_layer``: the published number of this program's layer 0, where
+    it holds a run of the layers (a pipeline stage); the dense layers and
+    the MLA layers follow the published numbers.  ``n_head`` and
+    ``n_kda_head`` are the heads HELD (default all 32), as
+    :class:`SolarOpen2Config`."""
+
+    rope_scaling = None
+    kda_neg_eigval = False
+    qk_norm = True
+    head_gate = True
+    tag_covers_add = ("dense",)
+
+    def __init__(self, vocab_size=157184, d_model=2560, n_layer=42,
+                 n_head=32, n_kda_head=32, d_head=128, kv_lora_rank=512,
+                 d_nope=128, d_rope=64, d_v=128, d_inner=6144, d_expert=768,
+                 d_shared=768, n_experts=512, top_k=8, n_group=8,
+                 topk_group=4, n_dense_layer=2, layer_group_size=6,
+                 first_layer=0, conv_taps=4, kda_lower_bound=-5.0,
+                 kda_chunk=64, route_scale=2.5, rms_eps=1e-6,
+                 rope_theta=6000000.0, n_held=None, expert_offset=0,
+                 init_std=0.02):
+        super().__init__(vocab_size, d_model, n_layer, n_head, d_expert,
+                         n_experts, top_k, rms_eps, n_held, expert_offset,
+                         init_std, d_head=d_head)
+        self.n_kda_head = n_kda_head
+        self.q_lora_rank = None
+        self.kv_lora_rank = kv_lora_rank
+        self.d_nope = d_nope
+        self.d_rope = d_rope
+        self.d_v = d_v
+        self.d_inner = d_inner
+        self.d_shared = d_shared
+        self.n_group = self.n_route_group = n_group
+        self.topk_group = topk_group
+        self.layer_group_size = layer_group_size
+        self.first_layer = first_layer
+        numbers = range(first_layer, first_layer + n_layer)
+        self.dense_layers = [j for j, i in enumerate(numbers)
+                             if i < n_dense_layer]
+        self.mla_layers = [j for j, i in enumerate(numbers)
+                           if (i + 1) % layer_group_size == 0]
+        self.conv_taps = conv_taps
+        self.kda_gate_rank = None
+        self.kda_lower_bound = kda_lower_bound
+        self.kda_chunk = kda_chunk
+        self.route_scale = route_scale
+        self.rope_theta = rope_theta
+
+    def mixer(self, idx):
+        return "mla" if idx in self.mla_layers else "kda"
+
+    def ffn(self, idx):
+        return "dense" if idx in self.dense_layers else "routed"
+
+
+def build_ling_pretrain(cfg: LingConfig, seq_len, fused_head=True,
+                        checkpoints=None):
+    """:func:`_causal_lm` over :class:`LingConfig`'s blocks; loss = its mean
+    next-token CE and nothing else (the selection bias is held at zero and
+    there is no auxiliary term).  ``checkpoints=[]`` collects the block
+    boundaries: the embedding's output and every block's, KDA, MLA, dense
+    or expert alike, as :func:`build_solar_open2_pretrain`.  Returns its
+    ``(feeds, parts, loss)``."""
+    return _causal_lm(cfg, seq_len, fused_head=fused_head,
+                      checkpoints=checkpoints, checkpoint_input=True)[:3]
+
+
+# -- Nemotron-H: Mamba-2 (SSD) mixers, position-free grouped-query attention --
+# -- and un-gated ReLU^2 experts, ONE sublayer a block ------------------------
+
+class NemotronHConfig(DecoderConfig):
+    """NVIDIA-Nemotron-3-Nano-30B-A3B defaults (``nvidia/NVIDIA-Nemotron-3-
+    Nano-30B-A3B-BF16`` config.json, ``model_type`` ``nemotron_h``; Mamba-2:
+    arXiv:2405.21060, Nemotron-H: arXiv:2504.03624).  One nemotron_h block:
+    ONE sublayer, pre-norm, no bias: ``out = x + Mixer(RMS(x))`` and no
+    second half (the one norm is ``<prefix>.norm.w``).  ``pattern`` (the
+    row's ``hybrid_override_pattern``) gives each block's sublayer by its
+    letter: ``M`` a Mamba-2 mixer (:func:`mamba2_mixer`: ``n_mamba_head``
+    heads of ``d_mamba_head``, ``n_group`` groups sharing ``B`` / ``C`` of
+    ``d_state``, ``conv_taps`` taps with a bias, chunks of ``chunk``); ``*``
+    causal grouped-query softmax attention with NO positional term, no gate
+    and no QK-norm (``n_head`` over ``n_kv_head`` heads of ``d_head``, the
+    ``attn`` tag); ``E`` the shared expert (:func:`relu2_ffn` at
+    ``d_shared``, the ``shared_expert`` tag) plus ``moe_ffn`` over
+    ``n_experts`` routed un-gated ReLU^2 experts of width ``d_expert``
+    (``top_k`` a token) with sigmoid scores, a selection bias held at zero,
+    the kept scores renormalised (``+ 1e-20``) and scaled."""
+
+    gated = False
+    act = "relu2"
+    tag_covers_add = ("gqa",)
+
+    def __init__(self, vocab_size=131072, d_model=2688,
+                 pattern="MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*"
+                         "EMEMEMEME",
+                 n_mamba_head=64, d_mamba_head=64, n_group=8, d_state=128,
+                 conv_taps=4, chunk=128, n_head=32, n_kv_head=2, d_head=128,
+                 d_expert=1856, d_shared=3712, n_experts=128, top_k=6,
+                 route_scale=2.5, rms_eps=1e-5, n_held=None, expert_offset=0,
+                 init_std=0.02):
+        if set(pattern) - set("ME*"):
+            raise ValueError(f"pattern {pattern!r}: M, E and * are built")
+        super().__init__(vocab_size, d_model, len(pattern), n_head, d_expert,
+                         n_experts, top_k, rms_eps, n_held, expert_offset,
+                         init_std, n_kv_head, d_head)
+        self.pattern = pattern
+        self.n_mamba_head = n_mamba_head
+        self.d_mamba_head = d_mamba_head
+        self.n_group = n_group
+        self.d_state = d_state
+        self.conv_taps = conv_taps
+        self.chunk = chunk
+        self.d_shared = d_shared
+        self.route_scale = route_scale
+
+    def mixer(self, idx):
+        return {"M": "mamba2", "*": "gqa", "E": None}[self.pattern[idx]]
+
+    def ffn(self, idx):
+        return "routed" if self.pattern[idx] == "E" else None
+
+
+def build_nemotron_h_pretrain(cfg: NemotronHConfig, seq_len, fused_head=True,
+                              checkpoints=None, attn_impl="flash"):
+    """:func:`_causal_lm` over :class:`NemotronHConfig`'s
+    ``len(cfg.pattern)`` one-sublayer blocks; loss = its mean next-token CE
+    and nothing else (the selection bias is held at zero and there is no
+    auxiliary term).  ``checkpoints=[]`` collects the block boundaries: the
+    embedding's output and every block's, Mamba, attention or expert alike,
+    as :func:`build_solar_open2_pretrain`.  Returns its ``(feeds, parts,
+    loss)``."""
+    return _causal_lm(cfg, seq_len, attn_impl, fused_head=fused_head,
+                      checkpoints=checkpoints, checkpoint_input=True)[:3]
+
+
+# -- the sequence mixers the configurations name ----------------------------
 
 def yarn_softmax_factor(rope_scaling):
     """What YaRN multiplies the softmax scale by (the DeepSeek family's
@@ -771,10 +1219,8 @@ def latent_attention(x, cfg: JoyaiConfig, param_prefix="attn"):
         return layers.fc(v, size=size, num_flatten_dims=2, bias_attr=False,
                          param_attr=ParamAttr(name=f"{param_prefix}.{name}.w"))
 
-    def norm(v, name):
-        return layers.rms_norm(
-            v, begin_norm_axis=2, epsilon=cfg.rms_eps,
-            param_attr=ParamAttr(name=f"{param_prefix}.{name}.w"))
+    def norm(v, name, axis=2):
+        return _rms(v, cfg, f"{param_prefix}.{name}", axis)
 
     def heads(v, width):                      # [b, t, h * w] -> [b, h, t, w]
         return layers.transpose(
@@ -799,12 +1245,8 @@ def latent_attention(x, cfg: JoyaiConfig, param_prefix="attn"):
             heads(proj(norm(c_kv, "kv_norm"), h * (dn + dv), "kv_b"),
                   dn + dv), [dn, dv], dim=3)
         if qk_norm:
-            q_nope, k_nope = (
-                layers.rms_norm(t, begin_norm_axis=3, epsilon=cfg.rms_eps,
-                                param_attr=ParamAttr(
-                                    name=f"{param_prefix}.{n}.w"))
-                for t, n in ((q_nope, "q_nope_norm"),
-                             (k_nope, "k_nope_norm")))
+            q_nope, k_nope = (norm(t, n, 3) for t, n in (
+                (q_nope, "q_nope_norm"), (k_nope, "k_nope_norm")))
         k_r = rotate(layers.unsqueeze(k_r, [1]))            # [b, 1, t, dr]
         q_rope = rotate(q_rope)
     ctx = layers.flash_attention(
@@ -818,314 +1260,6 @@ def latent_attention(x, cfg: JoyaiConfig, param_prefix="attn"):
                 layers.sigmoid(proj(x, h, "gate")), [3])
         ctx = layers.reshape(ctx, shape=[0, 0, h * dv])
         return proj(ctx, cfg.d_model, "out")
-
-
-def plain_residual(x, sublayer, name):
-    """The residual rule every block here had hard-wired: ``x + F(norm(x))``.
-    ``sublayer(x)`` norms its input and returns ``F``'s terms, which are
-    added to ``x`` one by one, in their order."""
-    for term in sublayer(x):
-        x = x + term
-    return x
-
-
-def hyper_connection(cfg: XingConfig):
-    """The residual rule of a stream ``cfg.hc_mult`` wide, held as a list
-    of that many [b, t, d] variables (manifold-constrained
-    hyper-connections, arXiv:2512.24880 §4; ``layers.hc_pre`` /
-    ``layers.hc_post``): the sublayer reads ``u``, a learned token-dependent
-    mix of the streams, and its output is written back to every stream
-    beside a doubly stochastic mix of the streams themselves.  Parameters
-    ``<name>.phi``, ``.alpha``, ``.bias``, one set a sublayer."""
-    def rule(x, sublayer, name):
-        u, h_post, h_res = layers.hc_pre(
-            x, cfg.hc_sinkhorn_iters, cfg.hc_eps, cfg.rms_eps,
-            cfg.hc_res_clamp, param_prefix=name)
-        y = None
-        for term in sublayer(u):
-            y = term if y is None else y + term
-        return layers.hc_post(x, y, h_post, h_res, cfg.hc_sinkhorn_iters)
-    return rule
-
-
-def joyai_decoder_layer(x, cfg: JoyaiConfig, idx=0, dense=None,
-                        param_prefix=None, residual=plain_residual):
-    """Pre-norm block, two norms: ``h = x + MLA(RMS1(x))``, ``out = h +
-    FFN(RMS2(h))``; no bias anywhere.  FFN: :func:`gated_ffn` of width
-    ``d_inner`` in a dense layer (``dense``, default ``idx <
-    n_dense_layer``); else the one shared expert (the same builder, width
-    ``d_expert``) plus ``moe_ffn`` as Trinity's block calls it
-    (``noaux_tc`` with one group: sigmoid scores, a selection bias held at
-    zero, the kept scores renormalised with ``1e-20`` and scaled).
-    ``residual(x, sublayer, name)`` is the rule that puts a sublayer's
-    output back into the stream, :func:`plain_residual` (the ``+`` above) by
-    default; :func:`hyper_connection` makes the one of a widened stream,
-    whose parameters are ``<prefix>.hc_attn.*`` and ``<prefix>.hc_ffn.*``.
-    ``param_prefix`` (default ``dec_<idx>``) names the parameters.  Returns
-    ``(out, expert_load or None)``."""
-    from ..initializer import NormalInitializer
-    p = param_prefix or f"dec_{idx}"
-    dense = idx < cfg.n_dense_layer if dense is None else dense
-    loads = []
-
-    def norm(v, name):
-        return layers.rms_norm(v, begin_norm_axis=2, epsilon=cfg.rms_eps,
-                               param_attr=ParamAttr(name=f"{p}.{name}.w"))
-
-    def attention(u):
-        return [latent_attention(norm(u, "ln1"), cfg, f"{p}.attn")]
-
-    def ffn(u):
-        m = norm(u, "ln2")
-        if dense:
-            with name_scope("dense_ffn"):
-                return [gated_ffn(m, cfg.d_inner, cfg.d_model, f"{p}.ffn")]
-        with name_scope("shared_expert"):
-            f = gated_ffn(m, cfg.d_expert, cfg.d_model, f"{p}.shared")
-        moe, _, _, load = layers.moe_ffn(
-            m, cfg.n_experts, cfg.top_k, cfg.d_expert,
-            norm_topk_prob=True, param_prefix=f"{p}.moe",
-            initializer=NormalInitializer(0.0, 0.02),
-            score_func="sigmoid", select_bias=True, norm_eps=1e-20,
-            route_scale=cfg.route_scale, num_held=cfg.n_held,
-            expert_offset=cfg.expert_offset)
-        loads.append(load)
-        return [f, moe]
-
-    h = residual(x, attention, f"{p}.hc_attn")
-    out = residual(h, ffn, f"{p}.hc_ffn")
-    return out, (loads[0] if loads else None)
-
-
-def build_joyai_pretrain(cfg: JoyaiConfig, seq_len, mtp_weight=0.3,
-                         checkpoints=None, fused_head=True):
-    """Causal LM over :func:`joyai_decoder_layer` blocks with one
-    multi-token-prediction module (arXiv:2412.19437 §2.2, depth 1): ids ->
-    embedding -> ``n_layer`` blocks -> final RMSNorm ``z`` -> untied
-    bias-free head, ``L_main`` = mean CE against ``lm_label`` (token ``i +
-    1``).  With ``cfg.n_mtp``: ``u = [RMS_e(E[lm_label]) | RMS_h(z)]
-    W_eh``, one whole expert-layer block over ``u`` (positions ``0 .. T -
-    1``), a norm, and THE SAME head: ``L_mtp`` = mean CE against
-    ``mtp_label`` (token ``i + 2``).  ``E`` (``word_embedding``) and the head
-    (``lm_out.w``) are the main model's parameters, read a second time by
-    name, so each one's gradient is the sum of its two uses.  Loss =
-    ``L_main + mtp_weight * L_mtp`` and nothing else (the selection bias is
-    held at zero, as in :func:`build_trinity_pretrain`).  The module lies
-    under the ``mtp`` tag.  With ``cfg.hc_mult`` > 1 (:class:`XingConfig`)
-    the stream between the blocks is ``hc_mult`` variables [b, t, d_model]
-    and the blocks' residual rule is :func:`hyper_connection`; its entry and
-    exit are here (arXiv:2409.19606 §3): the embedding copied to every
-    stream, and the streams' sum before the final norm.  ``checkpoints=[]``
-    collects the block outputs (the module's among them; every stream of a
-    widened one) for ``RecomputeOptimizer``.  Returns ``(feeds,
-    parts, loss)`` with ``parts`` = {"expert_load": [per expert layer, the
-    module's last], "hidden": ``z``, "mtp_hidden": the module's normed
-    output, "main_loss", "mtp_loss"}."""
-    src_ids = layers.data("src_ids", shape=[seq_len], dtype="int64")
-    lm_label = layers.data("lm_label", shape=[seq_len], dtype="int64")
-    feeds = [src_ids, lm_label]
-
-    def embed(ids):
-        return layers.embedding(ids, size=[cfg.vocab_size, cfg.d_model],
-                                param_attr=ParamAttr(name="word_embedding"))
-
-    def norm(v, name):
-        return layers.rms_norm(v, begin_norm_axis=2, epsilon=cfg.rms_eps,
-                               param_attr=ParamAttr(name=name))
-
-    x = embed(src_ids)
-    residual = plain_residual
-    if cfg.hc_mult > 1:
-        residual = hyper_connection(cfg)
-        # a variable a stream: the same embedding read hc_mult times
-        x = [layers.scale(x, scale=1.0) for _ in range(cfg.hc_mult)]
-    loads = []
-    for i in range(cfg.n_layer):
-        x, load = joyai_decoder_layer(x, cfg, i, residual=residual)
-        if load is not None:
-            loads.append(load)
-        if checkpoints is not None:
-            checkpoints.extend(x if cfg.hc_mult > 1 else [x])
-    if cfg.hc_mult > 1:
-        x = layers.sums(x)
-    z = norm(x, "final_norm.w")
-    _, main_loss = _lm_head_loss(z, cfg, lm_label, fused_head, "lm_out",
-                                 bias=False)
-    parts = {"expert_load": loads, "hidden": z, "main_loss": main_loss}
-    loss = main_loss
-    if cfg.n_mtp:
-        mtp_label = layers.data("mtp_label", shape=[seq_len], dtype="int64")
-        feeds.append(mtp_label)
-        with name_scope("mtp"):
-            u = layers.fc(
-                layers.concat([norm(embed(lm_label), "mtp_0.enorm.w"),
-                               norm(z, "mtp_0.hnorm.w")], axis=2),
-                size=cfg.d_model, num_flatten_dims=2, bias_attr=False,
-                param_attr=ParamAttr(name="mtp_0.eh_proj.w"))
-            u, load = joyai_decoder_layer(u, cfg, dense=False,
-                                          param_prefix="mtp_0")
-            if checkpoints is not None:
-                checkpoints.append(u)
-            s = norm(u, "mtp_0.shared_head_norm.w")
-            _, mtp_loss = _lm_head_loss(s, cfg, mtp_label, fused_head,
-                                        "lm_out", bias=False)
-        loads.append(load)
-        parts.update(mtp_hidden=s, mtp_loss=mtp_loss)
-        loss = main_loss + float(mtp_weight) * mtp_loss
-    return tuple(feeds), parts, loss
-
-
-# -- SmallThinker: a router that reads the layer's input before attention, ----
-# -- ReLU-gated experts, window layers with rotary, full layers without -------
-
-class SmallThinkerConfig:
-    """SmallThinker-21BA3B-Instruct defaults
-    (``PowerInfer/SmallThinker-21BA3B-Instruct`` config.json).  Every layer
-    is an expert layer.  ``sliding_window_layout[i]`` 1: layer ``i`` sees
-    ``window`` keys back, 0: the whole causal half; ``rope_layout[i]`` 1:
-    rotary on Q and K, 0: no positional term (published: the two layouts are
-    one, full layers first of every four).  ``n_held``/``expert_offset``: the
-    experts whose weights this program holds (default all), as
-    :class:`TrinityConfig`."""
-
-    def __init__(self, vocab_size=151936, d_model=2560, n_layer=52,
-                 n_head=28, n_kv_head=4, d_head=128, d_expert=768,
-                 n_experts=64, top_k=6, window=4096,
-                 sliding_window_layout=None, rope_layout=None,
-                 rms_eps=1e-6, rope_theta=1.5e6, n_held=None,
-                 expert_offset=0, init_std=0.02):
-        self.vocab_size = vocab_size
-        self.d_model = d_model
-        self.n_layer = n_layer
-        self.n_head = n_head
-        self.n_kv_head = n_kv_head
-        self.d_head = d_head
-        self.d_expert = d_expert
-        self.n_experts = n_experts
-        self.top_k = top_k
-        self.window = window
-        self.sliding_window_layout = list(sliding_window_layout) \
-            if sliding_window_layout is not None else \
-            [int(i % 4 != 0) for i in range(n_layer)]
-        self.rope_layout = list(rope_layout) if rope_layout is not None \
-            else list(self.sliding_window_layout)
-        assert len(self.sliding_window_layout) == n_layer
-        assert len(self.rope_layout) == n_layer
-        self.rms_eps = rms_eps
-        self.rope_theta = rope_theta
-        self.n_held = n_experts if n_held is None else n_held
-        self.expert_offset = expert_offset
-        self.init_std = init_std
-
-
-def smallthinker_decoder_layer(x, cfg: SmallThinkerConfig, idx=0,
-                               attn_impl="flash", is_test=False):
-    """One SmallThinker block, two norms, no bias anywhere: ``n = RMS1(x)``;
-    the router scores ``n``, the layer's input, BEFORE attention (six of 64,
-    softmax over the six kept logits); ``h = x + Attn(n)`` (grouped-query,
-    no QK-norm, no gate; rotate-half rotary where ``rope_layout`` says, a
-    ``window`` where ``sliding_window_layout`` says); ``out = h + sum_e p_e
-    Wd_e (relu(Wg_e m) * Wu_e m)`` over ``m = RMS2(h)``: the scores are
-    taken before attention and consumed after it, so ``moe_ffn`` gets
-    ``router_x=n`` beside its rows ``m``.  Returns ``(out, expert_load)``."""
-    from ..initializer import NormalInitializer
-    p = f"dec_{idx}"
-
-    def norm(v, name):
-        return layers.rms_norm(v, begin_norm_axis=2, epsilon=cfg.rms_eps,
-                               param_attr=ParamAttr(name=f"{p}.{name}.w"))
-
-    def rotate(q, k):
-        return (layers.rope(q, cfg.d_head, cfg.rope_theta),
-                layers.rope(k, cfg.d_head, cfg.rope_theta))
-
-    n = norm(x, "ln1")
-    with name_scope("attn"):
-        h = x + multi_head_attention(
-            n, n, n, cfg.d_model, cfg.n_head, is_test=is_test,
-            param_prefix=f"{p}.attn", attn_impl=attn_impl, causal=True,
-            bias=False, n_kv_head=cfg.n_kv_head, d_head=cfg.d_head,
-            window=cfg.window if cfg.sliding_window_layout[idx] else None,
-            head_hook=rotate if cfg.rope_layout[idx] else None)
-    moe, _, _, load = layers.moe_ffn(
-        norm(h, "ln2"), cfg.n_experts, cfg.top_k, cfg.d_expert,
-        norm_topk_prob=True, param_prefix=f"{p}.moe",
-        initializer=NormalInitializer(0.0, cfg.init_std),
-        num_held=cfg.n_held, expert_offset=cfg.expert_offset, act="relu",
-        router_x=n)
-    return h + moe, load
-
-
-def build_smallthinker_pretrain(cfg: SmallThinkerConfig, seq_len,
-                                is_test=False, attn_impl="flash",
-                                fused_head=True, checkpoints=None):
-    """Causal LM over :func:`smallthinker_decoder_layer` blocks: ids ->
-    embedding -> ``n_layer`` blocks -> final RMSNorm -> untied bias-free
-    head; loss = mean next-token CE (label 0 excluded, as in the other
-    builders) and nothing else (``config.json`` names no auxiliary loss).
-    ``checkpoints=[]`` collects the block outputs for ``RecomputeOptimizer``.
-    Returns ``(feeds, parts, loss)`` with ``parts`` = {"expert_load": [per
-    layer], "hidden": the final norm's output}."""
-    src_ids = layers.data("src_ids", shape=[seq_len], dtype="int64")
-    lm_label = layers.data("lm_label", shape=[seq_len], dtype="int64")
-    x = layers.embedding(src_ids, size=[cfg.vocab_size, cfg.d_model],
-                         param_attr=ParamAttr(name="word_embedding"))
-    loads = []
-    for i in range(cfg.n_layer):
-        x, load = smallthinker_decoder_layer(x, cfg, i, attn_impl, is_test)
-        loads.append(load)
-        if checkpoints is not None:
-            checkpoints.append(x)
-    x = layers.rms_norm(x, begin_norm_axis=2, epsilon=cfg.rms_eps,
-                        param_attr=ParamAttr(name="final_norm.w"))
-    _, loss = _lm_head_loss(x, cfg, lm_label, fused_head, "lm_out",
-                            bias=False)
-    return (src_ids, lm_label), {"expert_load": loads, "hidden": x}, loss
-
-
-# -- LFM2 (lfm2_moe): gated short-convolution operators beside grouped-query --
-# -- attention, sigmoid routing, one table for the embedding and the head -----
-
-class Lfm2Config:
-    """LFM2-8B-A1B defaults (``LiquidAI/LFM2-8B-A1B`` config.json,
-    ``model_type`` ``lfm2_moe``).  ``layer_types[i]`` is ``conv`` (the gated
-    short convolution of ``conv_taps`` taps) or ``full_attention``
-    (grouped-query, per-head QK-norm, rotary); the first ``n_dense_layer``
-    layers have a dense gated FFN of width ``d_inner``, the others
-    ``n_experts`` routed experts of width ``d_expert`` (``top_k`` a token,
-    sigmoid scores, a selection bias, the kept scores renormalised with
-    ``1e-6`` and scaled) and no shared expert; the head reads the embedding
-    table.  ``n_held``/``expert_offset``: the experts whose weights this
-    program holds (default all), as :class:`TrinityConfig`."""
-
-    def __init__(self, vocab_size=65536, d_model=2048, n_layer=24, n_head=32,
-                 n_kv_head=8, d_head=64, d_inner=7168, d_expert=1792,
-                 n_experts=32, top_k=4, n_dense_layer=2, layer_types=None,
-                 conv_taps=3, route_scale=1.0, rms_eps=1e-5, rope_theta=1e6,
-                 n_held=None, expert_offset=0, init_std=0.02):
-        self.vocab_size = vocab_size
-        self.d_model = d_model
-        self.n_layer = n_layer
-        self.n_head = n_head
-        self.n_kv_head = n_kv_head
-        self.d_head = d_head
-        self.d_inner = d_inner
-        self.d_expert = d_expert
-        self.n_experts = n_experts
-        self.top_k = top_k
-        self.n_dense_layer = n_dense_layer
-        # published: attention at layers 2, 6, 10, 14, 18 and 21 of 24
-        self.layer_types = list(layer_types) if layer_types else [
-            "full_attention" if i in (2, 6, 10, 14, 18, 21) else "conv"
-            for i in range(n_layer)]
-        assert len(self.layer_types) == n_layer
-        self.conv_taps = conv_taps
-        self.route_scale = route_scale
-        self.rms_eps = rms_eps
-        self.rope_theta = rope_theta
-        self.n_held = n_experts if n_held is None else n_held
-        self.expert_offset = expert_offset
-        self.init_std = init_std
 
 
 def short_conv_operator(x, d_model, taps=3, param_prefix="conv"):
@@ -1143,140 +1277,6 @@ def short_conv_operator(x, d_model, taps=3, param_prefix="conv"):
         initializer=UniformInitializer(-bound, bound)))
     return layers.fc(y, size=d_model, num_flatten_dims=2, bias_attr=False,
                      param_attr=ParamAttr(name=f"{param_prefix}.out_proj.w"))
-
-
-def lfm2_decoder_layer(x, cfg: Lfm2Config, idx=0, attn_impl="flash",
-                       is_test=False):
-    """One lfm2_moe block, pre-norm, two norms, no bias anywhere: ``h = x +
-    Op(RMS1(x))``, ``out = h + FF(RMS2(h))``.  ``Op``: where ``layer_types``
-    says ``full_attention``, grouped-query attention with Q and K RMS-normed
-    per head (weights ``[d_head]``) and then rotated (rotate-half), under
-    the ``attention_operator`` tag; else :func:`short_conv_operator` under
-    ``conv_operator``.  ``FF``: :func:`gated_ffn` in the first
-    ``n_dense_layer`` layers; else ``moe_ffn`` with sigmoid scores, a
-    selection bias held at zero, the kept scores renormalised (``+ 1e-6``)
-    and scaled, no shared expert.  Returns ``(out, expert_load or None)``."""
-    from ..initializer import NormalInitializer
-    p = f"dec_{idx}"
-
-    def norm(v, name, axis=2):
-        return layers.rms_norm(v, begin_norm_axis=axis, epsilon=cfg.rms_eps,
-                               param_attr=ParamAttr(name=f"{p}.{name}.w"))
-
-    def head_hook(q, k):
-        return tuple(layers.rope(norm(t, f"attn.{n}_norm", 3), cfg.d_head,
-                                 cfg.rope_theta)
-                     for t, n in ((q, "q"), (k, "k")))
-
-    n = norm(x, "ln1")
-    if cfg.layer_types[idx] == "full_attention":
-        with name_scope("attention_operator"):
-            op = multi_head_attention(
-                n, n, n, cfg.d_model, cfg.n_head, is_test=is_test,
-                param_prefix=f"{p}.attn", attn_impl=attn_impl, causal=True,
-                bias=False, n_kv_head=cfg.n_kv_head, d_head=cfg.d_head,
-                head_hook=head_hook)
-    else:
-        with name_scope("conv_operator"):
-            op = short_conv_operator(n, cfg.d_model, cfg.conv_taps,
-                                     f"{p}.conv")
-    h = x + op
-    m = norm(h, "ln2")
-    if idx < cfg.n_dense_layer:
-        with name_scope("dense_ffn"):
-            return h + gated_ffn(m, cfg.d_inner, cfg.d_model, f"{p}.ffn"), None
-    moe, _, _, load = layers.moe_ffn(
-        m, cfg.n_experts, cfg.top_k, cfg.d_expert, norm_topk_prob=True,
-        param_prefix=f"{p}.moe",
-        initializer=NormalInitializer(0.0, cfg.init_std),
-        score_func="sigmoid", select_bias=True, norm_eps=1e-6,
-        route_scale=cfg.route_scale, num_held=cfg.n_held,
-        expert_offset=cfg.expert_offset)
-    return h + moe, load
-
-
-def build_lfm2_pretrain(cfg: Lfm2Config, seq_len, is_test=False,
-                        attn_impl="flash", fused_head=True, checkpoints=None):
-    """Causal LM over :func:`lfm2_decoder_layer` blocks: ids -> embedding ->
-    ``n_layer`` blocks -> final RMSNorm -> logits over the embedding table
-    itself (``word_embedding`` is read by the lookup and by the head, and
-    its gradient is the sum of the two; there is no ``lm_out.w``); loss =
-    mean next-token CE (label 0 excluded, as in the
-    other builders) and nothing else (the selection bias is held at zero, as
-    in :func:`build_trinity_pretrain`).  ``checkpoints=[]`` collects the
-    block outputs for ``RecomputeOptimizer``.  Returns ``(feeds, parts,
-    loss)`` with ``parts`` = {"expert_load": [per expert layer], "hidden":
-    the final norm's output}."""
-    from ..framework.core import default_main_program
-    src_ids = layers.data("src_ids", shape=[seq_len], dtype="int64")
-    lm_label = layers.data("lm_label", shape=[seq_len], dtype="int64")
-    x = layers.embedding(src_ids, size=[cfg.vocab_size, cfg.d_model],
-                         param_attr=ParamAttr(name="word_embedding"))
-    loads = []
-    for i in range(cfg.n_layer):
-        x, load = lfm2_decoder_layer(x, cfg, i, attn_impl, is_test)
-        if load is not None:
-            loads.append(load)
-        if checkpoints is not None:
-            checkpoints.append(x)
-    x = layers.rms_norm(x, begin_norm_axis=2, epsilon=cfg.rms_eps,
-                        param_attr=ParamAttr(name="final_norm.w"))
-    table = default_main_program().global_block().var("word_embedding")
-    _, loss = _lm_head_loss(x, cfg, lm_label, fused_head, "lm_out",
-                            bias=False, table=table)
-    return (src_ids, lm_label), {"expert_load": loads, "hidden": x}, loss
-
-
-# -- Solar Open 2 (solar_open2; Kimi Linear's layer): gated delta-rule linear --
-# -- attention 3:1 with gated position-free grouped-query attention, sigmoid ----
-# -- routing beside a shared expert; a chip may hold a share of a layer's heads -
-
-class SolarOpen2Config:
-    """Solar-Open2-250B defaults (``upstage/Solar-Open2-250B`` config.json,
-    ``model_type`` ``solar_open2``; the linear-attention layer is Kimi
-    Linear's KDA, arXiv:2510.26692).  Layers in ``gqa_layers`` are softmax
-    grouped-query attention with no positional term and a gated output, the
-    others KDA (:func:`kda_attention`); every layer has ``n_experts`` routed
-    experts of width ``d_expert`` (``top_k`` a token, sigmoid scores, a
-    selection bias, the kept scores renormalised and scaled) beside one
-    shared expert of width ``d_expert * n_shared``.
-
-    ``n_head``, ``n_kv_head`` and ``n_kda_head`` are the heads this program
-    HOLDS, default all 64, 8 and 64: under tensor parallelism a chip holds a
-    share of a layer's heads, every head at its published ``d_head``, and
-    the output projections give the partial sum over the held heads (the
-    all-reduce is the deployment's).  ``n_held``/``expert_offset``: the
-    experts held, as :class:`TrinityConfig`."""
-
-    def __init__(self, vocab_size=196608, d_model=4096, n_layer=48,
-                 n_head=64, n_kv_head=8, n_kda_head=64, d_head=128,
-                 d_expert=1280, n_experts=320, top_k=8, n_shared=1,
-                 gqa_layers=None, conv_taps=4, kda_gate_rank=128,
-                 kda_neg_eigval=True, kda_chunk=64, route_scale=1.0,
-                 rms_eps=1e-5, n_held=None, expert_offset=0, init_std=0.02):
-        self.vocab_size = vocab_size
-        self.d_model = d_model
-        self.n_layer = n_layer
-        self.n_head = n_head
-        self.n_kv_head = n_kv_head
-        self.n_kda_head = n_kda_head
-        self.d_head = d_head
-        self.d_expert = d_expert
-        self.n_experts = n_experts
-        self.top_k = top_k
-        self.n_shared = n_shared
-        # published: a GQA layer first of every four
-        self.gqa_layers = sorted(gqa_layers) if gqa_layers is not None \
-            else list(range(0, n_layer, 4))
-        self.conv_taps = conv_taps
-        self.kda_gate_rank = kda_gate_rank
-        self.kda_neg_eigval = kda_neg_eigval
-        self.kda_chunk = kda_chunk
-        self.route_scale = route_scale
-        self.rms_eps = rms_eps
-        self.n_held = n_experts if n_held is None else n_held
-        self.expert_offset = expert_offset
-        self.init_std = init_std
 
 
 def kda_attention(x, cfg: SolarOpen2Config, param_prefix="kda"):
@@ -1338,294 +1338,14 @@ def kda_attention(x, cfg: SolarOpen2Config, param_prefix="kda"):
             rank="full" if full else r)
         o = layers.kda_scan(q, k, v, decay, beta, chunk=cfg.kda_chunk,
                             neg_eigval=cfg.kda_neg_eigval)
-        o = layers.rms_norm(o, begin_norm_axis=3, epsilon=cfg.rms_eps,
-                            param_attr=ParamAttr(
-                                name=f"{param_prefix}.o_norm.w"))
+        # (the call above stands on lines 1339 and 1340, where the parent has
+        # it: they are a source location inside the Mosaic bodies of
+        # Solar-Open2's and Ling's lowered steps, which the compile cache
+        # keys on: ROADMAP D13)
+        o = _rms(o, cfg, f"{param_prefix}.o_norm", 3)
         y = layers.reshape(o, shape=[0, 0, dq]) \
             * layers.sigmoid(g if full else proj(g, dq, "g_up"))
         return proj(y, cfg.d_model, "out")
-
-
-def solar_open2_decoder_layer(x, cfg: SolarOpen2Config, idx=0,
-                              attn_impl="flash", is_test=False):
-    """One solar_open2 block, pre-norm, two norms, no bias anywhere: ``h = x
-    + Mixer(RMS1(x))``, ``out = h + Shared(RMS2(h)) + MoE(RMS2(h))``.
-    ``Mixer``: in ``cfg.gqa_layers`` grouped-query softmax attention over
-    the whole causal half with NO positional term (``use_rope`` false) and
-    the output gated by ``sigmoid`` of a fourth slice of the fused
-    projection (``use_gqa_gate``), under the ``attn`` tag, at the
-    ``n_head`` over ``n_kv_head`` heads held here (the fused projection is
-    ``[d_model, (2 n_head + 2 n_kv_head) d_head]`` and the output projection
-    ``[n_head d_head, d_model]``: narrower than ``d_model`` where a chip
-    holds a share); else :func:`kda_attention`.  ``MoE``: ``moe_ffn`` with
-    sigmoid scores, a selection bias held at zero, the kept scores
-    renormalised (``+ 1e-20``) and scaled; ``Shared``: :func:`gated_ffn`.
-    Returns ``(out, expert_load)``."""
-    from ..initializer import NormalInitializer
-    p = f"dec_{idx}"
-
-    def norm(v, name):
-        return layers.rms_norm(v, begin_norm_axis=2, epsilon=cfg.rms_eps,
-                               param_attr=ParamAttr(name=f"{p}.{name}.w"))
-
-    n = norm(x, "ln1")
-    if idx in cfg.gqa_layers:
-        with name_scope("attn"):
-            mix = multi_head_attention(
-                n, n, n, cfg.d_model, cfg.n_head, is_test=is_test,
-                param_prefix=f"{p}.attn", attn_impl=attn_impl, causal=True,
-                bias=False, n_kv_head=cfg.n_kv_head, d_head=cfg.d_head,
-                out_gate=True)
-    else:
-        mix = kda_attention(n, cfg, f"{p}.kda")
-    h = x + mix
-    m = norm(h, "ln2")
-    with name_scope("shared_expert"):
-        f = gated_ffn(m, cfg.d_expert * cfg.n_shared, cfg.d_model,
-                      f"{p}.shared")
-    moe, _, _, load = layers.moe_ffn(
-        m, cfg.n_experts, cfg.top_k, cfg.d_expert, norm_topk_prob=True,
-        param_prefix=f"{p}.moe",
-        initializer=NormalInitializer(0.0, cfg.init_std),
-        score_func="sigmoid", select_bias=True, norm_eps=1e-20,
-        route_scale=cfg.route_scale, num_held=cfg.n_held,
-        expert_offset=cfg.expert_offset)
-    return h + f + moe, load
-
-
-def build_solar_open2_pretrain(cfg: SolarOpen2Config, seq_len, is_test=False,
-                               attn_impl="flash", fused_head=True,
-                               checkpoints=None):
-    """Causal LM over :func:`solar_open2_decoder_layer` blocks: ids ->
-    embedding -> ``n_layer`` blocks -> final RMSNorm -> untied bias-free
-    head; loss = mean next-token CE (label 0 excluded, as in the other
-    builders) and nothing else (the selection bias is held at zero, as in
-    :func:`build_trinity_pretrain`).  ``checkpoints=[]`` collects the block
-    boundaries for ``RecomputeOptimizer``: the embedding's output and every
-    block's, so that every block is computed again, the first too.  Returns
-    ``(feeds, parts, loss)`` with ``parts`` = {"expert_load": [per layer],
-    "hidden": the final norm's output}."""
-    src_ids = layers.data("src_ids", shape=[seq_len], dtype="int64")
-    lm_label = layers.data("lm_label", shape=[seq_len], dtype="int64")
-    x = layers.embedding(src_ids, size=[cfg.vocab_size, cfg.d_model],
-                         param_attr=ParamAttr(name="word_embedding"))
-    loads = []
-    if checkpoints is not None:
-        # the first block's input too: what lies before the first checkpoint
-        # is no segment and would be kept whole (0.95 GB and, XLA then
-        # rematerialising on its own, 17 ms a step at the published widths:
-        # benchmark/traffic/lm_s8192_r64.json, recompute_why)
-        checkpoints.append(x)
-    for i in range(cfg.n_layer):
-        x, load = solar_open2_decoder_layer(x, cfg, i, attn_impl, is_test)
-        loads.append(load)
-        if checkpoints is not None:
-            checkpoints.append(x)
-    x = layers.rms_norm(x, begin_norm_axis=2, epsilon=cfg.rms_eps,
-                        param_attr=ParamAttr(name="final_norm.w"))
-    _, loss = _lm_head_loss(x, cfg, lm_label, fused_head, "lm_out",
-                            bias=False)
-    return (src_ids, lm_label), {"expert_load": loads, "hidden": x}, loss
-
-
-# -- Ling 3.0: KDA with a bounded gate beside latent attention, --------------
-# -- group-limited routing, a leading dense layer ----------------------------
-
-class LingConfig:
-    """Ling-3.0-flash defaults (``inclusionAI/Ling-3.0-flash-VL``
-    config.json, the language model; the vision tower is not built).  Layer
-    ``i`` (published number) is latent attention where ``(i + 1) %
-    layer_group_size == 0`` and KDA otherwise: five KDA layers, then one MLA
-    layer.  KDA (:func:`kda_attention`): both gates at full rank
-    (``no_kda_lora``), the decay's gate bounded below by ``kda_lower_bound``
-    (``kda_safe_gate``), beta not doubled.  MLA (:func:`latent_attention`):
-    Q at full rank (``q_lora_rank`` None), QK-norm on the content parts, a
-    head-wise output gate.  The first ``n_dense_layer`` layers have a dense
-    gated FFN of width ``d_inner``, the others ``n_experts`` routed experts
-    of width ``d_expert`` (``top_k`` a token among the ``topk_group`` best of
-    ``n_group`` groups, sigmoid scores, a selection bias, renormalised and
-    scaled) beside one shared expert of width ``d_shared``.
-
-    ``first_layer``: the published number of this program's layer 0, where
-    it holds a run of the layers (a pipeline stage); the dense layers and
-    the MLA layers follow the published numbers.  ``n_head`` and
-    ``n_kda_head`` are the heads HELD (default all 32), as
-    :class:`SolarOpen2Config`; ``n_held``/``expert_offset`` the experts
-    held, as :class:`TrinityConfig`."""
-
-    rope_scaling = None
-    kda_neg_eigval = False
-    qk_norm = True
-    head_gate = True
-
-    def __init__(self, vocab_size=157184, d_model=2560, n_layer=42,
-                 n_head=32, n_kda_head=32, d_head=128, kv_lora_rank=512,
-                 d_nope=128, d_rope=64, d_v=128, d_inner=6144, d_expert=768,
-                 d_shared=768, n_experts=512, top_k=8, n_group=8,
-                 topk_group=4, n_dense_layer=2, layer_group_size=6,
-                 first_layer=0, conv_taps=4, kda_lower_bound=-5.0,
-                 kda_chunk=64, route_scale=2.5, rms_eps=1e-6,
-                 rope_theta=6000000.0, n_held=None, expert_offset=0,
-                 init_std=0.02):
-        self.vocab_size = vocab_size
-        self.d_model = d_model
-        self.n_layer = n_layer
-        self.n_head = n_head
-        self.n_kda_head = n_kda_head
-        self.d_head = d_head
-        self.q_lora_rank = None
-        self.kv_lora_rank = kv_lora_rank
-        self.d_nope = d_nope
-        self.d_rope = d_rope
-        self.d_v = d_v
-        self.d_inner = d_inner
-        self.d_expert = d_expert
-        self.d_shared = d_shared
-        self.n_experts = n_experts
-        self.top_k = top_k
-        self.n_group = n_group
-        self.topk_group = topk_group
-        self.layer_group_size = layer_group_size
-        self.first_layer = first_layer
-        numbers = range(first_layer, first_layer + n_layer)
-        self.dense_layers = [j for j, i in enumerate(numbers)
-                             if i < n_dense_layer]
-        self.mla_layers = [j for j, i in enumerate(numbers)
-                           if (i + 1) % layer_group_size == 0]
-        self.conv_taps = conv_taps
-        self.kda_gate_rank = None
-        self.kda_lower_bound = kda_lower_bound
-        self.kda_chunk = kda_chunk
-        self.route_scale = route_scale
-        self.rms_eps = rms_eps
-        self.rope_theta = rope_theta
-        self.n_held = n_experts if n_held is None else n_held
-        self.expert_offset = expert_offset
-        self.init_std = init_std
-
-
-def ling_decoder_layer(x, cfg: LingConfig, idx=0):
-    """One Ling block, pre-norm, two norms, no bias anywhere: ``u = x +
-    Mixer(RMS1(x))``, ``out = u + FFN(RMS2(u))``.  ``Mixer``:
-    :func:`latent_attention` in ``cfg.mla_layers``, else
-    :func:`kda_attention`.  ``FFN``: :func:`gated_ffn` of width ``d_inner``
-    in ``cfg.dense_layers`` (the ``dense_ffn`` tag); else the shared expert
-    (the same builder at ``d_shared``) plus ``moe_ffn`` with sigmoid scores,
-    a selection bias held at zero, group-limited selection, the kept scores
-    renormalised (``+ 1e-20``) and scaled.  Returns ``(out, expert_load or
-    None)``."""
-    from ..initializer import NormalInitializer
-    p = f"dec_{idx}"
-
-    def norm(v, name):
-        return layers.rms_norm(v, begin_norm_axis=2, epsilon=cfg.rms_eps,
-                               param_attr=ParamAttr(name=f"{p}.{name}.w"))
-
-    n = norm(x, "ln1")
-    if idx in cfg.mla_layers:
-        u = x + latent_attention(n, cfg, f"{p}.attn")
-    else:
-        u = x + kda_attention(n, cfg, f"{p}.kda")
-    m = norm(u, "ln2")
-    if idx in cfg.dense_layers:
-        with name_scope("dense_ffn"):
-            return u + gated_ffn(m, cfg.d_inner, cfg.d_model, f"{p}.ffn"), \
-                None
-    with name_scope("shared_expert"):
-        f = gated_ffn(m, cfg.d_shared, cfg.d_model, f"{p}.shared")
-    moe, _, _, load = layers.moe_ffn(
-        m, cfg.n_experts, cfg.top_k, cfg.d_expert, norm_topk_prob=True,
-        param_prefix=f"{p}.moe",
-        initializer=NormalInitializer(0.0, cfg.init_std),
-        score_func="sigmoid", select_bias=True, norm_eps=1e-20,
-        route_scale=cfg.route_scale, num_held=cfg.n_held,
-        expert_offset=cfg.expert_offset, n_group=cfg.n_group,
-        topk_group=cfg.topk_group)
-    return u + f + moe, load
-
-
-def build_ling_pretrain(cfg: LingConfig, seq_len, fused_head=True,
-                        checkpoints=None):
-    """Causal LM over :func:`ling_decoder_layer` blocks: ids -> embedding ->
-    ``n_layer`` blocks -> final RMSNorm -> untied bias-free head; loss =
-    mean next-token CE and nothing else (the selection bias is held at zero
-    and there is no auxiliary term).  ``checkpoints=[]`` collects the block
-    boundaries for ``RecomputeOptimizer``: the embedding's output and every
-    block's, KDA, MLA, dense or expert alike, as
-    :func:`build_solar_open2_pretrain`.  Returns ``(feeds, parts, loss)``
-    with ``parts`` = {"expert_load": [per expert layer], "hidden": the final
-    norm's output}."""
-    src_ids = layers.data("src_ids", shape=[seq_len], dtype="int64")
-    lm_label = layers.data("lm_label", shape=[seq_len], dtype="int64")
-    x = layers.embedding(src_ids, size=[cfg.vocab_size, cfg.d_model],
-                         param_attr=ParamAttr(name="word_embedding"))
-    loads = []
-    if checkpoints is not None:
-        checkpoints.append(x)
-    for i in range(cfg.n_layer):
-        x, load = ling_decoder_layer(x, cfg, i)
-        if load is not None:
-            loads.append(load)
-        if checkpoints is not None:
-            checkpoints.append(x)
-    x = layers.rms_norm(x, begin_norm_axis=2, epsilon=cfg.rms_eps,
-                        param_attr=ParamAttr(name="final_norm.w"))
-    _, loss = _lm_head_loss(x, cfg, lm_label, fused_head, "lm_out",
-                            bias=False)
-    return (src_ids, lm_label), {"expert_load": loads, "hidden": x}, loss
-
-
-# -- Nemotron-H: Mamba-2 (SSD) mixers, position-free grouped-query attention --
-# -- and un-gated ReLU^2 experts, ONE sublayer a block ------------------------
-
-class NemotronHConfig:
-    """NVIDIA-Nemotron-3-Nano-30B-A3B defaults (``nvidia/NVIDIA-Nemotron-3-
-    Nano-30B-A3B-BF16`` config.json, ``model_type`` ``nemotron_h``; Mamba-2:
-    arXiv:2405.21060, Nemotron-H: arXiv:2504.03624).  ``pattern`` (the
-    row's ``hybrid_override_pattern``) gives each block's ONE sublayer by
-    its letter: ``M`` a Mamba-2 mixer (:func:`mamba2_mixer`: ``n_mamba_head``
-    heads of ``d_mamba_head``, ``n_group`` groups sharing ``B`` / ``C`` of
-    ``d_state``, ``conv_taps`` taps with a bias, chunks of ``chunk``), ``*``
-    causal grouped-query softmax attention with NO positional term, no gate
-    and no QK-norm (``n_head`` over ``n_kv_head`` heads of ``d_head``), ``E``
-    ``n_experts`` routed un-gated ReLU^2 experts of width ``d_expert``
-    (``top_k`` a token, sigmoid scores, a selection bias, the kept scores
-    renormalised and scaled) beside one shared expert of the same form at
-    ``d_shared``.  ``n_held``/``expert_offset``: the experts held, as
-    :class:`TrinityConfig`."""
-
-    def __init__(self, vocab_size=131072, d_model=2688,
-                 pattern="MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*"
-                         "EMEMEMEME",
-                 n_mamba_head=64, d_mamba_head=64, n_group=8, d_state=128,
-                 conv_taps=4, chunk=128, n_head=32, n_kv_head=2, d_head=128,
-                 d_expert=1856, d_shared=3712, n_experts=128, top_k=6,
-                 route_scale=2.5, rms_eps=1e-5, n_held=None, expert_offset=0,
-                 init_std=0.02):
-        if set(pattern) - set("ME*"):
-            raise ValueError(f"pattern {pattern!r}: M, E and * are built")
-        self.vocab_size = vocab_size
-        self.d_model = d_model
-        self.pattern = pattern
-        self.n_layer = len(pattern)
-        self.n_mamba_head = n_mamba_head
-        self.d_mamba_head = d_mamba_head
-        self.n_group = n_group
-        self.d_state = d_state
-        self.conv_taps = conv_taps
-        self.chunk = chunk
-        self.n_head = n_head
-        self.n_kv_head = n_kv_head
-        self.d_head = d_head
-        self.d_expert = d_expert
-        self.d_shared = d_shared
-        self.n_experts = n_experts
-        self.top_k = top_k
-        self.route_scale = route_scale
-        self.rms_eps = rms_eps
-        self.n_held = n_experts if n_held is None else n_held
-        self.expert_offset = expert_offset
-        self.init_std = init_std
 
 
 def mamba2_mixer(x, cfg: NemotronHConfig, param_prefix="mamba"):
@@ -1695,85 +1415,213 @@ def mamba2_mixer(x, cfg: NemotronHConfig, param_prefix="mamba"):
         return proj(y, cfg.d_model, "out")
 
 
-def relu2_ffn(x, d_inner, d_model, param_prefix="ffn"):
-    """``down(relu(up(x))^2)`` out of the dense ops, no gate branch, no bias
-    (``<prefix>.up.w``, ``<prefix>.down.w``)."""
-    u = layers.fc(x, size=d_inner, num_flatten_dims=2, bias_attr=False,
-                  param_attr=ParamAttr(name=f"{param_prefix}.up.w"))
-    return layers.fc(layers.square(layers.relu(u)), size=d_model,
-                     num_flatten_dims=2, bias_attr=False,
-                     param_attr=ParamAttr(name=f"{param_prefix}.down.w"))
+# -- the assembled block and the loop over it ---------------------------------
+
+#: mixer kind -> (its builder over the block's normed input, the
+#: configuration and a parameter prefix; the suffix its parameters take).
+#: These tag themselves; ``"gqa"`` is :func:`grouped_query_attention`.
+MIXERS = {
+    "mla": (latent_attention, "attn"),
+    "kda": (kda_attention, "kda"),
+    "mamba2": (mamba2_mixer, "mamba"),
+    "conv": (lambda n, cfg, prefix: short_conv_operator(
+        n, cfg.d_model, cfg.conv_taps, prefix), "conv"),
+}
 
 
-def nemotron_h_block(x, cfg: NemotronHConfig, idx=0, attn_impl="flash"):
-    """One nemotron_h block: ONE sublayer, pre-norm, no bias: ``out = x +
-    Mixer(RMS(x))`` and no second half, ``Mixer`` by ``cfg.pattern[idx]``:
-    ``M`` :func:`mamba2_mixer`; ``*`` :func:`multi_head_attention` at
-    ``n_head`` over ``n_kv_head`` heads with no position, gate or QK-norm
-    (the ``attn`` tag); ``E`` the shared expert (:func:`relu2_ffn` at
-    ``d_shared``, the ``shared_expert`` tag) plus ``moe_ffn`` with un-gated
-    ReLU^2 experts, sigmoid scores, a selection bias held at zero, the kept
-    scores renormalised (``+ 1e-20``) and scaled.  Returns ``(out,
-    expert_load or None)``."""
-    from ..initializer import NormalInitializer
-    p, kind = f"dec_{idx}", cfg.pattern[idx]
-    n = layers.rms_norm(x, begin_norm_axis=2, epsilon=cfg.rms_eps,
-                        param_attr=ParamAttr(name=f"{p}.norm.w"))
-    if kind == "M":
-        return x + mamba2_mixer(n, cfg, f"{p}.mamba"), None
-    if kind == "*":
-        with name_scope("attn"):
-            return x + multi_head_attention(
-                n, n, n, cfg.d_model, cfg.n_head, param_prefix=f"{p}.attn",
-                attn_impl=attn_impl, causal=True, bias=False,
-                n_kv_head=cfg.n_kv_head, d_head=cfg.d_head), None
-    with name_scope("shared_expert"):
-        f = relu2_ffn(n, cfg.d_shared, cfg.d_model, f"{p}.shared")
-    moe, _, _, load = layers.moe_ffn(
-        n, cfg.n_experts, cfg.top_k, cfg.d_expert, norm_topk_prob=True,
-        param_prefix=f"{p}.moe",
-        initializer=NormalInitializer(0.0, cfg.init_std),
-        score_func="sigmoid", select_bias=True, norm_eps=1e-20,
-        route_scale=cfg.route_scale, num_held=cfg.n_held,
-        expert_offset=cfg.expert_offset, act="relu2", gated=False)
-    return x + f + moe, load
+def _rms(x, cfg, name, axis=2):
+    """RMS norm of ``x`` from ``axis`` on, its weight ``<name>.w``."""
+    return layers.rms_norm(x, begin_norm_axis=axis, epsilon=cfg.rms_eps,
+                           param_attr=ParamAttr(name=f"{name}.w"))
 
 
-def build_nemotron_h_pretrain(cfg: NemotronHConfig, seq_len, fused_head=True,
-                              checkpoints=None, attn_impl="flash"):
-    """Causal LM over :func:`nemotron_h_block` blocks: ids -> embedding ->
-    ``len(cfg.pattern)`` one-sublayer blocks -> final RMSNorm -> untied
-    bias-free head; loss = mean next-token CE and nothing else (the
-    selection bias is held at zero and there is no auxiliary term).
-    ``checkpoints=[]`` collects the block boundaries for
-    ``RecomputeOptimizer``: the embedding's output and every block's, Mamba,
-    attention or expert alike, as :func:`build_solar_open2_pretrain`.
-    Returns ``(feeds, parts, loss)`` with ``parts`` = {"expert_load": [per
-    expert block], "hidden": the final norm's output}."""
+def grouped_query_attention(n, cfg, idx, param_prefix, attn_impl="flash",
+                            is_test=False):
+    """Causal self-attention over ``n`` as ``cfg`` describes layer ``idx``
+    (:class:`DecoderConfig`): :func:`multi_head_attention` with no bias at
+    ``n_head`` over ``n_kv_head`` heads, its hook the layer's QK-norm
+    (``<prefix>.q_norm.w``, ``.k_norm.w``) and rotary, before the head
+    split where the norm is over the projection, after it otherwise."""
+    d_head = cfg.d_head or cfg.d_model // cfg.n_head
+    steps = []
+    if cfg.qk_norm_over:
+        axis = 2 if cfg.qk_norm_over == "projection" else 3
+        steps.append(lambda t, name: _rms(
+            t, cfg, f"{param_prefix}.{name}_norm", axis))
+    if cfg.rotary(idx):
+        steps.append(lambda t, name: layers.rope(t, d_head, cfg.rope_theta))
+
+    def hook(q, k):
+        t = {"q": q, "k": k}
+        order = [(name, step) for name in t for step in steps] \
+            if cfg.qk_in_turn else \
+            [(name, step) for step in steps for name in t]
+        for name, step in order:
+            t[name] = step(t[name], name)
+        return t["q"], t["k"]
+
+    where = "qk_hook" if cfg.qk_norm_over == "projection" else "head_hook"
+    return multi_head_attention(
+        n, n, n, cfg.d_model, cfg.n_head, is_test=is_test,
+        param_prefix=param_prefix, attn_impl=attn_impl, causal=True,
+        bias=False, n_kv_head=cfg.n_kv_head, d_head=cfg.d_head,
+        window=cfg.window_at(idx), out_gate=cfg.out_gate,
+        **({where: hook} if steps else {}))
+
+
+def routed_ffn(x, cfg, param_prefix, router_x=None):
+    """The routed FFN over ``x`` (the block's normed stream) as ``cfg``
+    describes it (:class:`DecoderConfig`): the shared expert where there is
+    one (``<prefix>.shared.*``, under the ``shared_expert`` tag; its two
+    parameters are created before the router's), then ``layers.moe_ffn``
+    (``<prefix>.moe.*``).  Returns the terms to add to the stream, in their
+    order, and ``moe_ffn``'s ``(lb_loss, z_loss, expert_load)``."""
+    dense = gated_ffn if cfg.gated else relu2_ffn
+    terms = []
+    if cfg.d_shared:
+        with name_scope("shared_expert"):
+            terms.append(dense(x, cfg.d_shared, cfg.d_model,
+                               f"{param_prefix}.shared"))
+    moe, *aux = layers.moe_ffn(
+        x, cfg.n_experts, cfg.top_k, cfg.d_expert,
+        norm_topk_prob=cfg.route_norm, param_prefix=f"{param_prefix}.moe",
+        initializer=initializer.NormalInitializer(0.0, cfg.init_std),
+        score_func=cfg.score_func, select_bias=cfg.select_bias,
+        norm_eps=cfg.route_norm_eps, route_scale=cfg.route_scale,
+        num_held=cfg.n_held, expert_offset=cfg.expert_offset, act=cfg.act,
+        router_x=router_x, n_group=cfg.n_route_group,
+        topk_group=cfg.topk_group, gated=cfg.gated)
+    return terms + [moe], tuple(aux)
+
+
+def decoder_block(x, cfg, idx=0, attn_impl="flash", is_test=False,
+                  param_prefix=None, residual=plain_residual):
+    """Layer ``idx`` of the decoder ``cfg`` describes
+    (:class:`DecoderConfig`): the sublayers ``cfg.mixer(idx)`` and
+    ``cfg.ffn(idx)`` name, each over an RMS norm of the stream and put back
+    into it by ``residual(x, sublayer, name)``: :func:`plain_residual` (the
+    ``+``) by default; :func:`hyper_connection` makes the rule of a widened
+    stream, whose parameters are ``<prefix>.hc_attn.*`` and
+    ``<prefix>.hc_ffn.*``.  No bias anywhere.  ``param_prefix`` (default
+    ``dec_<idx>``) names the parameters: the mixer's under its suffix of
+    ``MIXERS`` (``.attn`` for ``"gqa"``), a dense FFN's ``.ffn.*``, a
+    routed one's as :func:`routed_ffn` says, and the norms ``.ln1``,
+    ``.ln2`` ... in the order they are made (``.norm`` in a block of one
+    sublayer).  Returns ``(out, routed)``: ``routed`` is ``moe_ffn``'s
+    ``(lb_loss, z_loss, expert_load)``, None in a block with no routed
+    FFN."""
+    p = param_prefix or f"dec_{idx}"
+    mixer, ffn = cfg.mixer(idx), cfg.ffn(idx)
+    names = iter(["norm"] if None in (mixer, ffn)
+                 else ["ln1", "ln2", "ln3", "ln4"])
+    seen = {}
+
+    def norm(v):
+        return _rms(v, cfg, f"{p}.{next(names)}")
+
+    def mix(n):
+        seen["mixer_in"] = n
+        if mixer == "gqa":
+            return [grouped_query_attention(n, cfg, idx, f"{p}.attn",
+                                            attn_impl, is_test)]
+        build, suffix = MIXERS[mixer]
+        return [build(n, cfg, f"{p}.{suffix}")]
+
+    def feed_forward(m):
+        if ffn == "dense":
+            dense = gated_ffn if cfg.gated else relu2_ffn
+            return [dense(m, cfg.d_inner, cfg.d_model, f"{p}.ffn")]
+        terms, seen["routed"] = routed_ffn(
+            m, cfg, p, seen["mixer_in"] if cfg.router_before_mixer else None)
+        return terms
+
+    def sublayer(x, part, tag, build, name):
+        """``build`` over a norm of the stream, under ``tag``, its terms put
+        back by the rule.  The tag is left with the part or, where the
+        configuration has it cover the residual add, behind the rule."""
+        with contextlib.ExitStack() as held:
+            def normed(u):
+                n = norm(u)
+                with contextlib.ExitStack() as now:
+                    if tag:
+                        (held if part in cfg.tag_covers_add
+                         else now).enter_context(name_scope(tag))
+                    terms = build(n)
+                return [norm(sum(terms[1:], terms[0]))] \
+                    if cfg.sandwich_norm else terms
+            return residual(x, normed, f"{p}.{name}")
+
+    if mixer is not None:
+        x = sublayer(x, mixer, cfg.mixer_tags.get(mixer), mix, "hc_attn")
+    if ffn is not None:
+        x = sublayer(x, ffn, "dense_ffn" if ffn == "dense" else None,
+                     feed_forward, "hc_ffn")
+    return x, seen.get("routed")
+
+
+def _causal_lm(cfg, seq_len, attn_impl="flash", is_test=False,
+               fused_head=True, checkpoints=None, checkpoint_input=False,
+               residual=plain_residual, enter=None, leave=None):
+    """The causal LM every decoder here is: ids -> embedding (no position
+    table; times ``sqrt(d_model)`` under ``cfg.mup``) -> ``cfg.n_layer``
+    :func:`decoder_block` -> final RMSNorm -> bias-free head, untied
+    (``lm_out.w``) or, under ``cfg.tie_embeddings``, over the embedding
+    table itself (``word_embedding`` is read by the lookup and by the head,
+    and its gradient is the sum of the two).  Loss = mean next-token CE
+    (``lm_label`` as the pipeline shifted it; label 0 excluded, as in the
+    other builders).  The models have no dropout, so ``is_test`` only
+    reaches the attention's choice of path.  ``enter`` / ``leave`` map the
+    embedding to the stream the blocks carry and back (a list of variables
+    under :func:`hyper_connection`).
+
+    ``checkpoints=[]`` collects the block outputs (every stream of a
+    widened one) for ``RecomputeOptimizer``, and with ``checkpoint_input``
+    the first block's input too: what lies before the first checkpoint is
+    no segment and would be kept whole (0.95 GB and, XLA then
+    rematerialising on its own, 17 ms a step at Solar-Open2's published
+    widths: benchmark/traffic/lm_s8192_r64.json, recompute_why).  Each
+    entry point passes the rule its accepted cell's step was measured
+    under; one rule for all is a pair on the chip away (ROADMAP D19).
+
+    Returns ``(feeds, parts, loss, aux)``: ``parts`` = {"expert_load": [per
+    routed layer], "hidden": the final norm's output}, ``aux`` the routed
+    layers' ``(lb_loss, z_loss)`` for the recipe that trains on them."""
     src_ids = layers.data("src_ids", shape=[seq_len], dtype="int64")
     lm_label = layers.data("lm_label", shape=[seq_len], dtype="int64")
     x = layers.embedding(src_ids, size=[cfg.vocab_size, cfg.d_model],
                          param_attr=ParamAttr(name="word_embedding"))
-    loads = []
-    if checkpoints is not None:
-        checkpoints.append(x)
-    for i in range(cfg.n_layer):
-        x, load = nemotron_h_block(x, cfg, i, attn_impl)
-        if load is not None:
-            loads.append(load)
+    if cfg.mup:
+        x = layers.scale(x, scale=float(cfg.d_model) ** 0.5)
+    if enter is not None:
+        x = enter(x)
+
+    def keep(x):
         if checkpoints is not None:
-            checkpoints.append(x)
-    x = layers.rms_norm(x, begin_norm_axis=2, epsilon=cfg.rms_eps,
-                        param_attr=ParamAttr(name="final_norm.w"))
+            checkpoints.extend(x if isinstance(x, list) else [x])
+
+    if checkpoint_input:
+        keep(x)
+    routed = []
+    for i in range(cfg.n_layer):
+        x, r = decoder_block(x, cfg, i, attn_impl, is_test,
+                             residual=residual)
+        if r is not None:
+            routed.append(r)
+        keep(x)
+    if leave is not None:
+        x = leave(x)
+    x = _rms(x, cfg, "final_norm")
+    table = default_main_program().global_block().var("word_embedding") \
+        if cfg.tie_embeddings else None
     _, loss = _lm_head_loss(x, cfg, lm_label, fused_head, "lm_out",
-                            bias=False)
-    return (src_ids, lm_label), {"expert_load": loads, "hidden": x}, loss
+                            bias=False, table=table)
+    parts = {"expert_load": [r[2] for r in routed], "hidden": x}
+    return (src_ids, lm_label), parts, loss, [r[:2] for r in routed]
 
 
 def annotate_tensor_parallel(program=None):
     """Megatron-style TP layout via dist_spec (SURVEY §2.5: TP is a
     capability the reference LACKS — first-class here)."""
-    from ..framework.core import default_main_program
     program = program or default_main_program()
     for p in program.all_parameters():
         n = p.name

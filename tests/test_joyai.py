@@ -1,6 +1,6 @@
 """JoyAI-LLM-Flash's builders (``models/transformer.py``: ``latent_attention``,
-``joyai_decoder_layer``, ``build_joyai_pretrain``) at a toy size on the CPU
-against the plain float32 reference (``benchmark/reference/
+``decoder_block`` over ``JoyaiConfig``, ``build_joyai_pretrain``) at a toy
+size on the CPU against the plain float32 reference (``benchmark/reference/
 joyai_llm_flash.py``): latent attention alone; the share test (the routed
 parts of all shares plus the shared expert once are the uncut layer); loss,
 both its terms and every parameter's gradient of a 1 dense + 2 expert + MTP

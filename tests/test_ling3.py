@@ -1,7 +1,7 @@
 """Ling-3.0-flash's parts (``ops/kda_ops.py``: ``kda_gate``'s bounded form;
 ``ops/moe_ops.py``: group-limited selection; ``models/transformer.py``:
 ``latent_attention`` without a Q latent, with QK-norm and a head-wise gate,
-``kda_attention`` with full-rank gates, ``LingConfig``, ``ling_decoder_layer``,
+``kda_attention`` with full-rank gates, ``LingConfig`` under ``decoder_block``,
 ``build_ling_pretrain``) at a toy size on the CPU against the plain float32
 reference (``benchmark/reference/ling3_flash_vl.py``: the recurrence token by
 token, the literal softmax, the router by ``argsort``): the bounded gate and
@@ -576,7 +576,7 @@ def _layer_out(cfg, idx, x, values, seed=6):
     with scope_guard(scope), program_guard(main, startup):
         xv = layers.data("x", shape=list(x.shape), dtype="float32",
                          append_batch_size=False)
-        out, _ = T.ling_decoder_layer(xv, cfg, idx)
+        out, _ = T.decoder_block(xv, cfg, idx)
         exe = Executor()
         exe.run(startup, scope=scope, seed=seed)
     if values is None:

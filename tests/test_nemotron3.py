@@ -557,7 +557,7 @@ def test_the_expert_shares_add_up_to_the_uncut_expert_block():
         with scope_guard(scope), program_guard(main, startup):
             xv = layers.data("x", shape=[1, S, d], dtype="float32",
                              append_batch_size=False)
-            out, load = T.nemotron_h_block(xv, cfg, 0)
+            out, (_, _, load) = T.decoder_block(xv, cfg, 0)
             exe = pt.Executor()
             exe.run(startup, scope=scope, seed=1)
         held = slice(4 * share, 4 * share + 4)

@@ -1,7 +1,7 @@
 """Solar-Open2-250B's parts (``ops/kda_ops.py``: ``kda_scan`` with its grad op
 and ``kda_gate``; ``short_conv``'s ungated, activated form;
 ``models/transformer.py``: ``SolarOpen2Config``, ``kda_attention``,
-``solar_open2_decoder_layer``, ``build_solar_open2_pretrain``) at a toy size
+``decoder_block`` over it, ``build_solar_open2_pretrain``) at a toy size
 on the CPU against the plain float32 reference
 (``benchmark/reference/solar_open2_250b.py``, whose recurrence runs token by
 token): the chunked scan forward and every input's gradient at two chunk
@@ -487,7 +487,7 @@ def _layer_out(cfg, idx, x, values, seed=6):
     with scope_guard(scope), program_guard(main, startup):
         xv = layers.data("x", shape=list(x.shape), dtype="float32",
                          append_batch_size=False)
-        out, _ = T.solar_open2_decoder_layer(xv, cfg, idx, attn_impl="flash")
+        out, _ = T.decoder_block(xv, cfg, idx, attn_impl="flash")
         exe = Executor()
         exe.run(startup, scope=scope, seed=seed)
     if values is None:

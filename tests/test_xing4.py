@@ -222,10 +222,9 @@ def test_one_stream_with_unit_maps_is_joyais_block():
             xv = layers.data("x", shape=list(x.shape), dtype="float32",
                              append_batch_size=False)
             if rule is T.plain_residual:
-                out, _ = T.joyai_decoder_layer(xv, cfg, 0)
+                out, _ = T.decoder_block(xv, cfg, 0)
             else:                            # a stream of one variable
-                (out,), _ = T.joyai_decoder_layer([xv], cfg, 0,
-                                                  residual=rule)
+                (out,), _ = T.decoder_block([xv], cfg, 0, residual=rule)
             exe = Executor()
             exe.run(startup, scope=scope, seed=4)
         for p in main.all_parameters():
@@ -486,7 +485,7 @@ def test_the_shares_and_what_every_chip_computes_alike_once_are_the_layer():
         with scope_guard(scope), program_guard(main, startup):
             xv = layers.data("x", shape=list(x.shape), dtype="float32",
                              append_batch_size=False)
-            streams, _ = T.joyai_decoder_layer(
+            streams, _ = T.decoder_block(
                 layers.split(xv, N, dim=2), cfg, 0,
                 residual=T.hyper_connection(cfg))
             out = layers.concat(streams, axis=2)
@@ -534,7 +533,8 @@ def _ref_params_of_block(values, cfg):
 #: expert layer and the MTP module; the loss and every parameter's gradient
 #: fetched, no optimizer; CPU lowering) as PR 44 left it, taken at 40ebb42
 #: with this function: ``hc_mult`` 1 and ``rope_scaling`` None leave
-#: ``joyai_decoder_layer``, ``latent_attention``, ``build_joyai_pretrain`` and
+#: JoyAI's block (``decoder_block`` since PR 59), ``latent_attention``,
+#: ``build_joyai_pretrain`` and
 #: ``rope`` lowering as they did, to the byte.  The timed step's own text
 #: (``tools/joyai_step_aot.py --lowered``) was compared at both commits too
 #: and is the same outside the Mosaic kernels' serialized bodies, which carry
