@@ -36,8 +36,22 @@ all chunks at once: the forward's recurrence does not run again.
 quotient of cumulated products**, every exponent ``<= 0``, and the causal
 mask is applied BEFORE the ``exp`` (``exp(-inf) = 0``), so no ``inf * 0``
 reaches a gradient.  Float32 inside whatever AMP says, the products at
-``highest`` precision; Out comes back in X's dtype.  Written once in
-``jax.numpy``: a kernel that replaces it later is read by the same op names.
+``highest`` precision; Out comes back in X's dtype.
+
+Two lowerings of the one algorithm, chosen from the input
+(``pallas/ssd.py:fits`` beside ``device.on_tpu()``; no attribute, flag or
+environment variable chooses):
+
+- ``pallas``, on a TPU where the chunk is 128, ``t`` a whole number of
+  chunks, ``N`` and a group's ``R P`` lanes whole lane tiles and X, B, C
+  float32 or bf16: the kernel pair ``ssd_fwd`` / ``ssd_bwd`` of
+  ``pallas/ssd.py``.  A grid step is a chunk of a group; the streams are read
+  as they lie, ``L`` and ``C B^T`` live in VMEM, the group's states in VMEM
+  scratch from chunk to chunk (the recurrence over the chunk states is the
+  loop it is, in both directions), and the backward is written by hand.
+- ``xla``, everywhere else (the CPU, toy widths, chunk 16, ragged ``t``): the
+  ``jax.numpy`` text below, which is also what the kernels are tested
+  against.
 
 ``paddle_tpu_ssd_lowerings_total{impl, chunk}`` counts the lowerings."""
 
@@ -53,10 +67,11 @@ from .common import X
 
 SSD_LOWERINGS_CTR = _monitor.REGISTRY.counter(
     "paddle_tpu_ssd_lowerings_total",
-    "ssd_scan and ssd_scan_grad lowerings by what implements the op (xla: "
-    "jnp that XLA fuses, the recurrence over the chunk states one product) "
-    "and the chunk — counted while tracing, once per compile of a program "
-    "that holds the op", ("impl", "chunk"))
+    "ssd_scan and ssd_scan_grad lowerings by what implements the op (pallas: "
+    "the kernels ssd_fwd and ssd_bwd of pallas/ssd.py, the states in VMEM; "
+    "xla: jnp that XLA fuses, the recurrence over the chunk states one "
+    "product) and the chunk — counted while tracing, once per compile of a "
+    "program that holds the op", ("impl", "chunk"))
 
 _SCAN_IN = ("X", "Dt", "ALog", "B", "C", "D", "DtBias")
 
@@ -164,12 +179,20 @@ def ssd_chunked(x, dt, a_log, b, c, d, dt_bias=None, *, chunk=128,
     return y
 
 
-def _count(ctx, attrs):
+def _lowering(ctx, prim, attrs):
+    """``(impl, chunk)`` for the op and its grad op alike, decided from the
+    inputs and the backend, and one count of it."""
+    from ..device import on_tpu
+    from ..pallas import ssd
     chunk = int(attrs.get("chunk", 128))
+    x, _, _, b, c = prim[:5]
+    impl = "pallas" if ssd.fits(x.shape, b.shape, chunk,
+                                [v.dtype for v in (x, b, c)]) and on_tpu() \
+        else "xla"
     # shape inference runs the lowering abstractly: uncounted
     if not getattr(ctx, "is_abstract", False):
-        SSD_LOWERINGS_CTR.inc(impl="xla", chunk=str(chunk))
-    return chunk
+        SSD_LOWERINGS_CTR.inc(impl=impl, chunk=str(chunk))
+    return impl, chunk
 
 
 def _ssd_scan(ctx, ins, attrs):
@@ -182,9 +205,13 @@ def _ssd_scan(ctx, ins, attrs):
     chunk, which ``ssd_scan_grad`` starts each chunk from; it carries no
     gradient.  Attribute ``chunk`` (128; ``t`` is padded to a multiple
     inside)."""
-    chunk = _count(ctx, attrs)
+    from ..pallas import ssd
     prim = [X(ins, s) for s in _SCAN_IN]
-    out, states = ssd_chunked(*prim, chunk=chunk, with_states=True)
+    impl, chunk = _lowering(ctx, prim, attrs)
+    if impl == "pallas":
+        out, states = ssd.ssd_fwd(*prim)
+    else:
+        out, states = ssd_chunked(*prim, chunk=chunk, with_states=True)
     return {"Out": [out.astype(prim[0].dtype)], "States": [states]}
 
 
@@ -209,18 +236,29 @@ register_op("ssd_scan", _ssd_scan, grad_maker=_ssd_scan_grad_maker)
 @register_op("ssd_scan_grad")
 def _ssd_scan_grad(ctx, ins, attrs):
     """``ssd_scan``'s backward from its inputs, Out's gradient and the
-    forward op's States: the states' cotangents by one product with the
-    decays between chunks, then every chunk's vjp at its saved state, all
-    chunks at once (the module's docstring).  The recurrence over the
-    chunk states does not run again; a chunk's own tensors (its decay matrix,
-    ``C B^T``) are made again, not saved."""
+    forward op's States.  ``pallas``: the backward kernel, which walks the
+    chunks from the last with the states' cotangent in VMEM scratch and goes
+    back through each chunk by the hand-derived equations of
+    ``pallas/ssd.py``, and a few elementwise passes over ``[b, t, H]`` that
+    finish dDt, dALog, dD and dDtBias.  ``xla``: the states' cotangents by
+    one product with the decays between chunks, then every chunk's vjp at its
+    saved state, all chunks at once (the module's docstring).  On neither
+    path does the recurrence over the chunk states run again; a chunk's own
+    tensors (its decay matrix, ``C B^T``) are made again, not saved."""
+    from ..pallas import ssd
     f32 = jnp.float32
-    chunk = _count(ctx, attrs)
     prim = [X(ins, "X$" + s) for s in _SCAN_IN]
+    impl, chunk = _lowering(ctx, prim, attrs)
     x, dt, a_log, b, c, d, dt_bias = prim
     bsz, t, h, p = x.shape
     g = b.shape[2]
     d_out = X(ins, "OG$Out")
+    if impl == "pallas":
+        if d_out is None:
+            d_out = jnp.zeros(x.shape, x.dtype)
+        grads = ssd.ssd_bwd(*prim, X(ins, "States"), d_out)
+        return {"IG$" + s: [gr.astype(v.dtype)]
+                for s, gr, v in zip(_SCAN_IN, grads, prim) if v is not None}
     d_out = jnp.zeros(x.shape, f32) if d_out is None else d_out.astype(f32)
     s_in = X(ins, "States").reshape(bsz, g, h // g, -1, p, b.shape[3])
     n = s_in.shape[3]

@@ -604,3 +604,73 @@ def test_tpu_compiler_takes_the_short_conv_kernels_at_the_cells_shapes(
         [line for line in text.splitlines() if "custom_call_target" in line]
     assert len(jax.tree_util.tree_leaves(both.out_info)) == 3 + bias
     assert both.memory_analysis().temp_size_in_bytes < 4 << 20
+
+
+@pytest.mark.parametrize("half", ["forward", "backward"])
+def test_tpu_compiler_takes_the_ssd_kernels_at_the_cells_shapes(
+        topo, monkeypatch, half):
+    """``ssd_scan`` and ``ssd_scan_grad`` at Nemotron-3-Nano's [1, 8192, 64,
+    64] bf16 with 8 groups of state 128, float32 Dt and a DtBias, lowered as
+    on a TPU and compiled for one described chip: Mosaic takes each kernel
+    (interpret mode, ``tests/test_ssd_kernel.py``, says nothing about that:
+    the transposes, the dynamic sublane slices, the revisited blocks), ONE
+    custom call an op, the streams read as the program's ``[b, t, .]``
+    tensors lie (the reshapes to the op's four axes and back are bitcasts),
+    no copy or transpose of a state-shaped or ``[., 128, 128]``-shaped
+    operand beside it, and no temporary in HBM but the backward's two
+    ``[t, H]`` float32 streams and the partial rows of dD."""
+    import re
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu import device
+    from paddle_tpu.ops import ssd_ops
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    ctx = types.SimpleNamespace(amp=False, is_abstract=True)
+    attrs = {"chunk": 128}
+    slots = ("X", "Dt", "ALog", "B", "C", "D", "DtBias")
+    b, t, h, p, g, n = 1, 8192, 64, 64, 8, 128
+
+    def shaped(x, dt, a_log, bm, cm, d, bias):
+        return (x.reshape(b, t, h, p), dt, a_log, bm.reshape(b, t, g, n),
+                cm.reshape(b, t, g, n), d, bias)
+
+    def forward(*prim):
+        out = ssd_ops._ssd_scan(
+            ctx, {s: [v] for s, v in zip(slots, shaped(*prim))}, attrs)
+        return out["Out"][0].reshape(b, t, h * p), out["States"][0]
+
+    def backward(d_out, states, *prim):
+        ins = {"X$" + s: [v] for s, v in zip(slots, shaped(*prim))}
+        ins.update({"States": [states],
+                    "OG$Out": [d_out.reshape(b, t, h, p)]})
+        grads = ssd_ops._ssd_scan_grad(ctx, ins, attrs)
+        return [v[0].reshape(b, t, -1) if v[0].ndim == 4 else v[0]
+                for v in grads.values()]
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one)
+    x, bc = s((b, t, h * p), "bfloat16"), s((b, t, g * n), "bfloat16")
+    per = s((h,), "float32")
+    prim = (x, s((b, t, h), "float32"), per, bc, bc, per, per)
+    states = s((b, h, t // 128, p, n), "float32")
+    with _no_compile_cache():
+        if half == "forward":
+            compiled = jax.jit(forward).lower(*prim).compile()
+        else:
+            compiled = jax.jit(backward).lower(x, states, *prim).compile()
+    text = compiled.as_text()
+    name = {"forward": "ssd_fwd", "backward": "ssd_bwd"}[half]
+    calls = [line for line in text.split("\n")
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 1 and f'"{name}"' in text, calls
+    assert " while(" not in text
+    moved = [line for line in text.split("\n")
+             if re.search(r" (copy|transpose)\(", line)
+             and re.search(r"\[[\d,]*(64,128|128,128|128,64)\]", line)]
+    assert moved == [], moved
+    # backward: dDelta and dDA [8192, 64] float32, 2 MB each, and their
+    # elementwise children before the sums
+    limit = (1 << 20) if half == "forward" else (12 << 20)
+    assert compiled.memory_analysis().temp_size_in_bytes < limit

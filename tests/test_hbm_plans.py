@@ -276,7 +276,10 @@ def test_a_jit_with_compiler_options_has_its_plan_read_on_demand(
     assert "step_temporaries" not in cb.hbm_info
     n_hooks = len(hook_events)
     plans = memory.hbm_plans()                      # the first reader
-    assert len(plans) == before + 1
+    # (a worker whose earlier test files filled the store's window keeps its
+    # length: the new plan pushes the oldest out)
+    grown = min(before + 1, memory.MAX_HBM_PLANS)
+    assert len(plans) == grown
     # read late it is still served by JAX's caches, the seed a host scalar
     # in the device array's place (the deferred call pins no device buffer)
     late, = hook_events[n_hooks:]
@@ -285,7 +288,7 @@ def test_a_jit_with_compiler_options_has_its_plan_read_on_demand(
     assert plan["block"] == "train" and plan["temp_bytes"] > 0
     assert t0 < plan["compiled_at"] < t1            # when it compiled
     assert cb.hbm_info["step_temporaries"] == plan["temp_bytes"]
-    assert len(memory.hbm_plans()) == before + 1    # and once
+    assert len(memory.hbm_plans()) == grown         # and once
 
 
 def test_the_plan_store_is_a_window(monkeypatch):
