@@ -1205,11 +1205,16 @@ def lrn(input, n=5, k=1.0, alpha=1e-4, beta=0.75, name=None):
 
 def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
                     block_q=None, block_k=None, name=None, window=None,
-                    q_rope=None, k_rope=None):
+                    q_rope=None, k_rope=None, block_diffusion=None):
     """Fused online-softmax attention over [b, h, T, d] tensors.
 
     ``window`` (with ``causal``): key ``j`` is visible to query ``i`` iff
     ``0 <= i - j < window``; the kernels skip the blocks outside the band.
+    ``block_diffusion=B`` (alone: no ``causal``, no ``window``): T is a
+    noisy copy and a clean copy of one sequence, each in blocks of B, under
+    block diffusion's three-part mask
+    (``pallas.flash_attention.BlockDiffusion``); the kernels skip its dead
+    blocks alike and no bias tensor exists.
     K and V may have fewer heads than Q ([b, h_kv, T, d], ``h % h_kv ==
     0``): query head ``i`` reads KV head ``i // (h // h_kv)`` in the kernel.
     TPU-native replacement for the matmul→softmax→matmul chain of the
@@ -1238,6 +1243,8 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
              "block_q": block_q or 0, "block_k": block_k or 0}
     if window:
         attrs["window"] = int(window)
+    if block_diffusion:
+        attrs["block_diffusion"] = int(block_diffusion)
     helper.append_op("flash_attention", inputs=inputs,
                      outputs={"Out": [out], "Lse": [lse]}, attrs=attrs)
     return out

@@ -26,7 +26,8 @@ def multi_head_attention(queries, keys, values, d_model, n_head,
                          param_prefix="attn", attn_impl="base",
                          causal=False, bias=True, n_kv_head=None,
                          qk_hook=None, d_head=None, window=None,
-                         out_gate=False, head_hook=None):
+                         out_gate=False, head_hook=None,
+                         block_diffusion=None):
     """ref dist_transformer.py:958 multi_head_attention.
 
     attn_impl: "base" (matmul→softmax→matmul chain, ref recipe),
@@ -48,7 +49,8 @@ def multi_head_attention(queries, keys, values, d_model, n_head,
     runs after the head split, on [b, n_head, t, d_head] and [b, n_kv_head,
     t, d_head] (a QK-norm per head, weight ``[d_head]``, and the rotary
     embedding after it).  ``window`` (flash, causal): key ``j`` is visible
-    to query ``i`` iff ``0 <= i - j < window``.  ``out_gate=True``: a
+    to query ``i`` iff ``0 <= i - j < window``.  ``block_diffusion`` (flash,
+    not causal): ``layers.flash_attention``'s.  ``out_gate=True``: a
     fourth slice ``[d_model, n_head * d_head]`` of the fused projection
     gates the attention output, ``ctx * sigmoid(gate)``, before the output
     projection (self-attention only).  On the flash path K and V reach the
@@ -96,6 +98,8 @@ def multi_head_attention(queries, keys, values, d_model, n_head,
     # the flash kernels read grouped K/V heads through their index maps
     grouped = attn_impl == "flash" and n_kv_head != n_head
     assert window is None or attn_impl == "flash", "window= needs flash"
+    assert not block_diffusion or attn_impl == "flash", \
+        "block_diffusion= needs flash"
 
     def _split_heads(x, heads=n_head):
         # [b, t, d] -> [b, h, t, dh]
@@ -118,7 +122,8 @@ def multi_head_attention(queries, keys, values, d_model, n_head,
     if attn_impl == "flash":
         ctx = layers.flash_attention(q, k, v, bias=attn_bias, causal=causal,
                                      sm_scale=float(d_head) ** -0.5,
-                                     window=window)
+                                     window=window,
+                                     block_diffusion=block_diffusion)
     elif attn_impl == "ring":
         assert attn_bias is None, "ring attention supports causal= only"
         ctx = layers.ring_attention(q, k, v, causal=causal,
@@ -408,7 +413,9 @@ class DecoderConfig:
     ``rotary(idx)``, whether the layer turns Q and K (rotate-half, after
     the norm; by default wherever there is a ``rope_theta``);
     ``window_at(idx)``, the keys the layer sees back (None: the whole causal
-    half); ``out_gate``, the output times ``sigmoid`` of a fourth slice of
+    half); ``block_diffusion``, the block length where the stream is a noisy
+    copy beside a clean copy of each sequence under block diffusion's mask
+    (None: causal); ``out_gate``, the output times ``sigmoid`` of a fourth slice of
     the fused projection; ``mixer_tags``, the ``name_scope`` of a mixer that
     does not tag itself (the per-layer metrics read the tags).  The FFNs:
     the first ``n_dense_layer`` layers ``"dense"`` (width ``d_inner``, the
@@ -439,6 +446,7 @@ class DecoderConfig:
     qk_norm_over = None
     rope_theta = None
     out_gate = False
+    block_diffusion = None
     mixer_tags = {"gqa": "attn", "conv": "conv_operator"}
     n_dense_layer = 0
     gated = True
@@ -1435,9 +1443,43 @@ def _rms(x, cfg, name, axis=2):
                            param_attr=ParamAttr(name=f"{name}.w"))
 
 
+def _rotary_step(cfg, d_head):
+    """The hook's rotary step ``(t, name) -> t`` for ``cfg``: ``layers.rope``
+    at ``cfg.rope_theta`` over the sequence axis, or, where the stream is
+    two copies of one sequence (``cfg.block_diffusion``), over each copy
+    apart (:func:`_turned_by_copy`)."""
+    def turn(t, name=None):
+        return layers.rope(t, d_head, cfg.rope_theta)
+    if cfg.block_diffusion:
+        return lambda t, name: _turned_by_copy(t, turn)
+    return turn
+
+
+def _turned_by_copy(t, turn):
+    """``turn`` (the rotary embedding, whose position is the index along
+    the sequence axis) over a stream of two copies of one sequence, each
+    copy from position 0: the copies are folded into the heads (4-D ``[b,
+    heads, 2L, d_head]``) or the batch (3-D ``[b, 2L, d]``) and back, two
+    reshapes that move nothing, and the op and its kernel are what they
+    are for one copy."""
+    shape = [int(d) for d in t.shape]
+    if len(shape) == 4:
+        folded = [0, 2 * shape[1], shape[2] // 2, shape[3]]
+        back = [0, shape[1], shape[2], shape[3]]
+    else:
+        folded, back = [-1, shape[1] // 2, shape[2]], [-1] + shape[1:]
+    with name_scope("bd_stream"):
+        t = layers.reshape(t, shape=folded)
+    t = turn(t)
+    with name_scope("bd_stream"):
+        return layers.reshape(t, shape=back)
+
+
 def grouped_query_attention(n, cfg, idx, param_prefix, attn_impl="flash",
                             is_test=False):
-    """Causal self-attention over ``n`` as ``cfg`` describes layer ``idx``
+    """Causal self-attention over ``n`` (under ``cfg.block_diffusion``: block
+    diffusion's mask over the two copies ``n`` holds, each turned by its own
+    positions) as ``cfg`` describes layer ``idx``
     (:class:`DecoderConfig`): :func:`multi_head_attention` with no bias at
     ``n_head`` over ``n_kv_head`` heads, its hook the layer's QK-norm
     (``<prefix>.q_norm.w``, ``.k_norm.w``) and rotary, before the head
@@ -1449,7 +1491,7 @@ def grouped_query_attention(n, cfg, idx, param_prefix, attn_impl="flash",
         steps.append(lambda t, name: _rms(
             t, cfg, f"{param_prefix}.{name}_norm", axis))
     if cfg.rotary(idx):
-        steps.append(lambda t, name: layers.rope(t, d_head, cfg.rope_theta))
+        steps.append(_rotary_step(cfg, d_head))
 
     def hook(q, k):
         t = {"q": q, "k": k}
@@ -1463,8 +1505,9 @@ def grouped_query_attention(n, cfg, idx, param_prefix, attn_impl="flash",
     where = "qk_hook" if cfg.qk_norm_over == "projection" else "head_hook"
     return multi_head_attention(
         n, n, n, cfg.d_model, cfg.n_head, is_test=is_test,
-        param_prefix=param_prefix, attn_impl=attn_impl, causal=True,
-        bias=False, n_kv_head=cfg.n_kv_head, d_head=cfg.d_head,
+        param_prefix=param_prefix, attn_impl=attn_impl,
+        causal=not cfg.block_diffusion,
+        block_diffusion=cfg.block_diffusion, bias=False, n_kv_head=cfg.n_kv_head, d_head=cfg.d_head,
         window=cfg.window_at(idx), out_gate=cfg.out_gate,
         **({where: hook} if steps else {}))
 
@@ -1559,9 +1602,25 @@ def decoder_block(x, cfg, idx=0, attn_impl="flash", is_test=False,
     return x, seen.get("routed")
 
 
+def _next_token_feeds(cfg, seq_len, embed):
+    """The causal LM's feeds and stream: ``src_ids`` through the table, and
+    ``lm_label`` as the pipeline shifted it."""
+    src_ids = layers.data("src_ids", shape=[seq_len], dtype="int64")
+    lm_label = layers.data("lm_label", shape=[seq_len], dtype="int64")
+    return (src_ids, lm_label), embed(src_ids)
+
+
+def _next_token_loss(x, feeds, cfg, fused_head, table):
+    """Mean next-token CE of the final norm's output ``x`` (label 0
+    excluded, as in the other builders)."""
+    return _lm_head_loss(x, cfg, feeds[1], fused_head, "lm_out", bias=False,
+                         table=table)[1]
+
+
 def _causal_lm(cfg, seq_len, attn_impl="flash", is_test=False,
                fused_head=True, checkpoints=None, checkpoint_input=False,
-               residual=plain_residual, enter=None, leave=None):
+               residual=plain_residual, enter=None, leave=None,
+               feeds_of=_next_token_feeds, loss_of=_next_token_loss):
     """The causal LM every decoder here is: ids -> embedding (no position
     table; times ``sqrt(d_model)`` under ``cfg.mup``) -> ``cfg.n_layer``
     :func:`decoder_block` -> final RMSNorm -> bias-free head, untied
@@ -1572,7 +1631,12 @@ def _causal_lm(cfg, seq_len, attn_impl="flash", is_test=False,
     other builders).  The models have no dropout, so ``is_test`` only
     reaches the attention's choice of path.  ``enter`` / ``leave`` map the
     embedding to the stream the blocks carry and back (a list of variables
-    under :func:`hyper_connection`).
+    under :func:`hyper_connection`).  Another objective over the same loop
+    gives the two things that differ: ``feeds_of(cfg, seq_len, embed)``, the
+    feeds and the stream made of them (``embed``: ids through the table),
+    and ``loss_of(x, feeds, cfg, fused_head, table)``, the loss of the final
+    norm's output (:func:`build_sdar_pretrain`: two id feeds, a doubled
+    stream, ``leave`` taking its noisy half, a weighted denoising loss).
 
     ``checkpoints=[]`` collects the block outputs (every stream of a
     widened one) for ``RecomputeOptimizer``, and with ``checkpoint_input``
@@ -1586,12 +1650,13 @@ def _causal_lm(cfg, seq_len, attn_impl="flash", is_test=False,
     Returns ``(feeds, parts, loss, aux)``: ``parts`` = {"expert_load": [per
     routed layer], "hidden": the final norm's output}, ``aux`` the routed
     layers' ``(lb_loss, z_loss)`` for the recipe that trains on them."""
-    src_ids = layers.data("src_ids", shape=[seq_len], dtype="int64")
-    lm_label = layers.data("lm_label", shape=[seq_len], dtype="int64")
-    x = layers.embedding(src_ids, size=[cfg.vocab_size, cfg.d_model],
-                         param_attr=ParamAttr(name="word_embedding"))
-    if cfg.mup:
-        x = layers.scale(x, scale=float(cfg.d_model) ** 0.5)
+    def embed(ids):
+        x = layers.embedding(ids, size=[cfg.vocab_size, cfg.d_model],
+                             param_attr=ParamAttr(name="word_embedding"))
+        return layers.scale(x, scale=float(cfg.d_model) ** 0.5) \
+            if cfg.mup else x
+
+    feeds, x = feeds_of(cfg, seq_len, embed)
     if enter is not None:
         x = enter(x)
 
@@ -1613,10 +1678,101 @@ def _causal_lm(cfg, seq_len, attn_impl="flash", is_test=False,
     x = _rms(x, cfg, "final_norm")
     table = default_main_program().global_block().var("word_embedding") \
         if cfg.tie_embeddings else None
-    _, loss = _lm_head_loss(x, cfg, lm_label, fused_head, "lm_out",
-                            bias=False, table=table)
+    loss = loss_of(x, feeds, cfg, fused_head, table)
     parts = {"expert_load": [r[2] for r in routed], "hidden": x}
-    return (src_ids, lm_label), parts, loss, [r[:2] for r in routed]
+    return feeds, parts, loss, [r[:2] for r in routed]
+
+
+class SdarConfig(DecoderConfig):
+    """SDAR-30B-A3B-Chat defaults (``JetLM/SDAR-30B-A3B-Chat`` config.json,
+    ``model_type`` ``sdar_moe``; arXiv:2510.06303; the objective is block
+    diffusion, arXiv:2503.09573).  The network is Qwen3-MoE's, which
+    ``sdar_moe`` keeps: ``h = x + Attn(RMS(x))``, ``out = h + MoE(RMS(h))``,
+    no bias, no gate, no shared expert and no dense layer; ``n_head`` over
+    ``n_kv_head`` heads of ``d_head`` with a per-head RMS norm on Q and K
+    and rotary behind it; softmax scores over ``n_experts``, the ``top_k``
+    kept renormalised, no selection bias, SiLU-gated experts of
+    ``d_expert``; untied tables.  What is its own is the objective:
+    ``block_diffusion``, the block length B of the mask every layer runs
+    over the doubled stream, and ``mask_token_id``, what a noised token
+    becomes (the feeds bring the noisy ids; :func:`build_sdar_pretrain`)."""
+
+    qk_norm_over = "head"
+    score_func = "softmax"
+    select_bias = False
+    route_norm = True
+    route_norm_eps = 0.0
+
+    def __init__(self, vocab_size=151936, d_model=2048, n_layer=48,
+                 n_head=32, n_kv_head=4, d_head=128, d_expert=768,
+                 n_experts=128, top_k=8, rms_eps=1e-6, rope_theta=1e6,
+                 block_diffusion=4, mask_token_id=151669, n_held=None,
+                 expert_offset=0, init_std=0.02):
+        super().__init__(vocab_size, d_model, n_layer, n_head, d_expert,
+                         n_experts, top_k, rms_eps, n_held, expert_offset,
+                         init_std, n_kv_head, d_head)
+        self.rope_theta = rope_theta
+        self.block_diffusion = block_diffusion
+        self.mask_token_id = mask_token_id
+
+
+def build_sdar_pretrain(cfg: SdarConfig, seq_len, fused_head=True,
+                        checkpoints=None, attn_impl="flash"):
+    """Block-diffusion training of :class:`SdarConfig`'s decoder: the loop
+    of :func:`_causal_lm` over another objective.  Feeds, each ``[b,
+    seq_len]``: ``clean_ids`` (x_0), ``noisy_ids`` (x_t: x_0 with each
+    token of block ``b`` replaced by ``cfg.mask_token_id`` with probability
+    ``t_b``), ``lm_label`` (x_0 where x_t is the mask token, 0 elsewhere:
+    label 0 is excluded, as in the other builders) and ``loss_weight``
+    (float32, ``1 / t_b`` of each position's block).  The stream is ``[E(x_t);
+    E(x_0)]``, ``2 · seq_len`` rows through one table, positions ``i mod
+    seq_len``, every layer under the block-diffusion mask
+    (``layers.flash_attention(block_diffusion=cfg.block_diffusion)``); the
+    final norm and the head read the noisy half alone, and the loss is ``(1
+    / (b · seq_len)) Σ_{masked i} w_i · CE(logits_i, x_0^i)``: the token AT
+    its position, no shift, no auxiliary term.  What the objective adds
+    outside attention (joining the copies, the positions' restart, taking
+    the noisy half, weighting the loss) lies under the ``bd_stream`` tag.
+    ``checkpoints=[]`` collects the block boundaries, the stream's first
+    among them.  Returns ``(feeds, parts, loss)``; ``parts["hidden"]`` is
+    the final norm's output over the noisy half."""
+    if seq_len % cfg.block_diffusion:
+        raise ValueError(f"seq_len {seq_len} is not whole blocks of "
+                         f"{cfg.block_diffusion}")
+
+    def feeds_of(cfg, seq_len, embed):
+        ids = [layers.data(n, shape=[seq_len], dtype="int64")
+               for n in ("clean_ids", "noisy_ids", "lm_label")]
+        weight = layers.data("loss_weight", shape=[seq_len],
+                             dtype="float32")
+        noisy, clean = embed(ids[1]), embed(ids[0])
+        with name_scope("bd_stream"):
+            return (*ids, weight), layers.concat([noisy, clean], axis=1)
+
+    def noisy_half(x):
+        with name_scope("bd_stream"):
+            return layers.slice(x, axes=[1], starts=[0], ends=[seq_len])
+
+    def loss_of(x, feeds, cfg, fused_head, table):
+        label, weight = feeds[2], feeds[3]
+        w_attr = ParamAttr(name="lm_out.w")
+        if fused_head:
+            ce = layers.fused_lm_head_ce(x, cfg.vocab_size, label,
+                                         param_attr=w_attr, bias_attr=False,
+                                         ignore_index=0)
+        else:
+            logits = layers.fc(x, size=cfg.vocab_size, num_flatten_dims=2,
+                               param_attr=w_attr, bias_attr=False)
+            ce = layers.softmax_with_cross_entropy(
+                logits, layers.unsqueeze(label, [2]), ignore_index=0)
+        with name_scope("bd_stream"):
+            w = weight * layers.cast(label > 0, "float32")
+            return layers.reduce_mean(ce * layers.unsqueeze(w, [2]))
+
+    return _causal_lm(cfg, seq_len, attn_impl, fused_head=fused_head,
+                      checkpoints=checkpoints, checkpoint_input=True,
+                      leave=noisy_half, feeds_of=feeds_of,
+                      loss_of=loss_of)[:3]
 
 
 def annotate_tensor_parallel(program=None):

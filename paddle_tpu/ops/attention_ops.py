@@ -55,6 +55,24 @@ FLASH_BWD_KERNEL_CTR = _monitor.REGISTRY.counter(
     "paddle_tpu_flash_grad_lowerings_total, once per compile, nothing per "
     "step", ("kernel", "window", "widths"))
 
+FLASH_MASK_LOWERINGS_CTR = _monitor.REGISTRY.counter(
+    "paddle_tpu_flash_mask_lowerings_total",
+    "flash_attention and flash_attention_grad lowerings by the mask's form "
+    "(none, causal, window, block_diffusion), the block length of a "
+    "block-diffusion mask (0 otherwise), the implementation (pallas or the "
+    "blockwise jax fallback) and the kernel (fwd; fused, split or jax for "
+    "the grad op) — counted while tracing, once per compile, nothing per "
+    "step", ("mask", "block", "impl", "kernel"))
+
+FLASH_TILE_PAIRS_CTR = _monitor.REGISTRY.counter(
+    "paddle_tpu_flash_tile_pairs_total",
+    "the tile pairs of one head's grid in a flash lowering under a mask "
+    "form, by what the kernels do with them at the call's blocks: free "
+    "(every pair visible: no mask runs), masked (an edge crosses the tile) "
+    "or dead (skipped, and its K/V tile not copied); pass = fwd or bwd — "
+    "counted while tracing, once per compile, nothing per step",
+    ("mask", "block", "pass", "state"))
+
 
 def _flash_call(ctx, attrs, q, k, v, counter, pallas, more_labels=None,
                 q_rope=None, k_rope=None):
@@ -65,12 +83,16 @@ def _flash_call(ctx, attrs, q, k, v, counter, pallas, more_labels=None,
     arguments that gives the counter's further labels).  ``q_rope`` /
     ``k_rope``: the op's optional slots, handed on among the keyword
     arguments only where they are given, so that a call without them is the
-    call it always was."""
+    call it always was.  Under the attribute ``block_diffusion`` the window
+    returned, and handed to the kernels, is the mask form
+    (:func:`_mask_form`)."""
     from ..device import on_tpu
     bq, bk = attrs.get("block_q"), attrs.get("block_k")
     window = int(attrs.get("window") or 0) or None
     if window is not None and window >= max(q.shape[2], k.shape[2]):
         window = None
+    if attrs.get("block_diffusion"):
+        window = _mask_form(attrs, q, k)
     kw = dict(
         causal=bool(attrs.get("causal", False)),
         sm_scale=attrs.get("sm_scale") or None,
@@ -87,15 +109,58 @@ def _flash_call(ctx, attrs, q, k, v, counter, pallas, more_labels=None,
                     impl="pallas" if pallas and on_tpu() else "jax",
                     widths=widths,
                     **(more_labels(kw) if more_labels else {}))
+        _count_mask(kw, q, k, v, pallas, counter is FLASH_LOWERINGS_CTR)
     return window, kw, widths
+
+
+def _mask_form(attrs, q, k):
+    """The op's ``block_diffusion`` attribute (the block length B; the rows
+    are the noisy copy and the clean copy of one sequence, T = 2L) as the
+    kernels' mask form.  The form is the whole mask: no ``causal``, no
+    ``window`` beside it, and self-attention only."""
+    from ..pallas.flash_attention import block_diffusion
+    if attrs.get("causal") or attrs.get("window") or \
+            q.shape[2] != k.shape[2]:
+        raise ValueError(
+            "flash_attention: block_diffusion is the whole mask of a "
+            "self-attention (no causal, no window, Tq == Tk); got causal="
+            f"{attrs.get('causal')}, window={attrs.get('window')}, Tq="
+            f"{q.shape[2]}, Tk={k.shape[2]}")
+    return block_diffusion(q.shape[2], attrs["block_diffusion"])
+
+
+def _count_mask(kw, q, k, v, pallas, forward):
+    """One count of a lowering by its mask's form and, under a mask form,
+    of its grid's tile pairs by what becomes of them."""
+    from ..device import on_tpu
+    from ..pallas.flash_attention import (BlockDiffusion, flash_blocks,
+                                          flash_bwd_kernel)
+    window = kw["window"]
+    form = isinstance(window, BlockDiffusion)
+    mask = window.scope if form else "window" if window is not None else \
+        "causal" if kw["causal"] else "none"
+    block = str(window.block) if form else "0"
+    kernel = "fwd" if forward else "jax" if not pallas else \
+        flash_bwd_kernel(q, k, v, **kw)
+    FLASH_MASK_LOWERINGS_CTR.inc(
+        mask=mask, block=block, kernel=kernel,
+        impl="pallas" if pallas and on_tpu() else "jax")
+    if form:
+        blocks = flash_blocks(q, k, v, **kw)[0 if forward else 1]
+        for state, n in window.tile_pairs(*blocks).items():
+            FLASH_TILE_PAIRS_CTR.inc(n, mask=mask, block=block, state=state,
+                                     **{"pass": "fwd" if forward else "bwd"})
 
 
 def _window_scope(window):
     """A windowed layer's device operations lie under a ``window`` scope
     inside the op's own, forward and backward, so that a trace tells the
-    windowed layers from the full ones; a full layer's lie under none."""
-    return contextlib.nullcontext() if window is None else \
-        jax.named_scope("window")
+    windowed layers from the full ones; a full layer's lie under none, and
+    a mask form's under the form's own name (``block_diffusion``)."""
+    if window is None:
+        return contextlib.nullcontext()
+    return jax.named_scope(
+        "window" if isinstance(window, int) else window.scope)
 
 
 def _flash_attention(ctx, ins, attrs):
@@ -103,7 +168,11 @@ def _flash_attention(ctx, ins, attrs):
     with ``h % h_kv == 0`` (query head ``i`` reads KV head ``i // (h //
     h_kv)``); ``d_v`` is V's own and may differ from ``d_qk`` (latent
     attention: 192 over 128).  ``window`` > 0 with ``causal``: key ``j`` is
-    visible to query ``i`` iff ``0 <= i - j < window``.  Outputs: Out [b, h,
+    visible to query ``i`` iff ``0 <= i - j < window``.  ``block_diffusion``
+    = B > 0 (alone: no ``causal``, no ``window``, Tq == Tk): the rows are a
+    noisy and a clean copy of one sequence in blocks of B under block
+    diffusion's three-part mask, inside the kernels
+    (``pallas.flash_attention.BlockDiffusion``).  Outputs: Out [b, h,
     Tq, d_v] (shape inference runs this lowering abstractly, so Out's and
     the grad op's shapes follow V's) and Lse [b, h, Tq] float32, each
     query's log-sum-exp over its visible keys, which ``flash_attention_grad``

@@ -34,6 +34,15 @@ tile (7 products, no memory that grows: what a length too long for the fused
 accumulator falls back to).  ``_flash_bwd_pallas`` picks from the shapes; a
 bias takes the blockwise jax backward on every backend.
 
+Masks: the causal half, a trailing window over it (``window=`` an int), or
+a mask FORM, an object that is the whole mask and rides the same ``window``
+argument through the entry points and the kernels (:class:`BlockDiffusion`,
+PR 61: the one mask here that is no function of ``i - j``).  Four helpers
+tell them apart and nothing else does: :func:`_pos_mask` (a tile's mask),
+:func:`_block_dispatch` (a tile pair is skipped, mask-free or masked),
+:func:`_live_k` / :func:`_live_q` (the index maps, so that a skipped step
+copies no tile), and :func:`_seen` on the blockwise jax paths.
+
 Capability anchor in the reference: attention assembled from separate
 matmul/softmax/dropout ops in its Transformer recipe
 (``python/paddle/fluid/tests/unittests/dist_transformer.py:1034``
@@ -43,12 +52,14 @@ HBM.  This kernel is the TPU-native replacement.
 
 from __future__ import annotations
 
+import collections
 import functools
 import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..device import on_tpu
 
@@ -56,12 +67,201 @@ NEG_INF = -1e30
 _LANE = 128      # TPU lane width: min last-dim tile
 
 
+# ---------------------------------------------------------------------------
+# Mask forms: a mask that is not a function of i - j
+# ---------------------------------------------------------------------------
+
+class BlockDiffusion(collections.namedtuple("BlockDiffusion", "half block")):
+    """The attention mask of block-diffusion training (arXiv:2503.09573), a
+    value of the kernels' ``window`` argument (:func:`block_diffusion` makes
+    it).  The sequence is the noisy copy of ``half`` tokens followed by the
+    clean copy (``T = 2 · half``), both cut into blocks of ``block``; with
+    ``b(p) = p // block``, query ``i`` sees key ``j`` iff
+
+    - ``i < half``, ``j < half``: ``b(i) == b(j)`` (noisy sees its own block,
+      both directions);
+    - ``i < half``, ``j >= half``: ``b(j - half) < b(i)`` (noisy sees the clean
+      blocks strictly before its own);
+    - ``i >= half``, ``j >= half``: ``b(j - half) <= b(i - half)`` (clean is
+      block-causal);
+    - ``i >= half``, ``j < half``: never.
+
+    ``L² + L·block`` pairs a head (``L = half``) of the ``4 L²``.  The
+    kernels take it through the places a window goes: :meth:`dispatch` (a tile pair
+    is skipped, mask-free or masked), :meth:`tile_mask` (the mask of a tile an
+    edge crosses, from a column of query blocks and a row of key blocks, no
+    tile-wide integer work but one compare and one select), :meth:`live_k`
+    and :meth:`live_q` (the index maps that keep dead steps from copying a
+    tile).  ``causal`` is not read: the form is the whole mask."""
+
+    __slots__ = ()
+    #: the named scope the op's device operations lie under, and the
+    #: counters' ``mask`` label
+    scope = "block_diffusion"
+
+    def __str__(self):
+        return f"block_diffusion:{self.block}"
+
+    def _blk(self, pos):
+        shift = self.block.bit_length() - 1
+        return pos >> shift if self.block == 1 << shift else pos // self.block
+
+    def visible(self, q_pos, k_pos, tq_real=None, tk_real=None):
+        """The mask over int32 positions that broadcast against each other (a
+        column of queries, a row of keys, either way round), padding beyond
+        ``tq_real`` / ``tk_real`` invisible.  A key is one number, its block
+        (a noisy key's ``far`` higher); a query two: the number a noisy key
+        must EQUAL (its own noisy block; none for a clean query) and the
+        number a clean key must lie BELOW (a noisy query's block, a clean
+        query's block + 1).  Every select is on a column or a row of
+        integers; a pair costs two compares and an or (Mosaic has no select
+        between tiles of booleans)."""
+        half, far = self.half, jnp.int32(2 ** 29)
+        q_noisy, k_noisy = q_pos < half, k_pos < half
+        q_blk = self._blk(jnp.where(q_noisy, q_pos, q_pos - half))
+        key = self._blk(jnp.where(k_noisy, k_pos, k_pos - half)) + \
+            jnp.where(k_noisy, far, 0)
+        equal = jnp.where(q_noisy, q_blk + far, -1)
+        below = jnp.where(q_noisy, q_blk, q_blk + 1)
+        if tq_real is not None:
+            real = q_pos < tq_real
+            equal = jnp.where(real, equal, -1)
+            below = jnp.where(real, below, 0)
+        if tk_real is not None:
+            key = jnp.where(k_pos < tk_real, key, 2 * far)
+        return (key == equal) | (key < below)
+
+    def dense(self, tq, tk):
+        """The ``[tq, tk]`` boolean array (tests, references)."""
+        return self.visible(jnp.arange(tq, dtype=jnp.int32)[:, None],
+                            jnp.arange(tk, dtype=jnp.int32)[None, :])
+
+    def tile_mask(self, iq, ik, block_q, block_k, tq_real, tk_real,
+                  transposed=False):
+        """:func:`_pos_mask`'s result for this form."""
+        import jax.lax as lax
+        q_shape, k_shape = ((1, block_q), (block_k, 1)) if transposed \
+            else ((block_q, 1), (1, block_k))
+        q_pos = iq * block_q + lax.broadcasted_iota(
+            jnp.int32, q_shape, 1 if transposed else 0)
+        k_pos = ik * block_k + lax.broadcasted_iota(
+            jnp.int32, k_shape, 0 if transposed else 1)
+        return self.visible(q_pos, k_pos, tq_real, tk_real)
+
+    def tile_state(self, q0, k0, block_q, block_k, xp=jnp):
+        """``(live, full)`` of the tile pair whose first query is ``q0`` and
+        first key ``k0`` (traced scalars, or numpy arrays with ``xp=np``):
+        whether any pair of it is visible, and whether all are (then no
+        padding lies in it either).  A tile that straddles the two halves is
+        the union of its quarters."""
+        half, block = self
+        q1, k1 = q0 + (block_q - 1), k0 + (block_k - 1)
+        whole = (q1 < 2 * half) & (k1 < 2 * half)
+        q1, k1 = xp.minimum(q1, 2 * half - 1), xp.minimum(k1, 2 * half - 1)
+        qn, qc, kn, kc = q0 < half, q1 >= half, k0 < half, k1 >= half
+        # first and last block of the tile's noisy (n) and clean (c) rows
+        # and columns; read only where the tile has such rows or columns
+        qn0, qn1 = q0 // block, xp.minimum(q1, half - 1) // block
+        kn0, kn1 = k0 // block, xp.minimum(k1, half - 1) // block
+        qc0 = (xp.maximum(q0, half) - half) // block
+        qc1 = (xp.maximum(q1, half) - half) // block
+        kc0 = (xp.maximum(k0, half) - half) // block
+        kc1 = (xp.maximum(k1, half) - half) // block
+        live = (qn & kn & (kn0 <= qn1) & (kn1 >= qn0)) | \
+            (qn & kc & (kc0 < qn1)) | (qc & kc & (kc0 <= qc1))
+        full = whole & ~(qc & kn) & \
+            (~(qn & kn) | ((qn0 == qn1) & (kn0 == kn1) & (qn0 == kn0))) & \
+            (~(qn & kc) | (kc1 < qn0)) & (~(qc & kc) | (kc1 <= qc0))
+        return live, full
+
+    def dispatch(self, iq, ik, block_q, block_k, compute):
+        """:func:`_block_dispatch`'s ladder for this form: a tile pair with
+        no visible pair is skipped, one with every pair visible runs
+        ``compute(False)``, the others ``compute(True)``."""
+        from jax.experimental import pallas as pl
+        live, full = self.tile_state(iq * block_q, ik * block_k, block_q,
+                                     block_k)
+        pl.when(full)(lambda: compute(False))
+        pl.when(live & jnp.logical_not(full))(lambda: compute(True))
+
+    def live_k(self, i, j, block_q, block_k):
+        """:func:`_live_k` for this form.  The key blocks query block ``i``
+        sees are two runs: its noisy rows' own blocks, and the clean blocks
+        from the first to the last any of its rows sees.  A step before or
+        between them names the next run's first block (one copy, which that
+        run needs anyway), a step behind a run its last."""
+        half, block = self
+        q0 = i * block_q
+        q1 = jnp.minimum(q0 + block_q, 2 * half) - 1
+        last_noisy = jnp.minimum(q1, half - 1) // block
+        lo1 = (q0 // block * block) // block_k
+        hi1 = jnp.minimum(last_noisy * block + block - 1, half - 1) // block_k
+        # the last clean key a row of the tile sees: a noisy row's lies
+        # before its block, a clean row's ends its block
+        last = jnp.maximum(
+            jnp.where(q0 < half, last_noisy * block, 0),
+            jnp.where(q1 >= half, (q1 - half) // block * block + block, 0)
+        ) + (half - 1)
+        lo2, hi2 = half // block_k, jnp.maximum(last, half) // block_k
+        lo1, hi1 = jnp.where(q0 < half, lo1, lo2), jnp.where(q0 < half, hi1,
+                                                             lo2)
+        return jnp.where(j <= hi1, jnp.clip(j, lo1, hi1),
+                         jnp.clip(j, lo2, hi2))
+
+    def live_q(self, i, j, block_q, block_k):
+        """:func:`_live_q` for this form.  The query blocks that see key
+        block ``j`` are two runs as well: noisy rows (the blocks of its noisy
+        keys; behind its first clean key's block, to the end of the half)
+        and clean rows (from its first clean key's block to the end)."""
+        half, block = self
+        k0 = j * block_k
+        k1 = jnp.minimum(k0 + block_k, 2 * half) - 1
+        kn, kc = k0 < half, k1 >= half
+        first_clean = (jnp.maximum(k0, half) - half) // block
+        behind = (first_clean + 1) * block
+        a_lo = jnp.where(kn, jnp.where(kc, jnp.minimum(k0 // block * block,
+                                                       behind),
+                                       k0 // block * block), behind)
+        a_hi = jnp.where(kc, half - 1, jnp.minimum(
+            jnp.minimum(k1, half - 1) // block * block + block - 1, half - 1))
+        lo_c = (half + first_clean * block) // block_q
+        hi_c = (2 * half - 1) // block_q
+        lo_a, hi_a = a_lo // block_q, a_hi // block_q
+        no_a = jnp.logical_not(kn) & (behind > half - 1)
+        lo_a, hi_a = jnp.where(no_a, lo_c, lo_a), jnp.where(no_a, lo_c, hi_a)
+        lo_c, hi_c = jnp.where(kc, lo_c, hi_a), jnp.where(kc, hi_c, hi_a)
+        return jnp.where(i <= hi_a, jnp.clip(i, lo_a, hi_a),
+                         jnp.clip(i, lo_c, hi_c))
+
+    def tile_pairs(self, block_q, block_k):
+        """``{"free", "masked", "dead"}``: the tile pairs of one head's grid
+        at these blocks by what :meth:`dispatch` does with them."""
+        t = 2 * self.half
+        q0 = np.arange(0, t, block_q, dtype=np.int64)[:, None]
+        k0 = np.arange(0, t, block_k, dtype=np.int64)[None, :]
+        live, full = self.tile_state(q0, k0, block_q, block_k, xp=np)
+        return {"free": int(full.sum()), "masked": int((live & ~full).sum()),
+                "dead": int((~live).sum())}
+
+
+def block_diffusion(t, block):
+    """The :class:`BlockDiffusion` mask of a doubled sequence of ``t`` rows
+    in blocks of ``block``: pass it as ``window=`` (``causal=False``)."""
+    t, block = int(t), int(block)
+    if block < 1 or t % 2 or (t // 2) % block:
+        raise ValueError(
+            f"block diffusion over {t} rows in blocks of {block}: the rows "
+            "are two copies of one sequence of whole blocks")
+    return BlockDiffusion(t // 2, block)
+
+
 def mha_reference(q, k, v, bias=None, causal=False, sm_scale=None,
                   window=None, q_rope=None, k_rope=None):
     """O(T^2) reference attention (the math the kernel must reproduce): Q
     and K ``[b, h, T, d_qk]``, V ``[b, h, T, d_v]``, the result ``d_v`` wide.
     ``window``: with ``causal``, key ``j`` is visible to query ``i`` iff
-    ``0 <= i - j < window``.  K and V may have fewer heads than Q: query
+    ``0 <= i - j < window``; a :class:`BlockDiffusion`: its dense mask,
+    whatever ``causal`` says.  K and V may have fewer heads than Q: query
     head ``h`` reads KV head ``h // (n_q_heads // n_kv_heads)``.  With
     ``q_rope`` ``[b, h, T, d_r]`` and ``k_rope`` ``[b, h_r, T, d_r]`` the
     score is over ``[q | q_rope]`` and ``[k | k_rope]``, the rotary key's
@@ -82,8 +282,10 @@ def mha_reference(q, k, v, bias=None, causal=False, sm_scale=None,
                    k.astype(jnp.float32)) * sm_scale
     if bias is not None:
         s = s + bias.astype(jnp.float32)
-    if causal:
-        tq, tk = s.shape[-2], s.shape[-1]
+    tq, tk = s.shape[-2], s.shape[-1]
+    if isinstance(window, BlockDiffusion):
+        s = jnp.where(window.dense(tq, tk), s, NEG_INF)
+    elif causal:
         mask = jnp.tril(jnp.ones((tq, tk), dtype=bool), k=tk - tq)
         if window is not None:
             mask = mask & ~jnp.tril(jnp.ones((tq, tk), dtype=bool),
@@ -101,10 +303,14 @@ def mha_reference(q, k, v, bias=None, causal=False, sm_scale=None,
 def _pos_mask(iq, ik, block_q, block_k, causal, offset, tq_real, tk_real,
               transposed=False, window=None):
     """[bq, bk] (or [bk, bq]) validity mask for one block pair: padding
-    bounds + the causal triangle + the window's trailing edge.  Shared by
-    all four kernels."""
+    bounds + the causal triangle + the window's trailing edge, or what a
+    mask form says (:meth:`BlockDiffusion.tile_mask`).  Shared by all four
+    kernels."""
     import jax.lax as lax
 
+    if isinstance(window, BlockDiffusion):
+        return window.tile_mask(iq, ik, block_q, block_k, tq_real, tk_real,
+                                transposed)
     shape = (block_k, block_q) if transposed else (block_q, block_k)
     q_axis, k_axis = (1, 0) if transposed else (0, 1)
     q_pos = iq * block_q + lax.broadcasted_iota(jnp.int32, shape, q_axis)
@@ -128,10 +334,14 @@ def _block_dispatch(causal, pads, iq, ik, block_q, block_k, offset,
     skipped); any padding falls back to masked-everywhere.  With a
     ``window`` (causal only) the band has a second edge: blocks whose every
     key is ``window`` or more behind every query are dead too, and the mask
-    also runs on the blocks that trailing edge crosses.  ``compute``
+    also runs on the blocks that trailing edge crosses.  A mask form has a
+    ladder of its own (:meth:`BlockDiffusion.dispatch`).  ``compute``
     receives masked: bool."""
     from jax.experimental import pallas as pl
 
+    if isinstance(window, BlockDiffusion):
+        window.dispatch(iq, ik, block_q, block_k, compute)
+        return
     if not causal and not pads:
         compute(False)
         return
@@ -174,7 +384,10 @@ def _live_k(i, j, window, block_q, block_k, offset, nk):
     """The key block grid step (query block ``i``, key step ``j``) names:
     ``j`` itself, or under a window the nearest block of ``i``'s band, so
     that the dead steps before and after the band name the block already
-    resident and copy nothing."""
+    resident and copy nothing; under a mask form the nearest live block of
+    ``i``'s (:meth:`BlockDiffusion.live_k`)."""
+    if isinstance(window, BlockDiffusion):
+        return window.live_k(i, j, block_q, block_k)
     if window is None:
         return j
     lo = jnp.maximum(i * block_q + offset - (window - 1), 0) // block_k
@@ -185,12 +398,22 @@ def _live_k(i, j, window, block_q, block_k, offset, nk):
 def _live_q(i, j, window, block_q, block_k, offset, nq):
     """:func:`_live_k` for the grid that walks query blocks ``i`` inside a
     key block ``j`` (the split backward's dk/dv pass)."""
+    if isinstance(window, BlockDiffusion):
+        return window.live_q(i, j, block_q, block_k)
     if window is None:
         return i
     lo = jnp.maximum(j * block_k - offset, 0) // block_q
     hi = jnp.minimum((window + (j + 1) * block_k - 2 - offset) // block_q,
                      nq - 1)
     return jnp.clip(i, lo, hi)
+
+
+def _seen(window, q_pos, k_pos):
+    """The blockwise jax paths' second mask: a window's trailing edge, or the
+    mask form."""
+    if isinstance(window, BlockDiffusion):
+        return window.visible(q_pos, k_pos)
+    return q_pos - k_pos < window
 
 
 def _k_spec(block_q, block_k, d, window, group, offset, nk):
@@ -952,7 +1175,7 @@ def _flash_fwd_jax(q, k, v, bias, causal, sm_scale, block_k, offset,
         if causal:
             mask = mask & (q_pos >= k_pos)
         if window is not None:
-            mask = mask & (q_pos - k_pos < window)
+            mask = mask & _seen(window, q_pos, k_pos)
         s = jnp.where(mask[None], s, NEG_INF)
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -1046,7 +1269,7 @@ def _flash_bwd_jax(q, k, v, bias, o, lse, do, causal, sm_scale, block_k,
         if causal:
             mask = mask & (q_pos >= k_pos)
         if window is not None:
-            mask = mask & (q_pos - k_pos < window)
+            mask = mask & _seen(window, q_pos, k_pos)
         s = jnp.where(mask[None], s, NEG_INF)
         # true softmax from saved lse; guard fully-masked rows (lse=-inf)
         p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - lse[..., None]))
@@ -1322,7 +1545,7 @@ def _statics(q, k, v, causal, sm_scale, block_q, block_k, block_q_bwd,
                 f"{q.shape} and K {k.shape}: [b, h, Tq, d_r] and [b, h_r, "
                 "Tk, d_r] with h % h_r == 0")
         d = d + d_r                # the score's width: scale and tables
-    if window is not None:
+    if window is not None and not isinstance(window, BlockDiffusion):
         if not causal:
             raise ValueError("window= needs causal=True")
         window = int(window)
@@ -1415,7 +1638,11 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
     that band as they skip the blocks above the diagonal (no MXU work, and
     K/V index maps that name the resident block, so no copy), and run masks
     only on the blocks the two edges cross.  ``window=None`` lowers exactly
-    as before the argument existed.
+    as before the argument existed.  ``window=block_diffusion(T, B)`` (a
+    mask form, :class:`BlockDiffusion`; ``causal=False``, self-attention):
+    the rows are a noisy and a clean copy of one sequence under block
+    diffusion's three-part mask, its dead tile pairs skipped and their K/V
+    tiles not copied in the same way.
 
     K and V may have fewer heads than Q (``[batch, kv_heads, T, d]``, with
     ``heads % kv_heads == 0``): query head ``h`` reads KV head ``h //
@@ -1542,3 +1769,15 @@ def flash_lse_layout(q, k, v, causal=False, sm_scale=None, block_q=None,
                        None, None, interpret, window, q_rope, k_rope)[2]
     tq = q.shape[2]
     return "row" if _lse_rows(block_q, tq + (-tq) % block_q) else "lanes"
+
+
+def flash_blocks(q, k, v, **kw):
+    """``((block_q, block_k) of the forward, (block_q, block_k) of the
+    backward)`` a call with these arguments gets, from the shapes alone."""
+    *_, block_q, block_k, bwd_blocks, _, _, _, _ = _statics(
+        q, k, v, kw.get("causal", False), kw.get("sm_scale"),
+        kw.get("block_q"), kw.get("block_k"), kw.get("block_q_bwd"),
+        kw.get("block_k_bwd"), kw.get("bwd_impl"),
+        kw.get("interpret", False), kw.get("window"), kw.get("q_rope"),
+        kw.get("k_rope"))
+    return (block_q, block_k), bwd_blocks or (block_q, block_k)

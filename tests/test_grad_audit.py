@@ -205,6 +205,14 @@ def _configs(op):
              "KRope": [f(1, 1, 8, 2)]},
             {"sm_scale": 0.5, "causal": True}, loss_outputs=["Out"],
             rtol=8e-2, atol=2e-2),
+        # a mask form: block diffusion's three-part mask over a noisy and
+        # a clean copy of 4 tokens in blocks of 2, both query heads on one
+        # K/V head
+        "flash_attention+block_diffusion": lambda: _Cfg(
+            {"Q": [f(1, 2, 8, 4)], "K": [f(1, 1, 8, 4)],
+             "V": [f(1, 1, 8, 4)]},
+            {"sm_scale": 0.5, "causal": False, "block_diffusion": 2},
+            loss_outputs=["Out"], rtol=8e-2, atol=2e-2),
         "fsp": lambda: _Cfg({"X": [f(1, 2, 3, 3)], "Y": [f(1, 4, 3, 3)]}),
         # analysis.fusion rewrite target: exact composition of
         # mul+bias+gelu+tagged dropout (mask is a pure function of the
@@ -698,7 +706,7 @@ def _resolve(op_type):
 AUDIT_OPS = sorted(t for t in _diffable_ops() if t not in EXCLUDE)
 #: an op's second recipe, "<op>+<what>": optional slots that change what
 #: its grad op computes
-AUDIT_VARIANTS = ["flash_attention+rope"]
+AUDIT_VARIANTS = ["flash_attention+rope", "flash_attention+block_diffusion"]
 
 
 def test_audit_accounts_for_every_op():

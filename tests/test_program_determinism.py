@@ -62,6 +62,7 @@ BUILDERS = {
     "nemotron3": ("nemotron3_nano_30b_a3b",
                   lambda: _toy("test_nemotron3_cell", "toy_nemotron")),
     "olmoe": ("olmoe_1b_7b", lambda: _toy("test_olmoe_cell", "toy_olmoe")),
+    "sdar": ("sdar_30b_a3b", lambda: _toy("test_sdar_cell", "toy_sdar")),
     "bert_fused": ("bert_base",
                    lambda: _toy("test_benchmark_rehearsal", "toy_bert")),
     "resnet50": ("resnet50",

@@ -78,7 +78,8 @@ CELLS = {"joyai": ("joyai_llm_flash", "lm_mtp_s8192"),
          "xing4": ("xing4_29b_a4b", "lm_s4096_r64"),
          "solar": ("solar_open2_250b", "lm_s8192_r64"),
          "ling": ("ling3_flash_vl", "lm_s8192_r64"),
-         "nemotron3": ("nemotron3_nano_30b_a3b", "lm_s8192_r64")}
+         "nemotron3": ("nemotron3_nano_30b_a3b", "lm_s8192_r64"),
+         "sdar": ("sdar_30b_a3b", "bd_s8192_b4_r64")}
 
 
 def reads_after_update(text):
@@ -180,8 +181,8 @@ def main():
                     "says recompute, which --recompute sets, and so does "
                     "xing4's, whose timed step is the plain one; solar's "
                     "timed step recomputes: pass --recompute for it, and "
-                    "leave it out to see the plain step refused; ling's "
-                    "and nemotron3's likewise)")
+                    "leave it out to see the plain step refused; ling's, "
+                    "nemotron3's and sdar's likewise)")
     args = ap.parse_args()
     if args.run:
         return run_on_chip(args)
@@ -214,7 +215,7 @@ def main():
     if args.seq:
         traffic["seq_len"] = args.seq
     if args.recompute and args.cell in ("lfm2", "xing4", "solar", "ling",
-                                        "nemotron3"):
+                                        "nemotron3", "sdar"):
         traffic["recompute"] = True      # the adapter builds the fallback
     m = adapter.build_train(config, traffic, 7, 1, False)
     cb, step_args = dp_arith_check.caught_step(lambda: m["exe"].run(
@@ -352,6 +353,12 @@ def main():
         "flash_bwd_kernels": counted(attention_ops.FLASH_BWD_KERNEL_CTR,
                                      "kernel", "window", "widths"),
         "flash_fwd_lse": flash_fwd_lse(),
+        # every flash lowering by mask form, block length, implementation
+        # and kernel, and under a mask form its tile pairs by their fate
+        "flash_masks": counted(attention_ops.FLASH_MASK_LOWERINGS_CTR,
+                               "mask", "block", "impl", "kernel"),
+        "flash_tile_pairs": counted(attention_ops.FLASH_TILE_PAIRS_CTR,
+                                    "mask", "pass", "state"),
         "rope_lowerings": rope_lowerings(),
         "hc_lowerings": hc_lowerings(),
         "kda_lowerings": kda_lowerings(),

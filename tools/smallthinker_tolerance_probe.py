@@ -34,6 +34,11 @@ layer's norms and head gate).  ``--cell nemotron3`` (PR 57): the
 Nemotron-3-Nano cell's (8 of 128 un-gated experts; the kinds ``mamba``, the
 Mamba-2 blocks' own parameters, and ``attention``; the reference's
 token-by-token state-space recurrence run in bf16 is the control of the scan).
+``--cell sdar`` (PR 61): the SDAR cell's under block diffusion (16 of 128
+experts; four feeds, the adapter's ``FEEDS``; the experts compared over the
+stream's 2L rows, the final-norm output over its noisy half; the kind
+``attention``: what reaches the loss through the flash kernels under the
+mask).
 """
 
 import argparse
@@ -66,7 +71,9 @@ CELLS = {"smallthinker": ("smallthinker_21b_a3b", "lm_s16384",
                   "build_ling_pretrain", "test_ling3_cell", "toy_ling", {}),
          "nemotron3": ("nemotron3_nano_30b_a3b", "lm_s8192_r64",
                        "nemotron_config", "build_nemotron_h_pretrain",
-                       "test_nemotron3_cell", "toy_nemotron", {})}
+                       "test_nemotron3_cell", "toy_nemotron", {}),
+         "sdar": ("sdar_30b_a3b", "bd_s8192_b4_r64", "sdar_config",
+                  "build_sdar_pretrain", "test_sdar_cell", "toy_sdar", {})}
 
 
 def main():
@@ -104,6 +111,9 @@ def main():
         scope, main_p, startup = Scope(), Program(), Program()
         with scope_guard(scope), program_guard(main_p, startup):
             getattr(T, builder)(cfg, traffic["seq_len"], **forward_kw)
+            if hasattr(adapter, "scale_initial_values"):     # sdar's own
+                adapter.scale_initial_values(
+                    startup, config["assumed"]["initial_scale"])
             _train.executor(on_chip).run(
                 startup, scope=scope, seed=harness.exe_seed(
                     traffic["weights_seed"]))
@@ -122,8 +132,8 @@ def main():
 
         def against_float32(p):
             s = reference.sequence_sums(
-                p, jnp.asarray(feed["src_ids"]),
-                jnp.asarray(feed["lm_label"]),
+                p, *(jnp.asarray(feed[k]) for k in getattr(
+                    adapter, "FEEDS", ("src_ids", "lm_label"))),
                 **adapter.reference_kw(cfg, q_block))
             got = float(reference.loss_of_sums(s)["loss"])
             top = np.asarray(s["top_e"])
@@ -131,10 +141,14 @@ def main():
                 reference, params, feed, cfg,
                 hidden=np.asarray(s["hidden"], np.float32), q_block=q_block)
             differ = olmoe.tokens_that_differ(top, ref_top)
+            # the rows the final norm's output has: all, or (sdar) a
+            # doubled stream's noisy half
+            others = ~(adapter.noisy_rows(differ, 1, traffic["seq_len"])
+                       if hasattr(adapter, "noisy_rows") else differ)
             return {"loss_rel": _train.rel_err(got, want),
                     "top_k_differ_share": float(differ.mean()),
                     "hidden_rel_others": olmoe.hidden_difference(per_token,
-                                                                 ~differ),
+                                                                 others),
                     "hidden_rel_all": olmoe.hidden_difference(per_token)}
 
         _, g_ref = adapter.reference_gradient(reference, params, feed, cfg,
@@ -144,7 +158,7 @@ def main():
             _, g = adapter.reference_gradient(reference, p, feed, cfg,
                                               q_block)
             off = adapter.gradient_difference(g_ref, g)
-            return {"gradient": {k: v if k == "all" else list(v)
+            return {"gradient": {k: v if k in ("all", "leaves") else list(v)
                                  for k, v in off.items()}}
 
         out = {"device": jax.devices()[0].device_kind, "seed": seed}

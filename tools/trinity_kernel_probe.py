@@ -19,6 +19,10 @@ and whether ``Out`` and ``Lse`` are its bits.  ``--aot``, no chip: each
 forward row compiled for a described v5e, the VMEM limit the call asks for
 and the least that compiles it.  ``--window 0``: the full causal half alone
 (OLMoE's ``--seq 4096 --heads 64 --kv_heads 64 --window 0``).
+``--block_diffusion B`` (PR 61): block diffusion's three-part mask over the
+``--seq`` rows (a noisy and a clean copy of ``seq / 2`` tokens in blocks of
+``B``) in the window's place, then the full causal half at the same length
+(SDAR's cell: ``--seq 16384 --block_diffusion 4 --oracle_heads 1``).
 
     chiprun -- python3 tools/trinity_kernel_probe.py --forward --parent .scratch/parent
     JAX_PLATFORMS=cpu python3 tools/trinity_kernel_probe.py --aot
@@ -38,8 +42,19 @@ import joyai_kernel_probe as probe  # noqa: E402  (the forward sweep)
 
 
 def _windows(args):
-    """The window and the full causal half; ``--window 0``: the latter."""
+    """The window (or ``--block_diffusion``'s mask form) and the full causal
+    half; ``--window 0``: the latter."""
+    if args.block_diffusion:
+        import importlib
+        F = importlib.import_module("paddle_tpu.pallas.flash_attention")
+        return (F.block_diffusion(args.seq, args.block_diffusion), None)
     return (args.window, None) if args.window else (None,)
+
+
+def _causal(window):
+    """A mask form is the whole mask; a window is an edge of the causal
+    half."""
+    return window is None or isinstance(window, int)
 
 
 def aot(args, F):
@@ -61,7 +76,8 @@ def aot(args, F):
         probe.aot_forward(
             F, probe._blocks(args.fwd_blocks), lambda bq, bk: jax.jit(
                 lambda q, k, v: F._flash_fwd_pallas(
-                    q, k, v, None, True, args.head_dim ** -0.5, bq, bk, 0,
+                    q, k, v, None, _causal(window), args.head_dim ** -0.5,
+                    bq, bk, 0,
                     False,
                     window, group)
             ).lower(s(args.heads), s(args.kv_heads), s(args.kv_heads)))
@@ -73,6 +89,7 @@ def main():
     ap.add_argument("--heads", type=int, default=32)
     ap.add_argument("--kv_heads", type=int, default=4)
     ap.add_argument("--window", type=int, default=2048)
+    ap.add_argument("--block_diffusion", type=int, default=0)
     ap.add_argument("--head_dim", type=int, default=128,
                     help="the heads' width (LFM2: --seq 16384 --heads 32 "
                     "--kv_heads 8 --head_dim 64 --window 0)")
@@ -103,6 +120,7 @@ def main():
     interpret = jax.default_backend() != "tpu"
     if interpret:                                  # a rehearsal of the path
         args.seq, args.window, args.iters = 64, 16, 1
+        args.block_diffusion = min(args.block_diffusion, 4)
         args.fwd_blocks = "16,16;32,16"
     key = jax.random.PRNGKey(0)
     shape = lambda h: (1, h, args.seq,  # noqa
@@ -135,7 +153,7 @@ def main():
             f32 = [a.astype(jnp.float32) for a in (qg, kg, vg)]
             with jax.default_matmul_precision("highest"):
                 o, back = jax.vjp(lambda q, k, v: F.mha_reference(
-                    q, k, v, causal=True, window=window), *f32)
+                    q, k, v, causal=_causal(window), window=window), *f32)
                 return (o,) + back(dog.astype(jnp.float32))
 
         def of_group(i):
@@ -160,12 +178,13 @@ def main():
         if args.forward:
             probe.forward_sweep(F, parent, q, k, v,
                                 probe._blocks(args.fwd_blocks), timed,
-                                want[0], causal=True, window=window,
+                                want[0], causal=_causal(window),
+                                window=window,
                                 interpret=interpret)
             continue
         for blk in blocks:
             for impl in args.impls.split(","):
-                kw = dict(causal=True, window=window, bwd_impl=impl,
+                kw = dict(causal=_causal(window), window=window, bwd_impl=impl,
                           interpret=interpret)
                 if blk:
                     kw.update(block_q=blk[0], block_k=blk[1],
@@ -178,7 +197,8 @@ def main():
                     lambda q, k, v: F.flash_attention(q, k, v, **kw),
                     q, k, v)[1](do))
                 try:
-                    row = {"window": window, "blocks": blk, "bwd": impl,
+                    row = {"window": window if _causal(window)
+                           else str(window), "blocks": blk, "bwd": impl,
                            "fwd_ms": timed(fwd, q, k, v),
                            "fwd_bwd_ms": timed(both, q, k, v, do)}
                     mem = both.lower(q, k, v, do).compile().memory_analysis()
