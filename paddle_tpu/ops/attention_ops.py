@@ -73,9 +73,23 @@ FLASH_TILE_PAIRS_CTR = _monitor.REGISTRY.counter(
     "counted while tracing, once per compile, nothing per step",
     ("mask", "block", "pass", "state"))
 
+FLASH_SUBTILES_CTR = _monitor.REGISTRY.counter(
+    "paddle_tpu_flash_subtiles_total",
+    "of the MASKED tile pairs of one head's grid in a flash lowering "
+    "(causal, window or a mask form; the states of "
+    "paddle_tpu_flash_tile_pairs_total are theirs), what the kernels run "
+    "of them at the call's blocks: their sub-tiles by state, free (run "
+    "mask-free), masked (the mask runs on the sub-tile) or skipped (no "
+    "product, no exponential), where the tile pair's live region is static "
+    "and it is run by sub-tiles; whole: the masked tile pairs that run "
+    "all of their scores (padding, Tq != Tk, ragged blocks, a window that "
+    "is no multiple of the sub-tile, a bias); pass = fwd or bwd — counted "
+    "while tracing, once per compile, nothing per step",
+    ("mask", "block", "pass", "state"))
+
 
 def _flash_call(ctx, attrs, q, k, v, counter, pallas, more_labels=None,
-                q_rope=None, k_rope=None):
+                q_rope=None, k_rope=None, bias=None):
     """What the op and its grad op share: ``(window or None, the attributes
     as keyword arguments of the kernel's entry points, the widths label)``,
     and one count of the lowering in ``counter`` (``pallas``: whether a TPU
@@ -109,7 +123,8 @@ def _flash_call(ctx, attrs, q, k, v, counter, pallas, more_labels=None,
                     impl="pallas" if pallas and on_tpu() else "jax",
                     widths=widths,
                     **(more_labels(kw) if more_labels else {}))
-        _count_mask(kw, q, k, v, pallas, counter is FLASH_LOWERINGS_CTR)
+        _count_mask(kw, q, k, v, pallas, counter is FLASH_LOWERINGS_CTR,
+                    bias)
     return window, kw, widths
 
 
@@ -129,12 +144,14 @@ def _mask_form(attrs, q, k):
     return block_diffusion(q.shape[2], attrs["block_diffusion"])
 
 
-def _count_mask(kw, q, k, v, pallas, forward):
-    """One count of a lowering by its mask's form and, under a mask form,
-    of its grid's tile pairs by what becomes of them."""
+def _count_mask(kw, q, k, v, pallas, forward, bias=None):
+    """One count of a lowering by its mask's form; under a mask form, of
+    its grid's tile pairs by what becomes of them; and under any mask, of
+    its masked tile pairs' sub-tiles (``bias``: the op's, under which every
+    masked tile pair runs whole)."""
     from ..device import on_tpu
     from ..pallas.flash_attention import (BlockDiffusion, flash_blocks,
-                                          flash_bwd_kernel)
+                                          flash_bwd_kernel, flash_subtiles)
     window = kw["window"]
     form = isinstance(window, BlockDiffusion)
     mask = window.scope if form else "window" if window is not None else \
@@ -145,11 +162,18 @@ def _count_mask(kw, q, k, v, pallas, forward):
     FLASH_MASK_LOWERINGS_CTR.inc(
         mask=mask, block=block, kernel=kernel,
         impl="pallas" if pallas and on_tpu() else "jax")
+    which = {"pass": "fwd" if forward else "bwd"}
     if form:
         blocks = flash_blocks(q, k, v, **kw)[0 if forward else 1]
         for state, n in window.tile_pairs(*blocks).items():
             FLASH_TILE_PAIRS_CTR.inc(n, mask=mask, block=block, state=state,
-                                     **{"pass": "fwd" if forward else "bwd"})
+                                     **which)
+    if mask != "none":
+        for state, n in flash_subtiles(q, k, v, bias=bias, **kw)[
+                0 if forward else 1].items():
+            if n:
+                FLASH_SUBTILES_CTR.inc(n, mask=mask, block=block,
+                                       state=state, **which)
 
 
 def _window_scope(window):
@@ -193,7 +217,7 @@ def _flash_attention(ctx, ins, attrs):
     window, kw, _ = _flash_call(
         ctx, attrs, q, k, v, FLASH_LOWERINGS_CTR, pallas=True,
         more_labels=lambda kw: {"lse": flash_lse_layout(q, k, v, **kw)},
-        q_rope=X(ins, "QRope"), k_rope=X(ins, "KRope"))
+        q_rope=X(ins, "QRope"), k_rope=X(ins, "KRope"), bias=X(ins, "Bias"))
     with _window_scope(window):
         out, lse = flash_attention_fwd(q, k, v, X(ins, "Bias"), **kw)
         # Lse leaves with Out: where the kernel still writes the lane-
@@ -243,7 +267,7 @@ def _flash_attention_grad(ctx, ins, attrs):
     bias, out, d_out = X(ins, "X$Bias"), X(ins, "Out"), X(ins, "OG$Out")
     window, kw, widths = _flash_call(
         ctx, attrs, q, k, v, FLASH_GRAD_LOWERINGS_CTR, pallas=bias is None,
-        q_rope=X(ins, "X$QRope"), k_rope=X(ins, "X$KRope"))
+        q_rope=X(ins, "X$QRope"), k_rope=X(ins, "X$KRope"), bias=bias)
     if not getattr(ctx, "is_abstract", False):
         FLASH_BWD_KERNEL_CTR.inc(
             kernel=flash_bwd_kernel(q, k, v, bias, **kw),

@@ -41,7 +41,10 @@ PR 61: the one mask here that is no function of ``i - j``).  Four helpers
 tell them apart and nothing else does: :func:`_pos_mask` (a tile's mask),
 :func:`_block_dispatch` (a tile pair is skipped, mask-free or masked),
 :func:`_live_k` / :func:`_live_q` (the index maps, so that a skipped step
-copies no tile), and :func:`_seen` on the blockwise jax paths.
+copies no tile), and :func:`_seen` on the blockwise jax paths.  A masked
+tile pair whose live region is static runs by sub-tiles, those the mask
+leaves live and the mask on the edge ones alone (PR 62:
+:func:`_subtile_kinds`); the others run all of their scores.
 
 Capability anchor in the reference: attention assembled from separate
 matmul/softmax/dropout ops in its Transformer recipe
@@ -87,12 +90,17 @@ class BlockDiffusion(collections.namedtuple("BlockDiffusion", "half block")):
     - ``i >= half``, ``j < half``: never.
 
     ``L² + L·block`` pairs a head (``L = half``) of the ``4 L²``.  The
-    kernels take it through the places a window goes: :meth:`dispatch` (a tile pair
-    is skipped, mask-free or masked), :meth:`tile_mask` (the mask of a tile an
-    edge crosses, from a column of query blocks and a row of key blocks, no
-    tile-wide integer work but one compare and one select), :meth:`live_k`
-    and :meth:`live_q` (the index maps that keep dead steps from copying a
-    tile).  ``causal`` is not read: the form is the whole mask."""
+    kernels take it through the places a window goes: :meth:`tile_state` and
+    :meth:`dispatch` (a tile pair is skipped, mask-free or masked; the same
+    question of a sub-tile, asked in numpy at trace time, is what
+    :func:`_subtile_kinds` builds the three kinds of masked tile pair from:
+    at blocks of 4 in tiles of 1024 the noisy x noisy diagonal runs 8 of its
+    64 sub-tiles of 128, the other two diagonals 36), :meth:`tile_mask` (the
+    mask of a tile or sub-tile an edge crosses, from a column of query
+    blocks and a row of key blocks, no tile-wide integer work but one
+    compare and one select), :meth:`live_k` and :meth:`live_q` (the index
+    maps that keep dead steps from copying a tile).  ``causal`` is not read:
+    the form is the whole mask."""
 
     __slots__ = ()
     #: the named scope the op's device operations lie under, and the
@@ -237,11 +245,8 @@ class BlockDiffusion(collections.namedtuple("BlockDiffusion", "half block")):
         """``{"free", "masked", "dead"}``: the tile pairs of one head's grid
         at these blocks by what :meth:`dispatch` does with them."""
         t = 2 * self.half
-        q0 = np.arange(0, t, block_q, dtype=np.int64)[:, None]
-        k0 = np.arange(0, t, block_k, dtype=np.int64)[None, :]
-        live, full = self.tile_state(q0, k0, block_q, block_k, xp=np)
-        return {"free": int(full.sum()), "masked": int((live & ~full).sum()),
-                "dead": int((~live).sum())}
+        return dict(zip(("free", "masked", "dead"), grid_tile_pairs(
+            False, self, block_q, block_k, t, t)))
 
 
 def block_diffusion(t, block):
@@ -315,6 +320,13 @@ def _pos_mask(iq, ik, block_q, block_k, causal, offset, tq_real, tk_real,
     q_axis, k_axis = (1, 0) if transposed else (0, 1)
     q_pos = iq * block_q + lax.broadcasted_iota(jnp.int32, shape, q_axis)
     k_pos = ik * block_k + lax.broadcasted_iota(jnp.int32, shape, k_axis)
+    if tk_real is None:
+        # a sub-tile of an unpadded call (:func:`_subtile_kinds`): the
+        # causal edge or the window's, whichever crosses it, and no bound
+        mask = q_pos + offset >= k_pos
+        if window is not None:
+            mask = mask & (q_pos + offset - k_pos < window)
+        return mask
     mask = k_pos < tk_real
     if tq_real is not None:
         mask = mask & (q_pos < tq_real)
@@ -325,8 +337,200 @@ def _pos_mask(iq, ik, block_q, block_k, causal, offset, tq_real, tk_real,
     return mask
 
 
+def _band_live(iq, ik, block_q, block_k, offset, window):
+    """Whether any pair of tile pair ``(iq, ik)`` of a causal grid lies in
+    the band (traced scalars or numpy arrays: plain arithmetic).  Under a
+    ``window`` the band has a second edge: a tile whose every key is
+    ``window`` or more behind every query is dead too."""
+    live = iq * block_q + block_q - 1 + offset >= ik * block_k
+    if window is not None:
+        # nearest pair of the block: first query, last key
+        live = live & (iq * block_q + offset
+                       - ((ik + 1) * block_k - 1) < window)
+    return live
+
+
+def _band_full(iq, ik, block_q, block_k, offset, window):
+    """Whether every pair of tile pair ``(iq, ik)`` lies in the band: no
+    edge crosses it (and no padding lies in it, the caller's to know)."""
+    full = (ik + 1) * block_k - 1 <= iq * block_q + offset
+    if window is not None:
+        # farthest pair: last query, first key
+        full = full & (iq * block_q + block_q - 1 + offset
+                       - ik * block_k < window)
+    return full
+
+
+def _tile_state(window, iq, ik, block_q, block_k, offset, xp=jnp):
+    """``(live, full)`` of tile pair ``(iq, ik)`` (traced scalars, or numpy
+    arrays with ``xp=np``) under the causal half, a window or a mask
+    form."""
+    if isinstance(window, BlockDiffusion):
+        return window.tile_state(iq * block_q, ik * block_k, block_q,
+                                 block_k, xp=xp)
+    return (_band_live(iq, ik, block_q, block_k, offset, window),
+            _band_full(iq, ik, block_q, block_k, offset, window))
+
+
+# A masked tile pair by sub-tiles (PR 62).  The kernels' dispatch knows a
+# tile pair as dead, free or masked, and a masked one used to run whole: all
+# of its block_q x block_k scores, every product, exponential and select,
+# however little the edge leaves (half of a causal diagonal tile, 0.4 % of
+# block diffusion's noisy x noisy tile at blocks of 4).  Where the live
+# region of a masked tile pair is STATIC -- no padding, Tq == Tk, blocks
+# that are whole sub-tiles, a window that is a multiple of the sub-tile,
+# halves that are whole tiles under a mask form -- the tile pair is cut into
+# sub x sub sub-tiles, classified at trace time by the same
+# :func:`_tile_state` (dead, free, or an edge crosses it), and run as one
+# slab a row of sub-tiles (forward and dQ pass: ``sub`` query rows against
+# the run of key sub-tiles that are not dead; dK/dV and fused pass,
+# transposed: ``sub`` key rows against the run of query sub-tiles), the mask
+# on the slab's edge sub-tiles alone.  The sub-tile table of a masked tile
+# pair is a function of the tile pair's KIND -- ``q0 - k0`` and, under a mask
+# form, the halves its rows and columns lie in: the causal diagonal, a
+# window's trailing edge (two kinds where the window is no multiple of the
+# block), block diffusion's noisy x noisy, noisy x clean and clean x clean
+# diagonals -- and a kind is a ``pl.when`` like free / masked.  Anything
+# else keeps the whole-tile path.
+# The sub-tile, one constant a kernel, from the sweep on a v5e (table above
+# ``_FWD_DEFAULTS_D128``): 512 for the forward, 128 for the backward kernels.
+_SUB_FWD = 512          # the forward's sub-tile; 0: every masked tile whole
+_SUB_BWD = 128          # the backward kernels' (lane tiles of the sliced axis)
+_DEAD, _FREE, _EDGE = 0, 1, 2
+_MAX_KINDS = 4          # bodies traced a kernel: kinds x sub-tile rows
+
+
+def _grid_state(window, block_q, block_k, tq, tk):
+    """``(iq, ik, live, full)`` over one head's whole grid, in numpy: a
+    column of query blocks, a row of key blocks and :func:`_tile_state` of
+    every tile pair."""
+    iq = np.arange(-(-tq // block_q), dtype=np.int64)[:, None]
+    ik = np.arange(-(-tk // block_k), dtype=np.int64)[None, :]
+    live, full = (np.broadcast_to(x, (iq.size, ik.size)) for x in _tile_state(
+        window, iq, ik, block_q, block_k, tk - tq, np))
+    return iq, ik, live, full
+
+
+@functools.lru_cache(maxsize=None)
+def _subtile_kinds(causal, window, block_q, block_k, tq, tk, offset, sub):
+    """``None`` (every masked tile pair runs whole) or the kinds of masked
+    tile pair of this grid, a tuple of ``(q_noisy, k_noisy, delta, table,
+    n)``: the tile pairs with ``q0 - k0 == delta`` (and, under a mask form,
+    rows and columns in those halves) are exactly ``n`` masked ones, and the
+    ``sub x sub`` sub-tiles of each are ``table`` (``[block_q // sub,
+    block_k // sub]`` of ``_DEAD`` / ``_FREE`` / ``_EDGE``).  Found by
+    enumeration of the grid in numpy, so that nothing is assumed of a mask
+    but :func:`_tile_state`: a key whose tile pairs disagree, or that names
+    a free or a dead tile pair too, gives ``None``."""
+    form = isinstance(window, BlockDiffusion)
+    if not sub or not (form or causal) or offset or tq != tk or \
+            tq % block_q or tk % block_k or block_q % sub or block_k % sub:
+        return None
+    if isinstance(window, int) and window % sub:
+        return None
+    half = window.half if form else 0
+    if half % block_q or half % block_k:
+        return None
+    iq, ik, live, full = _grid_state(window, block_q, block_k, tq, tk)
+    q0, k0 = iq * block_q, ik * block_k
+    masked = live & ~full
+    nr, nc = block_q // sub, block_k // sub
+    r, c = np.arange(nr)[:, None], np.arange(nc)[None, :]
+    kinds = {}
+    for i, j in zip(*np.nonzero(masked)):
+        q, k = int(q0[i, 0]), int(k0[0, j])
+        sub_live, sub_full = _tile_state(window, i * nr + r, j * nc + c, sub,
+                                         sub, 0, np)
+        table = np.where(sub_full, _FREE, np.where(sub_live, _EDGE, _DEAD))
+        seen = kinds.setdefault((q < half, k < half, q - k), [table, 0])
+        if not np.array_equal(seen[0], table):
+            return None
+        seen[1] += 1
+    for (qn, kn, delta), (table, n) in kinds.items():
+        named = ((q0 < half) == qn) & ((k0 < half) == kn) & \
+            (q0 - k0 == delta)
+        if int(named.sum()) != n:
+            return None
+    if not kinds or len(kinds) > _MAX_KINDS or \
+            all((table == _EDGE).all() for table, _ in kinds.values()):
+        return None
+    return tuple((qn, kn, delta, table, n)
+                 for (qn, kn, delta), (table, n) in sorted(
+                     kinds.items(), key=lambda kv: kv[0]))
+
+
+def _slabs(table, sub):
+    """The slabs a sub-tile table is run by: ``(r, lo, edges, rows, cols)``
+    a row ``r`` of sub-tiles with any not dead, ``lo`` the first of their
+    run and ``edges`` those of the run a mask runs on (a dead one between
+    two live ones, which no mask here has, would be masked, not skipped),
+    ``rows`` and ``cols`` the slab's slices of the tile."""
+    for r, row in enumerate(np.asarray(table)):
+        live = np.nonzero(row != _DEAD)[0]
+        if live.size:
+            lo, hi = int(live[0]), int(live[-1]) + 1
+            yield (r, lo, tuple(c for c in range(lo, hi) if row[c] != _FREE),
+                   slice(r * sub, (r + 1) * sub), slice(lo * sub, hi * sub))
+
+
+def _mask_slab(x, sub, lo, edges, mask_of, fill):
+    """``x``, a slab of sub-tiles ``lo, lo + 1, ..`` side by side along its
+    last axis, with ``fill`` where ``mask_of(c)`` is false in each sub-tile
+    ``c`` of ``edges``, the others untouched: the pieces are whole lane
+    tiles, so cutting and joining them moves nothing."""
+    if not edges:
+        return x
+    n = x.shape[-1] // sub
+    pieces, start = [], 0
+    for i in range(n):
+        if lo + i in edges:
+            if start < i:
+                pieces.append(x[:, start * sub:i * sub])
+            pieces.append(jnp.where(mask_of(lo + i),
+                                    x[:, i * sub:(i + 1) * sub], fill))
+            start = i + 1
+    if start < n:
+        pieces.append(x[:, start * sub:])
+    return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, axis=-1)
+
+
+def grid_tile_pairs(causal, window, block_q, block_k, tq, tk, pads=False):
+    """``(free, masked, dead)``: the tile pairs of one head's grid by what
+    :func:`_block_dispatch` does with them (``pads``: the call pads a
+    length, and then no tile pair of a causal or unmasked grid runs
+    mask-free)."""
+    nq, nk = -(-tq // block_q), -(-tk // block_k)
+    form = isinstance(window, BlockDiffusion)
+    if not form and not causal:
+        return (0, nq * nk, 0) if pads else (nq * nk, 0, 0)
+    _, _, live, full = _grid_state(window, block_q, block_k, tq, tk)
+    if pads and not form:
+        full = np.zeros_like(live)
+    return (int(full.sum()), int((live & ~full).sum()), int((~live).sum()))
+
+
+def subtile_counts(causal, window, block_q, block_k, tq, tk, pads, sub):
+    """``{"free", "masked", "skipped", "whole"}``: of the masked tile pairs
+    of one head's grid at these blocks, their sub-tiles by what the kernels
+    do with them at sub-tiles of ``sub``, and under ``whole`` the masked
+    tile pairs that run whole (every one of a call whose live regions are
+    not static; ``pads`` as :func:`grid_tile_pairs` reads it)."""
+    kinds = None if pads else _subtile_kinds(
+        causal, window, block_q, block_k, tq, tk, tk - tq, sub)
+    counts = dict(free=0, masked=0, skipped=0, whole=0)
+    if kinds is None:
+        counts["whole"] = grid_tile_pairs(causal, window, block_q, block_k,
+                                          tq, tk, pads)[1]
+        return counts
+    for *_, table, n in kinds:
+        for state, code in (("free", _FREE), ("masked", _EDGE),
+                            ("skipped", _DEAD)):
+            counts[state] += n * int((table == code).sum())
+    return counts
+
+
 def _block_dispatch(causal, pads, iq, ik, block_q, block_k, offset,
-                    compute, window=None):
+                    compute, window=None, kinds=None):
     """The shared live/full block ladder (one definition for all three
     kernels): unpadded non-causal blocks take the mask-free path;
     unpadded causal grids run masks only on DIAGONAL blocks (fully-live
@@ -336,9 +540,24 @@ def _block_dispatch(causal, pads, iq, ik, block_q, block_k, offset,
     key is ``window`` or more behind every query are dead too, and the mask
     also runs on the blocks that trailing edge crosses.  A mask form has a
     ladder of its own (:meth:`BlockDiffusion.dispatch`).  ``compute``
-    receives masked: bool."""
+    receives masked: bool.  With ``kinds`` (:func:`_subtile_kinds`; no
+    padding then) a masked tile pair runs ``compute(True, table)``, its
+    kind's sub-tile table: one ``pl.when`` a kind."""
     from jax.experimental import pallas as pl
 
+    if kinds is not None:
+        form = isinstance(window, BlockDiffusion)
+        q0, k0 = iq * block_q, ik * block_k
+        _, full = _tile_state(window, iq, ik, block_q, block_k, offset)
+        pl.when(full)(lambda: compute(False))
+        for q_noisy, k_noisy, delta, table, _ in kinds:
+            named = q0 - k0 == delta
+            if form:
+                half = window.half
+                named = named & ((q0 < half) if q_noisy else (q0 >= half)) \
+                    & ((k0 < half) if k_noisy else (k0 >= half))
+            pl.when(named)(functools.partial(compute, True, table))
+        return
     if isinstance(window, BlockDiffusion):
         window.dispatch(iq, ik, block_q, block_k, compute)
         return
@@ -346,17 +565,9 @@ def _block_dispatch(causal, pads, iq, ik, block_q, block_k, offset,
         compute(False)
         return
     if causal:
-        live = iq * block_q + block_q - 1 + offset >= ik * block_k
-        if window is not None:
-            # nearest pair of the block: first query, last key
-            live = live & (iq * block_q + offset
-                           - ((ik + 1) * block_k - 1) < window)
+        live = _band_live(iq, ik, block_q, block_k, offset, window)
         if not pads:
-            full = (ik + 1) * block_k - 1 <= iq * block_q + offset
-            if window is not None:
-                # farthest pair: last query, first key
-                full = full & (iq * block_q + block_q - 1 + offset
-                               - ik * block_k < window)
+            full = _band_full(iq, ik, block_q, block_k, offset, window)
 
             @pl.when(full)
             def _():
@@ -371,6 +582,71 @@ def _block_dispatch(causal, pads, iq, ik, block_q, block_k, offset,
                 compute(True)
         return
     compute(True)
+
+
+# One trace a distinct call (PR 62).  A step calls one kernel at one set of
+# shapes once a layer and role (SDAR's: twelve forwards and six backwards
+# of one call each), every call traced the kernel's body again, and a body
+# run by sub-tiles is several times the equations of the whole-tile one:
+# SDAR's ``first_step_program_s`` read 47.4 s for the parent's 16.4 with the
+# backward at sub-tiles of 128.  The jaxpr of a call is kept by its
+# arguments' types, every other argument's value and what a trace reads of
+# this module (:func:`_trace_reads`), and bound again under the caller's
+# own name stack (so a trace names each call's device operations by ITS
+# scopes: nothing is a ``jit`` of its own).
+_TRACED = {}
+_TRACED_MOST = 256      # distinct calls kept; a step has a few dozen
+
+
+def _trace_reads():
+    """What a trace of a kernel call reads besides the call's arguments:
+    every function, class and number this module's namespace and the mask
+    form's class bind NOW (the kernels, the masks, the VMEM reckoning and
+    its limits, the sub-tiles, ``on_tpu``) and JAX's precision and width
+    defaults.  Part of the key of :func:`_traced_once`, so a call under a
+    replaced one of them is traced anew and never served another's
+    jaxpr."""
+    bound = list(globals().values()) + list(vars(BlockDiffusion).values())
+    return tuple(v for v in bound
+                 if callable(v) or isinstance(v, (int, float))) + (
+        jax.config.jax_default_matmul_precision, jax.config.jax_enable_x64)
+
+
+def _traced_once(fn):
+    """``fn`` (arrays and hashable values in, a tree of arrays out) traced
+    once a process for each distinct call: the arrays' types, the other
+    arguments and :func:`_trace_reads`.  Inside the executor's
+    ``shard_map`` (``check_vma=False``) the types are a shard's on the
+    map's mesh, a key of their own; under ``check_vma=True``
+    ``pallas_call`` itself refuses these kernels, kept or not: their
+    results name no ``vma``."""
+    import inspect
+    from jax.extend.core import jaxpr_as_fun
+    sig = inspect.signature(fn)
+
+    @functools.wraps(fn)
+    def call(*args, **kw):
+        bound = sig.bind(*args, **kw)
+        bound.apply_defaults()
+        arrays = {n: v for n, v in bound.arguments.items()
+                  if hasattr(v, "dtype")}
+        types = tuple((n, jax.typeof(v)) for n, v in arrays.items())
+        statics = tuple((n, v) for n, v in bound.arguments.items()
+                        if n not in arrays)
+        key = (fn, types, statics, _trace_reads())
+        if key not in _TRACED:
+            if len(_TRACED) >= _TRACED_MOST:
+                _TRACED.clear()
+            closed, out = jax.make_jaxpr(
+                lambda *xs: fn(**dict(statics), **dict(zip(arrays, xs))),
+                return_shape=True)(*(
+                    jax.ShapeDtypeStruct(t.shape, t.dtype,
+                                         weak_type=t.weak_type)
+                    for _, t in types))
+            _TRACED[key] = jaxpr_as_fun(closed), jax.tree.structure(out)
+        run, tree = _TRACED[key]
+        return jax.tree.unflatten(tree, run(*arrays.values()))
+    return call
 
 
 def _kv_head(b, group):
@@ -451,7 +727,7 @@ def _lse_rows(block_q, tqp):
 def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
                 acc_sc, m_sc, l_sc, q_sc, *, sm_scale, causal, block_q,
                 block_k, tk_real, offset, pads, window=None, lse_rows=False,
-                rope=None):
+                rope=None, kinds=None):
     """One (bh, iq, ik) grid step of online-softmax attention.
 
     ``rope`` (trace-time, like ``b_ref``): ``(qr_ref, kr_ref, qr_sc)``, the
@@ -474,6 +750,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
     the sequence divides the blocks (``pads`` is a trace-time constant),
     the causal and window masks run only on the blocks an edge crosses, and
     blocks wholly outside the band are skipped (:func:`_block_dispatch`).
+    A block an edge crosses is itself run by the sub-tiles the mask leaves
+    live where they are static (``kinds``, :func:`_subtile_kinds`):
+    ``_compute_sub`` takes ``sub`` query rows at a time against the run of
+    key sub-tiles they can see, ONE score product, the mask on the slab's
+    edge sub-tiles alone, one max / exp / sum / rescale over the slab's own
+    rows and one ``p · V[slice]``; max, denominator and accumulator are per
+    row, so a row is rescaled as often as before and sees the same keys in
+    the same order.  Without ``kinds`` (padding, a bias, ``Tq != Tk``,
+    ragged blocks) such a block runs all of its scores, as before PR 62.
     At the last key step the block's output is normalised and ``lse = m +
     log l`` leaves as a (1, block_q) row of ``[bh, 1, Tq]`` (``lse_rows``:
     the column transposed on the XLU, an exact move, one row stored) or, at
@@ -497,7 +782,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
         if rope is not None:
             qr_sc[...] = qr_ref[0].astype(jnp.float32) * sm_scale
 
-    def _compute(masked):
+    def _compute(masked, table=None):
+        if table is not None:
+            return _compute_sub(table)
         k = k_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q_sc[...], k, (((1,), (1,)), ((), ())),
@@ -526,8 +813,38 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
         m_sc[...] = m_new
         l_sc[...] = l_new
 
+    def _compute_sub(table):
+        # the masked tile pair of one kind, a slab a row of sub-tiles: the
+        # step above over ``sub`` query rows and the keys they can see
+        sub = block_q // len(table)
+        k = k_ref[0].astype(jnp.float32)
+        v = v_ref[0].astype(jnp.float32)
+        if rope is not None:
+            kr = kr_ref[0].astype(jnp.float32)
+        for r, lo, edges, rows, cols in _slabs(table, sub):
+            s = jax.lax.dot_general(
+                q_sc[rows, :], k[cols], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if rope is not None:
+                s = s + jax.lax.dot_general(
+                    qr_sc[rows, :], kr[cols], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            s = _mask_slab(s, sub, lo, edges, lambda c: _pos_mask(
+                iq * len(table) + r, ik * (block_k // sub) + c, sub, sub,
+                causal, offset, None, None, window=window), NEG_INF)
+            m_prev = m_sc[rows, :]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_sc[rows, :] = alpha * l_sc[rows, :] + jnp.sum(
+                p, axis=-1, keepdims=True)
+            acc_sc[rows, :] = acc_sc[rows, :] * alpha + jax.lax.dot_general(
+                p, v[cols], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_sc[rows, :] = m_new
+
     _block_dispatch(causal, pads, iq, ik, block_q, block_k, offset,
-                    _compute, window=window)
+                    _compute, window=window, kinds=kinds)
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -572,6 +889,7 @@ def _fwd_vmem_bytes(d, d_v, block_q, block_k, itemsize, bias_itemsize=0,
                int(_FUSED_VMEM_SHARE * _VMEM_BYTES))
 
 
+@_traced_once
 def _flash_fwd_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
                       offset, interpret, window=None, group=1, q_rope=None,
                       k_rope=None):
@@ -626,6 +944,8 @@ def _flash_fwd_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
         args.append(bias)
 
     lse_rows = _lse_rows(block_q, tqp)
+    kinds = None if bias is not None or pad_q or pad_k else _subtile_kinds(
+        causal, window, block_q, block_k, tq, tk, offset, _SUB_FWD)
 
     def kernel(q_ref, k_ref, v_ref, *rest):
         # rest = ([qr_ref, kr_ref,] [b_ref,] o_ref, lse_ref, acc, m, l, q32
@@ -639,7 +959,7 @@ def _flash_fwd_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
                     block_q=block_q, block_k=block_k,
                     tk_real=tk_real, offset=offset,
                     pads=tkp != tk_real, window=window, lse_rows=lse_rows,
-                    rope=rope)
+                    rope=rope, kinds=kinds)
 
     if lse_rows:
         lse_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
@@ -684,11 +1004,13 @@ def _flash_fwd_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    dq_sc, *, sm_scale, causal, block_q, block_k,
-                   tq_real, tk_real, offset, pads, window=None, rope=None):
+                   tq_real, tk_real, offset, pads, window=None, rope=None,
+                   kinds=None):
     """Grid (bh, iq, ik): accumulate dq over k-blocks in VMEM scratch.
-    Mask/scale elision as in _fwd_kernel (r5 skeleton microbench).  ``rope``:
-    ``(qr_ref, kr_ref, dqr_ref, dqr_sc)``, the score's second product and
-    the rotary query's gradient, ``ds · kr``, accumulated like dq."""
+    Mask/scale elision as in _fwd_kernel (r5 skeleton microbench), and its
+    slabs of sub-tiles under ``kinds``.  ``rope``: ``(qr_ref, kr_ref,
+    dqr_ref, dqr_sc)``, the score's second product and the rotary query's
+    gradient, ``ds · kr``, accumulated like dq."""
     import jax.lax as lax
     from jax.experimental import pallas as pl
 
@@ -702,7 +1024,44 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         if rope is not None:
             dqr_sc[...] = jnp.zeros_like(dqr_sc)
 
-    def _compute(masked):
+    def _compute_sub(table):
+        # one kind of masked tile pair by slabs, as in _fwd_kernel
+        sub = block_q // len(table)
+        q = q_ref[0].astype(jnp.float32) * sm_scale
+        k = k_ref[0].astype(jnp.float32)
+        v = v_ref[0].astype(jnp.float32)
+        do = do_ref[0].astype(jnp.float32)
+        lse = lse_ref[0]                             # (bq, 1)
+        delta = delta_ref[0]
+        if rope is not None:
+            kr = kr_ref[0].astype(jnp.float32)
+            qr = qr_ref[0].astype(jnp.float32) * sm_scale
+        for r, lo, edges, rows, cols in _slabs(table, sub):
+            s = lax.dot_general(q[rows], k[cols], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            if rope is not None:
+                s = s + lax.dot_general(
+                    qr[rows], kr[cols], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            p = _mask_slab(
+                jnp.exp(s - lse[rows]), sub, lo, edges,
+                lambda c: _pos_mask(
+                    iq * len(table) + r, ik * (block_k // sub) + c, sub,
+                    sub, causal, offset, None, None, window=window), 0.0)
+            dp = lax.dot_general(do[rows], v[cols], (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+            ds = p * (dp - delta[rows])
+            dq_sc[rows, :] = dq_sc[rows, :] + lax.dot_general(
+                ds, k[cols], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if rope is not None:
+                dqr_sc[rows, :] = dqr_sc[rows, :] + lax.dot_general(
+                    ds, kr[cols], (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+
+    def _compute(masked, table=None):
+        if table is not None:
+            return _compute_sub(table)
         q = q_ref[0].astype(jnp.float32) * sm_scale
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
@@ -735,7 +1094,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                 preferred_element_type=jnp.float32)
 
     _block_dispatch(causal, pads, iq, ik, block_q, block_k, offset,
-                    _compute, window=window)
+                    _compute, window=window, kinds=kinds)
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -747,7 +1106,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, *more, sm_scale, causal, block_q,
                     block_k, tq_real, tk_real, offset, pads, window=None,
-                    rope=None):
+                    rope=None, kinds=None):
     """Grid (bh, ik, iq): accumulate dk/dv over q-blocks in VMEM scratch
     (transposed tiles: everything is (bk, ·) so the MXU contractions stay
     tall).  Mask/scale elision as in _fwd_kernel (r5 microbench).  ``more``
@@ -767,7 +1126,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     score's second product, ``kr · qrᵀ`` into the same tile, and the two
     small products ``ds_t`` feeds beside its others: ``dkr += ds_t · qr``,
     one partial a QUERY head, summed over the rotary key's group outside
-    like a grouped K's, and ``dqr += ds_tᵀ · kr`` beside dq."""
+    like a grouped K's, and ``dqr += ds_tᵀ · kr`` beside dq.  ``kinds``
+    (:func:`_subtile_kinds`): a tile pair an edge crosses runs by slabs of
+    ``sub`` KEY rows, each against the run of query sub-tiles that can see
+    them (``[c · sub, block_q)`` on the causal diagonal): all five products,
+    the ``lse`` / ``delta`` rows and dq's rows over that run alone, the mask
+    on the probabilities of the edge sub-tiles; without them it runs all of
+    its scores."""
     import jax.lax as lax
     from jax.experimental import pallas as pl
 
@@ -792,7 +1157,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if rope is not None:
             dkr_sc[...] = jnp.zeros_like(dkr_sc)
 
-    def _compute(masked):
+    def _compute(masked, table=None):
+        if table is not None:
+            return _compute_sub(table)
         # sm_scale folds into q: s_t = k @ (q·scale) and
         # dk = ds_t @ (q·scale) each carry exactly one scale factor (dq
         # takes its factor on the accumulated head, at its end)
@@ -841,8 +1208,63 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     ds_t, kr, (((0,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
 
+    def _compute_sub(table):
+        # the masked tile pair of one kind, a slab a COLUMN of sub-tiles
+        # (the tiles are transposed): the step above over ``sub`` key rows
+        # and the queries that can see them, dq's rows among them
+        table = np.asarray(table).T
+        sub = block_k // len(table)
+        q = q_ref[0].astype(jnp.float32) * sm_scale
+        k = k_ref[0].astype(jnp.float32)
+        v = v_ref[0].astype(jnp.float32)
+        do = do_ref[0].astype(jnp.float32)
+        if rope is not None:
+            qr = qr_ref[0].astype(jnp.float32) * sm_scale
+            kr = kr_ref[0].astype(jnp.float32)
+        for c, lo, edges, keys, cols in _slabs(table, sub):
+            s_t = lax.dot_general(k[keys], q[cols], (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+            if rope is not None:
+                s_t = s_t + lax.dot_general(
+                    kr[keys], qr[cols], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            # the mask on the probabilities: the visible ones are the whole
+            # tile's bits, the others exactly 0 as there
+            # the rows' slabs from the refs: a (1, bq) value cut at a lane
+            # offset keeps the offset in its layout, and Mosaic broadcasts
+            # no such row over sublanes
+            p_t = _mask_slab(
+                jnp.exp(s_t - lse_ref[0, :, cols]), sub, lo, edges,
+                lambda r: _pos_mask(
+                    iq * (block_q // sub) + r, ik * len(table) + c, sub, sub,
+                    causal, offset, None, None, transposed=True,
+                    window=window), 0.0)
+            dv_sc[keys, :] = dv_sc[keys, :] + lax.dot_general(
+                p_t, do[cols], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dp_t = lax.dot_general(v[keys], do[cols], (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+            ds_t = p_t * (dp_t - delta_ref[0, :, cols])
+            dk_sc[keys, :] = dk_sc[keys, :] + lax.dot_general(
+                ds_t, q[cols], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if dq_sc is not None:
+                rows = pl.ds(pl.multiple_of(iq * block_q + lo * sub, sub),
+                             cols.stop - cols.start)
+                dq_sc[rows, :] = dq_sc[rows, :] + lax.dot_general(
+                    ds_t, k[keys], (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            if rope is not None:
+                dkr_sc[keys, :] = dkr_sc[keys, :] + lax.dot_general(
+                    ds_t, qr[cols], (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                if dq_sc is not None:
+                    dqr_sc[rows, :] = dqr_sc[rows, :] + lax.dot_general(
+                        ds_t, kr[keys], (((0,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+
     _block_dispatch(causal, pads, iq, ik, block_q, block_k, offset,
-                    _compute, window=window)
+                    _compute, window=window, kinds=kinds)
 
     @pl.when(iq == nq - 1)
     def _finalize():
@@ -935,6 +1357,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale, block_q,
                                    q_rope=q_rope, k_rope=k_rope)
 
 
+@_traced_once
 def _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale, block_q,
                             block_k, offset, interpret, window=None,
                             group=1, fused=False, q_rope=None, k_rope=None):
@@ -958,7 +1381,10 @@ def _flash_bwd_pallas_split(q, k, v, o, lse, do, causal, sm_scale, block_q,
     nq, nk = tqp // block_q, tkp // block_k
     statics = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
                    block_k=block_k, tq_real=tq, tk_real=tk, offset=offset,
-                   pads=tqp != tq or tkp != tk, window=window)
+                   pads=tqp != tq or tkp != tk, window=window,
+                   kinds=None if tqp != tq or tkp != tk else _subtile_kinds(
+                       causal, window, block_q, block_k, tq, tk, offset,
+                       _SUB_BWD))
     d_r = 0 if q_rope is None else q_rope.shape[2]
     if d_r:
         rope_group = bh // k_rope.shape[0]
@@ -1468,6 +1894,42 @@ _BWD_DEFAULTS = {2048: (1024, 512), 4096: (1024, 1024), 8192: (1024, 512),
 # the rows as shipped 2.146; the window's backward at (512, 512) 2.108, at
 # (512, 1024) stalled, no reading; forward (1024, 2048) 2.132; split at (1024,
 # 512) for both kinds of layer 1.925.
+# The sub-tile a masked tile pair is run by (``_SUB_FWD``, ``_SUB_BWD``; PR 62),
+# swept on a v5e at the rows' own blocks, bf16, ms a call, forward | fused
+# backward (forward + backward less the forward), "off" the kernels before
+# PR 62 (tools/trinity_kernel_probe.py --subs 0,128,256,512; my chip run, PR
+# 62; a second run of every row but "off" read within 0.04 ms of this one):
+#                                           off            128            256            512
+#   block diffusion 4, [32 over 4, 16384]   12.20 | 22.61  11.65 | 19.65  11.78 | 19.85  11.58 | 20.77
+#   causal [32 over 4, 16384, 128]          18.95 | 35.73  19.06 | 33.98  18.78 | 34.10  18.53 | 34.46
+#   causal [32 over 4, 8192, 128]            5.16 |  9.69   5.20 |  8.80   5.07 |  8.88   4.95 |  9.04
+#   window 2048 [32 over 4, 8192, 128]       3.38 |  6.91   3.32 |  6.40   3.15 |  6.40   2.98 |  6.93
+#     (the backward at (512, 512): 512 is the whole tile)
+#   causal [32, 8192, 128 + 64 | 128]        6.98 | 15.10   6.94 | 13.71   6.69 | 13.84   6.69 | 14.13
+#   causal [64, 4096, 128]                   2.97 |  5.55   3.02 |  4.66   2.88 |  4.73   2.75 |  4.93
+#   causal [32 over 8, 16384, 64] (LFM2)    18.38 | 34.77  18.44 | 33.04  18.17 | 33.17  17.94 | 33.53
+#   the SPLIT pair's backward (dQ pass + dK/dV pass; no listed step runs it):
+#     block diffusion 4 as above                    33.33          28.82              -          30.49
+#     causal [32 over 4, 8192, 128]                 13.96          12.78              -          13.11
+#     (at 128 it gains 4.51 and 1.18 ms where the fused kernel gains 2.96 and
+#     0.89: the dQ pass's slabs, rows of 128 like the forward's, gain too)
+# The backward's work is all area (five products and the exponentials of a
+# slab) and falls with the sub-tiles skipped, the more the finer: 128 reads
+# -5 (head width 64) to -16 %.  The forward keeps a cost a ROW of a tile
+# whatever its width (max, sum, the rescaling of the accumulator) and a slab
+# of few rows feeds the MXU badly: 512 is its best in every row (-2.2 to
+# -11.8 %), 128 loses to "off" in five of seven.  Reading the row state of all slabs before the
+# first and writing it after the last (so that no slab waits on a store)
+# moved no row by more than 0.03 ms: not taken.  Distances from the dense-mask
+# oracle are the same to the three digits printed at every sub-tile and
+# "off": block diffusion o 2.58e-3, dq 2.88e-3, dk 3.70e-3, dv 2.37e-3; causal
+# at 16384 2.50e-3, 2.89e-3, 3.69e-3, 2.39e-3; the two-product score o 2.50e-3,
+# dq 2.86e-3, dk 3.29e-3, dv 2.64e-3, dq_rope 2.87e-3, dk_rope 3.70e-3.
+# From the "off" rows, a grid step a head by least squares over the five
+# one-product cases (forward, residuals under 0.008 ms) and the four at (1024,
+# 1024) (backward): free 4.06 | 7.77 us, masked 4.28 | 8.72, DEAD 0.25 | 0.32:
+# block diffusion's 176 dead steps a head are 1.4 ms of its forward's 12.2
+# and 1.8 of its backward's 22.6, the causal half's 120 at 16384 1.0 and 1.2.
 _FWD_DEFAULTS_D128 = {4096: (1024, 1024), 8192: (1024, 1024),
                       16384: (1024, 1024)}
 _BWD_DEFAULTS_D128 = {4096: (1024, 1024), 8192: (1024, 1024),
@@ -1771,13 +2233,36 @@ def flash_lse_layout(q, k, v, causal=False, sm_scale=None, block_q=None,
     return "row" if _lse_rows(block_q, tq + (-tq) % block_q) else "lanes"
 
 
-def flash_blocks(q, k, v, **kw):
-    """``((block_q, block_k) of the forward, (block_q, block_k) of the
-    backward)`` a call with these arguments gets, from the shapes alone."""
-    *_, block_q, block_k, bwd_blocks, _, _, _, _ = _statics(
+def _statics_of(q, k, v, kw):
+    """:func:`_statics` from the keyword arguments of an entry point."""
+    return _statics(
         q, k, v, kw.get("causal", False), kw.get("sm_scale"),
         kw.get("block_q"), kw.get("block_k"), kw.get("block_q_bwd"),
         kw.get("block_k_bwd"), kw.get("bwd_impl"),
         kw.get("interpret", False), kw.get("window"), kw.get("q_rope"),
         kw.get("k_rope"))
+
+
+def flash_blocks(q, k, v, **kw):
+    """``((block_q, block_k) of the forward, (block_q, block_k) of the
+    backward)`` a call with these arguments gets, from the shapes alone."""
+    *_, block_q, block_k, bwd_blocks, _, _, _, _ = _statics_of(q, k, v, kw)
     return (block_q, block_k), bwd_blocks or (block_q, block_k)
+
+
+def flash_subtiles(q, k, v, bias=None, **kw):
+    """``(forward, backward)`` of :func:`subtile_counts` for a call with
+    these arguments, from the shapes alone: what becomes of its masked tile
+    pairs at the blocks the tables give and the kernels' sub-tiles (with a
+    ``bias`` every one runs whole: the forward keeps the whole-tile path and
+    the backward is the blockwise jax one)."""
+    causal, _, block_q, block_k, bwd_blocks, _, _, window, _ = _statics_of(
+        q, k, v, kw)
+    tq, tk = q.shape[2], k.shape[2]
+    bq_b, bk_b = bwd_blocks or (block_q, block_k)
+    return (subtile_counts(causal, window, block_q, block_k, tq, tk,
+                           bool(tk % block_k),
+                           0 if bias is not None else _SUB_FWD),
+            subtile_counts(causal, window, bq_b, bk_b, tq, tk,
+                           bool(tq % bq_b or tk % bk_b),
+                           0 if bias is not None else _SUB_BWD))

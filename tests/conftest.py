@@ -52,6 +52,17 @@ import pytest  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
+def _no_kept_flash_traces():
+    """The flash kernels' kept jaxprs (``_traced_once``) do not outlive a
+    test: what one test traced under its patches is no other's."""
+    import sys
+    yield
+    fa = sys.modules.get("paddle_tpu.pallas.flash_attention")
+    if fa is not None:
+        fa._TRACED.clear()
+
+
+@pytest.fixture(autouse=True)
 def _fresh_programs():
     """Each test gets fresh default programs + scope (ref tests use
     new Program() + program_guard; this keeps tests independent)."""

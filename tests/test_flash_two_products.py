@@ -263,10 +263,24 @@ def _one_product_jaxprs(old_signature):
 
 
 @pytest.mark.parametrize("old_signature", [True, False])
-def test_a_call_without_the_slots_traces_as_it_did(old_signature):
-    got = _one_product_jaxprs(old_signature)
-    assert {n: hashlib.sha256(t.encode()).hexdigest()[:16]
-            for n, t in got.items()} == PARENT_JAXPRS
+def test_a_call_without_the_slots_traces_as_it_did(old_signature,
+                                                   monkeypatch):
+    """Since PR 62 a masked tile pair of static kind runs by sub-tiles: at
+    (1024, 1024) the causal diagonal's bodies are new text, at (256, 256)
+    the backward's alone (the forward's sub-tile is wider than the block);
+    and with the sub-tiles off the
+    whole-tile path is the parent's text still, in all six."""
+    def hashes():
+        return {n: hashlib.sha256(t.encode()).hexdigest()[:16]
+                for n, t in _one_product_jaxprs(old_signature).items()}
+    now = hashes()
+    # forward sub-tiles of 512 and backward ones of 128: blocks of 256 hold
+    # no two of the forward's
+    assert {n for n, h in now.items() if h == PARENT_JAXPRS[n]} == {
+        "fwd.window.groups"}
+    monkeypatch.setattr(F, "_SUB_FWD", 0)
+    monkeypatch.setattr(F, "_SUB_BWD", 0)
+    assert hashes() == PARENT_JAXPRS
 
 
 def test_the_public_entry_without_the_slots_is_the_old_signatures_call():

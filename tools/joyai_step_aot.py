@@ -359,6 +359,10 @@ def main():
                                "mask", "block", "impl", "kernel"),
         "flash_tile_pairs": counted(attention_ops.FLASH_TILE_PAIRS_CTR,
                                     "mask", "pass", "state"),
+        # the masked tile pairs' sub-tiles by what the kernels run of them;
+        # "whole": masked tile pairs that run all of their scores (PR 62)
+        "flash_subtiles": counted(attention_ops.FLASH_SUBTILES_CTR,
+                                  "mask", "pass", "state"),
         "rope_lowerings": rope_lowerings(),
         "hc_lowerings": hc_lowerings(),
         "kda_lowerings": kda_lowerings(),
