@@ -193,9 +193,14 @@ MOE_LOWERINGS_CTR = _monitor.REGISTRY.counter(
     "groups = n_group/topk_group of the router's group-limited selection, "
     "1/1 where every expert competes with every other; gated = 1 where an "
     "expert is Wd (act(Wg x) * Wu x), three grouped matmuls, 0 where it is "
-    "Wd act(Wu x), two",
+    "Wd act(Wu x), two; slot_sum = the order XLA's gather brings a token's "
+    "k slots home in for the two un-sorts' sums: major, slot j of every "
+    "token in rows j*S .. (j+1)*S and the sum over the leading axis (where "
+    "k is no multiple of the float32 sublane tile of 8), or minor, a "
+    "token's k rows adjacent (k a multiple of 8, and the held-rows kernel, "
+    "which walks the slots in that order)",
     ("impl", "experts", "top_k", "held", "score_func", "ladder", "act",
-     "router_input", "unsort", "groups", "gated"))
+     "router_input", "unsort", "groups", "gated", "slot_sum"))
 
 
 MOE_ROUTED_ROWS_CTR = _monitor.REGISTRY.counter(
@@ -548,6 +553,65 @@ def _rows_unsort(S, k, d, ladder, dt):
     return None
 
 
+def _slot_major(k, unsort=None):
+    """Whether XLA's gather brings the slots home slot-major
+    (:func:`_sum_over_slots`): where the ``[S, k, d]`` float32 view would put
+    a ``k`` that is no multiple of 8, the sublane tile, on the sublanes.
+    Something the lowering reads off its input's shape, nothing else; the
+    held-rows kernel (``unsort``) builds no view."""
+    return unsort is None and k % 8 != 0
+
+
+def _sum_over_slots(rows, place, S, k, weights=None, held=None, major=False):
+    """``sum_j [held] w[t, j] * rows[place[t * k + j]]`` [S, d] in float32,
+    the terms added in slot order ``j = 0 .. k - 1``: the two un-sorts' sum
+    over a token's ``k`` slots, ``rows`` [L, d] gathered as stored and
+    widened after.  ``place`` [S * k] is in slot-id order; ``weights``
+    [S, k] float32 (the forward's ``top_p``) or none (the backward's gather
+    back to tokens); ``held`` [S * k] bool masks the slots whose row nobody
+    wrote (a column ``[S * k, 1]`` where the caller made it before it read
+    the rows).
+
+    One algorithm in one of two index orders.  ``major`` false: the gather
+    reads ``place`` as it is, a token's ``k`` rows come adjacent, the view is
+    ``[S, k, d]`` and the sum runs over axis 1.  On a TPU that view has ``k``
+    on the sublanes of float32's (8, 128) tile: a bitcast where ``k`` is a
+    multiple of 8, else a ``reshape`` that moves every row into an array
+    padded to 8, and a sum that reads the padding (Nemotron-3-Nano's k 6 and
+    Xing4.0's k 4: 18 and 7 ms a step, PERF.md section 6, PR 63).
+    ``major`` true: the gather reads ``place`` slot by slot (``S * k``
+    int32), rows ``j * S .. (j + 1) * S`` are slot ``j`` of every token, the
+    view ``[k, S, d]`` is a bitcast whenever ``S`` is a multiple of 8, and
+    the sum over the leading axis is ``k - 1`` adds of ``[S, d]`` slabs.  The
+    same products and the same terms in the same order either way.
+
+    The slot-major order of ``place`` and ``held`` is ``k`` strided slices
+    of the flat array, never ``reshape(S, k).T``: the TPU compiler makes
+    that transpose of a ``[4096, 4]`` int32 or bool array a copy into a
+    (4, 128)-tiled ``[4, 4096]`` which a v5e never finishes (Xing4.0's step
+    hung at its first run with either un-sort written so; ``[8192, 6]``
+    tiles by (8, 128) and ran; the float32 weights' transpose runs at both:
+    PERF.md section 6, PR 63)."""
+    f32 = jnp.float32
+    if major:
+        def ordered(a):             # [S * k, ...] by slot id -> by (j, t)
+            return jnp.concatenate([a[j::k] for j in range(k)])
+        view, axis = (k, S, -1), 0
+    else:
+        def ordered(a):
+            return a
+        view, axis = (S, k, -1), 1
+    ys = jnp.take(rows, ordered(place), axis=0)
+    if held is not None:
+        held = ordered(held)
+        ys = jnp.where(held[:, None] if held.ndim == 1 else held,
+                       ys.astype(f32), 0.0)
+    ys = ys.reshape(view).astype(f32)
+    if weights is not None:
+        ys = ys * (weights.T if major else weights)[:, :, None]
+    return jnp.sum(ys, axis=axis)
+
+
 def _moe_dtype(ctx, x):
     amp = getattr(ctx, "amp", False) and x.dtype in (jnp.float32,
                                                      jnp.bfloat16)
@@ -584,8 +648,16 @@ def _moe_ffn(ctx, ins, attrs):
     (float32 at full precision, whatever AMP says), ``dispatch`` (stable sort
     of the ``S*k`` slot -> expert ids, one row gather), ``experts`` (three
     grouped matmuls whose group sizes are data), ``combine`` (un-sort, weight
-    by ``p_e``, sum the ``k``).  Every shape is static; no ``[S, E, C]``
-    tensor exists.  With a share of the experts the row buffer's length is
+    by ``p_e``, sum the ``k``: :func:`_sum_over_slots`, in slot order 0 ..
+    ``k - 1`` in float32 whichever way the gather brings the slots home:
+    a token's ``k`` rows adjacent under an ``[S, k, d]`` view where ``k`` is
+    a multiple of 8, float32's sublane tile on a TPU, and else slot-major,
+    slot ``j`` of every token in rows ``j * S .. (j + 1) * S`` under a
+    ``[k, S, d]`` view, so that no view pads ``k`` to 8 and no ``reshape``
+    moves every row; ``moe_ffn_grad``'s gather back to tokens, its
+    ``dispatch``, likewise: ``_slot_major``, PR 63).  Every shape is static;
+    no ``[S, E, C]`` tensor exists.  With a share of the experts the row
+    buffer's length is
     one of ``held_ladder``'s static lengths, the shortest that holds the
     rows the router counted for the held experts (the held slots are sorted
     to the front, so every rung is dropless and gives what the longest
@@ -616,6 +688,7 @@ def _moe_ffn(ctx, ins, attrs):
     router_x = X(ins, "RouterX")
     ladder = () if n_held == E else held_ladder(S, k, n_held, E)
     unsort = _rows_unsort(S, k, d, ladder, dt) if ladder else None
+    major = _slot_major(k, unsort)
     if not getattr(ctx, "is_abstract", False):
         MOE_LOWERINGS_CTR.inc(
             impl=_experts_impl(dt), experts=str(E), top_k=str(k),
@@ -626,7 +699,8 @@ def _moe_ffn(ctx, ins, attrs):
             unsort="slots" if unsort is None else "rows",
             groups=f"{int(attrs.get('n_group', 1) or 1)}/"
                    f"{int(attrs.get('topk_group', 1) or 1)}",
-            gated="0" if wg is None else "1")
+            gated="0" if wg is None else "1",
+            slot_sum="major" if major else "minor")
         if ladder:
             _TRACED_LADDERS[S * k, E, n_held] = ladder
     xt = x.reshape(S, d)
@@ -647,8 +721,7 @@ def _moe_ffn(ctx, ins, attrs):
                 gate=functools.partial(_gate, act=act))
 
         with jax.named_scope("combine"):
-            ys = jnp.take(y, place, axis=0).reshape(S, k, d)
-            out = jnp.sum(ys.astype(jnp.float32) * top_p[:, :, None], axis=1)
+            out = _sum_over_slots(y, place, S, k, top_p, major=major)
     else:
         # the buffer's length at each stage is the rung's: the front of the
         # longest (Saved's shape), what lies behind not written.  The
@@ -672,9 +745,8 @@ def _moe_ffn(ctx, ins, attrs):
                                                   dt, act))
 
         def weighted_sum(rows, y, place, held, top_p):
-            ys = jnp.take(y[:rows], jnp.minimum(place, rows - 1), axis=0)
-            ys = jnp.where(held[:, None], ys.astype(jnp.float32), 0.0)
-            return jnp.sum(ys.reshape(S, k, d) * top_p[:, :, None], axis=1)
+            return _sum_over_slots(y[:rows], jnp.minimum(place, rows - 1),
+                                   S, k, top_p, held, major)
 
         with jax.named_scope("combine"):
             if unsort is None:
@@ -784,8 +856,8 @@ def _moe_ffn_grad(ctx, ins, attrs):
                 dxs_u, d_wu = transposed(xs, wu, du)
 
         with jax.named_scope("dispatch"):
-            dx = jnp.take(dxs_g + dxs_u, place, axis=0).reshape(S, k, d) \
-                .astype(f32).sum(axis=1)
+            dx = _sum_over_slots(dxs_g + dxs_u, place, S, k,
+                                 major=_slot_major(k))
     else:
         # the buffer's first rows are the held slots; what lies behind them
         # was never written and is masked wherever it is read.  The rung is
@@ -889,11 +961,11 @@ def _moe_ffn_grad(ctx, ins, attrs):
                             for w, c in ((wg, dg), (wu, du)))
 
         def back_to_tokens(rows, dxs, place, slot_held):
-            # gathered as stored, widened after: the same numbers as
-            # widening all the rows first, at half the bytes
-            return jnp.where(slot_held[:, None], jnp.take(
-                dxs[:rows], jnp.minimum(place, rows - 1), axis=0).astype(f32),
-                0.0).reshape(S, k, d).sum(axis=1)
+            # the mask's column before the rows are read: the order the
+            # k = 8 shares' backward has been lowered in since PR 35
+            return _sum_over_slots(held=slot_held[:, None], rows=dxs[:rows],
+                                   place=jnp.minimum(place, rows - 1), S=S,
+                                   k=k, major=_slot_major(k))
 
         with jax.named_scope("dispatch"):
             if unsort is None:

@@ -474,7 +474,14 @@ def test_rotate_half_pairing_is_another_function():
 #: gradient back to tokens as stored (bf16, widened after) and takes its
 #: buffer's length from a ladder (one rung at the toy's sizes: no switch).
 #: OLMoE's is ``test_trinity.OLMOE_TOY_STEP_SHA256``, unchanged by both.
+#: Taken again in PR 63, for the toys alone (ad196f98... until then, and
+#: still with the order forced slot-minor, ``TRINITY_TOY_STEP_SLOT_MINOR``):
+#: the toys' experts a token are no multiple of 8, so ``moe_ffn``'s un-sorts
+#: bring the slots home slot-major (``moe_ops._sum_over_slots``); Trinity's
+#: own eight lower as they did.
 TRINITY_TOY_STEP_SHA256 = (
+    "33f506a28ae94f171648a645fae9efcf37fa56bb1121c4c2bebc901f8106a7ea")
+TRINITY_TOY_STEP_SLOT_MINOR = (
     "ad196f981981175b28153f4f34ddf4cbef8f8325098fa248e3daee88402f213c")
 
 
@@ -493,14 +500,21 @@ def _step_text(mod, cfg, seq=16):
     return re.sub(r"loc\(.*?\)", "", cb.jitted.lower(*args).as_text())
 
 
-def test_one_width_and_the_default_rope_lower_as_at_the_parent():
+def test_one_width_and_the_default_rope_lower_as_at_the_parent(monkeypatch):
     """``d_qk == d_v`` and ``interleaved=False``: OLMoE's and Trinity's toy
-    steps lower to the recorded StableHLO text, byte for byte."""
+    steps lower to the recorded StableHLO text, byte for byte, and with
+    their slots summed slot-minor to the text recorded before PR 63."""
     import test_olmoe
     import test_trinity
-    text = _step_text(test_trinity, test_trinity.toy_cfg())
-    assert hashlib.sha256(text.encode()).hexdigest() == \
-        TRINITY_TOY_STEP_SHA256
-    text = _step_text(test_olmoe, test_olmoe.toy_cfg(n_layer=1))
-    assert hashlib.sha256(text.encode()).hexdigest() == \
-        test_trinity.OLMOE_TOY_STEP_SHA256
+    from paddle_tpu.ops import moe_ops
+
+    def shas():
+        return tuple(hashlib.sha256(_step_text(mod, cfg).encode()).hexdigest()
+                     for mod, cfg in ((test_trinity, test_trinity.toy_cfg()),
+                                      (test_olmoe,
+                                       test_olmoe.toy_cfg(n_layer=1))))
+    assert shas() == (TRINITY_TOY_STEP_SHA256,
+                      test_trinity.OLMOE_TOY_STEP_SHA256)
+    monkeypatch.setattr(moe_ops, "_slot_major", lambda *a: False)
+    assert shas() == (TRINITY_TOY_STEP_SLOT_MINOR,
+                      test_trinity.OLMOE_TOY_STEP_SLOT_MINOR)

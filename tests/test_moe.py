@@ -384,3 +384,234 @@ def test_the_ladders_that_take_the_kernel_follow_their_first_rung(
     assert moe_ops._rows_unsort(8192, 8, 2048, (65536,), "float32") is None
     monkeypatch.setattr(device, "on_tpu", lambda: False)
     assert moe_ops._rows_unsort(16384, 4, 2048, (65536,), "bfloat16") is None
+
+
+# -- the two un-sorts' sum over a token's slots, in either index order ---------
+#
+# Since PR 63 XLA's gather brings a token's ``k`` slots home slot-major where
+# ``k`` is no multiple of 8 (``moe_ops._sum_over_slots``): rows ``j * S .. (j
+# + 1) * S`` are slot ``j`` of every token and the sum runs over the leading
+# axis, so no ``[S, k, d]`` float32 view puts ``k`` on a TPU's sublanes.  The
+# same products and terms in the same order.
+
+import hashlib  # noqa: E402
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+KS = (2, 4, 6, 8)
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "held"])
+@pytest.mark.parametrize("weighted", [False, True], ids=["back", "sum"])
+@pytest.mark.parametrize("k", KS)
+def test_the_slots_sum_to_the_same_bits_in_either_order(k, weighted, masked):
+    """The helper alone: the two index orders and the sum written out slot by
+    slot, from bf16 rows, with and without the weights and the mask: the
+    same float32 to the bit where the terms are only added, and within the
+    rounding of a fused multiply-add where they are weighted first."""
+    from paddle_tpu.ops import moe_ops
+    S, d = 16, 24
+    rng = np.random.RandomState(k + 10 * weighted + 20 * masked)
+    rows = jnp.asarray(rng.randn(S * k, d) * 3, jnp.bfloat16)
+    place = jnp.asarray(rng.permutation(S * k), jnp.int32)
+    w = jnp.asarray(rng.rand(S, k), jnp.float32) if weighted else None
+    held = jnp.asarray(rng.rand(S * k) < 0.5) if masked else None
+    want = np.zeros((S, d), np.float32)
+    for j in range(k):
+        term = np.asarray(rows, np.float32)[np.asarray(place)[j::k]]
+        if masked:
+            term = np.where(np.asarray(held)[j::k, None], term, 0.0)
+        want = want + (term * np.asarray(w)[:, j:j + 1] if weighted else term)
+    minor, major = (np.asarray(jax.jit(lambda *a: moe_ops._sum_over_slots(
+        a[0], a[1], S, k, w, held, order))(rows, place))
+        for order in (False, True))
+    assert minor.dtype == np.float32 and minor.shape == (S, d)
+    if not weighted:
+        np.testing.assert_array_equal(major, minor)
+        np.testing.assert_array_equal(minor, want)
+    # numpy rounds each product before it adds; XLA's CPU code need not, in
+    # either order: an ulp or two of the terms' magnitudes
+    ulp = np.spacing(np.abs(np.asarray(rows, np.float32)).max() * k)
+    assert np.abs(major - minor).max() <= 2 * ulp
+    assert np.abs(minor - want).max() <= 2 * ulp
+    if masked:      # the mask as the column the grad op's held path hands over
+        got = jax.jit(lambda *a: moe_ops._sum_over_slots(
+            a[0], a[1], S, k, w, held[:, None], True))(rows, place)
+        np.testing.assert_array_equal(np.asarray(got), major)
+
+
+def test_the_order_follows_k_and_nothing_else():
+    from paddle_tpu.ops import moe_ops
+    assert [moe_ops._slot_major(k) for k in (1, 2, 4, 6, 8, 12, 16)] == \
+        [True, True, True, True, False, True, False]
+    # the held-rows kernel builds no view: its lowerings count as minor
+    assert not moe_ops._slot_major(6, object())
+
+
+#: the paths below, 16 tokens of 24 wide: every one of 32 experts held; a
+#: share of 8 of them from expert ``OFFSET`` whose ladder has one rung; and
+#: that share on a ladder of three rungs (``4k``, ``8k``, ``16k`` rows) under a
+#: routing on each rung.  ``(a, b)``: the first ``a`` tokens have every slot
+#: held here, the next ``b`` one slot, the others (two at least) none
+SLOT_PATHS = {"full": None, "one": (6, 2), "rung0": (2, 2), "rung1": (6, 2),
+              "rung2": (12, 2)}
+S_TOY, D_TOY, F_TOY, E_TOY = 16, 24, 8, 32
+SLOT_ATTRS = {"score_func": "sigmoid", "norm_topk_prob": True,
+              "norm_eps": 1e-20, "route_scale": 2.5}
+
+
+def _slot_step(k, path, monkeypatch):
+    """``step(x, d_out, rx, wr, wg, wu, wd)``: ``moe_ffn`` + ``moe_ffn_grad``
+    through the lowerings in float32, the router reading an input of its
+    own (so ``IG$X`` is the backward's gather back to tokens alone and
+    ``IG$RouterX`` the router's cotangent alone), on ``path``'s ladder."""
+    from paddle_tpu.ops import moe_ops
+    ctx = types.SimpleNamespace(amp=False)
+    share = path != "full"
+    attrs = dict(SLOT_ATTRS, top_k=k, expert_offset=OFFSET if share else 0)
+    if path.startswith("rung"):
+        monkeypatch.setattr(moe_ops, "held_ladder",
+                            lambda *a: (4 * k, 8 * k, S_TOY * k))
+
+    def step(x, d_out, rx, wr, wg, wu, wd):
+        ins = {"X": [x], "RouterX": [rx], "RouterW": [wr], "GateW": [wg],
+               "UpW": [wu], "DownW": [wd]}
+        fwd = moe_ops._moe_ffn(ctx, ins, attrs)
+        g_ins = {"X$" + n: v for n, v in ins.items()}
+        g_ins.update({"Saved": fwd["Saved"], "OG$Out": [d_out]})
+        bwd = moe_ops._moe_ffn_grad(ctx, g_ins, attrs)
+        return {"Out": fwd["Out"][0], "ExpertLoad": fwd["ExpertLoad"][0],
+                **{n: v[0] for n, v in bwd.items()}}
+    return step
+
+
+def _slot_operands(k, path, shapes_only=False):
+    """The step's operands.  Token ``t`` raises flag ``t`` of the router's
+    input and row ``t`` of the router's weight holds its scores, so the
+    routing is the one drawn here: ``path``'s ``(a, b)``."""
+    n_held, offset = (8, OFFSET) if path != "full" else (E_TOY, 0)
+    S, d, f, E = S_TOY, D_TOY, F_TOY, E_TOY
+    shapes = ((1, S, d), (1, S, d), (1, S, d), (d, E), (n_held, d, f),
+              (n_held, d, f), (n_held, f, d))
+    if shapes_only:
+        return [jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes]
+    rng = np.random.RandomState(8 * KS.index(k) +
+                                sorted(SLOT_PATHS).index(path))
+    here = np.arange(offset, offset + n_held)
+    away = np.setdiff1d(np.arange(E), here)
+    a, b = SLOT_PATHS[path] or (S, 0)
+    top_e = [rng.choice(here, k, replace=False) for _ in range(a)] + \
+        [np.concatenate([rng.choice(here, 1),
+                         rng.choice(away, k - 1, replace=False)])
+         for _ in range(b)] + \
+        [rng.choice(away, k, replace=False) for _ in range(S - a - b)]
+    wr = rng.randn(d, E) * 0.05
+    for t, chosen in enumerate(top_e):      # distinct margins: no near tie
+        wr[t] += -2.0
+        wr[t, chosen] += 3.0 + 0.3 * rng.permutation(k)
+    values = [rng.randn(*s) * 0.3 for s in shapes]
+    values[2], values[3] = np.eye(S, d)[None], wr
+    return [jnp.asarray(v, jnp.float32) for v in values], \
+        np.sort(np.stack(top_e), axis=1), a * k + b
+
+
+@pytest.mark.parametrize("path", sorted(SLOT_PATHS))
+@pytest.mark.parametrize("k", KS)
+def test_both_slot_orders_match_the_float32_reference(k, path, monkeypatch):
+    """``Out``, ``IG$X``, the router's cotangent (``IG$RouterX``),
+    ``IG$RouterW`` and the expert weights' gradients against
+    ``benchmark/reference/trinity_mini.py``'s float32 pieces, at the limits
+    the ops' other reference tests use (1e-5 of the largest entry forward,
+    1e-4 backward): every expert held, a share on one rung and on each of
+    three rungs, with tokens none of whose slots are held; the counter says
+    which order each lowering summed in."""
+    from benchmark.reference import trinity_mini as ref
+    from paddle_tpu.ops import moe_ops
+    (x, d_out, rx, wr, wg, wu, wd), top_e, held_rows = _slot_operands(k, path)
+    ctr = moe_ops.MOE_LOWERINGS_CTR
+    labels = dict(top_k=str(k), router_input="own", experts=str(E_TOY))
+    before = {o: ctr.value(slot_sum=o, **labels) for o in ("major", "minor")}
+    got = jax.jit(_slot_step(k, path, monkeypatch))(x, d_out, rx, wr, wg, wu,
+                                                    wd)
+    assert {o: ctr.value(slot_sum=o, **labels) - before[o]
+            for o in before} == {"major": k != 8, "minor": k == 8}
+    n_held = wg.shape[0]
+    offset = OFFSET if n_held < E_TOY else 0
+    load = np.asarray(got.pop("ExpertLoad"))
+    np.testing.assert_array_equal(
+        load, np.bincount(top_e.ravel(), minlength=E_TOY))
+    assert int(load[offset:offset + n_held].sum()) == held_rows
+    if path.startswith("rung"):
+        assert moe_ops.held_rung(held_rows, (4 * k, 8 * k, S_TOY * k)) == \
+            int(path[-1])
+
+    def want(x, rx, wr, wg, wu, wd):
+        blk = {"router_w": wr, "select_bias": jnp.zeros(E_TOY)}
+        weight, _ = ref.route(rx[0], blk, k, 2.5)
+        return sum(weight[:, offset + e, None] *
+                   ref.gated(x[0], wg[e], wu[e], wd[e])
+                   for e in range(n_held))[None]
+
+    with jax.default_matmul_precision("highest"):
+        out, vjp = jax.vjp(want, x, rx, wr, wg, wu, wd)
+        grads = dict(zip(("IG$X", "IG$RouterX", "IG$RouterW", "IG$GateW",
+                          "IG$UpW", "IG$DownW"), vjp(d_out)))
+    assert set(got) == set(grads) | {"Out"}
+    for name, value in dict(grads, Out=out).items():
+        w_, g_ = np.asarray(value, np.float64), np.asarray(got[name],
+                                                           np.float64)
+        err = np.abs(g_ - w_).max() / max(np.abs(w_).max(), 1e-12)
+        assert err <= (1e-5 if name == "Out" else 1e-4), (name, err)
+
+
+#: sha256[:16] of the jaxpr text of ``_slot_step`` at the PARENT commit
+#: f43df25 (jax 0.9.0), taken there with ``_slot_text``: what "k = 8 lowers
+#: to the parent's text, and so does every k with the slots brought home
+#: slot-minor" is held to
+PARENT_SLOT_JAXPRS = {
+    "full.k2": "5e96d016caaa4814", "full.k4": "421f5a92c9b23a0b",
+    "full.k6": "3b416883f6bc55fc", "full.k8": "a0d93ed36a99b145",
+    "one.k2": "af7c94ed137917e7", "one.k4": "c03d7c44cc86c10d",
+    "one.k6": "b0270ed505aef66f", "one.k8": "ca0a37b260b28652",
+    "rung1.k2": "9fdf4ef03349bd88", "rung1.k4": "6138c8f18f708ca3",
+    "rung1.k6": "6e99033b6246e1c4", "rung1.k8": "e7bba54bcbbe360d"}
+
+
+def _slot_text(k, path, monkeypatch):
+    return str(jax.make_jaxpr(_slot_step(k, path, monkeypatch))(
+        *_slot_operands(k, path, shapes_only=True)))
+
+
+@pytest.mark.skipif(jax.__version__ != "0.9.0",
+                    reason="the pinned texts are jax 0.9.0's")
+@pytest.mark.parametrize("path", ["full", "one", "rung1"])
+@pytest.mark.parametrize("k", KS)
+def test_k8_traces_as_the_parent_did_and_no_other_k_views_k_on_the_sublanes(
+        k, path, monkeypatch):
+    """The traced text of the op and its grad op: at k = 8 the parent's, to
+    the character; at k 2, 4 and 6 no ``reshape`` to ``(S, k, d)`` is left
+    (the view is ``(k, S, d)``: the forward's sum and the backward's
+    gather, on every rung), no index or mask is transposed, and with the order
+    forced slot-minor the text is the parent's again: nothing else moved."""
+    from paddle_tpu.ops import moe_ops
+
+    def sha(text):
+        return hashlib.sha256(text.encode()).hexdigest()[:16]
+    text = _slot_text(k, path, monkeypatch)
+    minor, major = (f"new_sizes=({a}, {b}, {D_TOY})"
+                    for a, b in ((S_TOY, k), (k, S_TOY)))
+    rungs = 3 if path.startswith("rung") else 1
+    if k == 8:
+        assert sha(text) == PARENT_SLOT_JAXPRS[f"{path}.k{k}"]
+        assert (text.count(minor), text.count(major)) == (2 * rungs, 0)
+        return
+    assert (text.count(minor), text.count(major)) == (0, 2 * rungs)
+    assert sha(text) != PARENT_SLOT_JAXPRS[f"{path}.k{k}"]
+    monkeypatch.setattr(moe_ops, "_slot_major", lambda *a: False)
+    parents = _slot_text(k, path, monkeypatch)
+    assert sha(parents) == PARENT_SLOT_JAXPRS[f"{path}.k{k}"]
+    # the slot-major order of the indices and the mask is strided slices: a
+    # transposed [S, 4] int32 or bool array hangs a v5e
+    for dtype in ("i32", "bool"):
+        assert f"{dtype}[{k},{S_TOY}] = transpose" not in text

@@ -281,15 +281,30 @@ def _lowered(name):
     return _jaxpr_text(f, x, w) + _jaxpr_text(g, x, w)
 
 
+#: the same of the four ``moe_ffn`` toys since PR 63: two experts a token is
+#: no multiple of 8, so XLA's gather brings the slots home slot-major
+#: (``moe_ops._sum_over_slots``); with the order forced slot-minor the texts
+#: are ``PARENT``'s still
+SLOT_MAJOR = {"moe_all_silu": "81247a05993d0673",
+              "moe_all_relu": "115bc015a0e8ff78",
+              "moe_held_silu": "2e5d3e6758c1cfba",
+              "moe_held_relu": "28d43fcda25370ad"}
+
+
 @pytest.mark.skipif(jax.__version__ != "0.9.0",
                     reason="the pinned texts are jax 0.9.0's")
 @pytest.mark.parametrize("name", sorted(PARENT))
-def test_the_defaults_lower_to_the_parents_text(name):
+def test_the_defaults_lower_to_the_parents_text(name, monkeypatch):
     """``short_conv`` without a bias and ``moe_ffn`` with gated experts are
     the lowerings they were before the arguments existed, forward and grad
-    op, to the text of their jaxprs."""
-    text = _lowered(name)
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == PARENT[name]
+    op, to the text of their jaxprs; ``moe_ffn``'s apart from the order its
+    un-sorts sum a token's two slots in (PR 63)."""
+    def sha():
+        return hashlib.sha256(_lowered(name).encode()).hexdigest()[:16]
+    if name in SLOT_MAJOR:
+        assert sha() == SLOT_MAJOR[name]
+        monkeypatch.setattr(moe_ops, "_slot_major", lambda *a: False)
+    assert sha() == PARENT[name]
 
 
 def test_ungated_experts_run_two_grouped_matmuls_where_gated_run_three():

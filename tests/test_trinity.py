@@ -339,6 +339,28 @@ def test_moe_lowerings_carry_held_and_score_func():
     assert ctr.value(**labels) == before + 1
 
 
+@pytest.mark.parametrize("k,order", [(3, "major"), (8, "minor")])
+def test_moe_lowerings_carry_the_order_the_slots_are_summed_in(k, order):
+    """``slot_sum``: ``major`` where the experts a token are no multiple of
+    8 (XLA's gather brings slot ``j`` of every token home together),
+    ``minor`` where they are; one lowering counts under one of the two, and a
+    reader that does not name the label sees it as it did."""
+    from paddle_tpu.ops.moe_ops import MOE_LOWERINGS_CTR as ctr
+    assert "slot_sum" in ctr.labelnames
+    labels = dict(impl="ragged_dot", experts="16", top_k=str(k), held="8",
+                  score_func="sigmoid", ladder=str(5 * k))
+    before = [ctr.value(**labels), ctr.value(slot_sum="major", **labels),
+              ctr.value(slot_sum="minor", **labels)]
+    rng = np.random.RandomState(4)
+    _run_share(rng.randn(1, 5, 16).astype(np.float32),
+               _moe_weights(rng, 16, 16, 8, 12), 16, k, 12, 4,
+               backward=False)
+    assert [ctr.value(**labels), ctr.value(slot_sum="major", **labels),
+            ctr.value(slot_sum="minor", **labels)] == \
+        [before[0] + 1, before[1] + (order == "major"),
+         before[2] + (order == "minor")]
+
+
 # -- the old lowerings are the old lowerings ----------------------------------------
 
 #: sha256 of the StableHLO text of the OLMoE toy block's training step
@@ -350,8 +372,15 @@ def test_moe_lowerings_carry_held_and_score_func():
 #: taken at its parent: d6d2bc0b...) covered the forward only, which is why
 #: PR 33's change of the backward did not move it.  A PR that means to change
 #: OLMoE's lowering replaces it (print the text's hash from
-#: ``_olmoe_step_text``) and says so.
+#: ``_olmoe_step_text``) and says so.  PR 63 did, for the toy alone (it read
+#: fb53143a... until then, and does still with the order forced slot-minor,
+#: ``OLMOE_TOY_STEP_SLOT_MINOR``): the toy routes to two experts a token, no
+#: multiple of 8, so its un-sorts bring the slots home slot-major
+#: (``moe_ops._sum_over_slots``); OLMoE's own eight lower as they did
+#: (``tests/test_moe.py``'s pinned k = 8 texts).
 OLMOE_TOY_STEP_SHA256 = (
+    "7da14bda2a33afe2ac8bc919bd50db2ce1f69cb7f0f7fcd0b11f0aabdcff63cf")
+OLMOE_TOY_STEP_SLOT_MINOR = (
     "fb53143a3ba4e6e01fbdd4dbed0d149f1c63d71b5633699f940939984c39c4ae")
 
 
@@ -370,13 +399,19 @@ def _olmoe_step_text():
     return re.sub(r"loc\(.*?\)", "", cb.jitted.lower(*args).as_text())
 
 
-def test_olmoes_toy_block_lowers_as_it_did_before_the_new_arguments():
+def test_olmoes_toy_block_lowers_as_it_did_before_the_new_arguments(
+        monkeypatch):
     """``window=None``, ``expert_offset=0``, every expert held, softmax and
     no bias leave OLMoE's lowering alone: the lowered step's text, forward
-    and backward, is the recorded one, to the byte."""
+    and backward, is the recorded one, to the byte, and apart from the order
+    its two slots a token are summed in the one PR 33 left."""
+    from paddle_tpu.ops import moe_ops
     text = _olmoe_step_text()
     assert text.count("stablehlo.while") == 2      # flash: forward, backward
     assert hashlib.sha256(text.encode()).hexdigest() == OLMOE_TOY_STEP_SHA256
+    monkeypatch.setattr(moe_ops, "_slot_major", lambda *a: False)
+    assert hashlib.sha256(_olmoe_step_text().encode()).hexdigest() == \
+        OLMOE_TOY_STEP_SLOT_MINOR
 
 
 def test_name_scope_rides_the_ops_and_their_grads_into_the_step():

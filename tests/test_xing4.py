@@ -542,7 +542,14 @@ def _ref_params_of_block(values, cfg):
 #: lowering replaces the hash and says so.  PR 48 did (it read 0f228436…
 #: until then): ``latent_attention`` hands the flash op its pieces (QRope,
 #: KRope) and the two ``concat``s and the ``expand`` are gone from the step.
+#: PR 63 did, for the toy alone (28f4c15b… until then, and still with the
+#: order forced slot-minor, ``JOYAI_TOY_STEP_SLOT_MINOR``): the toy's experts
+#: a token are no multiple of 8, so ``moe_ffn``'s un-sorts bring the slots
+#: home slot-major (``moe_ops._sum_over_slots``); JoyAI's own eight lower as
+#: they did.
 JOYAI_TOY_STEP_SHA256 = (
+    "ec85ee409703cb71494d8c2b56171c10b55f93c6b8330f55a683e31b12e2df9c")
+JOYAI_TOY_STEP_SLOT_MINOR = (
     "28f4c15b81468b08df4f320a905ff11ce51aec3d88a21f7eef051b8e8c560876")
 
 
@@ -562,10 +569,14 @@ def _joyai_step_text():
     return re.sub(r"loc\(.*?\)", "", cb.jitted.lower(*args).as_text())
 
 
-def test_joyais_step_lowers_as_it_did_before_the_new_attributes():
+def test_joyais_step_lowers_as_it_did_before_the_new_attributes(monkeypatch):
+    from paddle_tpu.ops import moe_ops
     text = _joyai_step_text()
     assert "hc_pre" not in text
     assert hashlib.sha256(text.encode()).hexdigest() == JOYAI_TOY_STEP_SHA256
+    monkeypatch.setattr(moe_ops, "_slot_major", lambda *a: False)
+    assert hashlib.sha256(_joyai_step_text().encode()).hexdigest() == \
+        JOYAI_TOY_STEP_SLOT_MINOR
 
 
 # -- scopes and counters -------------------------------------------------------------

@@ -16,7 +16,8 @@ hyper-connection ops' lowerings (``paddle_tpu_hc_lowerings_total``) and,
 for ``--cell solar`` and ``--cell ling``, the chunked scan's
 (``paddle_tpu_kda_lowerings_total``) and its gate's
 (``paddle_tpu_kda_gate_lowerings_total``), and the routing groups of every
-``moe_ffn`` lowering (``paddle_tpu_moe_lowerings_total{groups, gated}``),
+``moe_ffn`` lowering (``paddle_tpu_moe_lowerings_total{groups, gated,
+slot_sum}``),
 for ``--cell nemotron3`` the state-space scan's
 (``paddle_tpu_ssd_lowerings_total``), and for every cell that holds one
 every ``short_conv`` lowering's taps, form, bias and ``impl``
@@ -28,11 +29,12 @@ tool puts a pass-through in ``RecomputeOptimizer``'s place.
 
     JAX_PLATFORMS=cpu python3 tools/joyai_step_aot.py [--recompute] [--layers N]
 
-``--run``, on the chip: the same step handed to the chip's own compiler and
-run once, to see the chip refuse or take what the compiler here refused or
-took (prints whether it ran, the loss and the peak memory).
+``--run``, on the chip: the same step (``--cell``'s) handed to the chip's
+own compiler and run once under a watchdog, to see the chip refuse, take or
+never finish what the compiler here refused or took (prints whether it ran,
+the loss and the peak memory; a compile that passes here is not a run).
 
-    chiprun -- python3 tools/joyai_step_aot.py --run
+    chiprun -- python3 tools/joyai_step_aot.py --cell xing4 --run
 
 ``--fingerprint``: the sha256 of the step's lowered text with its debug
 info, which is what the persistent compile cache keys on; two processes
@@ -127,16 +129,24 @@ def reads_after_update(text):
 
 def run_on_chip(args):
     """The step built as the cell builds it, compiled by the chip's own
-    compiler and run once."""
+    compiler and run once, under a watchdog: a step that never ends on the
+    device (PR 63 met one: PERF.md section 7, row 45) leaves every thread's
+    stack on standard error after ``--watchdog`` seconds and exits."""
+    import faulthandler
+    import importlib
     import numpy as np
     from benchmark import harness
-    from benchmark.models import joyai_llm_flash as adapter
     from paddle_tpu import optimizer as opt
+    faulthandler.dump_traceback_later(args.watchdog, exit=True)
     if not args.recompute:
         opt.RecomputeOptimizer = _NoRecompute
-    config = harness.load_json("benchmark/configs/joyai_llm_flash.json")
-    traffic = harness.load_traffic("lm_mtp_s8192")
-    out = {"recompute": args.recompute, "ran": False}
+    config_name, traffic_name = CELLS[args.cell]
+    adapter = importlib.import_module("benchmark.models." + config_name)
+    config = harness.load_json(f"benchmark/configs/{config_name}.json")
+    traffic = harness.load_traffic(traffic_name)
+    if args.recompute and "recompute" in traffic:
+        traffic["recompute"] = True
+    out = {"cell": args.cell, "recompute": args.recompute, "ran": False}
     try:
         m = adapter.build_train(config, traffic, 7, 1, True)
         loss, = m["exe"].run(m["program"], feed=m["ring"][0],
@@ -157,6 +167,9 @@ def main():
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--recompute", action="store_true")
     ap.add_argument("--run", action="store_true")
+    ap.add_argument("--watchdog", type=int, default=420, help="--run: give "
+                    "up after this many seconds and print where every "
+                    "thread stood")
     ap.add_argument("--layers", type=int, default=0)
     ap.add_argument("--seq", type=int, default=0)
     ap.add_argument("--dump", default="")
@@ -269,11 +282,12 @@ def main():
 
     def moe_groups():
         """The step's moe_ffn forward lowerings (a recomputed clone counts)
-        by the experts routed over, those held, the routing groups and
-        whether the experts are gated (three grouped matmuls) or not."""
+        by the experts routed over, those held, the routing groups,
+        whether the experts are gated (three grouped matmuls) or not, and
+        the order the un-sorts' gather brings a token's slots home in."""
         from paddle_tpu.ops import moe_ops
         return counted(moe_ops.MOE_LOWERINGS_CTR, "experts", "held",
-                       "groups", "gated")
+                       "groups", "gated", "slot_sum")
 
     def ssd_lowerings():
         """The step's ssd_scan and ssd_scan_grad lowerings (a recomputed

@@ -47,18 +47,27 @@ the kernel's by the held row (PR 42), per share and load: no slot held, a
 fresh router's even share, one and a half times it, and every slot of every
 token held.  ``slots_*``: XLA's gather, mask and sum at the rung of
 ``held_ladder`` that load selects (``slots_back`` with the whole-buffer
-``dxs_g + dxs_u`` before it, as the lowering had it); ``rows_*``: the kernel;
-``same_*``: whether the two gave the same float32 to the bit on this device
+``dxs_g + dxs_u`` before it, as the lowering had it), a token's slots
+adjacent and summed over an ``[S, k, d]`` view; ``major_*`` (PR 63): the same
+through ``moe_ops._sum_over_slots`` slot-major, the view ``[k, S, d]``, which
+the lowerings take where ``k`` is no multiple of 8; ``rows_*``: the kernel,
+where it takes the shapes;
+``same_*`` (the kernel against ``slots_*``) and ``same_major_*``
+(``major_*`` against ``slots_*``): whether the two gave the same float32 to
+the bit on this device
 (where not, ``ulps_*``: the largest distance in units in the last place of
 the sum of the terms' magnitudes, and ``differ_*``: the share of the elements
 that differ).
 One more row at OLMoE's sizes (131072 slots of 2048, every expert held),
-timed only: no lowering takes the kernel there.
+and since PR 63 four a share at Nemotron-3-Nano's (8192 x 6 slots of 2688)
+and Xing4.0's (4096 x 4 of 3584): the two whose lowerings sum slot-major;
+Trinity's rows are the record of what k = 8 would gain or lose by it.
 
     chiprun -- python3 tools/trinity_experts_sweep.py --unsorts
 """
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -92,6 +101,11 @@ SHARES = (("trinity_mini", 128, 1024, 8192, 2048, 8, 16),
           ("smallthinker_21b_a3b", 64, 768, 16384, 2560, 6, 8))
 #: OLMoE's layer, every expert held: the un-sorts timed only (--unsorts)
 ALL_HELD = ("olmoe_1b_7b", 64, 1024, 16384, 2048, 8, 64)
+#: the two shares whose experts a token are no multiple of 8 on XLA's gather
+#: (--unsorts, PR 63): Nemotron-3-Nano's 8 of 128 (ladder 6144 / 12288 /
+#: 49152) and Xing4.0's 8 of 64 (4096 / 16384)
+NOT_BY_EIGHT = (("nemotron3_nano_30b_a3b", 128, 1856, 8192, 2688, 6, 8),
+                ("xing4_29b_a4b", 64, 1024, 4096, 3584, 4, 8))
 TOY = ("toy", 32, 128, 64, 128, 4, 4)
 
 
@@ -111,8 +125,8 @@ def routing(rng, S, k, E, G, share):
 
 
 def sweep_unsorts(args, timed, emit):
-    """The two un-sorts alone, by the slot and by the held row, per share and
-    load (module docstring)."""
+    """The two un-sorts alone, by the slot in both index orders and by the
+    held row, per share and load (module docstring)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -122,7 +136,22 @@ def sweep_unsorts(args, timed, emit):
     f32, bf = jnp.float32, jnp.bfloat16
     rng = np.random.default_rng(0)
     shares = (TOY,) if interpret else \
-        tuple(s for s in SHARES + (ALL_HELD,) if s[0] in args.shares)
+        tuple(s for s in SHARES + (ALL_HELD,) + NOT_BY_EIGHT
+              if s[0] in args.shares)
+
+    def distance(rec, tag, want, got, old, operands):
+        """Whether two un-sorts gave the same float32 to the bit; where
+        not, how far apart: in units in the last place of the sum of the
+        terms' magnitudes (a token's terms cancel: the sum's own last place
+        says nothing), and in how many of the elements."""
+        rec["same_" + tag] = bool((want == got).all())
+        if not rec["same_" + tag]:
+            size = np.asarray(old(*(jnp.abs(a) if a.dtype == bf else a
+                                    for a in operands)), np.float32)
+            rec["ulps_" + tag] = float((np.abs(got - want) /
+                                        np.spacing(size)).max())
+            rec["differ_" + tag] = float((want != got).mean())
+
     for name, E, _, S, d, k, G in shares:
         full = S * min(k, G)
         ladder = (full,) if G == E else moe_ops.held_ladder(S, k, G, E)
@@ -132,6 +161,7 @@ def sweep_unsorts(args, timed, emit):
         even = G / E
         loads = {"all": 1.0} if G == E else {
             "none": 0.0, "even": None, "heavy": 1.5 * even, "all": 1.0}
+        by_row = hr.fits(S, k, d, full, bf)
         for load, share in loads.items():
             held, _, place = jax.jit(
                 lambda t: moe_ops._held_slots(t, 0, G, k))(
@@ -139,16 +169,15 @@ def sweep_unsorts(args, timed, emit):
             held_rows = int(held.sum())
             rows = ladder[int(moe_ops.held_rung(held_rows, ladder))]
 
-            def slots_sum(y, place, held, top_p):
-                ys = jnp.take(y[:rows], jnp.minimum(place, rows - 1), axis=0)
-                ys = jnp.where(held[:, None], ys.astype(f32), 0.0)
-                return jnp.sum(ys.reshape(S, k, d) * top_p[:, :, None],
-                               axis=1)
+            def slots_sum(major, y, place, held, top_p):
+                return moe_ops._sum_over_slots(
+                    y[:rows], jnp.minimum(place, rows - 1), S, k, top_p,
+                    held, major)
 
-            def slots_back(a, b, place, held):
-                return jnp.where(held[:, None], jnp.take(
-                    (a + b)[:rows], jnp.minimum(place, rows - 1),
-                    axis=0).astype(f32), 0.0).reshape(S, k, d).sum(axis=1)
+            def slots_back(major, a, b, place, held):
+                return moe_ops._sum_over_slots(
+                    (a + b)[:rows], jnp.minimum(place, rows - 1), S, k,
+                    held=held, major=major)
 
             def rows_sum(y, place, held, top_p):
                 return hr.held_rows_to_tokens((y,), place, held, k, top_p,
@@ -162,24 +191,20 @@ def sweep_unsorts(args, timed, emit):
                    "held_rows": held_rows, "rung": rows}
             calls = {"sum": (slots_sum, rows_sum, (y, place, held, top_p)),
                      "back": (slots_back, rows_back, (a, b, place, held))}
-            for what, (old, new, operands) in calls.items():
-                old, new = jax.jit(old), jax.jit(new)
-                rec["slots_" + what] = timed(old, *operands)
+            for what, (slots, by_rows, operands) in calls.items():
+                minor, major = (jax.jit(functools.partial(slots, order))
+                                for order in (False, True))
+                rec["slots_" + what] = timed(minor, *operands)
+                rec["major_" + what] = timed(major, *operands)
                 want, got = (np.asarray(f(*operands), np.float64)
-                             for f in (old, new))
-                rec["same_" + what] = bool((want == got).all())
-                if not rec["same_" + what]:
-                    # how far apart, in units in the last place of the sum
-                    # of the terms' magnitudes (a token's terms cancel: the
-                    # sum's own last place says nothing), and in how many
-                    # of the elements
-                    size = np.asarray(jax.jit(old)(*(
-                        jnp.abs(a) if a.dtype == bf else a
-                        for a in operands)), np.float32)
-                    rec["ulps_" + what] = float((np.abs(got - want) /
-                                                 np.spacing(size)).max())
-                    rec["differ_" + what] = float((want != got).mean())
-                rec["rows_" + what] = timed(new, *operands)
+                             for f in (minor, major))
+                distance(rec, "major_" + what, want, got, minor, operands)
+                if by_row:
+                    new = jax.jit(by_rows)
+                    distance(rec, what, want,
+                             np.asarray(new(*operands), np.float64), minor,
+                             operands)
+                    rec["rows_" + what] = timed(new, *operands)
             emit(rec)
 
 
@@ -340,10 +365,13 @@ def main():
                     "and by the held row (pallas/held_rows.py), per share "
                     "and load, instead of the tiles")
     ap.add_argument("--shares", type=lambda s: s.split(","),
-                    default=[s[0] for s in SHARES + (ALL_HELD,)],
+                    default=[s[0] for s in SHARES + (ALL_HELD,) +
+                             NOT_BY_EIGHT],
                     help="the shares --lengths and --unsorts run "
                     "(comma-separated names of SHARES; olmoe_1b_7b: "
-                    "--unsorts' row with every expert held)")
+                    "--unsorts' row with every expert held; "
+                    "nemotron3_nano_30b_a3b, xing4_29b_a4b: --unsorts' rows "
+                    "at k 6 and k 4)")
     args = ap.parse_args()
     import jax
     import jax.numpy as jnp
