@@ -1958,9 +1958,13 @@ def moe_ffn(x, num_experts, top_k, d_expert, norm_topk_prob=False,
     load = helper.create_variable_for_type_inference("int32", True)
     top = helper.create_variable_for_type_inference("int32", True)
     # what moe_ffn_grad reuses: sort order, sorted rows, gate and up
-    # projections, the experts' output
+    # projections, the experts' output; the router's logits, its choice (by
+    # the column's slot and by index), its weights and its count of the rows
+    # an expert, where top_k is a multiple of 8 (ops/moe_ops.py:_narrow)
     saved = [helper.create_variable_for_type_inference(t, True)
-             for t in ("int32",) + (x.dtype,) * (4 if gated else 3)]
+             for t in ("int32",) + (x.dtype,) * (4 if gated else 3)
+             + ("float32", "int32", "int32", "float32", "int32")
+             * (int(top_k) % 8 == 0)]
     attrs = {"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob)}
     if score_func != "softmax":
         attrs["score_func"] = str(score_func)

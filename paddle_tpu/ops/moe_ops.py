@@ -22,6 +22,16 @@ was before they existed, bit for bit):
 - ``score_func``: ``softmax`` (over all experts) or ``sigmoid`` (each expert
   by itself).  Either way ``_router`` computes logits, scores, top-k and
   weights in float32 with the matmul at ``highest``, whatever AMP says.
+  The ``k`` are chosen without a sort (``_passes``: ``k`` passes of arg-max,
+  the first index winning a tie, which is ``lax.top_k``'s order; a TPU
+  lowers ``lax.top_k`` to a sort of the whole row), and the backward runs
+  no router again: ``moe_ffn`` saves the logits, the choice (as indices and
+  as each chosen column's slot), the weights and the count, and
+  ``moe_ffn_grad`` computes the router's two gradients
+  from them in closed form (``_router_backward``), two products where the
+  vjp of the forward had three (PR 64).  Not where ``top_k`` is no multiple
+  of 8 (``_narrow``): there the choice stays ``lax.top_k``'s and the
+  backward ``jax.vjp``'s of the router computed again, as they were.
 - input ``SelectBias`` [E_total]: added to the scores for the choice of the
   ``k`` experts only; the weights are the unbiased scores.  Not
   differentiated (the choice is not, and nothing else reads it).
@@ -32,9 +42,9 @@ was before they existed, bit for bit):
   ``noaux_tc`` / Ling 2.0's gate): the ``E_total`` experts in ``n_group``
   groups of consecutive experts, a group's score the sum of its two largest
   biased scores, the ``topk_group`` best groups kept, the others' entries
-  masked out of the choice of the ``k`` (``_group_mask``: ``lax.top_k`` over
-  ``[S, n_group, E / n_group]`` and over the group scores, no sort of
-  ``E_total``).  The weights stay the unbiased scores of the chosen; the
+  masked out of the choice of the ``k`` (``_group_mask``: the choice's own
+  top-k over ``[S, n_group, E / n_group]`` and over the group scores, no
+  sort of ``E_total``).  The weights stay the unbiased scores of the chosen; the
   backward passes nothing through the mask.  1 / 1 is no attribute and the
   lowering as it was.
 - ``expert_offset``: the router stays ``[d, E_total]``, the expert weights
@@ -333,73 +343,225 @@ def gated_experts(xs, wg, wu, wd, load, dt, impl=None, tiling=None,
     return mm(gate(g, u, dt), wd, load), g, u
 
 
-def _group_mask(sel, n_group, topk_group):
+def _narrow(k):
+    """Whether ``[S, k]`` arrays are narrow in the way a v5e's compiled step
+    has not survived: ``k`` no multiple of 8, so that XLA:TPU tiles them (4,
+    128) or pads them.  With 4 experts a token at 4096 tokens (Xing4.0's
+    cell) the step never finishes its first run with the choice by passes
+    of arg-max, and neither with the router's backward in closed form,
+    whichever way the ``k`` columns are joined or read (as PR 63's
+    transposed ``[4096, 4]`` index array: PERF.md section 6, PRs 63 and 64;
+    section 7, row 64); with ``jax.lax.top_k`` and ``jax.vjp`` it runs.  So
+    a router of such a ``k`` keeps both (:func:`_router`, ``moe_ffn_grad``);
+    LFM2's 4 of 32 at 16384 tokens ran either way, and 6 a token was not
+    tried.  Something the lowering reads off its attribute ``top_k``."""
+    return k % 8 != 0
+
+
+def _passes(a, k):
+    """The ``k`` largest of each row of ``a`` without a sort, as two lists of
+    ``k`` columns [..., 1]: ``k`` passes, each the row's maximum and the
+    first column that holds it (``argmax``: the first index wins a tie, as
+    ``lax.top_k`` orders them), that entry masked to -inf for the next pass.
+    Rows must hold at least ``k`` entries above -inf (the router's do:
+    scores are finite and the group mask leaves ``topk_group`` whole
+    groups), and no -0.0 beside a 0.0, which tie here.  Written as the
+    least column that equals the maximum, XLA:TPU's plan for JoyAI's
+    brim-full step no longer fitted the chip (16.04 of 15.75 GiB for the
+    parent's 14.55 GB).  ``jax.lax.top_k`` is a sort of the row on a TPU: on
+    a v5e the passes are 0.8 ms an evaluation faster over Ling's [8192, 512]
+    with its group mask, 0.6 over [8192, 320], 0.2 over 256, and level
+    within 0.08 ms from 128 entries down to 32 and from 8 passes down to 4,
+    so no rule on the shapes keeps the sort (tools/router_probe.py, PERF.md
+    section 6, PR 64)."""
+    cols = jnp.arange(a.shape[-1], dtype=jnp.int32)
+    tops, ats = [], []
+    for _ in range(k):
+        tops.append(jnp.max(a, axis=-1, keepdims=True))
+        ats.append(jnp.argmax(a, axis=-1, keepdims=True).astype(jnp.int32))
+        a = jnp.where(cols == ats[-1], -jnp.inf, a)
+    return tops, ats
+
+
+def _joined(ats):
+    """[..., k] int32 of ``k`` index columns: joined as float32 (exact, a
+    row is far under 2**24 entries) and made int32 behind a barrier.  Joined
+    as int32, XLA:TPU writes the [4096, 4] of Xing4.0's router by a
+    pad-and-add fusion into a (4, 128)-tiled layout, PR 63's shape (PERF.md
+    section 7, row 64); float32 arrays of that shape and tiling run."""
+    joined = jnp.concatenate([at.astype(jnp.float32) for at in ats], axis=-1)
+    return jax.lax.optimization_barrier(joined).astype(jnp.int32)
+
+
+def _top_k(a, k):
+    """``jax.lax.top_k(a, k)`` over the last axis by :func:`_passes`: the
+    same values and the same indices in the same order."""
+    tops, ats = _passes(a, k)
+    return jnp.concatenate(tops, axis=-1), _joined(ats)
+
+
+def _group_mask(sel, n_group, topk_group, top_k):
     """[S, E] bool: the experts of each token's ``topk_group`` best of
     ``n_group`` groups of ``E / n_group`` consecutive experts, a group's
     score the sum of its two largest entries of ``sel`` (DeepSeek-V3's
-    ``noaux_tc``); a ``top_k`` over [S, n_group, E / n_group] and one over
-    the group scores, no sort of ``E``."""
+    ``noaux_tc``): the two largest over [S, n_group, E / n_group] and the
+    ``topk_group`` largest of the group scores, by ``top_k`` (:func:`_top_k`,
+    no sort, or ``jax.lax.top_k``: the router's own choice says)."""
     S, E = sel.shape
-    top2, _ = jax.lax.top_k(sel.reshape(S, n_group, E // n_group), 2)
-    _, best = jax.lax.top_k(jnp.sum(top2, axis=-1), topk_group)
+    top2, _ = top_k(sel.reshape(S, n_group, E // n_group), 2)
+    _, best = top_k(jnp.sum(top2, axis=-1), topk_group)
     kept = jnp.any(best[:, :, None] == jnp.arange(n_group)[None, None, :],
                    axis=1)                                  # [S, n_group]
     return jnp.repeat(kept, E // n_group, axis=1)
 
 
+def _scores(logits, score_func):
+    if score_func == "sigmoid":
+        return jax.nn.sigmoid(logits)
+    if score_func == "softmax":
+        return jax.nn.softmax(logits, axis=-1)
+    raise ValueError(f"moe_ffn score_func {score_func!r}")
+
+
 def _router(xt, wr, k, renorm, score_func="softmax", bias=None,
             norm_eps=0.0, scale=1.0, n_group=1, topk_group=1):
     """Float32 at full precision whatever AMP says, softmax or sigmoid:
-    ``(top_p [S, k], lb [], z [])`` and, not differentiated, ``(top_e
-    [S, k], load [E])``.  ``bias`` [E] moves the choice of the ``k`` only;
-    ``top_p`` are the unbiased scores of the chosen, renormalised (``/ (sum
-    + norm_eps)``) and scaled if asked.  Under ``sigmoid`` the
-    load-balancing loss reads the scores normalised over the experts.
-    ``n_group`` > 1: the ``k`` are chosen among the experts of the
-    ``topk_group`` best groups (:func:`_group_mask` of the biased scores);
-    nothing is differentiated through the mask or the choice, the weights'
-    gradient flows through the chosen scores alone."""
+    ``(top_p [S, k], lb [], z [])`` and ``(top_e [S, k], load [E], logits
+    [S, E], rank [S, E] int32)``: ``rank`` holds a chosen column's slot, 1
+    to ``k``, and 0 elsewhere, and with ``load`` and ``logits`` is what
+    :func:`_router_backward` reads (None for a narrow ``k``,
+    :func:`_narrow`, whose choice is ``jax.lax.top_k``'s and whose backward
+    ``jax.vjp``'s).  ``bias`` [E] moves the
+    choice of the ``k`` only; ``top_p`` are the unbiased scores of the
+    chosen, renormalised (``/ (sum + norm_eps)``) and scaled if asked.
+    Under ``sigmoid`` the load-balancing loss reads the scores normalised
+    over the experts.  ``n_group`` > 1: the ``k`` are chosen among the
+    experts of the ``topk_group`` best groups (:func:`_group_mask` of the
+    biased scores).  The choice is :func:`_top_k`'s, no sort.  Three scopes
+    for the device trace: ``score`` (the product and the activation),
+    ``select`` (the mask, the choice, the weights), ``losses``."""
     f32 = jnp.float32
     S, E = xt.shape[0], wr.shape[-1]
-    logits = jnp.dot(xt.astype(f32), wr.astype(f32),
-                     precision=jax.lax.Precision.HIGHEST)           # [S, E]
-    if score_func == "sigmoid":
-        p = jax.nn.sigmoid(logits)
-    elif score_func == "softmax":
-        p = jax.nn.softmax(logits, axis=-1)
-    else:
-        raise ValueError(f"moe_ffn score_func {score_func!r}")
-    if n_group > 1:
-        sel = jax.lax.stop_gradient(
-            p if bias is None else p + bias.astype(f32)[None, :])
-        sel = jnp.where(_group_mask(sel, n_group, topk_group), sel, -jnp.inf)
-        _, top_e = jax.lax.top_k(sel, k)
-        top_p = jnp.take_along_axis(p, top_e, axis=-1)
-    elif bias is None:
-        top_p, top_e = jax.lax.top_k(p, k)
-    else:
-        _, top_e = jax.lax.top_k(
-            p + jax.lax.stop_gradient(bias.astype(f32))[None, :], k)
-        top_p = jnp.take_along_axis(p, top_e, axis=-1)
-    if renorm:
-        denom = jnp.sum(top_p, axis=-1, keepdims=True)
-        top_p = top_p / (denom + norm_eps if norm_eps else denom)
+    with jax.named_scope("score"):
+        logits = jnp.dot(xt.astype(f32), wr.astype(f32),
+                         precision=jax.lax.Precision.HIGHEST)       # [S, E]
+        p = _scores(logits, score_func)
+    with jax.named_scope("select"):
+        if _narrow(k):          # the choice and its scores as they were
+            if n_group > 1:
+                sel = jax.lax.stop_gradient(
+                    p if bias is None else p + bias.astype(f32)[None, :])
+                sel = jnp.where(_group_mask(sel, n_group, topk_group,
+                                            jax.lax.top_k), sel, -jnp.inf)
+                _, top_e = jax.lax.top_k(sel, k)
+                q = jnp.take_along_axis(p, top_e, axis=-1)
+            elif bias is None:
+                q, top_e = jax.lax.top_k(p, k)
+            else:
+                _, top_e = jax.lax.top_k(
+                    p + jax.lax.stop_gradient(bias.astype(f32))[None, :], k)
+                q = jnp.take_along_axis(p, top_e, axis=-1)
+            rank = None
+        else:
+            sel = p if bias is None else p + bias.astype(f32)[None, :]
+            if n_group > 1:
+                sel = jnp.where(_group_mask(sel, n_group, topk_group, _top_k),
+                                sel, -jnp.inf)
+            tops, ats = _passes(sel, k)
+            top_e = _joined(ats)
+            # the chosen scores and each chosen column's slot (1 .. k, 0 for
+            # the others) straight from the passes' columns, a
+            # compare-and-select a slot over the row: ``take_along_axis(p,
+            # top_e)`` is a gather of S * k single entries on a TPU, 0.55 to
+            # 1.4 ms at the cells' sizes and more than the choice itself,
+            # where the row sums cost 0.05 (tools/router_probe.py --pieces)
+            cols = jnp.arange(E, dtype=jnp.int32)
+            hits = [cols == at for at in ats]
+            q = jnp.concatenate(tops if sel is p else [
+                jnp.sum(jnp.where(hit, p, 0.0), axis=-1, keepdims=True)
+                for hit in hits], axis=-1)
+            rank = sum(jnp.where(hit, j + 1, 0) for j, hit in enumerate(hits))
+        if renorm:
+            denom = jnp.sum(q, axis=-1, keepdims=True)
+            q = q / (denom + norm_eps if norm_eps else denom)
+        top_p = q * scale if scale != 1.0 else q
+    with jax.named_scope("losses"):
+        load = _expert_load(top_e.reshape(S * k), E)
+        if score_func == "sigmoid":
+            p = p / jnp.sum(p, axis=-1, keepdims=True)
+        lb = E * jnp.sum(load.astype(f32) / S * jnp.mean(p, axis=0))
+        z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+    return (top_p, lb, z), (top_e, load, logits, rank)
+
+
+def _router_backward(xt, wr, logits, rank, load, cotangents, renorm,
+                     score_func="softmax", norm_eps=0.0, scale=1.0):
+    """``(dx [S, d], d_wr [d, E])``: the router's backward in closed form
+    from what the forward saved, the ``logits`` [S, E], the chosen columns'
+    slots ``rank`` [S, E] and the ``load`` [E]; no product of the forward's,
+    no choice, no count, no gather and no [S, k] integer array (a [4096, 4]
+    one read by its columns is among what hung Xing4.0's step: PERF.md
+    section 6, PR 64).  ``cotangents``: ``(d_top_p [S, k], d_lb, d_z)``, None
+    for a loss nobody differentiated.  Nothing flows through the bias, the
+    mask or the choice.
+
+    ``d_logits`` [S, E] is elementwise over the logits' rows beside a few
+    row sums: ``d_top_p`` set into the chosen columns by ``k``
+    compare-and-selects of ``rank`` (no scatter), then through
+    ``route_scale`` and the renormalisation (``q_j / (sum q + norm_eps)``,
+    the sum over the chosen columns of the row); the load-balancing loss ``E
+    sum_e load_e / S mean_s p_se`` adds ``d_lb E load_e / S^2`` to every
+    row, under ``sigmoid`` through the scores' normalisation over the
+    experts; the score function's own derivative; the z-loss's ``d_z 2 lse /
+    S softmax(logits)``.  Then the two products the vjp of the forward's
+    has, at ``highest``."""
+    f32 = jnp.float32
+    d_top_p, d_lb, d_z = cotangents
+    S, E = logits.shape
+    p = _scores(logits, score_func)
+    dp = sum(jnp.where(rank == j + 1, d_top_p[:, j:j + 1], 0.0)
+             for j in range(d_top_p.shape[-1]))
     if scale != 1.0:
-        top_p = top_p * scale
-    load = _expert_load(top_e.reshape(S * k), E)
+        dp = dp * scale
+    if renorm:      # top_p_j = q_j / denom: the row's sum moves every weight
+        chosen = rank > 0
+        denom = jnp.sum(jnp.where(chosen, p, 0.0), axis=-1, keepdims=True)
+        if norm_eps:
+            denom = denom + norm_eps
+        dp = (dp - jnp.where(chosen, jnp.sum(dp * p, axis=-1, keepdims=True)
+                             / denom, 0.0)) / denom
+    if d_lb is not None:
+        d_mean = (d_lb * E / S / S) * load.astype(f32)[None, :]
+        if score_func == "sigmoid":
+            total = jnp.sum(p, axis=-1, keepdims=True)
+            d_mean = (d_mean - jnp.sum(d_mean * p, axis=-1, keepdims=True)
+                      / total) / total
+        dp = dp + d_mean
     if score_func == "sigmoid":
-        p = p / jnp.sum(p, axis=-1, keepdims=True)
-    lb = E * jnp.sum(load.astype(f32) / S * jnp.mean(p, axis=0))
-    z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
-    return (top_p, lb, z), (top_e, load)
+        d_logits = dp * p * (1.0 - p)
+    else:
+        d_logits = p * dp - p * jnp.sum(p * dp, axis=-1, keepdims=True)
+    if d_z is not None:
+        lse = jax.nn.logsumexp(logits, axis=-1, keepdims=True)
+        d_logits = d_logits + (d_z * 2.0 / S) * lse * jnp.exp(logits - lse)
+    hi = jax.lax.Precision.HIGHEST
+    dx = jnp.dot(d_logits, wr.astype(f32).T, precision=hi)
+    d_wr = jnp.dot(xt.astype(f32).T, d_logits, precision=hi)
+    return dx.astype(xt.dtype), d_wr
+
+
+def _weights_of(attrs):
+    """The op's routing attributes that the weights read: ``_router``'s and
+    ``_router_backward``'s keywords alike."""
+    return dict(renorm=bool(attrs.get("norm_topk_prob", False)),
+                score_func=attrs.get("score_func", "softmax") or "softmax",
+                norm_eps=float(attrs.get("norm_eps", 0.0) or 0.0),
+                scale=float(attrs.get("route_scale", 1.0) or 1.0))
 
 
 def _router_of(attrs, k, bias):
     """``(xt, wr) -> _router(...)`` with the op's routing attributes."""
-    kw = dict(renorm=bool(attrs.get("norm_topk_prob", False)),
-              score_func=attrs.get("score_func", "softmax") or "softmax",
-              bias=bias, norm_eps=float(attrs.get("norm_eps", 0.0) or 0.0),
-              scale=float(attrs.get("route_scale", 1.0) or 1.0),
+    kw = dict(_weights_of(attrs), bias=bias,
               n_group=int(attrs.get("n_group", 1) or 1),
               topk_group=int(attrs.get("topk_group", 1) or 1))
     return lambda xt, wr: _router(xt, wr, k, **kw)
@@ -635,8 +797,14 @@ def _moe_ffn(ctx, ins, attrs):
     ``P_e``: mean of ``p_e`` over tokens); ZLoss [] = mean over tokens of
     ``logsumexp(r Wr)^2``; ExpertLoad [E] int32 rows per expert;
     TopExperts [B,T,k] int32, each token's experts by falling ``p``;
-    Saved: what ``moe_ffn_grad`` reuses (the sort order, the sorted rows, the
-    two projections and the experts' output).
+    Saved: what ``moe_ffn_grad`` reuses, in this order: the sort order
+    [S * k] int32, the sorted rows, the gate's projection (gated experts
+    only), the up projection and the experts' output (each over the row
+    buffer, in the rows' dtype), and the router's float32 logits [S, E], the
+    chosen columns' slots [S, E] int32 (1 to k, 0 elsewhere), its choice
+    [S, k] int32 (``TopExperts`` before its reshape), its weights [S, k]
+    float32 and its count [E] int32 (``ExpertLoad``); the router's five not
+    for a narrow ``k`` (:func:`_narrow`).
 
     Optional input SelectBias [E]; attributes ``score_func``, ``norm_eps``,
     ``route_scale`` and ``expert_offset`` as the module docstring says.  With
@@ -706,7 +874,7 @@ def _moe_ffn(ctx, ins, attrs):
     xt = x.reshape(S, d)
 
     with jax.named_scope("router"):
-        (top_p, lb, z), (top_e, load) = _router_of(
+        (top_p, lb, z), (top_e, load, logits, rank) = _router_of(
             attrs, k, X(ins, "SelectBias"))(
                 xt if router_x is None else router_x.reshape(S, d), wr)
 
@@ -757,7 +925,8 @@ def _moe_ffn(ctx, ins, attrs):
     return {"Out": [out.astype(x.dtype).reshape(B, T, d)], "LbLoss": [lb],
             "ZLoss": [z], "ExpertLoad": [load],
             "TopExperts": [top_e.astype(jnp.int32).reshape(B, T, k)],
-            "Saved": [a for a in (order, xs, g, u, y) if a is not None]}
+            "Saved": [a for a in (order, xs, g, u, y) if a is not None]
+            + ([] if _narrow(k) else [logits, rank, top_e, top_p, load])}
 
 
 def _moe_ffn_grad_maker(op, block, no_grad_set):
@@ -766,7 +935,7 @@ def _moe_ffn_grad_maker(op, block, no_grad_set):
     slots = tuple(s for s in ("X", "RouterW", "GateW", "UpW", "DownW")
                   if op.input(s))
     g_inputs = {"X$" + s: op.input(s) for s in slots}
-    if op.input("SelectBias"):
+    if op.input("SelectBias"):      # a narrow k's backward routes again
         g_inputs["X$SelectBias"] = op.input("SelectBias")
     if op.input("RouterX"):
         slots += ("RouterX",)
@@ -788,25 +957,35 @@ register_op("moe_ffn", _moe_ffn, grad_maker=_moe_ffn_grad_maker)
 @register_op("moe_ffn_grad")
 def _moe_ffn_grad(ctx, ins, attrs):
     """The backward of ``moe_ffn`` from what the forward saved: no second
-    sort, no second gather of the rows, no second forward matmul.  The router
-    (a [S, d] x [d, E] product, of ``RouterX`` where the forward had one: its
-    cotangent then is ``IG$RouterX``'s and ``X`` gets the experts' alone) is
-    computed again for its vjp; each grouped
-    matmul is transposed by ``jax.vjp`` at its saved operands, whose unused
-    primal XLA removes; the transposes of the two row gathers are gathers
-    (every row of ``x`` is read exactly ``k`` times; with a share of the
-    experts the gather back to tokens reads the held rows alone where the
-    forward's weighted sum does, and then adds each row's two parts as it
-    reads them).  An output whose gradient nobody produced counts as
-    zero."""
+    sort, no second gather of the rows, no second forward matmul, no second
+    routing.  The router's weights are the saved ones and its backward is in
+    closed form from the saved logits, slots and count (``_router_backward``
+    under the scope ``router/backward``: ``d_logits`` and the two products
+    with the router's weight and its input, ``RouterX`` where the forward had
+    one: its cotangent then is ``IG$RouterX``'s and ``X`` gets the experts'
+    alone) -- but for a narrow ``k`` (:func:`_narrow`), whose router is
+    computed again for its vjp as until PR 64 and whose ``Saved`` holds
+    nothing of the router; each grouped matmul is transposed by ``jax.vjp`` at its saved
+    operands, whose unused primal XLA removes; the transposes of the two row
+    gathers are gathers (every row of ``x`` is read exactly ``k`` times;
+    with a share of the experts the gather back to tokens reads the held
+    rows alone where the forward's weighted sum does, and then adds each
+    row's two parts as it reads them).  An output whose gradient nobody
+    produced counts as zero (a loss's: its term of the router's backward is
+    left out)."""
     x, wr = X(ins, "X$X"), X(ins, "X$RouterW")
     weights = [X(ins, "X$" + s) for s in ("GateW", "UpW", "DownW")]
-    if weights[0] is None:      # un-gated experts: no gate branch was saved
-        (order, xs, u, y), g = ins["Saved"], None
-    else:
-        order, xs, g, u, y = ins["Saved"]
     d_out, d_lb, d_z = (X(ins, "OG$" + s) for s in ("Out", "LbLoss", "ZLoss"))
     k = int(attrs["top_k"])
+    saved = list(ins["Saved"])
+    if not _narrow(k):
+        logits, rank, top_e, top_p, routed = saved[-5:]
+        load = routed       # the held path's is its experts' part of it
+        del saved[-5:]
+    if weights[0] is None:      # un-gated experts: no gate branch was saved
+        (order, xs, u, y), g = saved, None
+    else:
+        order, xs, g, u, y = saved
     B, T, d = x.shape
     S, R = B * T, B * T * k
     f32, dt = jnp.float32, xs.dtype
@@ -816,12 +995,13 @@ def _moe_ffn_grad(ctx, ins, attrs):
     E, n_held = wr.shape[-1], weights[1].shape[0]
     mm = _grouped_matmul(dt, tiling=None if n_held == E else _GMM_TILING_HELD)
     offset = int(attrs.get("expert_offset", 0) or 0)
+    router_in = xt if router_x is None else router_x.reshape(S, d)
 
-    with jax.named_scope("router"):
-        (top_p, _, _), router_vjp, (top_e, load) = jax.vjp(
-            _router_of(attrs, k, X(ins, "X$SelectBias")),
-            xt if router_x is None else router_x.reshape(S, d), wr,
-            has_aux=True)
+    if _narrow(k):          # the router again, for its vjp, as until PR 64
+        with jax.named_scope("router"):
+            (top_p, _, _), router_vjp, (top_e, load, *_) = jax.vjp(
+                _router_of(attrs, k, X(ins, "X$SelectBias")), router_in, wr,
+                has_aux=True)
 
     def transposed(rows, w, cot):
         return jax.vjp(lambda a, b: mm(a, b, load), rows, w)[1](cot)
@@ -984,10 +1164,16 @@ def _moe_ffn_grad(ctx, ins, attrs):
                 # over the whole buffer, and no switch
                 dx = unsort(dxs, place, slot_held, k)
 
-    with jax.named_scope("router"):
-        zero = jnp.zeros((), f32)
-        dx_r, d_wr = router_vjp((d_top_p, zero if d_lb is None else d_lb,
-                                 zero if d_z is None else d_z))
+    if _narrow(k):
+        with jax.named_scope("router"):
+            zero = jnp.zeros((), f32)
+            dx_r, d_wr = router_vjp((d_top_p, zero if d_lb is None else d_lb,
+                                     zero if d_z is None else d_z))
+    else:
+        with jax.named_scope("router"), jax.named_scope("backward"):
+            dx_r, d_wr = _router_backward(
+                router_in, wr, logits, rank, routed, (d_top_p, d_lb, d_z),
+                **_weights_of(attrs))
     out = {"IG$RouterW": [d_wr.astype(wr.dtype)], "IG$UpW": [d_wu],
            "IG$DownW": [d_wd]}
     if d_wg is not None:

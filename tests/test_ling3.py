@@ -218,7 +218,7 @@ def test_group_limited_selection_matches_the_argsort_form(groups):
               scale=2.5, n_group=n_group, topk_group=topk_group)
 
     def program(xt, wr):
-        (top_p, _, _), (top_e, load) = moe_ops._router(xt, wr, 4, **kw)
+        (top_p, _, _), (top_e, load, *_) = moe_ops._router(xt, wr, 4, **kw)
         return jnp.sum(top_p * cot), (top_p, top_e, load)
 
     def reference(xt, wr):

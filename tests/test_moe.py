@@ -395,10 +395,13 @@ def test_the_ladders_that_take_the_kernel_follow_their_first_rung(
 # same products and terms in the same order.
 
 import hashlib  # noqa: E402
+import os  # noqa: E402
+import re  # noqa: E402
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 KS = (2, 4, 6, 8)
 
 
@@ -567,15 +570,20 @@ def test_both_slot_orders_match_the_float32_reference(k, path, monkeypatch):
 
 #: sha256[:16] of the jaxpr text of ``_slot_step`` at the PARENT commit
 #: f43df25 (jax 0.9.0), taken there with ``_slot_text``: what "k = 8 lowers
-#: to the parent's text, and so does every k with the slots brought home
-#: slot-minor" is held to
+#: to the pinned text, and so does every k with the slots brought home
+#: slot-minor" is held to.  The three k = 8 texts were taken again on PR
+#: 64's own tree, which meant to change them (the router's choice by passes
+#: of arg-max, ``moe_ffn_grad``'s router in closed form from what ``Saved``
+#: now holds; full.k8 read a0d93ed3..., one.k8 ca0a37b2..., rung1.k8
+#: e7bba54b... until then); k 2, 4 and 6 are narrow (``moe_ops._narrow``),
+#: keep ``lax.top_k`` and the vjp, and are still PR 63's parent's
 PARENT_SLOT_JAXPRS = {
     "full.k2": "5e96d016caaa4814", "full.k4": "421f5a92c9b23a0b",
-    "full.k6": "3b416883f6bc55fc", "full.k8": "a0d93ed36a99b145",
+    "full.k6": "3b416883f6bc55fc", "full.k8": "5c80cf9631ab81f9",
     "one.k2": "af7c94ed137917e7", "one.k4": "c03d7c44cc86c10d",
-    "one.k6": "b0270ed505aef66f", "one.k8": "ca0a37b260b28652",
+    "one.k6": "b0270ed505aef66f", "one.k8": "4521a7e0b400c54c",
     "rung1.k2": "9fdf4ef03349bd88", "rung1.k4": "6138c8f18f708ca3",
-    "rung1.k6": "6e99033b6246e1c4", "rung1.k8": "e7bba54bcbbe360d"}
+    "rung1.k6": "6e99033b6246e1c4", "rung1.k8": "66061d6babe9ab6d"}
 
 
 def _slot_text(k, path, monkeypatch):
@@ -589,7 +597,7 @@ def _slot_text(k, path, monkeypatch):
 @pytest.mark.parametrize("k", KS)
 def test_k8_traces_as_the_parent_did_and_no_other_k_views_k_on_the_sublanes(
         k, path, monkeypatch):
-    """The traced text of the op and its grad op: at k = 8 the parent's, to
+    """The traced text of the op and its grad op: at k = 8 the pinned one, to
     the character; at k 2, 4 and 6 no ``reshape`` to ``(S, k, d)`` is left
     (the view is ``(k, S, d)``: the forward's sum and the backward's
     gather, on every rung), no index or mask is transposed, and with the order
@@ -615,3 +623,318 @@ def test_k8_traces_as_the_parent_did_and_no_other_k_views_k_on_the_sublanes(
     # transposed [S, 4] int32 or bool array hangs a v5e
     for dtype in ("i32", "bool"):
         assert f"{dtype}[{k},{S_TOY}] = transpose" not in text
+
+
+# -- the router: its backward by hand, its choice without a sort (PR 64) ------
+
+from paddle_tpu.framework.core import grad_var_name  # noqa: E402
+
+
+def _plain_router(xt, wr, k, renorm, score_func="softmax", bias=None,
+                  norm_eps=0.0, scale=1.0, n_group=1, topk_group=1):
+    """The router in its plain form, as ``moe_ffn`` and (under ``jax.vjp``)
+    ``moe_ffn_grad`` ran it until PR 64: every choice a ``jax.lax.top_k``,
+    the backward whatever the vjp of all this gives."""
+    f32 = jnp.float32
+    S, E = xt.shape[0], wr.shape[-1]
+    logits = jnp.dot(xt.astype(f32), wr.astype(f32),
+                     precision=jax.lax.Precision.HIGHEST)
+    p = jax.nn.sigmoid(logits) if score_func == "sigmoid" else \
+        jax.nn.softmax(logits, axis=-1)
+    sel = jax.lax.stop_gradient(
+        p if bias is None else p + bias.astype(f32)[None, :])
+    if n_group > 1:
+        top2, _ = jax.lax.top_k(sel.reshape(S, n_group, E // n_group), 2)
+        _, best = jax.lax.top_k(jnp.sum(top2, axis=-1), topk_group)
+        kept = jnp.any(best[:, :, None] == jnp.arange(n_group)[None, None, :],
+                       axis=1)
+        sel = jnp.where(jnp.repeat(kept, E // n_group, axis=1), sel, -jnp.inf)
+    _, top_e = jax.lax.top_k(sel, k)
+    top_p = jnp.take_along_axis(p, top_e, axis=-1)
+    if renorm:
+        denom = jnp.sum(top_p, axis=-1, keepdims=True)
+        top_p = top_p / (denom + norm_eps if norm_eps else denom)
+    if scale != 1.0:
+        top_p = top_p * scale
+    load = jnp.sum(top_e.reshape(S * k, 1) == jnp.arange(E)[None, :], axis=0,
+                   dtype=jnp.int32)
+    if score_func == "sigmoid":
+        p = p / jnp.sum(p, axis=-1, keepdims=True)
+    lb = E * jnp.sum(load.astype(f32) / S * jnp.mean(p, axis=0))
+    z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+    return (top_p, lb, z), (top_e, load)
+
+
+def _apart(got, want):
+    got, want = (np.asarray(a, np.float64) for a in (got, want))
+    return float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-30))
+
+
+ROUTER_S, ROUTER_D, ROUTER_E, ROUTER_K = 48, 24, 32, 8
+COTANGENTS = {"top_p": (1, 0, 0), "lb": (0, 1, 0), "z": (0, 0, 1),
+              "all": (1, 1, 1)}
+
+
+@pytest.mark.parametrize("cotangents", sorted(COTANGENTS))
+@pytest.mark.parametrize("scale", [1.0, 2.5])
+@pytest.mark.parametrize("renorm", [False, True], ids=["kept", "renorm"])
+@pytest.mark.parametrize("groups", [(1, 1), (8, 4)], ids=["free", "8of4"])
+@pytest.mark.parametrize("biased", [False, True], ids=["nobias", "bias"])
+@pytest.mark.parametrize("score_func", ["softmax", "sigmoid"])
+def test_the_routers_backward_by_hand_is_the_vjp_of_the_plain_form(
+        score_func, biased, groups, renorm, scale, cotangents):
+    """``_router`` gives the plain form's weights, losses, choice and count,
+    and ``_router_backward``, from the saved logits, slots and count alone,
+    the cotangents ``jax.vjp`` of the plain form gives: into
+    the router's input and its weight to 1e-6 of the largest entry, float32,
+    with the weights', the load-balancing loss's and the z-loss's cotangent
+    each alone and all three together (5e-6 where float32 itself is no
+    closer to float64 than 1e-6)."""
+    from paddle_tpu.ops import moe_ops
+    S, d, E, k = ROUTER_S, ROUTER_D, ROUTER_E, ROUTER_K
+    case = sorted(COTANGENTS).index(cotangents) + 4 * biased + 8 * renorm
+    rng = np.random.RandomState(case)
+    xt = jnp.asarray(rng.randn(S, d), jnp.float32)
+    wr = jnp.asarray(rng.randn(d, E) * 0.4, jnp.float32)
+    bias = jnp.asarray(rng.randn(E) * 0.3, jnp.float32) if biased else None
+    on = COTANGENTS[cotangents]
+    d_top_p = jnp.asarray(rng.randn(S, k), jnp.float32) * on[0]
+    d_lb, d_z = (jnp.asarray(c * v, jnp.float32)
+                 for c, v in zip(on[1:], (0.7, -0.3)))
+    weights = dict(renorm=renorm, score_func=score_func,
+                   norm_eps=1e-20 if renorm else 0.0, scale=scale)
+    kw = dict(weights, bias=bias, n_group=groups[0], topk_group=groups[1])
+    (want_out, pull, (want_e, want_load)) = jax.vjp(
+        lambda xt, wr: _plain_router(xt, wr, k, **kw), xt, wr, has_aux=True)
+    want = pull((d_top_p, d_lb, d_z))
+    out, (top_e, load, logits, rank) = moe_ops._router(xt, wr, k, **kw)
+    np.testing.assert_array_equal(np.asarray(top_e), np.asarray(want_e))
+    np.testing.assert_array_equal(np.asarray(load), np.asarray(want_load))
+    for a, b in zip(out, want_out):
+        assert _apart(a, b) <= 1e-6
+    np.testing.assert_array_equal(
+        np.asarray(rank), sum((j + 1) * (np.asarray(top_e)[:, j:j + 1]
+                                         == np.arange(E)) for j in range(k)))
+    got = moe_ops._router_backward(
+        xt, wr, logits, rank, load,
+        (d_top_p, d_lb if on[1] else None, d_z if on[2] else None), **weights)
+    # the load-balancing loss's cotangent alone is nearly one value along a
+    # row (the load is nearly even), which the score function's derivative
+    # cancels: there the vjp and the closed form each lie some 1e-6 from the
+    # float64 value (measured under softmax), so up to 5e-6 apart
+    limit = 5e-6 if cotangents == "lb" else 1e-6
+    for a, b, what in zip(got, want, ("dx", "d_wr")):
+        assert np.abs(np.asarray(b)).max() > 0, what
+        assert _apart(a, b) <= limit, (what, _apart(a, b))
+
+
+def _rows_to_choose_from(case, shape, k, rng):
+    a = rng.randn(*shape).astype(np.float32)
+    if case == "ties":          # every value twice or more, some k times over
+        a = np.round(a * 2) / 2
+        a[::3, : k + 1] = 7.0
+    elif case == "masked":      # k + 1 entries of a row above -inf
+        keep = np.argsort(rng.rand(*shape), axis=-1)[..., : k + 1]
+        masked = np.full(shape, -np.inf, np.float32)
+        np.put_along_axis(masked, keep, np.take_along_axis(
+            np.round(a), keep, axis=-1), axis=-1)
+        a = masked
+    return a + 0.0      # no -0.0, which lax.top_k orders behind 0.0
+
+
+@pytest.mark.parametrize("case", ["distinct", "ties", "masked"])
+@pytest.mark.parametrize("shape,k", [((40, 512), 8), ((40, 64), 8),
+                                     ((40, 32), 4), ((24, 8, 64), 2),
+                                     ((24, 8), 4), ((40, 128), 6)],
+                         ids=lambda v: "x".join(map(str, v))
+                         if isinstance(v, tuple) else f"k{v}")
+def test_the_choice_by_passes_is_top_ks_in_the_same_order(shape, k, case):
+    """``_top_k``'s ``k`` passes of arg-max against ``jax.lax.top_k``: the
+    same values and the same indices in the same order, on distinct values,
+    on rows of planted exact ties (the first index wins) and on rows with
+    all but ``k + 1`` entries masked to -inf; and no ``top_k`` or ``sort``
+    in its traced text."""
+    from paddle_tpu.ops import moe_ops
+    a = jnp.asarray(_rows_to_choose_from(
+        case, shape, k, np.random.RandomState(len(shape) + k)))
+    values, indices = moe_ops._top_k(a, k)
+    want_values, want_indices = jax.lax.top_k(a, k)
+    np.testing.assert_array_equal(np.asarray(indices),
+                                  np.asarray(want_indices))
+    np.testing.assert_array_equal(np.asarray(values), np.asarray(want_values))
+    assert indices.dtype == jnp.int32 and indices.shape == shape[:-1] + (k,)
+    text = str(jax.make_jaxpr(lambda a: moe_ops._top_k(a, k))(a))
+    assert "top_k[" not in text and " sort[" not in text
+
+
+def _parents_router_backward(monkeypatch, k, bias, **kw):
+    """``moe_ffn_grad`` as the parent ran its router: the two gradients from
+    ``jax.vjp`` of the plain form over the router's input and weight, in
+    place of ``_router_backward``."""
+    from paddle_tpu.ops import moe_ops
+
+    def router_backward(xt, wr, logits, rank, load, cotangents, **routing):
+        _, pull, _ = jax.vjp(
+            lambda xt, wr: _plain_router(xt, wr, k, bias=bias, **kw,
+                                         **routing), xt, wr, has_aux=True)
+        zero = jnp.zeros((), jnp.float32)
+        return pull(tuple(zero if c is None else c for c in cotangents))
+    monkeypatch.setattr(moe_ops, "_router_backward", router_backward)
+
+
+ROUTED = {"whole": dict(num_held=None, expert_offset=0),
+          "held": dict(num_held=8, expert_offset=OFFSET)}
+
+
+ROUTED_BIAS = np.random.RandomState(64).randn(32).astype(np.float32) * 0.05
+
+
+def _routed_step(path, recompute, seed=5):
+    """One training step of two ``moe_ffn`` layers through the executor,
+    sigmoid scores in groups under the selection bias ``ROUTED_BIAS``: the
+    loss and every parameter's and the input's gradient."""
+    E, k = 32, ROUTER_K
+    scope, main, startup = Scope(), Program(), Program()
+    with scope_guard(scope), program_guard(main, startup):
+        x = layers.data("x", shape=[2, 16, 24], dtype="float32",
+                        append_batch_size=False)
+        x.stop_gradient = False
+        h, aux, checkpoints = x, [], [x]
+        for i in range(2):
+            out, lb, z, _ = layers.moe_ffn(
+                h, E, k, 8, norm_topk_prob=True, score_func="sigmoid",
+                select_bias=True, norm_eps=1e-20, route_scale=2.5,
+                n_group=8, topk_group=4, param_prefix=f"moe{i}",
+                **ROUTED[path])
+            h = h + out
+            aux += [lb * 0.05, z * 0.01]
+            checkpoints.append(h)
+        loss = layers.mean(h * h)
+        for term in aux:
+            loss = loss + term
+        sgd = opt.SGD(learning_rate=0.0)
+        if recompute:
+            sgd = opt.RecomputeOptimizer(sgd)
+            sgd._set_checkpoints(checkpoints)
+        sgd.minimize(loss)
+        exe = Executor()
+        exe.run(startup, scope=scope, seed=seed)
+        for i in range(2):
+            scope.set_var(f"moe{i}.select_bias", jnp.asarray(ROUTED_BIAS))
+        names = [p.name for p in main.all_parameters() if p.trainable] + ["x"]
+        got = exe.run(main, feed={"x": np.random.RandomState(seed).randn(
+            2, 16, 24).astype(np.float32)}, scope=scope,
+            fetch_list=[loss.name] + [grad_var_name(n) for n in names])
+    return dict(zip(["loss"] + names, map(np.asarray, got)))
+
+
+@pytest.mark.parametrize("recompute", [False, True],
+                         ids=["plain", "recompute"])
+@pytest.mark.parametrize("path", sorted(ROUTED))
+def test_the_step_gives_the_gradients_the_parents_op_gave(
+        path, recompute, monkeypatch):
+    """``moe_ffn`` + ``moe_ffn_grad`` through the executor, every expert
+    held and a share of them, with and without ``RecomputeOptimizer``
+    (under it the saved logits and choice are the recomputed segment's):
+    the loss and every gradient against the same step with the grad op's
+    router as the parent had it, ``jax.vjp`` of the plain form."""
+    got = _routed_step(path, recompute)
+    _parents_router_backward(monkeypatch, ROUTER_K, jnp.asarray(ROUTED_BIAS),
+                             n_group=8, topk_group=4)
+    want = _routed_step(path, recompute)
+    assert set(got) == set(want) and len(got) == 2 * 4 + 2
+    assert abs(got["loss"] - want["loss"]) <= 1e-6 * abs(want["loss"])
+    for name in got:
+        assert np.abs(want[name]).max() > 0, name
+        assert _apart(got[name], want[name]) <= 1e-6, name
+
+
+def test_the_routers_parts_ride_their_scopes_into_the_lowered_step(
+        monkeypatch):
+    """``router/score``, ``router/select`` and ``router/losses`` forward and
+    ``router/backward`` in the grad op are in the lowered text's locations,
+    no router stands under a ``jvp(`` any more, and the benchmark's reader
+    (``part_scopes.part_of``) still gives all four to ``router`` while
+    ``tools/trace_by_op.py``'s gives each to itself."""
+    import importlib.util
+    from benchmark import part_scopes
+    text = jax.jit(_slot_step(8, "full", monkeypatch)).lower(
+        *_slot_operands(8, "full", shapes_only=True)).as_text(debug_info=True)
+    for scope in ("router/score", "router/select", "router/losses",
+                  "router/backward"):
+        assert scope in text, scope
+    # the experts' grouped matmuls are still transposed by jax.vjp; no part
+    # of the router stands inside a jvp( or a transpose( any more
+    assert "experts/transpose(jvp())" in text
+    assert not re.search(r"router[^\"]*jvp\(", text)
+    spec = importlib.util.spec_from_file_location(
+        "trace_by_op", os.path.join(ROOT, "tools", "trace_by_op.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    for rest, part in (("/router/score/dot_general", "score"),
+                       ("/router/select/argmax", "select"),
+                       ("/router/losses/reduce_sum", "losses"),
+                       ("/router/backward/dot_general", "backward")):
+        assert part_scopes.part_of(rest, part_scopes.MOE_PARTS) == "router"
+        assert part_scopes.part_of(rest, tool.ROUTER_PARTS) == part
+    assert part_scopes.part_of("/experts/transpose(jvp())/dot_general",
+                               tool.ROUTER_PARTS) == ""
+
+
+@pytest.mark.parametrize("mode", ["whole", "pieces"])
+def test_the_router_probe_runs_at_its_toy_size(mode):
+    """``tools/router_probe.py --cpu``: one line a cell; the router whole
+    (both choices the same experts, the closed-form backward beside the
+    vjp's) and, ``--pieces``, the choice's parts alone."""
+    import json
+    import subprocess
+    import sys
+    out = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "tools", "router_probe.py"),
+         "--cpu", "--calls", "1", "--cells", "ling,olmoe"]
+        + ["--pieces"] * (mode == "pieces"),
+        capture_output=True, text=True, timeout=300,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert out.returncode == 0, out.stderr[-2000:]
+    lines = [json.loads(line) for line in out.stdout.splitlines()
+             if line.startswith("{")]
+    assert [r["cell"] for r in lines] == ["ling", "olmoe"]
+    for r in lines:
+        if mode == "pieces":
+            assert {"passes_ms", "sort_ms", "gather_ms",
+                    "select_sums_ms"} <= set(r)
+            assert ("group_mask_ms" in r) == (r["cell"] == "ling")
+            continue
+        assert r["same_choice"]
+        assert r["d_wr_apart"] <= 1e-6 and r["dx_apart"] <= 1e-2   # bf16 x
+
+
+@pytest.mark.parametrize("k", KS)
+def test_a_narrow_k_keeps_the_sort_and_the_vjp_and_saves_no_routing(
+        k, monkeypatch):
+    """``moe_ops._narrow``: with ``top_k`` no multiple of 8 the op's choice
+    is ``lax.top_k``'s, its grad op takes ``jax.vjp`` of the router computed
+    again and ``Saved`` holds the five tensors it held before PR 64 (Xing4.0's
+    4 of 64 at 4096 tokens never finished a step otherwise, PERF.md section
+    6, PR 64); at 8 no ``top_k`` is left in either op, nothing of the router
+    stands under a ``jvp(`` and ``Saved`` holds the router's five more."""
+    from paddle_tpu.ops import moe_ops
+    assert moe_ops._narrow(k) == (k != 8)
+    text = _slot_text(k, "full", monkeypatch)
+    lowered = jax.jit(_slot_step(k, "full", monkeypatch)).lower(
+        *_slot_operands(k, "full", shapes_only=True)).as_text(debug_info=True)
+    scope, main = Scope(), Program()
+    with scope_guard(scope), program_guard(main, Program()):
+        x = layers.data("x", shape=[2, 8, 16], dtype="float32",
+                        append_batch_size=False)
+        layers.moe_ffn(x, 16, k, 8)
+    op, = [o for o in main.global_block().ops if o.type == "moe_ffn"]
+    if k == 8:
+        assert "top_k[" not in text and "router/backward" in lowered
+        assert not re.search(r"router[^\"]*jvp\(", lowered)
+        assert len(op.outputs["Saved"]) == 5 + 5
+    else:
+        assert text.count("top_k[") == 2         # forward, the vjp's forward
+        assert "router/backward" not in lowered
+        assert re.search(r"router[^\"]*jvp\(", lowered)
+        assert len(op.outputs["Saved"]) == 5

@@ -38,6 +38,9 @@ from benchmark import (harness, op_scopes, part_scopes,  # noqa: E402
 TAGS = ("window", "mtp.mla_proj", "mtp.shared_expert", "mtp", "mla_proj",
         "shared_expert", "dense_ffn")
 
+#: the scopes nested in ``moe_ffn``'s ``router`` and in its grad op's
+ROUTER_PARTS = ("score", "select", "losses", "backward")
+
 
 def _by_class(names, busy):
     """``{XLA operation class: [% of busy, instances]}`` of one scope's
@@ -71,6 +74,13 @@ def main():
         if op.startswith("moe_ffn"):
             moe[f"{role}/{part or '-'}"] = moe.get(f"{role}/{part or '-'}",
                                                    0.0) + s
+    # the router's own parts (PR 64: the product and activation, the choice,
+    # the losses; the grad op's closed-form backward)
+    router = {}
+    for (role, op, part), sec in part_scopes.reduce_parts(
+            paths[-1], (lo, hi), ROUTER_PARTS).items():
+        if op.startswith("moe_ffn") and part:
+            router[f"{role}/{part}"] = router.get(f"{role}/{part}", 0.0) + sec
     # what a model tags inside an op's scope: the windowed layers of
     # flash_attention, the dense ops of a shared expert or a dense FFN,
     # latent attention's projections, a multi-token-prediction module
@@ -94,7 +104,8 @@ def main():
            "remat_pct": share({k or "-": v for k, v in
                                remat_scopes.reduce_remat(
                                    paths[-1], (lo, hi)).items()}),
-           "moe_parts_pct": share(moe), "tagged_pct": share(tagged),
+           "moe_parts_pct": share(moe), "router_parts_pct": share(router),
+           "tagged_pct": share(tagged),
            "xla_ops_pct": {k: dict(list(share(by[k]).items())[:6])
                            for k in top + ["unscoped"] if k in by},
            "asked_xla_ops_pct": {k: _by_class(by[k], busy)
